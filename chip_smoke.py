@@ -1,0 +1,596 @@
+"""chip_smoke.py: does the system still start on the chip?
+
+`python chip_smoke.py` (no arguments) drives the main path once, in ONE
+process, at the full width of GPT-2-small (768 hidden, 12 layers, 12
+heads, vocab 50257, 1024 positions), weights random from the startup
+program's seed:
+
+  1. device     jax's default backend must be "tpu", or exit 1 before
+                any work (JAX_PLATFORMS=cpu, or no platform set and no
+                chip found, must never end in a CPU run that prints ok)
+  2. kernels    both Pallas flash families, forward and backward, bf16,
+                12 heads x 64, against mha_reference
+  3. train      gpt_lm_program -> pt.Executor, a few steps per shape;
+                the compiled step must contain the Mosaic custom call
+  4. serve      pt.server.serve over those weights in bf16; concurrent
+                POST /v1/generate (SSE and JSON), health counters, and
+                every greedy token checked on the float32 reference's
+                logits
+  5. placement  every live array sits on the chip (before shutdown)
+  6. four chips (only when jax.device_count() >= 4) serve on a
+                tensor-parallel mesh of 4 and train data-parallel
+
+One line per phase, a `summary=` line (versions, per-phase facts), then
+as the LAST line of stdout one JSON object with exactly these keys:
+  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+Without a TPU nothing is written to stdout at all. Wall and compile
+seconds are set-up facts, not performance records. Exit code 0 only if
+every phase passed (a failed phase ends in "ok": false and exit 1). The phases are plain functions
+of their sizes (tests/test_chip_smoke.py runs them at a toy size on the
+CPU); main() alone insists on the TPU.
+"""
+
+import http.client
+import json
+import sys
+import threading
+import time
+
+import numpy as np
+
+# Mosaic kernels reach XLA as this custom-call target.
+MOSAIC_TARGET = "tpu_custom_call"
+
+# kernels vs mha_reference (f32, highest matmul precision) on bf16
+# inputs: max |kernel - ref| over max |ref|. bf16 keeps 8 significand
+# bits (eps = 2^-8 = 3.9e-3); the kernel rounds p (forward) or ds
+# (backward) to bf16 once before its second matmul and the result once
+# on the way out, so a few eps of the largest element is what a correct
+# kernel shows, and a wrong mask, scale or block index shows O(1).
+KERNEL_REL_TOL = 2e-2
+
+# A served greedy token's reference logit (float32 forward at highest
+# matmul precision over the same bf16-rounded weights) must be within
+# this of that position's maximum. The engine computes in bf16 with a
+# bf16 KV cache and another batch shape, so its logits carry a few
+# 2^-8-relative roundings per layer of O(1) activations; a seeded-random
+# model's top two logits are often closer than that, so token identity
+# is not the contract here, closeness on the reference's logits is.
+# The largest logit of this model is about 2.5 (standard deviation 0.55),
+# so one bf16 rounding of it is 0.01; the margin allows ten. A wrong
+# token (bad page, bad position, bad shard) sits whole units below.
+LOGIT_MARGIN = 0.1
+
+# data-parallel loss vs the one-chip loss, same seed and batch: the
+# batch mean and the gradients are reduced in another order (per-chip
+# partial sums, then a cross-chip sum), and Adam's first steps amplify
+# sign flips of tiny gradients. That is worth parts in 1e5; a mis-sharded
+# batch or a missing all-reduce shows up as percents.
+DP_LOSS_REL_TOL = 1e-3
+
+
+class SmokeFailure(AssertionError):
+    """A phase found something wrong."""
+
+
+def _require(cond, message):
+    if not cond:
+        raise SmokeFailure(message)
+
+
+class CompileClock:
+    """Sums jax's own compile events, so a phase can report how much of
+    its wall time was XLA compilation (or loading from the persistent
+    cache: a cache hit is counted and costs its retrieval time only)."""
+
+    _COMPILE = "/jax/core/compile/backend_compile_duration"
+    _HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.compile_s = 0.0
+        self.cache_hits = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on_time)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_time(self, event, seconds, **_):
+        if event == self._COMPILE:
+            self.compile_s += seconds
+
+    def _on_event(self, event, **_):
+        if event == self._HIT:
+            self.cache_hits += 1
+
+
+# ---------------------------------------------------------------------------
+# phase 2: kernels
+# ---------------------------------------------------------------------------
+
+def _rel_err(got, ref):
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    return float(np.max(np.abs(got - ref)) / max(np.max(np.abs(ref)), 1e-30))
+
+
+def phase_kernels(seqs=(512, 1024), batch=2, heads=12, head_dim=64):
+    """Both kernel families (single-pass at s <= 512, tiled above),
+    forward and backward, causal without bias (GPT) and non-causal with
+    a per-key bias (BERT's padding mask), forced with impl="flash" and
+    compared with mha_reference."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import flash_attention as fa
+
+    worst = {}
+    for s in seqs:
+        family = "single_pass" if fa._small_ok(s, s) else "tiled"
+        for causal, with_bias in ((True, False), (False, True)):
+            ks = jax.random.split(jax.random.PRNGKey(s + int(causal)), 5)
+            shape = (batch, s, heads, head_dim)
+            q, k, v, g = (jax.random.normal(kk, shape, jnp.float32)
+                          .astype(jnp.bfloat16) for kk in ks[:4])
+            bias = None
+            if with_bias:
+                # a padding mask: the last quarter of the keys is masked
+                # for odd rows of the batch, plus a small graded term so
+                # the bias gradient has something to say
+                keep = (jnp.arange(s)[None, :] < (3 * s) // 4) \
+                    | (jnp.arange(batch)[:, None] % 2 == 0)
+                bias = (jnp.where(keep, 0.0, -1e4)
+                        + 0.1 * jax.random.normal(ks[4], (batch, s))
+                        ).astype(jnp.float32)[:, None, None, :]
+
+            def kernel_loss(q, k, v, bias):
+                o = fa.attention(q, k, v, bias, causal=causal,
+                                 impl="flash")
+                return jnp.sum(o.astype(jnp.float32)
+                               * g.astype(jnp.float32)), o
+
+            def ref_loss(q, k, v, bias):
+                o = fa.mha_reference(q, k, v, bias, causal=causal)
+                return jnp.sum(o * g.astype(jnp.float32)), o
+
+            argnums = (0, 1, 2, 3) if with_bias else (0, 1, 2)
+            (_, o_k), grads_k = jax.jit(jax.value_and_grad(
+                kernel_loss, argnums=argnums, has_aux=True))(q, k, v, bias)
+            f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+            with jax.default_matmul_precision("highest"):
+                (_, o_r), grads_r = jax.jit(jax.value_and_grad(
+                    ref_loss, argnums=argnums, has_aux=True))(*f32, bias)
+            names = ("out", "dq", "dk", "dv", "dbias")
+            for name, a, b in zip(names, (o_k,) + tuple(grads_k),
+                                  (o_r,) + tuple(grads_r)):
+                _require(a.shape == b.shape,
+                         f"kernels: {family} s={s} {name} shape "
+                         f"{a.shape} != reference {b.shape}")
+                a = np.asarray(a, np.float32)
+                _require(np.isfinite(a).all(),
+                         f"kernels: {family} s={s} {name} not finite")
+                err = _rel_err(a, b)
+                tag = f"{family}/{'causal' if causal else 'bias'}/{name}"
+                worst[tag] = max(worst.get(tag, 0.0), err)
+                _require(err <= KERNEL_REL_TOL,
+                         f"kernels: {tag} s={s} off the reference by "
+                         f"{err:.3e} of its largest element "
+                         f"(tolerance {KERNEL_REL_TOL:.0e})")
+    return {"cases": len(worst), "worst_rel_err": max(worst.values()),
+            "worst_case": max(worst, key=worst.get)}
+
+
+# ---------------------------------------------------------------------------
+# phase 3: train
+# ---------------------------------------------------------------------------
+
+def phase_train(cfg, batch, seq, steps=3, learning_rate=1e-4,
+                data_parallel=False, one_chip_losses=None):
+    """`steps` Adam steps of the causal-LM program on ONE repeated batch
+    through pt.Executor (bf16 AMP, dropout must be 0 in cfg so that the
+    loss falling is not left to luck). data_parallel runs the same step
+    under CompiledProgram.with_data_parallel over every device, and
+    one_chip_losses (same sizes, one device) is what it must reproduce.
+    Returns the losses, the scope (the serve phase reads its weights),
+    and how many Mosaic custom calls the compiled step holds."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu as pt
+    from paddle_tpu.models.gpt import gpt_lm_program
+
+    _require(cfg.dropout == 0.0, "train: cfg.dropout must be 0")
+    main, startup, fetches = gpt_lm_program(
+        cfg, seq, learning_rate=learning_rate, amp=True)
+    loss_var = fetches["loss"]
+    target = main
+    if data_parallel:
+        target = pt.CompiledProgram(main).with_data_parallel(
+            loss_name=loss_var.name)
+    rng = np.random.RandomState(seq)
+    feed = {"tokens": jnp.asarray(rng.randint(
+        0, cfg.vocab_size, (batch, seq)).astype(np.int64))}
+
+    exe = pt.Executor()
+    scope = pt.Scope()
+    losses = []
+    with pt.scope_guard(scope):
+        exe.run(startup)
+        for step in range(steps):
+            # the optimized HLO of the step, once: it is a second
+            # lower+compile of the same computation
+            exe.capture_hlo = step == 0
+            out, = exe.run(target, feed=feed, fetch_list=[loss_var])
+            losses.append(float(np.asarray(out).reshape(-1)[0]))
+    tag = f"train: b={batch} s={seq}" + (" dp" if data_parallel else "")
+    if data_parallel:
+        # replicated parameters, one copy per chip: a plan that had only
+        # met a virtual mesh could leave everything on the first device
+        placed = len(scope.find_var("gpt/wte").sharding.device_set)
+        _require(placed == jax.device_count(),
+                 f"{tag}: parameters sit on {placed} of "
+                 f"{jax.device_count()} devices")
+    _require(exe.last_hlo is not None,
+             f"{tag}: no HLO captured "
+             f"({getattr(exe, 'last_hlo_error', 'no error recorded')})")
+    mosaic_calls = exe.last_hlo.count(MOSAIC_TARGET)
+    if jax.default_backend() == "tpu":
+        # auto dispatch takes the kernel at s >= 256 on a TPU; a step
+        # that fell back to the einsum reference must not pass
+        _require(mosaic_calls >= 2 * cfg.layers,
+                 f"{tag}: compiled step holds {mosaic_calls} "
+                 f"{MOSAIC_TARGET} calls, expected a forward and a "
+                 f"backward kernel in each of {cfg.layers} layers")
+    _require(all(np.isfinite(losses)), f"{tag}: losses {losses}")
+    _require(losses[-1] < losses[0],
+             f"{tag}: loss did not fall on a repeated batch: {losses}")
+    facts = {"losses": losses, "mosaic_calls": mosaic_calls,
+             "scope": scope}
+    if one_chip_losses is not None:
+        gap = max(abs(a - b) / abs(a)
+                  for a, b in zip(one_chip_losses, losses))
+        facts["max_rel_gap_vs_one_chip"] = gap
+        _require(gap <= DP_LOSS_REL_TOL,
+                 f"{tag}: losses {losses} differ from the one-chip "
+                 f"{one_chip_losses} by {gap:.3g} relative "
+                 f"(tolerance {DP_LOSS_REL_TOL})")
+    return facts
+
+
+# ---------------------------------------------------------------------------
+# phases 4 and 5: serve, placement
+# ---------------------------------------------------------------------------
+
+def _generate(port, payload, timeout):
+    """POST /v1/generate. Returns (status, tokens, done) where done
+    carries finish_reason and the server's token count; SSE unless
+    payload["stream"] is False."""
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+    try:
+        conn.request("POST", "/v1/generate", json.dumps(payload),
+                     {"Content-Type": "application/json"})
+        r = conn.getresponse()
+        if r.status != 200:
+            return r.status, [], {"error": r.read().decode(errors="replace")}
+        if payload.get("stream") is False:
+            body = json.loads(r.read())
+            return r.status, body["tokens"], {
+                "finish_reason": body["finish_reason"],
+                "tokens": len(body["tokens"])}
+        tokens, done, event = [], None, "message"
+        for line in iter(r.readline, b""):
+            line = line.decode().rstrip("\n")
+            if not line:
+                event = "message"
+            elif line.startswith("event: "):
+                event = line[7:]
+            elif line.startswith("data: "):
+                obj = json.loads(line[6:])
+                if event == "done":
+                    done = obj
+                else:
+                    tokens.append(obj["token"])
+        return r.status, tokens, done or {}
+    finally:
+        conn.close()
+
+
+def _get_json(port, path):
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=60)
+    try:
+        conn.request("GET", path)
+        r = conn.getresponse()
+        return r.status, json.loads(r.read())
+    finally:
+        conn.close()
+
+
+def _reference_deficits(params, cfg, sequences, prompt_lens):
+    """How far below its position's maximum each served token's logit
+    is, on gpt_forward_logits in float32 at highest matmul precision.
+    sequences: prompt + output per request; padded to one length (the
+    causal mask keeps the padding out of every real position)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models.gpt_decode import gpt_forward_logits
+
+    f32 = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params)
+    width = max(len(s) for s in sequences)
+    padded = np.zeros((len(sequences), width), np.int32)
+    for i, s in enumerate(sequences):
+        padded[i, :len(s)] = s
+    with jax.default_matmul_precision("highest"):
+        logits = np.asarray(jax.jit(
+            lambda p, t: gpt_forward_logits(p, cfg, t))(f32, padded))
+    deficits = []
+    for i, (s, p_len) in enumerate(zip(sequences, prompt_lens)):
+        # output token j was sampled from the logits at position
+        # p_len - 1 + j
+        pos = np.arange(p_len - 1, len(s) - 1)
+        rows = logits[i, pos]
+        chosen = rows[np.arange(len(pos)), np.asarray(s[p_len:])]
+        deficits.append(rows.max(-1) - chosen)
+    return np.concatenate(deficits), float(logits.std())
+
+
+def _placement(platform):
+    """Phase 5: every live jax array sits on a `platform` device."""
+    import jax
+
+    live = jax.live_arrays()
+    stray = [(a.shape, str(a.dtype), sorted(d.platform for d in a.devices()))
+             for a in live
+             if any(d.platform != platform for d in a.devices())]
+    _require(not stray, f"placement: {len(stray)} of {len(live)} live "
+             f"arrays off {platform}: {stray[:5]}")
+    return len(live)
+
+
+def _mesh_facts(engine, tp):
+    """Four-chip layout: a column-parallel weight and the KV arena each
+    split over `tp` distinct devices, pool_bytes / tp on each chip."""
+    w = engine.scheduler.params["blocks"][0]["q"]["w"]
+    arena = engine.kv.kv
+    for name, arr in (("q.w", w), ("arena", arena)):
+        devices = {sh.device for sh in arr.addressable_shards}
+        _require(len(devices) == tp,
+                 f"serve: {name} sits on {len(devices)} devices, "
+                 f"expected {tp}: {sorted(map(str, devices))}")
+    _require(w.addressable_shards[0].data.shape[1] * tp == w.shape[1],
+             f"serve: q.w shard {w.addressable_shards[0].data.shape} "
+             f"of {w.shape} is not a 1/{tp} column slice")
+    per_chip = {sh.data.nbytes for sh in arena.addressable_shards}
+    expected = engine.kv.pool_bytes // tp
+    _require(per_chip == {expected} == {engine.kv.hbm_per_chip_bytes},
+             f"serve: arena bytes per chip {sorted(per_chip)}, reported "
+             f"{engine.kv.hbm_per_chip_bytes}, expected pool_bytes/{tp} "
+             f"= {expected}")
+    return {"arena_bytes_per_chip": expected}
+
+
+def phase_serve(params, cfg, prompt_lens, shared_prefix, max_new_tokens,
+                num_slots, prefill_buckets, max_len, mesh_shape=None,
+                request_timeout=600.0):
+    """pt.server.serve over `params`, every option but the sizes at its
+    default. len(prompt_lens) concurrent POST /v1/generate from threads
+    of this process; requests 0 and 1 share their first `shared_prefix`
+    tokens; even requests are greedy, odd ones sampled from a seed;
+    requests 2 and 3 ask for one JSON body, the rest stream SSE. Runs
+    the placement check before shutdown."""
+    import jax
+    import paddle_tpu as pt
+    from paddle_tpu.serving import ServingConfig
+
+    n = len(prompt_lens)
+    rng = np.random.RandomState(7)
+    prompts = [rng.randint(0, cfg.vocab_size, (p_len,)).tolist()
+               for p_len in prompt_lens]
+    prompts[1][:shared_prefix] = prompts[0][:shared_prefix]
+    payloads = []
+    for i, prompt in enumerate(prompts):
+        body = {"prompt": prompt, "max_new_tokens": max_new_tokens}
+        if i % 2:
+            body.update(temperature=0.8, seed=1000 + i)
+        if i in (2, 3):
+            body["stream"] = False
+        payloads.append(body)
+
+    server = pt.server.serve(params, cfg, pt.server.ServerConfig(
+        port=0, replicas=1, serving=ServingConfig(
+            num_slots=num_slots, prefill_buckets=prefill_buckets,
+            max_len=max_len, mesh_shape=mesh_shape)))
+    facts = {}
+    try:
+        engine = server.router.replicas[0].engine
+        results = [None] * n
+
+        def client(i):
+            try:
+                results[i] = _generate(server.port, payloads[i],
+                                       request_timeout)
+            except Exception as e:   # reported below, per request
+                results[i] = e
+
+        threads = [threading.Thread(target=client, args=(i,),
+                                    name=f"smoke-client-{i}")
+                   for i in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(request_timeout + 30.0)
+        _require(not any(t.is_alive() for t in threads),
+                 "serve: a client thread is still waiting")
+
+        for i, res in enumerate(results):
+            _require(not isinstance(res, Exception) and res is not None,
+                     f"serve: request {i} raised {res!r}")
+            status, tokens, done = res
+            _require(status == 200, f"serve: request {i} HTTP {status}: "
+                     f"{done}")
+            reason = done.get("finish_reason")
+            _require(reason in ("length", "stop"),
+                     f"serve: request {i} finish_reason {reason!r}")
+            _require(len(tokens) == done.get("tokens") == max_new_tokens,
+                     f"serve: request {i} streamed {len(tokens)} tokens, "
+                     f"server counted {done.get('tokens')}, asked for "
+                     f"{max_new_tokens}")
+            _require(all(0 <= t < cfg.vocab_size for t in tokens),
+                     f"serve: request {i} token out of range")
+
+        status, health = _get_json(server.port, "/healthz")
+        _require(status == 200, f"serve: /healthz HTTP {status}")
+        _require(health["replica_failures"] == 0
+                 and health["replica_restarts"] == 0,
+                 f"serve: replica_failures={health['replica_failures']} "
+                 f"replica_restarts={health['replica_restarts']}")
+        _require(all(r["state"] == "ok" for r in health["replicas"]),
+                 f"serve: replica states "
+                 f"{[r['state'] for r in health['replicas']]}")
+
+        stats = engine.stats()
+        # scheduler.py "Compile discipline": one prefill per bucket, one
+        # fused decode chunk, one admission sampler, one release (built
+        # on the first cancel; none is sent here)
+        bound = len(prefill_buckets) + 3
+        _require(stats["compiled_executables"] <= bound,
+                 f"serve: {stats['compiled_executables']} executables, "
+                 f"documented bound {bound}: "
+                 f"{engine.scheduler.compile_events}")
+        facts.update(compiled_executables=stats["compiled_executables"],
+                     prefix_hits=stats["prefix_hits"],
+                     pool_bytes=stats["pool_bytes"])
+
+        greedy = [i for i in range(n) if i % 2 == 0]
+        deficits, logit_std = _reference_deficits(
+            params, cfg,
+            [prompts[i] + results[i][1] for i in greedy],
+            [prompt_lens[i] for i in greedy])
+        facts.update(max_logit_deficit=float(deficits.max()),
+                     exact_argmax_share=float((deficits == 0).mean()),
+                     reference_logit_std=logit_std)
+        _require(deficits.max() <= LOGIT_MARGIN,
+                 f"serve: a greedy token sits {deficits.max():.3f} below "
+                 f"the float32 reference's best logit (margin "
+                 f"{LOGIT_MARGIN}, logit std {logit_std:.3f})")
+
+        if mesh_shape is not None:
+            facts.update(_mesh_facts(engine, mesh_shape[0]))
+        facts["live_arrays"] = _placement(jax.default_backend())
+    finally:
+        server.shutdown()
+    used = engine.kv.blocks_used
+    _require(used == 0, f"serve: {used} KV blocks in use after shutdown")
+    return facts
+
+
+# ---------------------------------------------------------------------------
+# the command
+# ---------------------------------------------------------------------------
+
+def _versions():
+    import jax
+    import jaxlib
+    from importlib import metadata
+
+    return {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+            "libtpu": metadata.version("libtpu")}
+
+
+def _fmt(value):
+    if isinstance(value, float):
+        return f"{value:.4g}"
+    if isinstance(value, list):
+        return "[" + ",".join(_fmt(v) for v in value) + "]"
+    return str(value)
+
+
+def main():
+    import jax
+
+    platform = jax.default_backend()
+    if platform != "tpu":
+        print(f"chip_smoke: jax's default backend is {platform!r}, not "
+              "'tpu'; this command runs on the chip only",
+              file=sys.stderr)
+        return 1
+    import jax.numpy as jnp
+    from paddle_tpu.models.gpt import GPTConfig
+    from paddle_tpu.models.gpt_decode import collect_gpt_params
+    from paddle_tpu.observability.device_peaks import device_report
+    from paddle_tpu.utils.compile_cache import ensure_compile_cache
+
+    device = device_report()
+    chips = device["count"]
+    print(f"phase=device ok platform={device['platform']} "
+          f"kind={device['kind']!r} chips={chips} "
+          f"compile_cache={ensure_compile_cache()}", flush=True)
+
+    clock = CompileClock()
+    phases = {}
+    failed = []
+
+    def run(name, fn, *args, **kwargs):
+        t0, c0, h0 = time.monotonic(), clock.compile_s, clock.cache_hits
+        try:
+            facts = fn(*args, **kwargs)
+        except Exception as e:
+            import traceback
+            traceback.print_exc()
+            failed.append(name)
+            print(f"phase={name} FAILED {type(e).__name__}: {e}",
+                  flush=True)
+            return None
+        shown = {k: v for k, v in facts.items() if k != "scope"}
+        shown.update(wall_s=round(time.monotonic() - t0, 1),
+                     compile_s=round(clock.compile_s - c0, 1),
+                     cache_hits=clock.cache_hits - h0)
+        phases[name] = shown
+        print(f"phase={name} ok "
+              + " ".join(f"{k}={_fmt(v)}" for k, v in shown.items()),
+              flush=True)
+        return facts
+
+    cfg = GPTConfig(dropout=0.0)     # GPT-2-small, every width published
+    serve_sizes = dict(
+        prompt_lens=(56, 60, 20, 100, 150, 200, 40, 256),
+        shared_prefix=48, max_new_tokens=32, num_slots=8,
+        prefill_buckets=(64, 256), max_len=1024)
+
+    run("kernels", phase_kernels)
+    # the published context (tiled kernels), then s=512 (single-pass)
+    long_run = run("train_s1024", phase_train, cfg, batch=8, seq=1024)
+    run("train_s512", phase_train, cfg, batch=16, seq=512)
+    params = None
+    if long_run is not None:
+        params = collect_gpt_params(long_run["scope"], cfg,
+                                    dtype=jnp.bfloat16)
+        serve = run("serve", phase_serve, params, cfg, **serve_sizes)
+        if serve is not None:
+            print(f"phase=placement ok live_arrays={serve['live_arrays']} "
+                  f"platform={platform}", flush=True)
+    else:
+        failed.append("serve")
+        print("phase=serve FAILED no weights: train_s1024 failed",
+              flush=True)
+
+    four_chips = "ran" if chips >= 4 else f"not run: chips={chips}"
+    if chips < 4:
+        print(f"phase=four_chips not run chips={chips} (needs 4)",
+              flush=True)
+    elif params is not None:
+        run("serve_tp4", phase_serve, params, cfg, mesh_shape=(4,),
+            **serve_sizes)
+        run("train_dp4", phase_train, cfg, batch=8, seq=1024,
+            data_parallel=True, one_chip_losses=long_run["losses"])
+
+    if failed:
+        print(f"chip_smoke: FAILED phases: {', '.join(failed)}",
+              file=sys.stderr)
+    print("summary=" + json.dumps(
+        {"versions": _versions(), "four_chips": four_chips,
+         "failed": failed, "phases": phases, "claim": None}), flush=True)
+    # The last line of stdout is the verdict and the device as jax
+    # reports it, these keys and no others: the driver's check reads it.
+    print(json.dumps({"ok": not failed, "device": device}), flush=True)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

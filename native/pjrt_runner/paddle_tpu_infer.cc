@@ -55,14 +55,14 @@ struct PTI_Predictor {
 
   bool Check(PJRT_Error* e, const char* what) {
     if (e == nullptr) return true;
-    PJRT_Error_Message_Args margs;
+    PJRT_Error_Message_Args margs = {};
     margs.struct_size = PJRT_Error_Message_Args_STRUCT_SIZE;
     margs.extension_start = nullptr;
     margs.error = e;
     api->PJRT_Error_Message(&margs);
     err = std::string(what) + ": " +
           std::string(margs.message, margs.message_size);
-    PJRT_Error_Destroy_Args dargs;
+    PJRT_Error_Destroy_Args dargs = {};
     dargs.struct_size = PJRT_Error_Destroy_Args_STRUCT_SIZE;
     dargs.extension_start = nullptr;
     dargs.error = e;
@@ -71,12 +71,12 @@ struct PTI_Predictor {
   }
 
   bool Await(PJRT_Event* event, const char* what) {
-    PJRT_Event_Await_Args args;
+    PJRT_Event_Await_Args args = {};
     args.struct_size = PJRT_Event_Await_Args_STRUCT_SIZE;
     args.extension_start = nullptr;
     args.event = event;
     if (!Check(api->PJRT_Event_Await(&args), what)) return false;
-    PJRT_Event_Destroy_Args d;
+    PJRT_Event_Destroy_Args d = {};
     d.struct_size = PJRT_Event_Destroy_Args_STRUCT_SIZE;
     d.extension_start = nullptr;
     d.event = event;
@@ -94,7 +94,7 @@ static bool StageHostBuffer(PTI_Predictor* p, const void* data,
     p->err = "unsupported dtype " + meta.dtype;
     return false;
   }
-  PJRT_Client_BufferFromHostBuffer_Args hb;
+  PJRT_Client_BufferFromHostBuffer_Args hb = {};
   hb.struct_size = PJRT_Client_BufferFromHostBuffer_Args_STRUCT_SIZE;
   hb.extension_start = nullptr;
   hb.client = p->client;
@@ -136,7 +136,7 @@ static PTI_Predictor* CreateImpl(const char* plugin_so,
   if (!get_api) return fail("plugin has no GetPjrtApi symbol");
   p->api = get_api();
 
-  PJRT_Plugin_Initialize_Args pi;
+  PJRT_Plugin_Initialize_Args pi = {};
   pi.struct_size = PJRT_Plugin_Initialize_Args_STRUCT_SIZE;
   pi.extension_start = nullptr;
   if (!p->Check(p->api->PJRT_Plugin_Initialize(&pi), "plugin init"))
@@ -171,7 +171,7 @@ static PTI_Predictor* CreateImpl(const char* plugin_so,
     named.push_back(v);
   }
 
-  PJRT_Client_Create_Args cc;
+  PJRT_Client_Create_Args cc = {};
   cc.struct_size = PJRT_Client_Create_Args_STRUCT_SIZE;
   cc.extension_start = nullptr;
   cc.create_options = named.empty() ? nullptr : named.data();
@@ -186,7 +186,7 @@ static PTI_Predictor* CreateImpl(const char* plugin_so,
     return fail(p->err);
   p->client = cc.client;
 
-  PJRT_Client_AddressableDevices_Args ad;
+  PJRT_Client_AddressableDevices_Args ad = {};
   ad.struct_size = PJRT_Client_AddressableDevices_Args_STRUCT_SIZE;
   ad.extension_start = nullptr;
   ad.client = p->client;
@@ -214,7 +214,7 @@ static PTI_Predictor* CreateImpl(const char* plugin_so,
   prog.format = kFmt;
   prog.format_size = sizeof(kFmt) - 1;
 
-  PJRT_Client_Compile_Args comp;
+  PJRT_Client_Compile_Args comp = {};
   comp.struct_size = PJRT_Client_Compile_Args_STRUCT_SIZE;
   comp.extension_start = nullptr;
   comp.client = p->client;
@@ -228,14 +228,14 @@ static PTI_Predictor* CreateImpl(const char* plugin_so,
   // the executable's REAL output count must match the manifest — PJRT
   // fills output_lists[0][i] for every executable output, so a stale
   // manifest would otherwise overflow the buffer array
-  PJRT_LoadedExecutable_GetExecutable_Args ge;
+  PJRT_LoadedExecutable_GetExecutable_Args ge = {};
   ge.struct_size = PJRT_LoadedExecutable_GetExecutable_Args_STRUCT_SIZE;
   ge.extension_start = nullptr;
   ge.loaded_executable = p->exec;
   if (!p->Check(p->api->PJRT_LoadedExecutable_GetExecutable(&ge),
                 "get executable"))
     return fail(p->err);
-  PJRT_Executable_NumOutputs_Args no;
+  PJRT_Executable_NumOutputs_Args no = {};
   no.struct_size = PJRT_Executable_NumOutputs_Args_STRUCT_SIZE;
   no.extension_start = nullptr;
   no.executable = ge.executable;
@@ -368,7 +368,7 @@ static int RunImpl(PTI_Predictor* p, const void* const* inputs,
     for (auto* bufs : {&in_bufs, &out_bufs}) {
       for (PJRT_Buffer* b : *bufs) {
         if (!b) continue;
-        PJRT_Buffer_Destroy_Args bd;
+        PJRT_Buffer_Destroy_Args bd = {};
         bd.struct_size = PJRT_Buffer_Destroy_Args_STRUCT_SIZE;
         bd.extension_start = nullptr;
         bd.buffer = b;
@@ -399,7 +399,7 @@ static int RunImpl(PTI_Predictor* p, const void* const* inputs,
     }
   }
 
-  PJRT_ExecuteOptions eo;
+  PJRT_ExecuteOptions eo = {};
   eo.struct_size = PJRT_ExecuteOptions_STRUCT_SIZE;
   eo.extension_start = nullptr;
   eo.send_callbacks = nullptr;
@@ -411,7 +411,7 @@ static int RunImpl(PTI_Predictor* p, const void* const* inputs,
   eo.num_non_donatable_input_indices = 0;
   eo.context = nullptr;
 
-  PJRT_LoadedExecutable_Execute_Args ex;
+  PJRT_LoadedExecutable_Execute_Args ex = {};
   ex.struct_size = PJRT_LoadedExecutable_Execute_Args_STRUCT_SIZE;
   ex.extension_start = nullptr;
   ex.executable = p->exec;
@@ -435,7 +435,7 @@ static int RunImpl(PTI_Predictor* p, const void* const* inputs,
   std::string d2h_err;
   for (size_t i = 0; i < out_bufs.size(); ++i) {
     if (d2h_err.empty()) {
-      PJRT_Buffer_ToHostBuffer_Args th;
+      PJRT_Buffer_ToHostBuffer_Args th = {};
       th.struct_size = PJRT_Buffer_ToHostBuffer_Args_STRUCT_SIZE;
       th.extension_start = nullptr;
       th.src = out_bufs[i];
@@ -461,21 +461,21 @@ void PTI_Destroy(PTI_Predictor* p) {
   if (!p) return;
   if (p->api) {
     for (PJRT_Buffer* b : p->param_bufs) {
-      PJRT_Buffer_Destroy_Args bd;
+      PJRT_Buffer_Destroy_Args bd = {};
       bd.struct_size = PJRT_Buffer_Destroy_Args_STRUCT_SIZE;
       bd.extension_start = nullptr;
       bd.buffer = b;
       p->api->PJRT_Buffer_Destroy(&bd);
     }
     if (p->exec) {
-      PJRT_LoadedExecutable_Destroy_Args d;
+      PJRT_LoadedExecutable_Destroy_Args d = {};
       d.struct_size = PJRT_LoadedExecutable_Destroy_Args_STRUCT_SIZE;
       d.extension_start = nullptr;
       d.executable = p->exec;
       p->api->PJRT_LoadedExecutable_Destroy(&d);
     }
     if (p->client) {
-      PJRT_Client_Destroy_Args d;
+      PJRT_Client_Destroy_Args d = {};
       d.struct_size = PJRT_Client_Destroy_Args_STRUCT_SIZE;
       d.extension_start = nullptr;
       d.client = p->client;

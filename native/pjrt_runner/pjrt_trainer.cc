@@ -50,13 +50,13 @@ const PJRT_Api* g_api = nullptr;
 
 void Check(PJRT_Error* err, const char* what) {
   if (err == nullptr) return;
-  PJRT_Error_Message_Args margs;
+  PJRT_Error_Message_Args margs = {};
   margs.struct_size = PJRT_Error_Message_Args_STRUCT_SIZE;
   margs.extension_start = nullptr;
   margs.error = err;
   g_api->PJRT_Error_Message(&margs);
   std::string msg(margs.message, margs.message_size);
-  PJRT_Error_Destroy_Args dargs;
+  PJRT_Error_Destroy_Args dargs = {};
   dargs.struct_size = PJRT_Error_Destroy_Args_STRUCT_SIZE;
   dargs.extension_start = nullptr;
   dargs.error = err;
@@ -65,12 +65,12 @@ void Check(PJRT_Error* err, const char* what) {
 }
 
 void Await(PJRT_Event* event, const char* what) {
-  PJRT_Event_Await_Args args;
+  PJRT_Event_Await_Args args = {};
   args.struct_size = PJRT_Event_Await_Args_STRUCT_SIZE;
   args.extension_start = nullptr;
   args.event = event;
   Check(g_api->PJRT_Event_Await(&args), what);
-  PJRT_Event_Destroy_Args d;
+  PJRT_Event_Destroy_Args d = {};
   d.struct_size = PJRT_Event_Destroy_Args_STRUCT_SIZE;
   d.extension_start = nullptr;
   d.event = event;
@@ -222,7 +222,7 @@ int main(int argc, char** argv) {
   if (!get_api) Die("plugin has no GetPjrtApi symbol");
   g_api = get_api();
 
-  PJRT_Plugin_Initialize_Args pi;
+  PJRT_Plugin_Initialize_Args pi = {};
   pi.struct_size = PJRT_Plugin_Initialize_Args_STRUCT_SIZE;
   pi.extension_start = nullptr;
   Check(g_api->PJRT_Plugin_Initialize(&pi), "plugin init");
@@ -251,7 +251,7 @@ int main(int argc, char** argv) {
     named.push_back(v);
   }
 
-  PJRT_Client_Create_Args cc;
+  PJRT_Client_Create_Args cc = {};
   cc.struct_size = PJRT_Client_Create_Args_STRUCT_SIZE;
   cc.extension_start = nullptr;
   cc.create_options = named.empty() ? nullptr : named.data();
@@ -265,7 +265,7 @@ int main(int argc, char** argv) {
   Check(g_api->PJRT_Client_Create(&cc), "client create");
   PJRT_Client* client = cc.client;
 
-  PJRT_Client_AddressableDevices_Args ad;
+  PJRT_Client_AddressableDevices_Args ad = {};
   ad.struct_size = PJRT_Client_AddressableDevices_Args_STRUCT_SIZE;
   ad.extension_start = nullptr;
   ad.client = client;
@@ -294,7 +294,7 @@ int main(int argc, char** argv) {
   prog.format = kFmt;
   prog.format_size = sizeof(kFmt) - 1;
 
-  PJRT_Client_Compile_Args comp;
+  PJRT_Client_Compile_Args comp = {};
   comp.struct_size = PJRT_Client_Compile_Args_STRUCT_SIZE;
   comp.extension_start = nullptr;
   comp.client = client;
@@ -317,7 +317,7 @@ int main(int argc, char** argv) {
       Die("in" + std::to_string(i) + " is " +
           std::to_string(raw[i].size()) + " bytes, manifest wants " +
           std::to_string(want));
-    PJRT_Client_BufferFromHostBuffer_Args hb;
+    PJRT_Client_BufferFromHostBuffer_Args hb = {};
     hb.struct_size = PJRT_Client_BufferFromHostBuffer_Args_STRUCT_SIZE;
     hb.extension_start = nullptr;
     hb.client = client;
@@ -338,7 +338,7 @@ int main(int argc, char** argv) {
   }
 
   // ---- the training loop: carry buffers stay on device ---------------------
-  PJRT_ExecuteOptions eo;
+  PJRT_ExecuteOptions eo = {};
   eo.struct_size = PJRT_ExecuteOptions_STRUCT_SIZE;
   eo.extension_start = nullptr;
   eo.send_callbacks = nullptr;
@@ -353,7 +353,7 @@ int main(int argc, char** argv) {
   std::vector<double> losses;
   std::vector<PJRT_Buffer*> out_bufs(out_meta.size());
   for (int step = 0; step < steps; ++step) {
-    PJRT_LoadedExecutable_Execute_Args ex;
+    PJRT_LoadedExecutable_Execute_Args ex = {};
     ex.struct_size = PJRT_LoadedExecutable_Execute_Args_STRUCT_SIZE;
     ex.extension_start = nullptr;
     ex.executable = exec;
@@ -375,7 +375,7 @@ int main(int argc, char** argv) {
       size_t bytes = DtypeSize(out_meta[li].dtype);
       for (int64_t d : out_meta[li].shape) bytes *= d;
       std::string host(bytes, '\0');
-      PJRT_Buffer_ToHostBuffer_Args th;
+      PJRT_Buffer_ToHostBuffer_Args th = {};
       th.struct_size = PJRT_Buffer_ToHostBuffer_Args_STRUCT_SIZE;
       th.extension_start = nullptr;
       th.src = out_bufs[li];
@@ -408,7 +408,7 @@ int main(int argc, char** argv) {
       for (auto& [out_j, in_i] : carry) kept[out_j] = true;
       for (size_t j = 0; j < out_bufs.size(); ++j) {
         if (!kept[j]) {
-          PJRT_Buffer_Destroy_Args bd;
+          PJRT_Buffer_Destroy_Args bd = {};
           bd.struct_size = PJRT_Buffer_Destroy_Args_STRUCT_SIZE;
           bd.extension_start = nullptr;
           bd.buffer = out_bufs[j];
@@ -425,7 +425,7 @@ int main(int argc, char** argv) {
     size_t bytes = DtypeSize(out_meta[j].dtype);
     for (int64_t d : out_meta[j].shape) bytes *= d;
     std::string host(bytes, '\0');
-    PJRT_Buffer_ToHostBuffer_Args th;
+    PJRT_Buffer_ToHostBuffer_Args th = {};
     th.struct_size = PJRT_Buffer_ToHostBuffer_Args_STRUCT_SIZE;
     th.extension_start = nullptr;
     th.src = out_bufs[j];

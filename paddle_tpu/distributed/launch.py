@@ -7,7 +7,9 @@ vars. TPU redesign: one process per *host* (a host drives all its local TPU
 chips through one jax client; intra-host parallelism is the device mesh, not
 processes), so --nproc_per_node defaults to 1 and multi-process launches are
 for multi-host (or CPU-mesh emulation) where jax.distributed coordinates via
-PADDLE_COORDINATOR_ADDRESS.
+PADDLE_COORDINATOR_ADDRESS. A chip belongs to one process at a time and the
+launcher assigns no chips: several processes on ONE host are the CPU-cluster
+path (JAX_PLATFORMS=cpu, Gloo), which is the launcher's tested use.
 
 Usage:
     python -m paddle_tpu.distributed.launch --hosts=ip1,ip2 train.py args...
@@ -64,7 +66,6 @@ def build_env(rank: int, args) -> dict:
         "PADDLE_NUM_PROCESSES": str(world),
         "PADDLE_COORDINATOR_ADDRESS":
             f"{hosts[0]}:{args.started_port + 9000}",
-        "FLAGS_selected_tpus": "all",
     })
     return env
 

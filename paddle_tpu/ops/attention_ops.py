@@ -5,7 +5,10 @@ ring.py). Replaces the reference's composed attention graphs (nets.py
 scaled_dot_product_attention) and the operators/fused/ family with one op
 whose lowering picks the right TPU implementation:
 
-  * no cp_axis          -> Pallas flash kernel on TPU, XLA reference on CPU
+  * no cp_axis          -> Pallas flash kernel on TPU, XLA reference on CPU;
+                           under a GSPMD mesh the kernel runs per shard of
+                           the batch (shard_map), because a Mosaic kernel
+                           cannot be partitioned automatically
   * cp_axis + 'ring'    -> ring attention over the mesh axis (ppermute)
   * cp_axis + 'ulysses' -> all-to-all sequence parallelism
 
@@ -25,6 +28,31 @@ def _cp_active(ctx, attrs):
     mesh = ctx.mesh
     return (cp_axis and mesh is not None and cp_axis in mesh.axis_names
             and mesh.shape[cp_axis] > 1)
+
+
+def _on_batch_shards(ctx, attrs, fn, *args):
+    """fn(*args) for the flash path. GSPMD cannot partition a Mosaic
+    kernel (jax refuses to lower one under a mesh: "wrap the call in a
+    shard_map"), so under a GSPMD mesh fn runs once per shard of the
+    batch: dim 0 of every argument and result (q/k/v/out are (b, ...),
+    lse is (b*n, ...) batch-major) is split over the data-parallel axis
+    when it divides the batch, and replicated over every other axis.
+    Inside explicit-SPMD execution (ctx.spmd_axes) the op already sees
+    one shard."""
+    mesh = ctx.mesh
+    if mesh is None or ctx.spmd_axes:
+        return fn(*args)
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    axis = attrs.get("batch_axis", "dp")
+    if axis not in mesh.axis_names or args[0].shape[0] % mesh.shape[axis]:
+        axis = None
+    spec = P(axis)
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(None if a is None else spec for a in args),
+        out_specs=spec, check_vma=False)(*args)
 
 
 def _fused_attention_grad_maker(op, block, no_grad_set):
@@ -69,9 +97,13 @@ def _fused_attention_grad_lower(ctx, ins, attrs):
     if not _cp_active(ctx, attrs):
         use_flash, _ = flash_dispatch(q, k, bias4, impl)
         if use_flash:
-            dq, dk, dv = attention_bwd_saved(
-                q, k, v, bias4, out, lse, g.astype(out.dtype), causal,
-                sm_scale, impl)
+            def bwd(q_, k_, v_, bias_, out_, lse_, g_):
+                return attention_bwd_saved(q_, k_, v_, bias_, out_, lse_,
+                                           g_, causal, sm_scale, impl)
+
+            dq, dk, dv = _on_batch_shards(
+                ctx, attrs, bwd, q, k, v, bias4, out, lse,
+                g.astype(out.dtype))
             return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv]}
 
     def f(q_, k_, v_):
@@ -90,7 +122,7 @@ def _fused_attention_grad_lower(ctx, ins, attrs):
              grad_maker=_fused_attention_grad_maker,
              grad_lower=_fused_attention_grad_lower)
 def _fused_attention(ctx, ins, attrs):
-    from .flash_attention import attention_fwd_lse
+    from .flash_attention import attention_fwd_lse, flash_dispatch
     from ..parallel.ring import (ring_attention_sharded,
                                  ulysses_attention_sharded)
 
@@ -138,6 +170,12 @@ def _fused_attention(ctx, ins, attrs):
     bias4 = None
     if bias_k is not None:
         bias4 = bias_k[:, None, None, :]
-    out, lse = attention_fwd_lse(q, k, v, bias4, causal=causal,
+    def fwd(q_, k_, v_, bias_):
+        return attention_fwd_lse(q_, k_, v_, bias_, causal=causal,
                                  sm_scale=sm_scale, impl=impl)
+
+    if flash_dispatch(q, k, bias4, impl)[0]:
+        out, lse = _on_batch_shards(ctx, attrs, fwd, q, k, v, bias4)
+    else:   # plain XLA ops: GSPMD partitions them itself
+        out, lse = fwd(q, k, v, bias4)
     return {"Out": [out], "Lse": [lse if lse is not None else dummy_lse]}

@@ -11,7 +11,9 @@ Layout is (batch, seq, heads, head_dim) end-to-end — no transposes around
 the kernel. Row statistics (m, l, lse, delta) are stored lane-padded to 128
 (Mosaic tiling requires the last dim be a lane multiple or the full array
 dim). `attention()` dispatches: Pallas on TPU backends, the einsum
-reference elsewhere (CPU tests) or when shapes are tiny/unaligned.
+reference elsewhere (CPU tests) or when shapes are tiny/unaligned;
+impl='flash' forces the kernel (interpreted on CPU, an error on any
+other non-TPU backend).
 """
 
 from __future__ import annotations
@@ -681,7 +683,8 @@ def flash_dispatch(q, k, bias=None, impl: Optional[str] = None):
     if impl is None:
         impl = os.environ.get("FLAGS_attention_impl", "")
     flag_ok = impl in ("", "auto", "flash")
-    on_tpu = jax.default_backend() == "tpu"
+    platform = jax.default_backend()
+    on_tpu = platform == "tpu"
     # flash supports only per-key biases: (b, sk) or (b, 1, 1, sk)
     bias_ok = bias is None or bias.ndim == 2 or (
         bias.ndim == 4 and bias.shape[1] == 1 and bias.shape[2] == 1)
@@ -701,7 +704,13 @@ def flash_dispatch(q, k, bias=None, impl: Optional[str] = None):
             "biases.")
     use = impl == "flash" or (flag_ok and on_tpu and bias_ok and shapes_ok
                               and long_enough and impl != "xla")
-    return use, not on_tpu
+    if use and platform not in ("tpu", "cpu"):
+        # the Pallas interpreter is a CPU test facility; anywhere else a
+        # forced kernel would run interpreted and pass for the real one
+        raise RuntimeError(
+            "impl='flash' compiles for TPU (Mosaic) and interprets on "
+            f"CPU for tests; the active backend is {platform!r}")
+    return use, platform == "cpu"
 
 
 def attention(q, k, v, bias=None, causal: bool = False,

@@ -271,9 +271,10 @@ _TICK_HELP = {
                        "triggered a compile (trace + XLA compile + "
                        "first execution; coarse buckets, 10ms..60s)",
     "mfu_proxy": "model-FLOPs-utilization proxy: cost_analysis FLOPs "
-                 "x dispatch rate over nominal peak FLOPs (override "
-                 "peak via PT_SERVING_PEAK_FLOPS) — a trend line, "
-                 "not an absolute MFU",
+                 "x dispatch rate over the device's published bf16 "
+                 "peak (observability.device_peaks, or "
+                 "PT_SERVING_PEAK_FLOPS); unset on a device with no "
+                 "published peak — a trend line, not an absolute MFU",
     "dispatch_hbm_bytes": "cost_analysis bytes accessed per fused "
                           "decode dispatch (the HBM roofline side of "
                           "the attribution)",

@@ -170,6 +170,7 @@ import numpy as np
 from .. import profiler
 from ..observability import request_log as _request_log
 from ..observability.tracer import get_tracer
+from ..utils.compile_cache import ensure_compile_cache
 from .kv_cache import ShapeBuckets, SlotKVCache
 
 _TRACER = get_tracer()
@@ -293,13 +294,6 @@ class SwappedSequence:
                                       if self.scales is not None else 0)
 
 
-# nominal single-chip peak used by the MFU proxy when the operator
-# hasn't told us the real one (PT_SERVING_PEAK_FLOPS). Deliberately a
-# round 1 TFLOP/s: the gauge is a TREND line (cost x dispatch rate over
-# a constant), not an absolute utilization claim — see _TICK_HELP.
-_NOMINAL_PEAK_FLOPS = 1e12
-
-
 class CompileJournal:
     """Executable cost & compile journal (ServingConfig(tick_profile=
     True) only — the engine installs one on the scheduler's
@@ -318,14 +312,23 @@ class CompileJournal:
     journal can never disagree with the compile-count hook."""
 
     def __init__(self, clock=time.monotonic, peak_flops=None):
+        # the peak the MFU proxy divides by: the caller's, else the
+        # operator's (PT_SERVING_PEAK_FLOPS), else the published bf16
+        # rate of the device jax reports; None on a device outside the
+        # table, and then there is no proxy
         if peak_flops is None:
             try:
                 peak_flops = float(
                     os.environ.get("PT_SERVING_PEAK_FLOPS") or 0) or None
             except ValueError:
                 peak_flops = None
-        self.peak_flops = float(peak_flops if peak_flops
-                                else _NOMINAL_PEAK_FLOPS)
+        if peak_flops is None:
+            from ..observability.device_peaks import device_peaks
+            try:
+                peak_flops = device_peaks()["bf16_flops"]
+            except LookupError:
+                peak_flops = None
+        self.peak_flops = peak_flops
         self._clock = clock
         self._t0 = clock()
         # one record per compile event, in dispatch order — the
@@ -374,9 +377,10 @@ class CompileJournal:
         """FLOPs issued per second over the journal's lifetime, as a
         fraction of peak_flops: sum over families of calls x per-
         dispatch FLOPs, divided by elapsed wall seconds and the peak.
-        None until at least one family has a known cost."""
+        None until at least one family has a known cost, and always on
+        a device with no published peak."""
         elapsed = self._clock() - self._t0
-        if elapsed <= 0:
+        if elapsed <= 0 or self.peak_flops is None:
             return None
         issued = 0.0
         known = False
@@ -602,6 +606,7 @@ class ContinuousBatchingScheduler:
     def _ensure_jits(self):
         if self._chunk_jit is not None:
             return
+        ensure_compile_cache()
         import jax
         import jax.numpy as jnp
         # deferred: models/__init__ pulls every model module (each doing

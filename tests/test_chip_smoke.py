@@ -1,0 +1,115 @@
+"""chip_smoke.py at a toy size on the CPU: its phase functions run (the
+kernels interpreted), main() refuses anything but a TPU before doing any
+work, and the compile cache lands where it should. The real sizes run on
+the chip only: `python chip_smoke.py`."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+from paddle_tpu.models.gpt import GPTConfig  # noqa: E402
+from paddle_tpu.models.gpt_decode import collect_gpt_params  # noqa: E402
+from paddle_tpu.utils.compile_cache import ensure_compile_cache  # noqa: E402
+
+
+def _toy(layers=2, **kw):
+    return GPTConfig(vocab_size=97, hidden=32, layers=layers, heads=4,
+                     max_pos=128, dropout=0.0, **kw)
+
+
+def test_main_exits_nonzero_on_cpu_before_any_work(monkeypatch, capsys):
+    def no_work(*args, **kwargs):
+        raise AssertionError("a phase ran without a TPU")
+
+    for name in ("phase_kernels", "phase_train", "phase_serve"):
+        monkeypatch.setattr(chip_smoke, name, no_work)
+    assert jax.default_backend() == "cpu"
+    assert chip_smoke.main() != 0
+    assert capsys.readouterr().out == ""    # no phase line, no result
+
+
+def test_last_stdout_line_is_the_verdict_and_the_device(monkeypatch, capsys):
+    """The driver's check reads the last line of stdout: one JSON object
+    with the keys ok and device {platform, kind, count} and no others.
+    Everything else the run has to say goes on the lines before it."""
+    import json
+
+    from paddle_tpu.models import gpt_decode
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(chip_smoke, "phase_kernels", lambda: {"cases": 0})
+    monkeypatch.setattr(
+        chip_smoke, "phase_train",
+        lambda *a, **kw: {"losses": [2.0, 1.0], "scope": None})
+    monkeypatch.setattr(gpt_decode, "collect_gpt_params",
+                        lambda *a, **kw: {})
+    for passing in (True, False):
+        def serve(*a, **kw):
+            chip_smoke._require(passing, "serve: stubbed to fail")
+            return {"live_arrays": 0}
+
+        monkeypatch.setattr(chip_smoke, "phase_serve", serve)
+        assert chip_smoke.main() == (0 if passing else 1)
+        lines = capsys.readouterr().out.splitlines()
+        last = json.loads(lines[-1])
+        assert set(last) == {"ok", "device"} and last["ok"] is passing
+        assert set(last["device"]) == {"platform", "kind", "count"}
+        assert isinstance(last["device"]["count"], int)
+        assert lines[-2].startswith("summary=")
+        assert json.loads(lines[-2][len("summary="):])["claim"] is None
+
+
+def test_kernels_phase_both_families_interpreted():
+    # 128 takes the single-pass family, 640 (> 512) the tiled one
+    facts = chip_smoke.phase_kernels(seqs=(128, 640), batch=1, heads=1)
+    assert facts["cases"] == 18     # 2 families x (4 causal + 5 bias)
+    assert facts["worst_rel_err"] <= chip_smoke.KERNEL_REL_TOL
+
+
+def test_train_then_serve_phases():
+    """The train phase's scope feeds the serve phase, as main() chains
+    them."""
+    cfg = _toy()
+    trained = chip_smoke.phase_train(cfg, batch=4, seq=16)
+    assert trained["mosaic_calls"] == 0     # no Mosaic on the CPU
+    assert trained["losses"][-1] < trained["losses"][0]
+    params = collect_gpt_params(trained["scope"], cfg, dtype=jnp.bfloat16)
+    facts = chip_smoke.phase_serve(
+        params, cfg, prompt_lens=(6, 7, 3, 12, 14, 16), shared_prefix=4,
+        max_new_tokens=8, num_slots=4, prefill_buckets=(8, 16), max_len=32)
+    assert facts["compiled_executables"] <= 2 + 3
+    assert facts["max_logit_deficit"] <= chip_smoke.LOGIT_MARGIN
+
+
+def test_data_parallel_step_with_the_kernel_matches_one_device():
+    """A Mosaic kernel cannot be partitioned by GSPMD; the fused_attention
+    op runs it per shard of the batch under a mesh. Forced here
+    (impl="flash", interpreted) because the CPU's auto dispatch never
+    takes the kernel, which is how this combination went unmet until the
+    chip refused it."""
+    cfg = _toy(layers=1, attn_impl="flash")
+    one = chip_smoke.phase_train(cfg, batch=8, seq=128)
+    # raises SmokeFailure if the losses part ways or parameters do not
+    # sit on every device
+    chip_smoke.phase_train(cfg, batch=8, seq=128, data_parallel=True,
+                           one_chip_losses=one["losses"])
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    configured = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert ensure_compile_cache() == str(tmp_path)
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == configured
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    first = ensure_compile_cache()
+    assert first == ensure_compile_cache() \
+        == os.path.join(REPO, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == first

@@ -928,11 +928,19 @@ def test_tick_profile_phase_sum_matches_wall(tiny_engine_params):
         eng.close()
 
 
-def test_compile_journal_families_and_gauges(tiny_engine_params):
+def test_compile_journal_families_and_gauges(tiny_engine_params,
+                                             monkeypatch):
     """The journal attributes every jit dispatch: family rows for both
     prefill buckets, the fused decode chunk and the sampler, compile
     wall seconds with shares summing to 1, cost_analysis-derived
-    per-dispatch FLOPs, and the live mfu-proxy / HBM gauges."""
+    per-dispatch FLOPs, and the live mfu-proxy / HBM gauges. The proxy
+    divides by a peak the operator states or the device's published
+    one; the CPU has neither, so without the variable there is none."""
+    from paddle_tpu.serving.scheduler import CompileJournal
+    bare = CompileJournal()
+    bare.note_call("decode_chunk", 0.1, True, {"flops": 1e9})
+    assert bare.peak_flops is None and bare.mfu_proxy() is None
+    monkeypatch.setenv("PT_SERVING_PEAK_FLOPS", "1e12")
     cfg, params = tiny_engine_params
     eng = _attr_engine(params, cfg, tick_profile=True)
     try:

@@ -195,8 +195,9 @@ def test_bench_serving_row_shape():
                                "collect", "stream", "bookkeeping"}
         assert all(v >= 0 for v in phases.values())
         assert phases["launch"] > 0          # dispatches really ticked
-        assert row["extra"]["mfu_proxy"] is not None
-        assert 0 < row["extra"]["mfu_proxy"] < 1
+        # ... which exists only over a published or stated peak, and
+        # the CPU has neither
+        assert row["extra"]["mfu_proxy"] is None
     # the traced re-run restored the disabled production default
     import paddle_tpu.observability as obs
     assert not obs.tracing_enabled()
@@ -378,7 +379,7 @@ def test_bench_serving_http_row_shape():
     # performance-attribution columns mirror the library rows
     phases = e["tick_phase_ms"]
     assert isinstance(phases, dict) and phases.get("launch", 0) > 0
-    assert e["mfu_proxy"] is not None and 0 < e["mfu_proxy"] < 1
+    assert e["mfu_proxy"] is None   # no published peak for the CPU
     # the server was torn down: no leftover wire surface
     import paddle_tpu as pt
     snap = pt.observability.get_registry().snapshot()

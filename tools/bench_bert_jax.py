@@ -6,7 +6,7 @@ head over all positions, rbg dropout, bf16 compute + f32 Adam — with
 device-resident carried state and donated buffers. The ceiling the
 framework's 57.3% MFU headline should approach.
 
-Flags: BATCH, SEQ, STEPS, DROPOUT, PEAK_TFLOPS.
+Flags: BATCH, SEQ, STEPS, DROPOUT.
 """
 
 import functools
@@ -20,13 +20,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddle_tpu.observability.device_peaks import device_peaks
+
 jax.config.update("jax_default_prng_impl", "rbg")
 
 BATCH = int(os.environ.get("BATCH", 128))
 SEQ = int(os.environ.get("SEQ", 128))
 STEPS = int(os.environ.get("STEPS", 30))
 DROPOUT = float(os.environ.get("DROPOUT", 0.1))
-PEAK = float(os.environ.get("PEAK_TFLOPS", 197.0)) * 1e12
 
 VOCAB, HIDDEN, LAYERS, HEADS, TYPES = 30522, 768, 12, 12, 2
 FFN = 4 * HIDDEN
@@ -162,6 +163,7 @@ def main():
     l = float(l)  # hard D2H sync
     dt = (time.perf_counter() - t0) / STEPS
     fl = flops_per_step(BATCH, SEQ)
+    PEAK = device_peaks()["bf16_flops"]
     print(f"batch={BATCH} seq={SEQ}: {dt*1e3:.1f} ms/step, "
           f"{BATCH/dt:.1f} samples/s, MFU={fl/dt/PEAK:.3f}, loss={l:.3f}")
 

@@ -15,6 +15,8 @@ import numpy as np  # noqa: E402
 def main():
     import paddle_tpu as pt
     from paddle_tpu.observability import train_stats
+    from paddle_tpu.observability.device_peaks import (device_peaks,
+                                                       device_report)
     from paddle_tpu.models.gpt import (GPTConfig, flops_per_step,
                                        gpt_lm_program)
 
@@ -22,7 +24,7 @@ def main():
     batch = int(os.environ.get("BENCH_BATCH", 16))
     steps = int(os.environ.get("BENCH_STEPS", 30))
     tele_steps = int(os.environ.get("BENCH_TELEMETRY_STEPS", 5))
-    peak = float(os.environ.get("PEAK_TFLOPS", 197.0)) * 1e12
+    peak = device_peaks()["bf16_flops"]
     amp = os.environ.get("BENCH_AMP", "1") == "1"
     cfg = GPTConfig(max_pos=max(1024, seq),
                     attn_impl=os.environ.get("BENCH_ATTN", "fused"))
@@ -121,6 +123,7 @@ def main():
         "unit": "MFU (batch=%d seq=%d, %.1f samples/s, %.1f ms/step)"
                 % (batch, seq, batch / dt, dt * 1e3),
         "vs_baseline": round(mfu / 0.45, 4),
+        "device": device_report(),
         "extra": extra,
     }))
 

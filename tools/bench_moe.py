@@ -25,13 +25,14 @@ H, FFN, LAYERS, HEADS, VOCAB = 768, 3072, 12, 12, 8192
 
 def main():
     import paddle_tpu as pt
+    from paddle_tpu.observability.device_peaks import device_peaks
 
     b = int(os.environ.get("BENCH_BATCH", 32))
     s = int(os.environ.get("BENCH_SEQ", 128))
     e = int(os.environ.get("BENCH_EXPERTS", 8))
     cf = float(os.environ.get("BENCH_CF", 1.25))
     steps = int(os.environ.get("BENCH_STEPS", 30))
-    peak = float(os.environ.get("PEAK_TFLOPS", 197.0)) * 1e12
+    peak = device_peaks()["bf16_flops"]
     hd = H // HEADS
     cap = int(math.ceil(s * cf / e))
 

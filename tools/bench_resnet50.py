@@ -4,7 +4,8 @@ Same protocol as tools/bench_resnet_jax.py (the raw-JAX roofline probe):
 N async-chained steps on device, one sync at the end. FLOPs use the
 standard 2*MAC convention (4.089 GMAC/img fwd, x3 for fwd+bwd).
 
-Flags: BATCH, STEPS, FMT (NCHW|NHWC), AMP (1|0), PEAK_TFLOPS.
+Flags: BATCH, STEPS, FMT (NCHW|NHWC), AMP (1|0). The peak comes from
+paddle_tpu/observability/device_peaks.py for the device jax reports.
 """
 
 import json
@@ -18,6 +19,8 @@ import numpy as np
 def main():
     import paddle_tpu as pt
     from paddle_tpu.models import resnet
+    from paddle_tpu.observability.device_peaks import (device_peaks,
+                                                       device_report)
 
     def env(name, default):
         # accept both this tool's flags and bench.py's BENCH_* spellings
@@ -27,7 +30,7 @@ def main():
     steps = int(env("STEPS", 50))
     fmt = env("FMT", "NCHW")
     amp = env("AMP", "1") == "1"
-    peak = float(os.environ.get("PEAK_TFLOPS", 197.0)) * 1e12
+    peak = device_peaks()["bf16_flops"]
 
     main_prog, startup = pt.Program(), pt.Program()
     with pt.program_guard(main_prog, startup):
@@ -75,6 +78,7 @@ def main():
         # the measured raw-JAX ceiling for this model on this chip is
         # ~30% MFU, not 45% — see BASELINE.md's roofline section
         "vs_jax_probe": round(mfu / 0.303, 4),
+        "device": device_report(),
     }))
 
 

@@ -7,11 +7,16 @@ Flags: BATCH, STEPS, DTYPE (bf16|f32), FMT (NCHW|NHWC), BN (f32|bf16).
 
 import functools
 import os
+import sys
 import time
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from paddle_tpu.observability.device_peaks import device_peaks
 
 BATCH = int(os.environ.get("BATCH", 128))
 STEPS = int(os.environ.get("STEPS", 20))
@@ -20,7 +25,6 @@ DTYPE = jnp.bfloat16 if os.environ.get("DTYPE", "bf16") == "bf16" \
 FMT = os.environ.get("FMT", "NHWC")
 BN_DTYPE = jnp.float32 if os.environ.get("BN", "f32") == "f32" \
     else jnp.bfloat16
-PEAK = float(os.environ.get("PEAK_TFLOPS", 197.0)) * 1e12
 
 CFG = (3, 4, 6, 3)
 
@@ -157,6 +161,7 @@ def main():
     jax.block_until_ready(l)
     dt = (time.perf_counter() - t0) / STEPS
     flops = 3 * 2 * 4.089e9 * BATCH  # fwd ~4.089 GMAC/img -> x2 flops, x3 train
+    PEAK = device_peaks()["bf16_flops"]
     print(f"fmt={FMT} dtype={DTYPE.__name__} bn={BN_DTYPE.__name__} "
           f"batch={BATCH}: {dt*1e3:.1f} ms/step, {BATCH/dt:.0f} img/s, "
           f"MFU={flops/dt/PEAK:.3f}, loss={float(l):.3f}")

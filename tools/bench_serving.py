@@ -44,8 +44,9 @@ the performance-attribution columns: `tick_phase_ms` ({phase: mean
 host ms per engine tick} from the serving_tick_phase_seconds
 histograms — where each tick's wall time went between admit /
 prefill_chunk / launch / collect / stream / bookkeeping) and
-`mfu_proxy` (the compile journal's FLOPs-issued-per-second over
-PT_SERVING_PEAK_FLOPS). The `--http`
+`mfu_proxy` (the compile journal's FLOPs-issued-per-second over the
+device's published peak or PT_SERVING_PEAK_FLOPS; null where neither
+exists, as on the CPU). The `--http`
 rows additionally run under a generous default SLO and report
 registry-sourced `slo_attainment` (server_slo_{met,missed}_total) and
 `goodput_tokens_per_s` (server_goodput_tokens_total / wall time).
@@ -312,9 +313,11 @@ def run_model(name, concurrencies=None, requests_per_level=None,
                     # serving_tick_phase_seconds histogram per phase):
                     # mean host ms per tick spent in each engine phase,
                     # and the journal-derived FLOP-utilization proxy
+                    # (the gauge stays at 0 where the device has no
+                    # published peak: null, not a utilization of 0)
                     "tick_phase_ms": _registry_tick_phase_ms(label),
                     "mfu_proxy": _registry_gauge_value(
-                        label, "serving_mfu_proxy"),
+                        label, "serving_mfu_proxy") or None,
                     **quantiles,
                 },
             })
@@ -1652,7 +1655,7 @@ def run_http(name, concurrencies=None, requests_per_level=None,
                     label, "serving_dispatch_host_seconds"),
                 "tick_phase_ms": _registry_tick_phase_ms(label),
                 "mfu_proxy": _registry_gauge_value(
-                    label, "serving_mfu_proxy"),
+                    label, "serving_mfu_proxy") or None,
                 "slo_attainment": _registry_slo_attainment(
                     server.router.metrics.label),
                 "goodput_tokens_per_s": round(

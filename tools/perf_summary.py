@@ -10,7 +10,8 @@ engine's bare CompileJournal snapshot); per engine it renders
   count, compile wall seconds and share, and jax cost_analysis()'s
   per-dispatch GFLOPs / MBytes where known;
 * the derived gauges: mfu_proxy (FLOPs issued per second over the
-  journal's lifetime against PT_SERVING_PEAK_FLOPS) and HBM bytes per
+  journal's lifetime against the device's published peak or
+  PT_SERVING_PEAK_FLOPS; "-" where neither exists) and HBM bytes per
   fused decode dispatch;
 * with ``--ticks`` (the /tickz payload), a per-phase host-overhead
   table over the tick flight ring: count, total/mean milliseconds,
@@ -118,9 +119,10 @@ def _fmt_cost(v, scale, width):
 def _print_journal(label, snap):
     mfu = snap.get("mfu_proxy")
     hbm = snap.get("dispatch_hbm_bytes")
+    peak = snap.get("peak_flops")
     print(f"engine {label}: {snap.get('compiles_total', 0)} compiles, "
           f"{snap.get('compile_seconds_total', 0.0):.3f}s compiling, "
-          f"peak {snap.get('peak_flops', 0):.3g} FLOP/s")
+          f"peak {'-' if peak is None else format(peak, '.3g')} FLOP/s")
     print(f"  mfu_proxy={'-' if mfu is None else format(mfu, '.3g')}  "
           f"hbm_bytes/dispatch="
           f"{'-' if hbm is None else format(int(hbm), 'd')}")
