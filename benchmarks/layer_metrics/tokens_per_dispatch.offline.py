@@ -1,0 +1,8 @@
+"""Tokens emitted per fused decode dispatch over the window."""
+from lib import readers
+
+LAYER, UNIT, MOVES = "scheduler", "tokens", "serve_tok_s"
+
+
+def read(run):
+    return readers.ratio(readers.delta(run, "tokens_out"), readers.delta(run, "dispatches"))
