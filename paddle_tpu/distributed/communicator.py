@@ -25,10 +25,11 @@ _LOG = logging.getLogger(__name__)
 
 def _comm_span(name, argfn):
     """Span for one KV-service RPC. `argfn` builds the byte-count args and
-    only runs while tracing is on — the send/recv loops fire every batch
-    and the disabled path must stay (near-)allocation-free."""
+    only runs while the ring is on — the send/recv loops fire every
+    batch, and with the ring off the span is its profiler annotation
+    alone."""
     if not tracing_enabled():
-        return trace_span(name)        # the shared no-op span
+        return trace_span(name, "comm")
     return trace_span(name, "comm", argfn())
 
 __all__ = ["Communicator"]

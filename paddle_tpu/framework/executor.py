@@ -29,7 +29,7 @@ from .core import Program, Variable, default_main_program
 from .registry import LowerContext, lower_op, get_op_def
 from ..utils.compile_cache import ensure_compile_cache
 from ..observability.metrics import get_registry
-from ..observability.tracer import trace_span, tracing_enabled
+from ..observability.tracer import get_tracer, trace_span
 from ..observability import train_stats as _train_stats
 
 __all__ = ["Scope", "Executor", "global_scope", "scope_guard",
@@ -214,6 +214,7 @@ class Executor:
             cache_capacity if cache_capacity is not None
             else _os.environ.get("FLAGS_executor_cache_capacity", "64"))
         self.compile_count = 0  # distinct compilations (tests/telemetry)
+        self.run_count = 0  # runs started: the ordinal on executor/run
         # run(validate=True) pre-flight reports, keyed like the compile
         # cache (program uid, version, feed set, fetch list); LRU via
         # the shared _memo helper
@@ -267,10 +268,12 @@ class Executor:
                              "executing").labels()
         inflight.inc()
         try:
-            # one observability span per run; a disabled tracer makes
-            # this a shared-singleton no-op — and when a serving request
-            # scope is ambient, the span carries its request_id
-            with trace_span("executor/run", "executor"):
+            # one span per run, the parent of the run's phase spans; it
+            # carries this executor's ordinal of the run and, in the
+            # ring, the request_id of an ambient serving request scope
+            step = self.run_count
+            self.run_count += 1
+            with trace_span("executor/run", "executor", {"step": step}):
                 out = self._run_impl(program, feed, fetch_list, scope,
                                      return_numpy, validate)
             runs.inc()
@@ -350,110 +353,122 @@ class Executor:
 
         blk = program.global_block
 
-        # Host-boundary ops (save/load/send/recv/readers) run eagerly
-        # against the scope: the prefix before the first compute op now,
-        # the suffix after the jitted computation. A host op sandwiched
-        # between compute ops would need the op-by-op interpreter the
-        # whole-block-jit design removed — reference programs (save/load
-        # programs, transpiler-emitted trainer prologues/epilogues) only
-        # use the prefix/suffix forms.
-        from .registry import _HOST_OPS
-        host_pre, host_post = [], []
-        compute_seen = False
-        for op in blk.ops:
-            if op.type in _HOST_OPS:
-                (host_post if compute_seen else host_pre).append(op)
-            elif op.type not in ("feed", "fetch"):
-                compute_seen = True
-                if host_post:
-                    raise RuntimeError(
-                        f"host-boundary op(s) "
-                        f"{[o.type for o in host_post]} appear between "
-                        f"compute ops; split the program (the reference "
-                        f"emits separate save/load programs too)")
-        for op in host_pre:
-            with trace_span(f"host/{op.type}", "host"):
-                _HOST_OPS[op.type](op, scope, feed)
-        if not compute_seen:
-            # host-only program (save/load programs): everything already
-            # ran via host_pre above
-            return [np.asarray(scope.find_var(f)) if return_numpy
-                    else scope.find_var(f) for f in fetch_names]
+        # The phases of a run are spans under executor/run, opened where
+        # the work is and never overlapping: prepare (everything up to
+        # the compile-cache lookup), compile (a miss), place (scope reads
+        # and host->device placement), dispatch (the compiled step's
+        # call, which returns futures), writeback (scope.set_var, host
+        # post ops, finite flags), fetch (the wait for the device).
+        with trace_span("executor/prepare", "executor"):
+            # Host-boundary ops (save/load/send/recv/readers) run eagerly
+            # against the scope: the prefix before the first compute op
+            # now, the suffix after the jitted computation. A host op
+            # sandwiched between compute ops would need the op-by-op
+            # interpreter the whole-block-jit design removed — reference
+            # programs (save/load programs, transpiler-emitted trainer
+            # prologues/epilogues) only use the prefix/suffix forms.
+            from .registry import _HOST_OPS
+            host_pre, host_post = [], []
+            compute_seen = False
+            for op in blk.ops:
+                if op.type in _HOST_OPS:
+                    (host_post if compute_seen else host_pre).append(op)
+                elif op.type not in ("feed", "fetch"):
+                    compute_seen = True
+                    if host_post:
+                        raise RuntimeError(
+                            f"host-boundary op(s) "
+                            f"{[o.type for o in host_post]} appear between "
+                            f"compute ops; split the program (the reference "
+                            f"emits separate save/load programs too)")
+            for op in host_pre:
+                with trace_span(f"host/{op.type}", "host"):
+                    _HOST_OPS[op.type](op, scope, feed)
+            if not compute_seen:
+                # host-only program (save/load programs): everything
+                # already ran via host_pre above
+                return [np.asarray(scope.find_var(f)) if return_numpy
+                        else scope.find_var(f) for f in fetch_names]
 
-        # Training telemetry (observability/train_stats.py): a program
-        # whose minimize() attached the tap carries the loss/grad-norm/
-        # sentinel-flag var names; while a StepLogger is installed those
-        # ride along in the SAME fetch tuple — one jitted computation,
-        # no extra device->host transfer. No logger => fetch list is
-        # exactly the user's (the no-op path; XLA dead-code-eliminates
-        # the unfetched telemetry ops).
-        tele = getattr(program, "_train_telemetry", None)
-        tele_logger = _train_stats.get_step_logger() if tele else None
-        all_fetch = list(fetch_names)
-        if tele_logger is not None:
-            seen = set(all_fetch)
-            for k in ("loss", "grad_norm", "flag", "lr"):
-                n = tele.get(k)
-                if n and n not in seen:
-                    all_fetch.append(n)
-                    seen.add(n)
-        self.last_fetch_names = list(all_fetch)
+            # Training telemetry (observability/train_stats.py): a program
+            # whose minimize() attached the tap carries the loss/grad-norm/
+            # sentinel-flag var names; while a StepLogger is installed
+            # those ride along in the SAME fetch tuple — one jitted
+            # computation, no extra device->host transfer. No logger =>
+            # fetch list is exactly the user's (the no-op path; XLA
+            # dead-code-eliminates the unfetched telemetry ops).
+            tele = getattr(program, "_train_telemetry", None)
+            tele_logger = _train_stats.get_step_logger() if tele else None
+            all_fetch = list(fetch_names)
+            if tele_logger is not None:
+                seen = set(all_fetch)
+                for k in ("loss", "grad_norm", "flag", "lr"):
+                    n = tele.get(k)
+                    if n and n not in seen:
+                        all_fetch.append(n)
+                        seen.add(n)
+            self.last_fetch_names = list(all_fetch)
 
-        # classify_persistables walks every op/var — ~6.5 ms of pure Python
-        # at ResNet-50 scale, re-done identically every step (measured: the
-        # bulk of the r3 "unexplained 4.6% framework overhead"). Same key
-        # ingredients as the compile cache, so memoize alongside it.
-        cls_key = (getattr(program, "_uid", id(program)), program.version,
-                   frozenset(feed), tuple(all_fetch))
-        mutable, created, readonly = self._memo(
-            self._classify_cache, cls_key,
-            lambda: classify_persistables(program, set(feed), all_fetch))
+            # classify_persistables walks every op/var — ~6.5 ms of pure
+            # Python at ResNet-50 scale, re-done identically every step
+            # (measured: the bulk of the r3 "unexplained 4.6% framework
+            # overhead"). Same key ingredients as the compile cache, so
+            # memoize alongside it.
+            cls_key = (getattr(program, "_uid", id(program)),
+                       program.version, frozenset(feed), tuple(all_fetch))
+            mutable, created, readonly = self._memo(
+                self._classify_cache, cls_key,
+                lambda: classify_persistables(program, set(feed),
+                                              all_fetch))
 
-        # ensure rng state
-        if "@RNG@" not in scope:
-            import jax
-            scope.set_var("@RNG@", jax.random.PRNGKey(program.random_seed))
+            # ensure rng state
+            if "@RNG@" not in scope:
+                import jax
+                scope.set_var("@RNG@",
+                              jax.random.PRNGKey(program.random_seed))
 
-        def _sig(v):
-            if hasattr(v, "shape") and hasattr(v, "dtype"):
-                return tuple(v.shape), str(v.dtype)
-            a = np.asarray(v)
-            return tuple(a.shape), str(a.dtype)
+            def _sig(v):
+                if hasattr(v, "shape") and hasattr(v, "dtype"):
+                    return tuple(v.shape), str(v.dtype)
+                a = np.asarray(v)
+                return tuple(a.shape), str(a.dtype)
 
-        feed_sig = tuple(sorted((k,) + _sig(v) for k, v in feed.items()))
-        cache_key = (getattr(program, "_uid", id(program)), program.version,
-                     feed_sig,
-                     tuple(all_fetch), tuple(mutable), tuple(readonly),
-                     id(dist_plan) if dist_plan else None)
+            feed_sig = tuple(sorted((k,) + _sig(v) for k, v in feed.items()))
+            cache_key = (getattr(program, "_uid", id(program)),
+                         program.version, feed_sig,
+                         tuple(all_fetch), tuple(mutable), tuple(readonly),
+                         id(dist_plan) if dist_plan else None)
 
-        # Compile-cache lookup with hit/miss/eviction counters and, on
-        # every miss after a program's first compile, recompilation
-        # attribution: which ingredient changed vs. the nearest cached
-        # key. Counters are always on (StepLogger or not) — families are
-        # re-fetched per run so a registry reset can't orphan them.
-        was_miss = False
-        compiled = self._cache.get(cache_key)
-        if compiled is not None:
-            self._cache.move_to_end(cache_key)
-            reg.counter("executor_cache_hits_total",
-                        "compile-cache hits").inc()
-        else:
-            was_miss = True
-            reg.counter("executor_cache_misses_total",
-                        "compile-cache misses (compilations)").inc()
-            cause, detail = self._attribute_recompile(cache_key)
-            if cause != "first_compile":
-                reg.counter(
-                    "executor_recompiles_total",
-                    "compile-cache misses after a program's first "
-                    "compile, by cause").labels(cause=cause).inc()
-                rec = {"ts": time.time(), "cause": cause, "detail": detail,
-                       "program": str(cache_key[0])[:8],
-                       "compile_index": self.compile_count + 1}
-                self.recompile_log.append(rec)
-                _train_stats.record_recompile(rec)
-            feed_shapes = {k: _sig(v)[0] for k, v in feed.items()}
-            self.compile_count += 1
+            # Compile-cache lookup with hit/miss/eviction counters and, on
+            # every miss after a program's first compile, recompilation
+            # attribution: which ingredient changed vs. the nearest cached
+            # key. Counters are always on (StepLogger or not) — families
+            # are re-fetched per run so a registry reset can't orphan them.
+            compiled = self._cache.get(cache_key)
+            was_miss = compiled is None
+            if not was_miss:
+                self._cache.move_to_end(cache_key)
+                reg.counter("executor_cache_hits_total",
+                            "compile-cache hits").inc()
+            else:
+                reg.counter("executor_cache_misses_total",
+                            "compile-cache misses (compilations)").inc()
+                cause, detail = self._attribute_recompile(cache_key)
+                if cause != "first_compile":
+                    reg.counter(
+                        "executor_recompiles_total",
+                        "compile-cache misses after a program's first "
+                        "compile, by cause").labels(cause=cause).inc()
+                    rec = {"ts": time.time(), "cause": cause,
+                           "detail": detail,
+                           "program": str(cache_key[0])[:8],
+                           "compile_index": self.compile_count + 1}
+                    self.recompile_log.append(rec)
+                    _train_stats.record_recompile(rec)
+                feed_shapes = {k: _sig(v)[0] for k, v in feed.items()}
+                self.compile_count += 1
+
+        if was_miss:
             with trace_span("executor/compile", "executor",
                             {"ops": len(blk.ops),
                              "fetches": len(all_fetch),
@@ -468,36 +483,38 @@ class Executor:
                 self._compile_stats.pop(old_key, None)
                 reg.counter("executor_cache_evictions_total",
                             "compile-cache LRU evictions").inc()
-        reg.gauge("executor_cache_size",
-                  "compiled executables cached").set(len(self._cache))
 
-        mut_in = {}
-        for n in mutable:
-            val = scope.find_var(n)
-            if val is None:
-                raise RuntimeError(
-                    f"persistable var {n!r} not initialized in scope; "
-                    "run the startup program first")
-            mut_in[n] = val
-        ro_in = {n: scope.find_var(n) for n in readonly}
-        for n, v in ro_in.items():
-            if v is None:
-                raise RuntimeError(
-                    f"persistable var {n!r} not initialized in scope; "
-                    "run the startup program first")
-        feed_in = {k: _as_feed_array(v, blk.vars.get(k))
-                   for k, v in feed.items()}
-        if dist_plan is not None:
-            feed_in = dist_plan.shard_feed(feed_in)
-            mut_in = dist_plan.place_scope(mut_in)
-            ro_in = dist_plan.place_scope(ro_in)
+        with trace_span("executor/place", "executor"):
+            reg.gauge("executor_cache_size",
+                      "compiled executables cached").set(len(self._cache))
+            mut_in = {}
+            for n in mutable:
+                val = scope.find_var(n)
+                if val is None:
+                    raise RuntimeError(
+                        f"persistable var {n!r} not initialized in scope; "
+                        "run the startup program first")
+                mut_in[n] = val
+            ro_in = {n: scope.find_var(n) for n in readonly}
+            for n, v in ro_in.items():
+                if v is None:
+                    raise RuntimeError(
+                        f"persistable var {n!r} not initialized in scope; "
+                        "run the startup program first")
+            feed_in = {k: _as_feed_array(v, blk.vars.get(k))
+                       for k, v in feed.items()}
+            if dist_plan is not None:
+                feed_in = dist_plan.shard_feed(feed_in)
+                mut_in = dist_plan.place_scope(mut_in)
+                ro_in = dist_plan.place_scope(ro_in)
 
-        key = scope.find_var("@RNG@")
-        if dist_plan is not None:
-            # on a multi-process mesh the key must be a GLOBAL replicated
-            # array (every process holds the same key: startup ran with
-            # the same seed everywhere); _put is a no-op otherwise
-            key = dist_plan._put(key, dist_plan.scope_sharding("@RNG@"))
+            key = scope.find_var("@RNG@")
+            if dist_plan is not None:
+                # on a multi-process mesh the key must be a GLOBAL
+                # replicated array (every process holds the same key:
+                # startup ran with the same seed everywhere); _put is a
+                # no-op otherwise
+                key = dist_plan._put(key, dist_plan.scope_sharding("@RNG@"))
 
         if getattr(self, "capture_hlo", False):
             # tools/comm_volume.py: optimized HLO with the SPMD partitioner's
@@ -518,24 +535,26 @@ class Executor:
                 compiled, mut_in, ro_in, feed_in, key, reg)
 
         t0 = time.perf_counter()
-        new_mut, fetches, new_key, finite_flags = compiled(
-            mut_in, ro_in, feed_in, key)
+        with trace_span("executor/dispatch", "executor"):
+            new_mut, fetches, new_key, finite_flags = compiled(
+                mut_in, ro_in, feed_in, key)
 
-        for n, v in new_mut.items():
-            scope.set_var(n, v)
-        scope.set_var("@RNG@", new_key)
+        with trace_span("executor/writeback", "executor"):
+            for n, v in new_mut.items():
+                scope.set_var(n, v)
+            scope.set_var("@RNG@", new_key)
 
-        for op in host_post:  # saves/sends see the post-step scope
-            with trace_span(f"host/{op.type}", "host"):
-                _HOST_OPS[op.type](op, scope, feed)
+            for op in host_post:  # saves/sends see the post-step scope
+                with trace_span(f"host/{op.type}", "host"):
+                    _HOST_OPS[op.type](op, scope, feed)
 
-        if finite_flags:
-            for tag, ok in finite_flags.items():
-                if not bool(ok):
-                    idx, op_type, var = tag.split(":", 2)
-                    raise FloatingPointError(
-                        f"nan/inf detected in output {var!r} of op "
-                        f"#{idx} ({op_type}) — FLAGS_check_nan_inf")
+            if finite_flags:
+                for tag, ok in finite_flags.items():
+                    if not bool(ok):
+                        idx, op_type, var = tag.split(":", 2)
+                        raise FloatingPointError(
+                            f"nan/inf detected in output {var!r} of op "
+                            f"#{idx} ({op_type}) — FLAGS_check_nan_inf")
 
         if tele_logger is not None:
             fetches = self._log_step_telemetry(
@@ -544,7 +563,8 @@ class Executor:
 
         if return_numpy:
             from .selected_rows import to_dense
-            return [np.asarray(to_dense(f)) for f in fetches]
+            with trace_span("executor/fetch", "executor"):
+                return [np.asarray(to_dense(f)) for f in fetches]
         return list(fetches)
 
     # -- training telemetry (observability/train_stats.py) -------------------
@@ -742,18 +762,21 @@ class Executor:
             # whole-block-jit design lowers each op exactly once, at trace
             # time, so the spans land on the compiling run — the host-side
             # analog of the reference executor's per-op RecordEvent.
+            # Their number grows with the program's ops, so they stay in
+            # the ring and out of the profiler's trace (Tracer.span).
             # FLAGS_trace_ops=0 suppresses them while keeping run/compile
             # spans; checked at trace time, so enable tracing BEFORE the
             # first run of a program (cached executables re-trace nothing).
-            trace_ops = (tracing_enabled()
+            tracer = get_tracer()
+            trace_ops = (tracer.enabled
                          and os.environ.get("FLAGS_trace_ops", "1") != "0")
             finite_flags = {}
             for i, op in enumerate(ops):
                 if trace_ops:
-                    with trace_span(op.type, "op",
-                                    {"op_index": i,
-                                     "inputs": ",".join(op.input_names()),
-                                     "outputs": ",".join(op.output_names())}):
+                    with tracer.span(op.type, "op",
+                                     {"op_index": i,
+                                      "inputs": ",".join(op.input_names()),
+                                      "outputs": ",".join(op.output_names())}):
                         lower_op(ctx, op, env)
                 else:
                     lower_op(ctx, op, env)

@@ -4,10 +4,21 @@ per-thread event lists), rebuilt as a first-class subsystem.
 
 Design constraints, in order:
 
-* **Disabled is a near-no-op.** `trace_span()` on a disabled tracer
-  returns a shared singleton context manager — no allocation, no clock
-  read, no lock. The serving decode loop and the executor wrap every
-  dispatch in a span, so the disabled path IS the production path.
+* **One source of host spans.** `trace_span()` brackets its body with
+  a `jax.profiler.TraceAnnotation`, so under ANY profiler session
+  (`jax.profiler.start_trace`, `pt.profiler.profiler()`, a TensorBoard
+  capture) the span is an event on a `/host:CPU` line of the xplane, on
+  the clock of the device's `XLA Ops`; when the ring is enabled it is
+  also recorded there. With no session and the ring off it records
+  nothing anywhere and costs about a microsecond (PERF.md has the
+  measured figure), so it is for spans whose number grows with steps,
+  ticks and dispatches. Spans whose number grows with tokens or requests
+  stay ring-only: `Tracer.span` / `record_complete` behind an
+  `if tracer.enabled` guard. jax is imported on the first span, not
+  when this module is.
+* **A span is its own stopwatch.** The object `trace_span()` yields
+  carries `seconds` after its body; a layer that feeds a histogram with
+  a phase's duration reads it there and not from a second clock pair.
 * **Thread-safe by construction.** Spans complete into a ring buffer
   under one small lock (the reference kept per-thread event lists and
   merged at report time; a single deque + lock is simpler and the
@@ -53,8 +64,8 @@ class Span(NamedTuple):
 
 
 class _NullSpan:
-    """Shared do-nothing context manager: the disabled fast path. One
-    instance for the whole process — entering/exiting allocates nothing."""
+    """Shared do-nothing context manager: what the ring-only entry points
+    (`Tracer.span`, `request_scope`) return while the ring is off."""
 
     __slots__ = ()
 
@@ -121,43 +132,71 @@ def _attach_request_id(args: Optional[Dict[str, Any]]
     return merged
 
 
-class _LiveSpan:
-    """Open span: stamps begin on __enter__, records on __exit__."""
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, bound by the first span
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_begin_ns", "_depth")
+
+def _annotation(name: str, args: Optional[Dict[str, Any]]):
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name, **args) if args else _ANNOTATION(name)
+
+
+class _LiveSpan:
+    """Open span: stamps begin on __enter__ and end on __exit__, records
+    into the ring when it is on, and holds the profiler annotation that
+    `trace_span` gave it open for exactly its body."""
+
+    __slots__ = ("_tracer", "name", "cat", "args", "_anno", "_depth",
+                 "begin_ns", "end_ns")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: Optional[Dict[str, Any]]):
+                 args: Optional[Dict[str, Any]], anno=None):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._anno = anno
+
+    @property
+    def seconds(self) -> float:
+        """The body's duration (valid once the span has exited)."""
+        return (self.end_ns - self.begin_ns) * 1e-9
 
     def __enter__(self):
-        stack = self._tracer._stack()
-        self._depth = len(stack)
-        stack.append(self)
-        self._begin_ns = time.monotonic_ns()
+        if self._anno is not None:
+            self._anno.__enter__()
+        if self._tracer._enabled:
+            stack = self._tracer._stack()
+            self._depth = len(stack)
+            stack.append(self)
+        else:
+            self._depth = -1
+        self.begin_ns = time.monotonic_ns()
         return self
 
     def __exit__(self, *exc):
-        end_ns = time.monotonic_ns()
-        tr = self._tracer
-        stack = tr._stack()
-        if stack and stack[-1] is self:
-            stack.pop()
-        else:  # exited out of order (generator teardown): best effort
-            try:
-                stack.remove(self)
-            except ValueError:
-                pass
-        if tr._enabled:  # may have been disabled while the span was open
-            t = threading.current_thread()
-            tr._record(Span(self.name, self.cat,
-                            (self._begin_ns - tr._epoch_ns) / 1e3,
-                            (end_ns - self._begin_ns) / 1e3,
-                            t.ident, t.name, self._depth,
-                            _attach_request_id(self.args)))
+        end_ns = self.end_ns = time.monotonic_ns()
+        if self._depth >= 0:
+            tr = self._tracer
+            stack = tr._stack()
+            if stack and stack[-1] is self:
+                stack.pop()
+            else:  # exited out of order (generator teardown): best effort
+                try:
+                    stack.remove(self)
+                except ValueError:
+                    pass
+            if tr._enabled:  # may have been disabled while the span was open
+                t = threading.current_thread()
+                tr._record(Span(self.name, self.cat,
+                                (self.begin_ns - tr._epoch_ns) / 1e3,
+                                (end_ns - self.begin_ns) / 1e3,
+                                t.ident, t.name, self._depth,
+                                _attach_request_id(self.args)))
+        if self._anno is not None:
+            self._anno.__exit__(*exc)
         return False
 
 
@@ -203,9 +242,9 @@ class Tracer:
 
     def span(self, name: str, cat: str = "",
              args: Optional[Dict[str, Any]] = None):
-        """Context manager recording one complete span. When the tracer is
-        disabled this returns the shared no-op span — callers can wrap hot
-        paths unconditionally."""
+        """Ring-only span (nothing reaches the profiler's trace): for spans
+        whose number grows with tokens or requests, under the caller's own
+        `if tracer.enabled` guard. Disabled, it is the shared no-op."""
         if not self._enabled:
             return _NULL_SPAN
         return _LiveSpan(self, name, cat, args)
@@ -235,29 +274,6 @@ class Tracer:
         self._record(Span(name, cat, (begin_ns - self._epoch_ns) / 1e3,
                           (end_ns - begin_ns) / 1e3, t.ident, t.name, 0,
                           _attach_request_id(args)))
-
-    def record_partition(self, prefix: str, end_ns: int,
-                         parts, cat: str = "",
-                         args: Optional[Dict[str, Any]] = None) -> None:
-        """Record a just-closed window as CONSECUTIVE named sub-spans
-        scaled to measured durations: `parts` is [(name, seconds), ...]
-        in execution order, the window ends at `end_ns` (monotonic_ns)
-        and begins sum(seconds) earlier. The retroactive-partition
-        idiom the engine's tick profiler uses to land its per-phase
-        attribution on the trace timeline (`<prefix>/<name>` spans);
-        zero-duration parts are skipped — an idle phase must not spam
-        the ring."""
-        if not self._enabled:
-            return
-        begin_ns = end_ns - int(sum(s for _, s in parts) * 1e9)
-        cursor = begin_ns
-        for name, seconds in parts:
-            if seconds <= 0:
-                continue
-            nxt = cursor + int(seconds * 1e9)
-            self.record_complete(f"{prefix}/{name}", cursor, nxt,
-                                 cat, args)
-            cursor = nxt
 
     # -- inspection ----------------------------------------------------------
 
@@ -305,8 +321,12 @@ def get_tracer() -> Tracer:
 
 def trace_span(name: str, cat: str = "",
                args: Optional[Dict[str, Any]] = None):
-    """`with trace_span("executor/run"): ...` on the global tracer."""
-    return _GLOBAL.span(name, cat, args)
+    """`with trace_span("executor/run") as sp: ...`: the one call a layer
+    makes for a span of a step, a tick or a dispatch. Always annotates the
+    profiler's trace (a no-op of its own when no session listens), records
+    into the global ring when that is on, and carries `sp.seconds`
+    afterwards."""
+    return _LiveSpan(_GLOBAL, name, cat, args, _annotation(name, args))
 
 
 def enable_tracing(capacity: Optional[int] = None) -> Tracer:

@@ -40,10 +40,10 @@ def _comm_span(kind: str, k, axis_name: str, hops: int):
     time (these wrappers run under jit tracing), so the span measures
     host-side build cost; the byte count is the collective's per-device
     K+V traffic — the number tools/comm_volume.py accounts for on the
-    wire. k: the local K shard (V matches). Disabled tracing skips the
-    byte math entirely."""
+    wire. k: the local K shard (V matches). With the ring off the byte
+    math is skipped and the span is its profiler annotation alone."""
     if not tracing_enabled():
-        return trace_span(kind)               # the shared no-op span
+        return trace_span(f"comm/{kind}", "comm")
     per_hop = 2 * int(np.prod(k.shape)) * k.dtype.itemsize   # K and V
     return trace_span(f"comm/{kind}", "comm",
                       {"axis": axis_name, "bytes": per_hop * max(1, hops),

@@ -1,15 +1,17 @@
 """Profiling (reference: python/paddle/fluid/profiler.py + platform/profiler.h
 RecordEvent / platform/device_tracer.cc CUPTI capture).
 
-Thin adapter over `paddle_tpu.observability`: the legacy API keeps its
-signatures, but the host-event half now records into the observability
-tracer (thread-safe ring buffer, chrome-trace exportable) instead of an
-ad-hoc path, so every existing `RecordEvent` call site — the serving
-scheduler's prefill/decode dispatches, user code — gains real traces for
-free. The device half is unchanged: jax.profiler captures host + device
-(XLA) timelines into an xplane trace viewable in TensorBoard/Perfetto
-(the analog of the reference's host event table + CUPTI DeviceTracer
-merged timeline), and the executor annotates every lowered op with
+Thin adapter over `paddle_tpu.observability`. Host spans have ONE
+source, `observability.trace_span`: every span it opens (the executor's
+`executor/run` and its phases, the engine's `serving/engine_step` and
+its `serving/tick/*` phases, `RecordEvent`, which is an alias of it) is
+a `jax.profiler.TraceAnnotation` around its body, so whoever starts a
+profiler session (this module, `jax.profiler.start_trace`, a TensorBoard
+capture) finds the program's phases on the `/host:CPU` lines of the
+xplane, on the clock of the device's `XLA Ops` (the analog of the
+reference's host event table + CUPTI DeviceTracer merged timeline); the
+same spans land in the tracer's ring (`/tracez`, chrome export) while
+that is enabled. The executor annotates every lowered op with
 jax.named_scope so op-level names survive into XLA metadata.
 
 start_profiler/profiler() drive BOTH: they start a jax xplane trace and
@@ -110,40 +112,15 @@ def cuda_profiler(*a, **kw):  # API parity; device tracing is always on
         yield
 
 
-class RecordEvent:
-    """RAII profiling range (reference: platform/profiler.h:81). Usable as
-    a context manager. Records a span into the observability tracer
-    (thread-safe: concurrent serving requests each land on their own
-    thread track) and, for xplane/device visibility, also opens a
-    jax.profiler.TraceAnnotation. Extra keyword args become span args
-    (e.g. byte counts) visible in the chrome trace."""
-
-    __slots__ = ("name", "args", "_ctx", "_span")
-
-    def __init__(self, name: str, **args):
-        self.name = name
-        self.args = args or None
-        self._ctx = None
-        self._span = None
-
-    def __enter__(self):
-        # annotation OUTSIDE the tracer span: the span's measured window
-        # must not include the annotation's own setup/teardown cost
-        import jax
-
-        self._ctx = jax.profiler.TraceAnnotation(self.name)
-        self._ctx.__enter__()
-        self._span = _obs_tracer.trace_span(self.name, "record_event",
-                                            self.args)
-        self._span.__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        self._span.__exit__(*exc)
-        self._span = None
-        self._ctx.__exit__(*exc)
-        self._ctx = None
-        return False
+def RecordEvent(name: str, **args):
+    """RAII profiling range (reference: platform/profiler.h:81), usable
+    as a context manager: an alias of `observability.trace_span(name,
+    "record_event", args)`. The span is an event in any profiler trace
+    that is being taken (a `/host:CPU` line of the xplane, beside the
+    device's operations) and, while the ring is enabled, in `/tracez`
+    and the chrome export; keyword args (e.g. byte counts) ride on
+    both."""
+    return _obs_tracer.trace_span(name, "record_event", args or None)
 
 
 record_event = RecordEvent

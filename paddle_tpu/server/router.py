@@ -87,6 +87,7 @@ from ..observability import request_log as _request_log
 from ..observability import watchdog as _watchdog
 from ..observability.alerts import FleetHealth, HealthConfig
 from ..observability.metrics import MetricsRegistry, get_registry
+from ..observability.tracer import trace_span
 from ..serving.engine import EngineOverloadError, ServingEngine
 from ..serving.migration import MigrationError
 
@@ -584,8 +585,11 @@ class Replica:
             else:
                 # idle: sleep until a submit kicks us (the timeout only
                 # bounds shutdown latency — deadline checks matter only
-                # while requests are in flight, which keeps the loop hot)
-                self._work.wait(timeout=0.02)
+                # while requests are in flight, which keeps the loop hot).
+                # The span tells a device gap with no request to serve
+                # from one in which the host was slow.
+                with trace_span("serving/idle_wait", "serving"):
+                    self._work.wait(timeout=0.02)
                 self._work.clear()
 
     # -- cross-replica migration (driver-thread halves) ----------------------

@@ -158,13 +158,23 @@ class ServingConfig:
     be set together; geometry is validated here with typed errors — no
     silent fallback (the weight_dtype discipline).
 
-    Observability knobs: dispatch_timing=True attributes every fused
-    decode dispatch's wall time into launch-side host work vs the
-    blocking wait for its result (serving_dispatch_{host,device}_seconds
-    histograms; off by default — disabled adds zero registry series and
-    zero clock reads). tick_profile=True turns on the performance-
-    attribution plane: every engine tick is decomposed into phases
-    (admit / prefill_chunk / launch / collect / stream / bookkeeping)
+    Observability knobs. Every tick's phases are spans of
+    observability.trace_span whatever the knobs say (serving/tick/admit,
+    /prefill_chunk, /launch, /collect, /stream under serving/engine_step,
+    with serving/decode_dispatch inside the launch; inside admit the two
+    waits for the device have their own spans, serving/wait/first_token
+    and, under page pressure, serving/wait/fence): they show in any
+    profiler trace and, while the ring is on, in /tracez. The knobs only
+    add sinks that those spans hand their own durations to.
+    dispatch_timing=True attributes every fused decode dispatch's wall
+    time into launch-side host work (the serving/decode_dispatch span)
+    vs the blocking wait for its result (the serving/tick/collect span,
+    or serving/wait/fence for a dispatch the fence collected):
+    serving_dispatch_{host,device}_seconds histograms; off by default —
+    disabled adds zero registry series. tick_profile=True turns on the
+    performance-attribution plane: every engine tick is decomposed into
+    phases (admit / prefill_chunk / launch / collect / stream, and
+    bookkeeping = the tick's span less those children)
     published as serving_tick_phase_seconds{phase} histograms, a
     bounded per-tick flight ring (/tickz), and the executable
     cost/compile journal (/compilez + serving_compiles_total{family},
@@ -359,44 +369,6 @@ def _default_buckets(max_len: int):
 TICK_RING_SIZE = 256
 
 
-class _TickClock:
-    """Per-tick phase stopwatch (tick_profile engines only). One
-    instance lives for the engine's life; start() re-arms it at the top
-    of each tick and lap(phase) charges the wall time since the last
-    cut to the named phase — MINUS whatever the scheduler's hooked
-    launch/collect segments already claimed inside that window
-    (hook(), wired as scheduler.on_tick_phase, both credits the named
-    phase and accrues the deduction). The invariant this buys:
-    sum(phases.values()) == the tick's wall time, exactly — no double
-    counting, no unattributed residue — which is what the phase-share
-    rollup in /varz and the phase-sum sanity test key on."""
-
-    __slots__ = ("phases", "_t0", "_tick_t0", "_hooked")
-
-    def __init__(self):
-        self.phases = dict.fromkeys(_TICK_PHASES, 0.0)
-        self._t0 = self._tick_t0 = 0.0
-        self._hooked = 0.0
-
-    def start(self) -> None:
-        self._t0 = self._tick_t0 = time.perf_counter()
-        self._hooked = 0.0
-        for phase in _TICK_PHASES:
-            self.phases[phase] = 0.0
-
-    def hook(self, phase: str, seconds: float) -> None:
-        # a scheduler-owned segment (launch/collect) inside the current
-        # lap window: credit its own phase, deduct it from the lap
-        self.phases[phase] += seconds
-        self._hooked += seconds
-
-    def lap(self, phase: str) -> None:
-        now = time.perf_counter()
-        self.phases[phase] += (now - self._t0) - self._hooked
-        self._hooked = 0.0
-        self._t0 = now
-
-
 class ServingEngine:
     """Continuous-batching generate service over a GPT parameter pytree.
 
@@ -530,21 +502,21 @@ class ServingEngine:
             adapters=self.adapters is not None,
             tick_profile=serving.tick_profile)
         if serving.dispatch_timing:
-            self.scheduler.dispatch_timing = True
             # bound through self.metrics at CALL time so a bench's
             # metrics reset keeps feeding the replacement instance
             self.scheduler.on_dispatch_timed = self._on_dispatch_timed
         # performance-attribution plane (tick_profile=True only — the
-        # default constructs NONE of this: no stopwatch, no ring, no
-        # journal, and the registry family set is pinned unchanged)
-        self._tick = None
+        # default constructs NONE of this: no phase table, no ring, no
+        # journal, and the registry family set is pinned unchanged).
+        # The tick's phase spans are opened either way; _phases is
+        # where they put their durations down when this plane is on.
+        self._phases = None
         self._tick_ring = None
         if serving.tick_profile:
-            self._tick = _TickClock()
+            self._phases = dict.fromkeys(_TICK_PHASES, 0.0)
             self._tick_ring = collections.deque(maxlen=TICK_RING_SIZE)
-            # scheduler-owned launch/collect segments feed the same
-            # per-tick stopwatch the engine laps the host phases into
-            self.scheduler.on_tick_phase = self._tick.hook
+            # the scheduler opens the launch and collect spans itself
+            self.scheduler.on_tick_phase = self._phases.__setitem__
             journal = CompileJournal()
             # bound through self.metrics at CALL time (bench reset
             # discipline, same as the other hooks)
@@ -756,21 +728,63 @@ class ServingEngine:
         of tokens emitted; 0 means idle OR a launch-only warm-up tick,
         so drive loops should key on queue/active state, not on the
         return value."""
-        with trace_span("serving/engine_step", "serving"):
-            return self._step_impl()
-
-    def _step_impl(self) -> int:
         step_no = self._step_no
         self._step_no += 1
-        tp = self._tick   # tick profiler (None = pinned off path:
-        #                   zero clock reads in this whole method)
-        if tp is not None:
-            tp.start()
+        with trace_span("serving/engine_step", "serving") as tick:
+            emitted = self._step_impl(step_no)
+        if self._phases is not None:
+            self._finish_tick(step_no, emitted, tick.seconds)
+        return emitted
+
+    def _step_impl(self, step_no: int) -> int:
+        # the tick's phases are child spans of serving/engine_step, each
+        # timed once, by its span; on a tick_profile engine the span's
+        # own duration goes into `phases` (None otherwise), and what the
+        # children leave of the tick is its bookkeeping
+        phases = self._phases
+        if phases is not None:
+            for phase in phases:
+                phases[phase] = 0.0
         if self.faults is not None:
             # counter already advanced: an injected exception fires
             # exactly once, and a supervisor retrying the driver loop
             # proceeds past it
             self.faults.begin_step(step_no)
+        with trace_span("serving/tick/admit", "serving") as sp:
+            emitted = self._admit_tick(step_no)
+        if phases is not None:
+            phases["admit"] = sp.seconds
+        # chunked prefill: dispatch at most one prefill token budget,
+        # interleaved with (and ordered before) this tick's decode
+        # dispatch; completed prefills' first tokens fan out here.
+        # A monolithic engine has nothing mid-prefill and opens no span.
+        if self.scheduler.prefill_pending:
+            with trace_span("serving/tick/prefill_chunk", "serving") as sp:
+                for event in self.scheduler.advance_prefill():
+                    self._emit(event)
+                    emitted += 1
+            if phases is not None:
+                phases["prefill_chunk"] = sp.seconds
+        # scheduler.step() opens serving/tick/launch and
+        # serving/tick/collect around its two segments
+        events = self.scheduler.step()
+        if events:
+            self.metrics.decode_steps += 1
+            self.metrics.observe_dispatch_tokens(len(events))
+            # token fan-out: callbacks + journal writes
+            with trace_span("serving/tick/stream", "serving") as sp:
+                for event in events:
+                    self._emit(event)
+            emitted += len(events)
+            if phases is not None:
+                phases["stream"] = sp.seconds
+        self._sync_gauges()
+        return emitted
+
+    def _admit_tick(self, step_no: int) -> int:
+        """The admit phase of a tick: deferred cancels, swap-ins, queue
+        pops, and admissions with their prefill dispatches. Returns the
+        first tokens emitted."""
         admitted = []
         with self._lock:
             # apply deferred cancels first (scheduler state is only ever
@@ -786,8 +800,6 @@ class ServingEngine:
                     if len(self._swapped) != n:
                         self.metrics.swapped_slots = len(self._swapped)
             self._pending_cancels.clear()
-        if tp is not None:   # deferred cancels are bookkeeping, not
-            tp.lap("bookkeeping")   # admission work
         # resume-first: preempted sequences have strict priority over
         # new admissions for freed pages/slots (they hold finished work
         # and a host-side arena copy; admissions behind them are what
@@ -877,31 +889,11 @@ class ServingEngine:
                     emitted += 1
                 # else: chunked prefill — pages mapped, first token
                 # surfaces from a later advance_prefill tick below
-        if tp is not None:   # swap-ins, queue pops, and admissions
-            tp.lap("admit")  # (their prefill dispatches included)
-        # chunked prefill: dispatch at most one prefill token budget,
-        # interleaved with (and ordered before) this tick's decode
-        # dispatch; completed prefills' first tokens fan out here.
-        # No-op (one attribute read) on a monolithic engine.
-        for event in self.scheduler.advance_prefill():
-            self._emit(event)
-            emitted += 1
-        if tp is not None:
-            tp.lap("prefill_chunk")
-        events = self.scheduler.step()
-        if tp is not None:
-            # the scheduler's hooked launch/collect segments already
-            # claimed their share of this window; the residue
-            # (_needs_dispatch scans, pipeline bookkeeping) is ours
-            tp.lap("bookkeeping")
-        if events:
-            self.metrics.decode_steps += 1
-            self.metrics.observe_dispatch_tokens(len(events))
-        for event in events:
-            self._emit(event)
-            emitted += 1
-        if tp is not None:   # token fan-out: callbacks + journal writes
-            tp.lap("stream")
+        return emitted
+
+    def _sync_gauges(self) -> None:
+        """The tick's tail: registry gauges and counters set from the
+        scheduler's and the allocator's host totals."""
         if self.scheduler.speculate_k:
             # speculation telemetry: the scheduler's cumulative host
             # totals ARE the registry truth (same discipline as the
@@ -932,10 +924,6 @@ class ServingEngine:
         self.metrics.weight_bytes = self.weight_bytes
         if self.adapters is not None:
             self._sync_adapter_metrics()
-        if tp is not None:
-            tp.lap("bookkeeping")   # gauge/counter sync tail
-            self._finish_tick(step_no, emitted)
-        return emitted
 
     def _admission_feasible(self, req, step_no: int) -> bool:
         """Can `req` take a slot + pages RIGHT NOW? Applies, in order:
@@ -1276,17 +1264,17 @@ class ServingEngine:
         snap["records"] = list(journal.records)
         return snap
 
-    def _finish_tick(self, step_no: int, emitted: int) -> None:
-        """Publish one completed tick: per-phase histogram samples, a
+    def _finish_tick(self, step_no: int, emitted: int,
+                     wall: float) -> None:
+        """Publish one completed tick of `wall` seconds (the duration of
+        its serving/engine_step span): per-phase histogram samples, a
         flight-ring record (t_mono-stamped so serving_summary --phases
         can join it against the request log), and the journal-derived
         mfu/bytes gauges."""
-        phases = self._tick.phases
-        wall = 0.0
+        phases = self._phases
+        phases["bookkeeping"] = wall - sum(phases.values())
         for phase in _TICK_PHASES:
-            seconds = phases[phase]
-            wall += seconds
-            self.metrics.observe_tick_phase(phase, seconds)
+            self.metrics.observe_tick_phase(phase, phases[phase])
         self._tick_ring.append({
             "step": step_no, "t_mono": time.monotonic(),
             "wall_s": wall, "phases": dict(phases),
@@ -1296,14 +1284,6 @@ class ServingEngine:
         if journal is not None:
             self.metrics.set_perf_gauges(journal.mfu_proxy(),
                                          journal.dispatch_hbm_bytes())
-        if _TRACER.enabled:
-            # retroactive phase sub-spans on the trace timeline, scaled
-            # to the measured durations (the decode_iter interpolation
-            # idiom): the tick just ended, so the window closes now
-            _TRACER.record_partition(
-                "serving/tick", time.monotonic_ns(),
-                [(phase, phases[phase]) for phase in _TICK_PHASES],
-                "serving", {"step": step_no, "emitted": emitted})
 
     def run_until_drained(self, max_steps: Optional[int] = None) -> int:
         """Step until queue, slots, and swap pool are empty; returns
@@ -1434,7 +1414,7 @@ class ServingEngine:
         replacement never kills diagnostics under a live engine.
         stats()/metrics keep working locally afterwards."""
         self.metrics.unregister()
-        if self._tick is not None:
+        if self._tick_ring is not None:
             # drop the /tickz + /compilez provider closures — the
             # perf-source registry must never outlive the engine it
             # reads from

@@ -25,10 +25,11 @@ decoder's `serving_spec_{proposed,accepted}_total`), gauges
 scrape or `get_registry().snapshot()` sees the serving plane without
 holding the engine, and the bench's p50/p99 rows come registry-sourced.
 `snapshot()` still returns the same plain dict as before (scrapers and
-tests keep consuming it directly), now with p50/p99 columns. Device-side
-visibility comes from the profiler.RecordEvent scopes the scheduler
-wraps around every prefill/decode dispatch (they land in the
-observability tracer AND the jax trace next to the XLA ops).
+tests keep consuming it directly), now with p50/p99 columns. The two
+optional planes time nothing themselves: the engine's tick phases and
+the scheduler's dispatches are spans of `observability.trace_span`
+(in any profiler trace next to the XLA ops, and in the ring while it
+is on), and each span hands its own duration to the sinks here.
 
 Degenerate cases return None, never raise and never emit inf: TPOT and
 output-rate cuts are undefined for single-token generations and for

@@ -169,7 +169,7 @@ import numpy as np
 
 from .. import profiler
 from ..observability import request_log as _request_log
-from ..observability.tracer import get_tracer
+from ..observability.tracer import get_tracer, trace_span
 from ..utils.compile_cache import ensure_compile_cache
 from .kv_cache import ShapeBuckets, SlotKVCache
 
@@ -427,8 +427,8 @@ class _Inflight(NamedTuple):
     begin_ns: int       # launch stamp; 0 = tracing was off at launch
     counts: Any = None  # spec mode: device (chunk, S) int32 commit
     #                     counts; block is (chunk, k+1, S) then
-    host_s: float = 0.0  # launch-side host seconds (dispatch_timing on;
-    #                      0.0 when the split is disabled)
+    host_s: float = 0.0  # launch-side host seconds: the duration of this
+    #                      dispatch's serving/decode_dispatch span
 
 
 class ContinuousBatchingScheduler:
@@ -540,14 +540,14 @@ class ContinuousBatchingScheduler:
         # blocked in the NEXT collect still shows this launch (a metric
         # bumped after step() returns would never record it)
         self.on_launch = None
-        # host/device dispatch split (off by default — the disabled
-        # path must stay clock-read-free): when on, _launch times the
-        # launch-side host segment (trace + enqueue of the chunk jit)
-        # and _collect times the block on this dispatch's result — the
-        # device-attributed segment — then fires on_dispatch_timed
-        # (host_s, device_s) per dispatch. The engine wires this to the
+        # host/device dispatch split: fired (host_s, device_s) once per
+        # collected dispatch, host_s the duration of its
+        # serving/decode_dispatch span (trace + enqueue of the chunk
+        # jit) and device_s that of the span around its fetch (the
+        # block on this dispatch's result: serving/tick/collect, or
+        # serving/wait/fence on the fence path). None unless the engine was
+        # built with dispatch_timing=True, which wires this to the
         # serving_dispatch_{host,device}_seconds histograms.
-        self.dispatch_timing = False
         self.on_dispatch_timed = None
         # deterministic fault injection (serving.faults.FaultPlan or
         # None): the engine installs its plan here so scheduled
@@ -566,9 +566,10 @@ class ContinuousBatchingScheduler:
         # point: AOT lowering re-runs the impl body, and its
         # _note_compile side effect must not inflate compile_events
         self._probing = False
-        # fired ("launch"|"collect", host seconds) around the two
-        # step() segments when the engine's tick profiler is on — the
-        # engine folds them into its per-tick phase decomposition
+        # fired ("launch"|"collect", seconds) with the duration of the
+        # serving/tick/launch and serving/tick/collect spans when the
+        # engine's tick profile is on — the engine's per-tick phase
+        # table; None otherwise
         self.on_tick_phase = None
 
     # -- jitted entry points ------------------------------------------------
@@ -1078,7 +1079,10 @@ class ContinuousBatchingScheduler:
             np.int32(max_new),
             np.int32(-1 if eos_id is None else eos_id),
             np.int32(prev_tok), *aid_row)
-        first = int(first)
+        # the one wait for the device in an admission: with a dispatch in
+        # flight the prefill and this sample are queued behind it
+        with trace_span("serving/wait/first_token", "serving"):
+            first = int(first)
         st = _Running(req, pos=p_len, max_new=max_new, eos_id=eos_id,
                       live_from=self._launches, seq=seq,
                       adapter_id=adapter_id)
@@ -1089,6 +1093,11 @@ class ContinuousBatchingScheduler:
         else:
             self._running[slot] = st
         return SequenceEvent(req, first, finished)
+
+    @property
+    def prefill_pending(self) -> bool:
+        """Is any admitted sequence still mid chunked prefill?"""
+        return bool(self._prefilling)
 
     def advance_prefill(self) -> List[SequenceEvent]:
         """One CHUNKED-PREFILL tick: dispatch budget-bounded prefill
@@ -1130,13 +1139,13 @@ class ContinuousBatchingScheduler:
         padded[0, :n] = pf.suffix[pf.cursor:pf.cursor + n]
         padded[0, n:] = 0
         start = pf.start + pf.cursor
-        t0 = time.perf_counter()
         with profiler.RecordEvent("serving/prefill_chunk", bucket=bucket,
                                   prompt_len=pf.p_len, slot=slot,
                                   start_pos=start, chunk_len=n,
                                   chunk_index=pf.chunk_index,
                                   request_id=getattr(pf.req,
-                                                     "request_id", None)):
+                                                     "request_id", None)
+                                  ) as dispatch:
             logits, arena, self._pt, self._state = \
                 self._jit_call(
                     f"prefill_chunk:L{bucket}", self._prefill_chunk_jit,
@@ -1149,7 +1158,7 @@ class ContinuousBatchingScheduler:
         # only from here on may a concurrent admission hash-hit them
         self.kv.register_prefix(slot, pf.start + pf.cursor)
         if self.on_prefill_chunk is not None:
-            self.on_prefill_chunk(time.perf_counter() - t0)
+            self.on_prefill_chunk(dispatch.seconds)
         rlog = _request_log.get_request_log()
         if rlog is not None:
             rlog.event("prefill",
@@ -1183,25 +1192,20 @@ class ContinuousBatchingScheduler:
             return []
         self._ensure_jits()
         launched = False
-        hook = self.on_tick_phase   # tick profiler (None = pinned off
-        #                             path: zero clock reads)
         if self._running and self._needs_dispatch():
-            if hook is None:
+            with trace_span("serving/tick/launch", "serving") as sp:
                 self._launch()
-            else:
-                t0 = time.perf_counter()
-                self._launch()
-                hook("launch", time.perf_counter() - t0)
+            if self.on_tick_phase is not None:
+                self.on_tick_phase("launch", sp.seconds)
             launched = True
         if self._inflight and (len(self._inflight) > 1 or not launched
                                or not self.overlap):
             fl = self._inflight.pop(0)
-            if hook is None:
-                return self._collect(fl)
-            t0 = time.perf_counter()
-            events = self._collect(fl)
-            hook("collect", time.perf_counter() - t0)
-            return events
+            with trace_span("serving/tick/collect", "serving") as sp:
+                fetched = self._fetch(fl)
+            if self.on_tick_phase is not None:
+                self.on_tick_phase("collect", sp.seconds)
+            return self._collect(fl, fetched, sp.seconds)
         return []
 
     def _needs_dispatch(self) -> bool:
@@ -1225,17 +1229,14 @@ class ContinuousBatchingScheduler:
     def _launch(self) -> None:
         if self.faults is not None:
             self.faults.before_dispatch(self._launches)
-        begin_ns = time.monotonic_ns() if _TRACER.enabled else 0
-        # host segment: everything between here and the enqueue
-        # returning — trace/lower on the first call, argument
+        # host segment: trace/lower on the first call, argument
         # flattening + dispatch enqueue after (the async dispatch
         # returns futures, so none of the device execution is in it)
-        host_t0 = time.perf_counter() if self.dispatch_timing else 0.0
         with profiler.RecordEvent("serving/decode_dispatch",
                                   active=len(self._running),
                                   slots=self.kv.num_slots,
                                   chunk=self.decode_chunk,
-                                  index=self._launches):
+                                  index=self._launches) as dispatch:
             apool = () if self.adapters is None \
                 else (self.adapters.pool,)
             block, arena, self._keys, self._state = self._jit_call(
@@ -1243,37 +1244,44 @@ class ContinuousBatchingScheduler:
                 self.params, self.kv.arena, self._pt, self._keys,
                 self._state, *apool)
             self.kv.store_arena(arena)
-        host_s = (time.perf_counter() - host_t0) if self.dispatch_timing \
-            else 0.0
         counts = None
         if self.speculate_k:
             block, counts = block
+        # the ring's per-token decode_iter spans interpolate between this
+        # dispatch's launch and its collect (0 = the ring was off)
+        begin_ns = dispatch.begin_ns if _TRACER.enabled else 0
         self._inflight.append(_Inflight(block, self._launches,
                                         self.decode_chunk, begin_ns,
-                                        counts, host_s))
+                                        counts, dispatch.seconds))
         self._launches += 1
         if self.on_launch is not None:
             self.on_launch()
 
-    def _collect(self, fl: _Inflight) -> List[SequenceEvent]:
+    @staticmethod
+    def _fetch(fl: _Inflight):
+        """Block on one dispatch's result: (block, counts or None) on the
+        host. The device segment of a dispatch: with overlap on, host
+        post-processing of the previous block already ran under this
+        dispatch's device time, so the wait here is the un-hidden device
+        execution remainder. The caller opens the span around it —
+        serving/tick/collect in step(), serving/wait/fence on the fence
+        path, which runs inside the tick's admit phase — and hands its
+        duration to _collect."""
         import jax
 
-        # device segment: the block on THIS dispatch's result. With
-        # overlap on, host post-processing of the previous block already
-        # ran under this dispatch's device time, so the wait here is the
-        # un-hidden device execution remainder — host_s + device_s is
-        # the dispatch's wall attribution, and host_s is the per-
-        # dispatch overhead the native-core work is judged against.
-        dev_t0 = time.perf_counter() if self.dispatch_timing else 0.0
         if fl.counts is None:
-            block = np.asarray(jax.device_get(fl.block))
-            counts = None
-        else:
-            block, counts = jax.device_get((fl.block, fl.counts))
-            block, counts = np.asarray(block), np.asarray(counts)
-        if self.dispatch_timing and self.on_dispatch_timed is not None:
-            self.on_dispatch_timed(fl.host_s,
-                                   time.perf_counter() - dev_t0)
+            return np.asarray(jax.device_get(fl.block)), None
+        block, counts = jax.device_get((fl.block, fl.counts))
+        return np.asarray(block), np.asarray(counts)
+
+    def _collect(self, fl: _Inflight, fetched,
+                 device_s: float) -> List[SequenceEvent]:
+        """Walk one fetched block into events. host_s + device_s is the
+        dispatch's wall attribution, and host_s is the per-dispatch
+        overhead the native-core work is judged against."""
+        block, counts = fetched
+        if self.on_dispatch_timed is not None:
+            self.on_dispatch_timed(fl.host_s, device_s)
         end_ns = time.monotonic_ns() if fl.begin_ns else 0
         rlog = _request_log.get_request_log()
         # per-(request, dispatch) token attribution for the event log:
@@ -1420,7 +1428,11 @@ class ContinuousBatchingScheduler:
         normal step() collection does."""
         batches: List[List[SequenceEvent]] = []
         while self._inflight:
-            batches.append(self._collect(self._inflight.pop(0)))
+            fl = self._inflight.pop(0)
+            # not a tick's collect phase: the fence runs inside admit
+            with trace_span("serving/wait/fence", "serving") as sp:
+                fetched = self._fetch(fl)
+            batches.append(self._collect(fl, fetched, sp.seconds))
         return batches
 
     def pick_victim(self, policy="newest") -> Optional[int]:

@@ -603,13 +603,14 @@ def test_create_engine_debug_port_plumb_through(tiny_engine_params,
 # ---------------------------------------------------------------------------
 
 def test_disabled_hot_path_is_noop_singleton(tiny_engine_params):
-    """Tracer off, no debug server: a full serving run records nothing,
-    stamps no clocks, and every span/scope call returns THE shared
-    no-op singleton — the hot path allocates nothing new."""
+    """Tracer off, no debug server: a full serving run records nothing
+    and stamps no queue-wait anchor, and the ring-only entry points
+    (Tracer.span, request_scope: the per-token and per-request spans)
+    return THE shared no-op singleton — nothing allocated per token."""
     from paddle_tpu.observability.tracer import _NULL_SPAN
     assert obs.get_debug_server() is None and obs.get_watchdog() is None
     tracer = obs.get_tracer()
-    assert obs.trace_span("x") is _NULL_SPAN
+    assert tracer.span("x") is _NULL_SPAN
     assert obs.request_scope("rid") is _NULL_SPAN
 
     eng = _make_engine(tiny_engine_params, slots=2)

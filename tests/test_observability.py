@@ -5,17 +5,24 @@ Executor.run with tracing enabled produces a chrome-trace JSON with at
 least one complete ("ph": "X") event per executed op, loadable in
 catapult format; (2) serving-engine metrics are visible in a registry
 snapshot after a 10-request continuous-batching run and the Prometheus
-text export parses; (3) the disabled-tracer path records nothing — the
-span count stays zero across full executor runs, and trace_span returns
-one shared singleton (no per-call allocation); (4) the legacy
-profiler.RecordEvent API delegates to the tracer and is thread-safe
-under concurrent recording."""
+text export parses; (3) with no profiler session and the ring off a
+span records nothing anywhere — the span count stays zero across full
+executor runs and engine ticks and the registry's family set does not
+move; (4) the legacy profiler.RecordEvent API is an alias of trace_span
+and is thread-safe under concurrent recording; (5) trace_span is the
+one source of host spans: under a jax.profiler session the executor's
+and the engine's phases are events on a /host:CPU line of the xplane,
+children inside their parent and not overlapping, and the sinks of
+dispatch_timing / tick_profile read those spans' own durations."""
 
+import contextlib
+import glob
 import json
 import os
 import re
 import tempfile
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -38,13 +45,23 @@ def _clean_tracer():
 # tracer core
 # ---------------------------------------------------------------------------
 
-def test_disabled_trace_span_is_shared_singleton():
-    """Disabled fast path: no allocation — every call returns THE no-op
-    span, and nothing is recorded."""
-    assert obs.trace_span("a") is obs.trace_span("b", "cat", {"k": 1})
-    with obs.trace_span("ignored"):
-        pass
-    assert obs.get_tracer().span_count == 0
+def test_span_is_its_own_stopwatch():
+    """The object trace_span yields carries its body's duration, ring
+    on or off; with the ring on, the recorded span IS that reading (one
+    clock pair, not two)."""
+    with obs.trace_span("quiet") as sp:
+        time.sleep(0.002)
+    assert sp.seconds >= 0.002
+    assert obs.get_tracer().span_count == 0      # ring off: not recorded
+    obs.enable_tracing()
+    with obs.trace_span("heard", "cat", {"k": 1}) as sp:
+        time.sleep(0.001)
+    (span,) = obs.get_tracer().snapshot()
+    assert span.name == "heard" and span.args == {"k": 1}
+    assert span.dur_us == pytest.approx(sp.seconds * 1e6, rel=1e-9)
+    # the ring-only entry point stays a shared no-op while the ring is off
+    obs.disable_tracing()
+    assert obs.get_tracer().span("a") is obs.get_tracer().span("b")
 
 
 def test_nested_spans_depths_and_order():
@@ -1631,6 +1648,297 @@ def test_builtin_anomaly_rules_fire_on_their_signals():
                            "compile_storm", "prefix_hit_ratio_drop"}
     assert fh.health()["status"] == "page"     # collapse is page-tier
     fh.close()
+
+
+# ---------------------------------------------------------------------------
+# one source of host spans: the phases of Executor.run and of an engine
+# tick in the profiler's own trace (the CPU gives names and nesting,
+# never a time)
+# ---------------------------------------------------------------------------
+
+_EXEC_PHASES = {"executor/prepare", "executor/place", "executor/dispatch",
+                "executor/writeback", "executor/fetch"}
+_TICK_SPANS = {"serving/tick/admit", "serving/tick/launch",
+               "serving/tick/collect", "serving/tick/stream"}
+_ADMISSION_SPANS = ("serving/prefill", "serving/wait/first_token")
+
+
+@contextlib.contextmanager
+def _profiler_session(trace_dir):
+    """A jax.profiler session of somebody else's: nothing of the
+    program is told about it, as benchmarks/run.py --trace 1 tells
+    nothing."""
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def _host_lines(trace_dir):
+    """{line name: [(event name, start_ns, end_ns, {stat: value})]} of
+    the xplane's /host:CPU plane, program spans only."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            found = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                      dict(ev.stats))
+                     for ev in line.events
+                     if ev.name.startswith(("executor/", "serving/"))]
+            if found:
+                lines.setdefault(line.name, []).extend(found)
+    return lines
+
+
+def _children_of(events, parent):
+    """The events that lie inside `parent`'s interval (itself apart), in
+    order of start."""
+    lo, hi = parent[1:3]
+    return sorted((e for e in events
+                   if e is not parent and lo <= e[1] and e[2] <= hi),
+                  key=lambda e: e[1])
+
+
+def _assert_disjoint(spans):
+    for a, b in zip(spans, spans[1:]):
+        assert a[2] <= b[1], (a, b)
+
+
+def _executor_case():
+    main, startup, loss = _small_program()
+    exe = pt.Executor()
+    scope = pt.Scope()
+    feed = {"x": np.random.rand(4, 16).astype("f")}
+    with pt.scope_guard(scope):
+        exe.run(startup)                                # run 0
+        exe.run(main, feed=feed, fetch_list=[loss])     # run 1 compiles
+
+    def once():                                         # run 2, 3, ...
+        with pt.scope_guard(scope):
+            exe.run(main, feed=feed, fetch_list=[loss])
+    return once, lambda: None
+
+
+def _engine_case(params_cfg, **kw):
+    cfg, params = params_cfg
+    eng = _attr_engine(params, cfg, **kw)
+    eng.generate(_attr_prompts(cfg, 2), max_new_tokens=3)   # compiles
+
+    def once():
+        for p in _attr_prompts(cfg, 3):
+            eng.submit(p, max_new_tokens=4)
+        eng.run_until_drained()
+    return once, eng.close
+
+
+@pytest.mark.parametrize("layer", ["executor", "engine"])
+def test_phase_spans_reach_the_profiler_trace(layer, tiny_engine_params,
+                                              tmp_path):
+    """Under a profiler session that the program knows nothing of, one
+    Executor.run and the ticks of one small batch each leave their phase
+    spans by name on a /host:CPU line of the xplane: children inside the
+    parent's interval, none overlapping another."""
+    once, close = (_executor_case() if layer == "executor"
+                   else _engine_case(tiny_engine_params))
+    try:
+        with _profiler_session(tmp_path):
+            once()
+    finally:
+        close()
+    assert obs.get_tracer().span_count == 0     # the ring stayed off
+    lines = _host_lines(tmp_path)
+    assert len(lines) == 1, list(lines)         # one thread drove it
+    (events,) = lines.values()
+    if layer == "executor":
+        (run,) = [e for e in events if e[0] == "executor/run"]
+        assert int(run[3]["step"]) == 2         # the run's ordinal rides
+        kids = _children_of(events, run)
+        assert [k[0] for k in kids] == [
+            "executor/prepare", "executor/place", "executor/dispatch",
+            "executor/writeback", "executor/fetch"]
+        _assert_disjoint(kids)
+        assert len(events) == 1 + len(_EXEC_PHASES) <= 8
+        return
+    ticks = [e for e in events if e[0] == "serving/engine_step"]
+    assert ticks
+    _assert_disjoint(ticks)
+    seen = set()
+    for tick in ticks:
+        inside = _children_of(events, tick)
+        phases = [k for k in inside if k[0].startswith("serving/tick/")]
+        _assert_disjoint(phases)
+        seen |= {k[0] for k in phases}
+        # a dispatch lies inside its phase: a prefill and the wait for
+        # its first token in the admission, the decode dispatch in the
+        # launch
+        for name, phase in (("serving/prefill", "serving/tick/admit"),
+                            ("serving/wait/first_token",
+                             "serving/tick/admit"),
+                            ("serving/decode_dispatch",
+                             "serving/tick/launch")):
+            for k in (k for k in inside if k[0] == name):
+                assert any(p[0] == phase and p[1] <= k[1] and k[2] <= p[2]
+                           for p in phases), (k, phases)
+        # an admission is its prefill, then the wait for its token
+        per_admission = [k for k in inside if k[0] in _ADMISSION_SPANS]
+        _assert_disjoint(per_admission)
+        assert [k[0] for k in per_admission] == \
+            list(_ADMISSION_SPANS) * (len(per_admission) // 2)
+        # at most 8 spans a tick, and those two an admission
+        assert len(inside) - len(per_admission) + 1 <= 8, inside
+    assert seen == _TICK_SPANS                  # monolithic prefill:
+    #                                             no prefill_chunk phase
+    for name in _ADMISSION_SPANS:
+        assert sum(e[0] == name for e in events) == 3
+    assert "serving/wait/fence" not in {e[0] for e in events}
+    # every event of the line lies inside some tick
+    assert all(any(t[1] <= e[1] and e[2] <= t[2] for t in ticks)
+               for e in events)
+
+
+@pytest.mark.parametrize("layer", ["executor", "engine"])
+def test_nothing_listening_records_nothing(layer, tiny_engine_params):
+    """No profiler session, ring off: the spans of a step and of a tick
+    record nothing anywhere, and the registry's family set is what it
+    was before."""
+    once, close = (_executor_case() if layer == "executor"
+                   else _engine_case(tiny_engine_params))
+    try:
+        once()                                  # families materialize
+        before = set(obs.get_registry().snapshot())
+        once()
+        assert set(obs.get_registry().snapshot()) == before
+        tracer = obs.get_tracer()
+        assert tracer.span_count == 0 and tracer.dropped == 0
+    finally:
+        close()
+
+
+@pytest.mark.parametrize("option", ["dispatch_timing", "tick_profile"])
+def test_option_sinks_read_the_spans(option, tiny_engine_params):
+    """What dispatch_timing=True and tick_profile=True publish is the
+    duration of the phase spans themselves (seen here through the
+    ring): one sample a collected dispatch, host seconds = the
+    serving/decode_dispatch spans, device seconds = the
+    serving/tick/collect spans; the tick ring's phases = the
+    serving/tick/* spans, and bookkeeping what they leave of the
+    serving/engine_step span."""
+    obs.enable_tracing()
+    once, close = _engine_case(tiny_engine_params, **{option: True})
+    try:
+        obs.get_tracer().clear()
+        snap0 = obs.get_registry().snapshot()
+        once()
+        snap = obs.get_registry().snapshot()
+    finally:
+        close()
+    total = {}
+    for sp in obs.get_tracer().snapshot():
+        total[sp.name] = total.get(sp.name, 0.0) + sp.dur_us * 1e-6
+    count = sum(sp.name == "serving/tick/collect"
+                for sp in obs.get_tracer().snapshot())
+
+    def grown(family, **labels):
+        """sum and count a histogram family grew by during once(),
+        over the series that carry `labels`."""
+        def of(s):
+            rows = [r for r in s.get(family, {}).get("series", [])
+                    if all(r["labels"].get(k) == v
+                           for k, v in labels.items())]
+            return (sum(r["sum"] for r in rows),
+                    sum(r["count"] for r in rows))
+        (s1, c1), (s0, c0) = of(snap), of(snap0)
+        return s1 - s0, c1 - c0
+
+    if option == "dispatch_timing":
+        host = grown("serving_dispatch_host_seconds")
+        dev = grown("serving_dispatch_device_seconds")
+        assert host[1] == dev[1] == count > 0
+        assert host[0] == pytest.approx(total["serving/decode_dispatch"],
+                                        rel=1e-6)
+        assert dev[0] == pytest.approx(total["serving/tick/collect"],
+                                       rel=1e-6)
+        return
+    ticks = sum(sp.name == "serving/engine_step"
+                for sp in obs.get_tracer().snapshot())
+    rest = total["serving/engine_step"]
+    for phase in ("admit", "launch", "collect", "stream"):
+        seconds, n = grown("serving_tick_phase_seconds", phase=phase)
+        assert n == ticks
+        assert seconds == pytest.approx(total[f"serving/tick/{phase}"],
+                                        rel=1e-6)
+        rest -= seconds
+    assert grown("serving_tick_phase_seconds",
+                 phase="bookkeeping")[0] == pytest.approx(rest, rel=1e-6)
+    assert "serving/tick/prefill_chunk" not in total
+
+
+def test_fence_inside_admit_is_no_collect_phase(tiny_engine_params):
+    """Under page pressure the admission's fence collects the dispatches
+    in flight INSIDE the admit phase. That wait has a span of its own
+    (serving/wait/fence) and is no serving/tick/collect: the phases of a
+    tick still do not overlap, still sum to its wall time with nothing
+    counted twice (bookkeeping, what they leave, never negative), and
+    dispatch_timing still lands one sample a dispatch."""
+    cfg, params = tiny_engine_params
+    obs.enable_tracing()
+    eng = pt.serving.ServingEngine(
+        params, cfg, pt.serving.ServingConfig(
+            num_slots=4, max_queue=16, block_size=4, kv_blocks=12,
+            decode_chunk=4, preempt=True, prefill_buckets=(4, 8),
+            max_len=32, tick_profile=True, dispatch_timing=True))
+    try:
+        rng = np.random.RandomState(0)
+        prompts = [rng.randint(0, cfg.vocab_size, (n,)).astype(np.int32)
+                   for n in (5, 7, 4, 6)]
+        # two fill the arena and leave a dispatch in flight; the third's
+        # admission then has to fence it before it can swap a victim out
+        for p in prompts[:2]:
+            eng.submit(p, max_new_tokens=12)
+        eng.step()
+        for p in prompts[2:]:
+            eng.submit(p, max_new_tokens=12)
+        eng.run_until_drained()
+        stats = eng.stats()
+        recs = eng._tick_records()
+        label = stats["engine_label"]
+        snap = obs.get_registry().snapshot()
+    finally:
+        eng.close()
+    assert stats["preemptions"] >= 1, "arena not tight enough to preempt"
+    spans = obs.get_tracer().snapshot()
+    by_name = {}
+    for sp in spans:
+        by_name.setdefault(sp.name, []).append(sp)
+    fences = by_name["serving/wait/fence"]
+    admits = by_name["serving/tick/admit"]
+    for sp in fences:       # every fence wait lies inside an admit phase
+        assert any(a.ts_us <= sp.ts_us and
+                   sp.ts_us + sp.dur_us <= a.ts_us + a.dur_us
+                   for a in admits), sp
+    for sp in by_name["serving/tick/collect"]:      # and no collect does
+        assert not any(a.ts_us <= sp.ts_us < a.ts_us + a.dur_us
+                       for a in admits), sp
+    for rec in recs:
+        assert all(v >= 0.0 for v in rec["phases"].values()), rec
+        assert rec["wall_s"] == pytest.approx(
+            sum(rec["phases"].values()), abs=1e-9)
+    assert sum(r["phases"]["collect"] for r in recs) == pytest.approx(
+        sum(sp.dur_us for sp in by_name["serving/tick/collect"]) * 1e-6,
+        rel=1e-6)
+    (device,) = [r for r in
+                 snap["serving_dispatch_device_seconds"]["series"]
+                 if r["labels"].get("engine") == label]
+    assert device["count"] == stats["dispatches"] == \
+        len(by_name["serving/tick/collect"]) + len(fences)
 
 
 if __name__ == "__main__":
