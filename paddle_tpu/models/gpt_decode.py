@@ -40,6 +40,7 @@ __all__ = ["collect_gpt_params", "quantize_params", "gpt_forward_logits",
            "gpt_decode_chunk_slots", "gpt_prefill_pages",
            "gpt_prefill_chunk_pages",
            "gpt_decode_step_pages", "gpt_decode_chunk_pages",
+           "paged_arena_shapes", "decode_attention_path",
            "gpt_decode_verify_slots", "gpt_decode_verify_pages",
            "spec_ngram_seed", "gpt_generate", "QUANTIZED_KV_KERNELS",
            "ADAPTER_KERNELS", "ADAPTER_PROJECTIONS",
@@ -500,10 +501,8 @@ def gpt_decode_verify_pages(params, cfg, toks, arena, pt, ts, done=None,
         q = _dense_a(h, blk["q"], la["q"]).reshape(s_dim, D, heads, hd)
         k = _dense_a(h, blk["k"], la["k"]).reshape(s_dim, D, heads, hd)
         v = _dense_a(h, blk["v"], la["v"]).reshape(s_dim, D, heads, hd)
-        arena = _kv_write(arena, li, 0, wblk, woff, k)
-        arena = _kv_write(arena, li, 1, wblk, woff, v)
-        K = _kv_gather(arena, li, 0, pt, dtype)    # (S, n, L, hd)
-        V = _kv_gather(arena, li, 1, pt, dtype)
+        arena = _kv_write(arena, li, wblk, woff, k, v)
+        K, V = _kv_gather(arena, li, pt, dtype)  # (S, n, L, hd)
         scores = jnp.einsum("bqnd,bnkd->bnqk", q, K,
                             preferred_element_type=jnp.float32)
         scores = jnp.where(pos_mask[:, None, :, :],
@@ -732,18 +731,42 @@ def gpt_decode_chunk_slots(params, cfg, tokens, cache, ts, keys, temps,
     return block, tokens, cache, ts, keys, done, remaining
 
 
-def _gather_pages(plane, pages):
-    """Assemble one sequence's K or V matrix from a block arena plane.
+def paged_arena_shapes(layers, num_blocks, heads, block_size, hd):
+    """(data shape, scale-plane shape) of a paged block arena — the ONE
+    place its physical layout is written down (serving/kv_cache.py
+    allocates from it).
 
-    plane: (num_blocks, heads, block_size, hd) — arena[layer, 0|1].
+    data: (layers, 1, num_blocks, heads, block_size, 2*hd). A row holds
+    the K and the V of one position of one head SIDE BY SIDE, K in lanes
+    [0, hd) and V in [hd, 2*hd): at hd = 64 the minor dimension is the
+    TPU's 128 lanes exactly, so a (block_size, 2*hd) page tile lies in
+    HBM without padding (an hd-wide minor dimension is padded to 128
+    lanes: twice the bytes, and no DMA can slice it) and one page of one
+    layer is ONE contiguous (heads, block_size, 2*hd) piece the decode
+    kernel copies as it lies. Axis 1 is kept at size 1 so that blocks
+    stay axis 2 and heads axis 3: the mesh plan shards axis 3, and the
+    swap payloads and migration tickets index axis 2.
+    scales (quantized arena only): (layers, 1, num_blocks, heads,
+    block_size, 2) — the K scale and the V scale of that row."""
+    data = (layers, 1, num_blocks, heads, block_size, 2 * hd)
+    return data, data[:-1] + (2,)
+
+
+def _gather_pages(leaf, li, pages):
+    """Assemble one sequence's K|V matrix from layer `li` of a block
+    arena leaf (data or scale plane).
+
+    leaf: (layers, 1, num_blocks, heads, block_size, w).
     pages: (..., P) int32 page table (one row per sequence). Returns
-    (..., heads, P*block_size, hd): the blocks in logical order, so row
-    t of the result is the K/V of absolute position t wherever block
-    t // block_size happens to live in the arena. Entries past a
-    sequence's allocated tail point at the scratch block; the causal
-    mask keeps attention from ever reading those rows."""
-    g = plane[pages]                      # (..., P, heads, bs, hd)
-    g = g.swapaxes(-4, -3)                # (..., heads, P, bs, hd)
+    (..., heads, P*block_size, w): the blocks in logical order, so row
+    t of the result is the K|V of absolute position t wherever block
+    t // block_size happens to live in the arena. Whole pages are
+    indexed straight out of the leaf (no `leaf[li, 0]` plane is sliced
+    out first). Entries past a sequence's allocated tail point at the
+    scratch block; the causal mask keeps attention from ever reading
+    those rows."""
+    g = leaf[li, 0, pages]                # (..., P, heads, bs, w)
+    g = g.swapaxes(-4, -3)                # (..., heads, P, bs, w)
     return g.reshape(*g.shape[:-3], g.shape[-3] * g.shape[-2],
                      g.shape[-1])
 
@@ -751,9 +774,10 @@ def _gather_pages(plane, pages):
 # -- quantized block arena ---------------------------------------------------
 #
 # A quantized arena is the pytree (data, scales): data is the usual
-# (layers, 2, num_blocks, heads, block_size, hd) laid down in int8, and
-# scales is the per-block scale PLANE (layers, 2, num_blocks, heads,
-# block_size) — one f32 abs-max scale per written K/V row per head, so
+# (layers, 1, num_blocks, heads, block_size, 2*hd) laid down in int8,
+# and scales is the per-block scale PLANE (layers, 1, num_blocks, heads,
+# block_size, 2) — one f32 abs-max scale per written K and per written V
+# row per head, so
 # every scatter quantizes chip-locally (the heads axis shards over the
 # tp mesh exactly like the data) and every page gather dequantizes
 # in-graph right before the attention matmul. The paged kernels below
@@ -793,33 +817,91 @@ def _quantize_rows(val):
     return q, (a / 127.0).astype(jnp.float32)
 
 
-def _kv_write(arena, li, j, wblk, woff, val):
-    """One ride-along K/V scatter (j = 0 for K, 1 for V): plain write
-    on a full-precision arena, quantize-at-scatter on a quantized one
-    (data row + its scale-plane entry land through the SAME redirected
-    block index, so the scratch/frozen-slot discipline holds for
-    both)."""
-    data, scales = _arena_parts(arena)
+def _kv_rows(scales, k, v):
+    """(data rows, scale rows) of K|V rows as the arena stores them: k
+    and v side by side; on a quantized arena (scales not None) int8
+    rows and their (K scale, V scale) pairs, else no scale rows."""
+    import jax.numpy as jnp
     if scales is None:
-        return data.at[li, j, wblk, :, woff, :].set(val)
-    q, s = _quantize_rows(val)
-    return (data.at[li, j, wblk, :, woff, :].set(q),
-            scales.at[li, j, wblk, :, woff].set(s))
+        return jnp.concatenate([k, v], -1), None
+    qk, sk = _quantize_rows(k)
+    qv, sv = _quantize_rows(v)
+    return jnp.concatenate([qk, qv], -1), jnp.stack([sk, sv], -1)
 
 
-def _kv_gather(arena, li, j, pages, dtype):
-    """Page-gather one K or V matrix, dequantized in-graph for a
-    quantized arena: rows come back as int8 * their scale-plane entry,
+def _kv_write(arena, li, wblk, woff, k, v):
+    """One ride-along K|V scatter (k, v: (..., heads, hd), one row per
+    entry of wblk/woff): plain write on a full-precision arena,
+    quantize-at-scatter on a quantized one (data row + its scale-plane
+    entry land through the SAME redirected block index, so the
+    scratch/frozen-slot discipline holds for both)."""
+    data, scales = _arena_parts(arena)
+    rows, srows = _kv_rows(scales, k, v)
+    data = data.at[li, 0, wblk, :, woff, :].set(rows)
+    if scales is None:
+        return data
+    return data, scales.at[li, 0, wblk, :, woff, :].set(srows)
+
+
+def _write_pages(leaf, li, pages, start, real_len, rows):
+    """Put rows (B, heads, w), the positions start .. start+real_len-1
+    of ONE sequence, into `leaf` (data or scale plane) as WHOLE PAGES:
+    the touched pages are read, the real rows merged in by position, and
+    the pages scattered back, each a (heads, block_size, w) piece that
+    is contiguous in the arena's own layout. (A scatter of single rows
+    makes XLA want the arena with heads next to the lanes, and it then
+    copies the whole arena into that layout and back, at the program's
+    edges or around every layer.) Pages no real row falls in, and pages
+    past the page row, are redirected to scratch block 0, which is what
+    the row scatter did with pad rows; `start` need not be aligned (a
+    later chunk of a chunked prefill keeps the rows before it)."""
+    import jax
+    import jax.numpy as jnp
+    bs, w = leaf.shape[4], leaf.shape[5]
+    B, heads = rows.shape[0], rows.shape[1]
+    P = pages.shape[0]
+    n_t = -(-B // bs) + 1                 # pages B unaligned rows can touch
+    off = start % bs
+    buf = jax.lax.dynamic_update_slice(
+        jnp.zeros((n_t * bs, heads, w), rows.dtype), rows, (off, 0, 0))
+    tiles = buf.reshape(n_t, bs, heads, w).transpose(0, 2, 1, 3)
+    r = jnp.arange(n_t * bs)
+    valid = ((r >= off) & (r < off + real_len)).reshape(n_t, 1, bs, 1)
+    t = jnp.arange(n_t)
+    pidx = start // bs + t
+    ids = jnp.where((pidx < P) & (t * bs < off + real_len),
+                    pages[jnp.minimum(pidx, P - 1)], 0)
+    merged = jnp.where(valid, tiles, leaf[li, 0, ids])
+    return leaf.at[li, 0, ids].set(merged)
+
+
+def _kv_write_pages(arena, li, pages, start, real_len, k, v):
+    """The prefill's K|V write of one sequence's suffix (k, v: (B, heads,
+    hd), row j at position start + j, rows past real_len are padding):
+    whole pages through `_write_pages`, quantize-at-write on a quantized
+    arena (data rows and their scale-plane entries ride the same page
+    ids). Leaves every real row exactly as `_kv_write` would."""
+    data, scales = _arena_parts(arena)
+    rows, srows = _kv_rows(scales, k, v)
+    data = _write_pages(data, li, pages, start, real_len, rows)
+    if scales is None:
+        return data
+    return data, _write_pages(scales, li, pages, start, real_len, srows)
+
+
+def _kv_gather(arena, li, pages, dtype):
+    """Page-gather one layer's (K, V) matrices, dequantized in-graph for
+    a quantized arena: rows come back as int8 * their scale-plane entry,
     fused right before the attention einsum — the only dequant site,
     no fp32 copy of the pool ever exists."""
     data, scales = _arena_parts(arena)
-    k = _gather_pages(data[li, j], pages)
+    g = _gather_pages(data, li, pages)     # (..., heads, L, 2*hd)
+    hd = g.shape[-1] // 2
+    k, v = g[..., :hd], g[..., hd:]
     if scales is None:
-        return k
-    g = scales[li, j][pages]              # (..., P, heads, bs)
-    g = g.swapaxes(-3, -2)                # (..., heads, P, bs)
-    s = g.reshape(*g.shape[:-2], g.shape[-2] * g.shape[-1])
-    return k.astype(dtype) * s[..., None].astype(dtype)
+        return k, v
+    s = _gather_pages(scales, li, pages).astype(dtype)     # (.., L, 2)
+    return k.astype(dtype) * s[..., 0:1], v.astype(dtype) * s[..., 1:2]
 
 
 def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
@@ -837,7 +919,7 @@ def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
     the real (unpadded) suffix length, >= 1 — admission never shares
     the block holding position p_len-1, so the last prompt position is
     always computed here and the first-token logits need no cached
-    activations. arena: (layers, 2, num_blocks, heads, block_size, hd).
+    activations. arena: see paged_arena_shapes.
     pages: (P,) int32 — THIS sequence's page row; suffix K/V rows are
     scattered to block pages[pos // bs] offset pos % bs, and attention
     gathers the whole row back (prefix blocks included) so hit blocks
@@ -912,22 +994,15 @@ def _prefill_pages_body(params, cfg, tokens, pfx_len, real_len, arena,
     pos = pfx_len + j                              # absolute positions
     x = (params["wte"][tokens[0]] + params["wpe"][pos]).astype(dtype)
     mask = jnp.arange(L)[None, :] <= pos[:, None]  # (B, L) causal
-    # pad rows -> scratch block 0 (see docstring); real rows have
-    # pos < p_len <= max_pages*bs so their page index never clamps
-    wblk = jnp.where(j < real_len,
-                     pages[jnp.minimum(pos // bs, pages.shape[0] - 1)],
-                     0)
-    woff = pos % bs
     for li, blk in enumerate(params["blocks"]):
         la = _lora_layer(adapters, adapter_id, li, live)
         h = _ln(x, blk["ln1"])
         q = _dense_a(h, blk["q"], la["q"]).reshape(B, heads, hd)
         k = _dense_a(h, blk["k"], la["k"]).reshape(B, heads, hd)
         v = _dense_a(h, blk["v"], la["v"]).reshape(B, heads, hd)
-        arena = _kv_write(arena, li, 0, wblk, woff, k)
-        arena = _kv_write(arena, li, 1, wblk, woff, v)
-        K = _kv_gather(arena, li, 0, pages, dtype)  # (heads, L, hd)
-        V = _kv_gather(arena, li, 1, pages, dtype)
+        # pad rows reach no page but scratch block 0 (see docstring)
+        arena = _kv_write_pages(arena, li, pages, pfx_len, real_len, k, v)
+        K, V = _kv_gather(arena, li, pages, dtype)  # (heads, L, hd)
         scores = jnp.einsum("bnd,nkd->bnk", q, K,
                             preferred_element_type=jnp.float32)
         scores = jnp.where(mask[:, None, :], scores / np.sqrt(hd), -1e30)
@@ -942,12 +1017,33 @@ def _prefill_pages_body(params, cfg, tokens, pfx_len, real_len, arena,
     return _head_logits(params, last), arena
 
 
+def decode_attention_path(arena, arena_constraint=None):
+    """Which attention the paged decode step runs, read off its input:
+    "paged_kernel" (ops/paged_attention: each slot's live pages straight
+    out of the arena) when the backend is a TPU, the arena is the bare
+    full-precision array with a lane-aligned K|V row and no mesh
+    constrains it; "gather" (`_kv_gather` + einsum) for everything else:
+    the quantized (int8, scales) arena, the tensor-parallel plan, a
+    backend that is not a TPU. The speculative verify pass
+    (gpt_decode_verify_pages, several query rows a slot) always
+    gathers. One algorithm; the form of the input says whether the
+    kernel applies, and no option or environment variable does."""
+    import jax
+    data, scales = _arena_parts(arena)
+    if (scales is None and arena_constraint is None
+            and data.shape[-1] % 128 == 0
+            and jax.default_backend() == "tpu"):
+        return "paged_kernel"
+    return "gather"
+
+
 def gpt_decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
-                          adapters=None, adapter_ids=None):
+                          adapters=None, adapter_ids=None,
+                          attention=None):
     """gpt_decode_step_slots over a PAGED pool: per-slot K/V live in
     arena blocks indirected through a page table instead of contiguous
     slab rows. tokens/ts: (S,) int32, pt: (S, P) int32 page table,
-    arena: (layers, 2, num_blocks, heads, block_size, hd). Returns
+    arena: see paged_arena_shapes. Returns
     (logits (S, V) f32, updated arena).
 
     The slab version's stale-row discipline does not survive paging —
@@ -962,7 +1058,13 @@ def gpt_decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
     LoRA pool + an (S,) int32 per-slot adapter-id vector — every
     projection gathers each slot's A/B rows and adds x @ A_s @ B_s, so
     co-batched slots hit DIFFERENT adapters in this one dispatch
-    (id 0 rows select the base output bit-exactly)."""
+    (id 0 rows select the base output bit-exactly).
+
+    `attention` is decode_attention_path's verdict, "paged_kernel" or
+    "gather" (None: decided here from the arena; the chunk loop passes
+    its own, which also knows of a mesh constraint). The kernel reads
+    only live pages and gives a frozen slot zeros where the gather
+    gives it garbage; either way the host discards those logits."""
     import jax.numpy as jnp
 
     heads = cfg.heads
@@ -971,34 +1073,46 @@ def gpt_decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
     bs = data.shape[4]
     s_dim, P = pt.shape
     L = P * bs
+    if attention is None:
+        attention = decode_attention_path(arena)
+    if attention == "paged_kernel":
+        # imported where it is used: `import paddle_tpu` stays free of
+        # Pallas for programs that never serve
+        from ..ops.paged_attention import paged_attention
+    else:
+        pos_mask = (jnp.arange(L)[None, :] <= ts[:, None])     # [S, L]
+        wblk = pt[jnp.arange(s_dim), ts // bs]
+        if done is not None:
+            wblk = jnp.where(done, 0, wblk)    # frozen -> scratch block
+        woff = ts % bs
     dtype = _arena_compute_dtype(params, data, _scales)
     live = None if adapters is None \
         else (adapter_ids != 0)[:, None, None]
-    rows = jnp.arange(s_dim)
     x = (params["wte"][tokens] + params["wpe"][ts]).astype(dtype)[:, None]
-    pos_mask = (jnp.arange(L)[None, :] <= ts[:, None])     # [S, L]
-    wblk = pt[rows, ts // bs]
-    if done is not None:
-        wblk = jnp.where(done, 0, wblk)        # frozen -> scratch block
-    woff = ts % bs
     for li, blk in enumerate(params["blocks"]):
         la = _lora_layer(adapters, adapter_ids, li, live)
         h = _ln(x, blk["ln1"])
         q = _dense_a(h, blk["q"], la["q"]).reshape(s_dim, heads, 1, hd)
         k = _dense_a(h, blk["k"], la["k"]).reshape(s_dim, heads, hd)
         v = _dense_a(h, blk["v"], la["v"]).reshape(s_dim, heads, hd)
-        arena = _kv_write(arena, li, 0, wblk, woff, k)
-        arena = _kv_write(arena, li, 1, wblk, woff, v)
-        K = _kv_gather(arena, li, 0, pt, dtype)  # (S, heads, L, hd)
-        V = _kv_gather(arena, li, 1, pt, dtype)
-        scores = jnp.einsum("bnqd,bnkd->bnqk", q, K,
-                            preferred_element_type=jnp.float32)
-        scores = jnp.where(pos_mask[:, None, None, :],
-                           scores / np.sqrt(hd), -1e30)
-        probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
-        probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
-        ctx = jnp.einsum("bnqk,bnkd->bnqd", probs, V)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(s_dim, 1, -1)
+        if attention == "paged_kernel":
+            # the kernel writes the row too (where a frozen slot's went
+            # to scratch it now goes nowhere), so no XLA scatter asks
+            # for the arena in another layout
+            ctx, arena = paged_attention(q[:, :, 0], k, v, arena, li, pt,
+                                         ts, done)
+            ctx = ctx.reshape(s_dim, 1, -1)
+        else:
+            arena = _kv_write(arena, li, wblk, woff, k, v)
+            K, V = _kv_gather(arena, li, pt, dtype)  # (S, heads, L, hd)
+            scores = jnp.einsum("bnqd,bnkd->bnqk", q, K,
+                                preferred_element_type=jnp.float32)
+            scores = jnp.where(pos_mask[:, None, None, :],
+                               scores / np.sqrt(hd), -1e30)
+            probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
+            probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
+            ctx = jnp.einsum("bnqk,bnkd->bnqd", probs, V)
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(s_dim, 1, -1)
         x = x + _dense_a(ctx, blk["out"], la["out"])
         h = _ln(x, blk["ln2"])
         x = x + _dense_a(_gelu_tanh(_dense_a(h, blk["mlp1"], la["mlp1"])),
@@ -1083,13 +1197,16 @@ def gpt_decode_chunk_pages(params, cfg, tokens, arena, pt, ts, keys,
         return (block, counts, tokens, arena, ts, keys, done, remaining,
                 (prev, table))
 
+    attention = decode_attention_path(arena, arena_constraint)
+
     def body(carry, _):
         tok, arena, ts, keys, done, rem = carry
         if arena_constraint is not None:
             arena = arena_constraint(arena)
         logits, arena = gpt_decode_step_pages(
             params, cfg, tok, arena, pt, ts, done,
-            adapters=adapters, adapter_ids=adapter_ids)
+            adapters=adapters, adapter_ids=adapter_ids,
+            attention=attention)
         nxt, keys = jax.vmap(sample_fn)(keys, logits, temps)
         emit = jnp.where(done, tok, nxt)
         rem = jnp.where(done, rem, rem - 1)

@@ -1445,6 +1445,8 @@ class ServingEngine:
         if self.adapters is not None:
             s.update(self.adapters.occupancy())
         s["compiled_executables"] = self.scheduler.compile_count
+        # "paged_kernel" or "gather": the decode step's attention path
+        s["decode_attention"] = self.scheduler.decode_attention
         # the registry label this engine's serving_* series carry, so a
         # caller can find them in observability.get_registry().snapshot()
         s["engine_label"] = self.metrics.engine_label

@@ -1,8 +1,9 @@
 """Paged KV-cache manager for the continuous-batching scheduler.
 
-The pool is a fixed-shape BLOCK ARENA `(layers, 2, num_blocks, heads,
-block_size, head_dim)` plus one page table `(num_slots, max_pages)`
-int32: a "slot" is one sequence's page-table row, and its K/V rows live
+The pool is a fixed-shape BLOCK ARENA `(layers, 1, num_blocks, heads,
+block_size, 2*head_dim)` (a row's K and V side by side: models/gpt_decode
+`paged_arena_shapes` owns the layout) plus one page table
+`(num_slots, max_pages)` int32: a "slot" is one sequence's page-table row, and its K/V rows live
 scattered across arena blocks (vLLM-style PagedAttention). Fixed shapes
 are still the whole point — XLA compiles ONE decode executable over the
 arena + page table (batch dim = num_slots, always) and one prefill per
@@ -94,7 +95,7 @@ SCRATCH_BLOCK = 0
 class SlotKVCache:
     """Paged block arena + slot/page allocator + hashed prefix cache.
 
-    kv: (layers, 2, num_blocks, heads, block_size, head_dim) — the block
+    kv: (layers, 1, num_blocks, heads, block_size, 2*head_dim) — the block
     arena (block 0 is scratch, never allocated). A slot is a page-table
     row of up to max_pages block ids; admission maps exactly the pages a
     request's prompt+budget needs (`blocks_for(p_len + max_new)`), so
@@ -164,9 +165,11 @@ class SlotKVCache:
         else:
             self.dtype = jnp.dtype(dtype) if dtype is not None \
                 else jnp.dtype(jnp.float32)
-        shape = (cfg.layers, 2, self.num_blocks, heads, self.block_size,
-                 hd)
-        scale_shape = shape[:-1]          # one scale per K/V row per head
+        # deferred like the scheduler's: models/__init__ pulls every
+        # model module, which must not run during package import
+        from ..models.gpt_decode import paged_arena_shapes
+        shape, scale_shape = paged_arena_shapes(
+            cfg.layers, self.num_blocks, heads, self.block_size, hd)
         # arena_device (a jax sharding/device or None = default): the
         # arena must be ALLOCATED under its mesh sharding, not
         # allocated whole and resharded after — allocate-then-move

@@ -312,13 +312,13 @@ class MigrationTicket:
                 " pool only adopts sequences serialized in its own "
                 "storage dtype")
         shape = self.payload.shape
-        arena = kv.kv.shape  # (L, 2, num_blocks, heads, bs, hd)
+        arena = kv.kv.shape  # (L, 1, num_blocks, heads, bs, 2*hd)
         if len(shape) != 6:
             # a malformed/truncated payload must reject cleanly, never
             # crash an index below or the adopting swap_in scatter
             raise TicketError(
                 f"ticket payload rank {len(shape)} != 6 — not a KV "
-                "block payload (layers, 2, blocks, heads, bs, hd)")
+                "block payload (layers, 1, blocks, heads, bs, 2*hd)")
         if shape[3] != arena[3]:
             # MESH GEOMETRY: tickets always carry the canonical FULL-
             # HEAD host layout (swap_out's device_get assembles the
@@ -346,7 +346,7 @@ class MigrationTicket:
                 f"a scale plane, engine kv_dtype {want} "
                 f"{'requires' if quantized else 'forbids'} one")
         if self.scales is not None:
-            want_s = shape[:5]            # (L, 2, blocks, heads, bs)
+            want_s = shape[:5] + (2,)     # (L, 1, blocks, heads, bs, 2)
             if (self.scales.dtype != np.float32
                     or tuple(self.scales.shape) != want_s):
                 raise TicketError(

@@ -215,3 +215,192 @@ def test_beam_default_reorder_rejects_wrong_layout(trained):
     with pytest.raises(ValueError, match="reorder"):
         pt.layers.decode.beam_search_decode_on_device(
             cached_step, 2, 3, 1, 2, 4, init_state=bad_state)
+
+
+# -- paged decode attention: the kernel against the gather path -------------
+
+BS, PAGES = 16, 4          # block size and page-row width of the cases
+
+
+def _gather_reference(q, arena, layer, pt, ts):
+    """`_gather_pages` + the einsum path of gpt_decode_step_pages, for
+    one layer: the form the kernel must reproduce."""
+    import jax.numpy as jnp
+    hd = q.shape[-1]
+    K, V = gd._kv_gather(arena, layer, pt, arena.dtype)
+    mask = jnp.arange(K.shape[2])[None, :] <= ts[:, None]
+    scores = jnp.einsum("bnd,bnkd->bnk", q, K,
+                        preferred_element_type=jnp.float32)
+    scores = jnp.where(mask[:, None, :], scores / np.sqrt(hd), -1e30)
+    probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
+    probs = (probs / probs.sum(-1, keepdims=True)).astype(q.dtype)
+    return jnp.einsum("bnk,bnkd->bnd", probs, V)
+
+
+def _kernel_case(heads, dtype, ts, pt=None, done=None):
+    return dict(heads=heads, dtype=dtype, ts=ts, pt=pt, done=done)
+
+
+_TS = {"ts0": 0, "page_last_row": BS - 1, "page_first_row": BS,
+       "row_end": PAGES * BS - 1}
+KERNEL_CASES = {
+    f"h{heads}-{dtype}-{name}": _kernel_case(heads, dtype, [t, 21])
+    for heads in (12, 25) for dtype in ("float32", "bfloat16")
+    for name, t in _TS.items()}
+# pages out of order, the tail of each row on scratch block 0
+KERNEL_CASES["out_of_order-scratch_tail"] = _kernel_case(
+    12, "bfloat16", [BS + 3, 2 * BS],
+    pt=[[7, 2, 0, 0], [5, 9, 3, 0]])
+# a frozen slot between two live ones
+KERNEL_CASES["done_slot"] = _kernel_case(
+    12, "bfloat16", [5, 2 * BS + 1, 40],
+    pt=[[1, 0, 0, 0], [4, 2, 6, 0], [8, 3, 5, 0]],
+    done=[False, True, False])
+KERNEL_CASES["chunk8-greedy-tokens"] = None
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES), ids=list(KERNEL_CASES))
+def test_paged_attention_kernel(case, trained, monkeypatch):
+    """ops/paged_attention, interpreted on the CPU, against the gather
+    path: the context of every live slot, the arena after the step's
+    own write (a frozen slot's went to scratch on the gather path and
+    goes nowhere in the kernel: every block but scratch is equal), and
+    zeros for a frozen slot. The last case runs one whole
+    gpt_decode_chunk_pages of 8 steps through either path."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.paged_attention import paged_attention
+
+    if KERNEL_CASES[case] is None:
+        _chunk_tokens_match(trained, monkeypatch)
+        return
+    c = KERNEL_CASES[case]
+    heads, hd, layers, layer = c["heads"], 64, 2, 1
+    dtype = jnp.dtype(c["dtype"])
+    ts = jnp.asarray(c["ts"], jnp.int32)
+    s_dim = ts.shape[0]
+    num_blocks = s_dim * PAGES + 1
+    rng = np.random.RandomState(len(case))
+    if c["pt"] is None:         # in order, every page allocated
+        pt_ = jnp.arange(1, num_blocks, dtype=jnp.int32).reshape(
+            s_dim, PAGES)
+    else:
+        pt_ = jnp.asarray(c["pt"], jnp.int32)
+    done = None if c["done"] is None else jnp.asarray(c["done"])
+    shape, _ = gd.paged_arena_shapes(layers, num_blocks, heads, BS, hd)
+    arena = jnp.asarray(rng.standard_normal(shape), dtype)
+    q, k, v = (jnp.asarray(rng.standard_normal((s_dim, heads, hd)), dtype)
+               for _ in range(3))
+
+    got, arena_k = paged_attention(q, k, v, arena, layer, pt_, ts, done)
+
+    wblk = pt_[jnp.arange(s_dim), ts // BS]
+    if done is not None:
+        wblk = jnp.where(done, 0, wblk)
+    arena_g = gd._kv_write(arena, layer, wblk, ts % BS, k, v)
+    want = _gather_reference(q, arena_g, layer, pt_, ts)
+    live = np.ones(s_dim, bool) if done is None else ~np.asarray(done)
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[live], np.asarray(want, np.float32)[live],
+        rtol=tol, atol=tol)
+    assert not np.asarray(got, np.float32)[~live].any()
+    # every block but scratch, in every layer, byte for byte
+    np.testing.assert_array_equal(
+        np.asarray(arena_k[:, :, 1:].astype(jnp.float32)),
+        np.asarray(arena_g[:, :, 1:].astype(jnp.float32)))
+    if done is not None:
+        # and what the frozen slot's stale page row points at is as it was
+        np.testing.assert_array_equal(
+            np.asarray(arena_k[:, :, 1:].astype(jnp.float32))[:, :, [3, 1, 5]],
+            np.asarray(arena[:, :, 1:].astype(jnp.float32))[:, :, [3, 1, 5]])
+
+
+def _chunk_tokens_match(trained, monkeypatch):
+    """One gpt_decode_chunk_pages of 8 steps: greedy tokens, positions
+    and the frozen mask of the kernel path are the gather path's."""
+    import jax.numpy as jnp
+    cfg, params, _ = trained
+    heads, hd = cfg.heads, cfg.hidden // cfg.heads
+    s_dim, bs, pages = 3, 4, 6
+    shape, _ = gd.paged_arena_shapes(cfg.layers, s_dim * pages + 1, heads,
+                                     bs, hd)
+    arena = jnp.zeros(shape, jnp.float32)
+    pt_ = jnp.asarray(np.random.RandomState(3).permutation(
+        s_dim * pages).reshape(s_dim, pages) + 1, jnp.int32)
+    prompts = [np.arange(5) % 97, (np.arange(9) * 7 + 1) % 97]
+    first = []
+    for slot, prompt in enumerate(prompts):
+        logits, arena = gd.gpt_prefill_pages(
+            params, cfg, jnp.asarray(prompt[None], jnp.int32), 0,
+            len(prompt), arena, pt_[slot])
+        first.append(int(np.argmax(np.asarray(logits[0]))))
+    args = dict(
+        tokens=jnp.asarray(first + [0], jnp.int32), arena=arena, pt=pt_,
+        ts=jnp.asarray([5, 9, 2], jnp.int32),
+        keys=jnp.zeros((s_dim, 2), jnp.uint32),
+        temps=jnp.zeros((s_dim,), jnp.float32),
+        done=jnp.asarray([False, False, True]),      # slot 2 rides frozen
+        remaining=jnp.asarray([8, 5, 0], jnp.int32),  # slot 1 ends inside
+        eos_ids=jnp.full((s_dim,), -1, jnp.int32), chunk=8)
+
+    def run():
+        return gd.gpt_decode_chunk_pages(params, cfg, **args)
+
+    want = run()
+    monkeypatch.setattr(gd, "decode_attention_path",
+                        lambda *a, **k: "paged_kernel")
+    got = run()
+    for i in (0, 1, 3, 5, 6):        # block, tokens, ts, done, remaining
+        np.testing.assert_array_equal(np.asarray(got[i]),
+                                      np.asarray(want[i]))
+    np.testing.assert_allclose(np.asarray(got[2])[:, :, 1:],
+                               np.asarray(want[2])[:, :, 1:],
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_decode_attention_path_is_read_off_the_input(trained, monkeypatch):
+    """No option picks the path: the backend, the arena's form and the
+    mesh constraint do. The quantized pair, a constrained arena, the
+    speculative verify pass and a row that is not whole lanes gather;
+    engine.stats() says which."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    shape, scale_shape = gd.paged_arena_shapes(2, 5, 2, 4, 64)
+    bare = jnp.zeros(shape, jnp.bfloat16)
+    quantized = (jnp.zeros(shape, jnp.int8),
+                 jnp.zeros(scale_shape, jnp.float32))
+    narrow = jnp.zeros(gd.paged_arena_shapes(2, 5, 4, 4, 8)[0])
+    assert gd.decode_attention_path(bare) == "gather"         # the CPU
+    cfg = GPTConfig(vocab_size=97, hidden=128, layers=2, heads=2,
+                    max_pos=64, dropout=0.0, attn_impl="xla")
+    params = _params_like(cfg)
+    sizes = dict(num_slots=2, max_len=32, block_size=4,
+                 prefill_buckets=(8,))
+
+    def path(**kw):
+        engine = ServingEngine(params, cfg, ServingConfig(**sizes, **kw))
+        try:
+            return engine.stats()["decode_attention"]
+        finally:
+            engine.close()
+
+    assert path() == "gather"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert gd.decode_attention_path(bare) == "paged_kernel"
+    assert gd.decode_attention_path(quantized) == "gather"
+    assert gd.decode_attention_path(bare, lambda a: a) == "gather"
+    assert gd.decode_attention_path(narrow) == "gather"
+    assert path() == "paged_kernel"
+    assert path(kv_dtype="int8") == "gather"
+    assert path(speculate_k=2) == "gather"
+
+
+def _params_like(cfg):
+    """Parameter pytree of `cfg`, from a fresh startup program."""
+    main, startup, _ = gpt_lm_program(cfg, 8, is_test=True)
+    exe, scope = pt.Executor(), pt.Scope()
+    with pt.scope_guard(scope):
+        exe.run(startup)
+        return gd.collect_gpt_params(scope, cfg)

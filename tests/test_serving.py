@@ -1238,8 +1238,9 @@ def test_slot_kv_cache_alloc_free(trained):
     # paged arena: num_blocks defaults to slab-equivalent capacity
     # (num_slots * pages-per-max_len) + the reserved scratch block 0
     assert kv.max_pages == 4 and kv.num_blocks == 2 * 4 + 1
-    assert kv.kv.shape == (cfg.layers, 2, 9, cfg.heads, 4,
-                           cfg.hidden // cfg.heads)
+    # a row's K and V side by side (gpt_decode.paged_arena_shapes)
+    assert kv.kv.shape == (cfg.layers, 1, 9, cfg.heads, 4,
+                           2 * (cfg.hidden // cfg.heads))
     assert kv.blocks_total == 8 and kv.blocks_used == 0
     a, b = kv.alloc(), kv.alloc()
     assert {a, b} == {0, 1} and kv.alloc() is None

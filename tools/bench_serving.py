@@ -1135,11 +1135,11 @@ def _quant_probe(cfg, pp, prompt, steps, kv_dtype, drive=None):
     bs = 8
     P = -(-(prompt.size + steps) // bs)
     heads, hd = cfg.heads, cfg.hidden // cfg.heads
-    data = jnp.zeros((cfg.layers, 2, P + 1, heads, bs, hd),
-                     jnp.float32)
+    shape, scale_shape = gd.paged_arena_shapes(cfg.layers, P + 1, heads,
+                                               bs, hd)
+    data = jnp.zeros(shape, jnp.float32)
     arena = data if kv_dtype is None else (
-        data.astype(jnp.int8),
-        jnp.zeros((cfg.layers, 2, P + 1, heads, bs), jnp.float32))
+        data.astype(jnp.int8), jnp.zeros(scale_shape, jnp.float32))
     pages = jnp.arange(1, P + 1, dtype=jnp.int32)
     logits, arena = gd.gpt_prefill_pages(
         pp, cfg, prompt[None], 0, prompt.size, arena, pages)
