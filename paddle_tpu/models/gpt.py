@@ -37,6 +37,11 @@ class GPTConfig:
         self.cp_axis = cp_axis
         self.seq_parallel = seq_parallel
 
+    def serving_model(self):
+        """What serving.ServingEngine serves this config through."""
+        from .gpt_decode import GPT_SERVING_MODEL
+        return GPT_SERVING_MODEL
+
 
 def _causal_attention(x, cfg: GPTConfig, prefix: str, seq: int):
     h, nh = cfg.hidden, cfg.heads

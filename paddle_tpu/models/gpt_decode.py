@@ -42,32 +42,9 @@ __all__ = ["collect_gpt_params", "quantize_params", "gpt_forward_logits",
            "gpt_decode_step_pages", "gpt_decode_chunk_pages",
            "paged_arena_shapes", "decode_attention_path",
            "gpt_decode_verify_slots", "gpt_decode_verify_pages",
-           "spec_ngram_seed", "gpt_generate", "QUANTIZED_KV_KERNELS",
-           "ADAPTER_KERNELS", "ADAPTER_PROJECTIONS",
+           "spec_ngram_seed", "gpt_generate", "ADAPTER_PROJECTIONS",
+           "GPT_SERVING_MODEL",
            "threefry2x32", "sample_key", "sample_split", "sample_gumbel"]
-
-# The paged kernels whose in-graph KV dequant path exists: a quantized
-# (int8 + scale plane) arena may ONLY flow through kernels named here.
-# Config validation reads this to refuse combinations whose dequant
-# path is not covered (e.g. speculate_k > 0 needs the verify kernel)
-# instead of silently falling back to garbage reads — there is no fp32
-# fallback anywhere in the quantized path.
-QUANTIZED_KV_KERNELS = ("gpt_prefill_pages", "gpt_prefill_chunk_pages",
-                        "gpt_decode_step_pages",
-                        "gpt_decode_chunk_pages",
-                        "gpt_decode_verify_pages")
-
-# The paged kernels whose per-slot LoRA gather-matmul path exists: an
-# engine with an adapter pool may ONLY dispatch kernels named here
-# (the QUANTIZED_KV_KERNELS discipline applied to multi-tenant
-# adapters). Config validation reads this to refuse combinations whose
-# low-rank path is not covered (speculate_k > 0 needs the verify
-# kernel's adapter path) instead of silently serving base-model tokens
-# for an adapterized request.
-ADAPTER_KERNELS = ("gpt_prefill_pages", "gpt_prefill_chunk_pages",
-                   "gpt_decode_step_pages",
-                   "gpt_decode_chunk_pages",
-                   "gpt_decode_verify_pages")
 
 # projections the low-rank adapter path covers (every matmul in the
 # block: attention q/k/v/out + both MLP projections)
@@ -1220,85 +1197,10 @@ def gpt_decode_chunk_pages(params, cfg, tokens, arena, pt, ts, keys,
     return block, tokens, arena, ts, keys, done, remaining
 
 
-# -- serving sampler PRNG ---------------------------------------------------
-#
-# The serving chunk kernels draw per-slot samples VMAPPED over the slot
-# dimension, and resumed/preempted/late-admitted sequences must reproduce
-# their streams bit-exactly wherever and whenever they land. The fleet's
-# default `rbg` PRNG cannot provide that: under vmap it generates the
-# whole batch's bits from ONE key (row r of a vmapped draw follows
-# keys[0]'s stream, not keys[r]'s — verified empirically; jax documents
-# rbg as not vmap-invariant), so a slot's draw silently depends on every
-# OTHER slot's key chain and on its own row index. The serving sampler
-# therefore rolls its own counter-based threefry2x32 (the Random123
-# function jax's default CPU PRNG is built on, bit-for-bit) and draws via
-# Gumbel-max — plain vectorized uint32/float32 ops with no batching rule
-# at all, so a row's sample is a pure function of (its key, its logits,
-# its temperature): vmap-invariant, slot-independent, and
-# schedule-independent by construction. Cost: one 20-round hash per
-# lane per draw — noise next to the model matmuls (the rbg default
-# exists for DROPOUT-mass generation, not one categorical per slot).
-
-def threefry2x32(key, x0, x1):
-    """Random123 threefry2x32 (20 rounds), matching jax's reference
-    implementation bit-for-bit. key: (..., 2) uint32 (leading dims
-    broadcast); x0/x1: uint32 counters, broadcastable against the key's
-    leading dims. Returns (y0, y1) uint32."""
-    import jax.numpy as jnp
-
-    k0 = key[..., 0]
-    k1 = key[..., 1]
-    k2 = k0 ^ k1 ^ jnp.uint32(0x1BD11BDA)
-    x0 = (x0 + k0).astype(jnp.uint32)
-    x1 = (x1 + k1).astype(jnp.uint32)
-
-    def rotl(v, d):
-        return (v << jnp.uint32(d)) | (v >> jnp.uint32(32 - d))
-
-    rots = ((13, 15, 26, 6), (17, 29, 16, 24))
-    ks = (k0, k1, k2)
-    for g in range(5):
-        for r in rots[g % 2]:
-            x0 = (x0 + x1).astype(jnp.uint32)
-            x1 = rotl(x1, r) ^ x0
-        x0 = (x0 + ks[(g + 1) % 3]).astype(jnp.uint32)
-        x1 = (x1 + ks[(g + 2) % 3] + jnp.uint32(g + 1)).astype(jnp.uint32)
-    return x0, x1
-
-
-def sample_key(seed):
-    """Pack a (traced or static) integer seed into a (2,) uint32
-    sampler key — the serving twin of PRNGKey(seed)."""
-    import jax.numpy as jnp
-
-    seed = jnp.asarray(seed)
-    return jnp.stack([jnp.zeros((), jnp.uint32),
-                      seed.astype(jnp.uint32)])
-
-
-def sample_split(key):
-    """Advance a sampler key one step: counter (1, 0) of the current
-    key's threefry stream. Draws use counter (0, lane) — disjoint, so a
-    key's draw never aliases its successor's."""
-    import jax.numpy as jnp
-
-    y0, y1 = threefry2x32(key, jnp.uint32(1), jnp.uint32(0))
-    return jnp.stack([y0, y1], axis=-1)
-
-
-def sample_gumbel(key, n):
-    """(n,) standard-Gumbel draws from `key`'s counters (0, 0..n-1) —
-    argmax(logits/temp + gumbel) IS a categorical(softmax(logits/temp))
-    draw (the Gumbel-max trick, the same construction jax.random.
-    categorical uses). u is centered on the 2^-24 lattice so log(u) and
-    log(-log(u)) are always finite."""
-    import jax.numpy as jnp
-
-    lanes = jnp.arange(n, dtype=jnp.uint32)
-    bits, _ = threefry2x32(key, jnp.uint32(0), lanes)
-    u = ((bits >> jnp.uint32(8)).astype(jnp.float32)
-         + jnp.float32(0.5)) * jnp.float32(2.0 ** -24)
-    return -jnp.log(-jnp.log(u))
+# the serving sampler's PRNG lives with the engine (every serving model
+# shares it); re-exported for the callers that knew it here
+from ..serving.sampling import (sample_gumbel, sample_key,  # noqa: E402,F401
+                                sample_split, threefry2x32)
 
 
 def _sample(logits, key, temperature, top_k):
@@ -1374,3 +1276,52 @@ def gpt_generate(params, cfg, prompt, max_new_tokens,
                         float(temperature), int(top_k), eos_id,
                         jax.random.PRNGKey(seed))
     return np.asarray(out)
+
+
+# -- the engine's view of this model ------------------------------------------
+
+from ..serving.model import FEATURES, CacheSpec, ServingModel  # noqa: E402
+
+
+class _GPTServingModel(ServingModel):
+    """The GPT family behind serving.model.ServingModel: every paged
+    kernel above carries the quantized-arena dequant and the per-slot
+    adapter path (the verify pass included), so it declares every
+    feature the engine has."""
+
+    name = "gpt2"
+    features = frozenset(FEATURES)
+
+    def max_positions(self, cfg):
+        return cfg.max_pos
+
+    def cache_spec(self, cfg):
+        shape, _ = paged_arena_shapes(cfg.layers, 1, cfg.heads, 1,
+                                      cfg.hidden // cfg.heads)
+        return CacheSpec(shape[0], shape[3], shape[5])
+
+    def activation_dtype(self, params):
+        import jax.numpy as jnp
+        return jnp.bfloat16 if params["wte"].dtype == jnp.bfloat16 \
+            else jnp.float32
+
+    def decode_attention_path(self, arena, arena_constraint=None):
+        return decode_attention_path(arena, arena_constraint)
+
+    def prefill(self, *args, **kw):
+        return gpt_prefill_pages(*args, **kw) + (None,)
+
+    def prefill_chunk(self, *args, **kw):
+        return gpt_prefill_chunk_pages(*args, **kw) + (None,)
+
+    def decode_chunk(self, *args, **kw):
+        return gpt_decode_chunk_pages(*args, **kw) + (None,)
+
+    def quantize_params(self, params, cfg):
+        return quantize_params(params, cfg)
+
+    def spec_ngram_seed(self, table, slot, tokens, real_len):
+        return spec_ngram_seed(table, slot, tokens, real_len)
+
+
+GPT_SERVING_MODEL = _GPTServingModel()

@@ -1,0 +1,641 @@
+"""Moonlight-16B-A3B (the published DeepSeek-V3 block) on the serving path.
+
+Multi-head LATENT attention and routed + shared experts, served through
+serving.model.ServingModel by the same engine, scheduler, block allocator
+and fused chunk loop as the GPT family:
+
+  * the cache holds ONE row a token a layer, `[c | k_rope]` AFTER the
+    latent's norm and the key's rotation (kv_lora_rank + qk_rope_head_dim
+    values, zero-padded to a multiple of 128 lanes: 576 -> 640), shared by
+    all heads: `CacheSpec(layers, heads=1, row_width)`;
+  * PREFILL attends in the EXPANDED form (k_nope and v expanded from the
+    latents through W_kvb, ordinary causal attention at head widths
+    192/192/128: the flash kernel's forward on a TPU for a cold prompt,
+    masked XLA attention over the gathered page row after a prefix hit
+    and on the CPU);
+  * DECODE attends in the ABSORBED form: `q_lat_h = q_nope_h W_UK_h^T`,
+    score `q_lat_h . c + q_rope_h . k_rope`, context `sum p c`, then
+    `o_h = o_lat_h W_UV_h` (ops/paged_attention.latent_paged_attention
+    walks the page table over the latent arena; a gather and two einsums
+    where the kernel does not apply);
+  * layers past `first_k_dense` route every token to `experts_per_tok` of
+    `n_routed_experts` SwiGLU experts (sigmoid scores in float32, the
+    picks by score + correction bias, the weights by score alone,
+    normalised and scaled) plus one shared SwiGLU. The expert product is
+    GROUPED: tokens sorted by expert, one `jax.lax.ragged_dot` per weight
+    over the rows that were routed (tokens x experts_per_tok of them, no
+    capacity, none dropped, never every expert on every token). On the
+    TPU `ragged_dot` compiles to one native call whose FLOPs are the
+    useful ones (a compile for a described v5e: PERF.md, PR 27).
+
+Parameters (`x @ W`, W is (in, out); no bias anywhere):
+  wte (V, h), head (h, V), norm_f (h,), layers[i]:
+    norm1, norm2 (h,); wq (h, heads*(nope+rope)); wkva (h, rank+rope);
+    kv_norm (rank,); wkvb (rank, heads*(nope+v)), a head's [k_nope | v];
+    wo (heads*v, h); then a dense layer's gate, up (h, I), down (I, h),
+    or an expert layer's router (h, E), router_bias (E,) float32,
+    w_gate, w_up (E, h, F), w_down (E, F, h), shared_gate, shared_up
+    (h, Fs), shared_down (Fs, h).
+
+Engine features: none of int8 weights or cache, adapters, speculation, a
+mesh plan or chunked prefill is implemented; the engine refuses each at
+construction from `features` below.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+from ..serving.model import CacheSpec, ServingModel
+from .gpt_decode import _gather_pages, _write_pages
+
+__all__ = ["MoonlightConfig", "init_params", "forward_logits",
+           "prefill_pages", "decode_step_pages", "decode_chunk_pages",
+           "decode_attention_path", "absorbed_attention", "route",
+           "grouped_experts", "rope", "MOONLIGHT_SERVING_MODEL"]
+
+_LANES = 128
+
+
+class MoonlightConfig:
+    """The published keys under this package's names (defaults are
+    Moonlight-16B-A3B's `config.json`)."""
+
+    def __init__(self, vocab_size=163840, hidden=2048, layers=27, heads=16,
+                 kv_lora_rank=512, qk_nope_head_dim=128,
+                 qk_rope_head_dim=64, v_head_dim=128, intermediate=11264,
+                 moe_intermediate=1408, n_routed_experts=64,
+                 n_shared_experts=2, experts_per_tok=6, first_k_dense=1,
+                 routed_scaling_factor=2.446, rms_eps=1e-5,
+                 rope_theta=50000.0, max_pos=8192, init_range=0.02):
+        self.vocab_size = vocab_size
+        self.hidden = hidden
+        self.layers = layers
+        self.heads = heads
+        self.kv_lora_rank = kv_lora_rank
+        self.qk_nope_head_dim = qk_nope_head_dim
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.v_head_dim = v_head_dim
+        self.intermediate = intermediate
+        self.moe_intermediate = moe_intermediate
+        self.n_routed_experts = n_routed_experts
+        self.n_shared_experts = n_shared_experts
+        self.experts_per_tok = experts_per_tok
+        self.first_k_dense = first_k_dense
+        self.routed_scaling_factor = routed_scaling_factor
+        self.rms_eps = rms_eps
+        self.rope_theta = rope_theta
+        self.max_pos = max_pos
+        self.init_range = init_range
+
+    @property
+    def qk_head_dim(self):
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def row_values(self):
+        """Values a token leaves in a layer's cache: latent + rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def row_width(self):
+        """The row as stored: `row_values` up to a multiple of 128 lanes
+        (a minor dimension that is not one is padded in HBM anyway and
+        cannot be sliced by a DMA: PERF.md, PR 26)."""
+        return -(-self.row_values // _LANES) * _LANES
+
+    def serving_model(self):
+        return MOONLIGHT_SERVING_MODEL
+
+
+def init_params(cfg: MoonlightConfig, key, dtype):
+    """Seeded random weights on the default device: normal(0, init_range)
+    matrices, unit norms, a small non-zero router correction bias (a
+    trained model's is not zero, and zero would leave it unexercised).
+    One jitted maker per KIND of layer, called once per layer: three
+    small programs whatever the depth, and never more than one layer's
+    generator bits alive beside the weights (the 64 experts of a layer
+    are 1.1 GB in bfloat16; all layers' in one call would hold the
+    stacks and their slices together)."""
+    import jax
+    import jax.numpy as jnp
+
+    h, n = cfg.hidden, cfg.heads
+    E, F = cfg.n_routed_experts, cfg.moe_intermediate
+    Fs = cfg.n_shared_experts * F
+    std = cfg.init_range
+
+    def normal(k, shape):
+        return (std * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
+
+    shapes = {"wq": (h, n * cfg.qk_head_dim), "wkva": (h, cfg.row_values),
+              "wkvb": (cfg.kv_lora_rank,
+                       n * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+              "wo": (n * cfg.v_head_dim, h)}
+    dense = dict(shapes, gate=(h, cfg.intermediate),
+                 up=(h, cfg.intermediate), down=(cfg.intermediate, h))
+    moe = dict(shapes, router=(h, E), w_gate=(E, h, F), w_up=(E, h, F),
+               w_down=(E, F, h), shared_gate=(h, Fs), shared_up=(h, Fs),
+               shared_down=(Fs, h))
+
+    def layer(shapes, k):
+        ks = jax.random.split(k, len(shapes) + 1)
+        lp = {name: normal(kk, shape)
+              for (name, shape), kk in zip(shapes.items(), ks)}
+        lp.update(norm1=jnp.ones((h,), dtype), norm2=jnp.ones((h,), dtype),
+                  kv_norm=jnp.ones((cfg.kv_lora_rank,), dtype))
+        if "router" in shapes:
+            lp["router_bias"] = 0.01 * jax.random.normal(ks[-1], (E,),
+                                                         jnp.float32)
+        return lp
+
+    def top(k):
+        k1, k2 = jax.random.split(k)
+        return {"wte": normal(k1, (cfg.vocab_size, h)),
+                "head": normal(k2, (h, cfg.vocab_size)),
+                "norm_f": jnp.ones((h,), dtype)}
+
+    make = {False: jax.jit(lambda k: layer(dense, k)),
+            True: jax.jit(lambda k: layer(moe, k))}
+    keys = jax.random.split(key, cfg.layers + 1)
+    params = jax.jit(top)(keys[-1])
+    params["layers"] = [make[i >= cfg.first_k_dense](keys[i])
+                        for i in range(cfg.layers)]
+    return params
+
+
+# -- the block's pieces -------------------------------------------------------
+
+def _rms(x, g, eps):
+    """RMS norm, statistics in float32, the result in x's type."""
+    import jax
+    import jax.numpy as jnp
+    x32 = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    return (x32 * inv * g.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope(x, pos, theta):
+    """Rotary position on the last axis of x at integer positions `pos`
+    (broadcast against x's leading axes), in the PUBLISHED element
+    order: the interleaved pairs (x0, x1), (x2, x3), ... are first
+    permuted to halves (x0, x2, ..., x1, x3, ...), then `x cos +
+    rotate_half(x) sin`. Float32 inside, x's type out."""
+    import jax.numpy as jnp
+    d = x.shape[-1]
+    x32 = x.astype(jnp.float32)
+    x32 = jnp.concatenate([x32[..., 0::2], x32[..., 1::2]], -1)
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = pos.astype(jnp.float32)[..., None] * inv
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)
+    rot = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]], -1)
+    return (x32 * cos + rot * sin).astype(x.dtype)
+
+
+def _swiglu(x, gate, up, down):
+    import jax
+    g = x @ gate
+    return (jax.nn.silu(g) * (x @ up)) @ down
+
+
+def _project(cfg, lp, x, pos):
+    """The attention's projections of tokens x (T, h) at positions pos
+    (T,): q_nope (T, n, nope), q_rope (T, n, rope) rotated, and the cache
+    row's two parts, c (T, rank) normed and k_rope (T, rope) rotated."""
+    T = x.shape[0]
+    n, nope = cfg.heads, cfg.qk_nope_head_dim
+    q = (x @ lp["wq"]).reshape(T, n, cfg.qk_head_dim)
+    q_nope = q[..., :nope]
+    q_rope = rope(q[..., nope:], pos[:, None], cfg.rope_theta)
+    kva = x @ lp["wkva"]
+    c = _rms(kva[:, :cfg.kv_lora_rank], lp["kv_norm"], cfg.rms_eps)
+    k_rope = rope(kva[:, cfg.kv_lora_rank:], pos, cfg.rope_theta)
+    return q_nope, q_rope, c, k_rope
+
+
+def _cache_rows(cfg, c, k_rope):
+    """[c | k_rope | 0] (T, row_width): the row as the arena stores it."""
+    import jax.numpy as jnp
+    pad = cfg.row_width - cfg.row_values
+    parts = [c, k_rope]
+    if pad:
+        parts.append(jnp.zeros((c.shape[0], pad), c.dtype))
+    return jnp.concatenate(parts, -1)
+
+
+def _wkvb_heads(cfg, lp):
+    """(W_UK, W_UV): (rank, n, nope) and (rank, n, v), the key and the
+    value half of W_kvb by head."""
+    w = lp["wkvb"].reshape(cfg.kv_lora_rank, cfg.heads,
+                           cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def _expand(cfg, lp, c, k_rope):
+    """Keys (T, n, nope + rope) and values (T, n, v) from cache rows."""
+    import jax.numpy as jnp
+    w_uk, w_uv = _wkvb_heads(cfg, lp)
+    k_nope = jnp.einsum("tc,cnd->tnd", c, w_uk)
+    v = jnp.einsum("tc,cnd->tnd", c, w_uv)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope[:, None, :],
+                                  k_nope.shape[:2] + k_rope.shape[-1:])], -1)
+    return k, v
+
+
+def _masked_attention(q, k, v, mask, scale, q_block=512):
+    """softmax(q k^T scale) v under `mask` (Tq, Tk), float32 scores and
+    statistics, by blocks of query rows so that the score matrix of a
+    long prompt never exists whole. q (Tq, n, d), k (Tk, n, d), v (Tk,
+    n, dv) -> (Tq, n, dv)."""
+    import jax
+    import jax.numpy as jnp
+
+    def block(args):
+        qb, mb = args
+        s = jnp.einsum("qnd,knd->nqk", qb, k,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(mb[None], s, -1e30)
+        p = jnp.exp(s - jnp.max(s, -1, keepdims=True))
+        p = (p / p.sum(-1, keepdims=True)).astype(v.dtype)
+        return jnp.einsum("nqk,knd->qnd", p, v)
+
+    tq = q.shape[0]
+    if tq <= q_block or tq % q_block:
+        return block((q, mask))
+    nb = tq // q_block
+    out = jax.lax.map(block, (q.reshape(nb, q_block, *q.shape[1:]),
+                              mask.reshape(nb, q_block, mask.shape[1])))
+    return out.reshape(tq, *out.shape[2:])
+
+
+def route(cfg, lp, x):
+    """The router. x (T, h) -> (picks (T, k) int32, weights (T, k)
+    float32). Scores are sigmoid(x W_g) in float32; the k largest of
+    score + correction bias are picked (one group, so no group stage);
+    the weights are the scores WITHOUT the bias at the picks, over their
+    sum + 1e-20, times routed_scaling_factor."""
+    import jax
+    import jax.numpy as jnp
+    logits = jnp.dot(x.astype(jnp.float32), lp["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, picks = jax.lax.top_k(scores + lp["router_bias"].astype(jnp.float32),
+                             cfg.experts_per_tok)
+    w = jnp.take_along_axis(scores, picks, -1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
+    return picks.astype(jnp.int32), w
+
+
+def grouped_experts(lp, xs, group_sizes):
+    """The grouped SwiGLU: xs (R, h) rows sorted by expert, group_sizes
+    (E,) how many rows each expert has (rows past their sum are
+    nobody's and come back zero). Three ragged products over the routed
+    rows alone."""
+    import jax
+    g = jax.lax.ragged_dot(xs, lp["w_gate"], group_sizes)
+    u = jax.lax.ragged_dot(xs, lp["w_up"], group_sizes)
+    return jax.lax.ragged_dot(jax.nn.silu(g) * u, lp["w_down"], group_sizes)
+
+
+def _moe(cfg, lp, x, live):
+    """The expert layer's feed-forward on tokens x (T, h). `live` (T,)
+    bool: rows that are real (a prefill's padding and a frozen slot's
+    ride-along are not: they get no expert and do not count). Returns
+    (y (T, h), counters)."""
+    import jax
+    import jax.numpy as jnp
+    T, k, E = x.shape[0], cfg.experts_per_tok, cfg.n_routed_experts
+    with jax.named_scope("moe/router"):
+        picks, w = route(cfg, lp, x)
+    with jax.named_scope("moe/dispatch"):
+        # rows that are not live are sent past the last expert: sorted
+        # to the end, in no group, never computed
+        flat = jnp.where(live[:, None], picks, E).reshape(-1)
+        order = jnp.argsort(flat)                      # stable
+        group_sizes = jnp.bincount(flat, length=E + 1)[:E].astype(jnp.int32)
+        xs = x[order // k]                             # (T*k, h)
+    with jax.named_scope("moe/experts"):
+        ys = grouped_experts(lp, xs, group_sizes)
+    with jax.named_scope("moe/shared"):
+        shared = _swiglu(x, lp["shared_gate"], lp["shared_up"],
+                         lp["shared_down"])
+    with jax.named_scope("moe/combine"):
+        back = ys[jnp.argsort(order)].reshape(T, k, -1)
+        # a row in no group is nobody's: whatever the product left there
+        back = jnp.where(live[:, None, None], back, 0)
+        y = jnp.einsum("tkh,tk->th", back.astype(jnp.float32), w)
+        y = (y + shared.astype(jnp.float32)).astype(x.dtype)
+    counters = {"expert_tokens": group_sizes,
+                "router_tokens": jnp.sum(live).astype(jnp.int32),
+                "experts_touched": jnp.sum(group_sizes > 0).astype(jnp.int32),
+                "moe_passes": jnp.any(live).astype(jnp.int32)}
+    return y, counters
+
+
+def _ffn(cfg, lp, x, live, counters):
+    """norm2 + the layer's feed-forward (dense or routed), with the
+    counters of a routed layer added to `counters`."""
+    import jax
+    h = _rms(x, lp["norm2"], cfg.rms_eps)
+    if "router" not in lp:
+        with jax.named_scope("ffn/dense"):
+            return _swiglu(h, lp["gate"], lp["up"], lp["down"]), counters
+    y, c = _moe(cfg, lp, h, live)
+    return y, {name: counters[name] + c[name] for name in counters}
+
+
+def _zero_counters(cfg):
+    import jax.numpy as jnp
+    return {"expert_tokens": jnp.zeros((cfg.n_routed_experts,), jnp.int32),
+            "router_tokens": jnp.zeros((), jnp.int32),
+            "experts_touched": jnp.zeros((), jnp.int32),
+            "moe_passes": jnp.zeros((), jnp.int32)}
+
+
+def _head(cfg, params, x):
+    import jax
+    import jax.numpy as jnp
+    with jax.named_scope("head"):
+        y = _rms(x, params["norm_f"], cfg.rms_eps)
+        return jnp.dot(y, params["head"],
+                       preferred_element_type=jnp.float32)
+
+
+def _act_dtype(params):
+    import jax.numpy as jnp
+    return jnp.bfloat16 if params["wte"].dtype == jnp.bfloat16 \
+        else jnp.float32
+
+
+# -- the whole sequence, no cache (tests; generation never runs it) ------------
+
+def forward_logits(params, cfg, tokens):
+    """Logits (T, V) float32 of one sequence tokens (T,), expanded
+    attention, the grouped expert product: the served math without a
+    cache."""
+    import jax.numpy as jnp
+    T = tokens.shape[0]
+    pos = jnp.arange(T)
+    x = params["wte"][tokens].astype(_act_dtype(params))
+    mask = pos[None, :] <= pos[:, None]
+    counters = _zero_counters(cfg)
+    live = jnp.ones((T,), bool)
+    for lp in params["layers"]:
+        h = _rms(x, lp["norm1"], cfg.rms_eps)
+        q_nope, q_rope, c, k_rope = _project(cfg, lp, h, pos)
+        k, v = _expand(cfg, lp, c, k_rope)
+        q = jnp.concatenate([q_nope, q_rope], -1)
+        o = _masked_attention(q, k, v, mask, 1.0 / np.sqrt(cfg.qk_head_dim))
+        x = x + o.reshape(T, -1) @ lp["wo"]
+        y, counters = _ffn(cfg, lp, x, live, counters)
+        x = x + y
+    return _head(cfg, params, x)
+
+
+# -- prefill into the pages: expanded attention --------------------------------
+
+def _flash_causal(q, k, v, scale):
+    """Causal self-attention of a cold prompt by the flash kernel's
+    forward at unequal widths. q, k (B, n, d), v (B, n, dv)."""
+    from ..ops.flash_attention import _flash_call
+    o, _ = _flash_call(q.swapaxes(0, 1), k.swapaxes(0, 1), v.swapaxes(0, 1),
+                       None, True, float(scale), False)
+    return o.swapaxes(0, 1)
+
+
+def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
+    """Prefill ONE sequence's prompt suffix tokens (1, B) (right-padded
+    to its bucket; real_len real) at positions pfx_len.., whose first
+    pfx_len positions are already cached (a prefix hit; 0 for a cold
+    prompt), into the pages of its page row `pages` (P,). Writes the
+    suffix's cache rows as whole pages and attends in the expanded form:
+    a cold prompt over its own rows, causally; after a prefix hit over
+    the whole gathered page row, masked by position. Returns (logits (1,
+    V) float32 of position pfx_len + real_len - 1, arena, counters)."""
+    import jax
+    import jax.numpy as jnp
+
+    B = tokens.shape[1]
+    bs = arena.shape[4]
+    L = pages.shape[0] * bs
+    dtype = arena.dtype
+    scale = 1.0 / np.sqrt(cfg.qk_head_dim)
+    on_tpu = jax.default_backend() == "tpu"
+    j = jnp.arange(B)
+    pos = pfx_len + j
+    live = j < real_len
+    x = params["wte"][tokens[0]].astype(dtype)
+    counters = _zero_counters(cfg)
+    for li, lp in enumerate(params["layers"]):
+        with jax.named_scope("mla/project"):
+            h = _rms(x, lp["norm1"], cfg.rms_eps)
+            q_nope, q_rope, c, k_rope = _project(cfg, lp, h, pos)
+            q = jnp.concatenate([q_nope, q_rope], -1)
+            rows = _cache_rows(cfg, c, k_rope)
+            arena = _write_pages(arena, li, pages, pfx_len, real_len,
+                                 rows[:, None, :])
+
+        def cold(arena, lp=lp, q=q, c=c, k_rope=k_rope):
+            k, v = _expand(cfg, lp, c, k_rope)
+            if on_tpu and B % 128 == 0:
+                return _flash_causal(q, k, v, scale)
+            return _masked_attention(q, k, v, j[None, :] <= j[:, None],
+                                     scale)
+
+        def warm(arena, li=li, lp=lp, q=q):
+            cached = _gather_pages(arena, li, pages)[0]       # (L, W)
+            k, v = _expand(
+                cfg, lp, cached[:, :cfg.kv_lora_rank],
+                cached[:, cfg.kv_lora_rank:cfg.row_values])
+            return _masked_attention(
+                q, k, v, jnp.arange(L)[None, :] <= pos[:, None], scale)
+
+        with jax.named_scope("mla/attend"):
+            o = jax.lax.cond(pfx_len == 0, cold, warm, arena)
+        with jax.named_scope("mla/project"):
+            x = x + o.reshape(B, -1) @ lp["wo"]
+        y, counters = _ffn(cfg, lp, x, live, counters)
+        x = x + y
+    last = x[real_len - 1][None]
+    return _head(cfg, params, last), arena, counters
+
+
+# -- decode through the pages: absorbed attention ------------------------------
+
+def decode_attention_path(arena, arena_constraint=None):
+    """ "latent_paged_kernel" on a TPU over the bare arena with a
+    lane-aligned row; "gather" elsewhere (the CPU)."""
+    import jax
+    if (not isinstance(arena, tuple) and arena_constraint is None
+            and arena.shape[-1] % _LANES == 0
+            and jax.default_backend() == "tpu"):
+        return "latent_paged_kernel"
+    return "gather"
+
+
+def absorbed_attention(q_ext, rows, mask):
+    """The gather form of the absorbed step: q_ext (S, n, W) scaled,
+    rows (S, L, W) each slot's cached rows, mask (S, L). Returns the
+    context (S, n, W) in the rows' space."""
+    import jax.numpy as jnp
+    s = jnp.einsum("snw,slw->snl", q_ext, rows,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(mask[:, None, :], s, -1e30)
+    p = jnp.exp(s - jnp.max(s, -1, keepdims=True))
+    p = (p / p.sum(-1, keepdims=True)).astype(rows.dtype)
+    return jnp.einsum("snl,slw->snw", p, rows)
+
+
+def decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
+                      attention=None):
+    """One decode step of every slot: tokens, ts (S,), pt (S, P). Writes
+    each live slot's cache row at position ts and attends over 0..ts in
+    the absorbed form. A frozen slot (`done`) writes to the scratch
+    block (the gather) or nowhere (the kernel) and its logits are the
+    caller's to discard. Returns (logits (S, V) float32, arena,
+    counters)."""
+    import jax
+    import jax.numpy as jnp
+
+    s_dim, P = pt.shape
+    bs = arena.shape[4]
+    dtype = arena.dtype
+    rank = cfg.kv_lora_rank
+    scale = 1.0 / np.sqrt(cfg.qk_head_dim)
+    if attention is None:
+        attention = decode_attention_path(arena)
+    if attention == "latent_paged_kernel":
+        from ..ops.paged_attention import latent_paged_attention
+    live = jnp.ones((s_dim,), bool) if done is None else ~done
+    x = params["wte"][tokens].astype(dtype)
+    counters = _zero_counters(cfg)
+    pad = cfg.row_width - cfg.row_values
+    for li, lp in enumerate(params["layers"]):
+        with jax.named_scope("mla/project"):
+            h = _rms(x, lp["norm1"], cfg.rms_eps)
+            q_nope, q_rope, c, k_rope = _project(cfg, lp, h, ts)
+            row = _cache_rows(cfg, c, k_rope)
+        with jax.named_scope("mla/absorb"):
+            w_uk, w_uv = _wkvb_heads(cfg, lp)
+            q_lat = jnp.einsum("snd,cnd->snc", q_nope, w_uk)
+            parts = [q_lat, q_rope]
+            if pad:
+                parts.append(jnp.zeros(q_rope.shape[:2] + (pad,), dtype))
+            q_ext = (jnp.concatenate(parts, -1).astype(jnp.float32)
+                     * scale).astype(dtype)
+        with jax.named_scope("mla/attend"):
+            if attention == "latent_paged_kernel":
+                o_ext, arena = latent_paged_attention(q_ext, row, arena, li,
+                                                      pt, ts, done)
+            else:
+                wblk = pt[jnp.arange(s_dim), ts // bs]
+                if done is not None:
+                    wblk = jnp.where(done, 0, wblk)
+                arena = arena.at[li, 0, wblk, 0, ts % bs].set(row)
+                cached = _gather_pages(arena, li, pt)[:, 0]   # (S, L, W)
+                o_ext = absorbed_attention(
+                    q_ext, cached,
+                    jnp.arange(P * bs)[None, :] <= ts[:, None])
+        with jax.named_scope("mla/absorb"):
+            o = jnp.einsum("snc,cnd->snd", o_ext[..., :rank], w_uv)
+        with jax.named_scope("mla/project"):
+            x = x + o.reshape(s_dim, -1) @ lp["wo"]
+        y, counters = _ffn(cfg, lp, x, live, counters)
+        x = x + y
+    return _head(cfg, params, x), arena, counters
+
+
+def decode_chunk_pages(params, cfg, tokens, arena, pt, ts, keys, temps,
+                       done, remaining, eos_ids, chunk, sample_fn=None,
+                       **unsupported):
+    """`chunk` iterations of decode_step_pages + per-slot sampling +
+    in-graph EOS/budget masking in ONE lax.scan: the GPT chunk loop's
+    carry, sampler and done mask (frozen slots re-emit their last token,
+    never advance, keys advance every iteration for every slot). Returns
+    (block (chunk, S) int32, tokens, arena, ts, keys, done, remaining,
+    counters summed over the chunk)."""
+    import jax
+    import jax.numpy as jnp
+
+    on = {k: v for k, v in unsupported.items()
+          if v is not None and v != 0}
+    if on:
+        raise NotImplementedError(
+            f"the Moonlight decode chunk has no {sorted(on)} path")
+    if sample_fn is None:
+        def sample_fn(key, logits, temp):
+            return jnp.argmax(logits, -1).astype(jnp.int32), key
+    attention = decode_attention_path(arena)
+
+    def body(carry, _):
+        tok, arena, ts, keys, done, rem, counters = carry
+        logits, arena, c = decode_step_pages(params, cfg, tok, arena, pt,
+                                             ts, done, attention=attention)
+        counters = {name: counters[name] + c[name] for name in counters}
+        nxt, keys = jax.vmap(sample_fn)(keys, logits, temps)
+        emit = jnp.where(done, tok, nxt)
+        rem = jnp.where(done, rem, rem - 1)
+        ndone = done | (emit == eos_ids) | (rem <= 0)
+        ts = jnp.where(done, ts, ts + 1)
+        return (emit, arena, ts, keys, ndone, rem, counters), emit
+
+    (tokens, arena, ts, keys, done, remaining, counters), block = \
+        jax.lax.scan(body, (tokens, arena, ts, keys, done, remaining,
+                            _zero_counters(cfg)), None, length=int(chunk))
+    return block, tokens, arena, ts, keys, done, remaining, counters
+
+
+# -- the engine's view of this model ------------------------------------------
+
+class _MoonlightServingModel(ServingModel):
+    name = "Moonlight-16B-A3B"
+    features = frozenset()
+
+    def max_positions(self, cfg):
+        return cfg.max_pos
+
+    def cache_spec(self, cfg):
+        return CacheSpec(cfg.layers, 1, cfg.row_width)
+
+    def activation_dtype(self, params):
+        return _act_dtype(params)
+
+    def decode_attention_path(self, arena, arena_constraint=None):
+        return decode_attention_path(arena, arena_constraint)
+
+    def counter_names(self, cfg):
+        # expert_tokens[e]: rows routed to expert e, and router_tokens:
+        # tokens routed (each layer counts), by both programs since
+        # start; the decode_* three by the decode chunk alone: tokens
+        # routed, experts that had a row, and passes of an expert layer
+        # with a live slot (what a step's expert bytes are counted from)
+        return {"expert_tokens": (cfg.n_routed_experts,),
+                "router_tokens": (), "decode_router_tokens": (),
+                "decode_experts_touched": (), "decode_moe_passes": ()}
+
+    def prefill(self, params, cfg, tokens, pfx_len, real_len, arena, pages,
+                adapters=None, adapter_id=None):
+        import jax.numpy as jnp
+        logits, arena, c = prefill_pages(params, cfg, tokens, pfx_len,
+                                         real_len, arena, pages)
+        zero = jnp.zeros((), jnp.int32)
+        return logits, arena, {
+            "expert_tokens": c["expert_tokens"],
+            "router_tokens": c["router_tokens"],
+            "decode_router_tokens": zero, "decode_experts_touched": zero,
+            "decode_moe_passes": zero}
+
+    def decode_chunk(self, *args, **kw):
+        out = decode_chunk_pages(*args, **kw)
+        c = out[-1]
+        return out[:-1] + ({
+            "expert_tokens": c["expert_tokens"],
+            "router_tokens": c["router_tokens"],
+            "decode_router_tokens": c["router_tokens"],
+            "decode_experts_touched": c["experts_touched"],
+            "decode_moe_passes": c["moe_passes"]},)
+
+
+MOONLIGHT_SERVING_MODEL = _MoonlightServingModel()

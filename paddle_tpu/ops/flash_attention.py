@@ -90,7 +90,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
 
     q_idx, k_idx = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
-    d = q_ref.shape[-1]
+    d = v_ref.shape[-1]       # the output's width: v's, not q's
 
     @pl.when(k_idx == 0)
     def _init():
@@ -436,12 +436,16 @@ def _pad_to(x, axis, mult):
 
 
 def _flash_call(q, k, v, bias, causal, sm_scale, interpret):
-    """q: (bn, sq, d); k/v: (bn, sk, d); bias: (bn, sk) or None.
-    Returns o (bn, sq, d) unpadded and lse (bn, sq_pad, 128) lane-padded."""
+    """q: (bn, sq, d); k: (bn, sk, d); v: (bn, sk, dv); bias: (bn, sk) or
+    None. Returns o (bn, sq, dv) unpadded and lse (bn, sq_pad, 128)
+    lane-padded. The forward takes a value width of its own (latent
+    attention's prefill: q, k of 192 and v of 128); the backward kernels
+    do not, and training never asks."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bn, sq0, d = q.shape
+    dv = v.shape[-1]
     sk0 = k.shape[1]
     block_q, block_k = _pick_blocks(sq0, sk0)
     q = _pad_to(q, 1, block_q)
@@ -453,7 +457,7 @@ def _flash_call(q, k, v, bias, causal, sm_scale, interpret):
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
         pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
+        pl.BlockSpec((1, block_k, dv), lambda i, j, kk: (i, kk, 0)),
     ]
     args = [q, k, v]
     kw = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
@@ -472,17 +476,17 @@ def _flash_call(q, k, v, bias, causal, sm_scale, interpret):
         grid=(bn, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda i, j, kk: (i, j, 0)),
             pl.BlockSpec((1, block_q, _LANES), lambda i, j, kk: (i, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bn, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((bn, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((bn, sq, _LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),

@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["paged_attention"]
+__all__ = ["paged_attention", "latent_paged_attention"]
 
 _NEG_INF = -1e30
 # pages in flight per program: deep enough to hide a DMA's latency behind
@@ -217,3 +217,170 @@ def paged_attention(q, k, v, arena, layer, pt, ts, done=None):
         lengths = jnp.where(done, 0, lengths)
     new = jnp.concatenate([k, v], -1).astype(arena.dtype)
     return _call(q, new, arena, layer, pt, lengths, platform == "cpu")
+
+
+# -- latent rows: every head attends the SAME cached row ----------------------
+#
+# Multi-head latent attention caches ONE compressed row a token a layer
+# (models/moonlight: the normed latent `c`, the rotated shared key
+# `k_rope`, zero lanes up to a multiple of 128) and decodes in the
+# absorbed form: head h's query is carried into the latent space, its
+# score is q_ext_h . row and its context sum p * row, so K and V are both
+# the page as it lies and the heads are the ROWS of a matrix product. The
+# arena is (layers, 1, num_blocks, 1, block_size, W): a page of a layer is
+# one contiguous (block_size, W) tile, copied as it lies; scores are
+# q (heads, W) x page^T on the MXU and the context p (heads, block_size)
+# x page. The page walk, the DMA ring, the step's own row written through
+# the live page and the aliased arena are the kernel's above.
+
+
+def _latent_kernel(layer_ref, pt_ref, len_ref, q_ref, new_ref, arena_ref,
+                   arena_out_ref, o_ref, kv_buf, stage, sems, wsem, *,
+                   block_size, pages, ring):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s = pl.program_id(0)
+    li = layer_ref[0]
+    length = len_ref[s]                       # live rows; 0 = frozen slot
+    n_pages = jax.lax.div(length + block_size - 1, block_size)
+    heads, w = q_ref.shape[1], q_ref.shape[2]
+
+    def page_copy(p, slot):
+        blk = pt_ref[s * pages + p]
+        return pltpu.make_async_copy(arena_ref.at[li, 0, blk, 0],
+                                     kv_buf.at[slot], sems.at[slot])
+
+    def attend(kv, q, carry, rows=None):
+        """One page of the online softmax; kv (block_size, W) as stored,
+        q (heads, W) in the same type, statistics in float32."""
+        m, l, acc = carry
+        sc = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        if rows is not None:
+            col = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            sc = jnp.where(col < rows, sc, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)                        # (heads, 1)
+        pr = jnp.exp(sc - m_new)                          # (heads, bs)
+        l = l * alpha + jnp.sum(pr, axis=1, keepdims=True)
+        acc = acc * alpha + jax.lax.dot_general(
+            pr.astype(kv.dtype), kv, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    @pl.when(length > 0)
+    def _live():
+        last = n_pages - 1
+        for i in range(ring):
+            @pl.when(i < n_pages)
+            def _prime(i=i):
+                page_copy(i, i).start()
+        q = q_ref[0]
+
+        def page_step(p, carry):
+            slot = jax.lax.rem(p, ring)
+            page_copy(p, slot).wait()
+            kv = kv_buf[slot]
+
+            @pl.when(p + ring < n_pages)
+            def _refill():
+                page_copy(p + ring, slot).start()
+
+            return attend(kv, q, carry)
+
+        carry = jax.lax.fori_loop(
+            0, last, page_step,
+            (jnp.full((heads, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((heads, 1), jnp.float32),
+             jnp.zeros((heads, w), jnp.float32)))
+        # the live page takes this step's row, is attended WITH it and
+        # goes back whole (see the kernel above)
+        slot = jax.lax.rem(last, ring)
+        page_copy(last, slot).wait()
+        kv = kv_buf[slot].astype(jnp.float32)
+        at = jax.lax.rem(length - 1, block_size)
+        row = jax.lax.broadcasted_iota(jnp.int32, kv.shape, 0)
+        kv = jnp.where(row == at, new_ref[0].astype(jnp.float32), kv)
+        stage[...] = kv.astype(stage.dtype)
+        back = pltpu.make_async_copy(
+            stage, arena_out_ref.at[li, 0, pt_ref[s * pages + last], 0],
+            wsem)
+        back.start()
+        _, l, acc = attend(stage[...], q, carry, rows=at + 1)
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
+        back.wait()
+
+    @pl.when(length == 0)
+    def _frozen():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _latent_call(q, new, arena, layer, pt, lengths, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s_dim, heads, w = q.shape
+    block_size = arena.shape[4]
+    pages = pt.shape[1]
+    ring = min(_RING, pages)
+    kern = functools.partial(_latent_kernel, block_size=block_size,
+                             pages=pages, ring=ring)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    arena, out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(s_dim,),
+            in_specs=[pl.BlockSpec((1, heads, w), lambda s, *_: (s, 0, 0)),
+                      pl.BlockSpec((1, 1, w), lambda s, *_: (s, 0, 0)),
+                      hbm],
+            out_specs=[hbm,
+                       pl.BlockSpec((1, heads, w), lambda s, *_: (s, 0, 0))],
+            scratch_shapes=[
+                pltpu.VMEM((ring, block_size, w), arena.dtype),
+                pltpu.VMEM((block_size, w), arena.dtype),
+                pltpu.SemaphoreType.DMA((ring,)),
+                pltpu.SemaphoreType.DMA(()),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct(arena.shape, arena.dtype),
+                   jax.ShapeDtypeStruct((s_dim, heads, w), q.dtype)],
+        input_output_aliases={5: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="latent_paged_attention",
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      pt.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
+      q, new[:, None, :], arena)
+    return out, arena
+
+
+def latent_paged_attention(q, row, arena, layer, pt, ts, done=None):
+    """One decode step of absorbed latent attention over the paged pool,
+    with its write.
+
+    q: (S, heads, W), each head's query in the ROW's space, already
+    scaled, zero in the lanes the row pads. row: (S, W), the new cache
+    row of position ts[s] of slot s. arena: (layers, 1, num_blocks, 1,
+    block_size, W), full precision. Slot s writes `row` as row ts[s] %
+    block_size of block pt[s, ts[s] // block_size] and every head
+    attends over positions 0..ts[s], its own row included: score
+    q_h . row_t (float32), softmax in float32, context sum p row_t. A
+    frozen slot (`done`) writes nothing, reads nothing and gets zeros.
+
+    Returns (context (S, heads, W) in q's dtype, of which the caller
+    keeps the latent lanes; the arena, its input's own buffer). Mosaic
+    on a TPU backend, interpreted on the CPU (tests), an error elsewhere."""
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(
+            "latent_paged_attention compiles for TPU (Mosaic) and "
+            f"interprets on CPU for tests; the active backend is "
+            f"{platform!r}")
+    lengths = ts + 1
+    if done is not None:
+        lengths = jnp.where(done, 0, lengths)
+    return _latent_call(q.astype(arena.dtype), row.astype(arena.dtype),
+                        arena, layer, pt, lengths, platform == "cpu")

@@ -696,8 +696,10 @@ class GenerationServer:
 def serve(params, cfg, config: Optional[ServerConfig] = None,
           registry: Optional[MetricsRegistry] = None) -> GenerationServer:
     """One-call deployment: build `config.replicas` ServingEngine
-    replicas over a GPT parameter pytree (gpt_decode's params/cfg, the
-    same pair ServingEngine takes) and start the HTTP service. Returns
+    replicas over a model's parameter pytree and config (the pair
+    ServingEngine takes: the config names its serving model, a GPTConfig
+    the GPT family, a MoonlightConfig Moonlight-16B-A3B) and start the
+    HTTP service. Returns
     the started GenerationServer; the bound port is `server.port`."""
     from ..serving import ServingConfig
 

@@ -15,9 +15,16 @@ prompt never stalls co-batched decode streams
 streaming callbacks (`engine`), and request/engine metrics incl. the
 dispatch-amortization and block/prefix-cache series (`metrics`).
 
+The engine knows no architecture: a model supplies its prefill, its fused
+decode chunk, a description of its per-layer cache state and the features
+it implements through `model.ServingModel`, named by its config's
+`serving_model()` (the GPT family, `models.gpt_decode`; Moonlight-16B-A3B,
+`models.moonlight`), and the sampler's PRNG every model shares is
+`sampling`.
+
 Entry points: `inference.create_engine(config, gpt_config)` to serve a
-saved model dir, or `ServingEngine(params, cfg)` over an in-memory
-parameter pytree.
+saved GPT model dir, or `ServingEngine(params, cfg)` over an in-memory
+parameter pytree of the model that `cfg` names.
 """
 
 from .adapters import (AdapterError, AdapterGeometryError, AdapterPool,
@@ -29,6 +36,7 @@ from .engine import (DEFAULT_RETRY_AFTER_S, EngineOverloadError,
 from .faults import FaultPlan, InjectedFault
 from .kv_cache import ShapeBuckets, SlotKVCache
 from .metrics import EngineMetrics, RequestMetrics
+from .model import CacheSpec, ServingModel, serving_model
 from .migration import (TICKET_VERSION, MigrationError, MigrationTicket,
                         TicketError)
 from .scheduler import (ContinuousBatchingScheduler, SequenceEvent,
@@ -42,6 +50,7 @@ __all__ = ["ServingEngine", "ServingConfig", "GenerationRequest",
            "EngineMetrics", "RequestMetrics",
            "MigrationTicket", "MigrationError", "TicketError",
            "TICKET_VERSION",
+           "ServingModel", "CacheSpec", "serving_model",
            "AdapterPool", "AdapterError", "UnknownAdapterError",
            "AdapterGeometryError", "AdapterPoolFullError",
            "AdapterReferencedError", "adapter_geometry",

@@ -35,6 +35,7 @@ from ..observability import request_log as _request_log
 from ..observability import watchdog as _watchdog
 from ..observability.tracer import get_tracer, request_scope, trace_span
 from .kv_cache import ShapeBuckets, SlotKVCache
+from .model import require_features, serving_model
 from .metrics import _TICK_PHASES, EngineMetrics, RequestMetrics
 from .scheduler import (PREFILL_PENDING, CompileJournal,
                         ContinuousBatchingScheduler)
@@ -78,7 +79,7 @@ class ServingConfig:
     dim = page-table rows); max_queue bounds the admission queue (beyond
     it, submit() sheds); prefill_buckets is the fixed set of padded
     prompt-SUFFIX lengths (compile count is O(len(buckets))); max_len is
-    the per-sequence position capacity (default cfg.max_pos).
+    the per-sequence position capacity (default: the model's positions).
 
     Paged pool knobs: block_size is the page granularity (HBM is paid
     per page actually mapped, and prefixes are hash-shared at block
@@ -138,10 +139,10 @@ class ServingConfig:
     across chunk sizes, preempt/resume, migration, and mesh shapes —
     and swap/migration payloads carry dtype + scales (a
     dtype-mismatched MigrationTicket rejects with TicketError).
-    Unknown dtype strings raise at construction; kv_dtype="int8" with
-    speculate_k > 0 additionally requires the verify kernel's dequant
-    path (gpt_decode.QUANTIZED_KV_KERNELS) — covered today, asserted
-    so it can never silently rot.
+    Unknown dtype strings raise at construction, and so does every
+    option the served model does not declare among its features
+    (serving.model.require_features: int8 weights or cache, adapters,
+    speculation, a mesh, chunked prefill).
 
     Multi-tenant adapter knobs (both default None = adapterless, the
     bit-identical pre-adapter engine with zero new executables or
@@ -259,7 +260,7 @@ class ServingConfig:
         # quantized serving (both off by default): weight_dtype="int8"
         # runs the q/k/v/out/mlp matmuls against per-output-channel
         # int8 weights with the dequant fused in-graph
-        # (gpt_decode.quantize_params); kv_dtype="int8" packs the
+        # (the model's quantize_params); kv_dtype="int8" packs the
         # paged block arena as int8 with a per-block scale plane,
         # quantize-at-scatter / dequant-at-gather. Unknown values are
         # a LOUD config error here — there is no silent fp32 fallback
@@ -370,20 +371,28 @@ TICK_RING_SIZE = 256
 
 
 class ServingEngine:
-    """Continuous-batching generate service over a GPT parameter pytree.
+    """Continuous-batching generate service over a model's parameter
+    pytree and config.
 
-    params/cfg are gpt_decode's (collect_gpt_params + GPTConfig);
-    inference.create_engine() wires them from a saved model dir."""
+    The config names its serving model (serving.model.ServingModel:
+    `cfg.serving_model()`), and params are that model's tree: a
+    GPTConfig with the GPT family's (collect_gpt_params;
+    inference.create_engine() wires them from a saved model dir), a
+    MoonlightConfig with models.moonlight's. Options the model does not
+    implement refuse here, at construction."""
 
     def __init__(self, params, cfg, serving: Optional[ServingConfig] = None):
         serving = serving or ServingConfig()
         self.cfg = cfg
         self.config = serving
+        model = self.model = serving_model(cfg)
+        require_features(model, serving)
+        max_pos = model.max_positions(cfg)
         max_len = int(serving.max_len if serving.max_len is not None
-                      else cfg.max_pos)
-        if max_len > cfg.max_pos:
+                      else max_pos)
+        if max_len > max_pos:
             raise ValueError(
-                f"max_len {max_len} exceeds cfg.max_pos {cfg.max_pos}")
+                f"max_len {max_len} exceeds cfg.max_pos {max_pos}")
         if serving.prefill_buckets is not None:
             buckets = serving.prefill_buckets
             too_big = [b for b in buckets if b > max_len]
@@ -396,46 +405,12 @@ class ServingEngine:
             buckets = _default_buckets(max_len)
         self.buckets = ShapeBuckets(buckets)
         import jax.numpy as jnp
-        dtype = params["wte"].dtype if params["wte"].dtype == jnp.bfloat16 \
-            else jnp.float32
+        dtype = model.activation_dtype(params)
         # quantized serving: weight-only int8 happens HERE, before the
         # scheduler shards anything, so the int8 tensors + scales ride
-        # the same Megatron TP placement the fp32 weights would. The
-        # kv_dtype="int8" x speculate_k gate is a coverage assert, not
-        # a policy: the verify kernel must carry the in-graph dequant
-        # path (gpt_decode.QUANTIZED_KV_KERNELS) or the combination
-        # refuses loudly — a quantized arena must never flow through a
-        # kernel that would read its int8 rows as values.
-        from ..models import gpt_decode as _gd
-        if serving.kv_dtype == "int8" and serving.speculate_k > 0 \
-                and "gpt_decode_verify_pages" not in \
-                _gd.QUANTIZED_KV_KERNELS:
-            raise ValueError(
-                "kv_dtype='int8' with speculate_k > 0 requires the "
-                "verify kernel's dequant path "
-                "(gpt_decode.QUANTIZED_KV_KERNELS lacks "
-                "'gpt_decode_verify_pages') — refusing rather than "
-                "silently reading quantized rows as values")
-        # multi-tenant adapters: same coverage-assert discipline — every
-        # kernel this engine can dispatch must carry the per-slot
-        # gather-matmul low-rank path (gpt_decode.ADAPTER_KERNELS), or
-        # the combination refuses at construction instead of silently
-        # serving base-model tokens for an adapterized request
-        if serving.max_adapters is not None:
-            needed = {"gpt_prefill_pages", "gpt_decode_chunk_pages"}
-            if serving.speculate_k > 0:
-                needed.add("gpt_decode_verify_pages")
-            if serving.prefill_chunk is not None:
-                needed.add("gpt_prefill_chunk_pages")
-            missing = sorted(needed - set(_gd.ADAPTER_KERNELS))
-            if missing:
-                raise ValueError(
-                    "max_adapters requires the per-slot adapter path in "
-                    f"every dispatched kernel; gpt_decode.ADAPTER_KERNELS "
-                    f"lacks {missing} — refusing rather than silently "
-                    "serving base-model tokens")
+        # the same Megatron TP placement the fp32 weights would
         if serving.weight_dtype == "int8":
-            params = _gd.quantize_params(params, cfg)
+            params = model.quantize_params(params, cfg)
         # whole-model parameter bytes AS SERVED (post-quantization,
         # pre-sharding: the sum across chips on a mesh) — the
         # capacity-planning number next to pool_bytes — and the dtype
@@ -445,8 +420,7 @@ class ServingEngine:
         import jax
         self.weight_bytes = int(sum(
             leaf.nbytes for leaf in jax.tree_util.tree_leaves(params)))
-        self._weight_dtype = serving.weight_dtype \
-            or str(jnp.dtype(params["wte"].dtype))
+        self._weight_dtype = serving.weight_dtype or str(jnp.dtype(dtype))
         # tensor-parallel mesh plan: built ONCE here (validates device
         # count + head/ffn divisibility), threaded into the scheduler,
         # which shards params + arena at construction so every jitted
@@ -609,8 +583,8 @@ class ServingEngine:
         self.buckets.bucket_for(prompt.size)          # raises if too long
         total = prompt.size + max_new_tokens
         if total > self.kv.max_len:
-            # max_len <= cfg.max_pos (enforced at construction), so this
-            # also guards the wpe-table clamp gpt_generate raises for
+            # max_len <= the model's positions (enforced at
+            # construction), so this also guards a position table's clamp
             raise ValueError(
                 f"prompt ({prompt.size}) + max_new_tokens "
                 f"({max_new_tokens}) exceeds the pool's max_len "
@@ -1445,8 +1419,16 @@ class ServingEngine:
         if self.adapters is not None:
             s.update(self.adapters.occupancy())
         s["compiled_executables"] = self.scheduler.compile_count
-        # "paged_kernel" or "gather": the decode step's attention path
+        # "paged_kernel", "latent_paged_kernel" or "gather": the decode
+        # step's attention path
         s["decode_attention"] = self.scheduler.decode_attention
+        # the served architecture, what a token costs the arena in a
+        # layer, and the model's own in-graph counters (a routed model's
+        # `expert_tokens` and `router_tokens` since start)
+        s["model"] = self.model.name
+        s["cache_row_bytes"] = self.kv.cache_row_bytes
+        for name, value in self.scheduler.model_counters.items():
+            s[name] = value.tolist()
         # the registry label this engine's serving_* series carry, so a
         # caller can find them in observability.get_registry().snapshot()
         s["engine_label"] = self.metrics.engine_label

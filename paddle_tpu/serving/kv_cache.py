@@ -1,9 +1,14 @@
 """Paged KV-cache manager for the continuous-batching scheduler.
 
 The pool is a fixed-shape BLOCK ARENA `(layers, 1, num_blocks, heads,
-block_size, 2*head_dim)` (a row's K and V side by side: models/gpt_decode
-`paged_arena_shapes` owns the layout) plus one page table
-`(num_slots, max_pages)` int32: a "slot" is one sequence's page-table row, and its K/V rows live
+block_size, row_width)` plus one page table `(num_slots, max_pages)`
+int32. What a row holds is the served model's business, described by its
+`serving.model.CacheSpec`: a GPT's is a head's K and V side by side
+(`heads` of `2*head_dim`), a latent-attention model's is ONE compressed
+row a token shared by all its heads (`heads` = 1). Nothing here reads a
+row; slots, blocks, refcounts, hashes, swap payloads and migration
+tickets index axis 2 and are the same for both. A "slot" is one
+sequence's page-table row, and its rows live
 scattered across arena blocks (vLLM-style PagedAttention). Fixed shapes
 are still the whole point — XLA compiles ONE decode executable over the
 arena + page table (batch dim = num_slots, always) and one prefill per
@@ -27,7 +32,7 @@ prefix can never see each other's divergence.
 
 Block index 0 is the reserved SCRATCH block: never allocated, it absorbs
 the in-graph ride-along writes of frozen slots (see
-gpt_decode_step_pages) and the page-row padding past a sequence's tail.
+the models' decode steps) and the page-row padding past a sequence's tail.
 
 Host-side bookkeeping (slots/blocks/refcounts/hashes) lives here; the
 arena itself is a jax value the scheduler threads through its jitted
@@ -95,8 +100,8 @@ SCRATCH_BLOCK = 0
 class SlotKVCache:
     """Paged block arena + slot/page allocator + hashed prefix cache.
 
-    kv: (layers, 1, num_blocks, heads, block_size, 2*head_dim) — the block
-    arena (block 0 is scratch, never allocated). A slot is a page-table
+    kv: (layers, 1, num_blocks, heads, block_size, row_width) — the block
+    arena, shaped by the served model's CacheSpec (block 0 is scratch, never allocated). A slot is a page-table
     row of up to max_pages block ids; admission maps exactly the pages a
     request's prompt+budget needs (`blocks_for(p_len + max_new)`), so
     the arena packs short requests densely instead of paying max_len per
@@ -130,11 +135,16 @@ class SlotKVCache:
         if mesh_shards < 1:
             raise ValueError(
                 f"mesh_shards must be >= 1, got {mesh_shards}")
-        if cfg.heads % mesh_shards:
+        # deferred like the scheduler's: a model's module must not be
+        # imported during package import
+        from .model import serving_model
+        spec = serving_model(cfg).cache_spec(cfg)
+        if spec.heads % mesh_shards:
             raise ValueError(
-                f"cfg.heads {cfg.heads} not divisible by mesh_shards "
-                f"{mesh_shards} — the arena's heads axis shards evenly "
-                "or not at all")
+                f"the arena's {spec.heads} heads are not divisible by "
+                f"mesh_shards {mesh_shards} — the heads axis shards "
+                "evenly or not at all")
+        self.spec = spec
         self.mesh_shards = int(mesh_shards)
         self.cfg = cfg
         self.num_slots = int(num_slots)
@@ -148,12 +158,11 @@ class SlotKVCache:
             raise ValueError(
                 f"num_blocks must be >= 2 (scratch + 1), got {num_blocks}")
         self.prefix_cache_enabled = bool(prefix_cache)
-        heads, hd = cfg.heads, cfg.hidden // cfg.heads
         # kv_dtype: the arena STORAGE discipline — None keeps the
         # compute-dtype slab ("float32"/"bfloat16" pool), "int8" packs
         # one byte per K/V value plus a per-(block, head, row) f32
-        # scale plane (models/gpt_decode quantize-at-scatter /
-        # dequant-at-gather). Anything else is a loud config error —
+        # scale plane (the model quantizes at the scatter and
+        # dequantizes at the gather). Anything else is a loud config error —
         # there is no silent fp32 fallback for an unknown dtype.
         if kv_dtype not in (None, "int8"):
             raise ValueError(
@@ -165,11 +174,8 @@ class SlotKVCache:
         else:
             self.dtype = jnp.dtype(dtype) if dtype is not None \
                 else jnp.dtype(jnp.float32)
-        # deferred like the scheduler's: models/__init__ pulls every
-        # model module, which must not run during package import
-        from ..models.gpt_decode import paged_arena_shapes
-        shape, scale_shape = paged_arena_shapes(
-            cfg.layers, self.num_blocks, heads, self.block_size, hd)
+        shape = spec.arena_shape(self.num_blocks, self.block_size)
+        scale_shape = shape[:-1] + (2,)
         # arena_device (a jax sharding/device or None = default): the
         # arena must be ALLOCATED under its mesh sharding, not
         # allocated whole and resharded after — allocate-then-move
@@ -232,6 +238,14 @@ class SlotKVCache:
         # admission can never hash-hit unfilled rows. Dropped whole on
         # free(slot) (cancel/preempt mid-prefill).
         self._pending_reg: Dict[int, List[Tuple[int, bytes, int]]] = {}
+
+    @property
+    def cache_row_bytes(self) -> int:
+        """Arena bytes one token holds in one layer, as stored (all
+        heads; a quantized row's scale pair included)."""
+        per_head = self.spec.row_width * self.dtype.itemsize \
+            + (8 if self.kv_quantized else 0)
+        return self.spec.heads * per_head
 
     # -- slot allocation ----------------------------------------------------
 

@@ -1,7 +1,9 @@
 """Continuous-batching step loop over the PAGED KV pool.
 
-Orca/vLLM-style iteration-level scheduling on top of gpt_decode's
-prefill/step split: instead of running each request's whole decode loop
+Orca/vLLM-style iteration-level scheduling on top of a served model's
+prefill/decode-chunk split (serving.model.ServingModel: the scheduler
+calls the model's programs through that interface and knows no
+architecture): instead of running each request's whole decode loop
 alone (TPU idle between requests, batch-1 latency everywhere), the
 scheduler keeps ONE batched decode dispatch hot over all slots and
 admits new requests into free slots between dispatches:
@@ -9,12 +11,12 @@ admits new requests into free slots between dispatches:
     admit:  map exactly the PAGES the request needs (prompt + budget)
             into the slot's page-table row — leading prompt blocks that
             hash-hit the prefix cache are shared in, refcounted, instead
-            of recomputed — then gpt_prefill_pages the remaining SUFFIX
+            of recomputed — then the model's prefill of the remaining SUFFIX
             (padded to a shape bucket) into the fresh blocks and sample
             the first token from the last-position logits. One dispatch
             per suffix-bucket shape; a prefix hit shrinks the suffix
             into the small buckets, which is the TTFT win.
-    step:   gpt_decode_chunk_pages over the WHOLE pool — `decode_chunk`
+    step:   the model's decode chunk over the WHOLE pool — `decode_chunk`
             fused decode iterations (fixed batch = num_slots, per-slot
             positions through the page table, in-graph sampling +
             EOS/budget masking) per dispatch, returning a (chunk, slots)
@@ -83,10 +85,10 @@ tests can assert O(buckets), not O(requests) — and that the chunk loop
 adds exactly ONE executable whatever decode_chunk is.
 
 Greedy sequences reproduce the sequential `gpt_generate` path
-token-for-token: the per-slot step math is gpt_decode_step's row-by-row,
+token-for-token: the per-slot step math is the sequential step's row-by-row,
 and argmax runs in-graph exactly as `_sample` does. Sampled sequences
 (temperature > 0) use a per-slot threefry2x32 Gumbel-max sampler
-(gpt_decode.sample_gumbel — NOT jax.random: the fleet's default rbg
+(serving.sampling.sample_gumbel — NOT jax.random: the fleet's default rbg
 PRNG is not vmap-invariant, see _sample_row) keyed from the request
 seed, one key split per decode iteration, frozen slots included. A
 request's seeded stream is therefore a pure function of (params,
@@ -128,7 +130,7 @@ draft -> verify -> accept pass — a per-slot trigram table (carried in
 the donated device state, seeded from the prompt at prefill) proposes
 up to k tokens, ONE multi-position model pass scores them all, and
 in-graph exact-match acceptance commits the matched run plus one
-corrected token (models/gpt_decode._spec_step). Tokens-per-model-pass
+corrected token (the GPT family's `_spec_step`). Tokens-per-model-pass
 rises from exactly 1 to between 1 and k+1 WITHOUT changing any stream:
 acceptance is "the sampler would have produced this token anyway", key
 chain advanced one split per committed token, so greedy AND seeded
@@ -150,7 +152,7 @@ closed over (a closure would bake the traced value in as a constant and
 uploads would be silently ignored), so an upload is a pure value update
 at fixed shape: zero recompiles, compile count unchanged. The kernels
 gather A/B rows by the carry vector and add the fp32 low-rank delta to
-the base projections (gpt_decode._dense_a); slots on adapter 0 SELECT
+the base projections (the GPT family's `_dense_a`); slots on adapter 0 SELECT
 the untouched base activation, which is what makes adapter_id=0 streams
 bit-identical to an adapterless engine. Host records carry the LOGICAL
 adapter id (the pool row is re-resolved at swap-in/migration — rows of
@@ -171,7 +173,9 @@ from .. import profiler
 from ..observability import request_log as _request_log
 from ..observability.tracer import get_tracer, trace_span
 from ..utils.compile_cache import ensure_compile_cache
+from . import sampling
 from .kv_cache import ShapeBuckets, SlotKVCache
+from .model import serving_model
 
 _TRACER = get_tracer()
 
@@ -429,6 +433,9 @@ class _Inflight(NamedTuple):
     #                     counts; block is (chunk, k+1, S) then
     host_s: float = 0.0  # launch-side host seconds: the duration of this
     #                      dispatch's serving/decode_dispatch span
+    counters: Any = None  # the model's in-graph counters of this chunk
+    #                       (None for a model that has none), fetched
+    #                       WITH the block
 
 
 class ContinuousBatchingScheduler:
@@ -479,6 +486,16 @@ class ContinuousBatchingScheduler:
                 kv.store_arena(plan.shard_arena(kv.arena))
         self.params = params
         self.cfg = cfg
+        # the served model (serving.model.ServingModel): its prefill,
+        # its decode chunk and what it says of its arena are all the
+        # scheduler knows of the architecture
+        self.model = serving_model(cfg)
+        # the model's in-graph counters (a routed model's tokens per
+        # expert), summed on the host as they arrive with the token
+        # blocks and the first tokens; {} for a model that has none
+        self.model_counters: Dict[str, np.ndarray] = {
+            name: np.zeros(shape, np.int64)
+            for name, shape in self.model.counter_names(cfg).items()}
         self.kv = kv
         self.buckets = buckets
         self.top_k = int(top_k)
@@ -486,13 +503,12 @@ class ContinuousBatchingScheduler:
         self.overlap = bool(overlap)
         self.speculate_k = int(speculate_k)
         self.speculate_ngram = int(speculate_ngram)
-        # which attention the decode chunk runs, read off what it is
-        # given (gpt_decode.decode_attention_path: the arena's form, the
-        # mesh plan, the backend) and fixed for the engine's life;
-        # speculation decodes through the verify pass, which gathers
-        from ..models.gpt_decode import decode_attention_path
+        # which attention the decode chunk runs, read by the model off
+        # what it is given (the arena's form, the mesh plan, the
+        # backend) and fixed for the engine's life; speculation decodes
+        # through the verify pass, which gathers
         self.decode_attention = "gather" if self.speculate_k else \
-            decode_attention_path(
+            self.model.decode_attention_path(
                 kv.arena, None if plan is None else plan.constrain_arena)
         # chunked prefill (None = monolithic, bit-identical to the
         # pre-knob engine with zero new executables): the per-tick
@@ -522,7 +538,7 @@ class ContinuousBatchingScheduler:
         self._spec_samples: List[int] = []
         self._running: Dict[int, _Running] = {}
         self._compile_events: List[str] = []
-        # (S, 2) uint32 sampler keys (gpt_decode.threefry2x32 streams,
+        # (S, 2) uint32 sampler keys (sampling.threefry2x32 streams,
         # NOT jax.random — see _sample_row); every row is re-seeded
         # in-graph at admission, so zeros are fine here
         self._keys = jax.numpy.zeros((kv.num_slots, 2), jax.numpy.uint32)
@@ -588,7 +604,7 @@ class ContinuousBatchingScheduler:
 
     def _sample_row(self, key, logits, temp):
         """In-graph per-slot sampler: counter-based threefry2x32 +
-        Gumbel-max (gpt_decode.sample_gumbel) with the temperature as a
+        Gumbel-max (sampling.sample_gumbel) with the temperature as a
         traced per-slot value. Deliberately NOT jax.random: the fleet's
         default rbg PRNG is not vmap-invariant (a vmapped draw follows
         keys[0]'s stream, not each row's own key), while this sampler is
@@ -598,17 +614,16 @@ class ContinuousBatchingScheduler:
         host-swap preemption bit-identically."""
         import jax
         import jax.numpy as jnp
-        from ..models import gpt_decode as gd
 
-        key_next = gd.sample_split(key)
+        key_next = sampling.sample_split(key)
         greedy = jnp.argmax(logits, -1).astype(jnp.int32)
         scaled = logits / jnp.maximum(temp, 1e-6)
         if self.top_k > 0:
             vals, idx = jax.lax.top_k(scaled, self.top_k)
-            g = gd.sample_gumbel(key, self.top_k)
+            g = sampling.sample_gumbel(key, self.top_k)
             drawn = idx[jnp.argmax(vals + g)].astype(jnp.int32)
         else:
-            g = gd.sample_gumbel(key, logits.shape[-1])
+            g = sampling.sample_gumbel(key, logits.shape[-1])
             drawn = jnp.argmax(scaled + g).astype(jnp.int32)
         return jnp.where(temp > 0.0, drawn, greedy), key_next
 
@@ -618,10 +633,8 @@ class ContinuousBatchingScheduler:
         ensure_compile_cache()
         import jax
         import jax.numpy as jnp
-        # deferred: models/__init__ pulls every model module (each doing
-        # `import paddle_tpu`), which must not run during package import
-        from ..models import gpt_decode as gd
 
+        model = self.model
         s_dim = self.kv.num_slots
         self._state = (jnp.zeros((s_dim,), jnp.int32),   # tokens
                        jnp.zeros((s_dim,), jnp.int32),   # ts
@@ -673,7 +686,7 @@ class ContinuousBatchingScheduler:
         def prefill_impl(params, arena, pt, state, tokens, pfx_len,
                          real_len, pages, slot, *alo):
             self._note_compile(f"prefill:L{tokens.shape[1]}")
-            logits, arena = gd.gpt_prefill_pages(
+            logits, arena, counters = model.prefill(
                 params, self.cfg, tokens, pfx_len, real_len, arena,
                 pages, adapters=alo[0] if alo else None,
                 adapter_id=alo[1] if alo else None)
@@ -683,21 +696,21 @@ class ContinuousBatchingScheduler:
                 # n-grams, then seed from THIS prompt's suffix (with a
                 # prefix-cache hit the hit blocks' tokens aren't here —
                 # seeding is best-effort; drafts are always verified)
-                state = state[:7] + (gd.spec_ngram_seed(
+                state = state[:7] + (model.spec_ngram_seed(
                     state[7], slot, tokens[0], real_len),) + state[8:]
             return (c_rep(logits[0]), c_arena(arena), c_rep(pt),
-                    c_rep(state))
+                    c_rep(state), c_rep(counters))
 
         def prefill_chunk_impl(params, arena, pt, state, tokens,
                                start_pos, real_len, pages, slot, *alo):
             # chunked prefill: per-position math shared with
-            # prefill_impl (gpt_prefill_chunk_pages rides the same
+            # prefill_impl (the model's chunked prefill rides the same
             # body), start_pos is the host-carried fill cursor. The
             # page-row install is idempotent across a prompt's chunks —
             # one executable per chunk bucket, whatever the chunk index.
             self._note_compile(
                 f"prefill_chunk:L{tokens.shape[1]}")
-            logits, arena = gd.gpt_prefill_chunk_pages(
+            logits, arena, counters = model.prefill_chunk(
                 params, self.cfg, tokens, start_pos, real_len, arena,
                 pages, adapters=alo[0] if alo else None,
                 adapter_id=alo[1] if alo else None)
@@ -707,16 +720,16 @@ class ContinuousBatchingScheduler:
                 # reset-per-chunk only costs acceptance rate on long
                 # prompts (drafts are always verified — the stream is a
                 # pure function of the sampler chain, never the table)
-                state = state[:7] + (gd.spec_ngram_seed(
+                state = state[:7] + (model.spec_ngram_seed(
                     state[7], slot, tokens[0], real_len),) + state[8:]
             return (c_rep(logits[0]), c_arena(arena), c_rep(pt),
-                    c_rep(state))
+                    c_rep(state), c_rep(counters))
 
         def admit_impl(keys, state, slot, seed, logits, temp, pos,
                        max_new, eos_id, prev_tok, *aid):
             self._note_compile("admit_sample")
             tokens, ts, done, remaining, temps, eos_ids = state[:6]
-            keys = keys.at[slot].set(gd.sample_key(seed))
+            keys = keys.at[slot].set(sampling.sample_key(seed))
             first, key_next = self._sample_row(keys[slot], logits, temp)
             keys = keys.at[slot].set(key_next)
             # finished-at-admission mirrors the host rule exactly so the
@@ -747,7 +760,7 @@ class ContinuousBatchingScheduler:
             tail = (state[-1],) if apool else ()
             if self.speculate_k:
                 (block, counts, tokens, arena, ts, keys, done,
-                 remaining, spec) = gd.gpt_decode_chunk_pages(
+                 remaining, spec, counters) = model.decode_chunk(
                     params, self.cfg, tokens, arena, pt, ts, keys,
                     temps, done, remaining, eos_ids, self.decode_chunk,
                     sample_fn=self._sample_row,
@@ -758,9 +771,10 @@ class ContinuousBatchingScheduler:
                 return (c_rep((block, counts)), c_arena(arena),
                         c_rep(keys),
                         c_rep((tokens, ts, done, remaining, temps,
-                               eos_ids) + spec + tail))
-            block, tokens, arena, ts, keys, done, remaining = \
-                gd.gpt_decode_chunk_pages(
+                               eos_ids) + spec + tail),
+                        c_rep(counters))
+            block, tokens, arena, ts, keys, done, remaining, counters = \
+                model.decode_chunk(
                     params, self.cfg, tokens, arena, pt, ts, keys,
                     temps, done, remaining, eos_ids, self.decode_chunk,
                     sample_fn=self._sample_row,
@@ -768,7 +782,7 @@ class ContinuousBatchingScheduler:
                     adapters=ad, adapter_ids=aids)
             return (c_rep(block), c_arena(arena), c_rep(keys),
                     c_rep((tokens, ts, done, remaining, temps,
-                           eos_ids) + tail))
+                           eos_ids) + tail), c_rep(counters))
 
         def release_impl(pt, state, slot):
             # cancel path: the host verdict the in-graph done mask can't
@@ -1062,7 +1076,7 @@ class ContinuousBatchingScheduler:
                                   prefix_len=pfx_len,
                                   request_id=getattr(req, "request_id",
                                                      None)):
-            logits, arena, self._pt, self._state = \
+            logits, arena, self._pt, self._state, counters = \
                 self._jit_call(
                     f"prefill:L{bucket}", self._prefill_jit,
                     self.params, self.kv.arena, self._pt, self._state,
@@ -1072,7 +1086,7 @@ class ContinuousBatchingScheduler:
         event = self._sample_first(
             slot, req, logits, p_len, max_new, temperature, seed,
             eos_id, int(prompt[0, -1]), self._admit_counter,
-            adapter_id=adapter_id)
+            adapter_id=adapter_id, counters=counters)
         self._admit_counter += 1
         rlog = _request_log.get_request_log()
         if rlog is not None:
@@ -1084,7 +1098,7 @@ class ContinuousBatchingScheduler:
 
     def _sample_first(self, slot, req, logits, p_len, max_new,
                       temperature, seed, eos_id, prev_tok,
-                      seq, adapter_id=0) -> SequenceEvent:
+                      seq, adapter_id=0, counters=None) -> SequenceEvent:
         """Sample the first token from last-position prefill logits and
         promote the slot to _running — the shared tail of monolithic
         admit() and the final prefill chunk (_prefill_step). ONE body
@@ -1103,7 +1117,14 @@ class ContinuousBatchingScheduler:
         # the one wait for the device in an admission: with a dispatch in
         # flight the prefill and this sample are queued behind it
         with trace_span("serving/wait/first_token", "serving"):
-            first = int(first)
+            if counters is None:
+                first = int(first)
+            else:
+                # the prefill's counters ride the one fetch there is
+                import jax
+                first, counters = jax.device_get((first, counters))
+                first = int(first)
+                self._add_counters(counters)
         st = _Running(req, pos=p_len, max_new=max_new, eos_id=eos_id,
                       live_from=self._launches, seq=seq,
                       adapter_id=adapter_id)
@@ -1167,7 +1188,7 @@ class ContinuousBatchingScheduler:
                                   request_id=getattr(pf.req,
                                                      "request_id", None)
                                   ) as dispatch:
-            logits, arena, self._pt, self._state = \
+            logits, arena, self._pt, self._state, _counters = \
                 self._jit_call(
                     f"prefill_chunk:L{bucket}", self._prefill_chunk_jit,
                     self.params, self.kv.arena, self._pt, self._state,
@@ -1260,7 +1281,8 @@ class ContinuousBatchingScheduler:
                                   index=self._launches) as dispatch:
             apool = () if self.adapters is None \
                 else (self.adapters.pool,)
-            block, arena, self._keys, self._state = self._jit_call(
+            block, arena, self._keys, self._state, counters = \
+                self._jit_call(
                 "decode_chunk", self._chunk_jit,
                 self.params, self.kv.arena, self._pt, self._keys,
                 self._state, *apool)
@@ -1273,15 +1295,20 @@ class ContinuousBatchingScheduler:
         begin_ns = dispatch.begin_ns if _TRACER.enabled else 0
         self._inflight.append(_Inflight(block, self._launches,
                                         self.decode_chunk, begin_ns,
-                                        counts, dispatch.seconds))
+                                        counts, dispatch.seconds,
+                                        counters))
         self._launches += 1
         if self.on_launch is not None:
             self.on_launch()
 
-    @staticmethod
-    def _fetch(fl: _Inflight):
+    def _add_counters(self, counters) -> None:
+        for name, value in counters.items():
+            self.model_counters[name] += np.asarray(value)
+
+    def _fetch(self, fl: _Inflight):
         """Block on one dispatch's result: (block, counts or None) on the
-        host. The device segment of a dispatch: with overlap on, host
+        host (a model's counters come in the same fetch and are added
+        up here). The device segment of a dispatch: with overlap on, host
         post-processing of the previous block already ran under this
         dispatch's device time, so the wait here is the un-hidden device
         execution remainder. The caller opens the span around it —
@@ -1290,6 +1317,12 @@ class ContinuousBatchingScheduler:
         duration to _collect."""
         import jax
 
+        if fl.counters is not None:
+            block, counts, counters = jax.device_get(
+                (fl.block, fl.counts, fl.counters))
+            self._add_counters(counters)
+            return np.asarray(block), \
+                None if counts is None else np.asarray(counts)
         if fl.counts is None:
             return np.asarray(jax.device_get(fl.block)), None
         block, counts = jax.device_get((fl.block, fl.counts))

@@ -1,0 +1,443 @@
+"""Moonlight-16B-A3B's serving model (models/moonlight.py) against its plain
+reference (benchmarks/reference/moonlight_ref.py: float32, highest
+precision, no cache, expanded attention, a Python loop over experts) on
+seeded random weights at a small size: hidden 64, 4 heads, nope 16 / rope
+8 / v 16, latent 32, 8 experts of width 32 with 2 a token and a shared one,
+1 dense + 2 expert layers; float32 weights, kernels interpreted.
+
+Tolerances. Both sides compute in float32 (conftest sets the highest
+matmul precision), so what separates them is the ORDER of the sums: the
+absorbed form contracts over the latent where the expanded one contracts
+over the head, the grouped product sums a token's experts in another
+order, the online softmax rescales. Logits here have a standard deviation
+of about 0.16 and a largest magnitude under 1; a float32 rounding is 6e-8
+relative, a few hundred of them in a row stay under 1e-5. LOGIT_ATOL is
+5e-5: 35 times the 1.4e-6 that was measured on the whole sequence, and 66
+times under what rounding the router's WEIGHTS to bfloat16 moves a logit
+by (3.3e-3; a norm's statistics in bfloat16 move one by 0.5: the last
+test shows both failing it). Top-k picks are discontinuous: every comparison that
+depends on the picks runs on inputs whose k-th and (k+1)-th biased scores
+are at least PICK_GAP apart (1e-4, a thousand float32 roundings), counts
+the tokens that were left out for being nearer, and requires that most
+remain; no tolerance is widened for a tie.
+"""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu  # noqa: F401
+from paddle_tpu.models import moonlight as ml
+from paddle_tpu.serving import ServingConfig, ServingEngine
+from paddle_tpu.serving.kv_cache import SlotKVCache
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+LOGIT_ATOL = 5e-5
+PICK_GAP = 1e-4
+
+
+def _load_reference():
+    path = os.path.join(ROOT, "benchmarks", "reference", "moonlight_ref.py")
+    spec = importlib.util.spec_from_file_location("moonlight_ref", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+ref = _load_reference()
+
+CFG = ml.MoonlightConfig(
+    vocab_size=211, hidden=64, layers=3, heads=4, kv_lora_rank=32,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, intermediate=96,
+    moe_intermediate=32, n_routed_experts=8, n_shared_experts=1,
+    experts_per_tok=2, first_k_dense=1, max_pos=64)
+# the same numbers under the published keys, as the reference reads them
+REF_CFG = {"num_attention_heads": 4, "qk_nope_head_dim": 16,
+           "qk_rope_head_dim": 8, "kv_lora_rank": 32, "v_head_dim": 16,
+           "rms_norm_eps": 1e-5, "rope_theta": 50000.0,
+           "num_experts_per_tok": 2, "norm_topk_prob": True,
+           "routed_scaling_factor": 2.446}
+
+
+@pytest.fixture(scope="module")
+def params():
+    p = ml.init_params(CFG, jax.random.PRNGKey(7), jnp.float32)
+    # norms away from one and a correction bias large enough to move picks
+    rng = np.random.default_rng(3)
+    for lp in p["layers"]:
+        for name in ("norm1", "norm2", "kv_norm"):
+            lp[name] = jnp.asarray(rng.uniform(0.5, 1.5, lp[name].shape),
+                                   jnp.float32)
+        if "router_bias" in lp:
+            lp["router_bias"] = jnp.asarray(
+                rng.normal(0, 0.02, lp["router_bias"].shape), jnp.float32)
+    # weights large enough that logits and router scores spread
+    return jax.tree_util.tree_map(
+        lambda a: a * 4.0 if a.ndim >= 2 else a, p)
+
+
+def tokens_of(seed, n):
+    return np.random.default_rng(seed).integers(0, CFG.vocab_size, n)
+
+
+WIDTH = 48
+
+
+def reference_logits(params, seq):
+    """The reference's logits at every position of `seq`, computed on
+    the sequence padded to WIDTH (causal: the padding reaches no real
+    position), so that every test shares one compiled reference."""
+    padded = list(seq) + [0] * (WIDTH - len(seq))
+    return np.asarray(ref.sequence_logits(params, REF_CFG, padded))[:len(seq)]
+
+
+def clear_of_ties(params, seq):
+    """Positions of `seq` at which, in every expert layer of the
+    reference, the k-th and (k+1)-th biased scores are PICK_GAP apart."""
+    x = jnp.asarray(params["wte"][jnp.asarray(seq)], jnp.float32)
+    ok = np.ones(len(seq), bool)
+    with jax.default_matmul_precision("highest"):
+        for lp in params["layers"]:
+            x = ref._attention(x, {k: lp[k] for k in ref._ATTN}, REF_CFG)
+            if "router" not in lp:
+                x = ref._dense_ffn(x, {k: lp[k] for k in
+                                       ("norm2", "gate", "up", "down")},
+                                   REF_CFG)
+                continue
+            xh, dense, acc, gap = ref._moe_head(
+                x, {k: lp[k] for k in ref._MOE_HEAD}, REF_CFG)
+            ok &= np.asarray(gap) >= PICK_GAP
+            for e in range(CFG.n_routed_experts):
+                acc = ref._expert(acc, xh, dense[:, e], lp["w_gate"][e],
+                                  lp["w_up"][e], lp["w_down"][e])
+            x = x + acc
+    # a tie at one position changes every later one through attention
+    return np.minimum.accumulate(ok)
+
+
+def test_forward_logits_match_reference(params):
+    seq = tokens_of(0, 40)
+    got = np.asarray(ml.forward_logits(params, CFG, jnp.asarray(seq)))
+    want = reference_logits(params, seq)
+    clear = clear_of_ties(params, seq)
+    assert clear.sum() >= 30, f"{(~clear).sum()} positions left out for ties"
+    assert want.std() > 0.1
+    assert np.abs(got - want)[clear].max() <= LOGIT_ATOL
+
+
+def test_router_matches_reference_and_bias_moves_picks_not_weights(params):
+    lp = params["layers"][1]
+    x = jnp.asarray(np.random.default_rng(5).normal(0, 1, (64, CFG.hidden)),
+                    jnp.float32)
+    picks, w = ml.route(CFG, lp, x)
+    with jax.default_matmul_precision("highest"):
+        r_picks, r_w, _ = ref.router(x, lp["router"], lp["router_bias"],
+                                     REF_CFG)
+        scores = np.asarray(jax.nn.sigmoid(x @ lp["router"]))
+    s = np.sort(scores + np.asarray(lp["router_bias"]), -1)
+    clear = (s[:, -2] - s[:, -3]) >= PICK_GAP
+    assert clear.sum() >= 56, f"{(~clear).sum()} of 64 tokens left out"
+    assert (np.sort(picks, -1) == np.sort(r_picks, -1))[clear].all()
+    order, r_order = np.argsort(picks, -1), np.argsort(r_picks, -1)
+    np.testing.assert_allclose(
+        np.take_along_axis(np.asarray(w), order, -1)[clear],
+        np.take_along_axis(np.asarray(r_w), r_order, -1)[clear], atol=1e-6)
+    # a large bias on one expert pulls it into (nearly) every token's
+    # picks, and each weight is still the UNBIASED score over the sum
+    biased = dict(lp, router_bias=lp["router_bias"].at[3].add(5.0))
+    b_picks, b_w = ml.route(CFG, biased, x)
+    assert (np.asarray(b_picks) == 3).any(-1).all()
+    assert not (np.asarray(picks) == 3).any(-1).all()
+    at = np.take_along_axis(scores, np.asarray(b_picks), -1)
+    np.testing.assert_allclose(
+        np.asarray(b_w), at / at.sum(-1, keepdims=True) * 2.446, rtol=1e-5)
+    assert np.asarray(b_w).max() < 2.446      # no 5.0 leaked into a weight
+
+
+def test_grouped_product_against_expert_loop(params):
+    """An expert with no token, one with every token, rows in no group."""
+    lp = params["layers"][2]
+    rng = np.random.default_rng(11)
+    T, E = 12, CFG.n_routed_experts
+    x = jnp.asarray(rng.normal(0, 1, (T, CFG.hidden)), jnp.float32)
+    # every token's first pick is expert 5; expert 2 gets nobody; the
+    # last two tokens are not live
+    second = rng.choice([0, 1, 3, 4, 6, 7], T)
+    picks = np.stack([np.full(T, 5), second], -1)
+    live = np.arange(T) < T - 2
+    flat = np.where(live[:, None], picks, E).reshape(-1)
+    order = np.argsort(flat, kind="stable")
+    sizes = np.bincount(flat, minlength=E + 1)[:E].astype(np.int32)
+    assert sizes[5] == T - 2 and sizes[2] == 0
+    ys = ml.grouped_experts(lp, x[order // 2], jnp.asarray(sizes))
+    back = np.asarray(ys)[np.argsort(order)].reshape(T, 2, -1)
+    for t in range(T):
+        for j in range(2):
+            e = picks[t, j]
+            want = ref._swiglu(x[t], lp["w_gate"][e], lp["w_up"][e],
+                               lp["w_down"][e])
+            if live[t]:
+                np.testing.assert_allclose(back[t, j], want, atol=2e-5)
+            else:
+                assert not back[t, j].any()      # nobody's row: zero
+
+
+def test_no_dense_product_and_no_dropped_token(params, monkeypatch):
+    """The rows that enter the expert product are tokens x k exactly."""
+    seen = []
+    real = ml.grouped_experts
+
+    def spy(lp, xs, sizes):
+        seen.append((xs.shape[0], int(np.asarray(sizes).sum())))
+        return real(lp, xs, sizes)
+
+    monkeypatch.setattr(ml, "grouped_experts", spy)
+    T = 23
+    ml.forward_logits(params, CFG, jnp.asarray(tokens_of(1, T)))
+    k = CFG.experts_per_tok
+    assert seen == [(T * k, T * k)] * (CFG.layers - CFG.first_k_dense)
+
+
+def test_absorbed_step_matches_expanded(params):
+    """For one query against the same cached rows: the absorbed score
+    q_lat . c + q_rope . k_rope and context (sum p c) W_UV equal the
+    expanded q . k and sum p v."""
+    lp = params["layers"][1]
+    rng = np.random.default_rng(2)
+    T = 9
+    x = jnp.asarray(rng.normal(0, 1, (T, CFG.hidden)), jnp.float32)
+    pos = jnp.arange(T)
+    q_nope, q_rope, c, k_rope = ml._project(CFG, lp, x, pos)
+    k, v = ml._expand(CFG, lp, c, k_rope)
+    scale = 1.0 / np.sqrt(CFG.qk_head_dim)
+    q = jnp.concatenate([q_nope, q_rope], -1)[-1:]           # the last token
+    s_exp = jnp.einsum("qnd,knd->nqk", q, k) * scale
+    p = jax.nn.softmax(s_exp, -1)
+    o_exp = jnp.einsum("nqk,knd->qnd", p, v)[0]
+    w_uk, w_uv = ml._wkvb_heads(CFG, lp)
+    q_lat = jnp.einsum("snd,cnd->snc", q_nope[-1:], w_uk)
+    pad = jnp.zeros((1, CFG.heads, CFG.row_width - CFG.row_values))
+    q_ext = jnp.concatenate([q_lat, q_rope[-1:], pad], -1) * scale
+    rows = ml._cache_rows(CFG, c, k_rope)
+    s_abs = jnp.einsum("snw,lw->snl", q_ext, rows)[0]
+    np.testing.assert_allclose(s_abs, s_exp[:, 0], atol=2e-6)
+    o_ext = ml.absorbed_attention(q_ext, rows[None], jnp.ones((1, T), bool))
+    o_abs = jnp.einsum("snc,cnd->snd", o_ext[..., :CFG.kv_lora_rank], w_uv)[0]
+    np.testing.assert_allclose(o_abs, o_exp, atol=2e-6)
+
+
+def test_latent_kernel_matches_gather(params):
+    """ops/paged_attention.latent_paged_attention (interpreted) against a
+    gather and two einsums over the same arena: live slots of several
+    lengths, one on a page's first row, one on its last, a frozen one."""
+    from paddle_tpu.ops.paged_attention import latent_paged_attention
+    rng = np.random.default_rng(4)
+    S, P, bs, W, n = 5, 4, 4, CFG.row_width, CFG.heads
+    arena = jnp.asarray(rng.normal(0, 1, (2, 1, 1 + S * P, 1, bs, W)),
+                        jnp.float32)
+    pt = jnp.asarray(1 + rng.permutation(S * P).reshape(S, P), jnp.int32)
+    ts = jnp.asarray([0, 3, 4, 9, 15], jnp.int32)
+    done = jnp.asarray([False, False, False, True, False])
+    q = jnp.asarray(rng.normal(0, 0.3, (S, n, W)), jnp.float32)
+    row = jnp.asarray(rng.normal(0, 1, (S, W)), jnp.float32)
+    for li in (0, 1):
+        out, after = latent_paged_attention(q, row, arena, li, pt, ts, done)
+        wblk = jnp.where(done, 0, pt[jnp.arange(S), ts // bs])
+        want_arena = arena.at[li, 0, wblk, 0, ts % bs].set(row)
+        cached = want_arena[li, 0, pt, 0].reshape(S, P * bs, W)
+        want = ml.absorbed_attention(
+            q, cached, jnp.arange(P * bs)[None] <= ts[:, None])
+        live = ~np.asarray(done)
+        np.testing.assert_allclose(np.asarray(out)[live],
+                                   np.asarray(want)[live], atol=2e-6)
+        assert not np.asarray(out)[~live].any()
+        # the kernel wrote the live slots' rows and nothing else (the
+        # gather's frozen slot dirties the scratch block, the kernel's
+        # writes nowhere)
+        np.testing.assert_array_equal(np.asarray(after)[:, :, 1:],
+                                      np.asarray(want_arena)[:, :, 1:])
+
+
+def test_flash_forward_at_unequal_widths():
+    """The prefill's kernel: q, k of one width and v of another."""
+    from paddle_tpu.ops.flash_attention import _flash_call, mha_reference
+    rng = np.random.default_rng(6)
+    n, s, d, dv = 2, 256, 24, 16
+    q, k = (jnp.asarray(rng.normal(0, 1, (n, s, d)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.normal(0, 1, (n, s, dv)), jnp.float32)
+    o, _ = _flash_call(q, k, v, None, True, 1.0 / np.sqrt(d), True)
+    # mha_reference wants equal widths: zero-extend v and cut the answer
+    vz = jnp.concatenate([v, jnp.zeros((n, s, d - dv))], -1)
+    want = mha_reference(q.swapaxes(0, 1)[None], k.swapaxes(0, 1)[None],
+                         vz.swapaxes(0, 1)[None], causal=True)[0]
+    np.testing.assert_allclose(o, want.swapaxes(0, 1)[..., :dv], atol=2e-5)
+
+
+_PREFILL = jax.jit(lambda params, *a: ml.prefill_pages(params, CFG, *a))
+_DECODE = {path: jax.jit(lambda params, *a, path=path: ml.decode_step_pages(
+    params, CFG, *a, attention=path)) for path in ("gather",
+                                                   "latent_paged_kernel")}
+
+
+def _prefill(params, arena, kv, slot, prompt, bucket, pfx_len=0):
+    padded = np.zeros((1, bucket), np.int32)
+    suffix = prompt[pfx_len:]
+    padded[0, :len(suffix)] = suffix
+    logits, arena, _ = _PREFILL(
+        params, jnp.asarray(padded), jnp.int32(pfx_len),
+        jnp.int32(len(suffix)), arena, jnp.asarray(kv.page_table[slot]))
+    return np.asarray(logits[0]), arena
+
+
+@pytest.mark.parametrize("attention", ["gather", "latent_paged_kernel"])
+def test_prefill_then_decode_matches_reference_at_every_position(
+        params, attention):
+    """A pool of four slots at different lengths: prompts of 5, 6 (one
+    page and a half: it crosses a page boundary) and 9 tokens, slot 1
+    FROZEN through the decode steps, slot 3 holding a stale sequence
+    first and then REUSED by a new one. Every decode step's logits of
+    every live slot against the reference's full forward pass."""
+    bs, steps = 4, 6
+    kv = SlotKVCache(CFG, 4, 32, jnp.float32, block_size=bs,
+                     prefix_cache=False)
+    arena = kv.arena
+    prompts = {0: tokens_of(20, 5), 1: tokens_of(21, 7), 2: tokens_of(22, 6),
+               3: tokens_of(23, 9)}
+    stale = tokens_of(24, 11)
+    slots = {i: kv.alloc() for i in range(4)}
+    # slot 3's previous life: fill its pages, then free and re-map them
+    kv.map_slot(3, stale, len(stale) + steps, register=False)
+    _, arena = _prefill(params, arena, kv, 3, stale, 16)
+    kv.free(3)
+    assert kv.alloc() == 3
+    # teacher-forced: each slot's continuation is drawn beforehand, so the
+    # reference runs ONCE per sequence and gives every position's logits
+    seqs = {s: list(p) + list(tokens_of(50 + s, steps))
+            for s, p in prompts.items()}
+    want = {s: reference_logits(params, seq) for s, seq in seqs.items()}
+    clear = {s: clear_of_ties(params, seq) for s, seq in seqs.items()}
+    for s, prompt in prompts.items():
+        kv.map_slot(s, prompt, len(prompt) + steps, register=False)
+        logits, arena = _prefill(params, arena, kv, s, prompt, 16)
+        assert np.abs(logits - want[s][len(prompt) - 1]).max() <= LOGIT_ATOL
+    pt = jnp.asarray(kv.page_table)
+    done = jnp.asarray([False, True, False, False])
+    ts = jnp.asarray([len(prompts[s]) for s in range(4)], jnp.int32)
+    own = kv.page_table[1][:kv.mapped_block_count(1)]    # not the scratch
+    frozen_rows = np.asarray(arena[:, 0, own])
+    left_out = 0
+    for i in range(steps):
+        tok = jnp.asarray([seqs[s][len(prompts[s]) + i] for s in range(4)],
+                          jnp.int32)
+        logits, arena, counters = _DECODE[attention](
+            params, tok, arena, pt, ts, done)
+        for s in (0, 2, 3):
+            at = len(prompts[s]) + i
+            if clear[s][at]:
+                assert np.abs(np.asarray(logits[s]) - want[s][at]).max() \
+                    <= LOGIT_ATOL, (s, at)
+            else:
+                left_out += 1
+        assert int(counters["router_tokens"]) == 3 * 2   # frozen: not routed
+        ts = jnp.where(done, ts, ts + 1)
+    assert left_out <= 3
+    # the frozen slot's pages were never written
+    np.testing.assert_array_equal(np.asarray(arena[:, 0, own]), frozen_rows)
+
+
+def test_prefill_after_a_prefix_hit_matches_a_cold_prefill(params):
+    """The warm branch (attention over the gathered page row): the last
+    logits of a prompt whose first two pages are already cached."""
+    bs = 4
+    kv = SlotKVCache(CFG, 2, 32, jnp.float32, block_size=bs,
+                     prefix_cache=False)
+    prompt = tokens_of(30, 13)
+    for s in (kv.alloc(), kv.alloc()):
+        kv.map_slot(s, prompt, 20, register=False)
+    cold, arena = _prefill(params, kv.arena, kv, 0, prompt, 16)
+    # slot 1: the first 8 positions through one prefill, the rest warm
+    _, arena = _prefill(params, arena, kv, 1, prompt[:8], 8)
+    warm, arena = _prefill(params, arena, kv, 1, prompt, 8, pfx_len=8)
+    assert np.abs(warm - cold).max() <= LOGIT_ATOL
+    assert np.abs(cold - reference_logits(params, prompt)[-1]).max() \
+        <= LOGIT_ATOL
+
+
+def _engine(params, **kw):
+    kw = dict(dict(num_slots=3, prefill_buckets=(8, 16), max_len=48,
+                   block_size=4, decode_chunk=4), **kw)
+    return ServingEngine(params, CFG, ServingConfig(**kw))
+
+
+def test_engine_serves_moonlight_and_counts_without_another_sync(
+        params, monkeypatch):
+    """The normal path: ServingEngine over the Moonlight tree, greedy
+    tokens the reference's best at every step, the counters exact, and
+    their fetch riding the fetches there already were: one device_get an
+    admission (with the first token) and one a collected chunk."""
+    eng = _engine(params)
+    fetches = []
+    real = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: fetches.append(1) or real(x))
+    prompts = [tokens_of(40 + i, n) for i, n in enumerate((5, 11, 7, 9))]
+    reqs = [eng.submit(p, 7) for p in prompts]
+    eng.run_until_drained()
+    stats = eng.stats()
+    assert stats["model"] == "Moonlight-16B-A3B"
+    assert stats["cache_row_bytes"] == CFG.row_width * 4
+    assert stats["decode_attention"] == "gather"        # the CPU
+    assert stats["compiled_executables"] == 2 + 2       # 2 buckets
+    assert len(fetches) == stats["prefills"] + stats["dispatches"]
+    tokens = sum(len(p) for p in prompts) + 4 * 6       # prefilled + decoded
+    n_moe, k = CFG.layers - CFG.first_k_dense, CFG.experts_per_tok
+    assert stats["router_tokens"] == tokens * n_moe
+    assert sum(stats["expert_tokens"]) == tokens * n_moe * k
+    assert stats["decode_router_tokens"] == 4 * 6 * n_moe
+    assert stats["decode_moe_passes"] <= stats["dispatches"] * 4 * n_moe
+    for prompt, req in zip(prompts, reqs):
+        seq = list(prompt) + list(req.tokens)
+        rows = reference_logits(params, seq)[len(prompt) - 1:-1]
+        deficit = rows.max(-1) - rows[np.arange(7), req.tokens]
+        assert deficit.max() <= 2 * LOGIT_ATOL
+    eng.close()
+
+
+@pytest.mark.parametrize("option", [
+    dict(weight_dtype="int8"), dict(kv_dtype="int8"),
+    dict(max_adapters=2, adapter_rank=2), dict(speculate_k=2),
+    dict(mesh_shape=(2,)), dict(prefill_chunk=8)])
+def test_moonlight_engine_refuses_what_the_model_lacks(params, option):
+    with pytest.raises(ValueError, match="does not implement"):
+        _engine(params, **option)
+
+
+@pytest.mark.parametrize("where", ["norm", "router"])
+def test_the_tolerance_catches_bfloat16_where_float32_is_stated(
+        params, monkeypatch, where):
+    """The norm's statistics or the router's scores in bfloat16 move the
+    logits past LOGIT_ATOL: the comparison above would fail."""
+    if where == "norm":
+        def rms16(x, g, eps):
+            x16 = x.astype(jnp.bfloat16)
+            inv = jax.lax.rsqrt(jnp.mean(x16 * x16, -1, keepdims=True)
+                                + jnp.bfloat16(eps))
+            return (x16 * inv).astype(x.dtype) * g
+        monkeypatch.setattr(ml, "_rms", rms16)
+    else:
+        real = ml.route
+
+        def route16(cfg, lp, x):
+            picks, w = real(cfg, lp, x)
+            return picks, w.astype(jnp.bfloat16).astype(jnp.float32)
+        monkeypatch.setattr(ml, "route", route16)
+    seq = tokens_of(0, 40)
+    got = np.asarray(ml.forward_logits(params, CFG, jnp.asarray(seq)))
+    want = reference_logits(params, seq)
+    clear = clear_of_ties(params, seq)
+    assert np.abs(got - want)[clear].max() > 10 * LOGIT_ATOL

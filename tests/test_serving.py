@@ -2482,9 +2482,10 @@ def test_quantized_spec_stream_identity(trained):
 def test_quantized_config_validation(trained):
     """Unknown dtype strings raise at construction with a clear
     message (no silent fp32 fallback), the SlotKVCache rejects them
-    too, and the kv_dtype x speculate_k gate keys on the verify
-    kernel's published dequant coverage (QUANTIZED_KV_KERNELS) — strip
-    the verify kernel from it and the combination must refuse."""
+    too, and the engine's gates key on the features the served model
+    DECLARES (serving.model.require_features) — strip the verify pass
+    from the GPT model's features and kv_dtype x speculate_k must
+    refuse."""
     cfg, _ = trained
     with pytest.raises(ValueError, match="weight_dtype"):
         make_engine(trained, weight_dtype="int4")
@@ -2492,10 +2493,10 @@ def test_quantized_config_validation(trained):
         make_engine(trained, kv_dtype="fp8")
     with pytest.raises(ValueError, match="kv_dtype"):
         SlotKVCache(cfg, 2, 32, kv_dtype="int4")
-    covered = gd.QUANTIZED_KV_KERNELS
+    model = gd.GPT_SERVING_MODEL
+    covered = model.features
     try:
-        gd.QUANTIZED_KV_KERNELS = tuple(
-            k for k in covered if k != "gpt_decode_verify_pages")
+        model.features = covered - {"speculation"}
         with pytest.raises(ValueError, match="verify"):
             make_engine(trained, speculate_k=2, **QUANT)
         # without speculation the verify kernel is never entered, so
@@ -2503,7 +2504,7 @@ def test_quantized_config_validation(trained):
         eng = make_engine(trained, **QUANT)
         eng.close()
     finally:
-        gd.QUANTIZED_KV_KERNELS = covered
+        model.features = covered
 
 
 def test_quantized_byte_accounting_and_gauges(trained):
