@@ -22,11 +22,16 @@ and fused chunk loop as the GPT family:
     `n_routed_experts` SwiGLU experts (sigmoid scores in float32, the
     picks by score + correction bias, the weights by score alone,
     normalised and scaled) plus one shared SwiGLU. The expert product is
-    GROUPED: tokens sorted by expert, one `jax.lax.ragged_dot` per weight
-    over the rows that were routed (tokens x experts_per_tok of them, no
-    capacity, none dropped, never every expert on every token). On the
-    TPU `ragged_dot` compiles to one native call whose FLOPs are the
-    useful ones (a compile for a described v5e: PERF.md, PR 27).
+    GROUPED: tokens sorted by expert, the SwiGLU over the rows that were
+    routed (tokens x experts_per_tok of them, no capacity, none dropped,
+    never every expert on every token). On a TPU it is ONE kernel a
+    layer, ops/grouped_swiglu: gate, up, `silu(g) * u` and down per
+    expert, the weights read where they lie, an expert with no row never
+    fetched; its row tile is chosen there from the static row count (16
+    rows for a decode step's 192, 128 for a prompt's thousands: PERF.md,
+    PR 28). Elsewhere (the CPU) it is one `jax.lax.ragged_dot` per
+    weight; `expert_product_path` says which, and the in-graph counter
+    `moe_kernel_passes` counts the layers that ran the kernel.
 
 Parameters (`x @ W`, W is (in, out); no bias anywhere):
   wte (V, h), head (h, V), norm_f (h,), layers[i]:
@@ -52,7 +57,8 @@ from .gpt_decode import _gather_pages, _write_pages
 __all__ = ["MoonlightConfig", "init_params", "forward_logits",
            "prefill_pages", "decode_step_pages", "decode_chunk_pages",
            "decode_attention_path", "absorbed_attention", "route",
-           "grouped_experts", "rope", "MOONLIGHT_SERVING_MODEL"]
+           "grouped_experts", "expert_product_path", "rope",
+           "MOONLIGHT_SERVING_MODEL"]
 
 _LANES = 128
 
@@ -288,12 +294,28 @@ def route(cfg, lp, x):
     return picks.astype(jnp.int32), w
 
 
+def expert_product_path(lp):
+    """ "grouped_swiglu_kernel" on a TPU for lane-aligned widths;
+    "ragged_dot" elsewhere (the CPU)."""
+    import jax
+    _, h, F = lp["w_gate"].shape
+    if h % _LANES == 0 and F % _LANES == 0 \
+            and jax.default_backend() == "tpu":
+        return "grouped_swiglu_kernel"
+    return "ragged_dot"
+
+
 def grouped_experts(lp, xs, group_sizes):
     """The grouped SwiGLU: xs (R, h) rows sorted by expert, group_sizes
     (E,) how many rows each expert has (rows past their sum are
-    nobody's and come back zero). Three ragged products over the routed
-    rows alone."""
+    nobody's and come back zero), over the routed rows alone. On a TPU
+    one kernel (ops/grouped_swiglu, which picks its row tile from the
+    static R); elsewhere three ragged products."""
     import jax
+    if expert_product_path(lp) == "grouped_swiglu_kernel":
+        from ..ops.grouped_swiglu import grouped_swiglu
+        return grouped_swiglu(xs, lp["w_gate"], lp["w_up"], lp["w_down"],
+                              group_sizes)
     g = jax.lax.ragged_dot(xs, lp["w_gate"], group_sizes)
     u = jax.lax.ragged_dot(xs, lp["w_up"], group_sizes)
     return jax.lax.ragged_dot(jax.nn.silu(g) * u, lp["w_down"], group_sizes)
@@ -327,10 +349,13 @@ def _moe(cfg, lp, x, live):
         back = jnp.where(live[:, None, None], back, 0)
         y = jnp.einsum("tkh,tk->th", back.astype(jnp.float32), w)
         y = (y + shared.astype(jnp.float32)).astype(x.dtype)
+    passes = jnp.any(live).astype(jnp.int32)
+    kernel = expert_product_path(lp) == "grouped_swiglu_kernel"
     counters = {"expert_tokens": group_sizes,
                 "router_tokens": jnp.sum(live).astype(jnp.int32),
                 "experts_touched": jnp.sum(group_sizes > 0).astype(jnp.int32),
-                "moe_passes": jnp.any(live).astype(jnp.int32)}
+                "moe_passes": passes,
+                "kernel_passes": passes if kernel else jnp.zeros_like(passes)}
     return y, counters
 
 
@@ -351,7 +376,8 @@ def _zero_counters(cfg):
     return {"expert_tokens": jnp.zeros((cfg.n_routed_experts,), jnp.int32),
             "router_tokens": jnp.zeros((), jnp.int32),
             "experts_touched": jnp.zeros((), jnp.int32),
-            "moe_passes": jnp.zeros((), jnp.int32)}
+            "moe_passes": jnp.zeros((), jnp.int32),
+            "kernel_passes": jnp.zeros((), jnp.int32)}
 
 
 def _head(cfg, params, x):
@@ -610,10 +636,14 @@ class _MoonlightServingModel(ServingModel):
         # tokens routed (each layer counts), by both programs since
         # start; the decode_* three by the decode chunk alone: tokens
         # routed, experts that had a row, and passes of an expert layer
-        # with a live slot (what a step's expert bytes are counted from)
+        # with a live slot (what a step's expert bytes are counted from);
+        # moe_kernel_passes: passes of an expert layer, a prefill's six
+        # and a decode step's, whose product was the grouped kernel (0
+        # where `ragged_dot` ran: every backend but the TPU)
         return {"expert_tokens": (cfg.n_routed_experts,),
                 "router_tokens": (), "decode_router_tokens": (),
-                "decode_experts_touched": (), "decode_moe_passes": ()}
+                "decode_experts_touched": (), "decode_moe_passes": (),
+                "moe_kernel_passes": ()}
 
     def prefill(self, params, cfg, tokens, pfx_len, real_len, arena, pages,
                 adapters=None, adapter_id=None):
@@ -625,7 +655,8 @@ class _MoonlightServingModel(ServingModel):
             "expert_tokens": c["expert_tokens"],
             "router_tokens": c["router_tokens"],
             "decode_router_tokens": zero, "decode_experts_touched": zero,
-            "decode_moe_passes": zero}
+            "decode_moe_passes": zero,
+            "moe_kernel_passes": c["kernel_passes"]}
 
     def decode_chunk(self, *args, **kw):
         out = decode_chunk_pages(*args, **kw)
@@ -635,7 +666,8 @@ class _MoonlightServingModel(ServingModel):
             "router_tokens": c["router_tokens"],
             "decode_router_tokens": c["router_tokens"],
             "decode_experts_touched": c["experts_touched"],
-            "decode_moe_passes": c["moe_passes"]},)
+            "decode_moe_passes": c["moe_passes"],
+            "moe_kernel_passes": c["kernel_passes"]},)
 
 
 MOONLIGHT_SERVING_MODEL = _MoonlightServingModel()

@@ -202,6 +202,28 @@ def test_no_dense_product_and_no_dropped_token(params, monkeypatch):
     assert seen == [(T * k, T * k)] * (CFG.layers - CFG.first_k_dense)
 
 
+def test_the_expert_kernel_is_the_same_layer_and_is_counted(
+        params, monkeypatch):
+    """The expert layer with the grouped kernel as its product (the
+    TPU's path, interpreted here) against the `ragged_dot` fallback on
+    the same tokens, two of them not live: the same output, and
+    `kernel_passes` counts the pass on the one and not on the other."""
+    lp = params["layers"][1]
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.normal(0, 1, (21, CFG.hidden)), jnp.float32)
+    live = jnp.arange(21) < 19
+    assert ml.expert_product_path(lp) == "ragged_dot"       # the CPU
+    want, fallback = ml._moe(CFG, lp, x, live)
+    monkeypatch.setattr(ml, "expert_product_path",
+                        lambda lp: "grouped_swiglu_kernel")
+    got, kernel = ml._moe(CFG, lp, x, live)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert int(kernel["kernel_passes"]) == int(kernel["moe_passes"]) == 1
+    assert int(fallback["kernel_passes"]) == 0
+    np.testing.assert_array_equal(kernel["expert_tokens"],
+                                  fallback["expert_tokens"])
+
+
 def test_absorbed_step_matches_expanded(params):
     """For one query against the same cached rows: the absorbed score
     q_lat . c + q_rope . k_rope and context (sum p c) W_UV equal the
@@ -400,6 +422,7 @@ def test_engine_serves_moonlight_and_counts_without_another_sync(
     assert sum(stats["expert_tokens"]) == tokens * n_moe * k
     assert stats["decode_router_tokens"] == 4 * 6 * n_moe
     assert stats["decode_moe_passes"] <= stats["dispatches"] * 4 * n_moe
+    assert stats["moe_kernel_passes"] == 0       # the CPU: `ragged_dot` ran
     for prompt, req in zip(prompts, reqs):
         seq = list(prompt) + list(req.tokens)
         rows = reference_logits(params, seq)[len(prompt) - 1:-1]
