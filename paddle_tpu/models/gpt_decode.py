@@ -21,11 +21,11 @@ creates, so a trained static-graph model generates without any export
 step. Forward math mirrors models/gpt.py exactly (pre-LN, separate
 q/k/v, tanh gelu, tied wte head, f32 LN stats).
 
-The serving chunk kernels additionally support SPECULATIVE DECODING
-(speculate_k > 0): a carried per-slot n-gram drafter proposes k tokens,
-one gpt_decode_verify_{slots,pages} pass scores them all, and in-graph
-exact-match acceptance commits 1..k+1 tokens per model pass without
-changing any stream (see _spec_step).
+The engine serves this family through the paged forms below
+(gpt_prefill_pages, gpt_decode_step_pages, gpt_decode_verify_pages): one
+prefill, one decode step and one multi-position verify pass over a block
+arena. The fused chunk loop around the step, its sampler, its finish rule
+and the speculative drafter are the engine's (serving/decode_loop.py).
 """
 
 from __future__ import annotations
@@ -35,16 +35,10 @@ from typing import Optional
 import numpy as np
 
 __all__ = ["collect_gpt_params", "quantize_params", "gpt_forward_logits",
-           "gpt_prefill",
-           "gpt_prefill_padded", "gpt_decode_step", "gpt_decode_step_slots",
-           "gpt_decode_chunk_slots", "gpt_prefill_pages",
-           "gpt_prefill_chunk_pages",
-           "gpt_decode_step_pages", "gpt_decode_chunk_pages",
-           "paged_arena_shapes", "decode_attention_path",
-           "gpt_decode_verify_slots", "gpt_decode_verify_pages",
-           "spec_ngram_seed", "gpt_generate", "ADAPTER_PROJECTIONS",
-           "GPT_SERVING_MODEL",
-           "threefry2x32", "sample_key", "sample_split", "sample_gumbel"]
+           "gpt_prefill", "gpt_decode_step", "gpt_prefill_pages",
+           "gpt_decode_step_pages", "gpt_decode_verify_pages",
+           "paged_arena_shapes", "decode_attention_path", "gpt_generate",
+           "ADAPTER_PROJECTIONS", "GPT_SERVING_MODEL"]
 
 # projections the low-rank adapter path covers (every matmul in the
 # block: attention q/k/v/out + both MLP projections)
@@ -231,11 +225,9 @@ def gpt_forward_logits(params, cfg, tokens):
 
 
 def _prefill_blocks(params, cfg, tokens, max_len):
-    """Shared prefill body: run the whole (possibly padded) prompt through
-    every block, filling the KV cache. Returns (hidden states (b, P, h)
-    BEFORE the final LN, cache). Both prefill entry points ride this one
-    loop so their math can never diverge — the serving path's token-parity
-    guarantee depends on it."""
+    """The sequential prefill's body: run the whole prompt through every
+    block, filling the KV cache. Returns (hidden states (b, P, h) BEFORE
+    the final LN, cache)."""
     import jax.numpy as jnp
 
     b, p_len = tokens.shape
@@ -283,27 +275,6 @@ def gpt_prefill(params, cfg, tokens, max_len):
     return _head_logits(params, x[:, -1:]), cache
 
 
-def gpt_prefill_padded(params, cfg, tokens, real_len, max_len):
-    """Prefill a RIGHT-PADDED prompt (the serving scheduler's bucketed
-    shapes): tokens (b, L_bucket) int32 padded past the real prompt,
-    real_len (b,) traced actual lengths. Returns (logits at position
-    real_len-1 (b, V) f32, cache (layers, 2, b, heads, max_len, head_dim))
-    with K/V rows [0, L_bucket) written.
-
-    Why the padding is safe: the causal mask keeps every real query
-    position inside the real prefix, and the pad rows the prefill leaves
-    at [real_len, L_bucket) are overwritten by the decode steps at those
-    positions BEFORE any step's [0, t] attention window reaches them —
-    decode at absolute position t writes row t and reads rows <= t only."""
-    import jax.numpy as jnp
-
-    x, cache = _prefill_blocks(params, cfg, tokens, max_len)
-    b = tokens.shape[0]
-    # the last REAL position per row, not the last padded one
-    last = x[jnp.arange(b), real_len - 1][:, None]
-    return _head_logits(params, last), cache
-
-
 def gpt_decode_step(params, cfg, token, cache, t):
     """One cached decode step. token: (b,) int32, t: traced scalar index
     of the ABSOLUTE position being computed. Returns (logits (b, V) f32,
@@ -343,114 +314,32 @@ def gpt_decode_step(params, cfg, token, cache, t):
     return _head_logits(params, x), cache
 
 
-def gpt_decode_step_slots(params, cfg, tokens, cache, ts):
-    """One cached decode step over the SLOT dimension (continuous
-    batching): every slot advances at its OWN absolute position. tokens:
-    (S,) int32, ts: (S,) int32 per-slot positions, cache: (layers, 2, S,
-    heads, max_len, head_dim). Returns (logits (S, V) f32, updated cache).
-
-    Per-slot math is exactly gpt_decode_step's — the shared-t
-    dynamic_update_slice becomes a per-row scatter at ts[s] and the
-    [0, t] attention window becomes a per-row mask — so a slot's logits
-    match what the same sequence produces on the sequential path.
-    Retired/free slots may keep stepping harmlessly: their writes land at
-    a stale position that admission's prefill overwrites before any
-    future attention window reads it."""
-    import jax.numpy as jnp
-
-    heads = cfg.heads
-    hd = cfg.hidden // cfg.heads
-    max_len = cache.shape[4]
-    s_dim = tokens.shape[0]
-    dtype = cache.dtype
-    rows = jnp.arange(s_dim)
-    x = (params["wte"][tokens] + params["wpe"][ts]).astype(dtype)[:, None]
-    pos_mask = (jnp.arange(max_len)[None, :] <= ts[:, None])   # [S, L]
-    for li, blk in enumerate(params["blocks"]):
-        h = _ln(x, blk["ln1"])
-        q = _dense(h, blk["q"]).reshape(s_dim, heads, 1, hd)
-        k = _dense(h, blk["k"]).reshape(s_dim, heads, hd)
-        v = _dense(h, blk["v"]).reshape(s_dim, heads, hd)
-        cache = cache.at[li, 0, rows, :, ts, :].set(k)
-        cache = cache.at[li, 1, rows, :, ts, :].set(v)
-        K, V = cache[li, 0], cache[li, 1]          # (S, n, L, hd)
-        scores = jnp.einsum("bnqd,bnkd->bnqk", q, K,
-                            preferred_element_type=jnp.float32)
-        scores = jnp.where(pos_mask[:, None, None, :],
-                           scores / np.sqrt(hd), -1e30)
-        probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
-        probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
-        ctx = jnp.einsum("bnqk,bnkd->bnqd", probs, V)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(s_dim, 1, -1)
-        x = x + _dense(ctx, blk["out"])
-        h = _ln(x, blk["ln2"])
-        x = x + _dense(_gelu_tanh(_dense(h, blk["mlp1"])), blk["mlp2"])
-    return _head_logits(params, x), cache
-
-
-def gpt_decode_verify_slots(params, cfg, toks, cache, ts):
-    """Multi-position decode step over the slot dim — the speculative
+def gpt_decode_verify_pages(params, cfg, toks, arena, pt, ts, done=None,
+                            adapters=None, adapter_ids=None):
+    """Multi-position decode step over the paged pool — the speculative
     VERIFY pass. toks: (S, D) int32 candidate tokens at absolute
     positions ts..ts+D-1 per slot (column 0 is each slot's committed
     current token, columns 1.. the drafter's proposals). One batched
-    pass writes all D K/V rows and returns logits for EVERY position —
-    (S, D, V) f32 — so one model dispatch scores the whole draft run
-    instead of D sequential steps.
+    pass writes all D K/V rows through the page table and returns logits
+    for EVERY position — (S, D, V) f32 — so one model dispatch scores
+    the whole draft run instead of D sequential steps.
 
     Causality inside the window: the query at offset j attends
     [0, ts+j], and rows ts..ts+j are written THIS pass before the
     layer's attention gather — so a previous pass's rejected-tail rows
-    in [ts, ts+D) are always rewritten before anything reads them
-    (the write-pointer "rewind" is implicit in re-verifying from the
-    committed position). Writes past max_len are dropped by the
-    scatter; the budget mask never commits tokens there. Per-position
-    math is gpt_decode_step_slots's row-for-row: D=1 is exactly that
-    kernel."""
-    import jax.numpy as jnp
+    in [ts, ts+D) are always rewritten before anything reads them (the
+    write-pointer "rewind" is implicit in re-verifying from the
+    committed position). Per-position math is gpt_decode_step_pages'
+    row-for-row: D=1 is exactly that step on the gather path.
 
-    heads = cfg.heads
-    hd = cfg.hidden // cfg.heads
-    max_len = cache.shape[4]
-    s_dim, D = toks.shape
-    dtype = cache.dtype
-    rows = jnp.arange(s_dim)[:, None]
-    pos = ts[:, None] + jnp.arange(D)[None, :]           # (S, D)
-    x = (params["wte"][toks] + params["wpe"][pos]).astype(dtype)
-    pos_mask = (jnp.arange(max_len)[None, None, :] <= pos[:, :, None])
-    for li, blk in enumerate(params["blocks"]):
-        h = _ln(x, blk["ln1"])
-        q = _dense(h, blk["q"]).reshape(s_dim, D, heads, hd)
-        k = _dense(h, blk["k"]).reshape(s_dim, D, heads, hd)
-        v = _dense(h, blk["v"]).reshape(s_dim, D, heads, hd)
-        cache = cache.at[li, 0, rows, :, pos, :].set(k)
-        cache = cache.at[li, 1, rows, :, pos, :].set(v)
-        K, V = cache[li, 0], cache[li, 1]          # (S, n, L, hd)
-        scores = jnp.einsum("bqnd,bnkd->bnqk", q, K,
-                            preferred_element_type=jnp.float32)
-        scores = jnp.where(pos_mask[:, None, :, :],
-                           scores / np.sqrt(hd), -1e30)
-        probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
-        probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
-        ctx = jnp.einsum("bnqk,bnkd->bqnd", probs, V).reshape(s_dim, D, -1)
-        x = x + _dense(ctx, blk["out"])
-        h = _ln(x, blk["ln2"])
-        x = x + _dense(_gelu_tanh(_dense(h, blk["mlp1"])), blk["mlp2"])
-    x = _ln(x, params["lnf"])
-    return (x @ params["wte"].T.astype(x.dtype)).astype(jnp.float32), cache
-
-
-def gpt_decode_verify_pages(params, cfg, toks, arena, pt, ts, done=None,
-                            adapters=None, adapter_ids=None):
-    """gpt_decode_verify_slots over the PAGED pool: the D per-slot K/V
-    writes go through the page table, and two redirects keep the arena
-    sound — `done` slots write the reserved scratch block (the frozen-
-    slot discipline: a retired slot's reallocated blocks must never be
-    dirtied by its ride-along verify), and positions whose page index
-    runs past the page row land in scratch too (draft overshoot past a
-    sequence's allocated tail, same rule as gpt_prefill_pages' pad
-    writes). Candidates at such positions read garbage and are never
-    committed — the budget mask stops strictly before the allocated
-    region ends."""
+    Two redirects keep the arena sound — `done` slots write the
+    reserved scratch block (the frozen-slot discipline: a retired slot's
+    reallocated blocks must never be dirtied by its ride-along verify),
+    and positions whose page index runs past the page row land in
+    scratch too (draft overshoot past a sequence's allocated tail, same
+    rule as gpt_prefill_pages' pad writes). Candidates at such positions
+    read garbage and are never committed — the budget mask stops
+    strictly before the allocated region ends."""
     import jax.numpy as jnp
 
     heads = cfg.heads
@@ -493,219 +382,6 @@ def gpt_decode_verify_pages(params, cfg, toks, arena, pt, ts, done=None,
                          blk["mlp2"], la["mlp2"])
     x = _ln(x, params["lnf"])
     return (x @ params["wte"].T.astype(x.dtype)).astype(jnp.float32), arena
-
-
-def _ngram_hash(a, b, size):
-    """Hash a 2-token drafter context into [0, size). Deterministic in
-    the token ids; collisions only cost acceptance rate, never
-    correctness — every draft is verified by the target model."""
-    import jax.numpy as jnp
-    ua = a.astype(jnp.uint32) * jnp.uint32(2654435761)
-    ub = b.astype(jnp.uint32) * jnp.uint32(40503)
-    return ((ua ^ ub) % jnp.uint32(size)).astype(jnp.int32)
-
-
-def spec_ngram_seed(table, slot, tokens, real_len):
-    """Reset one slot's drafter row and seed it with the prompt's
-    trigram statistics: context (tokens[j-2], tokens[j-1]) predicts
-    tokens[j] for every real j — prompt-lookup decoding's free lunch on
-    repetitive/structured text. tokens: (B,) int32 right-padded prompt
-    suffix; real_len: traced scalar count of real entries. table:
-    (S, T+1) int32 where column T is the trash column masked writes
-    land in and -1 marks "no prediction". The RESET is what matters for
-    hygiene: slot reuse must not draft from the previous occupant's
-    stream (drafts are verified, so stale entries could never corrupt
-    tokens — but acceptance stats must be a function of THIS request
-    alone)."""
-    import jax.numpy as jnp
-    B = tokens.shape[0]
-    size = table.shape[1] - 1
-    table = table.at[slot].set(-1)
-    if B < 3:
-        return table
-    idx = _ngram_hash(tokens[:-2], tokens[1:-1], size)   # (B-2,)
-    idx = jnp.where(jnp.arange(2, B) < real_len, idx, size)
-    return table.at[slot, idx].set(tokens[2:])
-
-
-def _spec_step(verify, sample_fn, temps, eos_ids, speculate_k, carry):
-    """One draft -> verify -> accept iteration of the speculative chunk
-    loop, shared by the slab and paged kernels. carry = (tok, pool, ts,
-    keys, done, rem, prev, table); verify(inputs (S, k+1), pool, ts,
-    done) -> (logits (S, k+1, V), pool). Returns (carry', (out_tokens
-    (k+1, S), counts (S,))).
-
-    Acceptance is EXACT-MATCH against what the sampler itself produces:
-    candidate j is sample_fn(key_j, logits_j, temp) where the key chain
-    advances one split per candidate — precisely the sequential
-    schedule — and logits_j are conditioned on the committed stream
-    only while every draft before j matched. So each committed token
-    equals, bit for bit, what the non-speculative path would have
-    emitted with the same seed: the drafter changes WHEN tokens arrive
-    (how many commit per model pass), never WHICH. Greedy is the
-    temp=0 special case (candidates are argmax rows).
-
-    EOS/budget stops are applied inside the accepted run with the
-    host's exact finish rule, so the committed run always ends at the
-    finish token; frozen slots re-emit their token with count 1 and
-    advance their key chain by one split — the non-speculative
-    ride-along cadence."""
-    import jax
-    import jax.numpy as jnp
-
-    k = int(speculate_k)
-    tok, pool, ts, keys, done, rem, prev, table = carry
-    s_dim = tok.shape[0]
-    rows = jnp.arange(s_dim)
-    size = table.shape[1] - 1
-    # draft: k chained trigram lookups; a miss (-1) proposes token 0 —
-    # shapes are fixed, so a hopeless draft costs nothing extra
-    drafts = []
-    a, b = prev, tok
-    for _ in range(k):
-        d = table[rows, _ngram_hash(a, b, size)]
-        d = jnp.where(d < 0, 0, d)
-        drafts.append(d)
-        a, b = b, d
-    inputs = jnp.stack([tok] + drafts, axis=1)           # (S, k+1)
-    logits, pool = verify(inputs, pool, ts, done)
-    cands, chain, cur = [], [keys], keys
-    for j in range(k + 1):
-        cj, cur = jax.vmap(sample_fn)(cur, logits[:, j], temps)
-        cands.append(cj)
-        chain.append(cur)
-    cands = jnp.stack(cands, axis=1)                     # (S, k+1)
-    chain = jnp.stack(chain, axis=1)                     # (S, k+2, key)
-    dr = jnp.stack(drafts, axis=1)                       # (S, k)
-    # candidate j is valid only while drafts 0..j-1 all matched (its
-    # logits saw the committed stream); the mask is monotone by cumprod
-    lead = jnp.cumprod((cands[:, :k] == dr).astype(jnp.int32), axis=1)
-    base = jnp.concatenate(
-        [jnp.ones((s_dim, 1), bool), lead.astype(bool)], axis=1)
-    jj = jnp.arange(k + 1)[None, :]
-    stop = (cands == eos_ids[:, None]) | (rem[:, None] - (jj + 1) <= 0)
-    stopped_before = jnp.concatenate(
-        [jnp.zeros((s_dim, 1), bool),
-         jnp.cumsum(stop.astype(jnp.int32), axis=1)[:, :-1] > 0], axis=1)
-    can = base & ~stopped_before             # monotone commit mask
-    c = can.sum(axis=1).astype(jnp.int32)    # >= 1: j=0 always commits
-    live = ~done
-    last = cands[rows, c - 1]
-    prev_commit = jnp.where(c >= 2, cands[rows, jnp.maximum(c - 2, 0)],
-                            tok)
-    ndone = done | (can & stop).any(axis=1)
-    # n-gram table update: every committed token registered under its
-    # 2-token context (frozen slots and rejected tails -> trash column)
-    seq = jnp.concatenate([prev[:, None], tok[:, None], cands], axis=1)
-    idx = _ngram_hash(seq[:, :k + 1], seq[:, 1:k + 2], size)
-    idx = jnp.where(can & live[:, None], idx, size)
-    table = table.at[rows[:, None], idx].set(cands)
-    out = jnp.where(live[:, None],
-                    jnp.where(can, cands, last[:, None]), tok[:, None])
-    counts = jnp.where(live, c, 1)
-    keys = chain[rows, jnp.where(live, c, 1)]
-    tok = jnp.where(live, last, tok)
-    prev = jnp.where(live, prev_commit, prev)
-    ts = jnp.where(live, ts + c, ts)
-    rem = jnp.where(live, rem - c, rem)
-    return ((tok, pool, ts, keys, ndone, rem, prev, table),
-            (out.T, counts))
-
-
-def gpt_decode_chunk_slots(params, cfg, tokens, cache, ts, keys, temps,
-                           done, remaining, eos_ids, chunk,
-                           sample_fn=None, speculate_k=0,
-                           spec_state=None):
-    """Fused multi-token decode: `chunk` iterations of
-    gpt_decode_step_slots + per-slot sampling + in-graph EOS/budget
-    masking inside ONE lax.scan — a single dispatch (and a single host
-    fetch) emits a (chunk, S) token block, amortizing the per-step
-    Python + dispatch + sync cost by the chunk factor.
-
-    tokens/ts: (S,) int32 — the token each slot feeds next and its
-    absolute position. keys: (S, 2) per-slot PRNG keys. temps: (S,) f32.
-    done: (S,) bool — slots that must ride along FROZEN (finished, free,
-    or cancelled); a frozen slot re-emits its last token, never advances
-    ts, and decrements nothing. remaining: (S,) int32 tokens each slot
-    may still emit; a slot freezes in-graph the moment it emits its
-    eos_id (eos_ids: (S,) int32, -1 = no eos — sampled ids are always
-    >= 0 so -1 never matches) or its remaining budget hits zero, exactly
-    the scheduler's host-side finish rule — so the host can consume a
-    slot's column up to ITS OWN finish point and discard the frozen
-    repeats after it, and a chunked stream is token-identical to the
-    per-step path whatever the chunk size.
-
-    A frozen slot's ride-along decode still rewrites row ts of its OWN
-    cache slot (same stale-row discipline as free slots in
-    gpt_decode_step_slots: the next admission's prefill overwrites
-    before anything reads), and ts never reaches max_len: the engine
-    admits only prompt+max_new <= max_len, and the budget mask freezes
-    ts at p_len+max_new-1 at most.
-
-    sample_fn(key, logits_row, temp) -> (token, key_next) is traced
-    per-slot (the serving scheduler passes its temperature/top-k
-    sampler); None means greedy argmax. Keys advance every iteration for
-    every slot — frozen slots included — mirroring the per-step path's
-    whole-pool vmap so per-request streams stay identical across chunk
-    sizes (a request's key is re-seeded at admission anyway).
-
-    Returns (block (chunk, S) int32 — iteration-major, so block[i, s] is
-    slot s's i-th in-chunk token — tokens, cache, ts, keys, done,
-    remaining), the post-chunk carry the next dispatch resumes from.
-
-    SPECULATIVE MODE (speculate_k > 0): each scan iteration becomes a
-    draft -> verify -> accept pass — the per-slot n-gram drafter in
-    spec_state = (prev (S,) int32 previous committed token, table
-    (S, T+1) int32 trigram table; see spec_ngram_seed) proposes
-    speculate_k tokens, ONE gpt_decode_verify_slots pass scores every
-    draft position, and in-graph exact-match acceptance (_spec_step)
-    commits the matched run plus one corrected token — between 1 and
-    speculate_k+1 tokens per model pass, streams bit-identical to
-    speculate_k=0 at every chunk size. The return shape changes to
-    (block (chunk, speculate_k+1, S), counts (chunk, S), tokens, cache,
-    ts, keys, done, remaining, spec_state): block[i, :counts[i, s], s]
-    are slot s's committed tokens of pass i, entries past the count
-    are frozen repeats the host discards.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    if sample_fn is None:
-        def sample_fn(key, logits, temp):
-            return jnp.argmax(logits, -1).astype(jnp.int32), key
-
-    if int(speculate_k) > 0:
-        prev, table = spec_state
-
-        def verify(inputs, cache, ts, done):
-            return gpt_decode_verify_slots(params, cfg, inputs, cache,
-                                           ts)
-
-        def body(carry, _):
-            return _spec_step(verify, sample_fn, temps, eos_ids,
-                              speculate_k, carry)
-
-        carry = (tokens, cache, ts, keys, done, remaining, prev, table)
-        (tokens, cache, ts, keys, done, remaining, prev, table), \
-            (block, counts) = jax.lax.scan(body, carry, None,
-                                           length=int(chunk))
-        return (block, counts, tokens, cache, ts, keys, done, remaining,
-                (prev, table))
-
-    def body(carry, _):
-        tok, cache, ts, keys, done, rem = carry
-        logits, cache = gpt_decode_step_slots(params, cfg, tok, cache, ts)
-        nxt, keys = jax.vmap(sample_fn)(keys, logits, temps)
-        emit = jnp.where(done, tok, nxt)
-        rem = jnp.where(done, rem, rem - 1)
-        ndone = done | (emit == eos_ids) | (rem <= 0)
-        ts = jnp.where(done, ts, ts + 1)
-        return (emit, cache, ts, keys, ndone, rem), emit
-
-    (tokens, cache, ts, keys, done, remaining), block = jax.lax.scan(
-        body, (tokens, cache, ts, keys, done, remaining), None,
-        length=int(chunk))
-    return block, tokens, cache, ts, keys, done, remaining
 
 
 def paged_arena_shapes(layers, num_blocks, heads, block_size, hd):
@@ -890,9 +566,15 @@ def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
 
     tokens: (1, B) int32 suffix, right-padded to a shape bucket.
     pfx_len: traced scalar — how many leading prompt positions are
-    ALREADY resident in this sequence's blocks (prefix-cache hits,
-    always a multiple of the block size; 0 = cold prompt, which makes
-    this exactly a paged gpt_prefill_padded). real_len: traced scalar,
+    ALREADY resident in this sequence's blocks: a prefix-cache hit (a
+    multiple of the block size; 0 = cold prompt) or, under chunked
+    prefill, the previous chunk's fill frontier, an ARBITRARY position
+    (enqueued-in-order dispatches make the earlier rows resident
+    without a sync). The per-position math does not depend on where the
+    suffix starts, so a suffix run as N chunks leaves the same K/V rows,
+    and on its final chunk the same last-position logits, as one
+    dispatch: what keeps chunked streams token-identical to
+    prefill_chunk=None. real_len: traced scalar,
     the real (unpadded) suffix length, >= 1 — admission never shares
     the block holding position p_len-1, so the last prompt position is
     always computed here and the first-token logits need no cached
@@ -917,47 +599,6 @@ def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
     every projection gathers its A/B rows and adds the low-rank delta
     (id 0 selects the base output bit-exactly), so the prompt's K/V
     rows are computed under the same adapter the decode path serves."""
-    return _prefill_pages_body(params, cfg, tokens, pfx_len, real_len,
-                               arena, pages, adapters, adapter_id)
-
-
-def gpt_prefill_chunk_pages(params, cfg, tokens, start_pos, real_len,
-                            arena, pages, adapters=None,
-                            adapter_id=None):
-    """Budget-bounded CHUNKED-PREFILL pass: process up to B suffix
-    tokens of ONE sequence's prompt starting at absolute position
-    `start_pos`, attending over everything already resident in its
-    arena blocks through the page row (vLLM/Sarathi-style chunked
-    prefill, so a long prompt never monopolizes the device in one
-    dispatch).
-
-    Identical math to gpt_prefill_pages with one contract relaxed:
-    `start_pos` is an ARBITRARY absolute position — the previous
-    chunk's fill frontier — not a block-aligned prefix-cache hit
-    length. Positions [0, start_pos) must already be resident (earlier
-    chunks and/or shared prefix blocks; enqueued-in-order dispatches
-    satisfy this without a sync), rows [start_pos, start_pos+real_len)
-    are written through the page row exactly as the monolithic kernel
-    writes them, and pad rows land in scratch. Because the per-position
-    math is gpt_prefill_pages' row-for-row, running a prompt suffix as
-    N chunks produces the same K/V rows — and the same last-position
-    logits on the final chunk — as one monolithic dispatch, which is
-    what keeps chunked streams token-identical to prefill_chunk=None.
-
-    Returns (logits of position start_pos+real_len-1, (1, V) f32,
-    arena) — only the FINAL chunk's logits are consumed (they seed the
-    first sampled token); earlier chunks' are dead values the scheduler
-    never fetches. Compiles once per CHUNK bucket, so chunking grows
-    the executable family by at most O(prefill buckets)."""
-    return _prefill_pages_body(params, cfg, tokens, start_pos, real_len,
-                               arena, pages, adapters, adapter_id)
-
-
-def _prefill_pages_body(params, cfg, tokens, pfx_len, real_len, arena,
-                        pages, adapters=None, adapter_id=None):
-    """Shared body of gpt_prefill_pages / gpt_prefill_chunk_pages: one
-    loop so the monolithic and chunked prefill math can never diverge
-    (the chunked path's token-parity guarantee depends on it)."""
     import jax.numpy as jnp
 
     heads, hd = cfg.heads, cfg.hidden // cfg.heads
@@ -1016,15 +657,19 @@ def decode_attention_path(arena, arena_constraint=None):
 
 def gpt_decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
                           adapters=None, adapter_ids=None,
-                          attention=None):
-    """gpt_decode_step_slots over a PAGED pool: per-slot K/V live in
-    arena blocks indirected through a page table instead of contiguous
-    slab rows. tokens/ts: (S,) int32, pt: (S, P) int32 page table,
-    arena: see paged_arena_shapes. Returns
-    (logits (S, V) f32, updated arena).
+                          arena_constraint=None):
+    """One cached decode step over the SLOT dimension of a paged pool
+    (continuous batching): every slot advances at its OWN absolute
+    position, its K/V in arena blocks indirected through a page table.
+    tokens/ts: (S,) int32, pt: (S, P) int32 page table, arena: see
+    paged_arena_shapes. Returns (logits (S, V) f32, updated arena).
 
-    The slab version's stale-row discipline does not survive paging —
-    a retired slot's blocks are REALLOCATED to other sequences, so a
+    Per-slot math is exactly gpt_decode_step's — the shared-t
+    dynamic_update_slice becomes a per-row write at ts[s] and the [0, t]
+    attention window a per-row mask — so a slot's logits match what the
+    same sequence produces on the sequential path.
+
+    A retired slot's blocks are REALLOCATED to other sequences, so a
     frozen slot riding along must not keep writing through its stale
     page row. `done` (S,) bool redirects frozen slots' K/V writes to
     the reserved scratch block 0 in-graph (their gathers still read
@@ -1037,11 +682,12 @@ def gpt_decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
     co-batched slots hit DIFFERENT adapters in this one dispatch
     (id 0 rows select the base output bit-exactly).
 
-    `attention` is decode_attention_path's verdict, "paged_kernel" or
-    "gather" (None: decided here from the arena; the chunk loop passes
-    its own, which also knows of a mesh constraint). The kernel reads
-    only live pages and gives a frozen slot zeros where the gather
-    gives it garbage; either way the host discards those logits."""
+    Which attention runs is decode_attention_path's verdict on the
+    arena and `arena_constraint` (the mesh plan's layout pin the chunk
+    loop applies, else None; only asked whether there is one). The
+    kernel reads only live pages and gives a frozen slot zeros where
+    the gather gives it garbage; either way the host discards those
+    logits."""
     import jax.numpy as jnp
 
     heads = cfg.heads
@@ -1050,8 +696,7 @@ def gpt_decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
     bs = data.shape[4]
     s_dim, P = pt.shape
     L = P * bs
-    if attention is None:
-        attention = decode_attention_path(arena)
+    attention = decode_attention_path(arena, arena_constraint)
     if attention == "paged_kernel":
         # imported where it is used: `import paddle_tpu` stays free of
         # Pallas for programs that never serve
@@ -1095,112 +740,6 @@ def gpt_decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
         x = x + _dense_a(_gelu_tanh(_dense_a(h, blk["mlp1"], la["mlp1"])),
                          blk["mlp2"], la["mlp2"])
     return _head_logits(params, x), arena
-
-
-def gpt_decode_chunk_pages(params, cfg, tokens, arena, pt, ts, keys,
-                           temps, done, remaining, eos_ids, chunk,
-                           sample_fn=None, speculate_k=0,
-                           spec_state=None, arena_constraint=None,
-                           adapters=None, adapter_ids=None):
-    """gpt_decode_chunk_slots over the paged pool: `chunk` iterations of
-    gpt_decode_step_pages + per-slot sampling + in-graph EOS/budget
-    masking in ONE lax.scan. Carry/masking semantics are identical to
-    the slab chunk kernel (frozen slots re-emit their last token, never
-    advance ts, keys advance every iteration for every slot), with one
-    paged addition: the done mask also redirects frozen slots' K/V
-    writes to the scratch block, so a retired slot's reallocated blocks
-    are never dirtied by its ride-along decode. The page table `pt`
-    ((S, P) int32) is read-only here — it changes only at admission.
-
-    Returns (block (chunk, S) int32, tokens, arena, ts, keys, done,
-    remaining).
-
-    SPECULATIVE MODE (speculate_k > 0): as in gpt_decode_chunk_slots —
-    each iteration drafts speculate_k tokens from the carried per-slot
-    n-gram table, verifies them in one gpt_decode_verify_pages pass
-    (frozen slots' AND past-the-page-row writes redirected to scratch),
-    and commits the accepted run + one corrected token in-graph.
-    Returns (block (chunk, speculate_k+1, S), counts (chunk, S),
-    tokens, arena, ts, keys, done, remaining, spec_state).
-
-    `arena_constraint` (tensor-parallel serving, else None): a
-    callable re-asserting the arena's mesh sharding, applied to the
-    scan carry at the top of every iteration so GSPMD keeps the
-    per-head block layout stable through the whole fused loop — one
-    sharded executable, no mid-scan resharding/all-gather of the
-    arena. Purely a layout pin: the computed values are unchanged.
-
-    QUANTIZED ARENA: `arena` may be the (int8 data, f32 scale plane)
-    pytree — the scan carries both leaves, every ride-along write
-    quantizes at the scatter and every page gather dequantizes
-    in-graph (see _kv_write/_kv_gather), and the frozen-slot scratch
-    redirect covers data AND scales. Streams from a quantized engine
-    are bit-identical to themselves across chunk sizes, preemption,
-    and mesh shapes — the same determinism contract as fp32, pinned
-    against its own quantized reference rather than the fp32 one.
-
-    ADAPTERS: `adapters`/`adapter_ids` (the LoRA pool + the (S,) int32
-    per-slot id vector from the decode carry) thread to every inner
-    step/verify pass — both are read-only through the scan (ids change
-    only at admission, exactly like the page table), so the fused loop
-    stays ONE executable however many distinct adapters the batch
-    mixes."""
-    import jax
-    import jax.numpy as jnp
-
-    if sample_fn is None:
-        def sample_fn(key, logits, temp):
-            return jnp.argmax(logits, -1).astype(jnp.int32), key
-
-    if int(speculate_k) > 0:
-        prev, table = spec_state
-
-        def verify(inputs, arena, ts, done):
-            if arena_constraint is not None:
-                arena = arena_constraint(arena)
-            return gpt_decode_verify_pages(params, cfg, inputs, arena,
-                                           pt, ts, done,
-                                           adapters=adapters,
-                                           adapter_ids=adapter_ids)
-
-        def body(carry, _):
-            return _spec_step(verify, sample_fn, temps, eos_ids,
-                              speculate_k, carry)
-
-        carry = (tokens, arena, ts, keys, done, remaining, prev, table)
-        (tokens, arena, ts, keys, done, remaining, prev, table), \
-            (block, counts) = jax.lax.scan(body, carry, None,
-                                           length=int(chunk))
-        return (block, counts, tokens, arena, ts, keys, done, remaining,
-                (prev, table))
-
-    attention = decode_attention_path(arena, arena_constraint)
-
-    def body(carry, _):
-        tok, arena, ts, keys, done, rem = carry
-        if arena_constraint is not None:
-            arena = arena_constraint(arena)
-        logits, arena = gpt_decode_step_pages(
-            params, cfg, tok, arena, pt, ts, done,
-            adapters=adapters, adapter_ids=adapter_ids,
-            attention=attention)
-        nxt, keys = jax.vmap(sample_fn)(keys, logits, temps)
-        emit = jnp.where(done, tok, nxt)
-        rem = jnp.where(done, rem, rem - 1)
-        ndone = done | (emit == eos_ids) | (rem <= 0)
-        ts = jnp.where(done, ts, ts + 1)
-        return (emit, arena, ts, keys, ndone, rem), emit
-
-    (tokens, arena, ts, keys, done, remaining), block = jax.lax.scan(
-        body, (tokens, arena, ts, keys, done, remaining), None,
-        length=int(chunk))
-    return block, tokens, arena, ts, keys, done, remaining
-
-
-# the serving sampler's PRNG lives with the engine (every serving model
-# shares it); re-exported for the callers that knew it here
-from ..serving.sampling import (sample_gumbel, sample_key,  # noqa: E402,F401
-                                sample_split, threefry2x32)
 
 
 def _sample(logits, key, temperature, top_k):
@@ -1311,17 +850,14 @@ class _GPTServingModel(ServingModel):
     def prefill(self, *args, **kw):
         return gpt_prefill_pages(*args, **kw) + (None,)
 
-    def prefill_chunk(self, *args, **kw):
-        return gpt_prefill_chunk_pages(*args, **kw) + (None,)
+    def decode_step(self, *args, **kw):
+        return gpt_decode_step_pages(*args, **kw) + (None,)
 
-    def decode_chunk(self, *args, **kw):
-        return gpt_decode_chunk_pages(*args, **kw) + (None,)
+    def verify(self, *args, **kw):
+        return gpt_decode_verify_pages(*args, **kw)
 
     def quantize_params(self, params, cfg):
         return quantize_params(params, cfg)
-
-    def spec_ngram_seed(self, table, slot, tokens, real_len):
-        return spec_ngram_seed(table, slot, tokens, real_len)
 
 
 GPT_SERVING_MODEL = _GPTServingModel()

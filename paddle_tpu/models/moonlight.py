@@ -2,7 +2,7 @@
 
 Multi-head LATENT attention and routed + shared experts, served through
 serving.model.ServingModel by the same engine, scheduler, block allocator
-and fused chunk loop as the GPT family:
+and fused chunk loop (serving/decode_loop.py) as the GPT family:
 
   * the cache holds ONE row a token a layer, `[c | k_rope]` AFTER the
     latent's norm and the key's rotation (kv_lora_rank + qk_rope_head_dim
@@ -55,7 +55,7 @@ from ..serving.model import CacheSpec, ServingModel
 from .gpt_decode import _gather_pages, _write_pages
 
 __all__ = ["MoonlightConfig", "init_params", "forward_logits",
-           "prefill_pages", "decode_step_pages", "decode_chunk_pages",
+           "prefill_pages", "decode_step_pages",
            "decode_attention_path", "absorbed_attention", "route",
            "grouped_experts", "expert_product_path", "rope",
            "MOONLIGHT_SERVING_MODEL"]
@@ -573,46 +573,6 @@ def decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
     return _head(cfg, params, x), arena, counters
 
 
-def decode_chunk_pages(params, cfg, tokens, arena, pt, ts, keys, temps,
-                       done, remaining, eos_ids, chunk, sample_fn=None,
-                       **unsupported):
-    """`chunk` iterations of decode_step_pages + per-slot sampling +
-    in-graph EOS/budget masking in ONE lax.scan: the GPT chunk loop's
-    carry, sampler and done mask (frozen slots re-emit their last token,
-    never advance, keys advance every iteration for every slot). Returns
-    (block (chunk, S) int32, tokens, arena, ts, keys, done, remaining,
-    counters summed over the chunk)."""
-    import jax
-    import jax.numpy as jnp
-
-    on = {k: v for k, v in unsupported.items()
-          if v is not None and v != 0}
-    if on:
-        raise NotImplementedError(
-            f"the Moonlight decode chunk has no {sorted(on)} path")
-    if sample_fn is None:
-        def sample_fn(key, logits, temp):
-            return jnp.argmax(logits, -1).astype(jnp.int32), key
-    attention = decode_attention_path(arena)
-
-    def body(carry, _):
-        tok, arena, ts, keys, done, rem, counters = carry
-        logits, arena, c = decode_step_pages(params, cfg, tok, arena, pt,
-                                             ts, done, attention=attention)
-        counters = {name: counters[name] + c[name] for name in counters}
-        nxt, keys = jax.vmap(sample_fn)(keys, logits, temps)
-        emit = jnp.where(done, tok, nxt)
-        rem = jnp.where(done, rem, rem - 1)
-        ndone = done | (emit == eos_ids) | (rem <= 0)
-        ts = jnp.where(done, ts, ts + 1)
-        return (emit, arena, ts, keys, ndone, rem, counters), emit
-
-    (tokens, arena, ts, keys, done, remaining, counters), block = \
-        jax.lax.scan(body, (tokens, arena, ts, keys, done, remaining,
-                            _zero_counters(cfg)), None, length=int(chunk))
-    return block, tokens, arena, ts, keys, done, remaining, counters
-
-
 # -- the engine's view of this model ------------------------------------------
 
 class _MoonlightServingModel(ServingModel):
@@ -634,7 +594,7 @@ class _MoonlightServingModel(ServingModel):
     def counter_names(self, cfg):
         # expert_tokens[e]: rows routed to expert e, and router_tokens:
         # tokens routed (each layer counts), by both programs since
-        # start; the decode_* three by the decode chunk alone: tokens
+        # start; the decode_* three by the decode step alone: tokens
         # routed, experts that had a row, and passes of an expert layer
         # with a live slot (what a step's expert bytes are counted from);
         # moe_kernel_passes: passes of an expert layer, a prefill's six
@@ -658,16 +618,18 @@ class _MoonlightServingModel(ServingModel):
             "decode_moe_passes": zero,
             "moe_kernel_passes": c["kernel_passes"]}
 
-    def decode_chunk(self, *args, **kw):
-        out = decode_chunk_pages(*args, **kw)
-        c = out[-1]
-        return out[:-1] + ({
+    def decode_step(self, params, cfg, tokens, arena, pt, ts, done, *,
+                    adapters=None, adapter_ids=None, arena_constraint=None):
+        logits, arena, c = decode_step_pages(
+            params, cfg, tokens, arena, pt, ts, done,
+            attention=decode_attention_path(arena, arena_constraint))
+        return logits, arena, {
             "expert_tokens": c["expert_tokens"],
             "router_tokens": c["router_tokens"],
             "decode_router_tokens": c["router_tokens"],
             "decode_experts_touched": c["experts_touched"],
             "decode_moe_passes": c["moe_passes"],
-            "moe_kernel_passes": c["kernel_passes"]},)
+            "moe_kernel_passes": c["kernel_passes"]}
 
 
 MOONLIGHT_SERVING_MODEL = _MoonlightServingModel()

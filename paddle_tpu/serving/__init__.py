@@ -15,12 +15,14 @@ prompt never stalls co-batched decode streams
 streaming callbacks (`engine`), and request/engine metrics incl. the
 dispatch-amortization and block/prefix-cache series (`metrics`).
 
-The engine knows no architecture: a model supplies its prefill, its fused
-decode chunk, a description of its per-layer cache state and the features
-it implements through `model.ServingModel`, named by its config's
-`serving_model()` (the GPT family, `models.gpt_decode`; Moonlight-16B-A3B,
-`models.moonlight`), and the sampler's PRNG every model shares is
-`sampling`.
+The engine knows no architecture: a model supplies its prefill, ONE decode
+step, optionally a speculative verify pass, a description of its per-layer
+cache state and the features it implements through `model.ServingModel`,
+named by its config's `serving_model()` (the GPT family,
+`models.gpt_decode`; Moonlight-16B-A3B, `models.moonlight`). The fused
+loop around the step is the engine's, written once (`decode_loop`: scan,
+sampling cadence, finish rule, drafter, named carry), and the sampler's
+PRNG every model shares is `sampling`.
 
 Entry points: `inference.create_engine(config, gpt_config)` to serve a
 saved GPT model dir, or `ServingEngine(params, cfg)` over an in-memory

@@ -1,14 +1,19 @@
 """What a model supplies to the serving engine.
 
 The engine, the scheduler and the cache manager know no architecture. A
-model is served through one `ServingModel`: its prefill of one prompt
-suffix into its pages, its fused decode chunk over the whole pool, a
-description of the cache state a token leaves behind in a layer
-(`CacheSpec`: how many "heads" the arena has and how wide a row is), and
-the engine features it implements. Everything else (slots, blocks, page
-tables, prefix hashing, swap and migration payloads on the block axis,
-the sampler, the done mask, the HTTP service) is the engine's and is the
-same for every model.
+model is served through one `ServingModel`, and what a new model writes
+is four things: a description of the cache state a token leaves behind
+in a layer (`cache_spec` -> `CacheSpec`: how many "heads" the arena has
+and how wide a row is), its `prefill` of one prompt suffix into its
+pages, ONE `decode_step` over the whole pool and, if it implements
+speculation, one multi-position `verify` pass; plus the engine features
+it declares. Everything else is the engine's and is the same for every
+model: slots, blocks, page tables, prefix hashing, swap and migration
+payloads on the block axis, the HTTP service, and the whole fused
+decode loop around the step (`decode_loop`: the scan, the sampler and
+its key cadence, the frozen-slot rule, the EOS/budget finish rule, the
+n-gram drafter and its acceptance, the named decode carry). A model has
+no loop of its own.
 
 A config object names its model by a `serving_model()` method
 (`models.gpt.GPTConfig`, `models.moonlight.MoonlightConfig`); a config
@@ -48,24 +53,42 @@ class ServingModel:
     """The interface. A model subclasses it and hands ONE instance to
     the engine through its config's `serving_model()`.
 
-    Array contracts (S slots, P pages a slot, B a prefill bucket):
+    Array contracts (S slots, P pages a slot, B a prefill bucket, V
+    the vocabulary):
       prefill(params, cfg, tokens (1, B), pfx_len, real_len, arena,
               pages (P,), adapters=None, adapter_id=None)
           -> (logits (1, V) f32 of position pfx_len + real_len - 1,
               arena, counters)
-      prefill_chunk: the same with an arbitrary start position
-          (feature "prefill_chunk")
-      decode_chunk(params, cfg, tokens, arena, pt (S, P), ts, keys,
-                   temps, done, remaining, eos_ids, chunk, sample_fn=,
-                   speculate_k=, spec_state=, arena_constraint=,
-                   adapters=, adapter_ids=)
-          -> (block, tokens, arena, ts, keys, done, remaining, counters)
-             and, speculating, (block, counts, tokens, arena, ts, keys,
-             done, remaining, spec_state, counters)
+          the real_len real tokens of a right-padded suffix at positions
+          pfx_len.., whose first pfx_len positions are already cached.
+          pfx_len is a multiple of the block size (a prefix hit; 0 for
+          a cold prompt) unless the model declares the feature
+          "prefill_chunk", which means "the start need not be
+          page-aligned": the engine then runs a long suffix as several
+          such calls, each starting where the last one stopped.
+      decode_step(params, cfg, tokens (S,), arena, pt (S, P), ts (S,),
+                  done (S,), *, adapters=None, adapter_ids=None,
+                  arena_constraint=None)
+          -> (logits (S, V) f32, arena, counters)
+          every slot one position on: writes each live slot's cache row
+          at position ts and attends over 0..ts. A slot whose `done` is
+          set is frozen: its write must reach no block but scratch
+          block 0 (its blocks may be another sequence's by now) and its
+          logits are discarded. `arena_constraint` is the mesh plan's
+          layout pin (feature "mesh"), which the loop has already
+          applied; the step is handed it only to read off which
+          attention it may run.
+      verify(params, cfg, toks (S, k+1), arena, pt, ts, done, *,
+             adapters, adapter_ids) -> (logits (S, k+1, V), arena)
+          feature "speculation": the positions ts..ts+k of every slot
+          in one pass, row j attending over 0..ts+j; writes past a
+          slot's page row and a frozen slot's go to scratch.
     `counters` is None or a dict of small int32 arrays the program
-    accumulated in-graph (a routed model's tokens per expert); the
-    scheduler fetches them WITH the chunk's token block or the first
-    token, adds them up on the host and `engine.stats()` reports them.
+    accumulated in-graph (a routed model's tokens per expert), with the
+    names and shapes `counter_names` gives, from both programs alike;
+    the loop sums a chunk's, the scheduler fetches them WITH the chunk's
+    token block or the first token, adds them up on the host and
+    `engine.stats()` reports them.
     """
 
     name = "model"
@@ -83,7 +106,7 @@ class ServingModel:
         raise NotImplementedError
 
     def decode_attention_path(self, arena, arena_constraint=None) -> str:
-        """Which attention the decode chunk runs on this arena, for
+        """Which attention `decode_step` runs on this arena, for
         `engine.stats()["decode_attention"]`."""
         raise NotImplementedError
 
@@ -95,20 +118,17 @@ class ServingModel:
                 pages, adapters=None, adapter_id=None):
         raise NotImplementedError
 
-    def prefill_chunk(self, params, cfg, tokens, start_pos, real_len,
-                      arena, pages, adapters=None, adapter_id=None):
+    def decode_step(self, params, cfg, tokens, arena, pt, ts, done, *,
+                    adapters=None, adapter_ids=None, arena_constraint=None):
         raise NotImplementedError
 
-    def decode_chunk(self, params, cfg, tokens, arena, pt, ts, keys,
-                     temps, done, remaining, eos_ids, chunk, **kw):
+    def verify(self, params, cfg, toks, arena, pt, ts, done, *,
+               adapters=None, adapter_ids=None):
+        """The speculative verify pass (feature "speculation")."""
         raise NotImplementedError
 
     def quantize_params(self, params, cfg):
         """Weight-only int8 (feature "int8_weights")."""
-        raise NotImplementedError
-
-    def spec_ngram_seed(self, table, slot, tokens, real_len):
-        """Seed a slot's drafter table (feature "speculation")."""
         raise NotImplementedError
 
 

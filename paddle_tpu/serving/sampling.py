@@ -1,7 +1,7 @@
 """The serving sampler's PRNG: a counter-based threefry2x32 and a Gumbel-max
-draw, plain vectorized uint32/float32 math that every serving model's fused
-decode loop and the scheduler's admission sampler share (it belongs to no
-model: models/gpt_decode.py re-exports it for its older callers)."""
+draw, plain vectorized uint32/float32 math that the engine's fused decode
+loop and the scheduler's admission sampler share (it belongs to no
+model)."""
 
 from __future__ import annotations
 
@@ -9,7 +9,7 @@ __all__ = ["threefry2x32", "sample_key", "sample_split", "sample_gumbel"]
 
 # -- serving sampler PRNG ---------------------------------------------------
 #
-# The serving chunk kernels draw per-slot samples VMAPPED over the slot
+# The fused decode loop draws per-slot samples VMAPPED over the slot
 # dimension, and resumed/preempted/late-admitted sequences must reproduce
 # their streams bit-exactly wherever and whenever they land. The fleet's
 # default `rbg` PRNG cannot provide that: under vmap it generates the

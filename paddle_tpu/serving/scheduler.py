@@ -1,9 +1,10 @@
 """Continuous-batching step loop over the PAGED KV pool.
 
 Orca/vLLM-style iteration-level scheduling on top of a served model's
-prefill/decode-chunk split (serving.model.ServingModel: the scheduler
-calls the model's programs through that interface and knows no
-architecture): instead of running each request's whole decode loop
+prefill/decode-step split (serving.model.ServingModel: the scheduler
+calls the model's prefill, and the engine's one decode loop,
+serving.decode_loop, calls its step; neither knows an architecture):
+instead of running each request's whole decode loop
 alone (TPU idle between requests, batch-1 latency everywhere), the
 scheduler keeps ONE batched decode dispatch hot over all slots and
 admits new requests into free slots between dispatches:
@@ -16,14 +17,15 @@ admits new requests into free slots between dispatches:
             the first token from the last-position logits. One dispatch
             per suffix-bucket shape; a prefix hit shrinks the suffix
             into the small buckets, which is the TTFT win.
-    step:   the model's decode chunk over the WHOLE pool — `decode_chunk`
-            fused decode iterations (fixed batch = num_slots, per-slot
-            positions through the page table, in-graph sampling +
-            EOS/budget masking) per dispatch, returning a (chunk, slots)
-            token block in one fetch. Always the same executable,
-            whatever mix of sequences is in flight.
-    retire: finished sequences freeze IN-GRAPH (the chunk kernel's done
-            mask, which also redirects their ride-along K/V writes to
+    step:   the decode loop over the WHOLE pool — `decode_chunk` fused
+            iterations of the model's decode step (fixed batch =
+            num_slots, per-slot positions through the page table,
+            in-graph sampling + EOS/budget masking) per dispatch,
+            returning a (chunk, slots) token block in one fetch. Always
+            the same executable, whatever mix of sequences is in flight.
+    retire: finished sequences freeze IN-GRAPH (the loop's done mask,
+            decode_loop.finish_rule, the function the host retires by
+            too; it also redirects their ride-along K/V writes to
             the scratch block — a frozen slot must never dirty blocks
             that admission has reallocated) and just free their pages
             host-side; the batch never stalls.
@@ -49,13 +51,15 @@ Decode fast path (why this is fast, not just correct):
     freezes finished slots — the device never needs the host's verdict
     to keep the batch sound.
 
-The decode carry (current token, position, done, remaining budget,
-temperature, eos id — all per-slot) AND the page table live ON DEVICE
+The decode carry (decode_loop.DecodeCarry: current token, position,
+done, remaining budget, temperature, eos id — all per-slot — and, when
+on, the drafter's rows and the adapter rows, every program reading it
+by field name) AND the page table live ON DEVICE
 between dispatches; the host only touches them at admission (the
 prefill/admit executables reset one slot's entries in-graph) and at
 cancel (the release executable freezes a cancelled slot and points its
 page row at scratch BEFORE its blocks can be reallocated — EOS/budget
-retirement needs no dispatch because the chunk kernel already froze the
+retirement needs no dispatch because the loop already froze the
 slot in-graph at the exact finish token). Each _Running records
 `live_from`, the index of the first dispatch whose block carries its
 tokens, so a block fetched AFTER a slot was retired and re-admitted is
@@ -102,7 +106,8 @@ single prefill dispatch is the one work unit that can monopolize the
 device — every co-batched decode stream stalls for its whole duration,
 which is exactly the TPOT p99 spike at peak load. With a budget set,
 admission maps pages exactly as today but the prompt suffix runs as a
-SEQUENCE of budget-bounded chunk dispatches (gpt_prefill_chunk_pages,
+SEQUENCE of budget-bounded chunk dispatches (the model's one `prefill`
+at a start that need not be page-aligned, the feature "prefill_chunk";
 shapes drawn from the same suffix buckets, so the executable family
 grows by at most O(prefill buckets)): the slot rides the fused decode
 chunk loop FROZEN meanwhile (its device done row is still True from
@@ -115,8 +120,8 @@ tick (advance_prefill) INTERLEAVED with decode dispatches — the
 Sarathi-style piggyback. The LAST chunk's logits feed the same
 admission sampler executable that monolithic prefill uses, so the
 first token — and every token after it — is token-identical to
-prefill_chunk=None (per-position prefill math is shared with the
-monolithic kernel; see gpt_prefill_chunk_pages). Prefix-cache
+prefill_chunk=None (it is the same `prefill`, whose per-position math
+does not depend on where the suffix starts). Prefix-cache
 REGISTRATION is deferred per block until the chunk that fills it has
 been enqueued (kv_cache.map_slot(register=False) +
 register_prefix): a concurrent admission must never hash-hit a block
@@ -130,7 +135,8 @@ draft -> verify -> accept pass — a per-slot trigram table (carried in
 the donated device state, seeded from the prompt at prefill) proposes
 up to k tokens, ONE multi-position model pass scores them all, and
 in-graph exact-match acceptance commits the matched run plus one
-corrected token (the GPT family's `_spec_step`). Tokens-per-model-pass
+corrected token (decode_loop's `_spec_step`, over the model's `verify`
+pass). Tokens-per-model-pass
 rises from exactly 1 to between 1 and k+1 WITHOUT changing any stream:
 acceptance is "the sampler would have produced this token anyway", key
 chain advanced one split per committed token, so greedy AND seeded
@@ -145,8 +151,9 @@ of a lucky streak is one EOS-style overshoot dispatch at the tail.
 
 MULTI-TENANT ADAPTERS (adapters=AdapterPool): co-batched slots each hit
 a DIFFERENT LoRA adapter inside the same fused dispatch. A per-slot
-adapter-ROW vector rides as the LAST element of the donated decode
-carry (row 0 = base identity), and the pool pytree is passed into every
+adapter-ROW vector rides as the LAST field of the donated decode
+carry (`adapter_rows`; row 0 = base identity), and the pool pytree is
+passed into every
 jitted entry point as a READ-ONLY extra argument — never donated, never
 closed over (a closure would bake the traced value in as a constant and
 uploads would be silently ignored), so an upload is a pure value update
@@ -174,6 +181,8 @@ from ..observability import request_log as _request_log
 from ..observability.tracer import get_tracer, trace_span
 from ..utils.compile_cache import ensure_compile_cache
 from . import sampling
+from .decode_loop import (DecodeCarry, decode_chunk, finish_rule,
+                          spec_ngram_seed)
 from .kv_cache import ShapeBuckets, SlotKVCache
 from .model import serving_model
 
@@ -217,6 +226,14 @@ class _Running:
         #                                   policies key on it; preserved
         #                                   across swap-out/swap-in)
         self.adapter_id = adapter_id      # LOGICAL adapter id (0 = base)
+
+    def finished_by(self, token: int) -> bool:
+        """Does `token`, the `produced`-th of this sequence, end it? The
+        host's reading of decode_loop.finish_rule, the same function
+        the device's done mask is computed by (eos_id None is the
+        carry's -1, which no sampled id equals)."""
+        return finish_rule(token, -1 if self.eos_id is None
+                           else self.eos_id, self.max_new - self.produced)
 
 
 class _Prefill:
@@ -423,6 +440,20 @@ class CompileJournal:
                 "dispatch_hbm_bytes": self.dispatch_hbm_bytes()}
 
 
+class _SlotRows(NamedTuple):
+    """One slot's rows of the decode carry and its sampler key, as the
+    swap-out program returns them and the swap-in program takes them
+    back (`spec`: the drafter's (prev, n-gram row) under speculation,
+    else None). SwappedSequence parks them on the host."""
+    token: Any
+    ts: Any
+    remaining: Any
+    temp: Any
+    eos: Any
+    key_row: Any
+    spec: Any = None
+
+
 class _Inflight(NamedTuple):
     """One launched-but-unfetched chunk dispatch."""
     block: Any          # device (chunk, S) int32 token block (a future)
@@ -487,7 +518,7 @@ class ContinuousBatchingScheduler:
         self.params = params
         self.cfg = cfg
         # the served model (serving.model.ServingModel): its prefill,
-        # its decode chunk and what it says of its arena are all the
+        # its decode step and what it says of its arena are all the
         # scheduler knows of the architecture
         self.model = serving_model(cfg)
         # the model's in-graph counters (a routed model's tokens per
@@ -503,7 +534,7 @@ class ContinuousBatchingScheduler:
         self.overlap = bool(overlap)
         self.speculate_k = int(speculate_k)
         self.speculate_ngram = int(speculate_ngram)
-        # which attention the decode chunk runs, read by the model off
+        # which attention the decode step runs, read by the model off
         # what it is given (the arena's form, the mesh plan, the
         # backend) and fixed for the engine's life; speculation decodes
         # through the verify pass, which gathers
@@ -552,9 +583,9 @@ class ContinuousBatchingScheduler:
         self._swapout_jit = None
         self._swapin_jit = None
         self._admit_counter = 0           # admission order for _Running.seq
-        # device-resident decode carry: (tokens, ts, done, remaining,
-        # temps, eos_ids), all (S,) — built lazily with the jits, next
-        # to the device page table (all rows scratch until admission)
+        # device-resident decode carry (decode_loop.DecodeCarry) — built
+        # lazily with the jits, next to the device page table (all rows
+        # scratch until admission)
         self._state = None
         self._pt = None
         self._inflight: List[_Inflight] = []
@@ -636,26 +667,12 @@ class ContinuousBatchingScheduler:
 
         model = self.model
         s_dim = self.kv.num_slots
-        self._state = (jnp.zeros((s_dim,), jnp.int32),   # tokens
-                       jnp.zeros((s_dim,), jnp.int32),   # ts
-                       jnp.ones((s_dim,), bool),         # done (all frozen)
-                       jnp.zeros((s_dim,), jnp.int32),   # remaining
-                       jnp.zeros((s_dim,), jnp.float32),  # temps
-                       jnp.full((s_dim,), -1, jnp.int32))  # eos_ids
-        if self.speculate_k:
-            # drafter carry rides in the SAME donated state tuple:
-            # prev committed token + per-slot trigram table (the extra
-            # column is the trash lane masked scatter writes land in)
-            self._state += (
-                jnp.zeros((s_dim,), jnp.int32),          # prev
-                jnp.full((s_dim, self.speculate_ngram + 1), -1,
-                         jnp.int32))                     # ngram table
         adapters_on = self.adapters is not None
-        if adapters_on:
-            # per-slot adapter POOL ROW vector, ALWAYS the last carry
-            # element (spec rows, if any, keep indices 6/7): row 0 is
-            # the base identity, so zeros mean "no adapter" everywhere
-            self._state += (jnp.zeros((s_dim,), jnp.int32),)
+        # every slot frozen and empty; the drafter's rows and the
+        # per-slot adapter pool rows ride in the SAME donated carry
+        self._state = DecodeCarry.idle(
+            s_dim, self.speculate_ngram if self.speculate_k else None,
+            adapters_on)
 
         # device page table: every row scratch until its slot admits
         self._pt = jnp.zeros((s_dim, self.kv.max_pages), jnp.int32)
@@ -683,11 +700,11 @@ class ContinuousBatchingScheduler:
         # form), and donate_argnums positions never shift. With
         # adapters on, prefill gets (pool, scalar row), chunk gets
         # (pool,) — the per-slot row vector is already in the carry.
-        def prefill_impl(params, arena, pt, state, tokens, pfx_len,
-                         real_len, pages, slot, *alo):
-            self._note_compile(f"prefill:L{tokens.shape[1]}")
+        def prefill_body(tag, params, arena, pt, state, tokens, start,
+                         real_len, pages, slot, alo):
+            self._note_compile(f"{tag}:L{tokens.shape[1]}")
             logits, arena, counters = model.prefill(
-                params, self.cfg, tokens, pfx_len, real_len, arena,
+                params, self.cfg, tokens, start, real_len, arena,
                 pages, adapters=alo[0] if alo else None,
                 adapter_id=alo[1] if alo else None)
             pt = pt.at[slot].set(pages)
@@ -695,108 +712,86 @@ class ContinuousBatchingScheduler:
                 # slot reuse hygiene: wipe the previous occupant's
                 # n-grams, then seed from THIS prompt's suffix (with a
                 # prefix-cache hit the hit blocks' tokens aren't here —
-                # seeding is best-effort; drafts are always verified)
-                state = state[:7] + (model.spec_ngram_seed(
-                    state[7], slot, tokens[0], real_len),) + state[8:]
+                # seeding is best-effort; drafts are always verified).
+                # Under chunked prefill the reset-per-chunk only costs
+                # acceptance rate on long prompts: the stream is a pure
+                # function of the sampler chain, never the table
+                prev, table = state.spec
+                state = state._replace(spec=(prev, spec_ngram_seed(
+                    table, slot, tokens[0], real_len)))
             return (c_rep(logits[0]), c_arena(arena), c_rep(pt),
                     c_rep(state), c_rep(counters))
 
+        def prefill_impl(params, arena, pt, state, tokens, pfx_len,
+                         real_len, pages, slot, *alo):
+            return prefill_body("prefill", params, arena, pt, state,
+                                tokens, pfx_len, real_len, pages, slot,
+                                alo)
+
         def prefill_chunk_impl(params, arena, pt, state, tokens,
                                start_pos, real_len, pages, slot, *alo):
-            # chunked prefill: per-position math shared with
-            # prefill_impl (the model's chunked prefill rides the same
-            # body), start_pos is the host-carried fill cursor. The
-            # page-row install is idempotent across a prompt's chunks —
-            # one executable per chunk bucket, whatever the chunk index.
-            self._note_compile(
-                f"prefill_chunk:L{tokens.shape[1]}")
-            logits, arena, counters = model.prefill_chunk(
-                params, self.cfg, tokens, start_pos, real_len, arena,
-                pages, adapters=alo[0] if alo else None,
-                adapter_id=alo[1] if alo else None)
-            pt = pt.at[slot].set(pages)
-            if self.speculate_k:
-                # same slot-reuse hygiene as monolithic prefill; the
-                # reset-per-chunk only costs acceptance rate on long
-                # prompts (drafts are always verified — the stream is a
-                # pure function of the sampler chain, never the table)
-                state = state[:7] + (model.spec_ngram_seed(
-                    state[7], slot, tokens[0], real_len),) + state[8:]
-            return (c_rep(logits[0]), c_arena(arena), c_rep(pt),
-                    c_rep(state), c_rep(counters))
+            # chunked prefill: the same program under its own tag (and
+            # so its own executables), start_pos the host-carried fill
+            # cursor. The page-row install is idempotent across a
+            # prompt's chunks — one executable per chunk bucket,
+            # whatever the chunk index.
+            return prefill_body("prefill_chunk", params, arena, pt,
+                                state, tokens, start_pos, real_len,
+                                pages, slot, alo)
 
         def admit_impl(keys, state, slot, seed, logits, temp, pos,
                        max_new, eos_id, prev_tok, *aid):
             self._note_compile("admit_sample")
-            tokens, ts, done, remaining, temps, eos_ids = state[:6]
             keys = keys.at[slot].set(sampling.sample_key(seed))
             first, key_next = self._sample_row(keys[slot], logits, temp)
             keys = keys.at[slot].set(key_next)
-            # finished-at-admission mirrors the host rule exactly so the
+            # finished-at-admission is the one finish rule, so the
             # device-side done mask never disagrees with _running
-            fin = (max_new <= 1) | ((eos_id >= 0) & (first == eos_id))
-            new_state = (tokens.at[slot].set(first),
-                         ts.at[slot].set(pos),
-                         done.at[slot].set(fin),
-                         remaining.at[slot].set(max_new - 1),
-                         temps.at[slot].set(temp),
-                         eos_ids.at[slot].set(eos_id))
+            left = max_new - 1
+            state = state._replace(
+                tokens=state.tokens.at[slot].set(first),
+                ts=state.ts.at[slot].set(pos),
+                done=state.done.at[slot].set(
+                    finish_rule(first, eos_id, left)),
+                remaining=state.remaining.at[slot].set(left),
+                temps=state.temps.at[slot].set(temp),
+                eos_ids=state.eos_ids.at[slot].set(eos_id))
             if self.speculate_k:
                 # first drafter context = (last prompt token, first
                 # sampled token); the table row was seeded at prefill
-                new_state += (state[6].at[slot].set(prev_tok),
-                              state[7])
+                prev, table = state.spec
+                state = state._replace(
+                    spec=(prev.at[slot].set(prev_tok), table))
             if aid:
                 # stamp this slot's adapter POOL ROW into the carry —
                 # from the next chunk on, the gather path serves it
-                new_state += (state[-1].at[slot].set(aid[0]),)
-            return c_rep(first), c_rep(keys), c_rep(new_state)
+                state = state._replace(
+                    adapter_rows=state.adapter_rows.at[slot].set(aid[0]))
+            return c_rep(first), c_rep(keys), c_rep(state)
 
         def chunk_impl(params, arena, pt, keys, state, *apool):
             self._note_compile("decode_chunk")
-            tokens, ts, done, remaining, temps, eos_ids = state[:6]
-            ad = apool[0] if apool else None
-            aids = state[-1] if apool else None
-            tail = (state[-1],) if apool else ()
-            if self.speculate_k:
-                (block, counts, tokens, arena, ts, keys, done,
-                 remaining, spec, counters) = model.decode_chunk(
-                    params, self.cfg, tokens, arena, pt, ts, keys,
-                    temps, done, remaining, eos_ids, self.decode_chunk,
-                    sample_fn=self._sample_row,
-                    speculate_k=self.speculate_k,
-                    spec_state=(state[6], state[7]),
-                    arena_constraint=arena_con,
-                    adapters=ad, adapter_ids=aids)
-                return (c_rep((block, counts)), c_arena(arena),
-                        c_rep(keys),
-                        c_rep((tokens, ts, done, remaining, temps,
-                               eos_ids) + spec + tail),
-                        c_rep(counters))
-            block, tokens, arena, ts, keys, done, remaining, counters = \
-                model.decode_chunk(
-                    params, self.cfg, tokens, arena, pt, ts, keys,
-                    temps, done, remaining, eos_ids, self.decode_chunk,
-                    sample_fn=self._sample_row,
-                    arena_constraint=arena_con,
-                    adapters=ad, adapter_ids=aids)
+            block, arena, keys, state, counters = decode_chunk(
+                model, params, self.cfg, arena, pt, keys, state,
+                self.decode_chunk, sample_fn=self._sample_row,
+                speculate_k=self.speculate_k,
+                adapters=apool[0] if apool else None,
+                arena_constraint=arena_con)
             return (c_rep(block), c_arena(arena), c_rep(keys),
-                    c_rep((tokens, ts, done, remaining, temps,
-                           eos_ids) + tail), c_rep(counters))
+                    c_rep(state), c_rep(counters))
 
         def release_impl(pt, state, slot):
             # cancel path: the host verdict the in-graph done mask can't
             # know — freeze the slot and point its page row at scratch
             # so its ride-along writes stop touching blocks admission
-            # may reallocate (the drafter tail, if any, rides along
-            # untouched: the next admission resets it at prefill)
+            # may reallocate (the drafter rows, if any, ride along
+            # untouched: the next admission resets them at prefill)
             self._note_compile("release_slot")
-            tokens, ts, done, remaining, temps, eos_ids = state[:6]
             pt = pt.at[slot].set(
                 jnp.zeros((pt.shape[1],), jnp.int32))
-            state = (tokens, ts, done.at[slot].set(True),
-                     remaining.at[slot].set(0), temps, eos_ids) \
-                + tuple(state[6:])
+            state = state._replace(
+                done=state.done.at[slot].set(True),
+                remaining=state.remaining.at[slot].set(0))
             return c_rep(pt), c_rep(state)
 
         def swapout_impl(arena, keys, state, blocks, slot):
@@ -814,22 +809,22 @@ class ContinuousBatchingScheduler:
                                 for a in arena)
             else:
                 payload = jnp.take(arena, blocks, axis=2)
-            tokens, ts, _done, remaining, temps, eos_ids = state[:6]
-            rows = (tokens[slot], ts[slot], remaining[slot], temps[slot],
-                    eos_ids[slot], keys[slot])
-            if self.speculate_k:
-                rows += (state[6][slot], state[7][slot])
+            rows = _SlotRows(
+                state.tokens[slot], state.ts[slot],
+                state.remaining[slot], state.temps[slot],
+                state.eos_ids[slot], keys[slot],
+                None if state.spec is None
+                else (state.spec[0][slot], state.spec[1][slot]))
             # payload stays heads-sharded on device; the device_get in
             # swap_out assembles the FULL-HEAD host layout from the
             # shards, which is what makes swap-pool records and
             # MigrationTickets mesh-portable
-            return (c_payload(payload),) + c_rep(rows)
+            return c_payload(payload), c_rep(rows)
 
         def swapin_impl(arena, pt, keys, state, payload, blocks, slot,
-                        token, ts_v, rem, temp, eos, key_row, *extra):
-            # extra = spec rows (prev, ngram) when speculating, then the
-            # adapter pool row when adapters are on — same varargs-tail
-            # convention as the other impls
+                        rows, *aid):
+            # aid = the adapter pool row when adapters are on — same
+            # varargs-tail convention as the other impls
             # host-swap restore: scatter the payload back through the
             # freshly adopted page row (padding lanes land in scratch,
             # the trash lane) and rebuild the slot's decode-carry rows
@@ -844,22 +839,24 @@ class ContinuousBatchingScheduler:
             else:
                 arena = arena.at[:, :, blocks].set(payload)
             pt = pt.at[slot].set(blocks)
-            keys = keys.at[slot].set(key_row)
-            tokens, ts, done, remaining, temps, eos_ids = state[:6]
-            new_state = (tokens.at[slot].set(token),
-                         ts.at[slot].set(ts_v),
-                         done.at[slot].set(False),
-                         remaining.at[slot].set(rem),
-                         temps.at[slot].set(temp),
-                         eos_ids.at[slot].set(eos))
+            keys = keys.at[slot].set(rows.key_row)
+            state = state._replace(
+                tokens=state.tokens.at[slot].set(rows.token),
+                ts=state.ts.at[slot].set(rows.ts),
+                done=state.done.at[slot].set(False),
+                remaining=state.remaining.at[slot].set(rows.remaining),
+                temps=state.temps.at[slot].set(rows.temp),
+                eos_ids=state.eos_ids.at[slot].set(rows.eos))
             if self.speculate_k:
-                prev, table = state[6], state[7]
-                new_state += (prev.at[slot].set(extra[0]),
-                              table.at[slot].set(extra[1]))
+                prev, table = state.spec
+                state = state._replace(
+                    spec=(prev.at[slot].set(rows.spec[0]),
+                          table.at[slot].set(rows.spec[1])))
             if adapters_on:
-                new_state += (state[-1].at[slot].set(extra[-1]),)
+                state = state._replace(
+                    adapter_rows=state.adapter_rows.at[slot].set(aid[0]))
             return (c_arena(arena), c_rep(pt), c_rep(keys),
-                    c_rep(new_state))
+                    c_rep(state))
 
         # donation (the executor's donate=True discipline): the arena,
         # the page table, the key table, and the decode carry are
@@ -1128,8 +1125,7 @@ class ContinuousBatchingScheduler:
         st = _Running(req, pos=p_len, max_new=max_new, eos_id=eos_id,
                       live_from=self._launches, seq=seq,
                       adapter_id=adapter_id)
-        finished = (st.produced >= max_new
-                    or (eos_id is not None and first == eos_id))
+        finished = st.finished_by(first)
         if finished:
             self.kv.free(slot)
         else:
@@ -1374,9 +1370,7 @@ class ContinuousBatchingScheduler:
                     st.produced += 1
                     st.pos += 1
                     self.kv.advance(slot)
-                    finished = (st.produced >= st.max_new
-                                or (st.eos_id is not None
-                                    and tok == st.eos_id))
+                    finished = st.finished_by(tok)
                     if finished:
                         # retire-without-stall: the slot frees NOW
                         # (in-graph it froze the moment this token was
@@ -1434,7 +1428,7 @@ class ContinuousBatchingScheduler:
         without emitting further tokens. Tokens the in-flight dispatch
         already produced for it are discarded at collect (the slot is no
         longer in _running). Unlike EOS/budget retirement — where the
-        chunk kernel froze the slot in-graph at the exact finish token —
+        decode loop froze the slot in-graph at the exact finish token —
         a cancel is a host-only verdict, so the release executable
         freezes the device-side slot and points its page row at scratch
         BEFORE the freed blocks can be reallocated by a later admission
@@ -1535,12 +1529,10 @@ class ContinuousBatchingScheduler:
         st = self._running.pop(slot)
         n_blocks = self.kv.mapped_block_count(slot)
         blocks_row = self.kv.page_table[slot].copy()
-        host = jax.device_get(self._jit_call(
+        payload, rows = jax.device_get(self._jit_call(
             "swap_out", self._swapout_jit,
             self.kv.arena, self._keys, self._state, blocks_row,
             np.int32(slot)))
-        payload, token, ts, rem, temp, eos, key_row = host[:7]
-        spec = (host[7], host[8]) if self.speculate_k else None
         # park only the rows the sequence owns: the gather is scratch-
         # padded to max_pages so ONE executable serves every block
         # count, but keeping the full-width copy would pin up to
@@ -1557,7 +1549,8 @@ class ContinuousBatchingScheduler:
         sw = SwappedSequence(
             st.req, st.pos, st.produced, st.max_new, st.eos_id,
             st.seq, self.kv.length(slot), n_blocks, payload,
-            token, ts, rem, temp, eos, np.asarray(key_row), spec,
+            rows.token, rows.ts, rows.remaining, rows.temp, rows.eos,
+            np.asarray(rows.key_row), rows.spec,
             scales=scales, adapter_id=st.adapter_id)
         self._pt, self._state = self._jit_call(
             "release_slot", self._release_jit,
@@ -1624,10 +1617,11 @@ class ContinuousBatchingScheduler:
             payload = jax.device_put(payload,
                                      self.plan.payload_sharding)
         args = [self.kv.arena, self._pt, self._keys, self._state,
-                payload, row, np.int32(slot), sw.token, sw.ts,
-                sw.remaining, sw.temp, sw.eos, sw.key_row]
-        if self.speculate_k:
-            args += [sw.spec[0], sw.spec[1]]
+                payload, row, np.int32(slot),
+                _SlotRows(sw.token, sw.ts, sw.remaining, sw.temp, sw.eos,
+                          sw.key_row,
+                          (sw.spec[0], sw.spec[1]) if self.speculate_k
+                          else None)]
         if self.adapters is not None:
             # re-resolve the pool ROW at resume: the engine holds the
             # id's refcount across the park, so the row cannot have

@@ -265,8 +265,8 @@ def test_paged_attention_kernel(case, trained, monkeypatch):
     path: the context of every live slot, the arena after the step's
     own write (a frozen slot's went to scratch on the gather path and
     goes nowhere in the kernel: every block but scratch is equal), and
-    zeros for a frozen slot. The last case runs one whole
-    gpt_decode_chunk_pages of 8 steps through either path."""
+    zeros for a frozen slot. The last case runs one whole chunk of 8
+    steps (serving.decode_loop) through either path."""
     import jax.numpy as jnp
     from paddle_tpu.ops.paged_attention import paged_attention
 
@@ -316,9 +316,11 @@ def test_paged_attention_kernel(case, trained, monkeypatch):
 
 
 def _chunk_tokens_match(trained, monkeypatch):
-    """One gpt_decode_chunk_pages of 8 steps: greedy tokens, positions
-    and the frozen mask of the kernel path are the gather path's."""
+    """One decode_loop.decode_chunk of 8 steps over the GPT's paged
+    step: greedy tokens, positions and the frozen mask of the kernel
+    path are the gather path's."""
     import jax.numpy as jnp
+    from paddle_tpu.serving.decode_loop import DecodeCarry, decode_chunk
     cfg, params, _ = trained
     heads, hd = cfg.heads, cfg.hidden // cfg.heads
     s_dim, bs, pages = 3, 4, 6
@@ -334,27 +336,28 @@ def _chunk_tokens_match(trained, monkeypatch):
             params, cfg, jnp.asarray(prompt[None], jnp.int32), 0,
             len(prompt), arena, pt_[slot])
         first.append(int(np.argmax(np.asarray(logits[0]))))
-    args = dict(
-        tokens=jnp.asarray(first + [0], jnp.int32), arena=arena, pt=pt_,
+    carry = DecodeCarry(
+        tokens=jnp.asarray(first + [0], jnp.int32),
         ts=jnp.asarray([5, 9, 2], jnp.int32),
-        keys=jnp.zeros((s_dim, 2), jnp.uint32),
-        temps=jnp.zeros((s_dim,), jnp.float32),
         done=jnp.asarray([False, False, True]),      # slot 2 rides frozen
         remaining=jnp.asarray([8, 5, 0], jnp.int32),  # slot 1 ends inside
-        eos_ids=jnp.full((s_dim,), -1, jnp.int32), chunk=8)
+        temps=jnp.zeros((s_dim,), jnp.float32),
+        eos_ids=jnp.full((s_dim,), -1, jnp.int32))
 
     def run():
-        return gd.gpt_decode_chunk_pages(params, cfg, **args)
+        block, arena_f, _, c, _ = decode_chunk(
+            gd.GPT_SERVING_MODEL, params, cfg, arena, pt_,
+            jnp.zeros((s_dim, 2), jnp.uint32), carry, 8)
+        return (block, c.tokens, c.ts, c.done, c.remaining), arena_f
 
-    want = run()
+    want, want_arena = run()
     monkeypatch.setattr(gd, "decode_attention_path",
                         lambda *a, **k: "paged_kernel")
-    got = run()
-    for i in (0, 1, 3, 5, 6):        # block, tokens, ts, done, remaining
-        np.testing.assert_array_equal(np.asarray(got[i]),
-                                      np.asarray(want[i]))
-    np.testing.assert_allclose(np.asarray(got[2])[:, :, 1:],
-                               np.asarray(want[2])[:, :, 1:],
+    got, got_arena = run()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    np.testing.assert_allclose(np.asarray(got_arena)[:, :, 1:],
+                               np.asarray(want_arena)[:, :, 1:],
                                rtol=1e-5, atol=1e-5)
 
 

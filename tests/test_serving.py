@@ -19,6 +19,7 @@ from paddle_tpu.models import gpt_decode as gd
 from paddle_tpu.serving import (EngineOverloadError, FaultPlan,
                                 InjectedFault, ServingConfig,
                                 ServingEngine, ShapeBuckets, SlotKVCache)
+from paddle_tpu.serving.decode_loop import DecodeCarry, decode_chunk
 
 
 def tiny_cfg():
@@ -54,30 +55,78 @@ def sequential_ref(trained, prompt, max_new):
 
 
 # ---------------------------------------------------------------------------
-# decode-primitive parity (models/gpt_decode additions)
+# decode-primitive parity: the paged forms against the sequential reference
 # ---------------------------------------------------------------------------
+
+BS = 4          # block size of the paged pools built below
+
+
+def paged_pool(trained, prompts, pages=8, bucket=None):
+    """A block arena and page table with every prompt prefilled into
+    its own slot's pages (slot s owns blocks 1 + s*pages ..; block 0 is
+    scratch), each padded to `bucket` when given. Returns (arena, page
+    table, [last-position logits (1, V)])."""
+    import jax.numpy as jnp
+    cfg, params = trained
+    shape, _ = gd.paged_arena_shapes(
+        cfg.layers, len(prompts) * pages + 1, cfg.heads, BS,
+        cfg.hidden // cfg.heads)
+    arena = jnp.zeros(shape, jnp.float32)
+    pt_ = jnp.arange(1, len(prompts) * pages + 1,
+                     dtype=jnp.int32).reshape(len(prompts), pages)
+    logits = []
+    for slot, prompt in enumerate(prompts):
+        prompt = np.asarray(prompt, np.int32).reshape(-1)
+        padded = np.zeros((1, bucket or prompt.size), np.int32)
+        padded[0, :prompt.size] = prompt
+        lg, arena = gd.gpt_prefill_pages(
+            params, cfg, jnp.asarray(padded), 0, prompt.size, arena,
+            pt_[slot])
+        logits.append(lg)
+    return arena, pt_, logits
+
+
+def slot_rows(arena, pt_, slot, n):
+    """A slot's first n K and V rows of every layer, in the sequential
+    cache's layout (layers, 2, heads, n, hd)."""
+    import jax.numpy as jnp
+    return np.stack([np.stack([np.asarray(x[:, :n]) for x in gd._kv_gather(
+        arena, li, pt_[slot], jnp.float32)]) for li in range(arena.shape[0])])
+
+
+def carry_of(tokens, ts, remaining, done=None, spec=None):
+    """A greedy DecodeCarry with no eos over len(tokens) slots."""
+    import jax.numpy as jnp
+    n = len(tokens)
+    return DecodeCarry(
+        jnp.asarray(tokens, jnp.int32), jnp.asarray(ts, jnp.int32),
+        jnp.zeros((n,), bool) if done is None else jnp.asarray(done),
+        jnp.asarray(remaining, jnp.int32), jnp.zeros((n,), jnp.float32),
+        jnp.full((n,), -1, jnp.int32), spec)
+
 
 def test_prefill_padded_matches_prefill(trained):
     """Padding the prompt to a bucket changes neither the last-real-
-    position logits nor the real K/V rows."""
+    position logits nor the real K/V rows: gpt_prefill_pages of a
+    right-padded suffix against the sequential gpt_prefill."""
     cfg, params = trained
     rng = np.random.RandomState(0)
     toks = np.asarray(rng.randint(0, cfg.vocab_size, (2, 5)), np.int32)
     ref_logits, ref_cache = gd.gpt_prefill(params, cfg, toks, max_len=16)
-    padded = np.zeros((2, 8), np.int32)
-    padded[:, :5] = toks
-    logits, cache = gd.gpt_prefill_padded(
-        params, cfg, padded, np.asarray([5, 5], np.int32), max_len=16)
-    np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(cache[:, :, :, :, :5]),
-                               np.asarray(ref_cache[:, :, :, :, :5]),
-                               rtol=1e-5, atol=1e-5)
+    arena, pt_, logits = paged_pool(trained, list(toks), bucket=8)
+    for slot in range(2):
+        np.testing.assert_allclose(np.asarray(logits[slot][0]),
+                                   np.asarray(ref_logits[slot]),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(
+            slot_rows(arena, pt_, slot, 5),
+            np.asarray(ref_cache[:, :, slot, :, :5]), rtol=1e-5, atol=1e-5)
 
 
 def test_decode_step_slots_matches_per_sequence_steps(trained):
-    """The slot-batched step at per-slot positions reproduces two
-    independent gpt_decode_step calls at different t."""
+    """The slot-batched paged step at per-slot positions reproduces two
+    independent gpt_decode_step calls at different t: their logits and
+    the K/V rows they leave behind."""
     import jax.numpy as jnp
     cfg, params = trained
     rng = np.random.RandomState(1)
@@ -89,18 +138,20 @@ def test_decode_step_slots_matches_per_sequence_steps(trained):
     la, ca2 = gd.gpt_decode_step(params, cfg, jnp.asarray([ta]), ca, 3)
     lb, cb2 = gd.gpt_decode_step(params, cfg, jnp.asarray([tb]), cb, 6)
 
-    pool = jnp.concatenate([ca, cb], axis=2)        # slots 0,1
-    logits, pool2 = gd.gpt_decode_step_slots(
-        params, cfg, jnp.asarray([ta, tb]), pool,
+    arena, pt_, _ = paged_pool(trained, [a[0], b[0]])        # slots 0,1
+    logits, arena2 = gd.gpt_decode_step_pages(
+        params, cfg, jnp.asarray([ta, tb]), arena, pt_,
         jnp.asarray([3, 6], jnp.int32))
     np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(la[0]),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(logits[1]), np.asarray(lb[0]),
                                rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(pool2[:, :, :1]),
-                               np.asarray(ca2), rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(pool2[:, :, 1:]),
-                               np.asarray(cb2), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(slot_rows(arena2, pt_, 0, 4),
+                               np.asarray(ca2[:, :, 0, :, :4]),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(slot_rows(arena2, pt_, 1, 7),
+                               np.asarray(cb2[:, :, 0, :, :7]),
+                               rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -384,83 +435,68 @@ def test_engine_metrics_populated(trained):
 # ---------------------------------------------------------------------------
 
 def test_chunk_kernel_matches_repeated_slot_steps(trained):
-    """gpt_decode_chunk_slots (greedy, no finishes) is exactly `chunk`
-    consecutive gpt_decode_step_slots + argmax iterations: same token
-    block, same cache, same positions — the fusion changes dispatch
-    count, not math."""
+    """decode_loop.decode_chunk over the GPT's paged step (greedy, no
+    finishes) is exactly `chunk` consecutive gpt_decode_step_pages +
+    argmax iterations: same token block, same arena, same positions —
+    the fusion changes dispatch count, not math."""
     import jax
     import jax.numpy as jnp
     cfg, params = trained
     rng = np.random.RandomState(9)
-    a = np.asarray(rng.randint(0, cfg.vocab_size, (1, 3)), np.int32)
-    b = np.asarray(rng.randint(0, cfg.vocab_size, (1, 6)), np.int32)
-    _, ca = gd.gpt_prefill(params, cfg, a, max_len=16)
-    _, cb = gd.gpt_prefill(params, cfg, b, max_len=16)
-    pool = jnp.concatenate([ca, cb], axis=2)
+    a = rng.randint(0, cfg.vocab_size, (3,)).astype(np.int32)
+    b = rng.randint(0, cfg.vocab_size, (6,)).astype(np.int32)
+    arena, pt_, _ = paged_pool(trained, [a, b])
     tokens = jnp.asarray([5, 9], jnp.int32)
     ts = jnp.asarray([3, 6], jnp.int32)
     keys = jax.random.split(jax.random.PRNGKey(0), 2)
-    temps = jnp.zeros((2,), jnp.float32)
-    done = jnp.zeros((2,), bool)
-    remaining = jnp.asarray([10, 10], jnp.int32)
-    eos = jnp.full((2,), -1, jnp.int32)
 
-    block, tok_f, pool_f, ts_f, _, done_f, rem_f = gd.gpt_decode_chunk_slots(
-        params, cfg, tokens, pool, ts, keys, temps, done, remaining,
-        eos, chunk=4)
+    block, arena_f, _, carry, counters = decode_chunk(
+        gd.GPT_SERVING_MODEL, params, cfg, arena, pt_, keys,
+        carry_of(tokens, ts, [10, 10]), 4)
 
-    ref_pool, ref_tok, ref_ts = jnp.concatenate([ca, cb], axis=2), \
-        tokens, ts
+    ref_arena, ref_tok, ref_ts = arena, tokens, ts
     ref_rows = []
     for _ in range(4):
-        logits, ref_pool = gd.gpt_decode_step_slots(
-            params, cfg, ref_tok, ref_pool, ref_ts)
+        logits, ref_arena = gd.gpt_decode_step_pages(
+            params, cfg, ref_tok, ref_arena, pt_, ref_ts)
         ref_tok = jnp.argmax(logits, -1).astype(jnp.int32)
         ref_ts = ref_ts + 1
         ref_rows.append(np.asarray(ref_tok))
     np.testing.assert_array_equal(np.asarray(block), np.stack(ref_rows))
-    np.testing.assert_array_equal(np.asarray(tok_f), ref_rows[-1])
-    np.testing.assert_array_equal(np.asarray(ts_f), np.asarray(ref_ts))
-    np.testing.assert_allclose(np.asarray(pool_f), np.asarray(ref_pool),
+    np.testing.assert_array_equal(np.asarray(carry.tokens), ref_rows[-1])
+    np.testing.assert_array_equal(np.asarray(carry.ts), np.asarray(ref_ts))
+    np.testing.assert_allclose(np.asarray(arena_f), np.asarray(ref_arena),
                                rtol=1e-5, atol=1e-5)
-    assert not np.asarray(done_f).any()
-    np.testing.assert_array_equal(np.asarray(rem_f), [6, 6])
+    assert not np.asarray(carry.done).any()
+    np.testing.assert_array_equal(np.asarray(carry.remaining), [6, 6])
+    assert counters is None
 
 
 def test_chunk_kernel_freezes_exhausted_slot(trained):
     """A slot whose budget runs out mid-chunk rides along frozen: its
     column repeats the final token, ts stops advancing, and the OTHER
-    slot's stream/cache rows are untouched by the freeze."""
+    slot's stream is untouched by the freeze."""
     import jax
     import jax.numpy as jnp
     cfg, params = trained
     rng = np.random.RandomState(10)
-    a = np.asarray(rng.randint(0, cfg.vocab_size, (1, 4)), np.int32)
-    b = np.asarray(rng.randint(0, cfg.vocab_size, (1, 4)), np.int32)
-    _, ca = gd.gpt_prefill(params, cfg, a, max_len=16)
-    _, cb = gd.gpt_prefill(params, cfg, b, max_len=16)
-    pool = jnp.concatenate([ca, cb], axis=2)
-    tokens = jnp.asarray([5, 9], jnp.int32)
-    ts = jnp.asarray([4, 4], jnp.int32)
-    keys = jax.random.split(jax.random.PRNGKey(1), 2)
-    temps = jnp.zeros((2,), jnp.float32)
-    done = jnp.zeros((2,), bool)
-    remaining = jnp.asarray([2, 10], jnp.int32)    # slot 0 freezes at 2
-    eos = jnp.full((2,), -1, jnp.int32)
-    block, tok_f, _, ts_f, _, done_f, _ = gd.gpt_decode_chunk_slots(
-        params, cfg, tokens, pool, ts, keys, temps, done, remaining,
-        eos, chunk=5)
+    a = rng.randint(0, cfg.vocab_size, (4,)).astype(np.int32)
+    b = rng.randint(0, cfg.vocab_size, (4,)).astype(np.int32)
+    arena, pt_, _ = paged_pool(trained, [a, b])
+    block, _, _, carry, _ = decode_chunk(
+        gd.GPT_SERVING_MODEL, params, cfg, arena, pt_,
+        jax.random.split(jax.random.PRNGKey(1), 2),
+        carry_of([5, 9], [4, 4], [2, 10]), 5)     # slot 0 freezes at 2
     col0 = np.asarray(block)[:, 0]
     assert (col0[2:] == col0[1]).all()             # frozen repeats
-    assert np.asarray(ts_f)[0] == 4 + 2            # advanced twice only
-    assert np.asarray(done_f).tolist() == [True, False]
+    assert np.asarray(carry.ts)[0] == 4 + 2        # advanced twice only
+    assert np.asarray(carry.done).tolist() == [True, False]
     # slot 1 unaffected: matches a solo unfrozen run of the same chunk
-    solo, _, _, _, _, _, _ = gd.gpt_decode_chunk_slots(
-        params, cfg, jnp.asarray([9], jnp.int32), cb,
-        jnp.asarray([4], jnp.int32), jax.random.split(
-            jax.random.PRNGKey(2), 1), jnp.zeros((1,), jnp.float32),
-        jnp.zeros((1,), bool), jnp.asarray([10], jnp.int32),
-        jnp.full((1,), -1, jnp.int32), chunk=5)
+    arena1, pt1, _ = paged_pool(trained, [b])
+    solo, *_ = decode_chunk(
+        gd.GPT_SERVING_MODEL, params, cfg, arena1, pt1,
+        jax.random.split(jax.random.PRNGKey(2), 1),
+        carry_of([9], [4], [10]), 5)
     np.testing.assert_array_equal(np.asarray(block)[:, 1],
                                   np.asarray(solo)[:, 0])
 
@@ -909,45 +945,38 @@ def test_cancel_releases_pages_on_device(trained):
 # ---------------------------------------------------------------------------
 
 def test_spec_chunk_kernel_commits_nonspec_stream(trained):
-    """Kernel pin (slab path): gpt_decode_chunk_slots with speculate_k>0
-    commits EXACTLY the non-speculative stream — acceptance changes how
-    many tokens each verify pass emits (the counts column), never which
-    tokens — and the carry (ts/remaining) advances by the committed
-    totals."""
+    """Loop pin: decode_loop.decode_chunk with speculate_k>0 over the
+    GPT's verify pass commits EXACTLY the non-speculative stream —
+    acceptance changes how many tokens each verify pass emits (the
+    counts column), never which tokens — and the carry (ts/remaining)
+    advances by the committed totals."""
     import jax
     import jax.numpy as jnp
     cfg, params = trained
     rng = np.random.RandomState(40)
-    a = np.asarray(rng.randint(0, cfg.vocab_size, (1, 3)), np.int32)
-    b = np.asarray(rng.randint(0, cfg.vocab_size, (1, 6)), np.int32)
-    _, ca = gd.gpt_prefill(params, cfg, a, max_len=32)
-    _, cb = gd.gpt_prefill(params, cfg, b, max_len=32)
-    tok0 = jnp.asarray([5, 9], jnp.int32)
-    ts = jnp.asarray([3, 6], jnp.int32)
+    a = rng.randint(0, cfg.vocab_size, (3,)).astype(np.int32)
+    b = rng.randint(0, cfg.vocab_size, (6,)).astype(np.int32)
+    arena, pt_, _ = paged_pool(trained, [a, b])
     keys = jax.random.split(jax.random.PRNGKey(0), 2)
-    temps = jnp.zeros((2,), jnp.float32)
-    done = jnp.zeros((2,), bool)
-    rem = jnp.asarray([20, 20], jnp.int32)
-    eos = jnp.full((2,), -1, jnp.int32)
 
-    ref_block, *_ = gd.gpt_decode_chunk_slots(
-        params, cfg, tok0, jnp.concatenate([ca, cb], axis=2), ts, keys,
-        temps, done, rem, eos, chunk=6)
+    ref_block, *_ = decode_chunk(
+        gd.GPT_SERVING_MODEL, params, cfg, arena, pt_, keys,
+        carry_of([5, 9], [3, 6], [20, 20]), 6)
     ref = np.asarray(ref_block)                    # (6, 2)
 
     spec = (jnp.zeros((2,), jnp.int32),
             jnp.full((2, 65), -1, jnp.int32))      # ngram table T=64
-    block, counts, _, _, ts_f, _, _, rem_f, _ = gd.gpt_decode_chunk_slots(
-        params, cfg, tok0, jnp.concatenate([ca, cb], axis=2), ts, keys,
-        temps, done, rem, eos, chunk=6, speculate_k=3, spec_state=spec)
+    (block, counts), _, _, carry, _ = decode_chunk(
+        gd.GPT_SERVING_MODEL, params, cfg, arena, pt_, keys,
+        carry_of([5, 9], [3, 6], [20, 20], spec=spec), 6, speculate_k=3)
     block, counts = np.asarray(block), np.asarray(counts)
     for s in range(2):
         committed = [int(block[i, j, s]) for i in range(6)
                      for j in range(counts[i, s])]
         assert committed[:6] == list(ref[:, s])
         total = counts[:, s].sum()
-        assert np.asarray(ts_f)[s] == [3, 6][s] + total
-        assert np.asarray(rem_f)[s] == 20 - total
+        assert np.asarray(carry.ts)[s] == [3, 6][s] + total
+        assert np.asarray(carry.remaining)[s] == 20 - total
     assert (counts >= 1).all() and (counts <= 4).all()
 
 
