@@ -455,7 +455,9 @@ def phase_serve(params, cfg, prompt_lens, shared_prefix, max_new_tokens,
                  f"{engine.scheduler.compile_events}")
         facts.update(compiled_executables=stats["compiled_executables"],
                      prefix_hits=stats["prefix_hits"],
-                     pool_bytes=stats["pool_bytes"])
+                     pool_bytes=stats["pool_bytes"],
+                     decode_attention=stats["decode_attention"],
+                     prefill_attention=stats["prefill_attention"])
 
         greedy = [i for i in range(n) if i % 2 == 0]
         deficits, logit_std = _reference_deficits(

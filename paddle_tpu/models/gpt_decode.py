@@ -37,7 +37,8 @@ import numpy as np
 __all__ = ["collect_gpt_params", "quantize_params", "gpt_forward_logits",
            "gpt_prefill", "gpt_decode_step", "gpt_prefill_pages",
            "gpt_decode_step_pages", "gpt_decode_verify_pages",
-           "paged_arena_shapes", "decode_attention_path", "gpt_generate",
+           "paged_arena_shapes", "decode_attention_path",
+           "prefill_attention_path", "gpt_generate",
            "ADAPTER_PROJECTIONS", "GPT_SERVING_MODEL"]
 
 # projections the low-rank adapter path covers (every matmul in the
@@ -557,8 +558,36 @@ def _kv_gather(arena, li, pages, dtype):
     return k.astype(dtype) * s[..., 0:1], v.astype(dtype) * s[..., 1:2]
 
 
+def _kernel_arena(arena, arena_constraint):
+    """Whether a Mosaic kernel may sit beside this arena: the backend is
+    a TPU, the arena is the bare full-precision array with a
+    lane-aligned K|V row, and no mesh plan constrains it."""
+    import jax
+    data, scales = _arena_parts(arena)
+    return (scales is None and arena_constraint is None
+            and data.shape[-1] % 128 == 0
+            and jax.default_backend() == "tpu")
+
+
+def prefill_attention_path(arena, bucket, arena_constraint=None):
+    """Which attention a COLD prompt's prefill runs in a bucket of
+    `bucket` rows, read off its input like decode_attention_path:
+    "flash" (ops/flash_attention's forward over the prompt's own rows)
+    on an arena a kernel may sit beside (`_kernel_arena`) when the
+    bucket is whole 128-row tiles; "gather" (the page row gathered back
+    and masked by position) for everything else. On the quantized arena
+    a cold prompt must go on attending over its dequantized rows, or a
+    chunked prefill, whose later chunks read those rows, and a whole
+    one stop agreeing. A prefill with rows already cached (pfx_len > 0)
+    gathers whatever this says."""
+    if bucket % 128 == 0 and _kernel_arena(arena, arena_constraint):
+        return "flash"
+    return "gather"
+
+
 def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
-                      pages, adapters=None, adapter_id=None):
+                      pages, adapters=None, adapter_id=None,
+                      arena_constraint=None):
     """Paged prefill of ONE sequence's prompt SUFFIX into its arena
     blocks, attending over an already-cached prefix through the page
     table — the single prefill entry point of the paged serving pool
@@ -580,9 +609,20 @@ def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
     always computed here and the first-token logits need no cached
     activations. arena: see paged_arena_shapes.
     pages: (P,) int32 — THIS sequence's page row; suffix K/V rows are
-    scattered to block pages[pos // bs] offset pos % bs, and attention
-    gathers the whole row back (prefix blocks included) so hit blocks
-    are never recomputed. Pad positions (j >= real_len) write to the
+    written to block pages[pos // bs] offset pos % bs as whole pages.
+    The attention is ONE algorithm, the causal softmax of the suffix's
+    queries, in two forms chosen by the traced pfx_len under one
+    `lax.cond`. WARM (pfx_len > 0: a prefix hit, a later chunk): the
+    whole page row is gathered back (prefix blocks included, so hit
+    blocks are never recomputed) and masked by position. COLD
+    (pfx_len == 0): the bucket's queries attend over the prompt's own
+    k, v, nothing gathered; where prefill_attention_path says "flash"
+    through the flash forward (no score matrix in HBM, no work on the
+    keys past the bucket), else through the gather as well, and the
+    program is then the one it was before there were two forms (no
+    cond). `arena_constraint` is the mesh plan's layout pin or None,
+    only asked whether there is one. Pad positions (j >= real_len)
+    compute values nobody reads in either form and write to the
     SCRATCH block unconditionally: with a large hit prefix and a small
     suffix bucket, pfx_len + bucket can run past max_pages*bs, where a
     clamped page gather would collide a pad write with a real row — and
@@ -599,6 +639,7 @@ def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
     every projection gathers its A/B rows and adds the low-rank delta
     (id 0 selects the base output bit-exactly), so the prompt's K/V
     rows are computed under the same adapter the decode path serves."""
+    import jax
     import jax.numpy as jnp
 
     heads, hd = cfg.heads, cfg.hidden // cfg.heads
@@ -607,6 +648,9 @@ def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
     bs = data.shape[4]
     L = pages.shape[0] * bs
     dtype = _arena_compute_dtype(params, data, _scales)
+    attention = prefill_attention_path(arena, B, arena_constraint)
+    if attention == "flash":
+        from ..ops.flash_attention import flash_causal_rows
     live = None if adapters is None else (adapter_id != 0)
     j = jnp.arange(B)
     pos = pfx_len + j                              # absolute positions
@@ -620,14 +664,24 @@ def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
         v = _dense_a(h, blk["v"], la["v"]).reshape(B, heads, hd)
         # pad rows reach no page but scratch block 0 (see docstring)
         arena = _kv_write_pages(arena, li, pages, pfx_len, real_len, k, v)
-        K, V = _kv_gather(arena, li, pages, dtype)  # (heads, L, hd)
-        scores = jnp.einsum("bnd,nkd->bnk", q, K,
-                            preferred_element_type=jnp.float32)
-        scores = jnp.where(mask[:, None, :], scores / np.sqrt(hd), -1e30)
-        probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
-        probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
-        ctx = jnp.einsum("bnk,nkd->bnd", probs, V).reshape(B, -1)
-        x = x + _dense_a(ctx, blk["out"], la["out"])
+
+        def warm(arena, li=li, q=q):
+            K, V = _kv_gather(arena, li, pages, dtype)  # (heads, L, hd)
+            scores = jnp.einsum("bnd,nkd->bnk", q, K,
+                                preferred_element_type=jnp.float32)
+            scores = jnp.where(mask[:, None, :], scores / np.sqrt(hd),
+                               -1e30)
+            probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
+            probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
+            return jnp.einsum("bnk,nkd->bnd", probs, V)
+
+        if attention == "flash":
+            def cold(arena, q=q, k=k, v=v):
+                return flash_causal_rows(q, k, v, 1.0 / np.sqrt(hd))
+            ctx = jax.lax.cond(pfx_len == 0, cold, warm, arena)
+        else:
+            ctx = warm(arena)
+        x = x + _dense_a(ctx.reshape(B, -1), blk["out"], la["out"])
         h = _ln(x, blk["ln2"])
         x = x + _dense_a(_gelu_tanh(_dense_a(h, blk["mlp1"], la["mlp1"])),
                          blk["mlp2"], la["mlp2"])
@@ -646,11 +700,7 @@ def decode_attention_path(arena, arena_constraint=None):
     (gpt_decode_verify_pages, several query rows a slot) always
     gathers. One algorithm; the form of the input says whether the
     kernel applies, and no option or environment variable does."""
-    import jax
-    data, scales = _arena_parts(arena)
-    if (scales is None and arena_constraint is None
-            and data.shape[-1] % 128 == 0
-            and jax.default_backend() == "tpu"):
+    if _kernel_arena(arena, arena_constraint):
         return "paged_kernel"
     return "gather"
 
@@ -846,6 +896,9 @@ class _GPTServingModel(ServingModel):
 
     def decode_attention_path(self, arena, arena_constraint=None):
         return decode_attention_path(arena, arena_constraint)
+
+    def prefill_attention_path(self, arena, bucket, arena_constraint=None):
+        return prefill_attention_path(arena, bucket, arena_constraint)
 
     def prefill(self, *args, **kw):
         return gpt_prefill_pages(*args, **kw) + (None,)

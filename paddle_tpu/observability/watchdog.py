@@ -230,14 +230,17 @@ class FlightRecorder:
             shutil.rmtree(self._written.pop(0), ignore_errors=True)
 
     def records(self) -> List[str]:
-        """Existing record paths, oldest first."""
-        try:
-            return [os.path.join(self.base_dir, d)
-                    for d in sorted(os.listdir(self.base_dir))
-                    if d.startswith("flight_")
-                    and os.path.isdir(os.path.join(self.base_dir, d))]
-        except OSError:
-            return []
+        """Existing record paths, oldest first. Taken under the dump's
+        lock, so a record this recorder is still writing (its files
+        exist, `meta.json` still empty) is never listed."""
+        with self._lock:
+            try:
+                return [os.path.join(self.base_dir, d)
+                        for d in sorted(os.listdir(self.base_dir))
+                        if d.startswith("flight_")
+                        and os.path.isdir(os.path.join(self.base_dir, d))]
+            except OSError:
+                return []
 
 
 class Watchdog:

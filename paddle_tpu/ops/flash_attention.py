@@ -27,7 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["attention", "attention_fwd_lse", "attention_bwd_saved",
-           "flash_attention", "flash_dispatch", "mha_reference"]
+           "flash_attention", "flash_causal_rows", "flash_dispatch",
+           "mha_reference"]
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -435,19 +436,21 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, widths)
 
 
-def _flash_call(q, k, v, bias, causal, sm_scale, interpret):
+def _flash_call(q, k, v, bias, causal, sm_scale, interpret, blocks=None):
     """q: (bn, sq, d); k: (bn, sk, d); v: (bn, sk, dv); bias: (bn, sk) or
     None. Returns o (bn, sq, dv) unpadded and lse (bn, sq_pad, 128)
     lane-padded. The forward takes a value width of its own (latent
     attention's prefill: q, k of 192 and v of 128); the backward kernels
-    do not, and training never asks."""
+    do not, and training never asks. `blocks` (block_q, block_k) stands
+    in for `_pick_blocks`' choice where a caller knows its grid better
+    (`flash_causal_rows`); the backward has no such argument."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bn, sq0, d = q.shape
     dv = v.shape[-1]
     sk0 = k.shape[1]
-    block_q, block_k = _pick_blocks(sq0, sk0)
+    block_q, block_k = blocks or _pick_blocks(sq0, sk0)
     q = _pad_to(q, 1, block_q)
     k = _pad_to(k, 1, block_k)
     v = _pad_to(v, 1, block_k)
@@ -715,6 +718,44 @@ def flash_dispatch(q, k, bias=None, impl: Optional[str] = None):
             "impl='flash' compiles for TPU (Mosaic) and interprets on "
             f"CPU for tests; the active backend is {platform!r}")
     return use, platform == "cpu"
+
+
+# a serving prefill's longest one-tile bucket: one sequence gives the
+# grid only `heads` programs a layer, so a tile per head beats many small
+# ones (TPU v5e, 25 heads x 64, ms a layer: 768 rows as 128 x 128 tiles
+# 0.284, as one tile 0.089; 1,024 rows as 512 x 512 tiles 0.127, as one
+# 0.128), and (1024, 1024) float32 scores still fit the kernel's VMEM
+_ONE_TILE_ROWS = 1024
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def _causal_rows_call(q, k, v, sm_scale, interpret):
+    # jitted like ops/paged_attention's calls: a program that unrolls its
+    # layers traces and lowers the kernel once and calls it from each
+    rows = q.shape[0]
+    o, _ = _flash_call(
+        q.swapaxes(0, 1), k.swapaxes(0, 1), v.swapaxes(0, 1), None, True,
+        sm_scale, interpret,
+        blocks=(rows, rows) if rows <= _ONE_TILE_ROWS else None)
+    return o.swapaxes(0, 1)
+
+
+def flash_causal_rows(q, k, v, sm_scale):
+    """Causal self-attention of ONE sequence's rows by the tiled flash
+    forward, for a serving prefill: q, k, v (rows, heads, d) as the
+    projections leave them, row i attending over rows 0..i; returns
+    (rows, heads, d). No residuals, no backward. Up to _ONE_TILE_ROWS
+    the sequence is one tile a head (`_pick_blocks` would cut 768 rows,
+    no multiple of 512, into 36 tiles of 128 x 128); longer ones take
+    `_pick_blocks`' tiles. Compiled by Mosaic on a TPU backend,
+    interpreted on the CPU (a test facility), an error on any other
+    backend, like `flash_dispatch`'s forced kernel."""
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(
+            "flash_causal_rows compiles for TPU (Mosaic) and interprets "
+            f"on CPU for tests; the active backend is {platform!r}")
+    return _causal_rows_call(q, k, v, float(sm_scale), platform == "cpu")
 
 
 def attention(q, k, v, bias=None, causal: bool = False,

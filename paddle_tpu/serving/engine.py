@@ -1422,6 +1422,17 @@ class ServingEngine:
         # "paged_kernel", "latent_paged_kernel" or "gather": the decode
         # step's attention path
         s["decode_attention"] = self.scheduler.decode_attention
+        # the prefill's: "flash" if a cold prompt attends over its own
+        # rows through the flash forward in some bucket (`flash_buckets`
+        # says which), else what the largest bucket runs ("gather";
+        # None: the model does not say), and how many prefills were
+        # dispatched cold under either and warm (rows already cached: a
+        # prefix hit, a later chunk; gathers)
+        verdicts = self.scheduler.prefill_attention
+        flash = [b for b, path in verdicts.items() if path == "flash"]
+        s["prefill_attention"] = dict(
+            self.scheduler.prefill_counts, flash_buckets=flash,
+            path="flash" if flash else verdicts[max(verdicts)])
         # the served architecture, what a token costs the arena in a
         # layer, and the model's own in-graph counters (a routed model's
         # `expert_tokens` and `router_tokens` since start)

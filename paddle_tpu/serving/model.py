@@ -56,7 +56,8 @@ class ServingModel:
     Array contracts (S slots, P pages a slot, B a prefill bucket, V
     the vocabulary):
       prefill(params, cfg, tokens (1, B), pfx_len, real_len, arena,
-              pages (P,), adapters=None, adapter_id=None)
+              pages (P,), adapters=None, adapter_id=None
+              [, arena_constraint=<the mesh plan's pin>: feature "mesh"])
           -> (logits (1, V) f32 of position pfx_len + real_len - 1,
               arena, counters)
           the real_len real tokens of a right-padded suffix at positions
@@ -109,6 +110,13 @@ class ServingModel:
         """Which attention `decode_step` runs on this arena, for
         `engine.stats()["decode_attention"]`."""
         raise NotImplementedError
+
+    def prefill_attention_path(self, arena, bucket, arena_constraint=None):
+        """Which attention `prefill` runs for a COLD prompt in a bucket
+        of `bucket` rows ("flash" or "gather"), for
+        `engine.stats()["prefill_attention"]`; None: the model does not
+        say."""
+        return None
 
     def counter_names(self, cfg):
         """{name: shape} of the counters the programs return."""

@@ -538,9 +538,19 @@ class ContinuousBatchingScheduler:
         # what it is given (the arena's form, the mesh plan, the
         # backend) and fixed for the engine's life; speculation decodes
         # through the verify pass, which gathers
+        pin = None if plan is None else plan.constrain_arena
         self.decode_attention = "gather" if self.speculate_k else \
-            self.model.decode_attention_path(
-                kv.arena, None if plan is None else plan.constrain_arena)
+            self.model.decode_attention_path(kv.arena, pin)
+        # which attention a COLD prompt's prefill runs in each bucket,
+        # by the model's word on the same inputs (None: the model does
+        # not say), and the host's counts of the prefills it dispatched:
+        # the scheduler knows the hit length and the chunk cursor at
+        # dispatch, so nothing is fetched. A prefill with rows already
+        # cached (a prefix hit, a later chunk) is warm and gathers.
+        self.prefill_attention = {
+            b: self.model.prefill_attention_path(kv.arena, b, pin)
+            for b in buckets}
+        self.prefill_counts = {"cold_flash": 0, "cold_gather": 0, "warm": 0}
         # chunked prefill (None = monolithic, bit-identical to the
         # pre-knob engine with zero new executables): the per-tick
         # prefill token budget AND the per-dispatch chunk ceiling
@@ -693,6 +703,8 @@ class ContinuousBatchingScheduler:
             c_payload = self.plan.constrain_payload
             c_rep = self.plan.constrain_rep
             arena_con = self.plan.constrain_arena
+        # only a model that declares "mesh" is ever handed the pin
+        pinned = {} if arena_con is None else {"arena_constraint": arena_con}
 
         # adapter extras ride VARARGS tails: adapterless callers pass
         # nothing, so the traced adapterless graphs are argument-for-
@@ -706,7 +718,7 @@ class ContinuousBatchingScheduler:
             logits, arena, counters = model.prefill(
                 params, self.cfg, tokens, start, real_len, arena,
                 pages, adapters=alo[0] if alo else None,
-                adapter_id=alo[1] if alo else None)
+                adapter_id=alo[1] if alo else None, **pinned)
             pt = pt.at[slot].set(pages)
             if self.speculate_k:
                 # slot reuse hygiene: wipe the previous occupant's
@@ -1068,6 +1080,7 @@ class ContinuousBatchingScheduler:
         padded = self._staging_for(bucket)
         padded[0, :suffix_len] = prompt[0, pfx_len:]
         padded[0, suffix_len:] = 0
+        self._count_prefill(bucket, pfx_len)
         with profiler.RecordEvent("serving/prefill", bucket=bucket,
                                   prompt_len=p_len, slot=slot,
                                   prefix_len=pfx_len,
@@ -1092,6 +1105,15 @@ class ContinuousBatchingScheduler:
                        slot=slot, bucket=bucket, prompt_len=p_len,
                        prefix_len=int(pfx_len), suffix_len=suffix_len)
         return event
+
+    def _count_prefill(self, bucket: int, start: int) -> None:
+        """One prefill dispatch of `bucket` rows starting at position
+        `start`, counted under the attention it runs: the device's
+        `pfx_len == 0` branch, decided here from the same number (plain
+        "cold" where the model gives no verdict)."""
+        path = self.prefill_attention[bucket]
+        key = "warm" if start else "cold" if path is None else "cold_" + path
+        self.prefill_counts[key] = self.prefill_counts.get(key, 0) + 1
 
     def _sample_first(self, slot, req, logits, p_len, max_new,
                       temperature, seed, eos_id, prev_tok,
@@ -1177,6 +1199,7 @@ class ContinuousBatchingScheduler:
         padded[0, :n] = pf.suffix[pf.cursor:pf.cursor + n]
         padded[0, n:] = 0
         start = pf.start + pf.cursor
+        self._count_prefill(bucket, start)
         with profiler.RecordEvent("serving/prefill_chunk", bucket=bucket,
                                   prompt_len=pf.p_len, slot=slot,
                                   start_pos=start, chunk_len=n,

@@ -400,6 +400,127 @@ def test_decode_attention_path_is_read_off_the_input(trained, monkeypatch):
     assert path(speculate_k=2) == "gather"
 
 
+# -- prefill attention: the flash forward against the gather path ------------
+
+def _prefill_case(bucket, pfx_len=0):
+    return dict(bucket=bucket, pfx_len=pfx_len)
+
+
+PREFILL_CASES = {"b128": _prefill_case(128), "b256": _prefill_case(256),
+                 "b512": _prefill_case(512),
+                 # rows already cached: the cond's other branch
+                 "b128-warm": _prefill_case(128, pfx_len=2 * BS)}
+
+
+@pytest.fixture(scope="module")
+def wide_bf16():
+    """A GPT whose heads are 64 wide (the width the kernels serve), in
+    bfloat16: (cfg, params)."""
+    import jax.numpy as jnp
+    cfg = GPTConfig(vocab_size=97, hidden=128, layers=2, heads=2,
+                    max_pos=512, dropout=0.0, attn_impl="xla")
+    main, startup, _ = gpt_lm_program(cfg, 8, is_test=True)
+    exe, scope = pt.Executor(), pt.Scope()
+    with pt.scope_guard(scope):
+        exe.run(startup)
+        return cfg, gd.collect_gpt_params(scope, cfg, dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("case", list(PREFILL_CASES), ids=list(PREFILL_CASES))
+def test_prefill_flash_matches_gather(case, wide_bf16, monkeypatch):
+    """A cold prompt attended over its own rows by the flash forward
+    (interpreted on the CPU, the path forced as the chip would choose
+    it) against the gather path: real_len below the bucket and no
+    multiple of the block size. The first layer's rows in the arena are
+    bit-equal (same projections of the same input), deeper ones and the
+    last position's logits within bfloat16's rounding (the forms round
+    their probabilities at different places). With rows already cached
+    the cond takes the gather branch and the result is today's
+    exactly."""
+    import jax.numpy as jnp
+    cfg, params = wide_bf16
+    c = PREFILL_CASES[case]
+    bucket, pfx_len = c["bucket"], c["pfx_len"]
+    real_len = bucket - 27                   # 101, 229, 485: BS divides none
+    pages = -(-(pfx_len + bucket) // BS)
+    shape, _ = gd.paged_arena_shapes(cfg.layers, pages + 1, cfg.heads, BS, 64)
+    row = jnp.asarray(np.random.RandomState(bucket).permutation(pages) + 1,
+                      jnp.int32)
+    rng = np.random.RandomState(bucket + 1)
+    arena = jnp.zeros(shape, jnp.bfloat16)
+    if pfx_len:
+        prefix = jnp.asarray(rng.randint(0, 97, (1, pfx_len)), jnp.int32)
+        _, arena = gd.gpt_prefill_pages(params, cfg, prefix, 0, pfx_len,
+                                        arena, row)
+    tokens = np.zeros((1, bucket), np.int32)
+    tokens[0, :real_len] = rng.randint(0, 97, real_len)
+
+    def run():
+        return gd.gpt_prefill_pages(params, cfg, jnp.asarray(tokens),
+                                    jnp.int32(pfx_len), jnp.int32(real_len),
+                                    arena, row)
+
+    want, want_arena = run()
+    monkeypatch.setattr(gd, "prefill_attention_path",
+                        lambda *a, **k: "flash")
+    got, got_arena = run()
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))
+    if pfx_len:
+        np.testing.assert_array_equal(f32(got), f32(want))
+        np.testing.assert_array_equal(f32(got_arena), f32(want_arena))
+        return
+    assert np.abs(f32(want)).max() > 0.1      # logits of some size
+    np.testing.assert_allclose(f32(got), f32(want), rtol=2e-2, atol=2e-2)
+    # every block but scratch (pad rows of either form land there)
+    np.testing.assert_array_equal(f32(got_arena)[0, :, 1:],
+                                  f32(want_arena)[0, :, 1:])
+    np.testing.assert_allclose(f32(got_arena)[1:, :, 1:],
+                               f32(want_arena)[1:, :, 1:],
+                               rtol=2e-2, atol=2e-2)
+
+
+def test_prefill_attention_path_is_read_off_the_input(monkeypatch):
+    """The twin of the decode test: the backend, the arena's form, the
+    mesh constraint and the bucket pick a cold prefill's attention, and
+    engine.stats() says which buckets run the flash forward."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    shape, scale_shape = gd.paged_arena_shapes(2, 5, 2, 4, 64)
+    bare = jnp.zeros(shape, jnp.bfloat16)
+    quantized = (jnp.zeros(shape, jnp.int8),
+                 jnp.zeros(scale_shape, jnp.float32))
+    narrow = jnp.zeros(gd.paged_arena_shapes(2, 5, 4, 4, 8)[0])
+    assert gd.prefill_attention_path(bare, 128) == "gather"     # the CPU
+    cfg = GPTConfig(vocab_size=97, hidden=128, layers=2, heads=2,
+                    max_pos=256, dropout=0.0, attn_impl="xla")
+    params = _params_like(cfg)
+    sizes = dict(num_slots=2, max_len=256, block_size=4,
+                 prefill_buckets=(64, 128))
+
+    def verdict(**kw):
+        engine = ServingEngine(params, cfg, ServingConfig(**sizes, **kw))
+        try:
+            s = engine.stats()["prefill_attention"]
+            return s["path"], s["flash_buckets"]
+        finally:
+            engine.close()
+
+    assert verdict() == ("gather", [])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert gd.prefill_attention_path(bare, 128) == "flash"
+    assert gd.prefill_attention_path(bare, 1024) == "flash"
+    assert gd.prefill_attention_path(bare, 64) == "gather"
+    assert gd.prefill_attention_path(bare, 192) == "gather"
+    assert gd.prefill_attention_path(quantized, 128) == "gather"
+    assert gd.prefill_attention_path(bare, 128, lambda a: a) == "gather"
+    assert gd.prefill_attention_path(narrow, 128) == "gather"
+    assert verdict() == ("flash", [128])
+    assert verdict(kv_dtype="int8") == ("gather", [])
+    assert verdict(mesh_shape=(2,)) == ("gather", [])
+
+
 def _params_like(cfg):
     """Parameter pytree of `cfg`, from a fresh startup program."""
     main, startup, _ = gpt_lm_program(cfg, 8, is_test=True)

@@ -2696,6 +2696,51 @@ def test_chunked_prefill_stream_identity_matrix(trained, k, kv_dtype):
     assert s["completed"] == 6
 
 
+def test_prefill_attention_counts_cold_warm_where_they_belong(
+        trained, monkeypatch):
+    """stats()["prefill_attention"]: a cold prompt, a prefix hit and
+    the chunks of a chunked prompt, counted by the host under the
+    attention each dispatch runs. Then the same with the flash forward
+    engaged as on the chip (the verdict forced for the 16 bucket, the
+    kernel interpreted): cold prompts count as cold_flash, the hit goes
+    through the cond's gather branch, and the greedy streams are the
+    sequential path's."""
+    rng = np.random.RandomState(30)
+    cfg, _ = trained
+    p = rng.randint(0, cfg.vocab_size, (10,)).astype(np.int32)
+    short = rng.randint(0, cfg.vocab_size, (3,)).astype(np.int32)
+    sizes = dict(prefill_buckets=(4, 16), block_size=4)
+
+    def counts(eng):
+        s = eng.stats()["prefill_attention"]
+        return s["path"], (s["cold_flash"], s["cold_gather"], s["warm"])
+
+    def serve():
+        eng = make_engine(trained, **sizes)
+        assert counts(eng)[1] == (0, 0, 0)
+        (cold,) = eng.generate([p], max_new_tokens=6)
+        first = counts(eng)
+        (hit,) = eng.generate([p], max_new_tokens=6)      # 2 blocks shared
+        eng.generate([short], max_new_tokens=2)           # the 4 bucket
+        after = counts(eng)
+        eng.close()
+        np.testing.assert_array_equal(cold, sequential_ref(trained, p, 6))
+        np.testing.assert_array_equal(hit, cold)
+        return first, after
+
+    assert serve() == (("gather", (0, 1, 0)), ("gather", (0, 2, 1)))
+    # chunks of 4: the first starts at 0 and is cold, the rest are warm
+    eng = make_engine(trained, prefill_chunk=4, prefix_cache=False, **sizes)
+    eng.generate([p], max_new_tokens=6)
+    assert counts(eng) == ("gather", (0, 1, 2))
+    eng.close()
+
+    monkeypatch.setattr(
+        gd, "prefill_attention_path",
+        lambda arena, bucket, con=None: "flash" if bucket == 16 else "gather")
+    assert serve() == (("flash", (1, 0, 0)), ("flash", (1, 1, 1)))
+
+
 def test_chunked_prefill_mid_batch_long_prompt_does_not_stall_streams(
         trained):
     """Behavioral half of the tentpole: a long prompt admitted while
