@@ -1,4 +1,6 @@
-"""Moonlight-16B-A3B (the published DeepSeek-V3 block) on the serving path.
+"""The published DeepSeek-V3 block on the serving path: Moonlight-16B-A3B
+as it stands, and Xing4.0-29B-A4B (`model_type: xing4_0`) by three fields
+of the one config.
 
 Multi-head LATENT attention and routed + shared experts, served through
 serving.model.ServingModel by the same engine, scheduler, block allocator
@@ -33,21 +35,51 @@ and fused chunk loop (serving/decode_loop.py) as the GPT family:
     weight; `expert_product_path` says which, and the in-graph counter
     `moe_kernel_passes` counts the layers that ran the kernel.
 
-Parameters (`x @ W`, W is (in, out); no bias anywhere):
-  wte (V, h), head (h, V), norm_f (h,), layers[i]:
-    norm1, norm2 (h,); wq (h, heads*(nope+rope)); wkva (h, rank+rope);
-    kv_norm (rank,); wkvb (rank, heads*(nope+v)), a head's [k_nope | v];
-    wo (heads*v, h); then a dense layer's gate, up (h, I), down (I, h),
-    or an expert layer's router (h, E), router_bias (E,) float32,
-    w_gate, w_up (E, h, F), w_down (E, F, h), shared_gate, shared_up
-    (h, Fs), shared_down (Fs, h).
+What a config may change in the block (defaults are Moonlight's, whose
+program they leave as it was, to the bit):
+  * `q_lora_rank`: the query through a low-rank pair with a norm
+    between, `q = RMSNorm(h W_qa; q_norm) W_qb`;
+  * `rope_scaling`: YaRN's dict (`rope_frequencies`: each rotary
+    frequency blended between itself and itself over `factor`; the
+    softmax scale times mscale^2, `attention_scale`);
+  * `hc_mult` n > 1: the residual `x + f(norm(x))` of BOTH sublayers
+    becomes a manifold-constrained hyper-connection over n streams
+    (`_residual`): the state a layer hands on is (rows, n, h); a
+    sublayer reads `u = H_pre X`, and `X' = H_res X + outer(H_post, y)`
+    with H_pre, H_post from sigmoids and H_res doubly stochastic by
+    `hc_sinkhorn_iters` Sinkhorn rounds of `exp(clamp(.))`, all from the
+    token's own streams (`hc_coefficients`) and all in float32 whatever
+    the weights' type. X_0 is the embedding repeated n times; the final
+    norm reads the streams' sum. Scopes `hc/coeff`, `hc/pre`, `hc/post`;
+    in-graph counters `hc_passes`, `hc_rowsum_dev_ppm`. The choice is a
+    Python branch on the config: at n = 1 none of it is traced.
 
-Engine features: none of int8 weights or cache, adapters, speculation, a
-mesh plan or chunked prefill is implemented; the engine refuses each at
-construction from `features` below.
+Parameters (`x @ W`, W is (in, out); no bias anywhere but the mixers'):
+  wte (V, h), head (h, V), norm_f (h,), layers[i]:
+    norm1, norm2 (h,); wq (h, heads*(nope+rope)), or with `q_lora_rank` r
+    wqa (h, r), q_norm (r,), wqb (r, heads*(nope+rope)); wkva (h,
+    rank+rope); kv_norm (rank,); wkvb (rank, heads*(nope+v)), a head's
+    [k_nope | v]; wo (heads*v, h); then a dense layer's gate, up (h, I),
+    down (I, h), or an expert layer's router (h, E), router_bias (E,)
+    float32, w_gate, w_up (E, h, F), w_down (E, F, h), shared_gate,
+    shared_up (h, Fs), shared_down (Fs, h); with `hc_mult` n > 1 also
+    hc_attn and hc_ffn, one mixer a sublayer: hc_norm (n*h,), phi (n*h,
+    2n + n*n), b_pre, b_post (n,), b_res (n, n) and the scalar gates
+    a_pre, a_post, a_res, the last six float32.
+
+Still refused. Engine features: none of int8 weights or cache, adapters,
+speculation, a mesh plan or chunked prefill is implemented (a 16k-row
+bucket's workspace is what a chunked latent prefill would cut; the mixer
+under a mesh is unwritten); the engine refuses each at construction from
+`features` below. Of the architecture: a multi-token-prediction module
+(how it joins n streams is not public: benchmarks/lib/xing.py refuses
+`num_nextn_predict_layers` != 0), grouped routing (`n_group` > 1), a
+rope scaling that is not YaRN.
 """
 
 from __future__ import annotations
+
+import math
 
 import numpy as np
 
@@ -58,6 +90,7 @@ __all__ = ["MoonlightConfig", "init_params", "forward_logits",
            "prefill_pages", "decode_step_pages",
            "decode_attention_path", "absorbed_attention", "route",
            "grouped_experts", "expert_product_path", "rope",
+           "rope_frequencies", "attention_scale", "hc_coefficients",
            "MOONLIGHT_SERVING_MODEL"]
 
 _LANES = 128
@@ -65,7 +98,8 @@ _LANES = 128
 
 class MoonlightConfig:
     """The published keys under this package's names (defaults are
-    Moonlight-16B-A3B's `config.json`)."""
+    Moonlight-16B-A3B's `config.json`; Xing4.0-29B-A4B sets
+    `q_lora_rank`, `rope_scaling`, `hc_mult` and its `name`)."""
 
     def __init__(self, vocab_size=163840, hidden=2048, layers=27, heads=16,
                  kv_lora_rank=512, qk_nope_head_dim=128,
@@ -73,7 +107,10 @@ class MoonlightConfig:
                  moe_intermediate=1408, n_routed_experts=64,
                  n_shared_experts=2, experts_per_tok=6, first_k_dense=1,
                  routed_scaling_factor=2.446, rms_eps=1e-5,
-                 rope_theta=50000.0, max_pos=8192, init_range=0.02):
+                 rope_theta=50000.0, max_pos=8192, init_range=0.02,
+                 q_lora_rank=None, rope_scaling=None, hc_mult=1,
+                 hc_sinkhorn_iters=20, hc_eps=1e-6, hc_res_clamp=30.0,
+                 name="Moonlight-16B-A3B"):
         self.vocab_size = vocab_size
         self.hidden = hidden
         self.layers = layers
@@ -93,6 +130,24 @@ class MoonlightConfig:
         self.rope_theta = rope_theta
         self.max_pos = max_pos
         self.init_range = init_range
+        # the query through a low-rank pair and its norm (None: one matrix)
+        self.q_lora_rank = q_lora_rank
+        # None (plain RoPE) or the published YaRN dict: factor,
+        # original_max_position_embeddings, beta_fast, beta_slow, mscale,
+        # mscale_all_dim
+        if rope_scaling is not None and rope_scaling.get("type") != "yarn":
+            raise ValueError("rope_scaling is None or a YaRN dict, not "
+                             f"{rope_scaling!r}")
+        self.rope_scaling = rope_scaling
+        # residual streams: 1 is `x + f(norm(x))`; n > 1 the
+        # manifold-constrained hyper-connections over n streams
+        if hc_mult < 1:
+            raise ValueError(f"hc_mult is at least 1, not {hc_mult}")
+        self.hc_mult = hc_mult
+        self.hc_sinkhorn_iters = hc_sinkhorn_iters
+        self.hc_eps = hc_eps
+        self.hc_res_clamp = hc_res_clamp
+        self.name = name
 
     @property
     def qk_head_dim(self):
@@ -111,7 +166,11 @@ class MoonlightConfig:
         return -(-self.row_values // _LANES) * _LANES
 
     def serving_model(self):
-        return MOONLIGHT_SERVING_MODEL
+        """The one serving model of this block, under this config's
+        name (`engine.stats()["model"]`)."""
+        if self.name == MOONLIGHT_SERVING_MODEL.name:
+            return MOONLIGHT_SERVING_MODEL
+        return _MoonlightServingModel(self.name)
 
 
 def init_params(cfg: MoonlightConfig, key, dtype):
@@ -130,19 +189,41 @@ def init_params(cfg: MoonlightConfig, key, dtype):
     E, F = cfg.n_routed_experts, cfg.moe_intermediate
     Fs = cfg.n_shared_experts * F
     std = cfg.init_range
+    m, r = cfg.hc_mult, cfg.q_lora_rank
 
     def normal(k, shape):
         return (std * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
 
-    shapes = {"wq": (h, n * cfg.qk_head_dim), "wkva": (h, cfg.row_values),
+    shapes = {"wkva": (h, cfg.row_values),
               "wkvb": (cfg.kv_lora_rank,
                        n * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
               "wo": (n * cfg.v_head_dim, h)}
+    if r is None:
+        shapes = dict(wq=(h, n * cfg.qk_head_dim), **shapes)
+    else:
+        shapes = dict(wqa=(h, r), wqb=(r, n * cfg.qk_head_dim), **shapes)
     dense = dict(shapes, gate=(h, cfg.intermediate),
                  up=(h, cfg.intermediate), down=(cfg.intermediate, h))
     moe = dict(shapes, router=(h, E), w_gate=(E, h, F), w_up=(E, h, F),
                w_down=(E, F, h), shared_gate=(h, Fs), shared_up=(h, Fs),
                shared_down=(Fs, h))
+
+    def mixer(k):
+        """One sublayer's mixer. `phi` as every matrix; the gates `a_*`
+        at HC_GATE and a diagonal HC_RES_DIAG in `b_res`, so that the
+        coefficients depend on the token and H_res leans to the identity
+        without being it (see HC_GATE); small seeded biases, so that
+        none is zero and unexercised."""
+        ks = jax.random.split(k, 4)
+        bias = lambda kk, shape: 0.1 * jax.random.normal(kk, shape,
+                                                         jnp.float32)
+        gate = jnp.float32(HC_GATE)
+        return {"hc_norm": jnp.ones((m * h,), dtype),
+                "phi": normal(ks[0], (m * h, 2 * m + m * m)),
+                "b_pre": bias(ks[1], (m,)), "b_post": bias(ks[2], (m,)),
+                "b_res": bias(ks[3], (m, m))
+                + HC_RES_DIAG * jnp.eye(m, dtype=jnp.float32),
+                "a_pre": gate, "a_post": gate, "a_res": gate}
 
     def layer(shapes, k):
         ks = jax.random.split(k, len(shapes) + 1)
@@ -150,9 +231,15 @@ def init_params(cfg: MoonlightConfig, key, dtype):
               for (name, shape), kk in zip(shapes.items(), ks)}
         lp.update(norm1=jnp.ones((h,), dtype), norm2=jnp.ones((h,), dtype),
                   kv_norm=jnp.ones((cfg.kv_lora_rank,), dtype))
+        if r is not None:
+            lp["q_norm"] = jnp.ones((r,), dtype)
         if "router" in shapes:
             lp["router_bias"] = 0.01 * jax.random.normal(ks[-1], (E,),
                                                          jnp.float32)
+        if m > 1:
+            # keys of their own: the other weights are what they were
+            lp["hc_attn"] = mixer(jax.random.fold_in(k, 1))
+            lp["hc_ffn"] = mixer(jax.random.fold_in(k, 2))
         return lp
 
     def top(k):
@@ -181,20 +268,71 @@ def _rms(x, g, eps):
     return (x32 * inv * g.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x, pos, theta):
+def yarn_mscale(factor, mscale):
+    """YaRN's attention-magnitude correction for a context stretched
+    `factor` times."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_frequencies(d, theta, scaling=None):
+    """(inv_freq (d/2,) float32, what cos and sin are scaled by) of a
+    rotary width d. Plain RoPE: theta^(-2i/d) and 1. YaRN (`scaling`,
+    the published dict): each frequency blended between itself
+    (extrapolation) and itself over `factor` (interpolation) by a linear
+    ramp over the dimensions between the one that turns `beta_fast`
+    times in the original context and the one that turns `beta_slow`
+    times; cos and sin scaled by mscale(factor, mscale) over
+    mscale(factor, mscale_all_dim)."""
+    import jax.numpy as jnp
+    extra = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if scaling is None:
+        return extra, 1.0
+    factor = scaling["factor"]
+    original = scaling["original_max_position_embeddings"]
+
+    def turns_dim(turns):
+        return (d * math.log(original / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_dim(scaling["beta_fast"])), 0)
+    high = min(math.ceil(turns_dim(scaling["beta_slow"])), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0, 1)
+    keep = 1.0 - ramp                       # 1: extrapolate, 0: interpolate
+    inv = extra / factor * (1 - keep) + extra * keep
+    return inv, (yarn_mscale(factor, scaling.get("mscale", 1))
+                 / yarn_mscale(factor, scaling.get("mscale_all_dim", 0)))
+
+
+def attention_scale(cfg):
+    """1 / sqrt(nope + rope), times YaRN's mscale(factor,
+    mscale_all_dim) squared where the positions are stretched."""
+    scale = 1.0 / math.sqrt(cfg.qk_head_dim)
+    sc = cfg.rope_scaling
+    if sc is not None and sc.get("mscale_all_dim", 0):
+        scale *= yarn_mscale(sc["factor"], sc["mscale_all_dim"]) ** 2
+    return scale
+
+
+def rope(x, pos, theta, scaling=None):
     """Rotary position on the last axis of x at integer positions `pos`
     (broadcast against x's leading axes), in the PUBLISHED element
     order: the interleaved pairs (x0, x1), (x2, x3), ... are first
     permuted to halves (x0, x2, ..., x1, x3, ...), then `x cos +
-    rotate_half(x) sin`. Float32 inside, x's type out."""
+    rotate_half(x) sin`, at `rope_frequencies(d, theta, scaling)`.
+    Float32 inside, x's type out."""
     import jax.numpy as jnp
     d = x.shape[-1]
     x32 = x.astype(jnp.float32)
     x32 = jnp.concatenate([x32[..., 0::2], x32[..., 1::2]], -1)
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    inv, mscale = rope_frequencies(d, theta, scaling)
     ang = pos.astype(jnp.float32)[..., None] * inv
     cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)
     sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     rot = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]], -1)
     return (x32 * cos + rot * sin).astype(x.dtype)
 
@@ -205,18 +343,166 @@ def _swiglu(x, gate, up, down):
     return (jax.nn.silu(g) * (x @ up)) @ down
 
 
+# -- the residual path ---------------------------------------------------------
+
+# Seeded values of a mixer's gates and of `b_res`'s diagonal (init_params).
+# The mHC paper starts the gates small, which would leave the dynamic
+# term without effect, H_res the same matrix at every token and, from
+# X_0's equal rows, the n streams one: nothing a test or a cell could see.
+# With `phi` normal(0, 0.02) over n x 3584 unit-RMS values a z has
+# standard deviation about 2.4; at a gate of 0.5 the sigmoids' arguments
+# spread by 1.2 and H_res's entries (diagonal leaning, not the identity)
+# by what benchmarks/configs/xing4.0-29b-a4b.json `assumed` records.
+HC_GATE = 0.5
+HC_RES_DIAG = 2.0
+
+
+def _add_all(parts):
+    """The sum of a few equal-shaped arrays as plain additions: n is 4,
+    and additions fuse with what is around them where a reduction over
+    an axis of 4 would be a kernel of its own."""
+    total = parts[0]
+    for part in parts[1:]:
+        total = total + part
+    return total
+
+
+def _three_bfloat16(w):
+    """[hi | mid | lo]: w (.., k) float32 as three bfloat16 pieces side
+    by side (.., 3k) whose sum is w to 2^-24 of it."""
+    import jax.numpy as jnp
+    pieces, rest = [], w
+    for _ in range(3):
+        piece = rest.astype(jnp.bfloat16)
+        pieces.append(piece)
+        rest = rest - piece.astype(jnp.float32)
+    return jnp.concatenate(pieces, -1)
+
+
+def hc_coefficients(cfg, hp, X):
+    """A sublayer's mixing coefficients from its input streams X (T, n,
+    C), all in float32 whatever X's type: H_pre (T, n) in (0, 1), H_post
+    (T, n) in (0, 2) and H_res (T, n, n), row j column i at [:, j, i],
+    made doubly stochastic by `hc_sinkhorn_iters` rounds of column then
+    row normalisation of exp(clamp(.)). Inside, the token axis is LAST,
+    so that the rounds are additions and products of whole vectors of
+    tokens; what comes back has it first, as the streams have."""
+    import jax
+    import jax.numpy as jnp
+    T, n, C = X.shape
+    f32, eps = jnp.float32, cfg.hc_eps
+    x = X.reshape(T, n * C)
+    x32 = x.astype(f32)
+    inv = jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    # RMSNorm(vec X; hc_norm) . phi with the norm's weight folded into
+    # phi's rows and the row's scale applied after the product (24
+    # values a token, not n x C)
+    phi = hp["hc_norm"].astype(f32)[:, None] * hp["phi"].astype(f32)
+    if x.dtype == jnp.bfloat16:
+        # the float32 product of bfloat16 streams WITHOUT a float32 copy
+        # of them (940 MB at 16k rows, written and read back in every
+        # sublayer): phi in three bfloat16 pieces that sum to it, the
+        # three products exact in float32 and summed there; 72 columns
+        # cost the MXU what 24 do
+        z = jnp.dot(x, _three_bfloat16(phi), preferred_element_type=f32)
+        z = _add_all(jnp.split(z, 3, axis=-1)[::-1])
+    else:
+        z = jnp.dot(x32, phi, precision=jax.lax.Precision.HIGHEST)
+    z = (z * inv).T                                           # (2n + n^2, T)
+    h_pre = jax.nn.sigmoid(hp["a_pre"] * z[:n] + hp["b_pre"][:, None])
+    h_post = 2.0 * jax.nn.sigmoid(hp["a_post"] * z[n:2 * n]
+                                  + hp["b_post"][:, None])
+    m = hp["a_res"] * z[2 * n:].reshape(n, n, T) + hp["b_res"][:, :, None]
+    m = jnp.exp(jnp.clip(m, -cfg.hc_res_clamp, cfg.hc_res_clamp))
+    for _ in range(cfg.hc_sinkhorn_iters):
+        cols = _add_all([m[j] for j in range(n)])             # (n, T)
+        m = m / (cols + eps)[None]
+        rows = _add_all([m[:, i] for i in range(n)])          # (n, T)
+        m = m / (rows + eps)[:, None]
+    return h_pre.T, h_post.T, m.transpose(2, 0, 1)
+
+
+def _residual(cfg, hp, x, f, live, counters):
+    """ONE sublayer around f (attention or feed-forward, its own norm
+    first): `x + f(x)` at `hc_mult` 1, where x is (T, h); the
+    manifold-constrained hyper-connection at n streams, where x is (T,
+    n, h): u = H_pre X, y = f(u), X' = H_res X + outer(H_post, y), the
+    coefficients from `hc_coefficients(hp)`. f(u, counters) returns
+    (y, counters, aux). The choice is the config's, made in Python: at
+    `hc_mult` 1 nothing of the mixer is traced. Returns (x', counters,
+    aux)."""
+    import jax
+    import jax.numpy as jnp
+    if cfg.hc_mult == 1:
+        y, counters, aux = f(x, counters)
+        return x + y, counters, aux
+    n, f32 = cfg.hc_mult, jnp.float32
+    with jax.named_scope("hc/coeff"):
+        h_pre, h_post, h_res = hc_coefficients(cfg, hp, x)
+        # how far a row of H_res is from summing to one, at the worst
+        # live token, in parts per million
+        rows = _add_all([h_res[:, :, i] for i in range(n)])
+        dev = jnp.max(jnp.where(live[:, None], jnp.abs(rows - 1.0), 0.0))
+    with jax.named_scope("hc/pre"):
+        u = _add_all([h_pre[:, i, None] * x[:, i].astype(f32)
+                      for i in range(n)]).astype(x.dtype)
+    y, counters, aux = f(u, counters)
+    with jax.named_scope("hc/post"):
+        # the streams are read again AS THEY LIE: behind the barrier
+        # their widening to float32 is this scope's own and fuses into
+        # the sums, where shared with `hc/pre` it would be a float32
+        # copy of the streams (940 MB at 16k rows) kept across f
+        x, y = jax.lax.optimization_barrier((x, y))
+        y32 = y.astype(f32)
+        out = [_add_all([h_res[:, j, i, None] * x[:, i].astype(f32)
+                         for i in range(n)]) + h_post[:, j, None] * y32
+               for j in range(n)]
+        x = jnp.stack(out, 1).astype(x.dtype)
+    passes = jnp.any(live).astype(jnp.int32)
+    counters = dict(
+        counters, hc_passes=counters["hc_passes"] + passes,
+        hc_rowsum_dev_ppm=counters["hc_rowsum_dev_ppm"]
+        + jnp.round(dev * 1e6).astype(jnp.int32))
+    return x, counters, aux
+
+
+def _streams_in(cfg, x):
+    """The residual state a layer is handed: x (T, h) itself, or its n
+    copies (T, n, h)."""
+    import jax.numpy as jnp
+    if cfg.hc_mult == 1:
+        return x
+    return jnp.broadcast_to(x[:, None, :], (x.shape[0], cfg.hc_mult,
+                                            x.shape[1]))
+
+
+def _streams_out(cfg, x):
+    """What the final norm is handed: x, or the sum of its n streams."""
+    import jax.numpy as jnp
+    if cfg.hc_mult == 1:
+        return x
+    return x.astype(jnp.float32).sum(-2).astype(x.dtype)
+
+
 def _project(cfg, lp, x, pos):
     """The attention's projections of tokens x (T, h) at positions pos
     (T,): q_nope (T, n, nope), q_rope (T, n, rope) rotated, and the cache
-    row's two parts, c (T, rank) normed and k_rope (T, rope) rotated."""
+    row's two parts, c (T, rank) normed and k_rope (T, rope) rotated.
+    The query is one matrix, or (`q_lora_rank`) a low-rank pair with a
+    norm between."""
     T = x.shape[0]
     n, nope = cfg.heads, cfg.qk_nope_head_dim
-    q = (x @ lp["wq"]).reshape(T, n, cfg.qk_head_dim)
+    theta, scaling = cfg.rope_theta, cfg.rope_scaling
+    if cfg.q_lora_rank is None:
+        q = x @ lp["wq"]
+    else:
+        q = _rms(x @ lp["wqa"], lp["q_norm"], cfg.rms_eps) @ lp["wqb"]
+    q = q.reshape(T, n, cfg.qk_head_dim)
     q_nope = q[..., :nope]
-    q_rope = rope(q[..., nope:], pos[:, None], cfg.rope_theta)
+    q_rope = rope(q[..., nope:], pos[:, None], theta, scaling)
     kva = x @ lp["wkva"]
     c = _rms(kva[:, :cfg.kv_lora_rank], lp["kv_norm"], cfg.rms_eps)
-    k_rope = rope(kva[:, cfg.kv_lora_rank:], pos, cfg.rope_theta)
+    k_rope = rope(kva[:, cfg.kv_lora_rank:], pos, theta, scaling)
     return q_nope, q_rope, c, k_rope
 
 
@@ -361,30 +647,43 @@ def _moe(cfg, lp, x, live):
 
 def _ffn(cfg, lp, x, live, counters):
     """norm2 + the layer's feed-forward (dense or routed), with the
-    counters of a routed layer added to `counters`."""
+    counters of a routed layer added to `counters`: the second
+    sublayer's f of `_residual`."""
     import jax
     h = _rms(x, lp["norm2"], cfg.rms_eps)
     if "router" not in lp:
         with jax.named_scope("ffn/dense"):
-            return _swiglu(h, lp["gate"], lp["up"], lp["down"]), counters
+            return _swiglu(h, lp["gate"], lp["up"], lp["down"]), counters, None
     y, c = _moe(cfg, lp, h, live)
-    return y, {name: counters[name] + c[name] for name in counters}
+    return y, dict(counters, **{name: counters[name] + c[name]
+                                for name in c}), None
+
+
+def _ffn_sublayer(cfg, lp, x, live, counters):
+    """The layer's second sublayer around the residual state x."""
+    x, counters, _ = _residual(
+        cfg, lp.get("hc_ffn"), x,
+        lambda u, counters: _ffn(cfg, lp, u, live, counters), live, counters)
+    return x, counters
 
 
 def _zero_counters(cfg):
     import jax.numpy as jnp
-    return {"expert_tokens": jnp.zeros((cfg.n_routed_experts,), jnp.int32),
-            "router_tokens": jnp.zeros((), jnp.int32),
-            "experts_touched": jnp.zeros((), jnp.int32),
-            "moe_passes": jnp.zeros((), jnp.int32),
-            "kernel_passes": jnp.zeros((), jnp.int32)}
+    zero = jnp.zeros((), jnp.int32)
+    counters = {"expert_tokens": jnp.zeros((cfg.n_routed_experts,),
+                                           jnp.int32),
+                "router_tokens": zero, "experts_touched": zero,
+                "moe_passes": zero, "kernel_passes": zero}
+    if cfg.hc_mult > 1:
+        counters.update(hc_passes=zero, hc_rowsum_dev_ppm=zero)
+    return counters
 
 
 def _head(cfg, params, x):
     import jax
     import jax.numpy as jnp
     with jax.named_scope("head"):
-        y = _rms(x, params["norm_f"], cfg.rms_eps)
+        y = _rms(_streams_out(cfg, x), params["norm_f"], cfg.rms_eps)
         return jnp.dot(y, params["head"],
                        preferred_element_type=jnp.float32)
 
@@ -404,19 +703,23 @@ def forward_logits(params, cfg, tokens):
     import jax.numpy as jnp
     T = tokens.shape[0]
     pos = jnp.arange(T)
-    x = params["wte"][tokens].astype(_act_dtype(params))
+    x = _streams_in(cfg, params["wte"][tokens].astype(_act_dtype(params)))
     mask = pos[None, :] <= pos[:, None]
+    scale = attention_scale(cfg)
     counters = _zero_counters(cfg)
     live = jnp.ones((T,), bool)
     for lp in params["layers"]:
-        h = _rms(x, lp["norm1"], cfg.rms_eps)
-        q_nope, q_rope, c, k_rope = _project(cfg, lp, h, pos)
-        k, v = _expand(cfg, lp, c, k_rope)
-        q = jnp.concatenate([q_nope, q_rope], -1)
-        o = _masked_attention(q, k, v, mask, 1.0 / np.sqrt(cfg.qk_head_dim))
-        x = x + o.reshape(T, -1) @ lp["wo"]
-        y, counters = _ffn(cfg, lp, x, live, counters)
-        x = x + y
+        def attend(u, counters, lp=lp):
+            h = _rms(u, lp["norm1"], cfg.rms_eps)
+            q_nope, q_rope, c, k_rope = _project(cfg, lp, h, pos)
+            k, v = _expand(cfg, lp, c, k_rope)
+            q = jnp.concatenate([q_nope, q_rope], -1)
+            o = _masked_attention(q, k, v, mask, scale)
+            return o.reshape(T, -1) @ lp["wo"], counters, None
+
+        x, counters, _ = _residual(cfg, lp.get("hc_attn"), x, attend, live,
+                                   counters)
+        x, counters = _ffn_sublayer(cfg, lp, x, live, counters)
     return _head(cfg, params, x)
 
 
@@ -447,43 +750,46 @@ def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
     bs = arena.shape[4]
     L = pages.shape[0] * bs
     dtype = arena.dtype
-    scale = 1.0 / np.sqrt(cfg.qk_head_dim)
+    scale = attention_scale(cfg)
     on_tpu = jax.default_backend() == "tpu"
     j = jnp.arange(B)
     pos = pfx_len + j
     live = j < real_len
-    x = params["wte"][tokens[0]].astype(dtype)
+    x = _streams_in(cfg, params["wte"][tokens[0]].astype(dtype))
     counters = _zero_counters(cfg)
     for li, lp in enumerate(params["layers"]):
-        with jax.named_scope("mla/project"):
-            h = _rms(x, lp["norm1"], cfg.rms_eps)
-            q_nope, q_rope, c, k_rope = _project(cfg, lp, h, pos)
-            q = jnp.concatenate([q_nope, q_rope], -1)
-            rows = _cache_rows(cfg, c, k_rope)
-            arena = _write_pages(arena, li, pages, pfx_len, real_len,
-                                 rows[:, None, :])
+        def attend(u, counters, arena=arena, li=li, lp=lp):
+            with jax.named_scope("mla/project"):
+                h = _rms(u, lp["norm1"], cfg.rms_eps)
+                q_nope, q_rope, c, k_rope = _project(cfg, lp, h, pos)
+                q = jnp.concatenate([q_nope, q_rope], -1)
+                rows = _cache_rows(cfg, c, k_rope)
+                arena = _write_pages(arena, li, pages, pfx_len, real_len,
+                                     rows[:, None, :])
 
-        def cold(arena, lp=lp, q=q, c=c, k_rope=k_rope):
-            k, v = _expand(cfg, lp, c, k_rope)
-            if on_tpu and B % 128 == 0:
-                return _flash_causal(q, k, v, scale)
-            return _masked_attention(q, k, v, j[None, :] <= j[:, None],
-                                     scale)
+            def cold(arena):
+                k, v = _expand(cfg, lp, c, k_rope)
+                if on_tpu and B % 128 == 0:
+                    return _flash_causal(q, k, v, scale)
+                return _masked_attention(q, k, v, j[None, :] <= j[:, None],
+                                         scale)
 
-        def warm(arena, li=li, lp=lp, q=q):
-            cached = _gather_pages(arena, li, pages)[0]       # (L, W)
-            k, v = _expand(
-                cfg, lp, cached[:, :cfg.kv_lora_rank],
-                cached[:, cfg.kv_lora_rank:cfg.row_values])
-            return _masked_attention(
-                q, k, v, jnp.arange(L)[None, :] <= pos[:, None], scale)
+            def warm(arena):
+                cached = _gather_pages(arena, li, pages)[0]       # (L, W)
+                k, v = _expand(
+                    cfg, lp, cached[:, :cfg.kv_lora_rank],
+                    cached[:, cfg.kv_lora_rank:cfg.row_values])
+                return _masked_attention(
+                    q, k, v, jnp.arange(L)[None, :] <= pos[:, None], scale)
 
-        with jax.named_scope("mla/attend"):
-            o = jax.lax.cond(pfx_len == 0, cold, warm, arena)
-        with jax.named_scope("mla/project"):
-            x = x + o.reshape(B, -1) @ lp["wo"]
-        y, counters = _ffn(cfg, lp, x, live, counters)
-        x = x + y
+            with jax.named_scope("mla/attend"):
+                o = jax.lax.cond(pfx_len == 0, cold, warm, arena)
+            with jax.named_scope("mla/project"):
+                return o.reshape(B, -1) @ lp["wo"], counters, arena
+
+        x, counters, arena = _residual(cfg, lp.get("hc_attn"), x, attend,
+                                       live, counters)
+        x, counters = _ffn_sublayer(cfg, lp, x, live, counters)
     last = x[real_len - 1][None]
     return _head(cfg, params, last), arena, counters
 
@@ -529,55 +835,62 @@ def decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
     bs = arena.shape[4]
     dtype = arena.dtype
     rank = cfg.kv_lora_rank
-    scale = 1.0 / np.sqrt(cfg.qk_head_dim)
+    scale = attention_scale(cfg)
     if attention is None:
         attention = decode_attention_path(arena)
     if attention == "latent_paged_kernel":
         from ..ops.paged_attention import latent_paged_attention
     live = jnp.ones((s_dim,), bool) if done is None else ~done
-    x = params["wte"][tokens].astype(dtype)
+    x = _streams_in(cfg, params["wte"][tokens].astype(dtype))
     counters = _zero_counters(cfg)
     pad = cfg.row_width - cfg.row_values
     for li, lp in enumerate(params["layers"]):
-        with jax.named_scope("mla/project"):
-            h = _rms(x, lp["norm1"], cfg.rms_eps)
-            q_nope, q_rope, c, k_rope = _project(cfg, lp, h, ts)
-            row = _cache_rows(cfg, c, k_rope)
-        with jax.named_scope("mla/absorb"):
-            w_uk, w_uv = _wkvb_heads(cfg, lp)
-            q_lat = jnp.einsum("snd,cnd->snc", q_nope, w_uk)
-            parts = [q_lat, q_rope]
-            if pad:
-                parts.append(jnp.zeros(q_rope.shape[:2] + (pad,), dtype))
-            q_ext = (jnp.concatenate(parts, -1).astype(jnp.float32)
-                     * scale).astype(dtype)
-        with jax.named_scope("mla/attend"):
-            if attention == "latent_paged_kernel":
-                o_ext, arena = latent_paged_attention(q_ext, row, arena, li,
-                                                      pt, ts, done)
-            else:
-                wblk = pt[jnp.arange(s_dim), ts // bs]
-                if done is not None:
-                    wblk = jnp.where(done, 0, wblk)
-                arena = arena.at[li, 0, wblk, 0, ts % bs].set(row)
-                cached = _gather_pages(arena, li, pt)[:, 0]   # (S, L, W)
-                o_ext = absorbed_attention(
-                    q_ext, cached,
-                    jnp.arange(P * bs)[None, :] <= ts[:, None])
-        with jax.named_scope("mla/absorb"):
-            o = jnp.einsum("snc,cnd->snd", o_ext[..., :rank], w_uv)
-        with jax.named_scope("mla/project"):
-            x = x + o.reshape(s_dim, -1) @ lp["wo"]
-        y, counters = _ffn(cfg, lp, x, live, counters)
-        x = x + y
+        def attend(u, counters, arena=arena, li=li, lp=lp):
+            with jax.named_scope("mla/project"):
+                h = _rms(u, lp["norm1"], cfg.rms_eps)
+                q_nope, q_rope, c, k_rope = _project(cfg, lp, h, ts)
+                row = _cache_rows(cfg, c, k_rope)
+            with jax.named_scope("mla/absorb"):
+                w_uk, w_uv = _wkvb_heads(cfg, lp)
+                q_lat = jnp.einsum("snd,cnd->snc", q_nope, w_uk)
+                parts = [q_lat, q_rope]
+                if pad:
+                    parts.append(jnp.zeros(q_rope.shape[:2] + (pad,), dtype))
+                q_ext = (jnp.concatenate(parts, -1).astype(jnp.float32)
+                         * scale).astype(dtype)
+            with jax.named_scope("mla/attend"):
+                if attention == "latent_paged_kernel":
+                    o_ext, arena = latent_paged_attention(
+                        q_ext, row, arena, li, pt, ts, done)
+                else:
+                    wblk = pt[jnp.arange(s_dim), ts // bs]
+                    if done is not None:
+                        wblk = jnp.where(done, 0, wblk)
+                    arena = arena.at[li, 0, wblk, 0, ts % bs].set(row)
+                    cached = _gather_pages(arena, li, pt)[:, 0]   # (S, L, W)
+                    o_ext = absorbed_attention(
+                        q_ext, cached,
+                        jnp.arange(P * bs)[None, :] <= ts[:, None])
+            with jax.named_scope("mla/absorb"):
+                o = jnp.einsum("snc,cnd->snd", o_ext[..., :rank], w_uv)
+            with jax.named_scope("mla/project"):
+                return o.reshape(s_dim, -1) @ lp["wo"], counters, arena
+
+        x, counters, arena = _residual(cfg, lp.get("hc_attn"), x, attend,
+                                       live, counters)
+        x, counters = _ffn_sublayer(cfg, lp, x, live, counters)
     return _head(cfg, params, x), arena, counters
 
 
 # -- the engine's view of this model ------------------------------------------
 
 class _MoonlightServingModel(ServingModel):
-    name = "Moonlight-16B-A3B"
+    """The latent-attention + expert block as the engine sees it; one
+    class for every config of it, named by the config."""
     features = frozenset()
+
+    def __init__(self, name):
+        self.name = name
 
     def max_positions(self, cfg):
         return cfg.max_pos
@@ -599,37 +912,47 @@ class _MoonlightServingModel(ServingModel):
         # with a live slot (what a step's expert bytes are counted from);
         # moe_kernel_passes: passes of an expert layer, a prefill's six
         # and a decode step's, whose product was the grouped kernel (0
-        # where `ragged_dot` ran: every backend but the TPU)
-        return {"expert_tokens": (cfg.n_routed_experts,),
-                "router_tokens": (), "decode_router_tokens": (),
-                "decode_experts_touched": (), "decode_moe_passes": (),
-                "moe_kernel_passes": ()}
+        # where `ragged_dot` ran: every backend but the TPU). With
+        # residual streams (`hc_mult` > 1) also hc_passes: sublayers
+        # mixed, by both programs, and hc_rowsum_dev_ppm: the sum over
+        # those of the largest |row sum of H_res - 1| at a live token,
+        # in parts per million (their ratio is a sublayer's mean)
+        names = {"expert_tokens": (cfg.n_routed_experts,),
+                 "router_tokens": (), "decode_router_tokens": (),
+                 "decode_experts_touched": (), "decode_moe_passes": (),
+                 "moe_kernel_passes": ()}
+        if cfg.hc_mult > 1:
+            names.update(hc_passes=(), hc_rowsum_dev_ppm=())
+        return names
+
+    @staticmethod
+    def _counters(c, decode):
+        """The block's counters under the engine's names; the decode_*
+        three count the decode step's alone."""
+        import jax.numpy as jnp
+        zero = jnp.zeros((), jnp.int32)
+        out = {"expert_tokens": c["expert_tokens"],
+               "router_tokens": c["router_tokens"],
+               "decode_router_tokens": c["router_tokens"] if decode else zero,
+               "decode_experts_touched":
+                   c["experts_touched"] if decode else zero,
+               "decode_moe_passes": c["moe_passes"] if decode else zero,
+               "moe_kernel_passes": c["kernel_passes"]}
+        out.update({name: c[name] for name in c if name.startswith("hc_")})
+        return out
 
     def prefill(self, params, cfg, tokens, pfx_len, real_len, arena, pages,
                 adapters=None, adapter_id=None):
-        import jax.numpy as jnp
         logits, arena, c = prefill_pages(params, cfg, tokens, pfx_len,
                                          real_len, arena, pages)
-        zero = jnp.zeros((), jnp.int32)
-        return logits, arena, {
-            "expert_tokens": c["expert_tokens"],
-            "router_tokens": c["router_tokens"],
-            "decode_router_tokens": zero, "decode_experts_touched": zero,
-            "decode_moe_passes": zero,
-            "moe_kernel_passes": c["kernel_passes"]}
+        return logits, arena, self._counters(c, decode=False)
 
     def decode_step(self, params, cfg, tokens, arena, pt, ts, done, *,
                     adapters=None, adapter_ids=None, arena_constraint=None):
         logits, arena, c = decode_step_pages(
             params, cfg, tokens, arena, pt, ts, done,
             attention=decode_attention_path(arena, arena_constraint))
-        return logits, arena, {
-            "expert_tokens": c["expert_tokens"],
-            "router_tokens": c["router_tokens"],
-            "decode_router_tokens": c["router_tokens"],
-            "decode_experts_touched": c["experts_touched"],
-            "decode_moe_passes": c["moe_passes"],
-            "moe_kernel_passes": c["kernel_passes"]}
+        return logits, arena, self._counters(c, decode=True)
 
 
-MOONLIGHT_SERVING_MODEL = _MoonlightServingModel()
+MOONLIGHT_SERVING_MODEL = _MoonlightServingModel("Moonlight-16B-A3B")
