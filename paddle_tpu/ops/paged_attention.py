@@ -230,30 +230,96 @@ def paged_attention(q, k, v, arena, layer, pt, ts, done=None):
 # arena is (layers, 1, num_blocks, 1, block_size, W): a page of a layer is
 # one contiguous (block_size, W) tile, copied as it lies; scores are
 # q (heads, W) x page^T on the MXU and the context p (heads, block_size)
-# x page. The page walk, the DMA ring, the step's own row written through
-# the live page and the aliased arena are the kernel's above.
+# x page.
+#
+# With 16 or 32 query rows a page is too little work to cover what one
+# step of the online softmax costs whatever its size (the semaphore's
+# wait, the chain score -> max -> exp -> context, the accumulator's
+# rescale), so the walk moves in GROUPS of G pages: each page of a group
+# is copied from its own arena block into a row slice of ONE contiguous
+# (G * block_size, W) buffer, and one step attends over the group. A
+# slot's pages are its whole groups and a TAIL of 1..G pages that ends
+# with the live one; the tail is attended at its own size (one branch a
+# size), so a page that is not live is neither fetched nor computed.
+# Nothing drains between slots: the grid runs the slots in order on one
+# core and the scalar-prefetch operands hold every slot's table and
+# length, so the groups of a slot and of the next live slot are ONE
+# stream, of which `buffers - 1` groups are in flight whenever a step
+# computes (a slot's first pages are on their way while the slot before
+# it attends its last ones), and the live page's write-back is waited for
+# when the stage is next needed (or by the last program). The step's own
+# row written through the live page and the aliased arena are the
+# kernel's above.
+
+# pages a step of the online softmax (a power of two; 512 rows at the
+# serving page of 128) and group buffers. Measured on a v5e at 16 and 32
+# heads: tools/bench_latent_decode.py, PERF.md section 6, PR 32 (a page
+# costs 0.38-0.41 us walked one by one, 0.24-0.27 in twos, 0.21 in fours
+# and in eights, beside 0.20 us for its DMA; two buffers leave 0.31).
+_LATENT_GROUP = 4
+_LATENT_BUFFERS = 3
+_LATENT_VMEM = 4 << 20
+
+
+def _latent_walk(heads, pages, w, block_size, itemsize):
+    """(G, buffers) of the latent walk, from shapes alone: the largest
+    power of two of pages a step up to _LATENT_GROUP that a slot's table
+    can fill and whose buffers stay inside _LATENT_VMEM."""
+    del heads                     # 16 and 32 rows want the same walk
+    page = block_size * w * itemsize
+    group = 1
+    while (2 * group <= min(_LATENT_GROUP, pages)
+           and _LATENT_BUFFERS * 2 * group * page <= _LATENT_VMEM):
+        group *= 2
+    return group, _LATENT_BUFFERS
 
 
 def _latent_kernel(layer_ref, pt_ref, len_ref, q_ref, new_ref, arena_ref,
-                   arena_out_ref, o_ref, kv_buf, stage, sems, wsem, *,
-                   block_size, pages, ring):
+                   arena_out_ref, o_ref, kv_buf, stage, state, sems, wsem, *,
+                   block_size, pages, group, buffers):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     s = pl.program_id(0)
+    n_slots = pl.num_programs(0)
     li = layer_ref[0]
+    bs = block_size
     length = len_ref[s]                       # live rows; 0 = frozen slot
-    n_pages = jax.lax.div(length + block_size - 1, block_size)
     heads, w = q_ref.shape[1], q_ref.shape[2]
+    # the rows of one packed tile of the arena's type (16 of bfloat16, 8
+    # of float32), or the page where that is smaller
+    tile = 32 // jnp.dtype(kv_buf.dtype).itemsize
+    tile = tile if bs % tile == 0 else bs
+    # what one program leaves the next: whether this slot's first groups
+    # are already on their way, the buffer its group 0 goes to, whether
+    # a write-back is in flight
+    PRIMED, BASE, WRITING = 0, 1, 2
 
-    def page_copy(p, slot):
-        blk = pt_ref[s * pages + p]
-        return pltpu.make_async_copy(arena_ref.at[li, 0, blk, 0],
-                                     kv_buf.at[slot], sems.at[slot])
+    @pl.when(s == 0)
+    def _first():
+        state[PRIMED] = 0
+        state[BASE] = 0
+        state[WRITING] = 0
+
+    def page_copy(t, p, buf, i):
+        """Page p of slot t -> rows [i * bs, (i + 1) * bs) of buffer buf."""
+        blk = pt_ref[t * pages + p]
+        return pltpu.make_async_copy(
+            arena_ref.at[li, 0, blk, 0],
+            kv_buf.at[buf, pl.ds(pl.multiple_of(i * bs, bs), bs)],
+            sems.at[buf])
+
+    def wait_page(buf, i):
+        page_copy(s, 0, buf, i).wait()        # a wait reads sizes only
+
+    def write_back(blk=0):
+        return pltpu.make_async_copy(
+            stage, arena_out_ref.at[li, 0, blk, 0], wsem)
 
     def attend(kv, q, carry, rows=None):
-        """One page of the online softmax; kv (block_size, W) as stored,
-        q (heads, W) in the same type, statistics in float32."""
+        """One step of the online softmax; kv (k * bs, W) as stored, q
+        (heads, W) in the same type, statistics in float32. rows: how
+        many of kv's rows are live (None: all of them)."""
         m, l, acc = carry
         sc = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -262,7 +328,7 @@ def _latent_kernel(layer_ref, pt_ref, len_ref, q_ref, new_ref, arena_ref,
             sc = jnp.where(col < rows, sc, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)                        # (heads, 1)
-        pr = jnp.exp(sc - m_new)                          # (heads, bs)
+        pr = jnp.exp(sc - m_new)                          # (heads, k * bs)
         l = l * alpha + jnp.sum(pr, axis=1, keepdims=True)
         acc = acc * alpha + jax.lax.dot_general(
             pr.astype(kv.dtype), kv, (((1,), (0,)), ((), ())),
@@ -271,49 +337,106 @@ def _latent_kernel(layer_ref, pt_ref, len_ref, q_ref, new_ref, arena_ref,
 
     @pl.when(length > 0)
     def _live():
-        last = n_pages - 1
-        for i in range(ring):
-            @pl.when(i < n_pages)
-            def _prime(i=i):
-                page_copy(i, i).start()
+        n_pages = jax.lax.div(length + bs - 1, bs)
+        n_full = jax.lax.div(n_pages - 1, group)   # whole groups
+        tail = n_pages - n_full * group            # 1..G pages, the last live
+        base = state[BASE]
+        # the next live slot (a frozen one in between is passed over and
+        # touches nothing); n_slots where this is the last
+        nxt = jax.lax.while_loop(
+            lambda t: jnp.logical_and(
+                t < n_slots, len_ref[jnp.minimum(t, n_slots - 1)] == 0),
+            lambda t: t + 1, s + 1)
+        nxt_live = nxt < n_slots
+        nxt = jnp.minimum(nxt, n_slots - 1)
+        n_next = jnp.where(
+            nxt_live, jax.lax.div(len_ref[nxt] + bs - 1, bs), 0)
+
+        def start(j):
+            """Group j of the stream of groups that runs from this
+            slot's (0..n_full, the tail last) into the next live slot's:
+            its live pages, each from its own block, into buffer
+            (base + j) % buffers. Nothing where the stream has ended."""
+            own = j <= n_full
+            t = jnp.where(own, s, nxt)
+            p0 = jnp.where(own, j, j - n_full - 1) * group
+            live = jnp.clip(jnp.where(own, n_pages, n_next) - p0, 0, group)
+            buf = jax.lax.rem(base + j, buffers)
+
+            def one(i, carry):
+                page_copy(t, p0 + i, buf, i).start()
+                return carry
+            jax.lax.fori_loop(0, live, one, 0)
+
+        def starts(j, carry):
+            start(j)
+            return carry
+
+        # buffers - 1 groups are in flight whenever a step computes. The
+        # slot before this one has started this slot's share of them
+        # (unless it was none: the first live slot); what reaches past
+        # this slot's tail into the next one's groups starts here
+        have = jnp.where(state[PRIMED] == 1,
+                         jnp.minimum(n_full + 1, buffers - 1), 0)
+        jax.lax.fori_loop(have, buffers - 1, starts, 0)
         q = q_ref[0]
 
-        def page_step(p, carry):
-            slot = jax.lax.rem(p, ring)
-            page_copy(p, slot).wait()
-            kv = kv_buf[slot]
-
-            @pl.when(p + ring < n_pages)
-            def _refill():
-                page_copy(p + ring, slot).start()
-
-            return attend(kv, q, carry)
+        def group_step(g, carry):
+            buf = jax.lax.rem(base + g, buffers)
+            for i in range(group):
+                wait_page(buf, i)
+            # into the buffer the step before this one read
+            start(g + buffers - 1)
+            return attend(kv_buf[buf], q, carry)
 
         carry = jax.lax.fori_loop(
-            0, last, page_step,
+            0, n_full, group_step,
             (jnp.full((heads, 1), _NEG_INF, jnp.float32),
              jnp.zeros((heads, 1), jnp.float32),
              jnp.zeros((heads, w), jnp.float32)))
-        # the live page takes this step's row, is attended WITH it and
-        # goes back whole (see the kernel above)
-        slot = jax.lax.rem(last, ring)
-        page_copy(last, slot).wait()
-        kv = kv_buf[slot].astype(jnp.float32)
-        at = jax.lax.rem(length - 1, block_size)
-        row = jax.lax.broadcasted_iota(jnp.int32, kv.shape, 0)
-        kv = jnp.where(row == at, new_ref[0].astype(jnp.float32), kv)
-        stage[...] = kv.astype(stage.dtype)
-        back = pltpu.make_async_copy(
-            stage, arena_out_ref.at[li, 0, pt_ref[s * pages + last], 0],
-            wsem)
-        back.start()
-        _, l, acc = attend(stage[...], q, carry, rows=at + 1)
-        o_ref[0] = (acc / l).astype(o_ref.dtype)
-        back.wait()
+        buf = jax.lax.rem(base + n_full, buffers)
+        state[PRIMED] = nxt_live.astype(jnp.int32)
+        state[BASE] = jax.lax.rem(base + n_full + 1, buffers)
+        # the live page takes this step's row where it has landed (the
+        # packed tile that holds row `at`, not the page), is attended
+        # WITH it as the tail's last page and goes back whole from the
+        # stage: a DMA cannot write one row of a packed tile, and the
+        # buffer is refilled before a write from it would have ended
+        at = jax.lax.rem(length - 1, bs)
+
+        def landed(i, carry):
+            wait_page(buf, i)
+            return carry
+        jax.lax.fori_loop(0, tail, landed, 0)
+        start(n_full + buffers - 1)
+        live0 = pl.multiple_of((tail - 1) * bs, bs)
+        t0 = pl.multiple_of(live0 + at // tile * tile, tile)
+        rows = kv_buf[buf, pl.ds(t0, tile), :]
+        row = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0)
+        kv_buf[buf, pl.ds(t0, tile), :] = jnp.where(
+            row == jax.lax.rem(at, tile), new_ref[0].astype(jnp.float32),
+            rows.astype(jnp.float32)).astype(kv_buf.dtype)
+
+        @pl.when(state[WRITING] == 1)
+        def _stage_free():
+            write_back().wait()
+        stage[...] = kv_buf[buf, pl.ds(live0, bs), :]
+        write_back(pt_ref[s * pages + n_pages - 1]).start()
+        state[WRITING] = 1
+        for k in range(1, group + 1):
+            @pl.when(tail == k)
+            def _tail(k=k):
+                _, l, acc = attend(kv_buf[buf, pl.ds(0, k * bs), :], q, carry,
+                                   rows=(k - 1) * bs + at + 1)
+                o_ref[0] = (acc / l).astype(o_ref.dtype)
 
     @pl.when(length == 0)
     def _frozen():
         o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(jnp.logical_and(s == n_slots - 1, state[WRITING] == 1))
+    def _drain():
+        write_back().wait()
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -324,9 +447,10 @@ def _latent_call(q, new, arena, layer, pt, lengths, interpret):
     s_dim, heads, w = q.shape
     block_size = arena.shape[4]
     pages = pt.shape[1]
-    ring = min(_RING, pages)
+    group, buffers = _latent_walk(heads, pages, w, block_size,
+                                  arena.dtype.itemsize)
     kern = functools.partial(_latent_kernel, block_size=block_size,
-                             pages=pages, ring=ring)
+                             pages=pages, group=group, buffers=buffers)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     arena, out = pl.pallas_call(
         kern,
@@ -339,9 +463,10 @@ def _latent_call(q, new, arena, layer, pt, lengths, interpret):
             out_specs=[hbm,
                        pl.BlockSpec((1, heads, w), lambda s, *_: (s, 0, 0))],
             scratch_shapes=[
-                pltpu.VMEM((ring, block_size, w), arena.dtype),
+                pltpu.VMEM((buffers, group * block_size, w), arena.dtype),
                 pltpu.VMEM((block_size, w), arena.dtype),
-                pltpu.SemaphoreType.DMA((ring,)),
+                pltpu.SMEM((3,), jnp.int32),
+                pltpu.SemaphoreType.DMA((buffers,)),
                 pltpu.SemaphoreType.DMA(()),
             ]),
         out_shape=[jax.ShapeDtypeStruct(arena.shape, arena.dtype),
@@ -369,6 +494,9 @@ def latent_paged_attention(q, row, arena, layer, pt, ts, done=None):
     attends over positions 0..ts[s], its own row included: score
     q_h . row_t (float32), softmax in float32, context sum p row_t. A
     frozen slot (`done`) writes nothing, reads nothing and gets zeros.
+    The walk attends `_latent_walk`'s G pages a step (a function of the
+    shapes: nothing a caller sets), so the float32 sums are taken G
+    pages at a time; only live pages are fetched or computed.
 
     Returns (context (S, heads, W) in q's dtype, of which the caller
     keeps the latent lanes; the arena, its input's own buffer). Mosaic

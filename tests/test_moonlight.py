@@ -252,36 +252,129 @@ def test_absorbed_step_matches_expanded(params):
     np.testing.assert_allclose(o_abs, o_exp, atol=2e-6)
 
 
-def test_latent_kernel_matches_gather(params):
-    """ops/paged_attention.latent_paged_attention (interpreted) against a
-    gather and two einsums over the same arena: live slots of several
-    lengths, one on a page's first row, one on its last, a frozen one."""
-    from paddle_tpu.ops.paged_attention import latent_paged_attention
-    rng = np.random.default_rng(4)
-    S, P, bs, W, n = 5, 4, 4, CFG.row_width, CFG.heads
-    arena = jnp.asarray(rng.normal(0, 1, (2, 1, 1 + S * P, 1, bs, W)),
-                        jnp.float32)
+def _latent_case(pages_live, at_rows, frozen, P, bs, heads, W,
+                 dtype=jnp.float32, seed=4):
+    """One pool: slot i holds pages_live[i] live pages with its new row at
+    row at_rows[i] of the live one (-1: the page's last row); the slots
+    named in `frozen` are done. Pages are dealt out of one permutation, so
+    no two slots share a block and block 0 is the scratch block."""
+    rng = np.random.default_rng(seed)
+    S = len(pages_live)
+    arena = jnp.asarray(rng.normal(0, 1, (2, 1, 1 + S * P, 1, bs, W)), dtype)
     pt = jnp.asarray(1 + rng.permutation(S * P).reshape(S, P), jnp.int32)
-    ts = jnp.asarray([0, 3, 4, 9, 15], jnp.int32)
-    done = jnp.asarray([False, False, False, True, False])
-    q = jnp.asarray(rng.normal(0, 0.3, (S, n, W)), jnp.float32)
-    row = jnp.asarray(rng.normal(0, 1, (S, W)), jnp.float32)
-    for li in (0, 1):
-        out, after = latent_paged_attention(q, row, arena, li, pt, ts, done)
-        wblk = jnp.where(done, 0, pt[jnp.arange(S), ts // bs])
-        want_arena = arena.at[li, 0, wblk, 0, ts % bs].set(row)
-        cached = want_arena[li, 0, pt, 0].reshape(S, P * bs, W)
-        want = ml.absorbed_attention(
-            q, cached, jnp.arange(P * bs)[None] <= ts[:, None])
-        live = ~np.asarray(done)
-        np.testing.assert_allclose(np.asarray(out)[live],
-                                   np.asarray(want)[live], atol=2e-6)
-        assert not np.asarray(out)[~live].any()
-        # the kernel wrote the live slots' rows and nothing else (the
-        # gather's frozen slot dirties the scratch block, the kernel's
-        # writes nowhere)
-        np.testing.assert_array_equal(np.asarray(after)[:, :, 1:],
-                                      np.asarray(want_arena)[:, :, 1:])
+    ts = jnp.asarray([(n - 1) * bs + (r % bs)
+                      for n, r in zip(pages_live, at_rows)], jnp.int32)
+    done = jnp.asarray([i in frozen for i in range(S)])
+    q = jnp.asarray(rng.normal(0, 0.3, (S, heads, W)), dtype)
+    row = jnp.asarray(rng.normal(0, 1, (S, W)), dtype)
+    return q, row, arena, pt, ts, done
+
+
+def _latent_cases():
+    from paddle_tpu.ops.paged_attention import _latent_walk
+    W, n = CFG.row_width, CFG.heads
+    # P is NOT a multiple of G, so the longest slot ends in a short tail
+    P, bs = 11, 8
+    G, _ = _latent_walk(n, P, W, bs, 4)
+    assert G > 2 and P % G and 2 * G + 1 <= P, (G, P)
+    return {
+        # PR 27's pool: a slot on a page's first row (0, 4), on its last
+        # (3, 15), a frozen one; four pages a slot are ONE tail each
+        "five-slots": dict(case=dict(
+            pages_live=[1, 1, 2, 3, 4], at_rows=[0, 3, 0, 1, 3], frozen=[3],
+            P=4, bs=4, heads=n, W=W)),
+        # every boundary of the walk in groups of G: 1, G-1, G, G+1, 2G+1
+        # and P live pages, the new row on a page's first and last row,
+        # a frozen slot between two live ones (the slot before it must
+        # start the pages of the slot AFTER it), a frozen slot last
+        "walk-boundaries": dict(case=dict(
+            pages_live=[1, G - 1, G, G, G + 1, 2 * G + 1, P, 2 * G, 1, 3],
+            at_rows=[0, -1, 0, 5, -1, 0, -1, -1, 3, 2], frozen=[3, 9],
+            P=P, bs=bs, heads=n, W=W)),
+        "frozen-first-and-all-but-one": dict(case=dict(
+            pages_live=[2, 2, G + 2, 2], at_rows=[1, 1, 0, 1],
+            frozen=[0, 1, 3], P=P, bs=bs, heads=n, W=W)),
+        # a table of ONE page: the walk has no whole group at all
+        "one-page-table": dict(case=dict(
+            pages_live=[1, 1, 1], at_rows=[0, -1, 2], frozen=[],
+            P=1, bs=bs, heads=n, W=W)),
+        # the serving tile, (128, 640), at both models' head counts
+        "real-tile-16-heads": dict(case=dict(
+            pages_live=[1, 5, 6, 4], at_rows=[0, -1, 77, 16], frozen=[],
+            P=6, bs=128, heads=16, W=640)),
+        "real-tile-32-heads": dict(case=dict(
+            pages_live=[6, 1, 5], at_rows=[-1, 100, 0], frozen=[1],
+            P=6, bs=128, heads=32, W=640)),
+        # the arena's own type: the new row goes into a packed tile of 16
+        # rows; probabilities are narrowed to bfloat16 on both sides
+        "real-tile-bfloat16": dict(atol=2e-2, case=dict(
+            pages_live=[5, 1, 6], at_rows=[15, 16, -1], frozen=[],
+            P=6, bs=128, heads=16, W=640, dtype=jnp.bfloat16)),
+    }
+
+
+@pytest.mark.parametrize("name", list(_latent_cases()))
+def test_latent_kernel_matches_gather(name):
+    """ops/paged_attention.latent_paged_attention (interpreted) against a
+    gather and two einsums over the same arena, through TWO decode steps
+    of two layers each on the arena the call before returned: what a call
+    wrote late (the live page's deferred write-back) is what the next
+    call reads, and the second step crosses into a new page where the
+    first one ended a page."""
+    from paddle_tpu.ops.paged_attention import latent_paged_attention
+    spec = _latent_cases()[name]
+    q, row, arena, pt, ts, done = _latent_case(**spec["case"])
+    S, P = pt.shape
+    bs, W = arena.shape[4], arena.shape[5]
+    live = ~np.asarray(done)
+    want_arena = arena
+    for step in (0, 1):
+        ts_now = jnp.minimum(ts + step, P * bs - 1)
+        for li in (0, 1):
+            # another row a call, so a stale page cannot pass for a new one
+            row_now = jnp.roll(row, 2 * step + li, axis=-1)
+            out, arena = latent_paged_attention(q, row_now, arena, li, pt,
+                                                ts_now, done)
+            wblk = jnp.where(done, 0, pt[jnp.arange(S), ts_now // bs])
+            want_arena = want_arena.at[li, 0, wblk, 0, ts_now % bs].set(
+                row_now)
+            cached = want_arena[li, 0, pt, 0].reshape(S, P * bs, W)
+            want = ml.absorbed_attention(
+                q, cached, jnp.arange(P * bs)[None] <= ts_now[:, None])
+            np.testing.assert_allclose(
+                np.asarray(out, np.float32)[live],
+                np.asarray(want, np.float32)[live],
+                atol=spec.get("atol", 2e-6))
+            assert not np.asarray(out, np.float32)[~live].any()
+            # the kernel wrote the live slots' rows and nothing else (the
+            # gather's frozen slot dirties the scratch block, the
+            # kernel's writes nowhere)
+            np.testing.assert_array_equal(
+                np.asarray(arena, np.float32)[:, :, 1:],
+                np.asarray(want_arena, np.float32)[:, :, 1:])
+
+
+def test_latent_walk_is_a_function_of_shapes():
+    """Pages a step and buffers in flight follow from (heads, pages, W,
+    block_size, itemsize) alone: the cells' shapes give what PERF.md
+    records (PR 32), a short table a shorter group, and nothing a caller
+    or the environment sets reaches the choice."""
+    import inspect
+    from paddle_tpu.ops import paged_attention as pa
+    assert pa._latent_walk(16, 64, 640, 128, 2) == (4, 3)     # Moonlight
+    assert pa._latent_walk(32, 128, 640, 128, 2) == (4, 3)    # Xing
+    assert pa._latent_walk(16, 1, 640, 128, 2)[0] == 1
+    assert pa._latent_walk(16, 3, 640, 128, 2)[0] == 2
+    # a page so large that four of them would not fit the buffers
+    group, buffers = pa._latent_walk(16, 64, 1024, 256, 4)
+    assert group < 4 and buffers * group * 256 * 1024 * 4 <= pa._LATENT_VMEM
+    assert list(inspect.signature(pa._latent_walk).parameters) == [
+        "heads", "pages", "w", "block_size", "itemsize"]
+    assert list(inspect.signature(pa.latent_paged_attention).parameters) == [
+        "q", "row", "arena", "layer", "pt", "ts", "done"]
+    assert list(inspect.signature(pa._latent_call).parameters) == [
+        "q", "new", "arena", "layer", "pt", "lengths", "interpret"]
+    assert "environ" not in inspect.getsource(pa)
 
 
 def test_flash_forward_at_unequal_widths():

@@ -71,6 +71,19 @@ def test_timeline_cli_without_xprof(tmp_path):
         assert "Traceback" not in r.stderr, (cli, r.stderr)
 
 
+def test_bench_latent_decode_measures_on_a_chip_or_not_at_all():
+    """tools/bench_latent_decode.py times a device kernel: on the CPU it
+    exits 1 and prints no number, it does not fall back to the
+    interpreter."""
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "tools/bench_latent_decode.py")],
+        capture_output=True, text=True, timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 1, (r.returncode, r.stderr)
+    assert "a chip is required" in r.stderr and not r.stdout, (r.stdout,
+                                                              r.stderr)
+
+
 def test_op_bench_single_op():
     sys.path.insert(0, os.path.join(REPO, "tools"))
     import op_bench
