@@ -316,17 +316,20 @@ def attention_scale(cfg):
     return scale
 
 
-def rope(x, pos, theta, scaling=None):
+def rope(x, pos, theta, scaling=None, interleaved=True):
     """Rotary position on the last axis of x at integer positions `pos`
     (broadcast against x's leading axes), in the PUBLISHED element
     order: the interleaved pairs (x0, x1), (x2, x3), ... are first
     permuted to halves (x0, x2, ..., x1, x3, ...), then `x cos +
     rotate_half(x) sin`, at `rope_frequencies(d, theta, scaling)`.
-    Float32 inside, x's type out."""
+    `interleaved=False`: a model published with its pairs already in
+    halves (models/mellum) is not permuted. Float32 inside, x's type
+    out."""
     import jax.numpy as jnp
     d = x.shape[-1]
     x32 = x.astype(jnp.float32)
-    x32 = jnp.concatenate([x32[..., 0::2], x32[..., 1::2]], -1)
+    if interleaved:
+        x32 = jnp.concatenate([x32[..., 0::2], x32[..., 1::2]], -1)
     inv, mscale = rope_frequencies(d, theta, scaling)
     ang = pos.astype(jnp.float32)[..., None] * inv
     cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)
@@ -564,14 +567,22 @@ def _masked_attention(q, k, v, mask, scale, q_block=512):
 
 def route(cfg, lp, x):
     """The router. x (T, h) -> (picks (T, k) int32, weights (T, k)
-    float32). Scores are sigmoid(x W_g) in float32; the k largest of
+    float32), by the config's scoring rule (`cfg.router_scoring`; a
+    config without the field is "sigmoid"). "sigmoid": scores are
+    sigmoid(x W_g) in float32; the k largest of
     score + correction bias are picked (one group, so no group stage);
     the weights are the scores WITHOUT the bias at the picks, over their
-    sum + 1e-20, times routed_scaling_factor."""
+    sum + 1e-20, times routed_scaling_factor. "softmax": scores are
+    softmax(x W_g) over the experts in float32, the k largest are picked
+    and their scores divided by their sum (no bias, no factor)."""
     import jax
     import jax.numpy as jnp
     logits = jnp.dot(x.astype(jnp.float32), lp["router"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
+    if getattr(cfg, "router_scoring", "sigmoid") == "softmax":
+        w, picks = jax.lax.top_k(jax.nn.softmax(logits, -1),
+                                 cfg.experts_per_tok)
+        return picks.astype(jnp.int32), w / w.sum(-1, keepdims=True)
     scores = jax.nn.sigmoid(logits)
     _, picks = jax.lax.top_k(scores + lp["router_bias"].astype(jnp.float32),
                              cfg.experts_per_tok)
@@ -608,7 +619,11 @@ def grouped_experts(lp, xs, group_sizes):
 
 
 def _moe(cfg, lp, x, live):
-    """The expert layer's feed-forward on tokens x (T, h). `live` (T,)
+    """The expert layer's feed-forward on tokens x (T, h), for every
+    config that names `n_routed_experts`, `experts_per_tok`,
+    `n_shared_experts` (0: no shared expert, none traced) and optionally
+    `router_scoring` (see `route`): this block's and models/mellum's.
+    `live` (T,)
     bool: rows that are real (a prefill's padding and a frozen slot's
     ride-along are not: they get no expert and do not count). Returns
     (y (T, h), counters)."""
@@ -626,15 +641,18 @@ def _moe(cfg, lp, x, live):
         xs = x[order // k]                             # (T*k, h)
     with jax.named_scope("moe/experts"):
         ys = grouped_experts(lp, xs, group_sizes)
-    with jax.named_scope("moe/shared"):
-        shared = _swiglu(x, lp["shared_gate"], lp["shared_up"],
-                         lp["shared_down"])
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe/shared"):
+            shared = _swiglu(x, lp["shared_gate"], lp["shared_up"],
+                             lp["shared_down"])
     with jax.named_scope("moe/combine"):
         back = ys[jnp.argsort(order)].reshape(T, k, -1)
         # a row in no group is nobody's: whatever the product left there
         back = jnp.where(live[:, None, None], back, 0)
         y = jnp.einsum("tkh,tk->th", back.astype(jnp.float32), w)
-        y = (y + shared.astype(jnp.float32)).astype(x.dtype)
+        if cfg.n_shared_experts:
+            y = y + shared.astype(jnp.float32)
+        y = y.astype(x.dtype)
     passes = jnp.any(live).astype(jnp.int32)
     kernel = expert_product_path(lp) == "grouped_swiglu_kernel"
     counters = {"expert_tokens": group_sizes,

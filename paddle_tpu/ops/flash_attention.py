@@ -86,14 +86,21 @@ def _masked_scores(q, k, b_ref, k_idx, q_idx, block_q, block_k, kv_len,
 
 def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, sm_scale, causal,
-                block_q, block_k, kv_len):
+                block_q, block_k, kv_len, window=None):
     from jax.experimental import pallas as pl
 
     q_idx, k_idx = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
     d = v_ref.shape[-1]       # the output's width: v's, not q's
+    k_step = k_idx            # the grid's own count: first and last step
+    if window is not None:
+        # a BAND: the grid's third axis is the few KV tiles that touch
+        # the query tile's window, the last of them the diagonal one
+        # (`_flash_call`'s index map fetches the same tile); a step
+        # before the sequence's first tile computes nothing
+        k_idx = q_idx - (nk - 1) + k_idx
 
-    @pl.when(k_idx == 0)
+    @pl.when(k_step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -102,6 +109,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
     def _compute():
         s = _masked_scores(q_ref[0], k_ref[0], b_ref, k_idx, q_idx,
                            block_q, block_k, kv_len, sm_scale, causal)
+        if window is not None:
+            # row i attends columns j with i - window < j (<= i: causal)
+            row = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                   + q_idx * block_q)
+            col = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                   + k_idx * block_k)
+            s = jnp.where(row - col < window, s, _NEG_INF)
         m_prev, l_prev = m_scr[:], l_scr[:]          # (block_q, 128)
         m_curr = jnp.max(s, axis=1)[:, None]         # (block_q, 1)
         m_new = jnp.maximum(m_prev, m_curr)          # (block_q, 128)
@@ -113,7 +127,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
+    if window is not None:
+        @pl.when(k_idx >= 0)
+        def _():
+            _compute()
+    elif causal:
         # skip blocks strictly above the diagonal
         @pl.when(k_idx * block_k <= q_idx * block_q + block_q - 1)
         def _():
@@ -121,7 +139,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
     else:
         _compute()
 
-    @pl.when(k_idx == nk - 1)
+    @pl.when(k_step == nk - 1)
     def _fin():
         d_ = o_ref.shape[-1]
         l = l_scr[:]
@@ -436,9 +454,15 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, widths)
 
 
-def _flash_call(q, k, v, bias, causal, sm_scale, interpret, blocks=None):
+def _flash_call(q, k, v, bias, causal, sm_scale, interpret, blocks=None,
+                group=1, window=None):
     """q: (bn, sq, d); k: (bn, sk, d); v: (bn, sk, dv); bias: (bn, sk) or
-    None. Returns o (bn, sq, dv) unpadded and lse (bn, sq_pad, 128)
+    None. `group` > 1: k and v are (bn // group, sk, .), KV head i // group
+    shared by the query heads i of its group through the index map (no
+    repeated K or V in HBM). `window` (causal self-attention, sq == sk,
+    equal tiles, no bias): row i attends i - window < j <= i, and a query
+    tile visits only the ceil((window - 1) / tile) + 1 KV tiles that touch
+    its window, not the sequence's. Neither exists in the backward. Returns o (bn, sq, dv) unpadded and lse (bn, sq_pad, 128)
     lane-padded. The forward takes a value width of its own (latent
     attention's prefill: q, k of 192 and v of 128); the backward kernels
     do not, and training never asks. `blocks` (block_q, block_k) stands
@@ -457,14 +481,30 @@ def _flash_call(q, k, v, bias, causal, sm_scale, interpret, blocks=None):
     sq, sk = q.shape[1], k.shape[1]
     nq, nk = sq // block_q, sk // block_k
 
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
-        pl.BlockSpec((1, block_k, dv), lambda i, j, kk: (i, kk, 0)),
-    ]
-    args = [q, k, v]
     kw = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
               block_k=block_k, kv_len=sk0)
+    if group == 1 and window is None:
+        kv_map = lambda i, j, kk: (i, kk, 0)
+    else:
+        if window is not None:
+            if not causal or bias is not None or sq != sk \
+                    or block_q != block_k:
+                raise ValueError(
+                    "a window is causal self-attention over equal tiles "
+                    "without a bias")
+            nk = min(nk, -(-(window - 1) // block_k) + 1)
+            kw["window"] = int(window)
+            band = nk - 1
+            kv_map = lambda i, j, kk: (i // group,
+                                       jnp.maximum(j - band + kk, 0), 0)
+        else:
+            kv_map = lambda i, j, kk: (i // group, kk, 0)
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
+        pl.BlockSpec((1, block_k, d), kv_map),
+        pl.BlockSpec((1, block_k, dv), kv_map),
+    ]
+    args = [q, k, v]
     if bias is not None:
         args.append(_pad_to(bias, 1, block_k)[:, None, :])  # (bn, 1, sk)
         in_specs.append(pl.BlockSpec((1, 1, block_k),
@@ -728,22 +768,28 @@ def flash_dispatch(q, k, bias=None, impl: Optional[str] = None):
 _ONE_TILE_ROWS = 1024
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
-def _causal_rows_call(q, k, v, sm_scale, interpret):
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "interpret", "window"))
+def _causal_rows_call(q, k, v, sm_scale, interpret, window=None):
     # jitted like ops/paged_attention's calls: a program that unrolls its
     # layers traces and lowers the kernel once and calls it from each
     rows = q.shape[0]
     o, _ = _flash_call(
         q.swapaxes(0, 1), k.swapaxes(0, 1), v.swapaxes(0, 1), None, True,
         sm_scale, interpret,
-        blocks=(rows, rows) if rows <= _ONE_TILE_ROWS else None)
+        blocks=(rows, rows) if rows <= _ONE_TILE_ROWS else None,
+        group=q.shape[1] // k.shape[1], window=window)
     return o.swapaxes(0, 1)
 
 
-def flash_causal_rows(q, k, v, sm_scale):
+def flash_causal_rows(q, k, v, sm_scale, window=None):
     """Causal self-attention of ONE sequence's rows by the tiled flash
-    forward, for a serving prefill: q, k, v (rows, heads, d) as the
-    projections leave them, row i attending over rows 0..i; returns
+    forward, for a serving prefill: q (rows, heads, d), k, v (rows,
+    kv_heads, d) as the projections leave them (kv_heads divides heads:
+    query head i reads KV head i // (heads / kv_heads), shared by the
+    index map), row i attending over rows 0..i, or with `window` over the
+    last `window` of them, itself counted (a band: only the KV tiles that
+    touch a query tile's window are visited); returns
     (rows, heads, d). No residuals, no backward. Up to _ONE_TILE_ROWS
     the sequence is one tile a head (`_pick_blocks` would cut 768 rows,
     no multiple of 512, into 36 tiles of 128 x 128); longer ones take
@@ -755,7 +801,8 @@ def flash_causal_rows(q, k, v, sm_scale):
         raise RuntimeError(
             "flash_causal_rows compiles for TPU (Mosaic) and interprets "
             f"on CPU for tests; the active backend is {platform!r}")
-    return _causal_rows_call(q, k, v, float(sm_scale), platform == "cpu")
+    return _causal_rows_call(q, k, v, float(sm_scale), platform == "cpu",
+                             window=None if window is None else int(window))
 
 
 def attention(q, k, v, bias=None, causal: bool = False,

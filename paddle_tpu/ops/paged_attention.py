@@ -182,11 +182,250 @@ def _call(q, new, arena, layer, pt, lengths, interpret):
     return out[:, :, 0, w // 2:], arena
 
 
-def paged_attention(q, k, v, arena, layer, pt, ts, done=None):
+# -- grouped heads and a row range ---------------------------------------------
+#
+# A model whose `group` query heads share one KV head (the arena holds the
+# KV heads: a page is (kv_heads, block_size, 2*hd)) reads a page ONCE for all
+# of them: the group's queries are the ROWS of two small matrix products, q
+# (rows, hd) x K^T (hd, block_size) and p (rows, block_size) x V, per KV
+# head, where the kernel above multiplies one query into a page on the VPU.
+# And the walk has a lower bound: slot s attends positions lo[s]..ts[s] and
+# fetches pages lo // block_size .. ts // block_size alone, the page that
+# holds `lo` masked below it, not skipped. The page table is read as a RING:
+# page p of a slot is block pt[s, p % P]. For a table that holds every page
+# (p < P) that is the table itself; for a window layer's fixed ring of
+# ceil(window / block_size) + 1 blocks it is where position p * block_size
+# was written (serving/model.py). What PR 32 taught the latent walk is taken
+# over where it applies: the walk moves in groups of _GROUPED_PAGES pages,
+# each page copied from its own block into a row slice of one buffer a KV
+# head, and one step of the online softmax attends a group (two products a
+# KV head a group, not a page); _GROUPED_BUFFERS - 1 groups are in flight
+# while a step computes. A short last group is attended at the buffer's
+# size with the pages that were not fetched masked (the buffers are zeroed
+# once, so what they hold is always a number). The live page's write-back
+# is waited for when the stage is next needed (or by the last program).
+# The READS still drain between slots: the latent walk's one stream of
+# groups across slots is not taken over (PERF.md, section 7).
+
+
+# pages a step of the grouped walk's online softmax and group buffers (the
+# latent walk's lesson, PR 32: a step's fixed cost wants several pages)
+_GROUPED_PAGES = 4
+_GROUPED_BUFFERS = 3
+
+
+def _grouped_kernel(layer_ref, pt_ref, lo_ref, len_ref, q_ref, new_ref,
+                    arena_ref, arena_out_ref, o_ref, kv_buf, stage, writing,
+                    sems, wsem, *, block_size, pages, group, buffers):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s = pl.program_id(0)
+    li = layer_ref[0]
+    bs = block_size
+    length = len_ref[s]                       # live rows; 0 = frozen slot
+    lo = lo_ref[s]                            # first position attended
+    kv_heads, rows, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+
+    @pl.when(s == 0)
+    def _first():
+        # a group's buffer is attended whole, the rows of pages that were
+        # not fetched masked: they must be numbers (p = 0 times them)
+        kv_buf[...] = jnp.zeros_like(kv_buf)
+        writing[0] = 0
+
+    def write_back(blk=0):
+        return pltpu.make_async_copy(
+            stage, arena_out_ref.at[li, 0, blk], wsem)
+
+    def block_of(p):
+        return pt_ref[s * pages + jax.lax.rem(p, pages)]
+
+    def page_copy(p, buf, i):
+        """Page p of this slot -> rows [i * bs, (i + 1) * bs) of every KV
+        head of group buffer `buf`."""
+        return pltpu.make_async_copy(
+            arena_ref.at[li, 0, block_of(p)],
+            kv_buf.at[buf, :, pl.ds(pl.multiple_of(i * bs, bs), bs)],
+            sems.at[buf])
+
+    def attend(kv, p0, carry):
+        """The group of pages that starts at page p0 in one step of the
+        online softmax, every KV head in turn; kv (kv_heads, G * bs, 2*hd)
+        as stored. Rows outside [lo, length) are masked: below `lo` in
+        the first page, past the live row in the last, the pages of a
+        short group that were not fetched, and whatever a ring block
+        still holds of a position that left the window."""
+        pos = p0 * bs + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, group * bs), 1)
+        keep = jnp.logical_and(pos >= lo, pos < length)
+        out = []
+        for h in range(kv_heads):
+            m, l, acc = carry[h]
+            sc = jax.lax.dot_general(
+                q_ref[0, h], kv[h, :, :hd], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)       # (rows, G * bs)
+            sc = jnp.where(keep, sc, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            pr = jnp.where(keep, jnp.exp(sc - m_new), 0.0)
+            l = l * alpha + jnp.sum(pr, axis=1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                pr.astype(kv.dtype), kv[h, :, hd:], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)       # (rows, hd)
+            out.append((m_new, l, acc))
+        return tuple(out)
+
+    @pl.when(length > 0)
+    def _live():
+        first = jax.lax.div(lo, bs)
+        last = jax.lax.div(length - 1, bs)
+        n_pages = last - first + 1
+        n_groups = jax.lax.div(n_pages + group - 1, group)
+
+        def start(g):
+            """The live pages of group g into buffer g % buffers; nothing
+            past the slot's last group."""
+            p0 = first + g * group
+            live = jnp.clip(last + 1 - p0, 0, group)
+            buf = jax.lax.rem(g, buffers)
+
+            def one(i, c):
+                page_copy(p0 + i, buf, i).start()
+                return c
+            jax.lax.fori_loop(0, live, one, 0)
+
+        def landed(g):
+            p0 = first + g * group
+            live = jnp.clip(last + 1 - p0, 0, group)
+            buf = jax.lax.rem(g, buffers)
+
+            def one(i, c):
+                page_copy(p0, buf, i).wait()       # a wait reads sizes only
+                return c
+            jax.lax.fori_loop(0, live, one, 0)
+            return buf
+
+        for g in range(buffers - 1):
+            start(g)
+
+        def group_step(g, carry):
+            buf = landed(g)
+            # into the buffer the step before this one read
+            start(g + buffers - 1)
+            return attend(kv_buf[buf], first + g * group, carry)
+
+        carry = jax.lax.fori_loop(
+            0, n_groups - 1, group_step,
+            tuple((jnp.full((rows, 1), _NEG_INF, jnp.float32),
+                   jnp.zeros((rows, 1), jnp.float32),
+                   jnp.zeros((rows, hd), jnp.float32))
+                  for _ in range(kv_heads)))
+        # the last group holds the live page: it takes this step's own
+        # K|V row where it has landed, is attended WITH it and goes back
+        # whole from the stage (see the kernel above)
+        g_last = n_groups - 1
+        buf = landed(g_last)
+        i_live = last - (first + g_last * group)
+        at = jax.lax.rem(length - 1, bs)
+        r0 = pl.multiple_of(i_live * bs, bs)
+        page = kv_buf[buf, :, pl.ds(r0, bs), :]
+        row = jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
+        page = jnp.where(row == at, new_ref[0].astype(jnp.float32),
+                         page.astype(jnp.float32)).astype(stage.dtype)
+        kv_buf[buf, :, pl.ds(r0, bs), :] = page
+
+        # the write-back of the slot before this one is waited for here,
+        # where the stage is next needed (or by the last program), not at
+        # its own program's end
+        @pl.when(writing[0] == 1)
+        def _stage_free():
+            write_back().wait()
+        stage[...] = page
+        write_back(block_of(last)).start()
+        writing[0] = 1
+        done = attend(kv_buf[buf], first + g_last * group, carry)
+        for h in range(kv_heads):
+            _, l, acc = done[h]
+            o_ref[0, h] = (acc / l).astype(o_ref.dtype)
+
+    @pl.when(length == 0)
+    def _frozen():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(jnp.logical_and(s == pl.num_programs(0) - 1, writing[0] == 1))
+    def _drain():
+        write_back().wait()
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _grouped_call(q, new, arena, layer, pt, lo, lengths, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s_dim, kv_heads, w = new.shape
+    hd = w // 2
+    heads = q.shape[1] // kv_heads
+    # the group's queries as the rows of a matrix product: a whole packed
+    # tile of the arena's type (16 rows of bfloat16, 8 of float32), the
+    # rows past the group zero
+    tile = 32 // arena.dtype.itemsize
+    rows = -(-heads // tile) * tile
+    qg = (q.astype(jnp.float32) * (1.0 / np.sqrt(hd))).astype(arena.dtype)
+    qg = qg.reshape(s_dim, kv_heads, heads, hd)
+    if rows != heads:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows - heads), (0, 0)))
+    block_size = arena.shape[4]
+    pages = pt.shape[1]
+    group = min(_GROUPED_PAGES, pages)
+    kern = functools.partial(_grouped_kernel, block_size=block_size,
+                             pages=pages, group=group,
+                             buffers=_GROUPED_BUFFERS)
+    q_spec = pl.BlockSpec((1, kv_heads, rows, hd), lambda s, *_: (s, 0, 0, 0))
+    new_spec = pl.BlockSpec((1, kv_heads, 1, w), lambda s, *_: (s, 0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    arena, out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(s_dim,),
+            in_specs=[q_spec, new_spec, hbm],
+            out_specs=[hbm, q_spec],
+            scratch_shapes=[
+                pltpu.VMEM((_GROUPED_BUFFERS, kv_heads, group * block_size,
+                            w), arena.dtype),
+                pltpu.VMEM((kv_heads, block_size, w), arena.dtype),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.SemaphoreType.DMA((_GROUPED_BUFFERS,)),
+                pltpu.SemaphoreType.DMA(()),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct(arena.shape, arena.dtype),
+                   jax.ShapeDtypeStruct((s_dim, kv_heads, rows, hd),
+                                        q.dtype)],
+        # operand 6 counts the four scalar-prefetch operands
+        input_output_aliases={6: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_attention_grouped",
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      pt.reshape(-1).astype(jnp.int32), lo.astype(jnp.int32),
+      lengths.astype(jnp.int32), qg, new[:, :, None, :], arena)
+    return out[:, :, :heads].reshape(s_dim, kv_heads * heads, hd), arena
+
+
+def paged_attention(q, k, v, arena, layer, pt, ts, done=None, lo=None):
     """One decode step's attention over the paged pool, with its write.
 
-    q, k, v: (S, heads, hd), the projections of the new position ts[s]
-    of slot s. arena: the bare full-precision array (layers, 1,
+    q: (S, q_heads, hd), k, v: (S, heads, hd), the projections of the new
+    position ts[s] of slot s; q_heads is heads times the GROUP of query
+    heads that share a KV head (1: a GPT's; query head i reads KV head
+    i // group). lo: None, or (S,) int32, the first position slot s
+    attends (a window layer's max(0, ts - window + 1)); pages before
+    lo // block_size are not fetched. With a group or a bound the page
+    table is a ring, page p at pt[s, p % P], and the kernel is the
+    grouped one above; group 1 without a bound is the kernel it always
+    was. arena: the bare full-precision array (layers, 1,
     num_blocks, heads, block_size, 2*hd). layer: which plane of it (a
     python int or an int32 scalar: one kernel serves every layer). pt:
     (S, P) int32 page table, ts: (S,) int32 positions. Slot s writes
@@ -216,7 +455,12 @@ def paged_attention(q, k, v, arena, layer, pt, ts, done=None):
     if done is not None:
         lengths = jnp.where(done, 0, lengths)
     new = jnp.concatenate([k, v], -1).astype(arena.dtype)
-    return _call(q, new, arena, layer, pt, lengths, platform == "cpu")
+    if q.shape[1] == k.shape[1] and lo is None:
+        return _call(q, new, arena, layer, pt, lengths, platform == "cpu")
+    if lo is None:
+        lo = jnp.zeros_like(lengths)
+    return _grouped_call(q, new, arena, layer, pt, lo, lengths,
+                         platform == "cpu")
 
 
 # -- latent rows: every head attends the SAME cached row ----------------------

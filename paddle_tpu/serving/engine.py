@@ -447,6 +447,13 @@ class ServingEngine:
                               arena_device=plan.arena_sharding
                               if plan else None,
                               kv_dtype=serving.kv_dtype)
+        if len(self.kv.group_layout) > 1 and serving.preempt:
+            raise ValueError(
+                f"the serving model {model.name!r} has "
+                f"{len(self.kv.group_layout)} cache groups: host swap "
+                "(preempt=True) carries one group's blocks — refusing at "
+                "construction rather than parking a sequence without its "
+                "window rows")
         self.scheduler = ContinuousBatchingScheduler(
             params, cfg, self.kv, self.buckets, top_k=serving.top_k,
             decode_chunk=serving.decode_chunk, overlap=serving.overlap,
@@ -589,12 +596,12 @@ class ServingEngine:
                 f"prompt ({prompt.size}) + max_new_tokens "
                 f"({max_new_tokens}) exceeds the pool's max_len "
                 f"({self.kv.max_len})")
-        if self.kv.blocks_for(total) > self.kv.blocks_total:
-            # an undersized arena (kv_blocks oversubscription) must shed
-            # impossible requests at the door, not queue them forever
-            raise ValueError(
-                f"request needs {self.kv.blocks_for(total)} KV blocks "
-                f"but the arena only has {self.kv.blocks_total}")
+        shortfall = self.kv.request_shortfall(total)
+        if shortfall is not None:
+            # an undersized arena (kv_blocks oversubscription, of any
+            # cache group's pool) must shed impossible requests at the
+            # door, not queue them forever
+            raise ValueError(shortfall)
         req = GenerationRequest(
             prompt, max_new_tokens, temperature, seed, eos_id, on_token,
             self.config.clock,
@@ -1022,6 +1029,15 @@ class ServingEngine:
         this on every replica engine when its own drain begins."""
         self._draining = True
 
+    def _refuse_grouped_migration(self, what: str) -> None:
+        """A ticket carries the primary cache group's blocks alone."""
+        from .migration import MigrationError
+        if len(self.kv.group_layout) > 1:
+            raise MigrationError(
+                f"{what} refused: the serving model {self.model.name!r} "
+                f"has {len(self.kv.group_layout)} cache groups and a "
+                "ticket carries one")
+
     def migrate_out(self, request) -> "Any":
         """Extract one RUNNING or PARKED sequence into a portable
         MigrationTicket: fence the pipeline (its tokens fan out
@@ -1044,6 +1060,7 @@ class ServingEngine:
         the sequence running on this engine."""
         from .migration import MigrationError, MigrationTicket
 
+        self._refuse_grouped_migration("migrate_out")
         if self._draining:
             raise MigrationError(
                 "engine is draining; migrate_out refused — the drain "
@@ -1162,6 +1179,7 @@ class ServingEngine:
         injected adopt-phase fault fires before any state changes."""
         from .migration import MigrationError
 
+        self._refuse_grouped_migration("migrate_in")
         if self._draining:
             raise MigrationError(
                 "engine is draining; migrate_in refused — not adopting "
@@ -1433,6 +1451,13 @@ class ServingEngine:
         s["prefill_attention"] = dict(
             self.scheduler.prefill_counts, flash_buckets=flash,
             path="flash" if flash else verdicts[max(verdicts)])
+        if len(self.kv.group_layout) > 1:
+            # every cache group's layers prefill by the one verdict (a
+            # window group's flash forward is the banded one), and
+            # `decode_attention` above is the model's {group: path}
+            s["prefill_attention"]["groups"] = {
+                g.spec.name: s["prefill_attention"]["path"]
+                for g in self.kv.group_layout}
         # the served architecture, what a token costs the arena in a
         # layer, and the model's own in-graph counters (a routed model's
         # `expert_tokens` and `router_tokens` since start)

@@ -30,7 +30,19 @@ which the decode tail writes into) are ever shared, so the first block a
 request writes is private by construction and two requests sharing a
 prefix can never see each other's divergence.
 
-Block index 0 is the reserved SCRATCH block: never allocated, it absorbs
+CACHE GROUPS (serving/model.py): a model whose layers keep different
+state (layers that attend over a window beside layers that attend over
+everything) names several CacheSpecs. The first is the PRIMARY group and
+is everything described above; each further group is a `_GroupPool`: an
+arena and a free list of its own, and a page row a slot that follows the
+primary's in the SAME page table (`group_layout[i].columns`). A window
+group's row is a RING of `ring_pages` blocks fixed at admission: a slot
+never holds more than `window + block_size` rows of it, nothing is freed
+before the slot is. Admission needs every pool to have the blocks; a
+model with more than one group takes no prefix hits and cannot be
+swapped out (one payload carries one group).
+
+Block index 0 is the reserved SCRATCH block (of every group's arena): never allocated, it absorbs
 the in-graph ride-along writes of frozen slots (see
 the models' decode steps) and the page-row padding past a sequence's tail.
 
@@ -97,6 +109,71 @@ class ShapeBuckets:
 SCRATCH_BLOCK = 0
 
 
+class _GroupPool:
+    """A further cache group's blocks: an arena, a free list and every
+    slot's page row (`layout.pages` entries, all claimed at admission and
+    released with the slot). No sharing, no hashes: a block belongs to
+    one slot."""
+
+    def __init__(self, layout, num_slots, block_size, num_blocks, alloc,
+                 dtype):
+        self.layout = layout
+        self.block_size = int(block_size)
+        if num_blocks is None:
+            num_blocks = num_slots * layout.pages + 1
+        self.num_blocks = int(num_blocks)
+        if self.num_blocks < 2:
+            raise ValueError(
+                f"cache group {layout.spec.name!r}: num_blocks must be >= "
+                f"2 (scratch + 1), got {num_blocks}")
+        shape = layout.spec.arena_shape(self.num_blocks, self.block_size)
+        self.kv = alloc(shape, dtype)
+        self.pool_bytes = math.prod(shape) * dtype.itemsize
+        self._free_blocks = list(range(self.num_blocks - 1, 0, -1))
+        self._slot_blocks: List[List[int]] = [[] for _ in range(num_slots)]
+        self.peak_blocks_used = 0
+
+    @property
+    def blocks_total(self) -> int:
+        return self.num_blocks - 1
+
+    @property
+    def blocks_used(self) -> int:
+        return self.blocks_total - len(self._free_blocks)
+
+    def blocks_for(self, positions: int) -> int:
+        """Blocks a slot of `positions` positions holds of this group:
+        its pages, never more than the ring's."""
+        return min((positions - 1) // self.block_size + 1, self.layout.pages)
+
+    def can_hold(self, positions: int) -> bool:
+        return self.blocks_for(positions) <= len(self._free_blocks)
+
+    def claim(self, slot: int, positions: int) -> List[int]:
+        blocks = [self._free_blocks.pop()
+                  for _ in range(self.blocks_for(positions))]
+        self._slot_blocks[slot] = blocks
+        self.peak_blocks_used = max(self.peak_blocks_used, self.blocks_used)
+        return blocks
+
+    def release(self, slot: int) -> None:
+        self._free_blocks.extend(reversed(self._slot_blocks[slot]))
+        self._slot_blocks[slot] = []
+
+    def held_rows(self, slot: int) -> int:
+        """Rows this group can hold of the slot: its blocks' worth."""
+        return len(self._slot_blocks[slot]) * self.block_size
+
+    def occupancy(self) -> Dict[str, object]:
+        spec = self.layout.spec
+        return {"name": spec.name, "window": spec.window,
+                "layers": spec.layers, "pages_a_slot": self.layout.pages,
+                "blocks_total": self.blocks_total,
+                "blocks_used": self.blocks_used,
+                "peak_blocks_used": self.peak_blocks_used,
+                "pool_bytes": self.pool_bytes}
+
+
 class SlotKVCache:
     """Paged block arena + slot/page allocator + hashed prefix cache.
 
@@ -114,7 +191,7 @@ class SlotKVCache:
     admission falls back to queueing when pages run out."""
 
     def __init__(self, cfg, num_slots: int, max_len: int, dtype=None,
-                 block_size: int = 16, num_blocks: Optional[int] = None,
+                 block_size: int = 16, num_blocks=None,
                  prefix_cache: bool = True, mesh_shards: int = 1,
                  arena_device=None, kv_dtype: Optional[str] = None):
         import jax.numpy as jnp
@@ -137,8 +214,28 @@ class SlotKVCache:
                 f"mesh_shards must be >= 1, got {mesh_shards}")
         # deferred like the scheduler's: a model's module must not be
         # imported during package import
-        from .model import serving_model
-        spec = serving_model(cfg).cache_spec(cfg)
+        from .model import cache_groups, serving_model
+        # the model's cache groups in one page table; the first is the
+        # primary, which everything below this block is about
+        self.group_layout = cache_groups(serving_model(cfg), cfg, max_len,
+                                         block_size)
+        spec = self.group_layout[0].spec
+        grouped = len(self.group_layout) > 1
+        if grouped and (mesh_shards != 1 or kv_dtype is not None):
+            raise ValueError(
+                "a model with several cache groups is served on one chip "
+                "from full-precision arenas: mesh_shards and kv_dtype "
+                "carry one group")
+        # `num_blocks`: the primary pool's, or one a group
+        if isinstance(num_blocks, (tuple, list)):
+            if len(num_blocks) != len(self.group_layout):
+                raise ValueError(
+                    f"num_blocks names {len(num_blocks)} pools, the model "
+                    f"has {len(self.group_layout)} cache groups")
+            group_blocks = list(num_blocks[1:])
+            num_blocks = num_blocks[0]
+        else:
+            group_blocks = [None] * (len(self.group_layout) - 1)
         if spec.heads % mesh_shards:
             raise ValueError(
                 f"the arena's {spec.heads} heads are not divisible by "
@@ -157,7 +254,9 @@ class SlotKVCache:
         if self.num_blocks < 2:
             raise ValueError(
                 f"num_blocks must be >= 2 (scratch + 1), got {num_blocks}")
-        self.prefix_cache_enabled = bool(prefix_cache)
+        # a hit of n blocks is valid for a window group only with the
+        # window's rows before it: a model with further groups takes none
+        self.prefix_cache_enabled = bool(prefix_cache) and not grouped
         # kv_dtype: the arena STORAGE discipline — None keeps the
         # compute-dtype slab ("float32"/"bfloat16" pool), "int8" packs
         # one byte per K/V value plus a per-(block, head, row) f32
@@ -201,6 +300,15 @@ class SlotKVCache:
         self._pool_bytes = math.prod(shape) * self.dtype.itemsize
         if self.kv_quantized:
             self._pool_bytes += math.prod(scale_shape) * 4
+        # the further groups' pools (none for a model with one group)
+        self._pools: List[_GroupPool] = [
+            _GroupPool(layout, self.num_slots, self.block_size, nb, alloc,
+                       self.dtype)
+            for layout, nb in zip(self.group_layout[1:], group_blocks)]
+        self._pool_bytes += sum(p.pool_bytes for p in self._pools)
+        # columns of the one page table: the primary's, then each pool's
+        self.table_width = self.max_pages + sum(
+            p.layout.pages for p in self._pools)
         # -- slot allocator (page-table rows) --
         self._free = list(range(self.num_slots - 1, -1, -1))  # pop->0,1,..
         self._free_set = set(self._free)           # O(1) double-free check
@@ -208,7 +316,7 @@ class SlotKVCache:
         self._slot_blocks: List[List[int]] = [[] for _ in
                                               range(self.num_slots)]
         # host mirror of the device page table (scratch-filled rows)
-        self.page_table = np.zeros((self.num_slots, self.max_pages),
+        self.page_table = np.zeros((self.num_slots, self.table_width),
                                    np.int32)
         # -- block allocator (block 0 = scratch, never handed out) --
         self._free_blocks = list(range(self.num_blocks - 1, 0, -1))
@@ -288,6 +396,8 @@ class SlotKVCache:
         for b in reversed(self._slot_blocks[slot]):
             self._decref(b)
         self._slot_blocks[slot] = []
+        for pool in self._pools:
+            pool.release(slot)
         self.page_table[slot, :] = SCRATCH_BLOCK
         self._len[slot] = 0
         self._free.append(slot)
@@ -414,7 +524,9 @@ class SlotKVCache:
                 lru_hits += 1
         feasible = (total_blocks - len(hit_blocks)
                     <= len(self._free_blocks) + len(self._lru)
-                    - lru_hits)
+                    - lru_hits
+                    and all(pool.can_hold(total_positions)
+                            for pool in self._pools))
         plan = (digests, hit_blocks, lru_hits, total_blocks, feasible)
         self._plan_cache = (self._plan_gen, key, plan)
         return plan
@@ -516,6 +628,11 @@ class SlotKVCache:
         elif pending:
             self._pending_reg[slot] = pending
         row = self._install_blocks(slot, blocks, p_len)
+        for pool in self._pools:
+            # every further group's row, whole, beside the primary's
+            held = pool.claim(slot, total_positions)
+            row[pool.layout.start:pool.layout.start + len(held)] = held
+        self.page_table[slot] = row
         return row, len(claimed) * bs
 
     def register_prefix(self, slot: int, frontier: int) -> None:
@@ -548,7 +665,7 @@ class SlotKVCache:
         row (scratch-padded) and update length/peak accounting — the
         shared tail of map_slot (admission) and adopt_blocks (swap-in)."""
         self._slot_blocks[slot] = blocks
-        row = np.full((self.max_pages,), SCRATCH_BLOCK, np.int32)
+        row = np.full((self.table_width,), SCRATCH_BLOCK, np.int32)
         row[:len(blocks)] = blocks
         self.page_table[slot] = row
         self._len[slot] = int(length)
@@ -568,6 +685,10 @@ class SlotKVCache:
         `n_blocks` private blocks (free + LRU-evictable)."""
         if n_blocks < 1:
             raise ValueError(f"n_blocks must be >= 1, got {n_blocks}")
+        if self._pools:
+            raise ValueError(
+                "a swapped sequence carries the primary cache group alone: "
+                "a model with several groups is not swapped or migrated")
         return n_blocks <= self.blocks_available
 
     def adopt_blocks(self, slot: int, n_blocks: int,
@@ -618,12 +739,19 @@ class SlotKVCache:
         way (a tuple donates both leaves)."""
         if self.kv_scales is not None:
             return (self.kv, self.kv_scales)
+        if self._pools:
+            # one arena a cache group, the primary first
+            return (self.kv,) + tuple(p.kv for p in self._pools)
         return self.kv
 
     def store_arena(self, arena) -> None:
         """Store a dispatch's arena output back (the donated buffers'
         successors) — the write half of the `arena` property."""
-        if self.kv_scales is not None:
+        if self._pools:
+            self.kv = arena[0]
+            for pool, kv in zip(self._pools, arena[1:]):
+                pool.kv = kv
+        elif self.kv_scales is not None:
             self.kv, self.kv_scales = arena
         else:
             self.kv = arena
@@ -660,7 +788,49 @@ class SlotKVCache:
         overstates per-chip HBM by the mesh factor."""
         return self._pool_bytes // self.mesh_shards
 
+    def request_shortfall(self, positions: int) -> Optional[str]:
+        """Why a request of `positions` positions could NEVER be mapped,
+        whatever is free (a pool smaller than the request), or None."""
+        if self.blocks_for(positions) > self.blocks_total:
+            return (f"request needs {self.blocks_for(positions)} KV blocks "
+                    f"but the arena only has {self.blocks_total}")
+        for pool in self._pools:
+            if pool.blocks_for(positions) > pool.blocks_total:
+                return (f"request needs {pool.blocks_for(positions)} blocks "
+                        f"of the cache group {pool.layout.spec.name!r} but "
+                        f"its pool only has {pool.blocks_total}")
+        return None
+
+    def group_rows(self, slot: int) -> Dict[str, int]:
+        """{group name: rows the group can hold of `slot` right now}: its
+        mapped blocks' worth (a window group's never passes window +
+        block_size)."""
+        rows = {self.spec.name:
+                len(self._slot_blocks[slot]) * self.block_size}
+        rows.update({p.layout.spec.name: p.held_rows(slot)
+                     for p in self._pools})
+        return rows
+
     def occupancy(self) -> Dict[str, object]:
+        out = self._occupancy()
+        if self._pools:
+            # every pool, the primary first (whose numbers are also the
+            # top-level ones); `pool_bytes` above is their sum
+            spec = self.spec
+            out["groups"] = [
+                {"name": spec.name, "window": spec.window,
+                 "layers": spec.layers, "pages_a_slot": self.max_pages,
+                 "blocks_total": self.blocks_total,
+                 "blocks_used": self.blocks_used,
+                 "peak_blocks_used": self.peak_blocks_used,
+                 "pool_bytes": self._pool_bytes
+                 - sum(p.pool_bytes for p in self._pools)}
+            ] + [p.occupancy() for p in self._pools]
+            out["prefix_cache"] = "off: a window group's rows before a " \
+                "hit are not kept"
+        return out
+
+    def _occupancy(self) -> Dict[str, object]:
         return {"num_slots": self.num_slots,
                 "active_slots": self.active_count,
                 "free_slots": self.free_count,

@@ -20,14 +20,35 @@ A config object names its model by a `serving_model()` method
 without one is served as a GPT, which is what the engine did before it
 had this interface. The model's module is imported when the first engine
 over such a config is built, never by `import paddle_tpu`.
+
+CACHE GROUPS. `cache_spec` may return a tuple of `CacheSpec`s: each is a
+group of the model's layers with an arena, a block pool and page rows of
+its own (layers that attend over everything beside layers that attend
+over a window). The first group is the PRIMARY: its pages are columns
+`[0, max_pages)` of the one page table and it alone is what the
+single-group engine always had (`kv_blocks`, `blocks_used`, the gauges).
+Every further group's page row follows in the SAME table, at the columns
+`cache_groups` gives (`GroupLayout.columns`), and its block ids index ITS
+arena; the arena the programs are handed is then the tuple of the
+groups' arenas, in order. A group with a `window` is a RING: a slot holds
+at most `ring_pages(window, block_size) = ceil(window / block_size) + 1`
+blocks of it whatever its length, the row is fixed at admission (the
+device table changes at admission alone, nothing is freed mid-request)
+and position p lives in row entry `(p // block_size) % ring_pages`, row
+`p % block_size`; a block is overwritten once every position in it has
+left every later position's window. A model with more than one group
+takes no prefix hits (a hit is valid for a window group only with the
+window's rows before it), and host swap (`preempt`) and migration are
+refused for it at construction and at the call: both carry one group.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional, Tuple
 
-__all__ = ["FEATURES", "CacheSpec", "ServingModel", "serving_model",
-           "require_features"]
+__all__ = ["FEATURES", "CacheSpec", "GroupLayout", "ServingModel",
+           "serving_model", "require_features", "cache_groups",
+           "group_columns", "ring_pages"]
 
 # engine features a model may implement, each switched on by a
 # ServingConfig field (see `require_features`)
@@ -39,14 +60,75 @@ class CacheSpec(NamedTuple):
     """The per-layer cache state of one token, as the arena stores it:
     the block arena is `(layers, 1, num_blocks, heads, block_size,
     row_width)`, blocks on axis 2 (swap payloads and migration tickets
-    index it) and heads on axis 3 (a mesh plan shards it)."""
+    index it) and heads on axis 3 (a mesh plan shards it). One spec is
+    one GROUP of layers; `window` (None: every position is kept) makes
+    the group a ring of `ring_pages` blocks a slot; `name` is how
+    `engine.stats()` calls the group."""
     layers: int
     heads: int
     row_width: int
+    window: Optional[int] = None
+    name: str = "kv"
 
     def arena_shape(self, num_blocks: int, block_size: int):
         return (self.layers, 1, num_blocks, self.heads, block_size,
                 self.row_width)
+
+
+def ring_pages(window: int, block_size: int) -> int:
+    """Blocks a slot holds of a window group: the window's own and the
+    one being written."""
+    return -(-int(window) // int(block_size)) + 1
+
+
+class GroupLayout(NamedTuple):
+    """Where a cache group's page row lies in the one page table:
+    columns [start, start + pages)."""
+    spec: CacheSpec
+    start: int
+    pages: int
+
+    @property
+    def columns(self) -> slice:
+        return slice(self.start, self.start + self.pages)
+
+
+def _layout(specs, max_pages: int, block_size: int):
+    out, start = [], 0
+    for spec in specs:
+        pages = max_pages if spec.window is None else \
+            min(max_pages, ring_pages(spec.window, block_size))
+        out.append(GroupLayout(spec, start, pages))
+        start += pages
+    return tuple(out)
+
+
+def cache_groups(model, cfg, max_len: int, block_size: int
+                 ) -> Tuple[GroupLayout, ...]:
+    """The model's cache groups laid out in one page table for slots of
+    `max_len` positions: the primary group's `max_pages` columns first,
+    then each further group's (a window group's `ring_pages`, never more
+    than `max_pages`)."""
+    specs = model.cache_spec(cfg)
+    if isinstance(specs, CacheSpec):
+        specs = (specs,)
+    if specs[0].window is not None:
+        raise ValueError("the first cache group is the primary one and "
+                         "keeps every position: list a window group after "
+                         "it")
+    return _layout(specs, -(-int(max_len) // int(block_size)), block_size)
+
+
+def group_columns(specs, width: int, block_size: int
+                  ) -> Tuple[slice, ...]:
+    """Each group's columns in a page table of `width` columns, as
+    `cache_groups` laid them out: what a model's programs, which are
+    handed the table and not `max_len`, split it by."""
+    for max_pages in range(1, width + 1):
+        layout = _layout(specs, max_pages, block_size)
+        if layout[-1].start + layout[-1].pages == width:
+            return tuple(g.columns for g in layout)
+    raise ValueError(f"no page table of these groups is {width} columns wide")
 
 
 class ServingModel:
@@ -84,6 +166,11 @@ class ServingModel:
           feature "speculation": the positions ts..ts+k of every slot
           in one pass, row j attending over 0..ts+j; writes past a
           slot's page row and a frozen slot's go to scratch.
+    With several cache groups (`cache_spec` a tuple) `arena` is the
+    tuple of the groups' arenas, the primary first, and `pages` / `pt`
+    hold every group's page row side by side at `cache_groups`' columns
+    (a window group's a ring, read modulo its width); `pfx_len` is then
+    always 0 (no prefix hits).
     `counters` is None or a dict of small int32 arrays the program
     accumulated in-graph (a routed model's tokens per expert), with the
     names and shapes `counter_names` gives, from both programs alike;
@@ -98,7 +185,9 @@ class ServingModel:
     def max_positions(self, cfg) -> int:
         raise NotImplementedError
 
-    def cache_spec(self, cfg) -> CacheSpec:
+    def cache_spec(self, cfg):
+        """One CacheSpec, or a tuple of them (cache groups, the primary
+        first: the module's docstring)."""
         raise NotImplementedError
 
     def activation_dtype(self, params):

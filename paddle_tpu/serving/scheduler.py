@@ -685,7 +685,7 @@ class ContinuousBatchingScheduler:
             adapters_on)
 
         # device page table: every row scratch until its slot admits
-        self._pt = jnp.zeros((s_dim, self.kv.max_pages), jnp.int32)
+        self._pt = jnp.zeros((s_dim, self.kv.table_width), jnp.int32)
         if self.plan is not None:
             self._state = self.plan.replicate(self._state)
             self._pt = self.plan.replicate(self._pt)
@@ -1545,6 +1545,10 @@ class ContinuousBatchingScheduler:
         preemptions)."""
         import jax
 
+        if len(self.kv.group_layout) > 1:
+            raise RuntimeError(
+                "swap_out of a model with several cache groups: the "
+                "payload carries the primary group alone")
         if self._inflight:
             raise RuntimeError(
                 "swap_out with dispatches in flight — sync() first")
