@@ -200,16 +200,18 @@ def _project(cfg, lp, x, pos, kind):
     return q, k, v
 
 
-def _attend_rows(cfg, q, k, v, kind, flash):
+def _attend_rows(cfg, q, k, v, kind, flash, real_len=None):
     """Causal attention of one sequence over its own rows (a window layer:
     the last `sliding_window` of them), q (T, heads, d), k, v (T, kv_heads,
-    d) -> (T, heads, d)."""
+    d) -> (T, heads, d). `real_len`: the rows that are not padding, which
+    the flash forward neither visits nor returns (zeros)."""
     import jax.numpy as jnp
     scale = 1.0 / math.sqrt(cfg.head_dim)
     window = cfg.sliding_window if kind == "window" else None
     if flash:
         from ..ops.flash_attention import flash_causal_rows
-        return flash_causal_rows(q, k, v, scale, window=window)
+        return flash_causal_rows(q, k, v, scale, window=window,
+                                 length=real_len)
     i = jnp.arange(q.shape[0])
     mask = i[None, :] <= i[:, None]
     if window is not None:
@@ -342,7 +344,7 @@ def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
                 arenas[kind] = _write_ring(arenas[kind], lg, rows_of[kind],
                                            real_len, kv)
         with jax.named_scope("attn/" + kind):
-            o = _attend_rows(cfg, q, k, v, kind, flash)
+            o = _attend_rows(cfg, q, k, v, kind, flash, real_len)
         with jax.named_scope("attn/project"):
             x = x + o.reshape(B, -1) @ lp["wo"]
         y, counters, _ = _ffn(cfg, lp, x, live, counters)

@@ -743,15 +743,6 @@ def forward_logits(params, cfg, tokens):
 
 # -- prefill into the pages: expanded attention --------------------------------
 
-def _flash_causal(q, k, v, scale):
-    """Causal self-attention of a cold prompt by the flash kernel's
-    forward at unequal widths. q, k (B, n, d), v (B, n, dv)."""
-    from ..ops.flash_attention import _flash_call
-    o, _ = _flash_call(q.swapaxes(0, 1), k.swapaxes(0, 1), v.swapaxes(0, 1),
-                       None, True, float(scale), False)
-    return o.swapaxes(0, 1)
-
-
 def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
     """Prefill ONE sequence's prompt suffix tokens (1, B) (right-padded
     to its bucket; real_len real) at positions pfx_len.., whose first
@@ -763,6 +754,7 @@ def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
     V) float32 of position pfx_len + real_len - 1, arena, counters)."""
     import jax
     import jax.numpy as jnp
+    from ..ops.flash_attention import flash_causal_rows
 
     B = tokens.shape[1]
     bs = arena.shape[4]
@@ -788,7 +780,7 @@ def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
             def cold(arena):
                 k, v = _expand(cfg, lp, c, k_rope)
                 if on_tpu and B % 128 == 0:
-                    return _flash_causal(q, k, v, scale)
+                    return flash_causal_rows(q, k, v, scale, length=real_len)
                 return _masked_attention(q, k, v, j[None, :] <= j[:, None],
                                          scale)
 

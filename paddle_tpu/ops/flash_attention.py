@@ -27,8 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["attention", "attention_fwd_lse", "attention_bwd_saved",
-           "flash_attention", "flash_causal_rows", "flash_dispatch",
-           "mha_reference"]
+           "causal_rows_tiles", "flash_attention", "flash_causal_rows",
+           "flash_dispatch", "mha_reference"]
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -81,71 +81,164 @@ def _masked_scores(q, k, b_ref, k_idx, q_idx, block_q, block_k, kv_len,
 
 
 # ---------------------------------------------------------------------------
-# Pallas forward kernel
+# Pallas forward kernel: a walk over the tiles that hold work
 # ---------------------------------------------------------------------------
+#
+# The forward's grid is (heads, VISITS). A visit is one (query tile, KV
+# tile) pair that holds a (row, column) the result needs: a column of the
+# keys' real length, at or below the row (causal), inside the row's window
+# (a band), of a row below `length` (a serving prompt's real row count
+# inside its bucket). A query tile's visits follow each other, its KV tiles
+# in order, the diagonal one last, so `m`, `l` and `acc` stay in VMEM over
+# them. The walk reaches the kernel as scalar-prefetch operands that the
+# index maps read: no grid step exists that fetches a tile and computes
+# nothing. Each visit carries bits: the query tile's first and last visit,
+# and whether the query tile holds no real row at all (its one visit stores
+# zeros). Every computed visit builds the masks, as the rectangle's steps
+# did: on a v5e they hide under the MXU's time (PERF.md, PR 34).
 
-def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, sm_scale, causal,
-                block_q, block_k, kv_len, window=None):
+_FIRST, _LAST, _EMPTY = 1, 2, 4
+
+
+# the columns one online-softmax update takes of a walk's KV tile: a
+# larger tile is taken in chunks, m, l and acc carried as values from one
+# to the next (TPU v5e, 32 heads 192/192/128, 16,384 rows: tiles of 1,024
+# whole 653 us a head, in chunks of 512 601; tiles of 512 whole 683, in
+# chunks of 256 748: PERF.md, PR 34)
+_CHUNK = 512
+
+
+def _spans(nq, block_q, block_k, causal, window, kv_len):
+    """(first KV tile, KV tiles) of each of `nq` query tiles: the KV tiles
+    that hold a column some row of the query tile attends."""
+    r0 = np.arange(nq) * block_q
+    hi = np.full(nq, kv_len - 1)
+    if causal:
+        hi = np.minimum(hi, r0 + block_q - 1)
+    lo = np.zeros(nq, np.int64) if window is None \
+        else np.maximum(r0 - (window - 1), 0)
+    first = lo // block_k
+    return first, hi // block_k - first + 1
+
+
+def _walk(nq, block_q, block_k, causal, window, kv_len):
+    """The walk over every row of the sequence, as numpy int32 arrays
+    (query tile (V,), KV tile (V,), bits (V,)) and the running visit
+    count before each query tile (nq + 1,)."""
+    first, n = _spans(nq, block_q, block_k, causal, window, kv_len)
+    upto = np.concatenate([[0], np.cumsum(n)])
+    qt = np.repeat(np.arange(nq), n)
+    kt = np.arange(upto[-1]) - upto[qt] + first[qt]
+    bits = _FIRST * (kt == first[qt]) + _LAST * (kt == (first + n)[qt] - 1)
+    return (qt.astype(np.int32), kt.astype(np.int32), bits.astype(np.int32),
+            upto.astype(np.int32))
+
+
+def _walk_to(length, walk, block_q, block_k):
+    """`_walk`'s arrays of a causal self-attention whose KV tile is a
+    multiple of its query tile, cut to the rows below the traced `length`:
+    the query tiles that hold a real row keep their visits (no KV tile's
+    edge falls inside a query tile: the same KV tiles, whole or not),
+    every later one has ONE visit that stores zeros and fetches nothing
+    new, and the steps past the count repeat the last visit. Returns
+    (query tile, KV tile, bits, count)."""
+    qt, kt, bits, upto = walk
+    nq, V = upto.shape[0] - 1, qt.shape[0]
+    live = jnp.clip((length + block_q - 1) // block_q, 0, nq)
+    real = jnp.asarray(upto)[live]
+    at = jnp.arange(V, dtype=jnp.int32)
+    held = at < real
+    return (jnp.where(held, qt, jnp.minimum(live + at - real, nq - 1)),
+            jnp.where(held, kt, jnp.maximum(live * block_q - 1, 0) // block_k),
+            jnp.where(held, bits, _EMPTY), real + nq - live)
+
+
+def _fwd_kernel(qt_ref, kt_ref, bits_ref, meta_ref, q_ref, k_ref, v_ref,
+                b_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, sm_scale,
+                causal, block_q, block_k, kv_len, window, ragged, single,
+                chunk):
     from jax.experimental import pallas as pl
 
-    q_idx, k_idx = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
     d = v_ref.shape[-1]       # the output's width: v's, not q's
-    k_step = k_idx            # the grid's own count: first and last step
-    if window is not None:
-        # a BAND: the grid's third axis is the few KV tiles that touch
-        # the query tile's window, the last of them the diagonal one
-        # (`_flash_call`'s index map fetches the same tile); a step
-        # before the sequence's first tile computes nothing
-        k_idx = q_idx - (nk - 1) + k_idx
 
-    @pl.when(k_step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _compute():
-        s = _masked_scores(q_ref[0], k_ref[0], b_ref, k_idx, q_idx,
-                           block_q, block_k, kv_len, sm_scale, causal)
-        if window is not None:
-            # row i attends columns j with i - window < j (<= i: causal)
-            row = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-                   + q_idx * block_q)
+    def _compute(q_idx, k_idx):
+        # the KV tile in chunks of columns, one online-softmax update
+        # each, m, l and acc carried as values: a chunk's first product
+        # does not wait for the chunk before it
+        q = q_ref[0]
+        m, l, acc = m_scr[:], l_scr[:], acc_scr[:]   # (block_q, 128 | d)
+        for c in range(block_k // chunk):
+            cols = slice(c * chunk, (c + 1) * chunk)
+            s = jax.lax.dot_general(
+                q, k_ref[0, cols, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            if b_ref is not None:
+                s = s + b_ref[0, :, cols].astype(jnp.float32)
             col = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                   + k_idx * block_k)
-            s = jnp.where(row - col < window, s, _NEG_INF)
-        m_prev, l_prev = m_scr[:], l_scr[:]          # (block_q, 128)
-        m_curr = jnp.max(s, axis=1)[:, None]         # (block_q, 1)
-        m_new = jnp.maximum(m_prev, m_curr)          # (block_q, 128)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - _lanes_to(m_new, s.shape[1]))
-        l_scr[:] = l_prev * alpha + jnp.sum(p, axis=1)[:, None]
-        m_scr[:] = m_new
-        acc_scr[:] = acc_scr[:] * _lanes_to(alpha, d) + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+                   + (k_idx * block_k + c * chunk))
+            s = jnp.where(col < kv_len, s, _NEG_INF)       # kv padding
+            if causal:
+                row = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                       + q_idx * block_q)
+                s = jnp.where(row >= col, s, _NEG_INF)
+                if window is not None:
+                    # row i attends columns j with i - window < j
+                    s = jnp.where(row - col < window, s, _NEG_INF)
+            m_curr = jnp.max(s, axis=1)[:, None]         # (block_q, 1)
+            m_new = jnp.maximum(m, m_curr)               # (block_q, 128)
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - _lanes_to(m_new, chunk))
+            l = l * alpha + jnp.sum(p, axis=1)[:, None]
+            acc = acc * _lanes_to(alpha, d) + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, cols, :],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            m = m_new
+        m_scr[:], l_scr[:], acc_scr[:] = m, l, acc
 
-    if window is not None:
-        @pl.when(k_idx >= 0)
-        def _():
-            _compute()
-    elif causal:
-        # skip blocks strictly above the diagonal
-        @pl.when(k_idx * block_k <= q_idx * block_q + block_q - 1)
-        def _():
-            _compute()
-    else:
-        _compute()
-
-    @pl.when(k_step == nk - 1)
-    def _fin():
-        d_ = o_ref.shape[-1]
+    def _fin(q_idx):
         l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)         # fully-masked rows
-        o_ref[0] = (acc_scr[:] / _lanes_to(l_safe, d_)).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[:] + jnp.log(l_safe)
+        o = acc_scr[:] / _lanes_to(l_safe, d)
+        lse = m_scr[:] + jnp.log(l_safe)
+        if ragged:
+            # a row at or past the length is nobody's: zeros
+            row = (jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+                   + q_idx * block_q)
+            o = jnp.where(row < meta_ref[1], o, 0.0)
+            lse = jnp.where(row < meta_ref[1], lse, _NEG_INF)
+        o_ref[0] = o.astype(o_ref.dtype)
+        lse_ref[0] = lse
+
+    if single:
+        # one tile a head (a serving prefill's short bucket): one visit,
+        # straight-line code: no branch
+        _init()
+        _compute(0, 0)
+        _fin(0)
+        return
+
+    i = pl.program_id(1)
+
+    @pl.when(i < meta_ref[0])
+    def _visit():
+        q_idx, k_idx, bits = qt_ref[i], kt_ref[i], bits_ref[i]
+        pl.when((bits & _FIRST) != 0)(_init)
+        if ragged:
+            pl.when((bits & _EMPTY) == 0)(lambda: _compute(q_idx, k_idx))
+        else:
+            _compute(q_idx, k_idx)
+        pl.when((bits & _LAST) != 0)(lambda: _fin(q_idx))
+
+        if ragged:
+            @pl.when((bits & _EMPTY) != 0)
+            def _nobody():
+                o_ref[...] = jnp.zeros_like(o_ref)
+                lse_ref[...] = jnp.full_like(lse_ref, _NEG_INF)
 
 
 # ---------------------------------------------------------------------------
@@ -455,19 +548,34 @@ def _pad_to(x, axis, mult):
 
 
 def _flash_call(q, k, v, bias, causal, sm_scale, interpret, blocks=None,
-                group=1, window=None):
-    """q: (bn, sq, d); k: (bn, sk, d); v: (bn, sk, dv); bias: (bn, sk) or
-    None. `group` > 1: k and v are (bn // group, sk, .), KV head i // group
-    shared by the query heads i of its group through the index map (no
-    repeated K or V in HBM). `window` (causal self-attention, sq == sk,
-    equal tiles, no bias): row i attends i - window < j <= i, and a query
-    tile visits only the ceil((window - 1) / tile) + 1 KV tiles that touch
-    its window, not the sequence's. Neither exists in the backward. Returns o (bn, sq, dv) unpadded and lse (bn, sq_pad, 128)
-    lane-padded. The forward takes a value width of its own (latent
-    attention's prefill: q, k of 192 and v of 128); the backward kernels
-    do not, and training never asks. `blocks` (block_q, block_k) stands
-    in for `_pick_blocks`' choice where a caller knows its grid better
-    (`flash_causal_rows`); the backward has no such argument."""
+                group=1, window=None, length=None):
+    """The forward. q: (bn, sq, d); k: (bn, sk, d); v: (bn, sk, dv); bias:
+    (bn, sk) or None. Returns o (bn, sq, dv) unpadded and lse (bn, sq_pad,
+    128) lane-padded.
+
+    The grid is (bn, visits): `_walk`'s list of the (query tile, KV tile)
+    pairs that hold work, read by the index maps from scalar-prefetch
+    operands (the comment above `_fwd_kernel`). Four things only the
+    forward has; the backward kernels have none of them, and training
+    never asks:
+      * `group` > 1: k and v are (bn // group, sk, .), KV head i // group
+        shared by the query heads i of its group through the index map
+        (no repeated K or V in HBM);
+      * `window` (causal self-attention, sq == sk, the KV tile a multiple
+        of the query tile, no bias):
+        row i attends i - window < j <= i, and a query tile visits only
+        the KV tiles that touch its window, not the sequence's;
+      * a value width of its own (latent attention's prefill: q, k of 192
+        and v of 128);
+      * `length` (a traced int32 scalar, 0 <= length <= sq; the same
+        conditions as a window): only rows below it
+        are real. The walk is then computed from it: tiles past it are
+        not visited, rows at or past it come back ZERO in o and _NEG_INF
+        in lse, whatever q, k and v hold there (no real row attends them:
+        causal). None: every row is real and the walk is a compile-time
+        constant.
+    `blocks` (block_q, block_k) stands in for `_pick_blocks`' choice where
+    a caller knows its grid better (`flash_causal_rows`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -479,62 +587,68 @@ def _flash_call(q, k, v, bias, causal, sm_scale, interpret, blocks=None,
     k = _pad_to(k, 1, block_k)
     v = _pad_to(v, 1, block_k)
     sq, sk = q.shape[1], k.shape[1]
-    nq, nk = sq // block_q, sk // block_k
+    if (window is not None or length is not None) and not (
+            causal and bias is None and sq0 == sk0
+            and block_k % block_q == 0):
+        raise ValueError(
+            "a window or a length is causal self-attention without a bias, "
+            "its KV tile a multiple of its query tile")
 
-    kw = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
-              block_k=block_k, kv_len=sk0)
-    if group == 1 and window is None:
-        kv_map = lambda i, j, kk: (i, kk, 0)
+    walk = _walk(sq // block_q, block_q, block_k, causal, window, sk0)
+    if length is None:
+        qt, kt, bits = walk[:3]
+        meta = np.asarray([qt.shape[0], sq0], np.int32)
     else:
-        if window is not None:
-            if not causal or bias is not None or sq != sk \
-                    or block_q != block_k:
-                raise ValueError(
-                    "a window is causal self-attention over equal tiles "
-                    "without a bias")
-            nk = min(nk, -(-(window - 1) // block_k) + 1)
-            kw["window"] = int(window)
-            band = nk - 1
-            kv_map = lambda i, j, kk: (i // group,
-                                       jnp.maximum(j - band + kk, 0), 0)
-        else:
-            kv_map = lambda i, j, kk: (i // group, kk, 0)
+        length = jnp.asarray(length, jnp.int32)
+        qt, kt, bits, count = _walk_to(length, walk, block_q, block_k)
+        meta = jnp.stack([count, length])
+    kern = functools.partial(
+        _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
+        block_k=block_k, kv_len=sk0, ragged=length is not None,
+        window=None if window is None else int(window),
+        single=walk[0].shape[0] == 1,
+        chunk=block_k if qt.shape[0] == 1 else min(block_k, _CHUNK))
+
+    q_map = lambda i, s, qt, kt, *_: (i, qt[s], 0)
+    kv_map = lambda i, s, qt, kt, *_: (i // group, kt[s], 0)
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
+        pl.BlockSpec((1, block_q, d), q_map),
         pl.BlockSpec((1, block_k, d), kv_map),
         pl.BlockSpec((1, block_k, dv), kv_map),
     ]
     args = [q, k, v]
     if bias is not None:
         args.append(_pad_to(bias, 1, block_k)[:, None, :])  # (bn, 1, sk)
-        in_specs.append(pl.BlockSpec((1, 1, block_k),
-                                     lambda i, j, kk: (i, 0, kk)))
-        kern = functools.partial(_fwd_kernel, **kw)
+        in_specs.append(pl.BlockSpec(
+            (1, 1, block_k), lambda i, s, qt, kt, *_: (i, 0, kt[s])))
     else:
-        def kern(q_r, k_r, v_r, o_r, lse_r, m_s, l_s, a_s):
-            _fwd_kernel(q_r, k_r, v_r, None, o_r, lse_r, m_s, l_s, a_s, **kw)
+        def kern(*refs, kern=kern):
+            kern(*refs[:7], None, *refs[7:])
 
     o, lse = pl.pallas_call(
         kern,
-        grid=(bn, nq, nk),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda i, j, kk: (i, j, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(bn, qt.shape[0]),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, block_q, dv), q_map),
+                pl.BlockSpec((1, block_q, _LANES), q_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, dv), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((bn, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((bn, sq, _LANES), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, dv), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*args)
+    )(jnp.asarray(qt), jnp.asarray(kt), jnp.asarray(bits),
+      jnp.asarray(meta), *args)
     return o[:, :sq0], lse
 
 
@@ -768,40 +882,75 @@ def flash_dispatch(q, k, bias=None, impl: Optional[str] = None):
 _ONE_TILE_ROWS = 1024
 
 
+# rows from which a whole triangle is walked in tiles of 1,024 (taken in
+# chunks of _CHUNK columns): half the K and V fetches and half the scratch
+# traffic a product (TPU v5e, a head of 192/192/128, us at 512 / at 1,024:
+# 2,048 rows 15.0 / 16.2, 4,096 53.9 / 52.0, 8,192 199 / 182, 16,384
+# holding 15,360 683 / 601); a band of 1,024 stays at 512 (117 / 124: it
+# would compute 2,048 columns a row)
+_LARGE_TILE_ROWS = 8192
+
+
+def _causal_rows_blocks(rows, window=None):
+    """The tiles of a `rows`-row sequence of `flash_causal_rows`."""
+    if rows <= _ONE_TILE_ROWS:
+        return rows, rows
+    if window is None and rows >= _LARGE_TILE_ROWS and rows % 1024 == 0:
+        return 1024, 1024
+    return _pick_blocks(rows, rows)
+
+
+def causal_rows_tiles(rows, length=None, window=None):
+    """(visited, in the bucket): the (query tile, KV tile) pairs a head of
+    `flash_causal_rows` computes of a `rows`-row bucket that holds `length`
+    real rows (None: all), and those of the bucket whole. Host integers
+    from the kernel's own walk (`_spans`), for the engine's counts."""
+    tile = _causal_rows_blocks(rows, window)[0]
+    nq = -(-rows // tile)
+    _, n = _spans(nq, tile, tile, True, window, rows)
+    live = nq if length is None else min(nq, -(-int(length) // tile))
+    return int(n[:live].sum()), int(n.sum())
+
+
 @functools.partial(jax.jit,
                    static_argnames=("sm_scale", "interpret", "window"))
-def _causal_rows_call(q, k, v, sm_scale, interpret, window=None):
+def _causal_rows_call(q, k, v, length, sm_scale, interpret, window=None):
     # jitted like ops/paged_attention's calls: a program that unrolls its
     # layers traces and lowers the kernel once and calls it from each
-    rows = q.shape[0]
     o, _ = _flash_call(
         q.swapaxes(0, 1), k.swapaxes(0, 1), v.swapaxes(0, 1), None, True,
-        sm_scale, interpret,
-        blocks=(rows, rows) if rows <= _ONE_TILE_ROWS else None,
-        group=q.shape[1] // k.shape[1], window=window)
+        sm_scale, interpret, blocks=_causal_rows_blocks(q.shape[0], window),
+        group=q.shape[1] // k.shape[1], window=window, length=length)
     return o.swapaxes(0, 1)
 
 
-def flash_causal_rows(q, k, v, sm_scale, window=None):
+def flash_causal_rows(q, k, v, sm_scale, window=None, length=None):
     """Causal self-attention of ONE sequence's rows by the tiled flash
-    forward, for a serving prefill: q (rows, heads, d), k, v (rows,
-    kv_heads, d) as the projections leave them (kv_heads divides heads:
-    query head i reads KV head i // (heads / kv_heads), shared by the
-    index map), row i attending over rows 0..i, or with `window` over the
-    last `window` of them, itself counted (a band: only the KV tiles that
-    touch a query tile's window are visited); returns
-    (rows, heads, d). No residuals, no backward. Up to _ONE_TILE_ROWS
-    the sequence is one tile a head (`_pick_blocks` would cut 768 rows,
-    no multiple of 512, into 36 tiles of 128 x 128); longer ones take
-    `_pick_blocks`' tiles. Compiled by Mosaic on a TPU backend,
-    interpreted on the CPU (a test facility), an error on any other
-    backend, like `flash_dispatch`'s forced kernel."""
+    forward, for a serving prefill: q (rows, heads, d), k (rows, kv_heads,
+    d), v (rows, kv_heads, dv) as the projections leave them (kv_heads
+    divides heads: query head i reads KV head i // (heads / kv_heads),
+    shared by the index map; dv may differ from d), row i attending over
+    rows 0..i, or with `window` over the last `window` of them, itself
+    counted; returns (rows, heads, dv). No residuals, no backward.
+
+    `length` (a traced int32 scalar; None: `rows`) is the prompt's real
+    row count inside its bucket. The kernel walks only the tiles that hold
+    work: at or below the diagonal, inside the window, below `length`.
+    Rows at or past `length` come back ZERO, never what the buffer held.
+
+    Up to _ONE_TILE_ROWS the sequence is one tile a head: one visit
+    (`_pick_blocks` would cut 768 rows, no multiple of 512, into 36 tiles
+    of 128 x 128); longer ones take `_pick_blocks`' tiles, and a whole
+    triangle from _LARGE_TILE_ROWS rows tiles of 1,024. Compiled by
+    Mosaic on a TPU backend, interpreted on the CPU (a test facility), an
+    error on any other backend, like `flash_dispatch`'s forced kernel."""
     platform = jax.default_backend()
     if platform not in ("tpu", "cpu"):
         raise RuntimeError(
             "flash_causal_rows compiles for TPU (Mosaic) and interprets "
             f"on CPU for tests; the active backend is {platform!r}")
-    return _causal_rows_call(q, k, v, float(sm_scale), platform == "cpu",
+    return _causal_rows_call(q, k, v, length, float(sm_scale),
+                             platform == "cpu",
                              window=None if window is None else int(window))
 
 

@@ -1443,9 +1443,12 @@ class ServingEngine:
         # the prefill's: "flash" if a cold prompt attends over its own
         # rows through the flash forward in some bucket (`flash_buckets`
         # says which), else what the largest bucket runs ("gather";
-        # None: the model does not say), and how many prefills were
+        # None: the model does not say), how many prefills were
         # dispatched cold under either and warm (rows already cached: a
-        # prefix hit, a later chunk; gathers)
+        # prefix hit, a later chunk; gathers), and the tiles the cold
+        # flash prefills' walks visited of those their buckets hold
+        # (`tiles_visited`, `tiles_in_bucket`: 1.0 where every prompt
+        # fills its bucket)
         verdicts = self.scheduler.prefill_attention
         flash = [b for b, path in verdicts.items() if path == "flash"]
         s["prefill_attention"] = dict(

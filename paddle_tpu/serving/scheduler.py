@@ -550,7 +550,13 @@ class ContinuousBatchingScheduler:
         self.prefill_attention = {
             b: self.model.prefill_attention_path(kv.arena, b, pin)
             for b in buckets}
-        self.prefill_counts = {"cold_flash": 0, "cold_gather": 0, "warm": 0}
+        # `tiles_visited` of `tiles_in_bucket`: the (query tile, KV tile)
+        # pairs a head computed in the cold flash prefills, a layer of
+        # each cache group counted once (a window group walks a band),
+        # beside what their buckets hold whole: the share of the buckets'
+        # triangles that held a prompt
+        self.prefill_counts = {"cold_flash": 0, "cold_gather": 0, "warm": 0,
+                               "tiles_visited": 0, "tiles_in_bucket": 0}
         # chunked prefill (None = monolithic, bit-identical to the
         # pre-knob engine with zero new executables): the per-tick
         # prefill token budget AND the per-dispatch chunk ceiling
@@ -1080,7 +1086,7 @@ class ContinuousBatchingScheduler:
         padded = self._staging_for(bucket)
         padded[0, :suffix_len] = prompt[0, pfx_len:]
         padded[0, suffix_len:] = 0
-        self._count_prefill(bucket, pfx_len)
+        self._count_prefill(bucket, pfx_len, suffix_len)
         with profiler.RecordEvent("serving/prefill", bucket=bucket,
                                   prompt_len=p_len, slot=slot,
                                   prefix_len=pfx_len,
@@ -1106,14 +1112,23 @@ class ContinuousBatchingScheduler:
                        prefix_len=int(pfx_len), suffix_len=suffix_len)
         return event
 
-    def _count_prefill(self, bucket: int, start: int) -> None:
-        """One prefill dispatch of `bucket` rows starting at position
-        `start`, counted under the attention it runs: the device's
-        `pfx_len == 0` branch, decided here from the same number (plain
-        "cold" where the model gives no verdict)."""
+    def _count_prefill(self, bucket: int, start: int, real_len: int) -> None:
+        """One prefill dispatch of `bucket` rows, `real_len` of them real,
+        starting at position `start`, counted under the attention it
+        runs: the device's `pfx_len == 0` branch, decided here from the
+        same number (plain "cold" where the model gives no verdict). A
+        cold flash prefill adds the tiles its walk visits and its bucket
+        holds, by the kernel's own count."""
         path = self.prefill_attention[bucket]
         key = "warm" if start else "cold" if path is None else "cold_" + path
         self.prefill_counts[key] = self.prefill_counts.get(key, 0) + 1
+        if key == "cold_flash":
+            from ..ops.flash_attention import causal_rows_tiles
+            for g in self.kv.group_layout:
+                visited, held = causal_rows_tiles(bucket, real_len,
+                                                  g.spec.window)
+                self.prefill_counts["tiles_visited"] += visited
+                self.prefill_counts["tiles_in_bucket"] += held
 
     def _sample_first(self, slot, req, logits, p_len, max_new,
                       temperature, seed, eos_id, prev_tok,
@@ -1199,7 +1214,7 @@ class ContinuousBatchingScheduler:
         padded[0, :n] = pf.suffix[pf.cursor:pf.cursor + n]
         padded[0, n:] = 0
         start = pf.start + pf.cursor
-        self._count_prefill(bucket, start)
+        self._count_prefill(bucket, start, n)
         with profiler.RecordEvent("serving/prefill_chunk", bucket=bucket,
                                   prompt_len=pf.p_len, slot=slot,
                                   start_pos=start, chunk_len=n,

@@ -441,17 +441,20 @@ def test_banded_flash_forward_with_shared_kv_heads(rows, window):
     assert float(jnp.abs(got - want).max()) <= 2e-6
 
 
-def test_the_band_visits_the_tiles_of_its_window_alone():
-    """The grid of a 16,384-row window layer: 32 query tiles x 3 KV tiles
-    of 512 (the 9 tiles of 128 a 1,024-row window touches), not 32 x 32."""
+@pytest.mark.parametrize("window,visits", [(1024, 93), (None, 136)])
+def test_the_band_visits_the_tiles_of_its_window_alone(window, visits):
+    """The grid of a 16,384-row layer is (heads, visits): a window layer
+    walks 93 (query tile, KV tile) pairs of 512 a head (1 + 2 + 30 x 3:
+    the 9 tiles of 128 a 1,024-row window touches), a full layer the
+    triangle's 136 pairs of 1,024 (528 of 512: tiles of 1,024 from 8,192
+    rows); no step of either only fetches."""
+    from paddle_tpu.ops.flash_attention import causal_rows_tiles
     q = jnp.zeros((16384, 8, 128), jnp.bfloat16)
     k = jnp.zeros((16384, 1, 128), jnp.bfloat16)
     text = str(jax.make_jaxpr(
-        lambda q, k: flash_causal_rows(q, k, k, 0.1, window=1024))(q, k))
-    assert "grid=(8, 32, 3)" in text
-    full = str(jax.make_jaxpr(
-        lambda q, k: flash_causal_rows(q, k, k, 0.1))(q, k))
-    assert "grid=(8, 32, 32)" in full
+        lambda q, k: flash_causal_rows(q, k, k, 0.1, window=window))(q, k))
+    assert f"grid=(8, {visits})" in text
+    assert causal_rows_tiles(16384, None, window) == (visits, visits)
 
 
 @pytest.mark.parametrize("mode", ["full", "ring"])
@@ -497,7 +500,11 @@ def test_grouped_paged_kernel_against_mha_reference(mode):
 # sha256 (16 hex) of str(jax.make_jaxpr(...)) at the sizes below, computed on
 # the parent commit (2310bf9, PR 32) by the same code under tests/conftest.py: a model with one
 # cache group is served by the program it had, and the kernels GPT and the
-# latent block run are traced as they were
+# latent block run are traced as they were. PR 34 re-pinned the two
+# `kernel.flash_causal_rows*` digests on its own final tree: the forward's
+# grid became a walk of visits read from scalar-prefetch operands (one visit
+# at 256 rows, ten at 2,048), so both jaxprs changed; the six programs (the
+# CPU's gather path) and the small-path `fwd_bwd` did not
 PARENT = {
     "moonlight.prefill": "2b751ed92e59903c",
     "moonlight.decode": "3db640a0cdcf93aa",
@@ -507,8 +514,8 @@ PARENT = {
     "gpt.decode": "0f3268c7f5194142",
     "kernel.paged_attention": "aee9f347c35d6388",
     "kernel.latent_paged_attention": "de5b060cb2ed656e",
-    "kernel.flash_causal_rows": "6c7a8a72ab3f958a",
-    "kernel.flash_causal_rows.2048": "ebd68a9e2db3bad9",
+    "kernel.flash_causal_rows": "2df3b31c10b0753f",
+    "kernel.flash_causal_rows.2048": "d261309d8fd22c3e",
     "kernel.flash_attention.fwd_bwd": "a46a861a21af2956",
 }
 _SIZES = dict(vocab_size=211, hidden=64, layers=3, heads=4, kv_lora_rank=32,

@@ -378,19 +378,21 @@ def test_latent_walk_is_a_function_of_shapes():
 
 
 def test_flash_forward_at_unequal_widths():
-    """The prefill's kernel: q, k of one width and v of another."""
-    from paddle_tpu.ops.flash_attention import _flash_call, mha_reference
+    """The prefill's kernel through the entry point the latent block
+    calls: q, k of one width and v of another, rows as the projections
+    leave them."""
+    from paddle_tpu.ops.flash_attention import (flash_causal_rows,
+                                                mha_reference)
     rng = np.random.default_rng(6)
     n, s, d, dv = 2, 256, 24, 16
-    q, k = (jnp.asarray(rng.normal(0, 1, (n, s, d)), jnp.float32)
+    q, k = (jnp.asarray(rng.normal(0, 1, (s, n, d)), jnp.float32)
             for _ in range(2))
-    v = jnp.asarray(rng.normal(0, 1, (n, s, dv)), jnp.float32)
-    o, _ = _flash_call(q, k, v, None, True, 1.0 / np.sqrt(d), True)
+    v = jnp.asarray(rng.normal(0, 1, (s, n, dv)), jnp.float32)
+    o = flash_causal_rows(q, k, v, 1.0 / np.sqrt(d))
     # mha_reference wants equal widths: zero-extend v and cut the answer
-    vz = jnp.concatenate([v, jnp.zeros((n, s, d - dv))], -1)
-    want = mha_reference(q.swapaxes(0, 1)[None], k.swapaxes(0, 1)[None],
-                         vz.swapaxes(0, 1)[None], causal=True)[0]
-    np.testing.assert_allclose(o, want.swapaxes(0, 1)[..., :dv], atol=2e-5)
+    vz = jnp.concatenate([v, jnp.zeros((s, n, d - dv))], -1)
+    want = mha_reference(q[None], k[None], vz[None], causal=True)[0]
+    np.testing.assert_allclose(o, want[..., :dv], atol=2e-5)
 
 
 _PREFILL = jax.jit(lambda params, *a: ml.prefill_pages(params, CFG, *a))

@@ -2739,6 +2739,14 @@ def test_prefill_attention_counts_cold_warm_where_they_belong(
         gd, "prefill_attention_path",
         lambda arena, bucket, con=None: "flash" if bucket == 16 else "gather")
     assert serve() == (("flash", (1, 0, 0)), ("flash", (1, 1, 1)))
+    # the tiles the cold flash prefills walked: a 16-row bucket is one
+    # tile a head, whatever its 10 real rows (ops/flash_attention)
+    eng = make_engine(trained, **sizes)
+    eng.generate([p], max_new_tokens=2)
+    s = eng.stats()["prefill_attention"]
+    assert (s["cold_flash"], s["tiles_visited"], s["tiles_in_bucket"]) \
+        == (1, 1, 1)
+    eng.close()
 
 
 def test_chunked_prefill_mid_batch_long_prompt_does_not_stall_streams(
