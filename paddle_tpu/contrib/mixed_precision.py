@@ -17,7 +17,7 @@ from __future__ import annotations
 
 from typing import Optional, Set
 
-from ..framework.core import Operator, Program
+from ..framework.core import NAMESCOPE_ATTR, Operator, Program
 
 __all__ = ["decorate", "rewrite_bf16", "AutoMixedPrecisionLists"]
 
@@ -84,15 +84,20 @@ def rewrite_bf16(program: Program,
         except KeyError:
             return None
 
-    def _insert_cast(name, to, cache, suffix):
+    def _insert_cast(name, to, cache, suffix, reader):
         if name in cache:
             return cache[name]
         v = blk.var(name)
         cast_name = name + suffix
         nv = blk.create_var(name=cast_name, shape=v.shape, dtype=to,
                             stop_gradient=v.stop_gradient)
+        attrs = {"out_dtype": to}
+        if NAMESCOPE_ATTR in reader.attrs:
+            # the cast is its first reader's cost: it carries that op's
+            # name scope, and its grad op then does too
+            attrs[NAMESCOPE_ATTR] = reader.attrs[NAMESCOPE_ATTR]
         new_ops.append(Operator(blk, "cast", {"X": [name]},
-                                {"Out": [cast_name]}, {"out_dtype": to}))
+                                {"Out": [cast_name]}, attrs))
         cache[name] = cast_name
         return cast_name
 
@@ -109,7 +114,7 @@ def rewrite_bf16(program: Program,
                 for j, n in enumerate(names):
                     if _dtype(n) in _FLOAT:
                         names[j] = _insert_cast(n, "bfloat16", cast_to_bf16,
-                                                "@BF16")
+                                                "@BF16", op)
             new_ops.append(op)
             for slot, names in op.outputs.items():
                 for n in names:
@@ -129,7 +134,7 @@ def rewrite_bf16(program: Program,
                 for j, n in enumerate(names):
                     if _dtype(n) == "bfloat16":
                         names[j] = _insert_cast(n, "float32", cast_to_f32,
-                                                "@FP32")
+                                                "@FP32", op)
             new_ops.append(op)
             for names in op.outputs.values():
                 for n in names:

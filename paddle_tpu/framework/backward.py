@@ -22,8 +22,8 @@ from __future__ import annotations
 import warnings
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
-from .core import (Block, Operator, Parameter, Program, Variable,
-                   grad_var_name, GRAD_SUFFIX)
+from .core import (NAMESCOPE_ATTR, Block, Operator, Parameter, Program,
+                   Variable, grad_var_name, GRAD_SUFFIX)
 from .registry import get_op_def
 
 __all__ = ["append_backward", "gradients", "GradientDropWarning"]
@@ -180,8 +180,12 @@ def _make_grad_op_descs(op: Operator, block: Block, accum: _GradAccum,
                     accum._declare_grad_var(gname, src)
                     fixed.append(gname)
                 outs[slot] = fixed
-            ops.append(Operator(block, d["type"], ins, outs,
-                                d.get("attrs", {})))
+            attrs = dict(d.get("attrs", {}))
+            if NAMESCOPE_ATTR in op.attrs:
+                # a grad maker writes its own attributes; the stage the
+                # forward op was built under is its grad ops' too
+                attrs.setdefault(NAMESCOPE_ATTR, op.attrs[NAMESCOPE_ATTR])
+            ops.append(Operator(block, d["type"], ins, outs, attrs))
         return ops
 
     # ---- generic maker ----

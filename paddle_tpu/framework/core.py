@@ -123,8 +123,20 @@ unique_name.guard = unique_name_guard
 unique_name.switch = _unique_name_switch
 
 
+# Fluid's name for the attribute an op built under `name_scope` carries
+# (framework.py `op_namescope`): the scopes open where the op was appended,
+# joined by "/". `lower_op` traces such an op under
+# jax.named_scope(f"{namescope}/{op.type}"), so a device trace tells the
+# head's matmul from a block's.
+NAMESCOPE_ATTR = "op_namescope"
+
+
 class name_scope:
-    """Prefix generated names for readability (fluid.name_scope analog)."""
+    """fluid.name_scope analog, both halves: generated names get the
+    prefix, and every op appended inside is stamped with the open scopes
+    (`NAMESCOPE_ATTR`; its `_grad` op inherits the stamp with the rest of
+    the forward op's attributes). Names given explicitly, parameters'
+    among them, are not touched."""
 
     def __init__(self, prefix: str):
         self._prefix = prefix
@@ -136,6 +148,14 @@ class name_scope:
     def __exit__(self, *exc):
         _generator._prefix.pop()
         return False
+
+
+def _stamp_namescope(attrs):
+    """`attrs` with the open name scopes recorded, unless it names one
+    already (a cloned or rewritten op keeps its own); no scope, no key."""
+    if not _generator._prefix or (attrs and NAMESCOPE_ATTR in attrs):
+        return attrs
+    return {**(attrs or {}), NAMESCOPE_ATTR: "/".join(_generator._prefix)}
 
 
 # ---------------------------------------------------------------------------
@@ -368,7 +388,7 @@ class Block:
     # -- ops ----------------------------------------------------------------
     def append_op(self, type: str, inputs=None, outputs=None, attrs=None,
                   infer_shape: bool = True) -> Operator:
-        op = Operator(self, type, inputs, outputs, attrs)
+        op = Operator(self, type, inputs, outputs, _stamp_namescope(attrs))
         self.ops.append(op)
         self.program._bump_version()
         if infer_shape:
@@ -378,7 +398,7 @@ class Block:
 
     def prepend_op(self, type: str, inputs=None, outputs=None, attrs=None,
                    infer_shape: bool = True) -> Operator:
-        op = Operator(self, type, inputs, outputs, attrs)
+        op = Operator(self, type, inputs, outputs, _stamp_namescope(attrs))
         self.ops.insert(0, op)
         self.program._bump_version()
         if infer_shape:
@@ -388,7 +408,7 @@ class Block:
 
     def insert_op(self, index: int, type: str, inputs=None, outputs=None,
                   attrs=None, infer_shape: bool = True) -> Operator:
-        op = Operator(self, type, inputs, outputs, attrs)
+        op = Operator(self, type, inputs, outputs, _stamp_namescope(attrs))
         self.ops.insert(index, op)
         self.program._bump_version()
         if infer_shape:

@@ -564,8 +564,17 @@ class Executor:
         if return_numpy:
             from .selected_rows import to_dense
             with trace_span("executor/fetch", "executor"):
-                return [np.asarray(to_dense(f)) for f in fetches]
-        return list(fetches)
+                out = [np.asarray(to_dense(f)) for f in fetches]
+        else:
+            out = list(fetches)
+        with trace_span("executor/release", "executor"):
+            # what the call still holds of the step goes here, where it
+            # went when the function returned: the donated inputs (some
+            # hundreds of arrays whose buffers the step consumed), the
+            # dictionaries around them and the fetched device arrays.
+            # Same order, same work, under a name of its own
+            del mut_in, ro_in, feed_in, new_mut, fetches, key, new_key
+        return out
 
     # -- training telemetry (observability/train_stats.py) -------------------
     def _analyze_compile(self, compiled, mut_in, ro_in, feed_in, key, reg):

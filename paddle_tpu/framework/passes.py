@@ -21,7 +21,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, Dict, List, Optional
 
-from .core import Block, Operator, Program
+from .core import NAMESCOPE_ATTR, Block, Operator, Program
 
 __all__ = ["Pass", "PassRegistry", "register_pass", "apply_pass",
            "get_pass", "Pattern", "OpNode", "Match"]
@@ -289,10 +289,16 @@ def replace_ops(block: Block, old_ops: List[Operator],
     """Splice: remove old_ops, insert new ops (as desc dicts with
     type/inputs/outputs/attrs) at the first removed position."""
     pos = min(block.ops.index(o) for o in old_ops)
+    # a fused op stands where its first stamped part stood
+    namescope = next((o.attrs[NAMESCOPE_ATTR] for o in old_ops
+                      if NAMESCOPE_ATTR in o.attrs), None)
     for o in old_ops:
         block.ops.remove(o)
     for k, d in enumerate(new_ops_desc):
+        attrs = dict(d.get("attrs", {}))
+        if namescope is not None:
+            attrs.setdefault(NAMESCOPE_ATTR, namescope)
         op = Operator(block, d["type"], d.get("inputs", {}),
-                      d.get("outputs", {}), d.get("attrs", {}))
+                      d.get("outputs", {}), attrs)
         block.ops.insert(pos + k, op)
     block.program._bump_version()

@@ -23,7 +23,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Set
 
 import numpy as np
 
-from .core import Block, Operator, GRAD_SUFFIX
+from .core import Block, Operator, GRAD_SUFFIX, NAMESCOPE_ATTR
 
 __all__ = ["OpDef", "register_op", "get_op_def", "has_op_def",
            "infer_op_shapes", "LowerContext", "lower_op", "DUMMY_BATCH",
@@ -197,10 +197,14 @@ class LowerContext:
 def lower_op(ctx: LowerContext, op: Operator, env: Dict[str, Any]) -> None:
     """Lower one op: read inputs from env, write outputs into env. Each op
     traces under jax.named_scope so XLA metadata (and profiler traces) carry
-    op-level names — the RecordEvent analog at zero runtime cost."""
+    op-level names — the RecordEvent analog at zero runtime cost: the op's
+    type, under the name scopes it was built in where `pt.name_scope`
+    stamped them (`head/matmul`, `attn/fc_grad`, `optimizer/adam`)."""
     import jax
 
-    with jax.named_scope(op.type):
+    namescope = op.attrs.get(NAMESCOPE_ATTR)
+    with jax.named_scope(f"{namescope}/{op.type}" if namescope
+                         else op.type):
         if op.type in _MACROS:
             _MACROS[op.type](ctx, op, env)
             return

@@ -72,20 +72,28 @@ def gpt_decoder(tokens, cfg: GPTConfig, is_test=False, prefix="gpt",
                 cut_vars=None):
     """tokens: int64 (-1, seq) -> hidden states (-1, seq, h), pre-LN
     residual stack with a final LN (GPT-2). cut_vars (list) collects the
-    per-layer residual var names — recompute/pipeline boundaries."""
+    per-layer residual var names — recompute/pipeline boundaries.
+
+    Every op is built under a `pt.name_scope` that names its stage in a
+    device trace, as the serving programs of models/gpt_decode.py name
+    theirs: `embed`, `norm`, `attn` (projections, fused_attention and the
+    residual add), `ffn`, and `head` for the final norm (gpt_lm_program
+    puts the product over the tied table there too)."""
     seq = int(tokens.shape[1])
     check_max_pos(seq, cfg)
-    wte = pt.layers.embedding(
-        tokens, size=[cfg.vocab_size, cfg.hidden],
-        param_attr=_attr(f"{prefix}/wte", cfg))
-    pos_ids = pt.layers.arange(0, seq, dtype="int64")
-    wpe = pt.layers.embedding(
-        pos_ids, size=[cfg.max_pos, cfg.hidden],
-        param_attr=_attr(f"{prefix}/wpe", cfg))
-    x = wte + wpe
-    if cfg.dropout > 0:
-        x = pt.layers.dropout(x, cfg.dropout, is_test=is_test,
-                              dropout_implementation="upscale_in_train")
+    with pt.name_scope("embed"):
+        wte = pt.layers.embedding(
+            tokens, size=[cfg.vocab_size, cfg.hidden],
+            param_attr=_attr(f"{prefix}/wte", cfg))
+        pos_ids = pt.layers.arange(0, seq, dtype="int64")
+        wpe = pt.layers.embedding(
+            pos_ids, size=[cfg.max_pos, cfg.hidden],
+            param_attr=_attr(f"{prefix}/wpe", cfg))
+        x = wte + wpe
+        if cfg.dropout > 0:
+            x = pt.layers.dropout(
+                x, cfg.dropout, is_test=is_test,
+                dropout_implementation="upscale_in_train")
     def _resid_drop(t):
         # GPT-2 resid_pdrop on every sublayer output; attn-prob dropout
         # stays absent on the fused path (standard for flash kernels,
@@ -98,12 +106,18 @@ def gpt_decoder(tokens, cfg: GPTConfig, is_test=False, prefix="gpt",
 
     for i in range(cfg.layers):
         p = f"{prefix}/l{i}"
-        x = x + _resid_drop(
-            _causal_attention(_ln(x, f"{p}/ln1"), cfg, p, seq))
-        x = x + _resid_drop(_mlp(_ln(x, f"{p}/ln2"), cfg, p))
+        with pt.name_scope("norm"):
+            h = _ln(x, f"{p}/ln1")
+        with pt.name_scope("attn"):
+            x = x + _resid_drop(_causal_attention(h, cfg, p, seq))
+        with pt.name_scope("norm"):
+            h = _ln(x, f"{p}/ln2")
+        with pt.name_scope("ffn"):
+            x = x + _resid_drop(_mlp(h, cfg, p))
         if cut_vars is not None:
             cut_vars.append(x.name)
-    return _ln(x, f"{prefix}/lnf")
+    with pt.name_scope("head"):
+        return _ln(x, f"{prefix}/lnf")
 
 
 def gpt_lm_program(cfg: GPTConfig, seq_len: int, is_test=False,
@@ -119,13 +133,15 @@ def gpt_lm_program(cfg: GPTConfig, seq_len: int, is_test=False,
         tokens = pt.layers.data("tokens", [seq_len], dtype="int64")
         h = gpt_decoder(tokens, cfg, is_test=is_test, cut_vars=cuts)
         wte = main.global_block.var("gpt/wte")
-        logits = pt.layers.matmul(h, wte, transpose_y=True)
-        # shift: logits[:, :-1] predict tokens[:, 1:]
-        pred = pt.layers.slice(logits, [1], [0], [seq_len - 1])
-        labels = pt.layers.slice(tokens, [1], [1], [seq_len])
-        labels = pt.layers.reshape(labels, [0, seq_len - 1, 1])
-        loss = pt.layers.softmax_with_cross_entropy(pred, labels)
-        mean_loss = pt.layers.mean(loss)
+        with pt.name_scope("head"):
+            logits = pt.layers.matmul(h, wte, transpose_y=True)
+        with pt.name_scope("loss"):
+            # shift: logits[:, :-1] predict tokens[:, 1:]
+            pred = pt.layers.slice(logits, [1], [0], [seq_len - 1])
+            labels = pt.layers.slice(tokens, [1], [1], [seq_len])
+            labels = pt.layers.reshape(labels, [0, seq_len - 1, 1])
+            loss = pt.layers.softmax_with_cross_entropy(pred, labels)
+            mean_loss = pt.layers.mean(loss)
 
         if optimizer == "adam":
             opt = pt.optimizer.Adam(learning_rate)

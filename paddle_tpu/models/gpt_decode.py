@@ -115,7 +115,22 @@ def quantize_params(params, cfg):
     return out
 
 
+def _stage(name):
+    """A stage's scope (`<layer>/<stage>`, the vocabulary of the expert
+    models): metadata on the traced operations, read back from a device
+    trace by benchmarks/lib/stage_times.py; no instruction changes."""
+    import jax
+    return jax.named_scope(name)
+
+
 def _ln(x, p, eps=1e-5):
+    """A block's layer norm, the stage `norm`; the final one is part of
+    `head` and calls `_layer_norm` itself."""
+    with _stage("norm"):
+        return _layer_norm(x, p, eps)
+
+
+def _layer_norm(x, p, eps=1e-5):
     import jax
     import jax.numpy as jnp
     xf = x.astype(jnp.float32)
@@ -190,6 +205,20 @@ def _dense_a(x, p, lora):
     return jnp.where(live, y + d.astype(y.dtype), y)
 
 
+def _embed(params, tokens, pos, dtype):
+    """The stage `embed`: the two table reads and the cast."""
+    with _stage("embed"):
+        return (params["wte"][tokens] + params["wpe"][pos]).astype(dtype)
+
+
+def _mlp(h, blk, la=None):
+    """The stage `ffn/dense`: both products and the GELU."""
+    la = la or {"mlp1": None, "mlp2": None}
+    with _stage("ffn/dense"):
+        return _dense_a(_gelu_tanh(_dense_a(h, blk["mlp1"], la["mlp1"])),
+                        blk["mlp2"], la["mlp2"])
+
+
 def _split_heads(x, heads):
     b, s, h = x.shape
     return x.reshape(b, s, heads, h // heads)
@@ -235,36 +264,43 @@ def _prefill_blocks(params, cfg, tokens, max_len):
     heads, hd = cfg.heads, cfg.hidden // cfg.heads
     dtype = params["wte"].dtype if params["wte"].dtype == jnp.bfloat16 \
         else jnp.float32
-    x = (params["wte"][tokens] + params["wpe"][:p_len]).astype(dtype)
+    x = _embed(params, tokens, slice(None, p_len), dtype)
     mask = jnp.tril(jnp.ones((p_len, p_len), bool))
     cache = jnp.zeros((cfg.layers, 2, b, heads, max_len, hd), dtype)
     for li, blk in enumerate(params["blocks"]):
         h = _ln(x, blk["ln1"])
-        q = _split_heads(_dense(h, blk["q"]), heads)
-        k = _split_heads(_dense(h, blk["k"]), heads)
-        v = _split_heads(_dense(h, blk["v"]), heads)
+        with _stage("attn/project"):
+            q = _split_heads(_dense(h, blk["q"]), heads)
+            k = _split_heads(_dense(h, blk["k"]), heads)
+            v = _split_heads(_dense(h, blk["v"]), heads)
         # cache layout (.., heads, seq, hd): seq-major per head so the
         # decode step's dynamic_update_slice touches one lane-row
-        cache = cache.at[li, 0, :, :, :p_len].set(k.transpose(0, 2, 1, 3))
-        cache = cache.at[li, 1, :, :, :p_len].set(v.transpose(0, 2, 1, 3))
-        scores = jnp.einsum("bqnd,bknd->bnqk", q, k,
-                            preferred_element_type=jnp.float32)
-        scores = jnp.where(mask, scores / np.sqrt(hd), -1e30)
-        probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
-        probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
-        ctx = jnp.einsum("bnqk,bknd->bqnd", probs, v).reshape(b, p_len, -1)
-        x = x + _dense(ctx, blk["out"])
-        h = _ln(x, blk["ln2"])
-        x = x + _dense(_gelu_tanh(_dense(h, blk["mlp1"])), blk["mlp2"])
+        with _stage("attn/write"):
+            cache = cache.at[li, 0, :, :, :p_len].set(
+                k.transpose(0, 2, 1, 3))
+            cache = cache.at[li, 1, :, :, :p_len].set(
+                v.transpose(0, 2, 1, 3))
+        with _stage("attn/attend"):
+            scores = jnp.einsum("bqnd,bknd->bnqk", q, k,
+                                preferred_element_type=jnp.float32)
+            scores = jnp.where(mask, scores / np.sqrt(hd), -1e30)
+            probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
+            probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
+            ctx = jnp.einsum("bnqk,bknd->bqnd", probs, v).reshape(
+                b, p_len, -1)
+        with _stage("attn/project"):
+            x = x + _dense(ctx, blk["out"])
+        x = x + _mlp(_ln(x, blk["ln2"]), blk)
     return x, cache
 
 
 def _head_logits(params, last):
     """Final LN + tied-wte head over a (b, 1, h) slice -> (b, V) f32."""
     import jax.numpy as jnp
-    last = _ln(last, params["lnf"])
-    logits = (last @ params["wte"].T.astype(last.dtype))[:, 0]
-    return logits.astype(jnp.float32)
+    with _stage("head"):
+        last = _layer_norm(last, params["lnf"])
+        logits = (last @ params["wte"].T.astype(last.dtype))[:, 0]
+        return logits.astype(jnp.float32)
 
 
 def gpt_prefill(params, cfg, tokens, max_len):
@@ -289,29 +325,32 @@ def gpt_decode_step(params, cfg, token, cache, t):
     max_len = cache.shape[4]
     b = token.shape[0]
     dtype = cache.dtype
-    x = (params["wte"][token] + params["wpe"][t]).astype(dtype)[:, None]
+    x = _embed(params, token, t, dtype)[:, None]
     pos_mask = (jnp.arange(max_len) <= t)          # [S]
     for li, blk in enumerate(params["blocks"]):
         h = _ln(x, blk["ln1"])
-        q = _dense(h, blk["q"]).reshape(b, heads, 1, hd)
-        k = _dense(h, blk["k"]).reshape(b, heads, 1, hd)
-        v = _dense(h, blk["v"]).reshape(b, heads, 1, hd)
-        cache = jax.lax.dynamic_update_slice(
-            cache, k[None, None], (li, 0, 0, 0, t, 0))
-        cache = jax.lax.dynamic_update_slice(
-            cache, v[None, None], (li, 1, 0, 0, t, 0))
-        K, V = cache[li, 0], cache[li, 1]          # (b, n, S, hd)
-        scores = jnp.einsum("bnqd,bnkd->bnqk", q, K,
-                            preferred_element_type=jnp.float32)
-        scores = jnp.where(pos_mask[None, None, None, :],
-                           scores / np.sqrt(hd), -1e30)
-        probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
-        probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
-        ctx = jnp.einsum("bnqk,bnkd->bnqd", probs, V)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, 1, -1)
-        x = x + _dense(ctx, blk["out"])
-        h = _ln(x, blk["ln2"])
-        x = x + _dense(_gelu_tanh(_dense(h, blk["mlp1"])), blk["mlp2"])
+        with _stage("attn/project"):
+            q = _dense(h, blk["q"]).reshape(b, heads, 1, hd)
+            k = _dense(h, blk["k"]).reshape(b, heads, 1, hd)
+            v = _dense(h, blk["v"]).reshape(b, heads, 1, hd)
+        with _stage("attn/write"):
+            cache = jax.lax.dynamic_update_slice(
+                cache, k[None, None], (li, 0, 0, 0, t, 0))
+            cache = jax.lax.dynamic_update_slice(
+                cache, v[None, None], (li, 1, 0, 0, t, 0))
+        with _stage("attn/attend"):
+            K, V = cache[li, 0], cache[li, 1]          # (b, n, S, hd)
+            scores = jnp.einsum("bnqd,bnkd->bnqk", q, K,
+                                preferred_element_type=jnp.float32)
+            scores = jnp.where(pos_mask[None, None, None, :],
+                               scores / np.sqrt(hd), -1e30)
+            probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
+            probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
+            ctx = jnp.einsum("bnqk,bnkd->bnqd", probs, V)
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, 1, -1)
+        with _stage("attn/project"):
+            x = x + _dense(ctx, blk["out"])
+        x = x + _mlp(_ln(x, blk["ln2"]), blk)
     return _head_logits(params, x), cache
 
 
@@ -355,7 +394,7 @@ def gpt_decode_verify_pages(params, cfg, toks, arena, pt, ts, done=None,
         else (adapter_ids != 0)[:, None, None]
     rows = jnp.arange(s_dim)[:, None]
     pos = ts[:, None] + jnp.arange(D)[None, :]           # (S, D)
-    x = (params["wte"][toks] + params["wpe"][pos]).astype(dtype)
+    x = _embed(params, toks, pos, dtype)
     pos_mask = (jnp.arange(L)[None, None, :] <= pos[:, :, None])
     pidx = pos // bs
     wblk = jnp.where(pidx < P, pt[rows, jnp.minimum(pidx, P - 1)], 0)
@@ -363,26 +402,32 @@ def gpt_decode_verify_pages(params, cfg, toks, arena, pt, ts, done=None,
         wblk = jnp.where(done[:, None], 0, wblk)
     woff = pos % bs
     for li, blk in enumerate(params["blocks"]):
-        la = _lora_layer(adapters, adapter_ids, li, live)
+        with _stage("attn/project"):
+            la = _lora_layer(adapters, adapter_ids, li, live)
         h = _ln(x, blk["ln1"])
-        q = _dense_a(h, blk["q"], la["q"]).reshape(s_dim, D, heads, hd)
-        k = _dense_a(h, blk["k"], la["k"]).reshape(s_dim, D, heads, hd)
-        v = _dense_a(h, blk["v"], la["v"]).reshape(s_dim, D, heads, hd)
-        arena = _kv_write(arena, li, wblk, woff, k, v)
-        K, V = _kv_gather(arena, li, pt, dtype)  # (S, n, L, hd)
-        scores = jnp.einsum("bqnd,bnkd->bnqk", q, K,
-                            preferred_element_type=jnp.float32)
-        scores = jnp.where(pos_mask[:, None, :, :],
-                           scores / np.sqrt(hd), -1e30)
-        probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
-        probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
-        ctx = jnp.einsum("bnqk,bnkd->bqnd", probs, V).reshape(s_dim, D, -1)
-        x = x + _dense_a(ctx, blk["out"], la["out"])
-        h = _ln(x, blk["ln2"])
-        x = x + _dense_a(_gelu_tanh(_dense_a(h, blk["mlp1"], la["mlp1"])),
-                         blk["mlp2"], la["mlp2"])
-    x = _ln(x, params["lnf"])
-    return (x @ params["wte"].T.astype(x.dtype)).astype(jnp.float32), arena
+        with _stage("attn/project"):
+            q = _dense_a(h, blk["q"], la["q"]).reshape(s_dim, D, heads, hd)
+            k = _dense_a(h, blk["k"], la["k"]).reshape(s_dim, D, heads, hd)
+            v = _dense_a(h, blk["v"], la["v"]).reshape(s_dim, D, heads, hd)
+        with _stage("attn/write"):
+            arena = _kv_write(arena, li, wblk, woff, k, v)
+        with _stage("attn/attend"):
+            K, V = _kv_gather(arena, li, pt, dtype)  # (S, n, L, hd)
+            scores = jnp.einsum("bqnd,bnkd->bnqk", q, K,
+                                preferred_element_type=jnp.float32)
+            scores = jnp.where(pos_mask[:, None, :, :],
+                               scores / np.sqrt(hd), -1e30)
+            probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
+            probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
+            ctx = jnp.einsum("bnqk,bnkd->bqnd", probs, V).reshape(
+                s_dim, D, -1)
+        with _stage("attn/project"):
+            x = x + _dense_a(ctx, blk["out"], la["out"])
+        x = x + _mlp(_ln(x, blk["ln2"]), blk, la)
+    with _stage("head"):
+        x = _layer_norm(x, params["lnf"])
+        return (x @ params["wte"].T.astype(x.dtype)).astype(
+            jnp.float32), arena
 
 
 def paged_arena_shapes(layers, num_blocks, heads, block_size, hd):
@@ -654,16 +699,20 @@ def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
     live = None if adapters is None else (adapter_id != 0)
     j = jnp.arange(B)
     pos = pfx_len + j                              # absolute positions
-    x = (params["wte"][tokens[0]] + params["wpe"][pos]).astype(dtype)
+    x = _embed(params, tokens[0], pos, dtype)
     mask = jnp.arange(L)[None, :] <= pos[:, None]  # (B, L) causal
     for li, blk in enumerate(params["blocks"]):
-        la = _lora_layer(adapters, adapter_id, li, live)
+        with _stage("attn/project"):
+            la = _lora_layer(adapters, adapter_id, li, live)
         h = _ln(x, blk["ln1"])
-        q = _dense_a(h, blk["q"], la["q"]).reshape(B, heads, hd)
-        k = _dense_a(h, blk["k"], la["k"]).reshape(B, heads, hd)
-        v = _dense_a(h, blk["v"], la["v"]).reshape(B, heads, hd)
+        with _stage("attn/project"):
+            q = _dense_a(h, blk["q"], la["q"]).reshape(B, heads, hd)
+            k = _dense_a(h, blk["k"], la["k"]).reshape(B, heads, hd)
+            v = _dense_a(h, blk["v"], la["v"]).reshape(B, heads, hd)
         # pad rows reach no page but scratch block 0 (see docstring)
-        arena = _kv_write_pages(arena, li, pages, pfx_len, real_len, k, v)
+        with _stage("attn/write"):
+            arena = _kv_write_pages(arena, li, pages, pfx_len, real_len,
+                                    k, v)
 
         def warm(arena, li=li, q=q):
             K, V = _kv_gather(arena, li, pages, dtype)  # (heads, L, hd)
@@ -675,16 +724,16 @@ def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
             probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
             return jnp.einsum("bnk,nkd->bnd", probs, V)
 
-        if attention == "flash":
-            def cold(arena, q=q, k=k, v=v):
-                return flash_causal_rows(q, k, v, 1.0 / np.sqrt(hd))
-            ctx = jax.lax.cond(pfx_len == 0, cold, warm, arena)
-        else:
-            ctx = warm(arena)
-        x = x + _dense_a(ctx.reshape(B, -1), blk["out"], la["out"])
-        h = _ln(x, blk["ln2"])
-        x = x + _dense_a(_gelu_tanh(_dense_a(h, blk["mlp1"], la["mlp1"])),
-                         blk["mlp2"], la["mlp2"])
+        with _stage("attn/attend"):
+            if attention == "flash":
+                def cold(arena, q=q, k=k, v=v):
+                    return flash_causal_rows(q, k, v, 1.0 / np.sqrt(hd))
+                ctx = jax.lax.cond(pfx_len == 0, cold, warm, arena)
+            else:
+                ctx = warm(arena)
+        with _stage("attn/project"):
+            x = x + _dense_a(ctx.reshape(B, -1), blk["out"], la["out"])
+        x = x + _mlp(_ln(x, blk["ln2"]), blk, la)
     last = x[real_len - 1][None, None]             # (1, 1, h)
     return _head_logits(params, last), arena
 
@@ -760,35 +809,39 @@ def gpt_decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
     dtype = _arena_compute_dtype(params, data, _scales)
     live = None if adapters is None \
         else (adapter_ids != 0)[:, None, None]
-    x = (params["wte"][tokens] + params["wpe"][ts]).astype(dtype)[:, None]
+    x = _embed(params, tokens, ts, dtype)[:, None]
     for li, blk in enumerate(params["blocks"]):
-        la = _lora_layer(adapters, adapter_ids, li, live)
+        with _stage("attn/project"):
+            la = _lora_layer(adapters, adapter_ids, li, live)
         h = _ln(x, blk["ln1"])
-        q = _dense_a(h, blk["q"], la["q"]).reshape(s_dim, heads, 1, hd)
-        k = _dense_a(h, blk["k"], la["k"]).reshape(s_dim, heads, hd)
-        v = _dense_a(h, blk["v"], la["v"]).reshape(s_dim, heads, hd)
+        with _stage("attn/project"):
+            q = _dense_a(h, blk["q"], la["q"]).reshape(s_dim, heads, 1, hd)
+            k = _dense_a(h, blk["k"], la["k"]).reshape(s_dim, heads, hd)
+            v = _dense_a(h, blk["v"], la["v"]).reshape(s_dim, heads, hd)
         if attention == "paged_kernel":
             # the kernel writes the row too (where a frozen slot's went
             # to scratch it now goes nowhere), so no XLA scatter asks
             # for the arena in another layout
-            ctx, arena = paged_attention(q[:, :, 0], k, v, arena, li, pt,
-                                         ts, done)
-            ctx = ctx.reshape(s_dim, 1, -1)
+            with _stage("attn/attend"):
+                ctx, arena = paged_attention(q[:, :, 0], k, v, arena, li,
+                                             pt, ts, done)
+                ctx = ctx.reshape(s_dim, 1, -1)
         else:
-            arena = _kv_write(arena, li, wblk, woff, k, v)
-            K, V = _kv_gather(arena, li, pt, dtype)  # (S, heads, L, hd)
-            scores = jnp.einsum("bnqd,bnkd->bnqk", q, K,
-                                preferred_element_type=jnp.float32)
-            scores = jnp.where(pos_mask[:, None, None, :],
-                               scores / np.sqrt(hd), -1e30)
-            probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
-            probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
-            ctx = jnp.einsum("bnqk,bnkd->bnqd", probs, V)
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(s_dim, 1, -1)
-        x = x + _dense_a(ctx, blk["out"], la["out"])
-        h = _ln(x, blk["ln2"])
-        x = x + _dense_a(_gelu_tanh(_dense_a(h, blk["mlp1"], la["mlp1"])),
-                         blk["mlp2"], la["mlp2"])
+            with _stage("attn/write"):
+                arena = _kv_write(arena, li, wblk, woff, k, v)
+            with _stage("attn/attend"):
+                K, V = _kv_gather(arena, li, pt, dtype)  # (S, heads, L, hd)
+                scores = jnp.einsum("bnqd,bnkd->bnqk", q, K,
+                                    preferred_element_type=jnp.float32)
+                scores = jnp.where(pos_mask[:, None, None, :],
+                                   scores / np.sqrt(hd), -1e30)
+                probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
+                probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
+                ctx = jnp.einsum("bnqk,bnkd->bnqd", probs, V)
+                ctx = ctx.transpose(0, 2, 1, 3).reshape(s_dim, 1, -1)
+        with _stage("attn/project"):
+            x = x + _dense_a(ctx, blk["out"], la["out"])
+        x = x + _mlp(_ln(x, blk["ln2"]), blk, la)
     return _head_logits(params, x), arena
 
 

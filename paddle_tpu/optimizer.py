@@ -13,7 +13,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from .framework.core import (Parameter, Program, Variable,
+from .framework.core import (NAMESCOPE_ATTR, Parameter, Program, Variable,
                              default_main_program,
                              default_startup_program, unique_name)
 from .framework.backward import append_backward
@@ -214,9 +214,12 @@ class Optimizer:
             ops.append(self._append_optimize_op(
                 block, p, g, self._param_lr(block, lr, p)))
         self._finish_update(block, params_grads, startup)
-        # tag everything appended here so clone(for_test=True) prunes it
+        # tag everything appended here so clone(for_test=True) prunes it,
+        # and stamp it as a name scope would (clipping, weight decay, the
+        # update) without entering one: an accumulator's name stays
         for op in block.ops[n_before:]:
             op.attrs.setdefault("op_role", "optimize")
+            op.attrs.setdefault(NAMESCOPE_ATTR, "optimizer")
         return ops
 
     def _param_lr(self, block, lr: Variable, param) -> Variable:

@@ -234,7 +234,7 @@ def _signature(ops):
     for op in ops:
         blk = op.block
         attrs = {k: v for k, v in sorted(op.attrs.items())
-                 if k not in ("name", "op_role")}
+                 if k not in ("name", "op_role", "op_namescope")}
 
         def vsig(n):
             if blk.has_var(n):

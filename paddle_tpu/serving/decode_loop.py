@@ -16,7 +16,15 @@ from __future__ import annotations
 
 from typing import Any, NamedTuple
 
-__all__ = ["DecodeCarry", "finish_rule", "decode_chunk", "spec_ngram_seed"]
+__all__ = ["DecodeCarry", "finish_rule", "decode_chunk", "spec_ngram_seed",
+           "SAMPLE_SCOPE", "FINISH_SCOPE"]
+
+# The loop's own stages in a device trace (`jax.named_scope`, metadata
+# only): the sampling call with its key split, and the finish rule with the
+# carry's update. The model's step between them stays under the model's own
+# scopes; the scheduler's admission sampler uses the same two names.
+SAMPLE_SCOPE = "loop/sample"
+FINISH_SCOPE = "loop/finish"
 
 
 class DecodeCarry(NamedTuple):
@@ -139,52 +147,55 @@ def _spec_step(verify, sample_fn, temps, eos_ids, speculate_k, carry):
     # shapes are fixed, so a hopeless draft costs nothing extra
     drafts = []
     a, b = prev, tok
-    for _ in range(k):
-        d = table[rows, _ngram_hash(a, b, size)]
-        d = jnp.where(d < 0, 0, d)
-        drafts.append(d)
-        a, b = b, d
-    inputs = jnp.stack([tok] + drafts, axis=1)           # (S, k+1)
+    with jax.named_scope("loop/draft"):
+        for _ in range(k):
+            d = table[rows, _ngram_hash(a, b, size)]
+            d = jnp.where(d < 0, 0, d)
+            drafts.append(d)
+            a, b = b, d
+        inputs = jnp.stack([tok] + drafts, axis=1)       # (S, k+1)
     logits, pool = verify(inputs, pool, ts, done)
     cands, chain, cur = [], [keys], keys
-    for j in range(k + 1):
-        cj, cur = jax.vmap(sample_fn)(cur, logits[:, j], temps)
-        cands.append(cj)
-        chain.append(cur)
-    cands = jnp.stack(cands, axis=1)                     # (S, k+1)
-    chain = jnp.stack(chain, axis=1)                     # (S, k+2, key)
-    dr = jnp.stack(drafts, axis=1)                       # (S, k)
-    # candidate j is valid only while drafts 0..j-1 all matched (its
-    # logits saw the committed stream); the mask is monotone by cumprod
-    lead = jnp.cumprod((cands[:, :k] == dr).astype(jnp.int32), axis=1)
-    base = jnp.concatenate(
-        [jnp.ones((s_dim, 1), bool), lead.astype(bool)], axis=1)
-    jj = jnp.arange(k + 1)[None, :]
-    stop = finish_rule(cands, eos_ids[:, None], rem[:, None] - (jj + 1))
-    stopped_before = jnp.concatenate(
-        [jnp.zeros((s_dim, 1), bool),
-         jnp.cumsum(stop.astype(jnp.int32), axis=1)[:, :-1] > 0], axis=1)
-    can = base & ~stopped_before             # monotone commit mask
-    c = can.sum(axis=1).astype(jnp.int32)    # >= 1: j=0 always commits
-    live = ~done
-    last = cands[rows, c - 1]
-    prev_commit = jnp.where(c >= 2, cands[rows, jnp.maximum(c - 2, 0)],
-                            tok)
-    ndone = done | (can & stop).any(axis=1)
-    # n-gram table update: every committed token registered under its
-    # 2-token context (frozen slots and rejected tails -> trash column)
-    seq = jnp.concatenate([prev[:, None], tok[:, None], cands], axis=1)
-    idx = _ngram_hash(seq[:, :k + 1], seq[:, 1:k + 2], size)
-    idx = jnp.where(can & live[:, None], idx, size)
-    table = table.at[rows[:, None], idx].set(cands)
-    out = jnp.where(live[:, None],
-                    jnp.where(can, cands, last[:, None]), tok[:, None])
-    counts = jnp.where(live, c, 1)
-    keys = chain[rows, jnp.where(live, c, 1)]
-    tok = jnp.where(live, last, tok)
-    prev = jnp.where(live, prev_commit, prev)
-    ts = jnp.where(live, ts + c, ts)
-    rem = jnp.where(live, rem - c, rem)
+    with jax.named_scope(SAMPLE_SCOPE):
+        for j in range(k + 1):
+            cj, cur = jax.vmap(sample_fn)(cur, logits[:, j], temps)
+            cands.append(cj)
+            chain.append(cur)
+        cands = jnp.stack(cands, axis=1)                 # (S, k+1)
+        chain = jnp.stack(chain, axis=1)                 # (S, k+2, key)
+    with jax.named_scope(FINISH_SCOPE):
+        dr = jnp.stack(drafts, axis=1)                   # (S, k)
+        # candidate j is valid only while drafts 0..j-1 all matched (its
+        # logits saw the committed stream); the mask is monotone by cumprod
+        lead = jnp.cumprod((cands[:, :k] == dr).astype(jnp.int32), axis=1)
+        base = jnp.concatenate(
+            [jnp.ones((s_dim, 1), bool), lead.astype(bool)], axis=1)
+        jj = jnp.arange(k + 1)[None, :]
+        stop = finish_rule(cands, eos_ids[:, None], rem[:, None] - (jj + 1))
+        stopped_before = jnp.concatenate(
+            [jnp.zeros((s_dim, 1), bool),
+             jnp.cumsum(stop.astype(jnp.int32), axis=1)[:, :-1] > 0], axis=1)
+        can = base & ~stopped_before             # monotone commit mask
+        c = can.sum(axis=1).astype(jnp.int32)    # >= 1: j=0 always commits
+        live = ~done
+        last = cands[rows, c - 1]
+        prev_commit = jnp.where(c >= 2, cands[rows, jnp.maximum(c - 2, 0)],
+                                tok)
+        ndone = done | (can & stop).any(axis=1)
+        # n-gram table update: every committed token registered under its
+        # 2-token context (frozen slots and rejected tails -> trash column)
+        seq = jnp.concatenate([prev[:, None], tok[:, None], cands], axis=1)
+        idx = _ngram_hash(seq[:, :k + 1], seq[:, 1:k + 2], size)
+        idx = jnp.where(can & live[:, None], idx, size)
+        table = table.at[rows[:, None], idx].set(cands)
+        out = jnp.where(live[:, None],
+                        jnp.where(can, cands, last[:, None]), tok[:, None])
+        counts = jnp.where(live, c, 1)
+        keys = chain[rows, jnp.where(live, c, 1)]
+        tok = jnp.where(live, last, tok)
+        prev = jnp.where(live, prev_commit, prev)
+        ts = jnp.where(live, ts + c, ts)
+        rem = jnp.where(live, rem - c, rem)
     return ((tok, pool, ts, keys, ndone, rem, prev, table),
             (out.T, counts))
 
@@ -287,11 +298,13 @@ def decode_chunk(model, params, cfg, arena, pt, keys, carry, chunk,
             params, cfg, tok, arena, pt, ts, done, adapters=adapters,
             adapter_ids=aids, arena_constraint=arena_constraint)
         counters = jax.tree_util.tree_map(jnp.add, counters, stepped)
-        nxt, keys = jax.vmap(sample_fn)(keys, logits, temps)
-        emit = jnp.where(done, tok, nxt)
-        rem = jnp.where(done, rem, rem - 1)
-        ndone = finish_rule(emit, eos_ids, rem, done)
-        ts = jnp.where(done, ts, ts + 1)
+        with jax.named_scope(SAMPLE_SCOPE):
+            nxt, keys = jax.vmap(sample_fn)(keys, logits, temps)
+        with jax.named_scope(FINISH_SCOPE):
+            emit = jnp.where(done, tok, nxt)
+            rem = jnp.where(done, rem, rem - 1)
+            ndone = finish_rule(emit, eos_ids, rem, done)
+            ts = jnp.where(done, ts, ts + 1)
         return (emit, arena, ts, keys, ndone, rem, counters), emit
 
     (tokens, arena, ts, keys, done, remaining, counters), block = \

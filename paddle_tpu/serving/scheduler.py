@@ -181,7 +181,8 @@ from ..observability import request_log as _request_log
 from ..observability.tracer import get_tracer, trace_span
 from ..utils.compile_cache import ensure_compile_cache
 from . import sampling
-from .decode_loop import (DecodeCarry, decode_chunk, finish_rule,
+from .decode_loop import (FINISH_SCOPE, SAMPLE_SCOPE, DecodeCarry,
+                          decode_chunk, finish_rule,
                           spec_ngram_seed)
 from .kv_cache import ShapeBuckets, SlotKVCache
 from .model import serving_model
@@ -760,20 +761,23 @@ class ContinuousBatchingScheduler:
         def admit_impl(keys, state, slot, seed, logits, temp, pos,
                        max_new, eos_id, prev_tok, *aid):
             self._note_compile("admit_sample")
-            keys = keys.at[slot].set(sampling.sample_key(seed))
-            first, key_next = self._sample_row(keys[slot], logits, temp)
-            keys = keys.at[slot].set(key_next)
+            with jax.named_scope(SAMPLE_SCOPE):
+                keys = keys.at[slot].set(sampling.sample_key(seed))
+                first, key_next = self._sample_row(keys[slot], logits,
+                                                   temp)
+                keys = keys.at[slot].set(key_next)
             # finished-at-admission is the one finish rule, so the
             # device-side done mask never disagrees with _running
             left = max_new - 1
-            state = state._replace(
-                tokens=state.tokens.at[slot].set(first),
-                ts=state.ts.at[slot].set(pos),
-                done=state.done.at[slot].set(
-                    finish_rule(first, eos_id, left)),
-                remaining=state.remaining.at[slot].set(left),
-                temps=state.temps.at[slot].set(temp),
-                eos_ids=state.eos_ids.at[slot].set(eos_id))
+            with jax.named_scope(FINISH_SCOPE):
+                state = state._replace(
+                    tokens=state.tokens.at[slot].set(first),
+                    ts=state.ts.at[slot].set(pos),
+                    done=state.done.at[slot].set(
+                        finish_rule(first, eos_id, left)),
+                    remaining=state.remaining.at[slot].set(left),
+                    temps=state.temps.at[slot].set(temp),
+                    eos_ids=state.eos_ids.at[slot].set(eos_id))
             if self.speculate_k:
                 # first drafter context = (last prompt token, first
                 # sampled token); the table row was seeded at prefill

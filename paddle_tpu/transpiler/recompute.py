@@ -158,7 +158,7 @@ def apply_recompute(program, checkpoints: Sequence[str]) -> int:
                 {"Out": [ren[op.output("Out")[0]]]},
                 {**{k: v for k, v in op.attrs.items()
                     if k in ("dropout_prob", "dropout_implementation",
-                             "is_test")},
+                             "is_test", "op_namescope")},
                  "op_role": "backward"}, infer_shape=False)
         else:
             ins = {s: [map_in(n) for n in ns]

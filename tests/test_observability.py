@@ -1657,7 +1657,7 @@ def test_builtin_anomaly_rules_fire_on_their_signals():
 # ---------------------------------------------------------------------------
 
 _EXEC_PHASES = {"executor/prepare", "executor/place", "executor/dispatch",
-                "executor/writeback", "executor/fetch"}
+                "executor/writeback", "executor/fetch", "executor/release"}
 _TICK_SPANS = {"serving/tick/admit", "serving/tick/launch",
                "serving/tick/collect", "serving/tick/stream"}
 _ADMISSION_SPANS = ("serving/prefill", "serving/wait/first_token")
@@ -1763,7 +1763,7 @@ def test_phase_spans_reach_the_profiler_trace(layer, tiny_engine_params,
         kids = _children_of(events, run)
         assert [k[0] for k in kids] == [
             "executor/prepare", "executor/place", "executor/dispatch",
-            "executor/writeback", "executor/fetch"]
+            "executor/writeback", "executor/fetch", "executor/release"]
         _assert_disjoint(kids)
         assert len(events) == 1 + len(_EXEC_PHASES) <= 8
         return
