@@ -481,7 +481,8 @@ class _MellumServingModel(ServingModel):
         return {"expert_tokens": (cfg.n_routed_experts,),
                 "router_tokens": (), "decode_router_tokens": (),
                 "decode_experts_touched": (), "decode_moe_passes": (),
-                "moe_kernel_passes": (), "decode_rows_full": (),
+                "moe_kernel_passes": (), "moe_rows_computed": (),
+                "decode_rows_full": (),
                 "decode_rows_window": ()}
 
     @staticmethod

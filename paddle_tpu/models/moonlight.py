@@ -24,16 +24,27 @@ and fused chunk loop (serving/decode_loop.py) as the GPT family:
     `n_routed_experts` SwiGLU experts (sigmoid scores in float32, the
     picks by score + correction bias, the weights by score alone,
     normalised and scaled) plus one shared SwiGLU. The expert product is
-    GROUPED: tokens sorted by expert, the SwiGLU over the rows that were
-    routed (tokens x experts_per_tok of them, no capacity, none dropped,
-    never every expert on every token). On a TPU it is ONE kernel a
-    layer, ops/grouped_swiglu: gate, up, `silu(g) * u` and down per
-    expert, the weights read where they lie, an expert with no row never
-    fetched; its row tile is chosen there from the static row count (16
-    rows for a decode step's 192, 128 for a prompt's thousands: PERF.md,
-    PR 28). Elsewhere (the CPU) it is one `jax.lax.ragged_dot` per
-    weight; `expert_product_path` says which, and the in-graph counter
-    `moe_kernel_passes` counts the layers that ran the kernel.
+    GROUPED over the rows that were routed (tokens x experts_per_tok of
+    them, no capacity, none dropped, never every expert on every token),
+    and the routed rows are LAID OUT ONCE: sorted by expert, every
+    expert's rows from a whole row tile on, their positions by COUNTING
+    (ops/grouped_swiglu.routed_positions: a (token, pick)'s row is its
+    group's start plus the earlier picks of the same expert; no sort).
+    `moe/dispatch` gathers the rows there (one scatter of tokens x picks
+    integers says whose row each is), `moe/experts` computes whole tiles
+    of ONE expert, `moe/combine` reads the products back by the same
+    positions, pick by pick in the weights' type, and sums them in
+    float32. On a TPU the product is ONE kernel a layer,
+    ops/grouped_swiglu: gate, up, `silu(g) * u` and down per expert, the
+    weights read where they lie, an expert with no row never fetched;
+    the row tile is the layout's, chosen from the static row count (16
+    rows for a decode step's 192, 256 for a prompt's thousands: PERF.md,
+    PRs 28 and 37). Elsewhere (the CPU) it is one `jax.lax.ragged_dot`
+    per weight over the same layout; `expert_product_path` says which,
+    the in-graph counter `moe_kernel_passes` counts the layers that ran
+    the kernel and `moe_rows_computed` the rows it computed (its visits'
+    whole tiles: `sum(expert_tokens)` over it is the share that were
+    someone's).
 
 What a config may change in the block (defaults are Moonlight's, whose
 program they leave as it was, to the bit):
@@ -602,20 +613,23 @@ def expert_product_path(lp):
     return "ragged_dot"
 
 
-def grouped_experts(lp, xs, group_sizes):
-    """The grouped SwiGLU: xs (R, h) rows sorted by expert, group_sizes
-    (E,) how many rows each expert has (rows past their sum are
-    nobody's and come back zero), over the routed rows alone. On a TPU
-    one kernel (ops/grouped_swiglu, which picks its row tile from the
-    static R); elsewhere three ragged products."""
+def grouped_experts(lp, xs, group_sizes, tile):
+    """The grouped SwiGLU over the routed rows alone: xs (R, h) in the
+    layout of ops/grouped_swiglu.routed_positions (sorted by expert,
+    every group from a whole tile of `tile` rows on), group_sizes (E,)
+    how many rows each expert has. A row between a group's end and its
+    tile's is computed for nobody; the tiles past the last group's are
+    not to be read. On a TPU one kernel (ops/grouped_swiglu); elsewhere
+    three ragged products over the groups rounded up to the tile."""
     import jax
     if expert_product_path(lp) == "grouped_swiglu_kernel":
         from ..ops.grouped_swiglu import grouped_swiglu
         return grouped_swiglu(xs, lp["w_gate"], lp["w_up"], lp["w_down"],
-                              group_sizes)
-    g = jax.lax.ragged_dot(xs, lp["w_gate"], group_sizes)
-    u = jax.lax.ragged_dot(xs, lp["w_up"], group_sizes)
-    return jax.lax.ragged_dot(jax.nn.silu(g) * u, lp["w_down"], group_sizes)
+                              group_sizes, tile)
+    whole = -(-group_sizes // tile) * tile
+    g = jax.lax.ragged_dot(xs, lp["w_gate"], whole)
+    u = jax.lax.ragged_dot(xs, lp["w_up"], whole)
+    return jax.lax.ragged_dot(jax.nn.silu(g) * u, lp["w_down"], whole)
 
 
 def _moe(cfg, lp, x, live):
@@ -625,41 +639,69 @@ def _moe(cfg, lp, x, live):
     `router_scoring` (see `route`): this block's and models/mellum's.
     `live` (T,)
     bool: rows that are real (a prefill's padding and a frozen slot's
-    ride-along are not: they get no expert and do not count). Returns
-    (y (T, h), counters)."""
+    ride-along are not: they get no expert and do not count). The routed
+    rows are laid out ONCE, by counting (`routed_positions`): the
+    dispatch gathers them there, the product computes whole tiles of one
+    expert, the weighted sum reads them back by the same positions.
+    Returns (y (T, h), counters)."""
     import jax
     import jax.numpy as jnp
+    from ..ops.grouped_swiglu import (padded_rows, routed_positions,
+                                      row_tile_for)
     T, k, E = x.shape[0], cfg.experts_per_tok, cfg.n_routed_experts
+    tile = row_tile_for(T * k, E)
     with jax.named_scope("moe/router"):
         picks, w = route(cfg, lp, x)
     with jax.named_scope("moe/dispatch"):
-        # rows that are not live are sent past the last expert: sorted
-        # to the end, in no group, never computed
-        flat = jnp.where(live[:, None], picks, E).reshape(-1)
-        order = jnp.argsort(flat)                      # stable
-        group_sizes = jnp.bincount(flat, length=E + 1)[:E].astype(jnp.int32)
-        xs = x[order // k]                             # (T*k, h)
+        # a row that is not live has no position: in no group, never
+        # moved, never computed
+        pos, group_sizes = routed_positions(picks, live, E, tile)
+        at = pos.reshape(-1)
+        token = jnp.arange(T * k, dtype=jnp.int32) // k
+        rows = padded_rows(T * k, E, tile)
+        if 4 * T * k <= rows:
+            # a step's few rows in a buffer that is mostly the experts'
+            # round-ups: the rows are PLACED (a gather fetches every row
+            # of the buffer, ~15 ns a row whoever's it is: PERF.md, PR 37)
+            xs = jnp.zeros((rows, x.shape[1]), x.dtype).at[at].set(
+                x[token], mode="drop", unique_indices=True)
+        else:
+            # whose row each row of the buffer is (nobody's: token 0's,
+            # for nobody): the scatter moves T * k integers, the gather
+            # the rows
+            source = jnp.zeros((rows,), jnp.int32).at[at].set(
+                token, mode="drop", unique_indices=True)
+            xs = x[source]
     with jax.named_scope("moe/experts"):
-        ys = grouped_experts(lp, xs, group_sizes)
+        ys = grouped_experts(lp, xs, group_sizes, tile)
     if cfg.n_shared_experts:
         with jax.named_scope("moe/shared"):
             shared = _swiglu(x, lp["shared_gate"], lp["shared_up"],
                              lp["shared_down"])
     with jax.named_scope("moe/combine"):
-        back = ys[jnp.argsort(order)].reshape(T, k, -1)
-        # a row in no group is nobody's: whatever the product left there
-        back = jnp.where(live[:, None, None], back, 0)
-        y = jnp.einsum("tkh,tk->th", back.astype(jnp.float32), w)
+        # pick by pick, (k, T, h) in the weights' type (token-major it
+        # would be re-laid for k = 4 and 6), then ONE multiply-and-sum
+        # over the picks in float32, in pick order; a dead token's `pos`
+        # is past the buffer and reads whatever its last row holds
+        back = ys.at[pos.T.reshape(-1)].get(mode="clip").reshape(k, T, -1)
+        y = back[0].astype(jnp.float32) * w[:, 0, None]
+        for j in range(1, k):
+            y = y + back[j].astype(jnp.float32) * w[:, j, None]
+        y = jnp.where(live[:, None], y, 0)
         if cfg.n_shared_experts:
             y = y + shared.astype(jnp.float32)
         y = y.astype(x.dtype)
     passes = jnp.any(live).astype(jnp.int32)
+    zero = jnp.zeros_like(passes)
     kernel = expert_product_path(lp) == "grouped_swiglu_kernel"
     counters = {"expert_tokens": group_sizes,
                 "router_tokens": jnp.sum(live).astype(jnp.int32),
                 "experts_touched": jnp.sum(group_sizes > 0).astype(jnp.int32),
                 "moe_passes": passes,
-                "kernel_passes": passes if kernel else jnp.zeros_like(passes)}
+                "kernel_passes": passes if kernel else zero,
+                # rows the kernel computed: its visits' whole tiles
+                "rows_computed": jnp.sum(-(-group_sizes // tile)) * tile
+                if kernel else zero}
     return y, counters
 
 
@@ -691,7 +733,8 @@ def _zero_counters(cfg):
     counters = {"expert_tokens": jnp.zeros((cfg.n_routed_experts,),
                                            jnp.int32),
                 "router_tokens": zero, "experts_touched": zero,
-                "moe_passes": zero, "kernel_passes": zero}
+                "moe_passes": zero, "kernel_passes": zero,
+                "rows_computed": zero}
     if cfg.hc_mult > 1:
         counters.update(hc_passes=zero, hc_rowsum_dev_ppm=zero)
     return counters
@@ -922,7 +965,10 @@ class _MoonlightServingModel(ServingModel):
         # with a live slot (what a step's expert bytes are counted from);
         # moe_kernel_passes: passes of an expert layer, a prefill's six
         # and a decode step's, whose product was the grouped kernel (0
-        # where `ragged_dot` ran: every backend but the TPU). With
+        # where `ragged_dot` ran: every backend but the TPU), and
+        # moe_rows_computed: the rows those passes computed (visits x row
+        # tile), so sum(expert_tokens) / moe_rows_computed is the share of
+        # the kernel's rows that were someone's (0 rows without it). With
         # residual streams (`hc_mult` > 1) also hc_passes: sublayers
         # mixed, by both programs, and hc_rowsum_dev_ppm: the sum over
         # those of the largest |row sum of H_res - 1| at a live token,
@@ -930,7 +976,7 @@ class _MoonlightServingModel(ServingModel):
         names = {"expert_tokens": (cfg.n_routed_experts,),
                  "router_tokens": (), "decode_router_tokens": (),
                  "decode_experts_touched": (), "decode_moe_passes": (),
-                 "moe_kernel_passes": ()}
+                 "moe_kernel_passes": (), "moe_rows_computed": ()}
         if cfg.hc_mult > 1:
             names.update(hc_passes=(), hc_rowsum_dev_ppm=())
         return names
@@ -947,7 +993,8 @@ class _MoonlightServingModel(ServingModel):
                "decode_experts_touched":
                    c["experts_touched"] if decode else zero,
                "decode_moe_passes": c["moe_passes"] if decode else zero,
-               "moe_kernel_passes": c["kernel_passes"]}
+               "moe_kernel_passes": c["kernel_passes"],
+               "moe_rows_computed": c["rows_computed"]}
         out.update({name: c[name] for name in c if name.startswith("hc_")})
         return out
 

@@ -1,40 +1,59 @@
 """Grouped SwiGLU: the routed experts' three products as ONE Pallas TPU
-kernel over rows sorted by expert.
+kernel over rows sorted by expert, every expert's rows starting on a row
+tile of their own.
 
-`xs` (R, h) holds the routed rows, expert by expert; `group_sizes` (E,)
-says how many each expert has. For the rows of expert e the kernel
-computes `(silu(x W_gate[e]) * (x W_up[e])) W_down[e]` with float32
-accumulation and a float32 `silu(g) * u`; the (rows, F) intermediate
-never leaves VMEM. Rows past the groups' sum are nobody's and come back
-zero. The weights are read where they lie, (E, h, F), (E, h, F) and
-(E, F, h): no fused or re-laid-out copy exists on either side of the call.
+THE LAYOUT (`routed_positions`, `padded_rows`). The rows are cut into
+tiles of `row_tile`. Expert e's rows lie together, in the order of their
+(token, pick), from row `start[e]` on, where `start` is the running sum
+of the group sizes each rounded UP to the tile: a tile has ONE owner.
+The rows between a group's end and its last tile's are nobody's (they
+hold whatever the caller left there and come back as whatever the
+products make of it: a row of a product depends on its own row alone),
+and so are the tiles past the last group's, which are never visited,
+never written and never to be read. The buffer is static: the routed
+rows rounded up to the tile plus one tile an expert. The positions come
+from COUNTING, not from a sort: a (token, pick)'s row is its group's
+start plus the number of earlier (token, pick)s on the same expert, a
+blocked running count over the tokens (a triangular 0/1 matrix product a
+block, exact in float32, and a masked sum over the blocks' totals). The
+caller lays the rows out once and reads the products back by the same
+positions (models/moonlight.py::_moe).
 
-One algorithm, one tile parameter. The rows are cut into tiles of
-`row_tile`; the grid walks the VISITS, one for every (expert, row tile)
-pair that shares a row, in the order of the rows, so the walk is known
-before the kernel runs (scalar-prefetch operands, computed from
-`group_sizes` by a few XLA operations on E + 1 integers). A visit holds
-its expert's three matrices whole in VMEM, (h, F), (h, F), (F, h): one
+THE KERNEL. For the rows of expert e it computes `(silu(x W_gate[e]) *
+(x W_up[e])) W_down[e]` with float32 accumulation and a float32 `silu(g)
+* u`; the (rows, F) intermediate never leaves VMEM. The weights are read
+where they lie, (E, h, F), (E, h, F) and (E, F, h): no fused or
+re-laid-out copy exists on either side of the call. The grid walks the
+VISITS, `sum(ceil(n_e / row_tile))` of them, one for every tile that has
+an owner, in the order of the rows: visit i is tile i, and its expert is
+known before the kernel runs (scalar-prefetch operands, computed from
+`group_sizes` by a few masked sums on E integers). A visit holds its
+expert's three matrices whole in VMEM, (h, F), (h, F), (F, h): one
 contiguous block each, double-buffered by the pipeline (34.6 MB at
 Moonlight's widths, so `vmem_limit_bytes` is raised), fetched while the
 visit before computes and NOT fetched again while the expert stays the
 same; an expert with no row has no visit, so it costs no DMA and no
-product. The visit computes the whole tile and stores the rows that are
-its expert's (a tile that a group boundary cuts is visited once by each
-side and stays in VMEM between them). After the last expert the rows in
-no group are one more group with no product, whose visits store zeros.
+product. A visit computes its tile and stores it whole: it reads nothing
+of the output, masks nothing, and no tile is visited twice. The grid is
+static (every tile of the buffer); its steps past the walk's count do
+nothing and move nothing.
 
-What differs between the two callers is how many rows an expert has, and
-the static row count says it (`row_tile_for`):
+One algorithm, one tile parameter. What differs between the two callers
+is how many rows an expert has, and the static row count says it
+(`row_tile_for`):
   * few rows, many experts (a decode step: 192 rows over 64 experts):
     the call is a stream of expert weights, a tile is the smallest the
     MXU takes (16 rows: a packed bfloat16 tile), and nearly every visit
     is another expert: the time is the weights' DMA;
-  * many rows an expert (a prompt: 12k-49k rows, 190-770 an expert): the
-    call is bound by the MXU, a tile is 128 rows (one pass of the MXU's
-    own height; at 256 the call is 2-5% faster for the large buckets
-    and 512 is slower, PERF.md, PR 28), and an expert's matrices are
-    fetched once for all its tiles.
+  * many rows an expert (a prompt: 4k-131k rows, 64-2,000 an expert):
+    the call is bound by the MXU, an expert's matrices are fetched once
+    for all its tiles, and a tile is up to 256 rows: a weight tile
+    loaded into the MXU then serves 256 rows and the call runs at
+    80-85% of the FLOP peak on the rows it computes where 128 gave
+    64-72% (PERF.md, PR 37: with ONE owner a tile the larger tile costs
+    half a tile more of nobody's rows an expert and still wins from
+    1,536 tokens of a 2,048 bucket on; 512 does not fit the VMEM the
+    compiler grants at Xing's widths).
 
 Inside a visit the products run in loops over chunks of OUTPUT lanes
 (128 of F for gate and up, `_CHUNK` of h for down): each chunk is a
@@ -54,18 +73,21 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["grouped_swiglu", "row_tile_for"]
+__all__ = ["grouped_swiglu", "row_tile_for", "padded_rows",
+           "routed_positions"]
 
 # the smallest row tile: one packed bfloat16 tile of 16 sublanes
 _MIN_TILE = 16
-# the largest: the MXU's own height; larger tiles compute more rows of
-# other experts and take longer to compile
-_MAX_TILE = 128
+# the largest: two passes of the MXU's height a weight tile; a larger tile
+# computes more rows of nobody (half a tile an expert)
+_MAX_TILE = 256
 _MIB = 1 << 20
 _LANES = 128
 # lanes of h a step of the down product writes: the compile time grows
 # with it, the speed by 1-2% a doubling
 _CHUNK = 256
+# tokens a block of the running count: one triangular product a block
+_COUNT_BLOCK = 256
 
 
 def row_tile_for(rows, groups):
@@ -85,32 +107,69 @@ def _running(v):
     return jnp.sum(jnp.where(i[None, :] <= i[:, None], v[None, :], 0), 1)
 
 
-def _visits(group_sizes, rows, tile):
-    """The walk over (group, row tile) pairs, from `group_sizes` (E,).
-    Group E is the rows in no group. Returns int32 arrays
-    (group (V,), weights (V,), tile (V,), offsets (E + 2,), count (1,))
-    with V = tiles + E static: visit i is rows of `group[i]` inside row
-    tile `tile[i]`, reads the matrices of expert `weights[i]` (the
-    group's own; the last expert's again for group E, so nothing is
-    fetched), and visits past `count` repeat the last one."""
+def padded_rows(rows, groups, tile):
+    """Rows of the buffer that holds `rows` routed rows over `groups`
+    experts in tiles of `tile` (all static): the rows rounded up to the
+    tile, and a tile an expert for the groups' round-ups."""
+    return (-(-rows // tile) + groups) * tile
+
+
+def routed_positions(picks, live, groups, tile):
+    """The layout, by counting. picks (T, k) int32: the experts of each
+    token; live (T,) bool. Returns (pos (T, k) int32, group_sizes
+    (groups,) int32): `pos[t, j]` is the row of (t, j) in a buffer of
+    `padded_rows(T * k, groups, tile)` rows, its group's start (the
+    groups before it, each rounded up to `tile`) plus the number of live
+    (t', j') before (t, j) with the same expert: the order a stable sort
+    by expert gives. A token that is not live counts nowhere and its
+    `pos` is the buffer's length: past every row, so a scatter drops it
+    and a gather must not trust it."""
+    T, k = picks.shape
+    e = jnp.arange(groups, dtype=jnp.int32)
+    hit = (picks[:, :, None] == e) & live[:, None, None]       # (T, k, E)
+    chose = jnp.sum(hit, 1, dtype=jnp.int32)                   # (T, E)
+    # how many earlier tokens chose e: inside a block of tokens a strictly
+    # lower-triangular product (0/1 and counts up to k in bfloat16, the
+    # sums in float32: exact), across blocks a masked sum of their totals
+    B = min(T, _COUNT_BLOCK)
+    nb = -(-T // B)
+    blocks = jnp.pad(chose, ((0, nb * B - T), (0, 0))).reshape(nb, B, groups)
+    i = jnp.arange(B, dtype=jnp.int32)
+    below = (i[:, None] > i[None, :]).astype(jnp.bfloat16)
+    inside = jnp.einsum("ts,bse->bte", below, blocks.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+    totals = jnp.sum(blocks, 1)                                # (nb, E)
+    b = jnp.arange(nb, dtype=jnp.int32)
+    before = jnp.sum(jnp.where((b[None, :] < b[:, None])[:, :, None],
+                               totals[None, :, :], 0), 1)      # (nb, E)
+    group_sizes = jnp.sum(totals, 0)
+    whole = -(-group_sizes // tile) * tile
+    start = _running(whole) - whole
+    base = (inside.astype(jnp.int32) + (before + start)[:, None, :]
+            ).reshape(nb * B, groups)[:T]
+    # a token that names an expert twice (no router does): its earlier picks
+    j = jnp.arange(k, dtype=jnp.int32)
+    twice = jnp.sum((picks[:, :, None] == picks[:, None, :])
+                    & (j[:, None] > j[None, :]), -1, dtype=jnp.int32)
+    pos = jnp.sum(jnp.where(hit, base[:, None, :], 0), -1) + twice
+    return (jnp.where(live[:, None], pos, padded_rows(T * k, groups, tile)),
+            group_sizes)
+
+
+def _visits(group_sizes, tile, visits):
+    """The walk over the tiles that have an owner, from `group_sizes`
+    (E,): int32 arrays (expert (V,), tile (V,), count (1,)) with V =
+    `visits` static. Visit i < count is tile i, whose rows are expert
+    `expert[i]`'s; the steps past `count` repeat the last visit, so they
+    fetch nothing (with no row anywhere that is tile 0 and the last
+    expert, once)."""
     E = group_sizes.shape[0]
-    tiles = -(-rows // tile)
-    V = tiles + E
-    sizes = group_sizes.astype(jnp.int32)
-    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), _running(sizes),
-                               jnp.full((1,), tiles * tile, jnp.int32)])
-    lo, hi = offsets[:-1], offsets[1:]                 # E + 1 groups
-    n = jnp.where(hi > lo, (hi - 1) // tile - lo // tile + 1, 0)
-    upto = _running(n)
+    upto = _running(-(-group_sizes.astype(jnp.int32) // tile))
     count = upto[-1]
-    at = jnp.minimum(jnp.arange(V, dtype=jnp.int32), count - 1)
-    group = jnp.sum(at[:, None] >= upto[None, :], 1, dtype=jnp.int32)
-    of_group = group[:, None] == jnp.arange(E + 1, dtype=jnp.int32)[None, :]
-    # the group's first tile, less the visits before the group's own
-    start = lo // tile - (upto - n)
-    tile_of = at + jnp.sum(jnp.where(of_group, start[None, :], 0), 1)
-    last = jnp.max(jnp.where(sizes > 0, jnp.arange(E, dtype=jnp.int32), 0))
-    return group, jnp.minimum(group, last), tile_of, offsets, count.reshape(1)
+    at = jnp.clip(jnp.arange(visits, dtype=jnp.int32), 0,
+                  jnp.maximum(count - 1, 0))
+    expert = jnp.sum(at[:, None] >= upto[None, :], 1, dtype=jnp.int32)
+    return jnp.minimum(expert, E - 1), at, count.reshape(1)
 
 
 def _dot(a, b):
@@ -122,57 +181,35 @@ def _dot(a, b):
                    preferred_element_type=jnp.float32)
 
 
-def _kernel(group_ref, weights_ref, tile_ref, offsets_ref, count_ref,
-            x_ref, gate_ref, up_ref, down_ref, o_ref, act_ref, *, experts,
-            tile):
+def _kernel(expert_ref, tile_ref, count_ref, x_ref, gate_ref, up_ref,
+            down_ref, o_ref, act_ref):
     from jax.experimental import pallas as pl
 
-    del weights_ref                      # the index maps' alone
-    i = pl.program_id(0)
+    del expert_ref, tile_ref             # the index maps' alone
 
-    @pl.when(i < count_ref[0])
+    @pl.when(pl.program_id(0) < count_ref[0])
     def _visit():
-        g, t = group_ref[i], tile_ref[i]
-        row = t * tile + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
-        mine = (row >= offsets_ref[g]) & (row < offsets_ref[g + 1])
-        # the first visit of a tile finds whatever the buffer held
-        first = (i == 0) | (tile_ref[jnp.maximum(i - 1, 0)] != t)
-
-        def store(y, at):
-            """Rows of this group from y, the others as they were, into
-            the lanes `at` of the tile."""
-            kept = jnp.where(first, jnp.zeros(y.shape, o_ref.dtype),
-                             o_ref[:, at])
-            o_ref[:, at] = jnp.where(mine, y.astype(o_ref.dtype), kept)
-
         # the products by chunks of output lanes (the module docstring)
         h, F = gate_ref.shape
         fc = _LANES if F % _LANES == 0 else F
         hc = _CHUNK if h % _CHUNK == 0 else h
+        x = x_ref[...]
 
-        @pl.when(g < experts)
-        def _product():
-            x = x_ref[...]
+        def f_step(f, _):
+            at = pl.ds(pl.multiple_of(f * fc, fc), fc)
+            gate = _dot(x, gate_ref[:, at])
+            up = _dot(x, up_ref[:, at])
+            act_ref[:, at] = (gate * jax.nn.sigmoid(gate)
+                              * up).astype(act_ref.dtype)
 
-            def f_step(f, _):
-                at = pl.ds(pl.multiple_of(f * fc, fc), fc)
-                gate = _dot(x, gate_ref[:, at])
-                up = _dot(x, up_ref[:, at])
-                act_ref[:, at] = (gate * jax.nn.sigmoid(gate)
-                                  * up).astype(act_ref.dtype)
+        jax.lax.fori_loop(0, F // fc, f_step, None)
+        act = act_ref[...]
 
-            jax.lax.fori_loop(0, F // fc, f_step, None)
-            act = act_ref[...]
+        def h_step(n, _):
+            at = pl.ds(pl.multiple_of(n * hc, hc), hc)
+            o_ref[:, at] = _dot(act, down_ref[:, at]).astype(o_ref.dtype)
 
-            def h_step(n, _):
-                at = pl.ds(pl.multiple_of(n * hc, hc), hc)
-                store(_dot(act, down_ref[:, at]), at)
-
-            jax.lax.fori_loop(0, h // hc, h_step, None)
-
-        @pl.when(g == experts)
-        def _nobody():
-            store(jnp.zeros(o_ref.shape, jnp.float32), slice(None))
+        jax.lax.fori_loop(0, h // hc, h_step, None)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -182,22 +219,22 @@ def _call(xs, w_gate, w_up, w_down, group_sizes, tile, interpret):
 
     R, h = xs.shape
     E, _, F = w_gate.shape
-    walk = _visits(group_sizes, R, tile)
+    walk = _visits(group_sizes, tile, R // tile)
     # two buffers of the three matrices and of the row tile in and out,
     # the activation, and room for the chunks' float32 values
     vmem = (2 * 3 * h * F * jnp.dtype(w_gate.dtype).itemsize
             + (4 * h + F) * tile * jnp.dtype(xs.dtype).itemsize + 8 * _MIB)
-    rows = pl.BlockSpec((tile, h), lambda i, g, w, t, *_: (t[i], 0))
+    rows = pl.BlockSpec((tile, h), lambda i, e, t, *_: (t[i], 0))
     return pl.pallas_call(
-        functools.partial(_kernel, experts=E, tile=tile),
+        _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(walk[0].shape[0],),
+            num_scalar_prefetch=3,
+            grid=(R // tile,),
             in_specs=[
                 rows,
-                pl.BlockSpec((None, h, F), lambda i, g, w, *_: (w[i], 0, 0)),
-                pl.BlockSpec((None, h, F), lambda i, g, w, *_: (w[i], 0, 0)),
-                pl.BlockSpec((None, F, h), lambda i, g, w, *_: (w[i], 0, 0)),
+                pl.BlockSpec((None, h, F), lambda i, e, *_: (e[i], 0, 0)),
+                pl.BlockSpec((None, h, F), lambda i, e, *_: (e[i], 0, 0)),
+                pl.BlockSpec((None, F, h), lambda i, e, *_: (e[i], 0, 0)),
             ],
             out_specs=rows,
             scratch_shapes=[pltpu.VMEM((tile, F), xs.dtype)]),
@@ -210,18 +247,21 @@ def _call(xs, w_gate, w_up, w_down, group_sizes, tile, interpret):
     )(*walk, xs, w_gate, w_up, w_down)
 
 
-def grouped_swiglu(xs, w_gate, w_up, w_down, group_sizes, row_tile=None):
-    """The grouped SwiGLU of rows sorted by expert.
+def grouped_swiglu(xs, w_gate, w_up, w_down, group_sizes, row_tile):
+    """The grouped SwiGLU of rows laid out by `routed_positions`.
 
-    xs: (R, h), the rows of expert 0, then of expert 1, ...; group_sizes:
-    (E,) integers, how many rows each expert has (their sum at most R);
-    w_gate, w_up: (E, h, F), w_down: (E, F, h), in xs's type. Returns
-    (R, h) in xs's type: for a row r of expert e `(silu(xs[r] w_gate[e])
-    * (xs[r] w_up[e])) w_down[e]`, products accumulated in float32, the
-    activation in float32 and rounded to xs's type before the down
-    product; zero for a row in no group. An expert with no row is never
-    read. row_tile: rows a visit computes (a multiple of 16); None takes
-    `row_tile_for(R, E)`.
+    xs: (R, h), R a multiple of `row_tile`: the rows of expert 0, then,
+    from the next whole tile on, of expert 1, ...; group_sizes: (E,)
+    integers, how many rows each expert has (each rounded up to the
+    tile, their sum at most R); w_gate, w_up: (E, h, F), w_down: (E, F,
+    h), in xs's type. Returns (R, h) in xs's type: for a row r of expert
+    e `(silu(xs[r] w_gate[e]) * (xs[r] w_up[e])) w_down[e]`, products
+    accumulated in float32, the activation in float32 and rounded to
+    xs's type before the down product. A row between a group's end and
+    its tile's comes back as the same function of whatever it held; a
+    tile past the last group's is NOT WRITTEN. An expert with no row is
+    never read. row_tile: rows a visit computes (a multiple of 16), the
+    layout's own (`row_tile_for` of the routed row count).
 
     Compiled by Mosaic on a TPU backend, interpreted on the CPU (a test
     facility), an error on any other backend: an interpreted kernel must
@@ -231,9 +271,10 @@ def grouped_swiglu(xs, w_gate, w_up, w_down, group_sizes, row_tile=None):
         raise RuntimeError(
             "grouped_swiglu compiles for TPU (Mosaic) and interprets on "
             f"CPU for tests; the active backend is {platform!r}")
-    tile = row_tile_for(xs.shape[0], w_gate.shape[0]) \
-        if row_tile is None else int(row_tile)
+    tile = int(row_tile)
     if tile % _MIN_TILE:
         raise ValueError(f"row_tile {tile} is no multiple of {_MIN_TILE}")
+    if xs.shape[0] % tile:
+        raise ValueError(f"{xs.shape[0]} rows are no whole tiles of {tile}")
     return _call(xs, w_gate, w_up, w_down, group_sizes, tile,
                  platform == "cpu")

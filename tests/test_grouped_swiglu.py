@@ -1,7 +1,9 @@
-"""The grouped SwiGLU kernel (ops/grouped_swiglu.py), interpreted on the
-CPU at tiny widths, against a float32 loop over the experts in numpy (the
-reference's way: benchmarks/reference/moonlight_ref.py computes every
-expert apart), and compiled at Moonlight's widths for a described v5e.
+"""The grouped SwiGLU kernel and its layout (ops/grouped_swiglu.py):
+positions by counting against a stable sort, the kernel interpreted on
+the CPU at tiny widths against a float32 loop over the experts in numpy
+(the reference's way: benchmarks/reference/moonlight_ref.py computes
+every expert apart), and compiled at the three expert models' widths for
+a described v5e.
 
 Tolerance: both sides are float32 (conftest sets the highest matmul
 precision) and differ by the order of a 48- or 64-term sum of products of
@@ -27,11 +29,29 @@ def _weights(seed):
             rng.normal(0, 0.2, (E, F, H)).astype(np.float32))
 
 
-def _expert_loop(xs, w_gate, w_up, w_down, sizes):
-    out = np.zeros_like(xs)
+def _starts(sizes, tile):
+    """Where each group starts: the groups before it, whole tiles each."""
+    whole = -(-np.asarray(sizes) // tile) * tile
+    return np.cumsum(whole) - whole
+
+
+def _laid_out(rows, sizes, tile, fill):
+    """(xs in the kernel's layout, the rows' places): `rows` (sum of
+    sizes, H) sorted by expert go to their groups' tiles; every other
+    row of the buffer holds `fill`."""
+    xs = np.full((gs.padded_rows(len(rows), E, tile), H), fill, np.float32)
+    place = np.concatenate(
+        [start + np.arange(n) for start, n in zip(_starts(sizes, tile), sizes)]
+    ).astype(np.int64)
+    xs[place] = rows
+    return xs, place
+
+
+def _expert_loop(rows, w_gate, w_up, w_down, sizes):
+    out = np.zeros_like(rows)
     start = 0
     for e, n in enumerate(sizes):
-        x = xs[start:start + n]
+        x = rows[start:start + n]
         g = x @ w_gate[e]
         out[start:start + n] = (g / (1.0 + np.exp(-g)) * (x @ w_up[e])) \
             @ w_down[e]
@@ -39,79 +59,132 @@ def _expert_loop(xs, w_gate, w_up, w_down, sizes):
     return out
 
 
-# (rows, rows of each expert, the row tiles to run)
+# (routed rows, rows of each expert, the row tiles to run)
 CASES = {
-    "an_expert_with_no_row": (24, [3, 0, 5, 1, 0, 7], [16]),
+    "an_expert_with_no_row": (16, [3, 0, 5, 1, 0, 7], [16]),
     "an_expert_with_every_row": (48, [0, 0, 48, 0, 0, 0], [16]),
-    "rows_in_no_group_come_back_zero": (64, [2, 1, 0, 4, 3, 1], [16]),
-    "no_row_in_any_group": (20, [0] * E, [16]),
+    "rows_past_a_groups_end_are_poison": (11, [2, 1, 0, 4, 3, 1], [16]),
+    "no_row_in_any_group": (0, [0] * E, [16]),
     "a_row_count_that_is_no_multiple_of_the_tile": (37, [9, 4, 0, 11, 6, 7],
                                                     [16]),
-    "a_group_boundary_inside_a_tile": (32, [5, 6, 5, 6, 5, 5], [16]),
-    "few_rows_and_many_rows_tilings_agree": (200, [40, 0, 71, 3, 60, 20],
+    "a_group_of_several_tiles_and_a_part": (70, [5, 38, 5, 6, 11, 5], [16]),
+    "few_rows_and_many_rows_tilings_agree": (194, [40, 0, 71, 3, 60, 20],
                                              [16, 64, 128]),
 }
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_grouped_swiglu_against_the_expert_loop(case):
-    rows, sizes, tiles = CASES[case]
+    n, sizes, tiles = CASES[case]
+    assert n == sum(sizes)
     w_gate, w_up, w_down = _weights(1)
-    xs = np.random.default_rng(2).normal(0, 1, (rows, H)).astype(np.float32)
-    want = _expert_loop(xs, w_gate, w_up, w_down, sizes)
-    # an expert with no row is never read: its weights are poison
-    for e, n in enumerate(sizes):
-        if n == 0:
+    rows = np.random.default_rng(2).normal(0, 1, (n, H)).astype(np.float32)
+    want = _expert_loop(rows, w_gate, w_up, w_down, sizes)
+    # an expert with no row is never read: its weights are poison; and so
+    # is every row of the buffer that is nobody's (a product's row reads
+    # its own row alone)
+    for e, size in enumerate(sizes):
+        if size == 0:
             w_gate[e] = w_up[e] = w_down[e] = np.nan
-    got = [np.asarray(gs.grouped_swiglu(
-        jnp.asarray(xs), jnp.asarray(w_gate), jnp.asarray(w_up),
-        jnp.asarray(w_down), jnp.asarray(sizes, jnp.int32), row_tile=tile))
-        for tile in tiles]
+    got = []
+    for tile in tiles:
+        xs, place = _laid_out(rows, sizes, tile, np.nan)
+        y = np.asarray(gs.grouped_swiglu(
+            jnp.asarray(xs), jnp.asarray(w_gate), jnp.asarray(w_up),
+            jnp.asarray(w_down), jnp.asarray(sizes, jnp.int32), tile))
+        assert y.shape == xs.shape
+        got.append(y[place])
     for y in got:
         np.testing.assert_allclose(y, want, atol=ATOL)
-        assert not y[sum(sizes):].any()          # nobody's rows: zero
     for y in got[1:]:
         np.testing.assert_allclose(y, got[0], atol=ATOL)
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_the_walk_visits_every_shared_pair_once_and_no_untouched_expert(
+def test_the_walk_visits_whole_tiles_of_one_expert_and_no_untouched_expert(
         case):
-    """The scalar-prefetch walk itself: the (group, tile) pairs are
-    exactly those that share a row, in the rows' order; the weights a
-    visit names are a touched expert's (so an untouched one costs no
-    DMA: the block index never names it), and change only when the
-    group does."""
-    rows, sizes, tiles = CASES[case]
+    """The scalar-prefetch walk itself: visit i is tile i, every row of
+    it inside ONE group's whole tiles; the visits number the sum of the
+    groups' ceilings; the weights a visit names are a touched expert's
+    (so an untouched one costs no DMA: the block index never names it)
+    and change only when the group does; the steps past the count repeat
+    the last visit."""
+    n, sizes, tiles = CASES[case]
     for tile in tiles:
-        group, weights, tile_of, offsets, count = (
+        steps = gs.padded_rows(n, E, tile) // tile
+        expert, tile_of, count = (
             np.asarray(a) for a in gs._visits(jnp.asarray(sizes, jnp.int32),
-                                              rows, tile))
-        n_tiles = -(-rows // tile)
-        assert group.shape == (n_tiles + E,)
-        bounds = np.concatenate([[0], np.cumsum(sizes), [n_tiles * tile]])
-        np.testing.assert_array_equal(offsets, bounds)
-        want = [(g, t) for g in range(E + 1) for t in range(n_tiles)
-                if max(bounds[g], t * tile) < min(bounds[g + 1],
-                                                  (t + 1) * tile)]
-        n = int(count[0])
-        assert list(zip(group[:n], tile_of[:n])) == want
-        # the visits past the count repeat the last: nothing is fetched
-        assert (group[n:] == group[n - 1]).all()
-        assert (tile_of[n:] == tile_of[n - 1]).all()
-        touched = [e for e in range(E) if sizes[e]]
-        if touched:
-            assert set(weights) <= set(touched)
-            assert (weights[:n][group[:n] < E] == group[:n][group[:n] < E]
-                    ).all()
-            assert (weights[group == E] == touched[-1]).all()
+                                              tile, steps))
+        assert expert.shape == tile_of.shape == (steps,)
+        ceilings = [-(-size // tile) for size in sizes]
+        v = int(count[0])
+        assert v == sum(ceilings) <= steps
+        np.testing.assert_array_equal(tile_of[:v], np.arange(v))
+        starts = _starts(sizes, tile)
+        for i in range(v):
+            e = expert[i]
+            assert sizes[e] > 0                       # a touched expert
+            assert starts[e] <= i * tile              # one owner
+            assert (i + 1) * tile <= starts[e] + ceilings[e] * tile
+        assert (np.diff(expert[:v]) >= 0).all()
+        # the steps past the count repeat the last: nothing is fetched
+        if v:
+            assert (expert[v:] == expert[v - 1]).all()
+            assert (tile_of[v:] == v - 1).all()
+        else:
+            assert not tile_of.any() and len(set(expert)) == 1
+
+
+# (tokens, picks a token, experts, tile, how the picks are drawn)
+LAYOUTS = {
+    "dead_rows": (23, 2, 8, 16, "dead"),
+    "empty_experts": (19, 2, 8, 16, "few"),
+    "every_pick_on_one_expert": (21, 3, 8, 16, "one"),
+    "rows_no_multiple_of_the_tile": (37, 3, 8, 16, "any"),
+    "k4": (300, 4, 16, 64, "dead"),
+    "k6": (700, 6, 64, 128, "dead"),
+    "k8": (48, 8, 64, 16, "any"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LAYOUTS))
+def test_positions_by_counting_are_the_stable_sorts_order(case):
+    """`routed_positions` against numpy's stable sort by expert: the
+    same order inside a group, every group from a whole tile on, a dead
+    token nowhere (its position is past the buffer)."""
+    T, k, experts, tile, how = LAYOUTS[case]
+    rng = np.random.default_rng(T)
+    if how == "one":                     # no router does: counted all the same
+        picks = np.full((T, k), 5)
+    else:
+        among = experts // 2 if how == "few" else experts
+        picks = np.stack([rng.permutation(among)[:k] for _ in range(T)])
+    live = rng.random(T) < 0.7 if how == "dead" else np.ones(T, bool)
+    pos, sizes = gs.routed_positions(
+        jnp.asarray(picks, jnp.int32), jnp.asarray(live), experts, tile)
+    pos, sizes = np.asarray(pos).reshape(-1), np.asarray(sizes)
+    flat = np.where(live[:, None], picks, experts).reshape(-1)
+    order = np.argsort(flat, kind="stable")
+    want_sizes = np.bincount(flat, minlength=experts + 1)[:experts]
+    np.testing.assert_array_equal(sizes, want_sizes)
+    whole = -(-want_sizes // tile) * tile
+    buffer = gs.padded_rows(T * k, experts, tile)
+    assert whole.sum() <= buffer and buffer % tile == 0
+    first = np.cumsum(want_sizes) - want_sizes     # in the sorted order
+    want = np.full(T * k, buffer)
+    for r, at in enumerate(order[:want_sizes.sum()]):
+        e = flat[at]
+        want[at] = (np.cumsum(whole) - whole)[e] + r - first[e]
+    np.testing.assert_array_equal(pos, want)
 
 
 @pytest.mark.parametrize("rows,groups,tile", [
     (192, 64, 16),        # a decode step: 32 slots x 6 experts a token
     (24, 64, 16),         # a chat-sized step
-    (2048 * 6, 64, 128),  # the smallest prompt bucket
-    (8192 * 6, 64, 128),  # the largest
+    (512 * 8, 64, 64),    # Mellum's smallest prompt bucket
+    (2048 * 4, 64, 128),  # Xing's
+    (2048 * 6, 64, 256),  # Moonlight's
+    (16384 * 8, 64, 256), # the largest
     (24, 8, 16), (400, 8, 64),
 ])
 def test_the_row_tile_follows_the_static_row_count(rows, groups, tile):
@@ -122,14 +195,14 @@ def test_a_backend_that_is_neither_is_an_error(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
     with pytest.raises(RuntimeError, match="grouped_swiglu compiles for TPU"):
         gs.grouped_swiglu(jnp.zeros((16, H)), *map(jnp.asarray, _weights(0)),
-                          jnp.zeros((E,), jnp.int32))
+                          jnp.zeros((E,), jnp.int32), 16)
 
 
 # -- compiled for the chip, without the chip ----------------------------------
-# Moonlight's widths, both regimes: what interpret mode cannot refuse (a
-# slice off the tiling, more VMEM than the limit asked for). The topology
-# is described inside a fixture, never at import (one process may hold
-# libtpu; see the on-chip-measurement guide).
+# The three expert models' widths, both regimes: what interpret mode
+# cannot refuse (a slice off the tiling, more VMEM than the limit asked
+# for). The topology is described inside a fixture, never at import (one
+# process may hold libtpu; see the on-chip-measurement guide).
 
 @pytest.fixture(scope="module")
 def one_chip():
@@ -143,17 +216,25 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("rows", [192, 2048 * 6, 8192 * 6])
-def test_compiles_at_moonlights_widths_for_a_described_v5e(one_chip, rows):
-    experts, h, f = 64, 2048, 1408
+@pytest.mark.parametrize("h,f,rows", [
+    (2048, 1408, 192), (2048, 1408, 2048 * 6), (2048, 1408, 8192 * 6),
+    (3584, 1024, 16384 * 4),              # Xing's largest bucket
+    (2304, 896, 16384 * 8),               # Mellum's
+], ids=["moonlight_step", "moonlight_2048", "moonlight_8192", "xing_16384",
+        "mellum_16384"])
+def test_compiles_at_the_models_widths_for_a_described_v5e(one_chip, h, f,
+                                                           rows):
+    experts = 64
+    tile = gs.row_tile_for(rows, experts)
 
     def shape(*dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     compiled = gs._call.lower(
-        shape(rows, h), shape(experts, h, f), shape(experts, h, f),
-        shape(experts, f, h), shape(experts, dtype=jnp.int32),
-        tile=gs.row_tile_for(rows, experts), interpret=False).compile()
+        shape(gs.padded_rows(rows, experts, tile), h), shape(experts, h, f),
+        shape(experts, h, f), shape(experts, f, h),
+        shape(experts, dtype=jnp.int32), tile=tile,
+        interpret=False).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     # the weights go in where they lie: nothing of their size is made

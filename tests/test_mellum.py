@@ -504,12 +504,16 @@ def test_grouped_paged_kernel_against_mha_reference(mode):
 # `kernel.flash_causal_rows*` digests on its own final tree: the forward's
 # grid became a walk of visits read from scalar-prefetch operands (one visit
 # at 256 rows, ten at 2,048), so both jaxprs changed; the six programs (the
-# CPU's gather path) and the small-path `fwd_bwd` did not
+# CPU's gather path) and the small-path `fwd_bwd` did not. PR 37 re-pinned the
+# four programs of the latent block on its own final tree: their expert layer
+# lays the routed rows out by counting (models/moonlight.py::_moe: no sort, one
+# scatter, gathers by position), so every program that has one changed; the
+# GPT pair and the five kernels did not
 PARENT = {
-    "moonlight.prefill": "2b751ed92e59903c",
-    "moonlight.decode": "3db640a0cdcf93aa",
-    "xing.prefill": "75e106810d00021d",
-    "xing.decode": "117b45afda26fcdf",
+    "moonlight.prefill": "73441c244b67d9a4",
+    "moonlight.decode": "be9095bd33244135",
+    "xing.prefill": "c2ce3271710703a0",
+    "xing.decode": "7c7f5e056a7fe97a",
     "gpt.prefill": "3ac9276678295d6b",
     "gpt.decode": "0f3268c7f5194142",
     "kernel.paged_attention": "aee9f347c35d6388",

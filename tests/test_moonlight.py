@@ -159,7 +159,11 @@ def test_router_matches_reference_and_bias_moves_picks_not_weights(params):
 
 
 def test_grouped_product_against_expert_loop(params):
-    """An expert with no token, one with every token, rows in no group."""
+    """An expert with no token, one with every token, rows of nobody:
+    the layout by counting, the product over it, and the rows read back
+    by the same positions."""
+    from paddle_tpu.ops.grouped_swiglu import (padded_rows, routed_positions,
+                                               row_tile_for)
     lp = params["layers"][2]
     rng = np.random.default_rng(11)
     T, E = 12, CFG.n_routed_experts
@@ -169,45 +173,55 @@ def test_grouped_product_against_expert_loop(params):
     second = rng.choice([0, 1, 3, 4, 6, 7], T)
     picks = np.stack([np.full(T, 5), second], -1)
     live = np.arange(T) < T - 2
-    flat = np.where(live[:, None], picks, E).reshape(-1)
-    order = np.argsort(flat, kind="stable")
-    sizes = np.bincount(flat, minlength=E + 1)[:E].astype(np.int32)
+    tile = row_tile_for(T * 2, E)
+    pos, sizes = routed_positions(jnp.asarray(picks, jnp.int32),
+                                  jnp.asarray(live), E, tile)
+    pos, sizes = np.asarray(pos), np.asarray(sizes)
     assert sizes[5] == T - 2 and sizes[2] == 0
-    ys = ml.grouped_experts(lp, x[order // 2], jnp.asarray(sizes))
-    back = np.asarray(ys)[np.argsort(order)].reshape(T, 2, -1)
-    for t in range(T):
+    rows = padded_rows(T * 2, E, tile)
+    assert (pos[~live] == rows).all() and (pos[live] < rows).all()
+    assert len(set(pos[live].reshape(-1))) == (T - 2) * 2     # a row each
+    # nobody's rows hold poison: a product's row reads its own row alone
+    xs = np.full((rows, CFG.hidden), np.nan, np.float32)
+    xs[pos[live]] = np.asarray(x)[live][:, None, :]
+    ys = np.asarray(ml.grouped_experts(lp, jnp.asarray(xs),
+                                       jnp.asarray(sizes), tile))
+    for t in range(T - 2):
         for j in range(2):
             e = picks[t, j]
             want = ref._swiglu(x[t], lp["w_gate"][e], lp["w_up"][e],
                                lp["w_down"][e])
-            if live[t]:
-                np.testing.assert_allclose(back[t, j], want, atol=2e-5)
-            else:
-                assert not back[t, j].any()      # nobody's row: zero
+            np.testing.assert_allclose(ys[pos[t, j]], want, atol=2e-5)
 
 
 def test_no_dense_product_and_no_dropped_token(params, monkeypatch):
-    """The rows that enter the expert product are tokens x k exactly."""
+    """The rows that enter the expert product are tokens x k exactly, in
+    a buffer of the static size the layout names."""
+    from paddle_tpu.ops.grouped_swiglu import padded_rows, row_tile_for
     seen = []
     real = ml.grouped_experts
 
-    def spy(lp, xs, sizes):
-        seen.append((xs.shape[0], int(np.asarray(sizes).sum())))
-        return real(lp, xs, sizes)
+    def spy(lp, xs, sizes, tile):
+        seen.append((xs.shape[0], int(np.asarray(sizes).sum()), tile))
+        return real(lp, xs, sizes, tile)
 
     monkeypatch.setattr(ml, "grouped_experts", spy)
     T = 23
     ml.forward_logits(params, CFG, jnp.asarray(tokens_of(1, T)))
-    k = CFG.experts_per_tok
-    assert seen == [(T * k, T * k)] * (CFG.layers - CFG.first_k_dense)
+    k, E = CFG.experts_per_tok, CFG.n_routed_experts
+    tile = row_tile_for(T * k, E)
+    assert seen == [(padded_rows(T * k, E, tile), T * k, tile)] \
+        * (CFG.layers - CFG.first_k_dense)
 
 
 def test_the_expert_kernel_is_the_same_layer_and_is_counted(
         params, monkeypatch):
     """The expert layer with the grouped kernel as its product (the
     TPU's path, interpreted here) against the `ragged_dot` fallback on
-    the same tokens, two of them not live: the same output, and
-    `kernel_passes` counts the pass on the one and not on the other."""
+    the same tokens, two of them not live: the same output;
+    `kernel_passes` counts the pass and `rows_computed` the visits'
+    whole tiles on the one and not on the other."""
+    from paddle_tpu.ops.grouped_swiglu import row_tile_for
     lp = params["layers"][1]
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.normal(0, 1, (21, CFG.hidden)), jnp.float32)
@@ -222,6 +236,89 @@ def test_the_expert_kernel_is_the_same_layer_and_is_counted(
     assert int(fallback["kernel_passes"]) == 0
     np.testing.assert_array_equal(kernel["expert_tokens"],
                                   fallback["expert_tokens"])
+    tile = row_tile_for(21 * CFG.experts_per_tok, CFG.n_routed_experts)
+    sizes = np.asarray(kernel["expert_tokens"])
+    assert int(kernel["rows_computed"]) == (-(-sizes // tile)).sum() * tile
+    assert int(kernel["rows_computed"]) >= sizes.sum() == 19 * 2
+    assert int(fallback["rows_computed"]) == 0
+
+
+def _moe_by_experts(cfg, lp, x, live):
+    """The expert layer one token and one expert at a time, float32, in
+    numpy: the picks and weights are `route`'s (the router has its own
+    tests), everything after them is recomputed."""
+    picks, w = (np.asarray(a) for a in ml.route(cfg, lp, x))
+    x = np.asarray(x, np.float32)
+
+    def swiglu(row, gate, up, down):
+        g = row @ np.asarray(gate, np.float32)
+        return (g / (1.0 + np.exp(-g)) * (row @ np.asarray(up, np.float32))) \
+            @ np.asarray(down, np.float32)
+
+    out = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        if live[t]:
+            for j, e in enumerate(picks[t]):
+                out[t] += w[t, j] * swiglu(x[t], lp["w_gate"][e],
+                                           lp["w_up"][e], lp["w_down"][e])
+        if cfg.n_shared_experts:
+            out[t] += swiglu(x[t], lp["shared_gate"], lp["shared_up"],
+                             lp["shared_down"])
+    return out
+
+
+@pytest.mark.parametrize("product", ["ragged_dot", "grouped_swiglu_kernel"])
+@pytest.mark.parametrize("shared", [1, 0], ids=["shared", "no_shared"])
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_the_expert_layer_against_a_loop_over_experts(
+        params, monkeypatch, scoring, shared, product):
+    """`_moe` whole (layout by counting, product, weighted sum by the
+    same positions) under both scoring rules, with and without a shared
+    expert, through the interpreted kernel and through `ragged_dot`; the
+    last three tokens are not live and get the shared expert alone."""
+    import types
+    cfg = types.SimpleNamespace(
+        experts_per_tok=3, n_routed_experts=CFG.n_routed_experts,
+        n_shared_experts=shared, router_scoring=scoring,
+        routed_scaling_factor=CFG.routed_scaling_factor)
+    lp = params["layers"][1]
+    x = jnp.asarray(np.random.default_rng(17).normal(0, 1, (29, CFG.hidden)),
+                    jnp.float32)
+    live = np.arange(29) < 26
+    monkeypatch.setattr(ml, "expert_product_path", lambda lp: product)
+    got, counters = ml._moe(cfg, lp, x, jnp.asarray(live))
+    np.testing.assert_allclose(np.asarray(got),
+                               _moe_by_experts(cfg, lp, x, live), atol=2e-5)
+    assert int(counters["expert_tokens"].sum()) == 26 * 3
+    if not shared:
+        assert not np.asarray(got)[~live].any()
+
+
+def _primitives(jaxpr):
+    """The names of every primitive in a jaxpr and in the jaxprs it holds."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names |= _primitives(sub)
+    return names
+
+
+def test_the_expert_layer_sorts_nothing(params):
+    """The layout comes from counting: no sort (an `argsort` is one) in
+    the layer's jaxpr, at a prompt's row count or at a step's; what
+    moves the rows is one scatter of integers and gathers."""
+    lp = params["layers"][1]
+    for T in (8, 700):
+        names = _primitives(jax.make_jaxpr(
+            lambda x, live: ml._moe(CFG, lp, x, live))(
+                jnp.zeros((T, CFG.hidden)), jnp.ones((T,), bool)).jaxpr)
+        assert not [n for n in names if "sort" in n]
+        assert {"gather", "scatter", "ragged_dot_general"} <= names \
+            or {"gather", "scatter", "ragged_dot"} <= names
 
 
 def test_absorbed_step_matches_expanded(params):
@@ -518,6 +615,7 @@ def test_engine_serves_moonlight_and_counts_without_another_sync(
     assert stats["decode_router_tokens"] == 4 * 6 * n_moe
     assert stats["decode_moe_passes"] <= stats["dispatches"] * 4 * n_moe
     assert stats["moe_kernel_passes"] == 0       # the CPU: `ragged_dot` ran
+    assert stats["moe_rows_computed"] == 0       # so the kernel computed none
     for prompt, req in zip(prompts, reqs):
         seq = list(prompt) + list(req.tokens)
         rows = reference_logits(params, seq)[len(prompt) - 1:-1]
