@@ -179,6 +179,139 @@ def phase_kernels(seqs=(512, 1024), batch=2, heads=12, head_dim=64):
 
 
 # ---------------------------------------------------------------------------
+# phase 2b: the kernels of a chip's share of an expert-parallel model
+# ---------------------------------------------------------------------------
+
+def phase_share_kernels(heads=128, kv_heads=8, head_dim=128, window=4096,
+                        block=128, hidden=4096, width=4096, experts=128,
+                        held=16, picks=8, shared=4, rows=8192, tokens=512):
+    """command-a-plus-05-2026's shapes against `jax.numpy` twins: the
+    grouped paged kernel at heads / kv_heads queries a KV head over a ring
+    of window / block + 1 blocks (slots below, at and beyond the window,
+    one frozen); the flash forward's band of `window` over `rows` rows
+    with shared KV heads; and the expert layer that holds `held` of
+    `experts` experts (`models/moonlight._moe`: the sliced kernel where an
+    expert's matrices do not fit VMEM whole) at a step's and a prompt's
+    row counts."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models import moonlight as ml
+    from paddle_tpu.ops.flash_attention import flash_causal_rows
+    from paddle_tpu.ops.paged_attention import paged_attention
+
+    facts = {}
+    d, group = head_dim, heads // kv_heads
+    ring = -(-window // block) + 1
+    ks = iter(jax.random.split(jax.random.PRNGKey(38), 16))
+    normal = lambda *shape: jax.random.normal(next(ks), shape, jnp.float32)
+
+    # the grouped paged kernel over a ring
+    ts = jnp.asarray([block - 3, window - 1, window + block + 7,
+                      2 * window + 5 * block + 1, 3 * block], jnp.int32)
+    done = jnp.asarray([False, False, False, False, True])
+    S = ts.shape[0]
+    arena = (0.5 * normal(1, 1, S * ring + 1, kv_heads, block, 2 * d)
+             ).astype(jnp.bfloat16)
+    table = 1 + jnp.arange(S * ring, dtype=jnp.int32).reshape(S, ring)
+    q, k, v = ((0.5 * normal(S, n, d)).astype(jnp.bfloat16)
+               for n in (heads, kv_heads, kv_heads))
+    lo = jnp.maximum(ts - window + 1, 0)
+    got, after = jax.jit(lambda *a: paged_attention(*a[:4], 0, *a[4:7],
+                                                    lo=a[7]))(
+        q, k, v, arena, table, ts, done, lo)
+    after32 = np.asarray(after.astype(jnp.float32))
+    worst = 0.0
+    for s in range(S - 1):
+        at = np.arange(int(lo[s]), int(ts[s]) + 1)
+        blocks = np.asarray(table)[s, (at // block) % ring]
+        kv = after32[0, 0, blocks, :, at % block]           # (L, kv, 2d)
+        qs = np.asarray(q[s].astype(jnp.float32)).reshape(kv_heads, group, d)
+        sc = np.einsum("kgd,lkd->kgl", qs, kv[..., :d]) / np.sqrt(d)
+        pr = np.exp(sc - sc.max(-1, keepdims=True))
+        want = np.einsum("kgl,lkd->kgd", pr / pr.sum(-1, keepdims=True),
+                         kv[..., d:]).reshape(heads, d)
+        worst = max(worst, _rel_err(got[s], want))
+    _require(float(jnp.abs(got[S - 1].astype(jnp.float32)).max()) == 0.0,
+             "share_kernels: a frozen slot's context is not zero")
+    _require(worst <= KERNEL_REL_TOL,
+             f"share_kernels: the grouped paged kernel ({group} queries a KV "
+             f"head, ring of {ring}) is off its twin by {worst:.3e}")
+    facts.update(paged_group=group, paged_ring=ring,
+                 paged_rel_err=worst)
+
+    # the banded flash forward
+    q, k, v = ((0.5 * normal(rows, n, d)).astype(jnp.bfloat16)
+               for n in (heads, kv_heads, kv_heads))
+    scale = d ** -0.5
+    got = jax.jit(lambda q, k, v: flash_causal_rows(
+        q, k, v, scale, window=window))(q, k, v)
+    check = np.asarray([0, window - 1, window, rows // 2 + 3, rows - 1])
+    i, j = check[:, None], np.arange(rows)[None, :]
+    mask = jnp.asarray((j <= i) & (i - j < window))
+    with jax.default_matmul_precision("highest"):
+        kk, vv = (jnp.repeat(t.astype(jnp.float32), group, 1) for t in (k, v))
+        sc = jnp.einsum("qnd,knd->nqk", q[check].astype(jnp.float32), kk) * scale
+        pr = jax.nn.softmax(jnp.where(mask[None], sc, -jnp.inf), -1)
+        want = jnp.einsum("nqk,knd->qnd", pr, vv)
+    err = _rel_err(got[check], want)
+    _require(err <= KERNEL_REL_TOL,
+             f"share_kernels: the flash band of {window} over {rows} rows is "
+             f"off its twin by {err:.3e}")
+    facts.update(flash_rows=rows, flash_band_rel_err=err)
+
+    # the expert layer of a share
+    cfg = types.SimpleNamespace(
+        experts_per_tok=picks, n_routed_experts=experts,
+        n_shared_experts=shared, router_scoring="sigmoid",
+        experts_held=(held, held), shared_expert_combination="average")
+    w = lambda *shape: (0.02 * normal(*shape)).astype(jnp.bfloat16)
+    lp = {"router": w(hidden, experts), "w_gate": w(held, hidden, width),
+          "w_up": w(held, hidden, width), "w_down": w(held, width, hidden),
+          "shared_gate": w(hidden, shared * width),
+          "shared_up": w(hidden, shared * width),
+          "shared_down": w(shared * width, hidden)}
+    moe = jax.jit(lambda lp, x, live: ml._moe(cfg, lp, x, live))
+
+    def twin(lp, x, live):
+        """Every held expert on every token, weighted by the pick's weight
+        (zero where it was not picked), the shared experts' mean. The
+        weights are ARGUMENTS: as constants 2 GB of them cost the compiler
+        tens of GB of the host's memory."""
+        x32 = x.astype(jnp.float32)
+        pk, pw = ml.route(cfg, lp, x)
+        f32 = lambda a: a.astype(jnp.float32)
+        y = ml._swiglu(x32, f32(lp["shared_gate"]), f32(lp["shared_up"]),
+                       f32(lp["shared_down"])) / shared
+        for e in range(held):
+            we = jnp.sum(jnp.where(pk == held + e, pw, 0), -1)
+            y = y + jnp.where(live, we, 0)[:, None] * ml._swiglu(
+                x32, f32(lp["w_gate"][e]), f32(lp["w_up"][e]),
+                f32(lp["w_down"][e]))
+        return y
+
+    for n in (32, tokens):
+        x = normal(n, hidden).astype(jnp.bfloat16)
+        live = jnp.arange(n) % 7 != 3
+        got, counters = moe(lp, x, live)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(twin)(lp, x, live)
+        err = _rel_err(got, want)
+        _require(err <= KERNEL_REL_TOL,
+                 f"share_kernels: the expert layer holding {held} of "
+                 f"{experts} at {n} tokens is off its twin by {err:.3e}")
+        held_picks = int(counters["expert_tokens"].sum())
+        _require(0 < held_picks < int(live.sum()) * picks,
+                 f"share_kernels: {held_picks} held picks of "
+                 f"{int(live.sum()) * picks}")
+        facts[f"moe_rel_err_{n}"] = err
+        facts[f"moe_held_picks_{n}"] = held_picks
+    facts["moe_path"] = ml.expert_product_path(lp)
+    return facts
+
+
+# ---------------------------------------------------------------------------
 # phase 3: train
 # ---------------------------------------------------------------------------
 
@@ -556,6 +689,7 @@ def main():
         prefill_buckets=(64, 256), max_len=1024)
 
     run("kernels", phase_kernels)
+    run("share_kernels", phase_share_kernels)
     # the published context (tiled kernels), then s=512 (single-pass)
     long_run = run("train_s1024", phase_train, cfg, batch=8, seq=1024)
     run("train_s512", phase_train, cfg, batch=16, seq=512)

@@ -382,6 +382,36 @@ def _gather_attend(cfg, q, rows, keep):
     return jnp.einsum("skgl,skld->skgd", p, rows[..., d:]).reshape(S, -1, d)
 
 
+def _attend_step(cfg, q, k, v, arena, lg, table, ts, done, lo, kind, path):
+    """One layer's attention of a decode step, with its write: q (S,
+    heads, d), k, v (S, kv_heads, d) of position ts (S,), `arena` the
+    layer's group's, `lg` its plane there, `table` (S, pages) the
+    group's columns of the page table (a window group's a ring), `lo`
+    (S,) the first position attended. `path`: "paged_kernel" or
+    "gather". Returns (o (S, heads, d), the arena)."""
+    import jax.numpy as jnp
+    s_dim, pages = table.shape
+    bs, dtype = arena.shape[4], arena.dtype
+    if path == "paged_kernel":
+        from ..ops.paged_attention import paged_attention
+        return paged_attention(q, k, v, arena, lg, table, ts, done,
+                               lo=None if kind == "full" else lo)
+    page = ts // bs
+    wblk = table[jnp.arange(s_dim), page % pages]
+    if done is not None:
+        wblk = jnp.where(done, 0, wblk)
+    a = arena.at[lg, 0, wblk, :, ts % bs].set(
+        jnp.concatenate([k, v], -1).astype(dtype))
+    rows = _gather_pages(a, lg, table)             # (S, kv, pages*bs, 2d)
+    # entry c of the table holds the one page t in (page - pages, page]
+    # with t % pages == c (a full row: c itself)
+    c = jnp.arange(pages)[None, :]
+    t = page[:, None] - (page[:, None] - c) % pages
+    at = (t[:, :, None] * bs + jnp.arange(bs)).reshape(s_dim, -1)
+    keep = (at >= lo[:, None]) & (at <= ts[:, None])
+    return _gather_attend(cfg, q, rows, keep), a
+
+
 def decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
                       attention=None):
     """One decode step of every slot: tokens, ts (S,), pt (S, P + R).
@@ -401,43 +431,21 @@ def decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
     tables = _tables(cfg, pt, bs)
     if attention is None:
         attention = decode_attention_path(arena)
-    if "paged_kernel" in attention.values():
-        from ..ops.paged_attention import paged_attention
     live = jnp.ones((s_dim,), bool) if done is None else ~done
     lo = {"full": jnp.zeros_like(ts),
           "window": jnp.maximum(ts - cfg.sliding_window + 1, 0)}
-    page = ts // bs
-    slots = jnp.arange(s_dim)
     x = params["wte"][tokens].astype(dtype)
     counters = _zero_counters(cfg)
     rows_attended = {kind: jnp.sum(jnp.where(live, ts - lo[kind] + 1, 0))
                      .astype(jnp.int32) for kind in lo}
     for li, lp in enumerate(params["layers"]):
         kind, lg = cfg.kind(li), cfg.index_in_group(li)
-        table = tables[kind]
-        pages = table.shape[1]
         with jax.named_scope("attn/project"):
             q, k, v = _project(cfg, lp, x, ts, kind)
         with jax.named_scope("attn/" + kind):
-            if attention[kind] == "paged_kernel":
-                o, arenas[kind] = paged_attention(
-                    q, k, v, arenas[kind], lg, table, ts, done,
-                    lo=None if kind == "full" else lo[kind])
-            else:
-                wblk = table[slots, page % pages]
-                if done is not None:
-                    wblk = jnp.where(done, 0, wblk)
-                a = arenas[kind].at[lg, 0, wblk, :, ts % bs].set(
-                    jnp.concatenate([k, v], -1).astype(dtype))
-                arenas[kind] = a
-                rows = _gather_pages(a, lg, table)     # (S, kv, pages*bs, 2d)
-                # entry c of the table holds the one page t in (page -
-                # pages, page] with t % pages == c (a full row: c itself)
-                c = jnp.arange(pages)[None, :]
-                t = page[:, None] - (page[:, None] - c) % pages
-                at = (t[:, :, None] * bs + jnp.arange(bs)).reshape(s_dim, -1)
-                keep = (at >= lo[kind][:, None]) & (at <= ts[:, None])
-                o = _gather_attend(cfg, q, rows, keep)
+            o, arenas[kind] = _attend_step(
+                cfg, q, k, v, arenas[kind], lg, tables[kind], ts, done,
+                lo[kind], kind, attention[kind])
         with jax.named_scope("attn/project"):
             x = x + o.reshape(s_dim, -1).astype(dtype) @ lp["wo"]
         y, counters, _ = _ffn(cfg, lp, x, live, counters)

@@ -100,7 +100,7 @@ from .gpt_decode import _gather_pages, _write_pages
 __all__ = ["MoonlightConfig", "init_params", "forward_logits",
            "prefill_pages", "decode_step_pages",
            "decode_attention_path", "absorbed_attention", "route",
-           "grouped_experts", "expert_product_path", "rope",
+           "held_experts", "grouped_experts", "expert_product_path", "rope",
            "rope_frequencies", "attention_scale", "hc_coefficients",
            "MOONLIGHT_SERVING_MODEL"]
 
@@ -581,11 +581,15 @@ def route(cfg, lp, x):
     float32), by the config's scoring rule (`cfg.router_scoring`; a
     config without the field is "sigmoid"). "sigmoid": scores are
     sigmoid(x W_g) in float32; the k largest of
-    score + correction bias are picked (one group, so no group stage);
-    the weights are the scores WITHOUT the bias at the picks, over their
-    sum + 1e-20, times routed_scaling_factor. "softmax": scores are
+    score + correction bias are picked (one group, so no group stage; a
+    layer without `router_bias` has no bias: the scores themselves are
+    ranked); the weights are the scores WITHOUT the bias at the picks,
+    over their sum + 1e-20, times routed_scaling_factor (a config
+    without the field: 1, no factor). "softmax": scores are
     softmax(x W_g) over the experts in float32, the k largest are picked
-    and their scores divided by their sum (no bias, no factor)."""
+    and their scores divided by their sum (no bias, no factor). The
+    router is as wide as the MODEL has experts, whichever of them this
+    chip holds (`held_experts`)."""
     import jax
     import jax.numpy as jnp
     logits = jnp.dot(x.astype(jnp.float32), lp["router"].astype(jnp.float32),
@@ -595,11 +599,30 @@ def route(cfg, lp, x):
                                  cfg.experts_per_tok)
         return picks.astype(jnp.int32), w / w.sum(-1, keepdims=True)
     scores = jax.nn.sigmoid(logits)
-    _, picks = jax.lax.top_k(scores + lp["router_bias"].astype(jnp.float32),
-                             cfg.experts_per_tok)
-    w = jnp.take_along_axis(scores, picks, -1)
-    w = w / (w.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
+    if "router_bias" in lp:
+        _, picks = jax.lax.top_k(
+            scores + lp["router_bias"].astype(jnp.float32),
+            cfg.experts_per_tok)
+        w = jnp.take_along_axis(scores, picks, -1)
+    else:
+        w, picks = jax.lax.top_k(scores, cfg.experts_per_tok)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    factor = getattr(cfg, "routed_scaling_factor", 1.0)
+    if factor != 1.0:
+        w = w * factor
     return picks.astype(jnp.int32), w
+
+
+def held_experts(cfg):
+    """(first, count): the routed experts this chip holds, ids first ..
+    first + count - 1 of `cfg.n_routed_experts` (`cfg.experts_held`; a
+    config without the field, or None, holds them all). The chip's share
+    of an expert-parallel deployment: the router scores every expert of
+    the model, the layer lays out and computes the picks that fall on
+    its own, and what the experts held elsewhere would add is left out
+    (no code stands in for the other chips or their exchange)."""
+    held = getattr(cfg, "experts_held", None)
+    return (0, cfg.n_routed_experts) if held is None else tuple(held)
 
 
 def expert_product_path(lp):
@@ -632,34 +655,43 @@ def grouped_experts(lp, xs, group_sizes, tile):
     return jax.lax.ragged_dot(jax.nn.silu(g) * u, lp["w_down"], whole)
 
 
-def _moe(cfg, lp, x, live):
-    """The expert layer's feed-forward on tokens x (T, h), for every
-    config that names `n_routed_experts`, `experts_per_tok`,
-    `n_shared_experts` (0: no shared expert, none traced) and optionally
-    `router_scoring` (see `route`): this block's and models/mellum's.
-    `live` (T,)
-    bool: rows that are real (a prefill's padding and a frozen slot's
-    ride-along are not: they get no expert and do not count). The routed
-    rows are laid out ONCE, by counting (`routed_positions`): the
-    dispatch gathers them there, the product computes whole tiles of one
-    expert, the weighted sum reads them back by the same positions.
-    Returns (y (T, h), counters)."""
+# A layer that holds `count` of E experts gets T * k * count / E picks on
+# average and T * k at the worst. Its routed buffer is sized for
+# HELD_SLACK times the average (a SECOND STATIC SIZE beside the worst
+# case's), so that dispatch's gather, the kernel's grid and the buffer
+# combine reads out of follow the picks that are held (combine still
+# makes T * k row reads, a clipped one for a pick held elsewhere: the
+# alternatives measured slower or no faster, PERF.md, PR 38); a pass
+# whose held picks do not fit there (their groups, each rounded up to
+# the tile) takes the other branch of a `lax.cond`, the same code over
+# the tokens in E / (count * HELD_SLACK) parts, each of which fits
+# whatever its routing: no pick is ever dropped. Below HELD_SPLIT_FROM
+# picks (a decode step) the worst case is a few hundred rows and the one
+# buffer holds it.
+HELD_SLACK = 2
+HELD_SPLIT_FROM = 4096
+
+
+def _lay_out(lp, x, picks, live, groups, tile, slots, average):
+    """Dispatch and the experts' product over a buffer for `slots` picks
+    (static): picks (T, k) as `routed_positions` takes them, `live` (T,)
+    by token or (T, k) by pick, at most `slots` of them live; `average`
+    (static) how many are expected (T * k where every expert is held),
+    which says whether dispatch places or gathers. Returns (ys, the
+    buffer's rows through their experts, pos (T, k), group_sizes
+    (groups,))."""
     import jax
     import jax.numpy as jnp
-    from ..ops.grouped_swiglu import (padded_rows, routed_positions,
-                                      row_tile_for)
-    T, k, E = x.shape[0], cfg.experts_per_tok, cfg.n_routed_experts
-    tile = row_tile_for(T * k, E)
-    with jax.named_scope("moe/router"):
-        picks, w = route(cfg, lp, x)
+    from ..ops.grouped_swiglu import padded_rows, routed_positions
+    T, k = picks.shape
     with jax.named_scope("moe/dispatch"):
-        # a row that is not live has no position: in no group, never
+        # a pick that is not live has no position: in no group, never
         # moved, never computed
-        pos, group_sizes = routed_positions(picks, live, E, tile)
+        pos, group_sizes = routed_positions(picks, live, groups, tile)
         at = pos.reshape(-1)
         token = jnp.arange(T * k, dtype=jnp.int32) // k
-        rows = padded_rows(T * k, E, tile)
-        if 4 * T * k <= rows:
+        rows = padded_rows(slots, groups, tile)
+        if 4 * average <= rows:
             # a step's few rows in a buffer that is mostly the experts'
             # round-ups: the rows are PLACED (a gather fetches every row
             # of the buffer, ~15 ns a row whoever's it is: PERF.md, PR 37)
@@ -674,22 +706,95 @@ def _moe(cfg, lp, x, live):
             xs = x[source]
     with jax.named_scope("moe/experts"):
         ys = grouped_experts(lp, xs, group_sizes, tile)
+    return ys, pos, group_sizes
+
+
+def _weighted_sum(ys, pos, w, live):
+    """Combine's sum (inside `moe/combine`): pick by pick, (k, T, h) in
+    the weights' type (token-major it would be re-laid for k = 4 and 6),
+    then ONE multiply-and-sum over the picks in float32, in pick order;
+    a dead pick's `pos` is past the buffer and reads whatever its last
+    row holds (a dead token's sum is zeroed here, a dead pick of a live
+    token has weight 0: `_moe`). Returns (T, h) float32."""
+    import jax.numpy as jnp
+    T, k = pos.shape
+    back = ys.at[pos.T.reshape(-1)].get(mode="clip").reshape(k, T, -1)
+    y = back[0].astype(jnp.float32) * w[:, 0, None]
+    for j in range(1, k):
+        y = y + back[j].astype(jnp.float32) * w[:, j, None]
+    return jnp.where(live[:, None], y, 0)
+
+
+def _moe(cfg, lp, x, live):
+    """The expert layer's feed-forward on tokens x (T, h), for every
+    config that names `n_routed_experts`, `experts_per_tok`,
+    `n_shared_experts` (0: no shared expert, none traced) and optionally
+    `router_scoring` (see `route`), `experts_held` (see `held_experts`)
+    and `shared_expert_combination` ("sum", the default, or "average":
+    the shared experts, stored as ONE SwiGLU n times as wide, over their
+    count): this block's, models/mellum's and models/command_a's.
+    `live` (T,)
+    bool: rows that are real (a prefill's padding and a frozen slot's
+    ride-along are not: they get no expert and do not count). The routed
+    rows are laid out ONCE, by counting (`routed_positions`): the
+    dispatch gathers them there, the product computes whole tiles of one
+    expert, the weighted sum reads them back by the same positions.
+    Returns (y (T, h), counters)."""
+    import jax
+    import jax.numpy as jnp
+    from ..ops.grouped_swiglu import padded_rows, row_tile_for
+    T, k, E = x.shape[0], cfg.experts_per_tok, cfg.n_routed_experts
+    first, held = held_experts(cfg)
+    tile = row_tile_for(T * k, E)
+    with jax.named_scope("moe/router"):
+        picks, w = route(cfg, lp, x)
+    mine, slots, average, parts = live, T * k, T * k, 1
+    if held < E:
+        picks = picks - first
+        mine = live[:, None] & (picks >= 0) & (picks < held)
+        # a pick of an expert held elsewhere: its weight divided the sum
+        # and multiplies nothing here
+        w = jnp.where(mine, w, 0)
+        average = -(-T * k * held // E)
+        parts = max(1, E // (held * HELD_SLACK))
+        if T * k < HELD_SPLIT_FROM or T % parts:
+            parts = 1
+    if parts == 1:
+        ys, pos, group_sizes = _lay_out(lp, x, picks, mine, held, tile,
+                                        slots, average)
+    else:
+        slots = T * k // parts
+
+        def routed(x, picks, mine, w, live):
+            ys, pos, sizes = _lay_out(lp, x, picks, mine, held, tile, slots,
+                                      average)
+            with jax.named_scope("moe/combine"):
+                return _weighted_sum(ys, pos, w, live), sizes
+
+        def in_parts(*whole):
+            ys, sizes = jax.lax.map(lambda part: routed(*part), tuple(
+                a.reshape(parts, T // parts, *a.shape[1:]) for a in whole))
+            return ys.reshape(T, -1), jnp.sum(sizes, 0)
+
+        sizes = jnp.sum(mine[:, :, None] & (
+            picks[:, :, None] == jnp.arange(held, dtype=jnp.int32)),
+            (0, 1), dtype=jnp.int32)
+        fits = jnp.sum(-(-sizes // tile)) * tile <= \
+            padded_rows(slots, held, tile)
+        y, group_sizes = jax.lax.cond(fits, routed, in_parts,
+                                      x, picks, mine, w, live)
     if cfg.n_shared_experts:
         with jax.named_scope("moe/shared"):
             shared = _swiglu(x, lp["shared_gate"], lp["shared_up"],
                              lp["shared_down"])
     with jax.named_scope("moe/combine"):
-        # pick by pick, (k, T, h) in the weights' type (token-major it
-        # would be re-laid for k = 4 and 6), then ONE multiply-and-sum
-        # over the picks in float32, in pick order; a dead token's `pos`
-        # is past the buffer and reads whatever its last row holds
-        back = ys.at[pos.T.reshape(-1)].get(mode="clip").reshape(k, T, -1)
-        y = back[0].astype(jnp.float32) * w[:, 0, None]
-        for j in range(1, k):
-            y = y + back[j].astype(jnp.float32) * w[:, j, None]
-        y = jnp.where(live[:, None], y, 0)
+        if parts == 1:
+            y = _weighted_sum(ys, pos, w, live)
         if cfg.n_shared_experts:
-            y = y + shared.astype(jnp.float32)
+            shared = shared.astype(jnp.float32)
+            if getattr(cfg, "shared_expert_combination", "sum") == "average":
+                shared = shared * (1.0 / cfg.n_shared_experts)
+            y = y + shared
         y = y.astype(x.dtype)
     passes = jnp.any(live).astype(jnp.int32)
     zero = jnp.zeros_like(passes)
@@ -730,7 +835,7 @@ def _ffn_sublayer(cfg, lp, x, live, counters):
 def _zero_counters(cfg):
     import jax.numpy as jnp
     zero = jnp.zeros((), jnp.int32)
-    counters = {"expert_tokens": jnp.zeros((cfg.n_routed_experts,),
+    counters = {"expert_tokens": jnp.zeros((held_experts(cfg)[1],),
                                            jnp.int32),
                 "router_tokens": zero, "experts_touched": zero,
                 "moe_passes": zero, "kernel_passes": zero,

@@ -74,7 +74,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["grouped_swiglu", "row_tile_for", "padded_rows",
-           "routed_positions"]
+           "routed_positions", "f_slices"]
 
 # the smallest row tile: one packed bfloat16 tile of 16 sublanes
 _MIN_TILE = 16
@@ -88,6 +88,9 @@ _LANES = 128
 _CHUNK = 256
 # tokens a block of the running count: one triangular product a block
 _COUNT_BLOCK = 256
+# what two buffers of a visit's three weight blocks may take of VMEM (128
+# MiB on a v5e, 110 granted to a kernel at most: `vmem_limit_bytes`)
+_WEIGHTS_VMEM = 64 * _MIB
 
 
 def row_tile_for(rows, groups):
@@ -116,17 +119,28 @@ def padded_rows(rows, groups, tile):
 
 def routed_positions(picks, live, groups, tile):
     """The layout, by counting. picks (T, k) int32: the experts of each
-    token; live (T,) bool. Returns (pos (T, k) int32, group_sizes
-    (groups,) int32): `pos[t, j]` is the row of (t, j) in a buffer of
-    `padded_rows(T * k, groups, tile)` rows, its group's start (the
-    groups before it, each rounded up to `tile`) plus the number of live
-    (t', j') before (t, j) with the same expert: the order a stable sort
-    by expert gives. A token that is not live counts nowhere and its
-    `pos` is the buffer's length: past every row, so a scatter drops it
-    and a gather must not trust it."""
+    token, numbered from the FIRST OF THE `groups` EXPERTS HELD HERE (a
+    layer that holds experts f .. f + groups - 1 of more gives picks -
+    f). live: (T,) bool by token (every pick of a live token is then one
+    of the `groups`), or (T, k) by PICK: a pick is live where `live` says
+    so AND its expert is one of the `groups` (0 <= pick < groups); a pick
+    of an expert held elsewhere is in no group, is never moved and never
+    computed. Returns (pos (T, k) int32, group_sizes (groups,) int32):
+    `pos[t, j]` is the row of (t, j) in a buffer of
+    `padded_rows(T * k, groups, tile)` rows (or fewer: the live picks'
+    groups, each rounded up to `tile`, are all the layout needs), its
+    group's start (the groups before it, each rounded up to `tile`) plus
+    the number of live (t', j') before (t, j) with the same expert: the
+    order a stable sort by expert gives. A pick that is not live counts
+    nowhere and its `pos` is `padded_rows(T * k, groups, tile)`: past
+    every row, so a scatter drops it and a gather must not trust it."""
     T, k = picks.shape
     e = jnp.arange(groups, dtype=jnp.int32)
-    hit = (picks[:, :, None] == e) & live[:, None, None]       # (T, k, E)
+    by_pick = live.ndim == 2
+    if by_pick:
+        live = live & (picks >= 0) & (picks < groups)
+    hit = (picks[:, :, None] == e) & (                         # (T, k, E)
+        live[:, :, None] if by_pick else live[:, None, None])
     chose = jnp.sum(hit, 1, dtype=jnp.int32)                   # (T, E)
     # how many earlier tokens chose e: inside a block of tokens a strictly
     # lower-triangular product (0/1 and counts up to k in bfloat16, the
@@ -152,8 +166,8 @@ def routed_positions(picks, live, groups, tile):
     twice = jnp.sum((picks[:, :, None] == picks[:, None, :])
                     & (j[:, None] > j[None, :]), -1, dtype=jnp.int32)
     pos = jnp.sum(jnp.where(hit, base[:, None, :], 0), -1) + twice
-    return (jnp.where(live[:, None], pos, padded_rows(T * k, groups, tile)),
-            group_sizes)
+    return (jnp.where(live if by_pick else live[:, None], pos,
+                      padded_rows(T * k, groups, tile)), group_sizes)
 
 
 def _visits(group_sizes, tile, visits):
@@ -181,6 +195,34 @@ def _dot(a, b):
                    preferred_element_type=jnp.float32)
 
 
+def _products(x_ref, gate_ref, up_ref, down_ref, act_ref, store):
+    """One visit's products over the F lanes the weight blocks hold, by
+    chunks of output lanes (the module docstring): the activation into
+    `act_ref`, then `store(lanes of h, that chunk of act @ down)`."""
+    from jax.experimental import pallas as pl
+
+    h, F = gate_ref.shape
+    fc = _LANES if F % _LANES == 0 else F
+    hc = _CHUNK if h % _CHUNK == 0 else h
+    x = x_ref[...]
+
+    def f_step(f, _):
+        at = pl.ds(pl.multiple_of(f * fc, fc), fc)
+        gate = _dot(x, gate_ref[:, at])
+        up = _dot(x, up_ref[:, at])
+        act_ref[:, at] = (gate * jax.nn.sigmoid(gate)
+                          * up).astype(act_ref.dtype)
+
+    jax.lax.fori_loop(0, F // fc, f_step, None)
+    act = act_ref[...]
+
+    def h_step(n, _):
+        at = pl.ds(pl.multiple_of(n * hc, hc), hc)
+        store(at, _dot(act, down_ref[:, at]))
+
+    jax.lax.fori_loop(0, h // hc, h_step, None)
+
+
 def _kernel(expert_ref, tile_ref, count_ref, x_ref, gate_ref, up_ref,
             down_ref, o_ref, act_ref):
     from jax.experimental import pallas as pl
@@ -189,27 +231,52 @@ def _kernel(expert_ref, tile_ref, count_ref, x_ref, gate_ref, up_ref,
 
     @pl.when(pl.program_id(0) < count_ref[0])
     def _visit():
-        # the products by chunks of output lanes (the module docstring)
-        h, F = gate_ref.shape
-        fc = _LANES if F % _LANES == 0 else F
-        hc = _CHUNK if h % _CHUNK == 0 else h
-        x = x_ref[...]
+        def store(at, part):
+            o_ref[:, at] = part.astype(o_ref.dtype)
 
-        def f_step(f, _):
-            at = pl.ds(pl.multiple_of(f * fc, fc), fc)
-            gate = _dot(x, gate_ref[:, at])
-            up = _dot(x, up_ref[:, at])
-            act_ref[:, at] = (gate * jax.nn.sigmoid(gate)
-                              * up).astype(act_ref.dtype)
+        _products(x_ref, gate_ref, up_ref, down_ref, act_ref, store)
 
-        jax.lax.fori_loop(0, F // fc, f_step, None)
-        act = act_ref[...]
 
-        def h_step(n, _):
-            at = pl.ds(pl.multiple_of(n * hc, hc), hc)
-            o_ref[:, at] = _dot(act, down_ref[:, at]).astype(o_ref.dtype)
+def _kernel_in_slices(expert_ref, tile_ref, count_ref, x_ref, gate_ref,
+                      up_ref, down_ref, o_ref, act_ref, acc_ref):
+    """A visit in `pl.num_programs(1)` steps, a SLICE of F each (the
+    expert's matrices do not fit VMEM whole): the slice's part of the
+    down product is added up in float32 (`acc_ref`) and the tile stored
+    at the last slice."""
+    from jax.experimental import pallas as pl
 
-        jax.lax.fori_loop(0, h // hc, h_step, None)
+    del expert_ref, tile_ref
+    f, last = pl.program_id(1), pl.num_programs(1) - 1
+
+    @pl.when(pl.program_id(0) < count_ref[0])
+    def _visit():
+        def store(at, part):
+            @pl.when(f == 0)
+            def _():
+                acc_ref[:, at] = part
+
+            @pl.when(f > 0)
+            def _():
+                acc_ref[:, at] += part
+
+        _products(x_ref, gate_ref, up_ref, down_ref, act_ref, store)
+
+        @pl.when(f == last)
+        def _():
+            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def f_slices(h, F, itemsize):
+    """In how many slices of F a visit holds its expert's matrices: 1
+    where two buffers of the three fit the VMEM a kernel is granted
+    (Moonlight's 34.6 MB, Xing's 44, Mellum's 24.8), else the power of
+    two that makes them fit (command-a's 4096 x 4096: 201 MB whole, 4
+    slices of 1,024 lanes, 50 MB)."""
+    n = 1
+    while 2 * 3 * h * (F // n) * itemsize > _WEIGHTS_VMEM \
+            and (F // n) % (2 * _LANES) == 0:
+        n *= 2
+    return n
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -220,30 +287,65 @@ def _call(xs, w_gate, w_up, w_down, group_sizes, tile, interpret):
     R, h = xs.shape
     E, _, F = w_gate.shape
     walk = _visits(group_sizes, tile, R // tile)
-    # two buffers of the three matrices and of the row tile in and out,
-    # the activation, and room for the chunks' float32 values
-    vmem = (2 * 3 * h * F * jnp.dtype(w_gate.dtype).itemsize
-            + (4 * h + F) * tile * jnp.dtype(xs.dtype).itemsize + 8 * _MIB)
-    rows = pl.BlockSpec((tile, h), lambda i, e, t, *_: (t[i], 0))
+    item = jnp.dtype(w_gate.dtype).itemsize
+    slices = f_slices(h, F, item)
+    Fs = F // slices
+    # two buffers of the three matrices (a slice of them) and of the row
+    # tile in and out, the activation, and room for the chunks' float32
+    # values (and the sliced visit's float32 tile)
+    vmem = (2 * 3 * h * Fs * item
+            + (4 * h + Fs) * tile * jnp.dtype(xs.dtype).itemsize + 8 * _MIB)
+    params = dict(
+        out_shape=jax.ShapeDtypeStruct((R, h), xs.dtype),
+        interpret=interpret)
+    if slices == 1:
+        rows = pl.BlockSpec((tile, h), lambda i, e, t, *_: (t[i], 0))
+        return pl.pallas_call(
+            _kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(R // tile,),
+                in_specs=[
+                    rows,
+                    pl.BlockSpec((None, h, F), lambda i, e, *_: (e[i], 0, 0)),
+                    pl.BlockSpec((None, h, F), lambda i, e, *_: (e[i], 0, 0)),
+                    pl.BlockSpec((None, F, h), lambda i, e, *_: (e[i], 0, 0)),
+                ],
+                out_specs=rows,
+                scratch_shapes=[pltpu.VMEM((tile, F), xs.dtype)]),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=int(min(vmem, 110 * _MIB))),
+            name="grouped_swiglu", **params,
+        )(*walk, xs, w_gate, w_up, w_down)
+
+    # a step past the walk's count repeats the last visit's LAST slice:
+    # it fetches nothing
+    def at(i, f, c):
+        return jnp.where(i < c[0], f, slices - 1)
+
+    rows = pl.BlockSpec((tile, h), lambda i, f, e, t, c: (t[i], 0))
     return pl.pallas_call(
-        _kernel,
+        _kernel_in_slices,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(R // tile,),
+            grid=(R // tile, slices),
             in_specs=[
                 rows,
-                pl.BlockSpec((None, h, F), lambda i, e, *_: (e[i], 0, 0)),
-                pl.BlockSpec((None, h, F), lambda i, e, *_: (e[i], 0, 0)),
-                pl.BlockSpec((None, F, h), lambda i, e, *_: (e[i], 0, 0)),
+                pl.BlockSpec((None, h, Fs),
+                             lambda i, f, e, t, c: (e[i], 0, at(i, f, c))),
+                pl.BlockSpec((None, h, Fs),
+                             lambda i, f, e, t, c: (e[i], 0, at(i, f, c))),
+                pl.BlockSpec((None, Fs, h),
+                             lambda i, f, e, t, c: (e[i], at(i, f, c), 0)),
             ],
             out_specs=rows,
-            scratch_shapes=[pltpu.VMEM((tile, F), xs.dtype)]),
-        out_shape=jax.ShapeDtypeStruct((R, h), xs.dtype),
+            scratch_shapes=[pltpu.VMEM((tile, Fs), xs.dtype),
+                            pltpu.VMEM((tile, h), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=int(min(vmem, 110 * _MIB))),
-        interpret=interpret,
-        name="grouped_swiglu",
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(min(vmem + 4 * tile * h, 110 * _MIB))),
+        name="grouped_swiglu_sliced", **params,
     )(*walk, xs, w_gate, w_up, w_down)
 
 

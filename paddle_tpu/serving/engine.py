@@ -1465,6 +1465,7 @@ class ServingEngine:
         # layer, and the model's own in-graph counters (a routed model's
         # `expert_tokens` and `router_tokens` since start)
         s["model"] = self.model.name
+        s.update(self.model.describe(self.cfg))
         s["cache_row_bytes"] = self.kv.cache_row_bytes
         for name, value in self.scheduler.model_counters.items():
             s[name] = value.tolist()

@@ -211,6 +211,12 @@ class ServingModel:
         """{name: shape} of the counters the programs return."""
         return {}
 
+    def describe(self, cfg):
+        """What `engine.stats()` says of the served config beside the
+        model's name: a dict of plain values (the experts this chip
+        holds of how many, its slice of the vocabulary)."""
+        return {}
+
     def prefill(self, params, cfg, tokens, pfx_len, real_len, arena,
                 pages, adapters=None, adapter_id=None):
         raise NotImplementedError

@@ -27,7 +27,8 @@ def test_main_exits_nonzero_on_cpu_before_any_work(monkeypatch, capsys):
     def no_work(*args, **kwargs):
         raise AssertionError("a phase ran without a TPU")
 
-    for name in ("phase_kernels", "phase_train", "phase_serve"):
+    for name in ("phase_kernels", "phase_share_kernels", "phase_train",
+                 "phase_serve"):
         monkeypatch.setattr(chip_smoke, name, no_work)
     assert jax.default_backend() == "cpu"
     assert chip_smoke.main() != 0
@@ -44,6 +45,7 @@ def test_last_stdout_line_is_the_verdict_and_the_device(monkeypatch, capsys):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(chip_smoke, "phase_kernels", lambda: {"cases": 0})
+    monkeypatch.setattr(chip_smoke, "phase_share_kernels", lambda: {})
     monkeypatch.setattr(
         chip_smoke, "phase_train",
         lambda *a, **kw: {"losses": [2.0, 1.0], "scope": None})
@@ -70,6 +72,19 @@ def test_kernels_phase_both_families_interpreted():
     facts = chip_smoke.phase_kernels(seqs=(128, 640), batch=1, heads=1)
     assert facts["cases"] == 18     # 2 families x (4 causal + 5 bias)
     assert facts["worst_rel_err"] <= chip_smoke.KERNEL_REL_TOL
+
+
+def test_share_kernels_phase_interpreted():
+    """The phase at a small size: the grouped paged kernel over a ring,
+    the flash band and the expert layer of a share, each against its
+    twin (the kernels interpreted, the expert product `ragged_dot`)."""
+    facts = chip_smoke.phase_share_kernels(
+        heads=8, kv_heads=2, head_dim=128, window=32, block=8, hidden=128,
+        width=256, experts=16, held=4, picks=4, shared=2, rows=256, tokens=64)
+    assert (facts["paged_group"], facts["paged_ring"]) == (4, 5)
+    assert max(facts[k] for k in facts if k.endswith("rel_err")
+               or k.startswith("moe_rel_err")) <= chip_smoke.KERNEL_REL_TOL
+    assert 0 < facts["moe_held_picks_64"] < 64 * 4
 
 
 def test_train_then_serve_phases():

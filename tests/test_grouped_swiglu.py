@@ -216,16 +216,21 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("h,f,rows", [
-    (2048, 1408, 192), (2048, 1408, 2048 * 6), (2048, 1408, 8192 * 6),
-    (3584, 1024, 16384 * 4),              # Xing's largest bucket
-    (2304, 896, 16384 * 8),               # Mellum's
+@pytest.mark.parametrize("h,f,rows,experts,tile", [
+    (2048, 1408, 192, 64, None), (2048, 1408, 2048 * 6, 64, None),
+    (2048, 1408, 8192 * 6, 64, None),
+    (3584, 1024, 16384 * 4, 64, None),    # Xing's largest bucket
+    (2304, 896, 16384 * 8, 64, None),     # Mellum's
+    # command-a's share: 16 experts held of 128, matrices of 4096 x 4096
+    # in four slices of F; a 32-slot step's worst case and an 8,192-row
+    # prompt's second static size, at the tiles the 128-wide router gives
+    (4096, 4096, 32 * 8, 16, 16), (4096, 4096, 8192 * 8 // 4, 16, 256),
 ], ids=["moonlight_step", "moonlight_2048", "moonlight_8192", "xing_16384",
-        "mellum_16384"])
+        "mellum_16384", "command_a_step", "command_a_8192"])
 def test_compiles_at_the_models_widths_for_a_described_v5e(one_chip, h, f,
-                                                           rows):
-    experts = 64
-    tile = gs.row_tile_for(rows, experts)
+                                                           rows, experts,
+                                                           tile):
+    tile = tile or gs.row_tile_for(rows, experts)
 
     def shape(*dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)

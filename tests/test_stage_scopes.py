@@ -238,16 +238,40 @@ def _mellum():
         {"prefill_buckets": (8, 16, 32)}
 
 
-@pytest.mark.parametrize("family", ["gpt", "moonlight", "mellum"])
+def _command_a():
+    from paddle_tpu.models.command_a import CommandAConfig, init_params
+    cfg = CommandAConfig(
+        vocab_size=96, hidden=64, layers=4, heads=8, kv_heads=2, head_dim=16,
+        moe_intermediate=32, n_routed_experts=16, n_shared_experts=2,
+        experts_per_tok=4, experts_held=(4, 4), vocab_slice=(0, 96, 768),
+        sliding_window=8, max_pos=64)
+    return cfg, init_params(cfg, jax.random.PRNGKey(0), jnp.float32), \
+        {"prefill_buckets": (8, 16, 32)}
+
+
+@pytest.mark.parametrize("program", ["chunk", "prefill"])
+def test_command_a_names_every_stage_in_both_programs(program):
+    """The parallel block's eleven stages, the expert layer's through the
+    `lax.cond` of a share's second buffer size where a program has it."""
+    cfg, params, kw = _command_a()
+    found = stages_in(serving_hlo(engine_of(params, cfg, **kw), program))
+    assert {"embed", "norm", "attn/project", "attn/window", "attn/full",
+            "moe/router", "moe/dispatch", "moe/experts", "moe/shared",
+            "moe/combine", "head"} <= found, found
+    assert not {s for s in found if s.startswith(("mla/", "ffn/", "hc/"))}
+
+
+@pytest.mark.parametrize("family", ["gpt", "moonlight", "mellum", "command_a"])
 def test_the_loop_names_its_work_for_every_model_family(gpt_params, family):
     cfg, params, kw = (CFG, gpt_params, {}) if family == "gpt" else \
-        (_moonlight() if family == "moonlight" else _mellum())
+        {"moonlight": _moonlight, "mellum": _mellum,
+         "command_a": _command_a}[family]()
     engine = engine_of(params, cfg, **kw)
     found = stages_in(serving_hlo(engine, "chunk"))
     assert LOOP_STAGES <= found, found
     # the model's step between them keeps the model's own scopes
     own = {"gpt": "attn/project", "moonlight": "mla/attend",
-           "mellum": "attn/window"}[family]
+           "mellum": "attn/window", "command_a": "moe/shared"}[family]
     assert own in found and "head" in found
     assert LOOP_STAGES <= stages_in(serving_hlo(engine, "admit"))
 

@@ -1,8 +1,9 @@
 """The expert layer alone: `models/moonlight.py::_moe` stage by stage at
-the three expert cells' widths.
+the four expert cells' widths.
 
-One layer's weights (router, 64 experts, the shared expert where the
-config has one; bfloat16, made on the chip and passed as ARGUMENTS: as
+One layer's weights (router, the experts HELD (64 of 64; command-a's 16
+of 128, routed over all), the shared experts where the
+config has them; bfloat16, made on the chip and passed as ARGUMENTS: as
 constants 1 GB of them costs minutes of compile) and `_moe` jitted once a
 row count, traced once a case:
   * every prompt length a cell's traffic file sends, in the bucket the
@@ -18,7 +19,8 @@ the microseconds of each stage a line gives
     checkout without it, one visit of `tile` rows for every (expert, row
     tile) pair of groups laid end to end: the layout before PR 37);
   * `dispatch_hbm`, `combine_hbm`: one copy of the bucket's routed rows
-    (tokens x picks x h x 2 B) over 819 GB/s, over the stage's time.
+    (tokens x picks x h x 2 B; the held share of them where the layer
+    holds a share of the experts) over 819 GB/s, over the stage's time.
 A chip is required: on any other backend it exits 1 with nothing
 measured.
 
@@ -43,8 +45,9 @@ PEAK_FLOPS = 197e12              # TPU v5e, bfloat16 (Google Cloud documentation
 HBM_BYTES_PER_S = 819e9
 HERE = os.path.dirname(os.path.abspath(__file__))
 STAGES = ("router", "dispatch", "experts", "shared", "combine")
-EXPERTS = 64
-# the published widths (benchmarks/configs/*.json) and the cells' traffic
+# the published widths (benchmarks/configs/*.json) and the cells' traffic;
+# `experts` the router's width, `held` how many of them the layer holds
+# (the first ones; all where the key is absent)
 MODELS = {
     "moonlight": dict(h=2048, F=1408, k=6, shared=2, scoring="sigmoid",
                       factor=2.446, traffic="longctx-offline"),
@@ -52,6 +55,9 @@ MODELS = {
                  factor=2.5, traffic="longdoc-offline"),
     "mellum": dict(h=2304, F=896, k=8, shared=0, scoring="softmax",
                    factor=1.0, traffic="mixedlen-offline"),
+    "commanda": dict(h=4096, F=4096, k=8, shared=4, scoring="sigmoid",
+                     factor=1.0, traffic="reason-offline", experts=128,
+                     held=16, bias=False, combination="average"),
 }
 REPEATS = 6
 
@@ -73,15 +79,18 @@ def cases_of(traffic):
 
 def layer(jax, jnp, model):
     """One expert layer's parameters, on the device."""
-    h, F, E = model["h"], model["F"], EXPERTS
+    h, F, E = model["h"], model["F"], model.get("experts", 64)
+    held = model.get("held", E)
     keys = iter(jax.random.split(jax.random.PRNGKey(0), 8))
 
     def w(*shape):
         return (0.02 * jax.random.normal(next(keys), shape, jnp.float32)
                 ).astype(jnp.bfloat16)
 
-    lp = {"router": w(h, E), "router_bias": jnp.zeros((E,), jnp.float32),
-          "w_gate": w(E, h, F), "w_up": w(E, h, F), "w_down": w(E, F, h)}
+    lp = {"router": w(h, E), "w_gate": w(held, h, F), "w_up": w(held, h, F),
+          "w_down": w(held, F, h)}
+    if model.get("bias", True):
+        lp["router_bias"] = jnp.zeros((E,), jnp.float32)
     if model["shared"]:
         Fs = model["shared"] * F
         lp.update(shared_gate=w(h, Fs), shared_up=w(h, Fs),
@@ -122,10 +131,17 @@ def bench(name, model, ml, gs):
     import jax.numpy as jnp
     import numpy as np
 
+    experts = model.get("experts", 64)
+    held = model.get("held", experts)
     cfg = types.SimpleNamespace(
-        experts_per_tok=model["k"], n_routed_experts=EXPERTS,
+        experts_per_tok=model["k"], n_routed_experts=experts,
         n_shared_experts=model["shared"], router_scoring=model["scoring"],
         routed_scaling_factor=model["factor"])
+    if held < experts:
+        # a share of an expert-parallel deployment (a checkout whose layer
+        # is not told which experts it holds cannot run this case)
+        cfg.experts_held = (0, held)
+        cfg.shared_expert_combination = model["combination"]
     lp = layer(jax, jnp, model)
     h, F, k = model["h"], model["F"], model["k"]
     programs, rows = {}, []
@@ -143,13 +159,14 @@ def bench(name, model, ml, gs):
         if not bool(jnp.isfinite(y.astype(jnp.float32)).all()):
             raise SystemExit(f"{name} {case}: the layer's output is not finite")
         sizes = np.asarray(counters["expert_tokens"])
-        assert sizes.sum() == length * k, (sizes.sum(), length, k)
-        rows_tile = gs.row_tile_for(tokens * k, EXPERTS)
+        if held == experts:
+            assert sizes.sum() == length * k, (sizes.sum(), length, k)
+        rows_tile = gs.row_tile_for(tokens * k, experts)
         computed = counters.get("rows_computed")
         computed = old_layout_rows(sizes.tolist(), rows_tile) \
             if computed is None else int(computed)
         fact = dict(tokens=tokens, live=length, tile=rows_tile,
-                    rows_routed=length * k, rows_computed=computed)
+                    rows_routed=int(sizes.sum()), rows_computed=computed)
         with tempfile.TemporaryDirectory() as trace_dir:
             jax.profiler.start_trace(trace_dir)
             for _ in range(REPEATS):
@@ -164,7 +181,10 @@ def bench(name, model, ml, gs):
         us = {s: 1e6 * entry["stages"].get(f"moe/{s}", 0.0) / runs
               for s in STAGES}
         flops = 6 * h * F
-        copy_us = 1e6 * fact["tokens"] * k * h * 2 / HBM_BYTES_PER_S
+        # one copy of the rows that are HELD (tokens x picks where every
+        # expert is)
+        copy_us = 1e6 * fact["tokens"] * k * held // experts * h * 2 \
+            / HBM_BYTES_PER_S
         row = dict(model=name, case=case, **fact,
                    **{f"{s}_us": round(v, 1) for s, v in us.items()},
                    layer_us=round(1e6 * entry["busy_s"] / runs, 1),
