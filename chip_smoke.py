@@ -184,7 +184,8 @@ def phase_kernels(seqs=(512, 1024), batch=2, heads=12, head_dim=64):
 
 def phase_share_kernels(heads=128, kv_heads=8, head_dim=128, window=4096,
                         block=128, hidden=4096, width=4096, experts=128,
-                        held=16, picks=8, shared=4, rows=8192, tokens=512):
+                        held=16, picks=8, shared=4, rows=8192, tokens=512,
+                        combine_tokens=2048, combine_hidden=2304):
     """command-a-plus-05-2026's shapes against `jax.numpy` twins: the
     grouped paged kernel at heads / kv_heads queries a KV head over a ring
     of window / block + 1 blocks (slots below, at and beyond the window,
@@ -192,7 +193,9 @@ def phase_share_kernels(heads=128, kv_heads=8, head_dim=128, window=4096,
     with shared KV heads; and the expert layer that holds `held` of
     `experts` experts (`models/moonlight._moe`: the sliced kernel where an
     expert's matrices do not fit VMEM whole) at a step's and a prompt's
-    row counts."""
+    row counts; and the combine kernel (ops/routed_combine) against XLA's
+    gather and sum of the same rows, at `combine_hidden` lanes with every
+    pick held and at `hidden` with `held` of `experts`."""
     import types
 
     import jax
@@ -308,6 +311,37 @@ def phase_share_kernels(heads=128, kv_heads=8, head_dim=128, window=4096,
         facts[f"moe_rel_err_{n}"] = err
         facts[f"moe_held_picks_{n}"] = held_picks
     facts["moe_path"] = ml.expert_product_path(lp)
+
+    # the combine kernel against XLA's gather and sum of the same rows:
+    # Mellum's widths (8 picks of 64, every pick held) and this share's (8
+    # of 128, 16 held: the held picks alone are fetched)
+    from paddle_tpu.ops.grouped_swiglu import (padded_rows, routed_positions,
+                                               row_tile_for)
+    ks = iter(jax.random.split(jax.random.PRNGKey(39), 16))
+    for name, h, of, term in (("mellum", combine_hidden, held, None),
+                              ("command_a", hidden, experts, 1.0 / shared)):
+        n, tile = combine_tokens, row_tile_for(combine_tokens * picks, of)
+        pk = jax.vmap(lambda key: jax.random.permutation(key, of)[:picks])(
+            jax.random.split(next(ks), n)).astype(jnp.int32)
+        live = jnp.arange(n) % 7 != 3
+        pos, sizes = routed_positions(
+            pk, live[:, None] & (pk < held) if of > held else live, held, tile)
+        buffer = padded_rows(n * picks, held, tile)
+        ys = normal(buffer, h).astype(jnp.bfloat16)
+        bits = jax.lax.bitcast_convert_type(ys, jnp.uint16).astype(jnp.uint32)
+        words = (bits[:, :h // 2] | (bits[:, h // 2:] << 16))[:, None, :]
+        pw = jnp.where(pos < buffer, jnp.abs(normal(n, picks)), 0)
+        both = jax.jit(lambda ys, sh, by_dma: ml._combine(
+            ys, pos, pw, live, sh, term, jnp.bfloat16, by_dma),
+            static_argnums=2)
+        sh = None if term is None else normal(n, h).astype(jnp.bfloat16)
+        got, want = both(words, sh, True), both(ys, sh, False)
+        err = _rel_err(got, want)
+        _require(err <= KERNEL_REL_TOL,
+                 f"share_kernels: the combine kernel at {name}'s widths is "
+                 f"off XLA's gather by {err:.3e}")
+        facts[f"combine_rel_err_{name}"] = err
+        facts[f"combine_rows_fetched_{name}"] = int(sizes.sum())
     return facts
 
 

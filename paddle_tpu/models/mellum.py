@@ -490,7 +490,7 @@ class _MellumServingModel(ServingModel):
                 "router_tokens": (), "decode_router_tokens": (),
                 "decode_experts_touched": (), "decode_moe_passes": (),
                 "moe_kernel_passes": (), "moe_rows_computed": (),
-                "decode_rows_full": (),
+                "moe_combine_kernel_passes": (), "decode_rows_full": (),
                 "decode_rows_window": ()}
 
     @staticmethod

@@ -33,8 +33,21 @@ and fused chunk loop (serving/decode_loop.py) as the GPT family:
     `moe/dispatch` gathers the rows there (one scatter of tokens x picks
     integers says whose row each is), `moe/experts` computes whole tiles
     of ONE expert, `moe/combine` reads the products back by the same
-    positions, pick by pick in the weights' type, and sums them in
-    float32. On a TPU the product is ONE kernel a layer,
+    positions, pick by pick in the weights' type, sums them in float32
+    in pick order, adds the shared experts' term and rounds once
+    (`_combine`): ONE sum with two carriers, chosen by `combine_path`
+    from the static byte size of the products. XLA's gather and fusion
+    for a decode step and a short prompt (below COMBINE_KERNEL_FROM a
+    row costs it 8-15 ns, or the whole sum less than a kernel's call);
+    from there on, on a TPU, the kernel ops/routed_combine: the expert
+    kernel stores a row's words TOGETHER (`grouped_swiglu(...,
+    packed=True)`: two column halves a 32-bit word, the same bfloat16
+    roundings) and the combine fetches every row that is someone's by a
+    DMA of its own, a token tile at a time, the next tile's rows in
+    flight while this one is summed; a pick past the buffer (a dead
+    token's, one held elsewhere) is never fetched and adds exactly 0.
+    The in-graph counter `combine_kernel_passes` counts the passes that
+    took the kernel. On a TPU the product is ONE kernel a layer,
     ops/grouped_swiglu: gate, up, `silu(g) * u` and down per expert, the
     weights read where they lie, an expert with no row never fetched;
     the row tile is the layout's, chosen from the static row count (16
@@ -351,10 +364,14 @@ def rope(x, pos, theta, scaling=None, interleaved=True):
     return (x32 * cos + rot * sin).astype(x.dtype)
 
 
-def _swiglu(x, gate, up, down):
+def _swiglu_hidden(x, gate, up):
     import jax
     g = x @ gate
-    return (jax.nn.silu(g) * (x @ up)) @ down
+    return jax.nn.silu(g) * (x @ up)
+
+
+def _swiglu(x, gate, up, down):
+    return _swiglu_hidden(x, gate, up) @ down
 
 
 # -- the residual path ---------------------------------------------------------
@@ -395,12 +412,19 @@ def _three_bfloat16(w):
 
 def hc_coefficients(cfg, hp, X):
     """A sublayer's mixing coefficients from its input streams X (T, n,
-    C), all in float32 whatever X's type: H_pre (T, n) in (0, 1), H_post
-    (T, n) in (0, 2) and H_res (T, n, n), row j column i at [:, j, i],
+    C), all in float32 whatever X's type: H_pre (n, T) in (0, 1), H_post
+    (n, T) in (0, 2) and H_res (n, n, T), row j column i at [j, i],
     made doubly stochastic by `hc_sinkhorn_iters` rounds of column then
-    row normalisation of exp(clamp(.)). Inside, the token axis is LAST,
-    so that the rounds are additions and products of whole vectors of
-    tokens; what comes back has it first, as the streams have."""
+    row normalisation of exp(clamp(.)). The token axis is LAST, inside
+    and in what comes back, so that the rounds are additions and
+    products of whole vectors of tokens and a reader takes a
+    coefficient as ONE vector (T,), `h_res[j, i]`: a vector has one
+    layout, so no reader's choice of layout reaches back into the
+    rounds. Handed back token-first, (T, n, n), they took the layout of
+    whatever read them next, and behind a Pallas call's output the
+    compiler put the 4 streams in the lanes: 32 MB a round out of VMEM
+    for 1 MB in it, `hc/coeff` 5.5 ms a sublayer of 16k tokens for 1.2
+    (PERF.md, PR 39)."""
     import jax
     import jax.numpy as jnp
     T, n, C = X.shape
@@ -433,7 +457,7 @@ def hc_coefficients(cfg, hp, X):
         m = m / (cols + eps)[None]
         rows = _add_all([m[:, i] for i in range(n)])          # (n, T)
         m = m / (rows + eps)[:, None]
-    return h_pre.T, h_post.T, m.transpose(2, 0, 1)
+    return h_pre, h_post, m
 
 
 def _residual(cfg, hp, x, f, live, counters):
@@ -455,10 +479,10 @@ def _residual(cfg, hp, x, f, live, counters):
         h_pre, h_post, h_res = hc_coefficients(cfg, hp, x)
         # how far a row of H_res is from summing to one, at the worst
         # live token, in parts per million
-        rows = _add_all([h_res[:, :, i] for i in range(n)])
-        dev = jnp.max(jnp.where(live[:, None], jnp.abs(rows - 1.0), 0.0))
+        rows = _add_all([h_res[:, i] for i in range(n)])          # (n, T)
+        dev = jnp.max(jnp.where(live, jnp.abs(rows - 1.0), 0.0))
     with jax.named_scope("hc/pre"):
-        u = _add_all([h_pre[:, i, None] * x[:, i].astype(f32)
+        u = _add_all([h_pre[i][:, None] * x[:, i].astype(f32)
                       for i in range(n)]).astype(x.dtype)
     y, counters, aux = f(u, counters)
     with jax.named_scope("hc/post"):
@@ -468,8 +492,8 @@ def _residual(cfg, hp, x, f, live, counters):
         # copy of the streams (940 MB at 16k rows) kept across f
         x, y = jax.lax.optimization_barrier((x, y))
         y32 = y.astype(f32)
-        out = [_add_all([h_res[:, j, i, None] * x[:, i].astype(f32)
-                         for i in range(n)]) + h_post[:, j, None] * y32
+        out = [_add_all([h_res[j, i][:, None] * x[:, i].astype(f32)
+                         for i in range(n)]) + h_post[j][:, None] * y32
                for j in range(n)]
         x = jnp.stack(out, 1).astype(x.dtype)
     passes = jnp.any(live).astype(jnp.int32)
@@ -636,32 +660,58 @@ def expert_product_path(lp):
     return "ragged_dot"
 
 
-def grouped_experts(lp, xs, group_sizes, tile):
+def grouped_experts(lp, xs, group_sizes, tile, packed=False):
     """The grouped SwiGLU over the routed rows alone: xs (R, h) in the
     layout of ops/grouped_swiglu.routed_positions (sorted by expert,
     every group from a whole tile of `tile` rows on), group_sizes (E,)
     how many rows each expert has. A row between a group's end and its
     tile's is computed for nobody; the tiles past the last group's are
-    not to be read. On a TPU one kernel (ops/grouped_swiglu); elsewhere
-    three ragged products over the groups rounded up to the tile."""
+    not to be read. On a TPU one kernel (ops/grouped_swiglu; `packed`,
+    the kernel's alone: its rows as `combine_path`'s kernel reads them);
+    elsewhere three ragged products over the groups rounded up to the
+    tile."""
     import jax
     if expert_product_path(lp) == "grouped_swiglu_kernel":
         from ..ops.grouped_swiglu import grouped_swiglu
         return grouped_swiglu(xs, lp["w_gate"], lp["w_up"], lp["w_down"],
-                              group_sizes, tile)
+                              group_sizes, tile, packed)
     whole = -(-group_sizes // tile) * tile
     g = jax.lax.ragged_dot(xs, lp["w_gate"], whole)
     u = jax.lax.ragged_dot(xs, lp["w_up"], whole)
     return jax.lax.ragged_dot(jax.nn.silu(g) * u, lp["w_down"], whole)
 
 
+# From this many bytes of routed products on, a prompt's combine is the
+# kernel ops/routed_combine (a row a DMA: 10-20 ns a (token, pick)
+# whatever the row's width); below it XLA's gather reads a row in 8-15 ns
+# (Mellum's 512 and 1,024 buckets: 36 and 72 MiB of products) or the whole
+# sum costs less than a Pallas call (a decode step: 4-8 MiB, 1-8 us), and
+# from it on 34-80 ns (command-a's 4,096 bucket, 96 MiB, is the smallest
+# that is slow: PERF.md, PR 39).
+COMBINE_KERNEL_FROM = 84 << 20
+
+
+def combine_path(lp, x, rows):
+    """ "row_dma_kernel" where the expert product is the kernel, the rows
+    are bfloat16 of whole 256 lanes and `rows` of them (static) make
+    COMBINE_KERNEL_FROM bytes; "gather" elsewhere (the CPU, a decode
+    step, a short prompt)."""
+    import jax.numpy as jnp
+    h = x.shape[1]
+    if expert_product_path(lp) == "grouped_swiglu_kernel" \
+            and x.dtype == jnp.bfloat16 and h % (2 * _LANES) == 0 \
+            and rows * h * x.dtype.itemsize >= COMBINE_KERNEL_FROM:
+        return "row_dma_kernel"
+    return "gather"
+
+
 # A layer that holds `count` of E experts gets T * k * count / E picks on
 # average and T * k at the worst. Its routed buffer is sized for
 # HELD_SLACK times the average (a SECOND STATIC SIZE beside the worst
 # case's), so that dispatch's gather, the kernel's grid and the buffer
-# combine reads out of follow the picks that are held (combine still
-# makes T * k row reads, a clipped one for a pick held elsewhere: the
-# alternatives measured slower or no faster, PERF.md, PR 38); a pass
+# combine reads out of follow the picks that are held (XLA's combine
+# still makes T * k row reads, a clipped one for a pick held elsewhere;
+# the kernel's fetches the held ones alone); a pass
 # whose held picks do not fit there (their groups, each rounded up to
 # the tile) takes the other branch of a `lax.cond`, the same code over
 # the tokens in E / (count * HELD_SLACK) parts, each of which fits
@@ -672,14 +722,14 @@ HELD_SLACK = 2
 HELD_SPLIT_FROM = 4096
 
 
-def _lay_out(lp, x, picks, live, groups, tile, slots, average):
+def _lay_out(lp, x, picks, live, groups, tile, slots, average, packed):
     """Dispatch and the experts' product over a buffer for `slots` picks
     (static): picks (T, k) as `routed_positions` takes them, `live` (T,)
     by token or (T, k) by pick, at most `slots` of them live; `average`
     (static) how many are expected (T * k where every expert is held),
     which says whether dispatch places or gathers. Returns (ys, the
-    buffer's rows through their experts, pos (T, k), group_sizes
-    (groups,))."""
+    buffer's rows through their experts, `packed` (static) for the
+    combine kernel; pos (T, k); group_sizes (groups,))."""
     import jax
     import jax.numpy as jnp
     from ..ops.grouped_swiglu import padded_rows, routed_positions
@@ -705,12 +755,12 @@ def _lay_out(lp, x, picks, live, groups, tile, slots, average):
                 token, mode="drop", unique_indices=True)
             xs = x[source]
     with jax.named_scope("moe/experts"):
-        ys = grouped_experts(lp, xs, group_sizes, tile)
+        ys = grouped_experts(lp, xs, group_sizes, tile, packed)
     return ys, pos, group_sizes
 
 
 def _weighted_sum(ys, pos, w, live):
-    """Combine's sum (inside `moe/combine`): pick by pick, (k, T, h) in
+    """XLA's sum of `_combine`: pick by pick, (k, T, h) in
     the weights' type (token-major it would be re-laid for k = 4 and 6),
     then ONE multiply-and-sum over the picks in float32, in pick order;
     a dead pick's `pos` is past the buffer and reads whatever its last
@@ -723,6 +773,36 @@ def _weighted_sum(ys, pos, w, live):
     for j in range(1, k):
         y = y + back[j].astype(jnp.float32) * w[:, j, None]
     return jnp.where(live[:, None], y, 0)
+
+
+def _combine(ys, pos, w, live, shared, scale, dtype, by_dma):
+    """`moe/combine`'s whole sum: every token's routed products times
+    their weights, summed in float32 in pick order, a dead token's sum
+    0; plus `shared` (T, h) in float32 (None: no shared expert), times
+    `scale` where that is not None; ONE rounding to `dtype`. `by_dma`
+    (static, `combine_path`'s) says how `_lay_out` left `ys` and who
+    reads it: the buffer's rows (R, h), gathered by XLA
+    (`_weighted_sum`), or the kernel's packed rows, fetched by the
+    kernel ops/routed_combine, a DMA a row that is someone's (a dead
+    token has no position), the shared term and the rounding inside
+    it."""
+    if by_dma:
+        from ..ops.routed_combine import routed_combine
+        return routed_combine(ys, pos, w, shared,
+                              1.0 if scale is None else scale, dtype)
+    return _sum_end(_weighted_sum(ys, pos, w, live), shared, scale, dtype)
+
+
+def _sum_end(y, shared, scale, dtype):
+    """The end of XLA's sum: y (T, h) float32 plus `shared` in float32
+    (None: none), times `scale` where that is not None, ONE rounding."""
+    import jax.numpy as jnp
+    if shared is not None:
+        shared = shared.astype(jnp.float32)
+        if scale is not None:
+            shared = shared * scale
+        y = y + shared
+    return y.astype(dtype)
 
 
 def _moe(cfg, lp, x, live):
@@ -738,7 +818,9 @@ def _moe(cfg, lp, x, live):
     ride-along are not: they get no expert and do not count). The routed
     rows are laid out ONCE, by counting (`routed_positions`): the
     dispatch gathers them there, the product computes whole tiles of one
-    expert, the weighted sum reads them back by the same positions.
+    expert, the weighted sum reads them back by the same positions
+    (`_combine`: XLA's gather, or from COMBINE_KERNEL_FROM bytes of
+    products on a kernel's row DMAs; one sum, two carriers).
     Returns (y (T, h), counters)."""
     import jax
     import jax.numpy as jnp
@@ -759,20 +841,32 @@ def _moe(cfg, lp, x, live):
         parts = max(1, E // (held * HELD_SLACK))
         if T * k < HELD_SPLIT_FROM or T % parts:
             parts = 1
+    if parts > 1:
+        slots = T * k // parts
+    by_dma = combine_path(
+        lp, x, padded_rows(slots, held, tile)) == "row_dma_kernel"
     if parts == 1:
         ys, pos, group_sizes = _lay_out(lp, x, picks, mine, held, tile,
-                                        slots, average)
+                                        slots, average, by_dma)
     else:
-        slots = T * k // parts
-
         def routed(x, picks, mine, w, live):
             ys, pos, sizes = _lay_out(lp, x, picks, mine, held, tile, slots,
-                                      average)
+                                      average, by_dma)
+            # the picks' sum alone, in float32: the shared term and the
+            # rounding come behind the `lax.cond`, where XLA's sum has them
             with jax.named_scope("moe/combine"):
-                return _weighted_sum(ys, pos, w, live), sizes
+                return _combine(ys, pos, w, live, None, None, jnp.float32,
+                                by_dma), sizes
 
         def in_parts(*whole):
-            ys, sizes = jax.lax.map(lambda part: routed(*part), tuple(
+            # the barrier keeps a part's sum out of the fusion that stacks
+            # the parts: fused, XLA wants the whole stack in the combine
+            # kernel's scoped VMEM (34 MB of it at 4,096 tokens)
+            def one(part):
+                done = routed(*part)
+                return jax.lax.optimization_barrier(done) if by_dma else done
+
+            ys, sizes = jax.lax.map(one, tuple(
                 a.reshape(parts, T // parts, *a.shape[1:]) for a in whole))
             return ys.reshape(T, -1), jnp.sum(sizes, 0)
 
@@ -783,19 +877,26 @@ def _moe(cfg, lp, x, live):
             padded_rows(slots, held, tile)
         y, group_sizes = jax.lax.cond(fits, routed, in_parts,
                                       x, picks, mine, w, live)
+    shared, scale = None, None
     if cfg.n_shared_experts:
         with jax.named_scope("moe/shared"):
-            shared = _swiglu(x, lp["shared_gate"], lp["shared_up"],
-                             lp["shared_down"])
+            hidden = _swiglu_hidden(x, lp["shared_gate"], lp["shared_up"])
+            if by_dma and parts == 1:
+                # the shared experts' last product BEHIND the routed
+                # kernel, where XLA's own order has it when its fusion
+                # reads it: free of the kernel's output the scheduler
+                # finished them first and kept their (T, h) beside the
+                # routed rows and their products, the layer's peak (80
+                # MiB more at Xing's 16k bucket)
+                hidden, ys = jax.lax.optimization_barrier((hidden, ys))
+            shared = hidden @ lp["shared_down"]
+        if getattr(cfg, "shared_expert_combination", "sum") == "average":
+            scale = 1.0 / cfg.n_shared_experts
     with jax.named_scope("moe/combine"):
         if parts == 1:
-            y = _weighted_sum(ys, pos, w, live)
-        if cfg.n_shared_experts:
-            shared = shared.astype(jnp.float32)
-            if getattr(cfg, "shared_expert_combination", "sum") == "average":
-                shared = shared * (1.0 / cfg.n_shared_experts)
-            y = y + shared
-        y = y.astype(x.dtype)
+            y = _combine(ys, pos, w, live, shared, scale, x.dtype, by_dma)
+        else:
+            y = _sum_end(y, shared, scale, x.dtype)
     passes = jnp.any(live).astype(jnp.int32)
     zero = jnp.zeros_like(passes)
     kernel = expert_product_path(lp) == "grouped_swiglu_kernel"
@@ -806,7 +907,8 @@ def _moe(cfg, lp, x, live):
                 "kernel_passes": passes if kernel else zero,
                 # rows the kernel computed: its visits' whole tiles
                 "rows_computed": jnp.sum(-(-group_sizes // tile)) * tile
-                if kernel else zero}
+                if kernel else zero,
+                "combine_kernel_passes": passes if by_dma else zero}
     return y, counters
 
 
@@ -839,7 +941,7 @@ def _zero_counters(cfg):
                                            jnp.int32),
                 "router_tokens": zero, "experts_touched": zero,
                 "moe_passes": zero, "kernel_passes": zero,
-                "rows_computed": zero}
+                "rows_computed": zero, "combine_kernel_passes": zero}
     if cfg.hc_mult > 1:
         counters.update(hc_passes=zero, hc_rowsum_dev_ppm=zero)
     return counters
@@ -1073,7 +1175,10 @@ class _MoonlightServingModel(ServingModel):
         # where `ragged_dot` ran: every backend but the TPU), and
         # moe_rows_computed: the rows those passes computed (visits x row
         # tile), so sum(expert_tokens) / moe_rows_computed is the share of
-        # the kernel's rows that were someone's (0 rows without it). With
+        # the kernel's rows that were someone's (0 rows without it);
+        # moe_combine_kernel_passes: those of them whose combine was the
+        # kernel ops/routed_combine (`combine_path`: a prompt of
+        # COMBINE_KERNEL_FROM bytes of products; no decode step). With
         # residual streams (`hc_mult` > 1) also hc_passes: sublayers
         # mixed, by both programs, and hc_rowsum_dev_ppm: the sum over
         # those of the largest |row sum of H_res - 1| at a live token,
@@ -1081,7 +1186,8 @@ class _MoonlightServingModel(ServingModel):
         names = {"expert_tokens": (cfg.n_routed_experts,),
                  "router_tokens": (), "decode_router_tokens": (),
                  "decode_experts_touched": (), "decode_moe_passes": (),
-                 "moe_kernel_passes": (), "moe_rows_computed": ()}
+                 "moe_kernel_passes": (), "moe_rows_computed": (),
+                 "moe_combine_kernel_passes": ()}
         if cfg.hc_mult > 1:
             names.update(hc_passes=(), hc_rowsum_dev_ppm=())
         return names
@@ -1099,7 +1205,8 @@ class _MoonlightServingModel(ServingModel):
                    c["experts_touched"] if decode else zero,
                "decode_moe_passes": c["moe_passes"] if decode else zero,
                "moe_kernel_passes": c["kernel_passes"],
-               "moe_rows_computed": c["rows_computed"]}
+               "moe_rows_computed": c["rows_computed"],
+               "moe_combine_kernel_passes": c["combine_kernel_passes"]}
         out.update({name: c[name] for name in c if name.startswith("hc_")})
         return out
 

@@ -17,7 +17,22 @@ start plus the number of earlier (token, pick)s on the same expert, a
 blocked running count over the tokens (a triangular 0/1 matrix product a
 block, exact in float32, and a masked sum over the blocks' totals). The
 caller lays the rows out once and reads the products back by the same
-positions (models/moonlight.py::_moe).
+positions (models/moonlight.py::_moe, `_combine`): by XLA's gather out
+of the plain (R, h) output, or, for a long prompt, by the row DMAs of
+ops/routed_combine out of the PACKED one.
+
+THE PACKED OUTPUT (`packed=True`; bfloat16 of whole 256 lanes). A row of
+a (R, h) bfloat16 array is no piece of memory: a tile holds 16 rows, a
+32-bit word two of them. Packed, the kernel stores the same values,
+rounded to bfloat16 the same way, as 32-bit words that hold two COLUMN
+halves of ONE row (column j low, column j + h / 2 high: `pack_halves`, a
+shift, a mask and an or beside an MXU-bound product), and lays a row's
+h / 256 lines of 128 words TOGETHER: the output block is LINES, (tile *
+h / 256, 128), a chunk's 128-lane pieces go out by strided stores (one
+line of every row), and the caller sees (R, 1, h / 2) uint32, for XLA a
+bitcast of the same bytes ((1, 128) tiles: a row is one run of h * 2
+bytes, what a one-row DMA can fetch). Measured beside the plain store at
+the four models' widths: within 2% either way (PERF.md, PR 39).
 
 THE KERNEL. For the rows of expert e it computes `(silu(x W_gate[e]) *
 (x W_up[e])) W_down[e]` with float32 accumulation and a float32 `silu(g)
@@ -74,7 +89,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["grouped_swiglu", "row_tile_for", "padded_rows",
-           "routed_positions", "f_slices"]
+           "routed_positions", "f_slices", "pack_halves", "unpack_halves"]
 
 # the smallest row tile: one packed bfloat16 tile of 16 sublanes
 _MIN_TILE = 16
@@ -195,15 +210,19 @@ def _dot(a, b):
                    preferred_element_type=jnp.float32)
 
 
-def _products(x_ref, gate_ref, up_ref, down_ref, act_ref, store):
+def _products(x_ref, gate_ref, up_ref, down_ref, act_ref, store,
+              packed=False):
     """One visit's products over the F lanes the weight blocks hold, by
     chunks of output lanes (the module docstring): the activation into
-    `act_ref`, then `store(lanes of h, that chunk of act @ down)`."""
+    `act_ref`, then `store(lanes of h, that chunk of act @ down)`; with
+    `packed`, `store(n, words)` for chunk n of the packed row: the same
+    lanes of h's low and high half (`_lanes_of(h // 2)` of them), two
+    products in one word (`pack_halves`)."""
     from jax.experimental import pallas as pl
 
     h, F = gate_ref.shape
     fc = _LANES if F % _LANES == 0 else F
-    hc = _CHUNK if h % _CHUNK == 0 else h
+    hc = _lanes_of(h // 2) if packed else _CHUNK if h % _CHUNK == 0 else h
     x = x_ref[...]
 
     def f_step(f, _):
@@ -218,13 +237,59 @@ def _products(x_ref, gate_ref, up_ref, down_ref, act_ref, store):
 
     def h_step(n, _):
         at = pl.ds(pl.multiple_of(n * hc, hc), hc)
-        store(at, _dot(act, down_ref[:, at]))
+        part = _dot(act, down_ref[:, at])
+        if packed:
+            high = pl.ds(pl.multiple_of(h // 2 + n * hc, hc), hc)
+            store(n, pack_halves(part, _dot(act, down_ref[:, high])))
+        else:
+            store(at, part)
 
-    jax.lax.fori_loop(0, h // hc, h_step, None)
+    jax.lax.fori_loop(0, (h // 2 if packed else h) // hc, h_step, None)
+
+
+def _lanes_of(width):
+    """Lanes a chunk of a packed row's `width` words: the most of 512
+    down to 128 that divide it."""
+    return next(c for c in (512, 384, 256, 128) if width % c == 0)
+
+
+def pack_halves(lo, hi):
+    """Two float32 blocks as ONE block of 32-bit words: each value
+    rounded to bfloat16 (to nearest even, as a store in that type rounds
+    it), `lo`'s 16 bits in a word's low half and `hi`'s in its high."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    def bits(v):
+        return pltpu.bitcast(v.astype(jnp.bfloat16).astype(jnp.float32),
+                             jnp.uint32)
+    return (bits(lo) >> 16) | (bits(hi) & jnp.uint32(0xffff0000))
+
+
+def unpack_halves(word):
+    """(lo, hi) float32 of `pack_halves`'s words, exactly."""
+    from jax.experimental.pallas import tpu as pltpu
+    return (pltpu.bitcast(word << 16, jnp.float32),
+            pltpu.bitcast(word & jnp.uint32(0xffff0000), jnp.float32))
+
+
+def _store_packed(o_ref):
+    """`store(n, words)` of chunk n of a visit's packed rows into its
+    output block: LINES of `_LANES` words, (tile * h / 256, 128), a
+    row's lines together, so the chunk's 128-lane pieces go to every
+    row's line of that piece, a strided store each."""
+    from jax.experimental import pallas as pl
+
+    def store(n, word):
+        tile, pieces = word.shape[0], word.shape[1] // _LANES
+        S = o_ref.shape[0] // tile
+        for q in range(pieces):
+            o_ref[pl.ds(n * pieces + q, tile, stride=S), :] = \
+                word[:, q * _LANES:(q + 1) * _LANES]
+    return store
 
 
 def _kernel(expert_ref, tile_ref, count_ref, x_ref, gate_ref, up_ref,
-            down_ref, o_ref, act_ref):
+            down_ref, o_ref, act_ref, *, packed):
     from jax.experimental import pallas as pl
 
     del expert_ref, tile_ref             # the index maps' alone
@@ -234,11 +299,12 @@ def _kernel(expert_ref, tile_ref, count_ref, x_ref, gate_ref, up_ref,
         def store(at, part):
             o_ref[:, at] = part.astype(o_ref.dtype)
 
-        _products(x_ref, gate_ref, up_ref, down_ref, act_ref, store)
+        _products(x_ref, gate_ref, up_ref, down_ref, act_ref,
+                  _store_packed(o_ref) if packed else store, packed)
 
 
 def _kernel_in_slices(expert_ref, tile_ref, count_ref, x_ref, gate_ref,
-                      up_ref, down_ref, o_ref, act_ref, acc_ref):
+                      up_ref, down_ref, o_ref, act_ref, acc_ref, *, packed):
     """A visit in `pl.num_programs(1)` steps, a SLICE of F each (the
     expert's matrices do not fit VMEM whole): the slice's part of the
     down product is added up in float32 (`acc_ref`) and the tile stored
@@ -263,7 +329,19 @@ def _kernel_in_slices(expert_ref, tile_ref, count_ref, x_ref, gate_ref,
 
         @pl.when(f == last)
         def _():
-            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+            if not packed:
+                o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+                return
+            half = acc_ref.shape[1] // 2
+            hc, store_words = _lanes_of(half), _store_packed(o_ref)
+
+            def out_step(n, _):
+                low = pl.multiple_of(n * hc, hc)
+                store_words(n, pack_halves(
+                    acc_ref[:, pl.ds(low, hc)],
+                    acc_ref[:, pl.ds(pl.multiple_of(half + low, hc), hc)]))
+
+            jax.lax.fori_loop(0, half // hc, out_step, None)
 
 
 def f_slices(h, F, itemsize):
@@ -279,8 +357,9 @@ def f_slices(h, F, itemsize):
     return n
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def _call(xs, w_gate, w_up, w_down, group_sizes, tile, interpret):
+@functools.partial(jax.jit, static_argnames=("tile", "interpret", "packed"))
+def _call(xs, w_gate, w_up, w_down, group_sizes, tile, interpret,
+          packed=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -295,43 +374,49 @@ def _call(xs, w_gate, w_up, w_down, group_sizes, tile, interpret):
     # values (and the sliced visit's float32 tile)
     vmem = (2 * 3 * h * Fs * item
             + (4 * h + Fs) * tile * jnp.dtype(xs.dtype).itemsize + 8 * _MIB)
-    params = dict(
-        out_shape=jax.ShapeDtypeStruct((R, h), xs.dtype),
-        interpret=interpret)
+    if packed:
+        # LINES of 128 words, a row's h / 256 of them together; the caller
+        # sees (R, 1, h / 2), the same bytes (a bitcast for XLA)
+        out = jax.ShapeDtypeStruct((R * h // (2 * _LANES), _LANES), jnp.uint32)
+        out_block = (tile * h // (2 * _LANES), _LANES)
+    else:
+        out = jax.ShapeDtypeStruct((R, h), xs.dtype)
+        out_block = (tile, h)
+    params = dict(out_shape=out, interpret=interpret)
+    shape = (R, 1, h // 2) if packed else (R, h)
     if slices == 1:
-        rows = pl.BlockSpec((tile, h), lambda i, e, t, *_: (t[i], 0))
         return pl.pallas_call(
-            _kernel,
+            functools.partial(_kernel, packed=packed),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,
                 grid=(R // tile,),
                 in_specs=[
-                    rows,
+                    pl.BlockSpec((tile, h), lambda i, e, t, *_: (t[i], 0)),
                     pl.BlockSpec((None, h, F), lambda i, e, *_: (e[i], 0, 0)),
                     pl.BlockSpec((None, h, F), lambda i, e, *_: (e[i], 0, 0)),
                     pl.BlockSpec((None, F, h), lambda i, e, *_: (e[i], 0, 0)),
                 ],
-                out_specs=rows,
+                out_specs=pl.BlockSpec(out_block,
+                                       lambda i, e, t, *_: (t[i], 0)),
                 scratch_shapes=[pltpu.VMEM((tile, F), xs.dtype)]),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
                 vmem_limit_bytes=int(min(vmem, 110 * _MIB))),
             name="grouped_swiglu", **params,
-        )(*walk, xs, w_gate, w_up, w_down)
+        )(*walk, xs, w_gate, w_up, w_down).reshape(shape)
 
     # a step past the walk's count repeats the last visit's LAST slice:
     # it fetches nothing
     def at(i, f, c):
         return jnp.where(i < c[0], f, slices - 1)
 
-    rows = pl.BlockSpec((tile, h), lambda i, f, e, t, c: (t[i], 0))
     return pl.pallas_call(
-        _kernel_in_slices,
+        functools.partial(_kernel_in_slices, packed=packed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(R // tile, slices),
             in_specs=[
-                rows,
+                pl.BlockSpec((tile, h), lambda i, f, e, t, c: (t[i], 0)),
                 pl.BlockSpec((None, h, Fs),
                              lambda i, f, e, t, c: (e[i], 0, at(i, f, c))),
                 pl.BlockSpec((None, h, Fs),
@@ -339,17 +424,19 @@ def _call(xs, w_gate, w_up, w_down, group_sizes, tile, interpret):
                 pl.BlockSpec((None, Fs, h),
                              lambda i, f, e, t, c: (e[i], at(i, f, c), 0)),
             ],
-            out_specs=rows,
+            out_specs=pl.BlockSpec(out_block,
+                                   lambda i, f, e, t, c: (t[i], 0)),
             scratch_shapes=[pltpu.VMEM((tile, Fs), xs.dtype),
                             pltpu.VMEM((tile, h), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=int(min(vmem + 4 * tile * h, 110 * _MIB))),
         name="grouped_swiglu_sliced", **params,
-    )(*walk, xs, w_gate, w_up, w_down)
+    )(*walk, xs, w_gate, w_up, w_down).reshape(shape)
 
 
-def grouped_swiglu(xs, w_gate, w_up, w_down, group_sizes, row_tile):
+def grouped_swiglu(xs, w_gate, w_up, w_down, group_sizes, row_tile,
+                   packed=False):
     """The grouped SwiGLU of rows laid out by `routed_positions`.
 
     xs: (R, h), R a multiple of `row_tile`: the rows of expert 0, then,
@@ -365,6 +452,13 @@ def grouped_swiglu(xs, w_gate, w_up, w_down, group_sizes, row_tile):
     never read. row_tile: rows a visit computes (a multiple of 16), the
     layout's own (`row_tile_for` of the routed row count).
 
+    packed (static; bfloat16 rows of whole 256 lanes alone): the SAME
+    values, rounded to bfloat16 the same way, as (R, 1, h / 2) uint32: a
+    row's words lie TOGETHER in memory (one row a (1, 128)-tiled slab,
+    where a (R, h) bfloat16 array interleaves 16 rows a tile), word j
+    holding column j in its low half and column j + h / 2 in its high
+    (`pack_halves`): what ops/routed_combine fetches a row a DMA.
+
     Compiled by Mosaic on a TPU backend, interpreted on the CPU (a test
     facility), an error on any other backend: an interpreted kernel must
     not pass for the real one."""
@@ -378,5 +472,9 @@ def grouped_swiglu(xs, w_gate, w_up, w_down, group_sizes, row_tile):
         raise ValueError(f"row_tile {tile} is no multiple of {_MIN_TILE}")
     if xs.shape[0] % tile:
         raise ValueError(f"{xs.shape[0]} rows are no whole tiles of {tile}")
+    if packed and (xs.dtype != jnp.bfloat16 or xs.shape[1] % (2 * _LANES)):
+        raise ValueError(
+            f"packed rows are bfloat16 of whole {2 * _LANES} lanes; got "
+            f"{xs.dtype} of {xs.shape[1]}")
     return _call(xs, w_gate, w_up, w_down, group_sizes, tile,
-                 platform == "cpu")
+                 platform == "cpu", bool(packed))

@@ -77,14 +77,19 @@ def test_kernels_phase_both_families_interpreted():
 def test_share_kernels_phase_interpreted():
     """The phase at a small size: the grouped paged kernel over a ring,
     the flash band and the expert layer of a share, each against its
-    twin (the kernels interpreted, the expert product `ragged_dot`)."""
+    twin (the kernels interpreted, the expert product `ragged_dot`), and
+    the combine kernel against XLA's gather."""
     facts = chip_smoke.phase_share_kernels(
-        heads=8, kv_heads=2, head_dim=128, window=32, block=8, hidden=128,
-        width=256, experts=16, held=4, picks=4, shared=2, rows=256, tokens=64)
+        heads=8, kv_heads=2, head_dim=128, window=32, block=8, hidden=256,
+        width=256, experts=16, held=4, picks=4, shared=2, rows=256, tokens=64,
+        combine_tokens=48, combine_hidden=512)
     assert (facts["paged_group"], facts["paged_ring"]) == (4, 5)
     assert max(facts[k] for k in facts if k.endswith("rel_err")
                or k.startswith("moe_rel_err")) <= chip_smoke.KERNEL_REL_TOL
     assert 0 < facts["moe_held_picks_64"] < 64 * 4
+    live = sum(1 for n in range(48) if n % 7 != 3)
+    assert facts["combine_rows_fetched_mellum"] == live * 4
+    assert 0 < facts["combine_rows_fetched_command_a"] < live * 4
 
 
 def test_train_then_serve_phases():

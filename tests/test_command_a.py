@@ -481,6 +481,7 @@ def test_the_engine_serves_the_references_greedy_tokens(params, p_len, new):
     assert st["moe_picks_held"] == sum(st["expert_tokens"])
     assert 0 < st["moe_picks_held"] < st["moe_picks_routed"]
     assert st["decode_moe_picks_routed"] == 4 * st["decode_router_tokens"]
+    assert st["moe_combine_kernel_passes"] == 0          # the CPU gathers
     assert 0 <= st["decode_moe_picks_held"] <= st["moe_picks_held"]
     assert st["decode_rows_full"] > 0 and st["decode_rows_window"] > 0
     assert st["compiled_executables"] <= 3 + 2

@@ -244,3 +244,86 @@ def test_compiles_at_the_models_widths_for_a_described_v5e(one_chip, h, f,
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     # the weights go in where they lie: nothing of their size is made
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
+@pytest.mark.parametrize("h,f,k,tokens,router,held,parts,shared", [
+    (2304, 896, 8, 16384, 64, 64, 1, False),      # Mellum's largest bucket
+    (3584, 1024, 4, 16384, 64, 64, 1, True),      # Xing's
+    (2048, 1408, 6, 8192, 64, 64, 1, True),       # Moonlight's
+    # command-a's, in slices: under its `lax.cond` the kernel hands out
+    # the picks' float32 sum, the shared term comes behind
+    (4096, 4096, 8, 8192, 128, 16, 4, False),
+], ids=["mellum_16384", "xing_16384", "moonlight_8192", "command_a_8192"])
+def test_a_prompts_two_kernels_compile_for_a_described_v5e(
+        one_chip, h, f, k, tokens, router, held, parts, shared):
+    """The packed store of the expert kernel and the combine kernel that
+    reads it (ops/routed_combine.py), at the buckets that take them: a
+    row's lines stored by strided stores, fetched by a one-row DMA,
+    summed through strided loads; nothing of the products' size is
+    copied between the two (the reshape of the lines to rows is a
+    bitcast)."""
+    from paddle_tpu.ops import routed_combine as rc
+    tile = gs.row_tile_for(tokens * k, router)
+    rows = gs.padded_rows(tokens * k // parts, held, tile)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def both(xs, gate, up, down, sizes, pos, w, term):
+        ys = gs._call(xs, gate, up, down, sizes, tile=tile, interpret=False,
+                      packed=True)
+        return rc._call(ys, pos, w, term, 0.25, jnp.dtype(
+            jnp.bfloat16 if parts == 1 else jnp.float32), False)
+
+    compiled = jax.jit(both).lower(
+        shape(rows, h), shape(held, h, f), shape(held, h, f),
+        shape(held, f, h), shape(held, dtype=jnp.int32),
+        shape(tokens, k, dtype=jnp.int32), shape(tokens, k, dtype=jnp.float32),
+        shape(tokens, h) if shared else None).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    # the products (rows x h x 2 bytes) are the one large temporary
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < rows * h * 2 + (8 << 20)
+
+
+def test_the_mixers_rounds_keep_their_layout_behind_the_combine_kernel(
+        one_chip, monkeypatch):
+    """Xing's feed-forward sublayer and the next one's coefficients at its
+    widths, 4,096 tokens, with the two kernels compiled: every array of
+    the Sinkhorn rounds keeps the tokens in the lanes (the last axis
+    minor). Handed on token-first, the coefficients took the layout of
+    whatever read them, and behind the combine kernel's output that put
+    the 4 streams in the lanes (`{0,2,1`: `hc/coeff` 4.6 times slower on
+    the chip; PERF.md, PR 39)."""
+    import re
+    from paddle_tpu.models import moonlight as ml
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ml.MoonlightConfig(
+        vocab_size=512, hidden=3584, layers=2, heads=4, kv_lora_rank=128,
+        qk_nope_head_dim=32, qk_rope_head_dim=32, v_head_dim=32,
+        intermediate=256, moe_intermediate=1024, n_routed_experts=64,
+        n_shared_experts=1, experts_per_tok=4, first_k_dense=1, max_pos=64,
+        hc_mult=4)
+    tokens = 4096
+    lp = jax.eval_shape(lambda: ml.init_params(
+        cfg, jax.random.PRNGKey(0), jnp.bfloat16))["layers"][1]
+
+    def two_sublayers(lp, x, live):
+        counters = ml._zero_counters(cfg)
+        for _ in range(2):
+            x, counters = ml._ffn_sublayer(cfg, lp, x, live, counters)
+        return x, counters
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    text = jax.jit(two_sublayers).lower(
+        jax.tree_util.tree_map(on_chip, lp),
+        jax.ShapeDtypeStruct((tokens, 4, 3584), jnp.bfloat16,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((tokens,), jnp.bool_, sharding=one_chip),
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    rounds = re.findall(r"f32\[4,4,%d\]\{([0-9,]+)" % tokens, text)
+    assert len(rounds) > 40 and set(rounds) <= {"2,0,1", "2,1,0"}, set(rounds)

@@ -201,9 +201,9 @@ def test_no_dense_product_and_no_dropped_token(params, monkeypatch):
     seen = []
     real = ml.grouped_experts
 
-    def spy(lp, xs, sizes, tile):
+    def spy(lp, xs, sizes, tile, packed=False):
         seen.append((xs.shape[0], int(np.asarray(sizes).sum()), tile))
-        return real(lp, xs, sizes, tile)
+        return real(lp, xs, sizes, tile, packed)
 
     monkeypatch.setattr(ml, "grouped_experts", spy)
     T = 23
@@ -267,7 +267,23 @@ def _moe_by_experts(cfg, lp, x, live):
     return out
 
 
-@pytest.mark.parametrize("product", ["ragged_dot", "grouped_swiglu_kernel"])
+def _wide_layer(lp, hidden):
+    """`lp` (an expert layer of CFG's) at `hidden` lanes in bfloat16, the
+    type and width the combine kernel takes: seeded matrices of the
+    widths' own shapes, the router's bias kept."""
+    rng = np.random.default_rng(hidden)
+    E, _, F = lp["w_gate"].shape
+    shapes = {"router": (hidden, E), "w_gate": (E, hidden, F),
+              "w_up": (E, hidden, F), "w_down": (E, F, hidden),
+              "shared_gate": (hidden, F), "shared_up": (hidden, F),
+              "shared_down": (F, hidden)}
+    wide = {name: jnp.asarray(rng.normal(0, 0.1, shape), jnp.float32)
+            .astype(jnp.bfloat16) for name, shape in shapes.items()}
+    return dict(wide, router_bias=lp["router_bias"])
+
+
+@pytest.mark.parametrize("product", ["ragged_dot", "grouped_swiglu_kernel",
+                                     "row_dma_kernel"])
 @pytest.mark.parametrize("shared", [1, 0], ids=["shared", "no_shared"])
 @pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
 def test_the_expert_layer_against_a_loop_over_experts(
@@ -275,23 +291,35 @@ def test_the_expert_layer_against_a_loop_over_experts(
     """`_moe` whole (layout by counting, product, weighted sum by the
     same positions) under both scoring rules, with and without a shared
     expert, through the interpreted kernel and through `ragged_dot`; the
-    last three tokens are not live and get the shared expert alone."""
+    last three tokens are not live and get the shared expert alone.
+    "row_dma_kernel": the grouped kernel's packed rows read back by the
+    combine kernel (`combine_path`, its threshold at 0), on a bfloat16
+    layer of 256 lanes, to bfloat16's tolerance: the activation and every
+    product are rounded to 8 bits where the loop keeps float32."""
     import types
     cfg = types.SimpleNamespace(
         experts_per_tok=3, n_routed_experts=CFG.n_routed_experts,
         n_shared_experts=shared, router_scoring=scoring,
         routed_scaling_factor=CFG.routed_scaling_factor)
-    lp = params["layers"][1]
-    x = jnp.asarray(np.random.default_rng(17).normal(0, 1, (29, CFG.hidden)),
-                    jnp.float32)
+    lp, hidden, dtype, atol = params["layers"][1], CFG.hidden, jnp.float32, 2e-5
+    by_dma = product == "row_dma_kernel"
+    if by_dma:
+        product, hidden, dtype, atol = "grouped_swiglu_kernel", 256, \
+            jnp.bfloat16, 3e-2
+        lp = _wide_layer(lp, hidden)
+        monkeypatch.setattr(ml, "COMBINE_KERNEL_FROM", 0)
+    x = jnp.asarray(np.random.default_rng(17).normal(0, 1, (29, hidden)),
+                    jnp.float32).astype(dtype)
     live = np.arange(29) < 26
     monkeypatch.setattr(ml, "expert_product_path", lambda lp: product)
     got, counters = ml._moe(cfg, lp, x, jnp.asarray(live))
-    np.testing.assert_allclose(np.asarray(got),
-                               _moe_by_experts(cfg, lp, x, live), atol=2e-5)
+    want = _moe_by_experts(cfg, lp, x, live)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, atol=atol,
+                               rtol=2e-2 if by_dma else 1e-7)
     assert int(counters["expert_tokens"].sum()) == 26 * 3
+    assert int(counters["combine_kernel_passes"]) == int(by_dma)
     if not shared:
-        assert not np.asarray(got)[~live].any()
+        assert not np.asarray(got, np.float32)[~live].any()
 
 
 def _primitives(jaxpr):
@@ -616,6 +644,7 @@ def test_engine_serves_moonlight_and_counts_without_another_sync(
     assert stats["decode_moe_passes"] <= stats["dispatches"] * 4 * n_moe
     assert stats["moe_kernel_passes"] == 0       # the CPU: `ragged_dot` ran
     assert stats["moe_rows_computed"] == 0       # so the kernel computed none
+    assert stats["moe_combine_kernel_passes"] == 0   # and XLA's gather combined
     for prompt, req in zip(prompts, reqs):
         seq = list(prompt) + list(req.tokens)
         rows = reference_logits(params, seq)[len(prompt) - 1:-1]

@@ -189,7 +189,8 @@ def test_mixer_coefficients_match_reference_and_are_doubly_stochastic(
     hp = params["layers"][2]["hc_ffn"]
     X = jnp.asarray(np.random.default_rng(9).normal(0, 1, (37, 4, 64)),
                     jnp.float32)
-    h_pre, h_post, h_res = (np.asarray(a)
+    # the block keeps the token axis LAST: (n, T), (n, T), (n, n, T)
+    h_pre, h_post, h_res = (np.moveaxis(np.asarray(a), -1, 0)
                             for a in ml.hc_coefficients(cfg, hp, X))
     with jax.default_matmul_precision("highest"):
         want = ref.mixer_coefficients(X, hp, dict(REF_CFG,
@@ -209,7 +210,7 @@ def test_the_clamp_sits_before_the_exponential(params):
     hp = dict(params["layers"][0]["hc_attn"], a_res=jnp.float32(400.0))
     X = jnp.asarray(np.random.default_rng(1).normal(0, 1, (11, 4, 64)),
                     jnp.float32)
-    _, _, h_res = ml.hc_coefficients(CFG, hp, X)
+    h_res = jnp.moveaxis(ml.hc_coefficients(CFG, hp, X)[2], -1, 0)
     with jax.default_matmul_precision("highest"):
         want = ref.mixer_coefficients(X, hp, REF_CFG)[2]
     assert np.isfinite(np.asarray(h_res)).all()

@@ -20,7 +20,11 @@ the microseconds of each stage a line gives
     tile) pair of groups laid end to end: the layout before PR 37);
   * `dispatch_hbm`, `combine_hbm`: one copy of the bucket's routed rows
     (tokens x picks x h x 2 B; the held share of them where the layer
-    holds a share of the experts) over 819 GB/s, over the stage's time.
+    holds a share of the experts) over 819 GB/s, over the stage's time;
+  * `combine_ns_row`: `combine_us` over the bucket's tokens x picks, and
+    `combine_path`: which carrier the case's combine took (`_moe`'s
+    counter `combine_kernel_passes`: "row_dma_kernel", else "gather"; a
+    checkout without the counter has the gather alone).
 A chip is required: on any other backend it exits 1 with nothing
 measured.
 
@@ -166,7 +170,9 @@ def bench(name, model, ml, gs):
         computed = old_layout_rows(sizes.tolist(), rows_tile) \
             if computed is None else int(computed)
         fact = dict(tokens=tokens, live=length, tile=rows_tile,
-                    rows_routed=int(sizes.sum()), rows_computed=computed)
+                    rows_routed=int(sizes.sum()), rows_computed=computed,
+                    combine_path="row_dma_kernel" if int(counters.get(
+                        "combine_kernel_passes", 0)) else "gather")
         with tempfile.TemporaryDirectory() as trace_dir:
             jax.profiler.start_trace(trace_dir)
             for _ in range(REPEATS):
@@ -196,6 +202,7 @@ def bench(name, model, ml, gs):
                        / (us["experts"] * 1e-6), 1),
                    dispatch_hbm=round(100 * copy_us / us["dispatch"], 1),
                    combine_hbm=round(100 * copy_us / us["combine"], 1),
+                   combine_ns_row=round(1e3 * us["combine"] / (tokens * k), 1),
                    kinds={s: {kind: round(1e6 * v / runs, 1)
                               for kind, v in sorted(
                                   entry["kinds"].get(f"moe/{s}", {}).items(),
@@ -208,7 +215,7 @@ def bench(name, model, ml, gs):
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--models", default="moonlight,xing,mellum")
+    ap.add_argument("--models", default="moonlight,xing,mellum,commanda")
     ap.add_argument("--repo", default=os.path.join(HERE, ".."))
     ap.add_argument("--tile", type=int, default=None)
     ap.add_argument("--tag", default="")
@@ -235,7 +242,8 @@ def main():
         results += rows
         cols = ("case", "tokens", "live", "tile", "rows_computed",
                 *(f"{s}_us" for s in STAGES), "layer_us", "flops_routed",
-                "flops_computed", "dispatch_hbm", "combine_hbm")
+                "flops_computed", "dispatch_hbm", "combine_hbm",
+                "combine_ns_row", "combine_path")
         print(f"# {name}" + (f" ({args.tag})" if args.tag else ""))
         print(" | ".join(cols))
         for row in rows:
