@@ -32,6 +32,7 @@ CPU); main() alone insists on the TPU.
 
 import http.client
 import json
+import math
 import sys
 import threading
 import time
@@ -343,6 +344,101 @@ def phase_share_kernels(heads=128, kv_heads=8, head_dim=128, window=4096,
         facts[f"combine_rel_err_{name}"] = err
         facts[f"combine_rows_fetched_{name}"] = int(sizes.sum())
     return facts
+
+
+# ---------------------------------------------------------------------------
+# phase 2c: generation by diffusion over blocks through the kernel paths
+# ---------------------------------------------------------------------------
+
+def phase_block_diffusion(hidden=256, heads=8, kv_heads=2, head_dim=128,
+                          width=128, experts=8, picks=2, vocab=512, layers=2,
+                          prompt_len=130, max_new=6, bucket=256, page=128,
+                          force_kernels=False):
+    """One request of a small block-diffusion model (models/sdar:
+    lane-aligned widths, bfloat16) through the engine: the prefill of the
+    prompt's whole blocks by the flash forward with `block=`, then two
+    blocks of passes through the block-row paged kernel (a prompt of 130
+    tokens leaves two of the first block fixed, so the first commit emits
+    2 tokens and the second 4). Every served token's logit, at the pass
+    that fixed it, against its position's largest by the program's own
+    float32 forward over the whole sequence (XLA attention, no cache).
+    `force_kernels` (the CPU test): take the kernel paths interpreted."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.models import mellum, sdar
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    cfg = sdar.SdarConfig(
+        vocab_size=vocab, hidden=hidden, layers=layers, heads=heads,
+        kv_heads=kv_heads, head_dim=head_dim, moe_intermediate=width,
+        n_routed_experts=experts, experts_per_tok=picks,
+        max_pos=max(4 * page, 2 * bucket), mask_token_id=vocab - 1,
+        init_range=0.05, name="sdar-smoke")
+    params = sdar.init_params(cfg, jax.random.PRNGKey(40), jnp.bfloat16)
+    paths = (mellum.decode_attention_path, mellum.prefill_attention_path)
+    if force_kernels:
+        for module in (sdar, mellum):     # the programs' and the verdicts'
+            module.decode_attention_path = \
+                lambda a, c=None: {"full": "paged_kernel"}
+            module.prefill_attention_path = lambda a, b, c=None: "flash"
+    try:
+        engine = ServingEngine(params, cfg, ServingConfig(
+            num_slots=2, prefill_buckets=(bucket,), max_len=cfg.max_pos,
+            block_size=page))
+        prompt = np.random.default_rng(40).integers(0, vocab - 1, prompt_len)
+        req = engine.submit(prompt, max_new)
+        engine.run_until_drained()
+        stats = engine.stats()
+    finally:
+        for module in (sdar, mellum):
+            module.decode_attention_path, module.prefill_attention_path = paths
+    B = cfg.block_length
+    _require(req.state == "finished" and len(req.tokens) == max_new
+             and len(req.fixed_at) == len(req.confidence) == max_new,
+             f"block_diffusion: {len(req.tokens)} of {max_new} tokens served")
+    _require(stats["decode_attention"] == {"full": "paged_kernel"}
+             and stats["prefill_attention"]["path"] == "flash",
+             "block_diffusion: a pass or the prefill gathered: "
+             f"{stats['decode_attention']}, {stats['prefill_attention']}")
+    whole = prompt_len // B * B
+    first = B - (prompt_len - whole)
+    blocks = 1 + -(-(max_new - first) // B)
+    _require(stats["blocks_committed"] == blocks,
+             f"block_diffusion: {stats['blocks_committed']} blocks committed, "
+             f"not {blocks}")
+    # replay each pass in float32: the block with the tokens fixed before it
+    # in place and the mask elsewhere, behind the prompt and the blocks so far
+    wide = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params)
+    seq = list(prompt) + list(req.tokens)
+    fixed = [-1] * (prompt_len - whole) + list(req.fixed_at)
+    sure = [1.0] * (prompt_len - whole) + list(req.confidence)
+    worst = drift = 0.0
+    for start in range(whole, len(seq) - B + 1, B):
+        at = fixed[start - whole:start - whole + B]
+        for s in range(max(at) + 1):
+            block = [t if f < s else cfg.mask_token_id
+                     for t, f in zip(seq[start:start + B], at)]
+            logits = np.array(sdar.forward_logits(
+                wide, cfg, jnp.asarray(seq[:start] + block)))[start:]
+            logits[:, cfg.mask_token_id] = -np.inf
+            for j in (j for j in range(B) if at[j] == s):
+                served = float(logits[j, seq[start + j]])
+                worst = max(worst, float(logits[j].max()) - served)
+                # the confidence the pass returned against the replay's
+                top = float(logits[j].max())
+                lse = top + math.log(float(np.exp(logits[j] - top).sum()))
+                drift = max(drift, abs(
+                    math.log(sure[start - whole + j]) - (served - lse)))
+    _require(worst <= LOGIT_MARGIN,
+             f"block_diffusion: a served token lies {worst} under its "
+             "position's best logit at the pass that fixed it")
+    _require(drift <= LOGIT_MARGIN,
+             f"block_diffusion: a returned confidence lies {drift} (in "
+             "logs) from the replay's probability of its token")
+    return {"blocks_committed": blocks, "block_passes": stats["block_passes"],
+            "max_logit_deficit": worst, "max_confidence_drift": drift,
+            "fixed_at": list(req.fixed_at)}
 
 
 # ---------------------------------------------------------------------------
@@ -724,6 +820,7 @@ def main():
 
     run("kernels", phase_kernels)
     run("share_kernels", phase_share_kernels)
+    run("block_diffusion", phase_block_diffusion)
     # the published context (tiled kernels), then s=512 (single-pass)
     long_run = run("train_s1024", phase_train, cfg, batch=8, seq=1024)
     run("train_s512", phase_train, cfg, batch=16, seq=512)

@@ -49,8 +49,10 @@ beside the expert layer's: `decode_rows_full`, `decode_rows_window`, the
 rows a decode step had to attend, summed over live slots and that kind's
 layers.
 
-Not built, because the published config has no key for it: a per-head
-q/k norm, a multi-token-prediction head. Refused by the engine from
+Not built, because the published config has no key for it: a
+multi-token-prediction head; a per-head q/k norm is not THIS model's
+(models/sdar, which shares this config, these weights and the cache group
+and brings its own projections, has one). Refused by the engine from
 `features`: int8 weights or cache, adapters, speculation, a mesh plan,
 chunked prefill; and, having two cache groups, host swap and migration.
 """

@@ -156,7 +156,7 @@ def _walk_to(length, walk, block_q, block_k):
 def _fwd_kernel(qt_ref, kt_ref, bits_ref, meta_ref, q_ref, k_ref, v_ref,
                 b_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, sm_scale,
                 causal, block_q, block_k, kv_len, window, ragged, single,
-                chunk):
+                chunk, block=None):
     from jax.experimental import pallas as pl
 
     d = v_ref.shape[-1]       # the output's width: v's, not q's
@@ -185,6 +185,12 @@ def _fwd_kernel(qt_ref, kt_ref, bits_ref, meta_ref, q_ref, k_ref, v_ref,
             if causal:
                 row = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
                        + q_idx * block_q)
+                if block is not None:
+                    # block-causal: row i attends columns j <= i | (B - 1),
+                    # the whole of its own block of B rows (B a power of
+                    # two that divides the tiles: the diagonal tiles' mask
+                    # alone differs, the walk is the causal one)
+                    row = row | (block - 1)
                 s = jnp.where(row >= col, s, _NEG_INF)
                 if window is not None:
                     # row i attends columns j with i - window < j
@@ -548,7 +554,7 @@ def _pad_to(x, axis, mult):
 
 
 def _flash_call(q, k, v, bias, causal, sm_scale, interpret, blocks=None,
-                group=1, window=None, length=None):
+                group=1, window=None, length=None, block=None):
     """The forward. q: (bn, sq, d); k: (bn, sk, d); v: (bn, sk, dv); bias:
     (bn, sk) or None. Returns o (bn, sq, dv) unpadded and lse (bn, sq_pad,
     128) lane-padded.
@@ -574,6 +580,9 @@ def _flash_call(q, k, v, bias, causal, sm_scale, interpret, blocks=None,
         in lse, whatever q, k and v hold there (no real row attends them:
         causal). None: every row is real and the walk is a compile-time
         constant.
+      * `block` (causal self-attention, a power of two that divides both
+        tiles): the mask is BLOCK-causal, row i attends j <= i | (block -
+        1); with a `length` that is a multiple of it.
     `blocks` (block_q, block_k) stands in for `_pick_blocks`' choice where
     a caller knows its grid better (`flash_causal_rows`)."""
     from jax.experimental import pallas as pl
@@ -594,6 +603,13 @@ def _flash_call(q, k, v, bias, causal, sm_scale, interpret, blocks=None,
             "a window or a length is causal self-attention without a bias, "
             "its KV tile a multiple of its query tile")
 
+    if block is not None and (
+            not causal or block & (block - 1) or block_q % block
+            or block_k % block or window is not None):
+        raise ValueError(
+            f"a block-causal mask of {block} rows is causal, without a "
+            f"window, a power of two that divides the tiles "
+            f"({block_q}, {block_k})")
     walk = _walk(sq // block_q, block_q, block_k, causal, window, sk0)
     if length is None:
         qt, kt, bits = walk[:3]
@@ -607,7 +623,8 @@ def _flash_call(q, k, v, bias, causal, sm_scale, interpret, blocks=None,
         block_k=block_k, kv_len=sk0, ragged=length is not None,
         window=None if window is None else int(window),
         single=walk[0].shape[0] == 1,
-        chunk=block_k if qt.shape[0] == 1 else min(block_k, _CHUNK))
+        chunk=block_k if qt.shape[0] == 1 else min(block_k, _CHUNK),
+        block=block)
 
     q_map = lambda i, s, qt, kt, *_: (i, qt[s], 0)
     kv_map = lambda i, s, qt, kt, *_: (i // group, kt[s], 0)
@@ -912,19 +929,22 @@ def causal_rows_tiles(rows, length=None, window=None):
     return int(n[:live].sum()), int(n.sum())
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("sm_scale", "interpret", "window"))
-def _causal_rows_call(q, k, v, length, sm_scale, interpret, window=None):
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret",
+                                             "window", "block"))
+def _causal_rows_call(q, k, v, length, sm_scale, interpret, window=None,
+                      block=None):
     # jitted like ops/paged_attention's calls: a program that unrolls its
     # layers traces and lowers the kernel once and calls it from each
     o, _ = _flash_call(
         q.swapaxes(0, 1), k.swapaxes(0, 1), v.swapaxes(0, 1), None, True,
         sm_scale, interpret, blocks=_causal_rows_blocks(q.shape[0], window),
-        group=q.shape[1] // k.shape[1], window=window, length=length)
+        group=q.shape[1] // k.shape[1], window=window, length=length,
+        block=block)
     return o.swapaxes(0, 1)
 
 
-def flash_causal_rows(q, k, v, sm_scale, window=None, length=None):
+def flash_causal_rows(q, k, v, sm_scale, window=None, length=None,
+                      block=None):
     """Causal self-attention of ONE sequence's rows by the tiled flash
     forward, for a serving prefill: q (rows, heads, d), k (rows, kv_heads,
     d), v (rows, kv_heads, dv) as the projections leave them (kv_heads
@@ -937,6 +957,11 @@ def flash_causal_rows(q, k, v, sm_scale, window=None, length=None):
     row count inside its bucket. The kernel walks only the tiles that hold
     work: at or below the diagonal, inside the window, below `length`.
     Rows at or past `length` come back ZERO, never what the buffer held.
+
+    `block` (None: causal) makes the mask BLOCK-causal for a model that
+    generates by diffusion over blocks: row i attends rows 0 .. i | (block
+    - 1), its own block of `block` rows whole. A power of two that divides
+    the tiles and `length`; only the diagonal tiles' mask changes.
 
     Up to _ONE_TILE_ROWS the sequence is one tile a head: one visit
     (`_pick_blocks` would cut 768 rows, no multiple of 512, into 36 tiles
@@ -951,7 +976,8 @@ def flash_causal_rows(q, k, v, sm_scale, window=None, length=None):
             f"on CPU for tests; the active backend is {platform!r}")
     return _causal_rows_call(q, k, v, length, float(sm_scale),
                              platform == "cpu",
-                             window=None if window is None else int(window))
+                             window=None if window is None else int(window),
+                             block=None if block is None else int(block))
 
 
 def attention(q, k, v, bias=None, causal: bool = False,

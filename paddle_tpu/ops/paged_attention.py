@@ -216,7 +216,8 @@ _GROUPED_BUFFERS = 3
 
 def _grouped_kernel(layer_ref, pt_ref, lo_ref, len_ref, q_ref, new_ref,
                     arena_ref, arena_out_ref, o_ref, kv_buf, stage, writing,
-                    sems, wsem, *, block_size, pages, group, buffers):
+                    sems, wsem, *, block_size, pages, group, buffers,
+                    block=1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -327,12 +328,22 @@ def _grouped_kernel(layer_ref, pt_ref, lo_ref, len_ref, q_ref, new_ref,
         g_last = n_groups - 1
         buf = landed(g_last)
         i_live = last - (first + g_last * group)
-        at = jax.lax.rem(length - 1, bs)
+        # BLOCK ROWS: the pass's `block` new rows are positions length -
+        # block .. length - 1, which one page holds (`block` divides the
+        # page and the first of them); 1 is the one row of a decode step
+        at = jax.lax.rem(length - block, bs)
         r0 = pl.multiple_of(i_live * bs, bs)
         page = kv_buf[buf, :, pl.ds(r0, bs), :]
         row = jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
-        page = jnp.where(row == at, new_ref[0].astype(jnp.float32),
-                         page.astype(jnp.float32)).astype(stage.dtype)
+        if block == 1:
+            page = jnp.where(row == at, new_ref[0].astype(jnp.float32),
+                             page.astype(jnp.float32)).astype(stage.dtype)
+        else:
+            new = new_ref[0].astype(jnp.float32)
+            page = page.astype(jnp.float32)
+            for b in range(block):
+                page = jnp.where(row == at + b, new[:, b:b + 1], page)
+            page = page.astype(stage.dtype)
         kv_buf[buf, :, pl.ds(r0, bs), :] = page
 
         # the write-back of the slot before this one is waited for here,
@@ -363,15 +374,25 @@ def _grouped_call(q, new, arena, layer, pt, lo, lengths, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    s_dim, kv_heads, w = new.shape
+    # BLOCK ROWS (q (S, B, q_heads, hd), new (S, B, kv_heads, w)): a pass
+    # of block diffusion writes B rows a slot and every one of its B x
+    # group queries a KV head attends all `lengths` rows, the block's own
+    # included: the queries are B x group rows of the same two products,
+    # under the one mask `pos < length`
+    block = new.shape[1] if new.ndim == 4 else 1
+    s_dim, kv_heads, w = new.shape[0], new.shape[-2], new.shape[-1]
     hd = w // 2
-    heads = q.shape[1] // kv_heads
+    heads = q.shape[-2] // kv_heads * block
     # the group's queries as the rows of a matrix product: a whole packed
     # tile of the arena's type (16 rows of bfloat16, 8 of float32), the
     # rows past the group zero
     tile = 32 // arena.dtype.itemsize
     rows = -(-heads // tile) * tile
     qg = (q.astype(jnp.float32) * (1.0 / np.sqrt(hd))).astype(arena.dtype)
+    if new.ndim == 4:
+        qg = qg.reshape(s_dim, block, kv_heads, heads // block, hd) \
+            .transpose(0, 2, 1, 3, 4)
+        new = new.transpose(0, 2, 1, 3)
     qg = qg.reshape(s_dim, kv_heads, heads, hd)
     if rows != heads:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows - heads), (0, 0)))
@@ -380,9 +401,10 @@ def _grouped_call(q, new, arena, layer, pt, lo, lengths, interpret):
     group = min(_GROUPED_PAGES, pages)
     kern = functools.partial(_grouped_kernel, block_size=block_size,
                              pages=pages, group=group,
-                             buffers=_GROUPED_BUFFERS)
+                             buffers=_GROUPED_BUFFERS, block=block)
     q_spec = pl.BlockSpec((1, kv_heads, rows, hd), lambda s, *_: (s, 0, 0, 0))
-    new_spec = pl.BlockSpec((1, kv_heads, 1, w), lambda s, *_: (s, 0, 0, 0))
+    new_spec = pl.BlockSpec((1, kv_heads, block, w),
+                            lambda s, *_: (s, 0, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     arena, out = pl.pallas_call(
         kern,
@@ -410,8 +432,13 @@ def _grouped_call(q, new, arena, layer, pt, lo, lengths, interpret):
         name="paged_attention_grouped",
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       pt.reshape(-1).astype(jnp.int32), lo.astype(jnp.int32),
-      lengths.astype(jnp.int32), qg, new[:, :, None, :], arena)
-    return out[:, :, :heads].reshape(s_dim, kv_heads * heads, hd), arena
+      lengths.astype(jnp.int32), qg,
+      new if new.ndim == 4 else new[:, :, None, :], arena)
+    out = out[:, :, :heads]
+    if q.ndim == 4:
+        return out.reshape(s_dim, kv_heads, block, heads // block, hd) \
+            .transpose(0, 2, 1, 3, 4).reshape(q.shape), arena
+    return out.reshape(s_dim, kv_heads * heads, hd), arena
 
 
 def paged_attention(q, k, v, arena, layer, pt, ts, done=None, lo=None):
@@ -434,6 +461,14 @@ def paged_attention(q, k, v, arena, layer, pt, ts, done=None, lo=None):
     from blocks pt[s, 0..ts[s] // block_size]. done: (S,) bool or None;
     a frozen slot writes nothing, reads nothing and gets zeros.
 
+    BLOCK ROWS (a pass of block diffusion): q (S, B, q_heads, hd), k, v
+    (S, B, heads, hd), the projections of positions ts[s] .. ts[s] + B -
+    1. Slot s writes the B rows through its live page (B divides the page
+    and ts[s], so they never straddle one) and EVERY one of its B x group
+    queries a KV head attends positions 0 .. ts[s] + B - 1: one length, no
+    mask inside the block. The grouped kernel with B x group rows in its
+    two products; returns (S, B, q_heads, hd). No `lo`.
+
     Returns (context (S, heads, hd) in q's dtype, the arena): softmax(q
     k^T / sqrt(hd)) v with float32 scores, statistics and accumulator;
     the arena is the input's own buffer (`input_output_aliases`), so a
@@ -451,10 +486,19 @@ def paged_attention(q, k, v, arena, layer, pt, ts, done=None, lo=None):
         raise TypeError(
             "paged_attention reads the full-precision arena; a quantized "
             "(int8, scales) arena takes the gather path")
-    lengths = ts + 1
+    lengths = ts + (q.shape[1] if q.ndim == 4 else 1)
     if done is not None:
         lengths = jnp.where(done, 0, lengths)
     new = jnp.concatenate([k, v], -1).astype(arena.dtype)
+    if q.ndim == 4:
+        if lo is not None or arena.shape[4] % q.shape[1]:
+            raise ValueError(
+                "block rows attend everything and never straddle a page: "
+                f"no `lo`, and the block ({q.shape[1]}) divides the page "
+                f"({arena.shape[4]})")
+        return _grouped_call(q, new, arena, layer, pt,
+                             jnp.zeros_like(lengths), lengths,
+                             platform == "cpu")
     if q.shape[1] == k.shape[1] and lo is None:
         return _call(q, new, arena, layer, pt, lengths, platform == "cpu")
     if lo is None:

@@ -551,6 +551,12 @@ class _Handler(BaseHTTPRequestHandler):
             "metrics": handle.request.metrics.to_dict()
             if handle.request is not None else {},
         }
+        if handle.request is not None and handle.request.fixed_at:
+            # block diffusion: the pass of its block at which each token
+            # was fixed and the probability that pass gave it (what a
+            # client that trades quality against steps reads)
+            body["fixed_at"] = list(handle.request.fixed_at)
+            body["confidence"] = list(handle.request.confidence)
         if reason == "replica_failed":
             # the serving replica died mid-generation: the client should
             # re-submit after a short backoff (a header can't carry this
@@ -589,6 +595,13 @@ class _Handler(BaseHTTPRequestHandler):
                             srv.config.retry_after_floor_s
                     if handle.request is not None:
                         done["metrics"] = handle.request.metrics.to_dict()
+                        if handle.request.fixed_at:
+                            # block diffusion: the pass of its block at
+                            # which each streamed token was fixed, and
+                            # the probability that pass gave it
+                            done["fixed_at"] = list(handle.request.fixed_at)
+                            done["confidence"] = list(
+                                handle.request.confidence)
                     self.wfile.write(
                         f"event: done\ndata: {json.dumps(done)}\n\n"
                         .encode())

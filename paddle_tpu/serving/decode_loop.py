@@ -1,13 +1,20 @@
 """The fused decode loop: the engine's, one for every served model.
 
 A model supplies ONE decode step over the pool (`ServingModel.decode_step`)
-and, for speculation, one multi-position verify pass (`verify`). What is
-wrapped around it is written here once: the `lax.scan` of `chunk`
-iterations, the per-slot sampling call and its key cadence, the frozen-slot
-rule, the EOS/budget finish rule (`finish_rule`, which the scan, the
-speculative acceptance, the admission sampler and the host's block walk all
-call), the n-gram drafter with its exact-match acceptance, and the named
-decode carry (`DecodeCarry`) every jitted program of the scheduler threads.
+and, for speculation, one multi-position verify pass (`verify`); a model
+that generates by diffusion over blocks supplies one block pass instead
+(`block_step`). What is wrapped around it is written here once: the
+`lax.scan` of `chunk` iterations, the per-slot sampling call and its key
+cadence, the frozen-slot rule, the EOS/budget finish rule (`finish_rule`,
+which the scan, the speculative acceptance, the block commit, the
+admission sampler and the host's block walk all call), the n-gram drafter
+with its exact-match acceptance, the unmasking rule of a denoising pass,
+and the named decode carry (`DecodeCarry`) every jitted program of the
+scheduler threads.
+
+A scan iteration has three bodies: the causal step (one token a slot), the
+speculative pass (1 to k + 1 tokens a slot) and the DIFFUSION pass (0 tokens
+a slot until its block commits, then up to B at once: `_block_pass`).
 
 Imports no model and, at module level, no jax.
 """
@@ -17,7 +24,8 @@ from __future__ import annotations
 from typing import Any, NamedTuple
 
 __all__ = ["DecodeCarry", "finish_rule", "decode_chunk", "spec_ngram_seed",
-           "SAMPLE_SCOPE", "FINISH_SCOPE"]
+           "SAMPLE_SCOPE", "FINISH_SCOPE", "UNMASK_SCOPE",
+           "DIFFUSION_COUNTERS", "MASKED", "PROMPT", "open_block"]
 
 # The loop's own stages in a device trace (`jax.named_scope`, metadata
 # only): the sampling call with its key split, and the finish rule with the
@@ -25,6 +33,19 @@ __all__ = ["DecodeCarry", "finish_rule", "decode_chunk", "spec_ngram_seed",
 # scopes; the scheduler's admission sampler uses the same two names.
 SAMPLE_SCOPE = "loop/sample"
 FINISH_SCOPE = "loop/finish"
+# the diffusion pass's own stage between them: which masked positions a
+# pass fixes (the threshold, else the rank)
+UNMASK_SCOPE = "loop/unmask"
+
+# The diffusion body's in-graph counters, under the names a block-diffusion
+# model lists in its `counter_names`: live slot-passes, blocks committed,
+# and the positions fixed because their confidence cleared the threshold or
+# because they ranked first where too few did.
+DIFFUSION_COUNTERS = ("block_passes", "blocks_committed",
+                      "tokens_fixed_by_threshold", "tokens_fixed_by_rank")
+# `fixed_at` of a block position that is not a pass's number: still the
+# mask token, or a token of the prompt (never emitted)
+MASKED, PROMPT = -2, -1
 
 
 class DecodeCarry(NamedTuple):
@@ -35,7 +56,13 @@ class DecodeCarry(NamedTuple):
     are >= 0, so -1 never matches). `spec` is the drafter's (prev (S,)
     previous committed token, table (S, T+1) trigram table, see
     `spec_ngram_seed`) under speculation and `adapter_rows` the per-slot
-    adapter POOL ROW (0 = the base identity) with an adapter pool; each
+    adapter POOL ROW (0 = the base identity) with an adapter pool;
+    `block` a block-diffusion model's current block of B positions a slot
+    (tokens (S, B), the mask token where still masked; fixed_at (S, B):
+    MASKED, PROMPT or the pass that fixed the position; confidence (S, B)
+    float32: the probability that pass gave the token it fixed, 0 where
+    none did; passes (S,) run on the block so far), `ts` then being the
+    block's first position; each
     is None when off and then flattens to nothing, so the carry's leaves
     are exactly the fields in use, in this order."""
     tokens: Any
@@ -46,16 +73,24 @@ class DecodeCarry(NamedTuple):
     eos_ids: Any
     spec: Any = None
     adapter_rows: Any = None
+    block: Any = None
 
     @classmethod
-    def idle(cls, num_slots, speculate_ngram=None, adapters=False):
+    def idle(cls, num_slots, speculate_ngram=None, adapters=False,
+             block_length=None):
         """Every slot frozen and empty: the carry before any admission.
         `speculate_ngram` sizes the drafter table (its extra column is
         the trash lane masked scatter writes land in; -1 marks "no
-        prediction"); None leaves speculation off."""
+        prediction"); None leaves speculation off. `block_length` sizes a
+        block-diffusion model's block; None leaves it off."""
         import jax.numpy as jnp
         s = int(num_slots)
         return cls(
+            block=None if block_length is None else (
+                jnp.zeros((s, int(block_length)), jnp.int32),
+                jnp.full((s, int(block_length)), MASKED, jnp.int32),
+                jnp.zeros((s, int(block_length)), jnp.float32),
+                jnp.zeros((s,), jnp.int32)),
             tokens=jnp.zeros((s,), jnp.int32),
             ts=jnp.zeros((s,), jnp.int32),
             done=jnp.ones((s,), bool),
@@ -200,6 +235,121 @@ def _spec_step(verify, sample_fn, temps, eos_ids, speculate_k, carry):
             (out.T, counts))
 
 
+def open_block(cfg_diffusion, tail):
+    """A request's FIRST block as the carry holds it, from the prompt's
+    last p mod B tokens `tail` (a host sequence): those, fixed as PROMPT,
+    and the mask token elsewhere. Returns (tokens (B,), fixed_at (B,)) as
+    int32 numpy arrays."""
+    import numpy as np
+    B = int(cfg_diffusion["block_length"])
+    toks = np.full((B,), int(cfg_diffusion["mask_token_id"]), np.int32)
+    fixed = np.full((B,), MASKED, np.int32)
+    toks[:len(tail)] = tail
+    fixed[:len(tail)] = PROMPT
+    return toks, fixed
+
+
+def _block_pass(block_step, sample_fn, diffusion, temps, eos_ids, carry):
+    """One PASS of block diffusion over every slot's current block.
+    carry = (toks (S, B), fixed (S, B), sure (S, B), passes (S,), pool, ts,
+    keys, done, rem, counters); block_step(toks, pool, ts, done) -> (logits
+    (S, B, V), pool, counters). Returns (carry', (out (B, S), counts (S,),
+    fixed_at (B, S), confidence (B, S))).
+
+    A block that holds a mask is DENOISED: at each masked position j the
+    pass's logits (the mask token's own at -inf) give x0_j, drawn by
+    `sample_fn` from a key folded from the slot's by j, and c_j, the
+    probability softmax(l_j / T) gives x0_j (T = 1 for a greedy slot); with
+    n = B / steps, the positions whose c_j clears the threshold are fixed
+    if at least n do, else the n of largest c_j; a fixed position keeps its
+    c_j beside the pass's number. A block that holds none was run for its
+    K|V alone and COMMITS: its tokens behind the prompt's are emitted up to the
+    first that `finish_rule` stops at (an eos inside the block, a budget
+    that is no multiple of B), `ts` moves B on and the next block is all
+    mask. So a slot emits 0 tokens a pass, or up to B. Keys split once a
+    slot a pass, frozen slots included."""
+    import jax
+    import jax.numpy as jnp
+    from . import sampling
+
+    B = int(diffusion["block_length"])
+    n = B // int(diffusion["denoising_steps"])
+    mask_id = int(diffusion["mask_token_id"])
+    threshold = float(diffusion["confidence_threshold"])
+    toks, fixed, sure, passes, pool, ts, keys, done, rem, counters = carry
+    live = ~done
+    masked = fixed == MASKED
+    denoise = live & masked.any(axis=1)
+    commit = live & ~denoise
+    logits, pool, stepped = block_step(toks, pool, ts, done)
+    counters = jax.tree_util.tree_map(jnp.add, counters, stepped)
+    with jax.named_scope(SAMPLE_SCOPE):
+        # row by row over (S x B, V), as the head left the logits: a block's
+        # B rows in the sublanes of a (S, B, V) array would be a copy of it
+        S = toks.shape[0]
+        rows = logits.reshape(S * B, -1)
+        vocab = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
+        rows = jnp.where(vocab == mask_id, -jnp.inf, rows)
+        sub = jax.vmap(lambda key: jax.vmap(
+            lambda j: sampling.sample_fold(key, j))(
+                jnp.arange(B, dtype=jnp.uint32)))(keys)      # (S, B, 2)
+        row_temps = jnp.repeat(temps, B)
+        x0, _ = jax.vmap(sample_fn)(sub.reshape(S * B, 2), rows, row_temps)
+        keys = jax.vmap(sampling.sample_split)(keys)
+        z = rows / jnp.where(row_temps > 0.0, row_temps, 1.0)[:, None]
+        conf = jnp.exp(
+            jnp.take_along_axis(z, x0[:, None], -1)[:, 0]
+            - jax.scipy.special.logsumexp(z, axis=-1)).reshape(S, B)
+        x0 = x0.reshape(S, B)
+    with jax.named_scope(UNMASK_SCOPE):
+        # rank 0 is the most confident masked position; ties to the left
+        order = jnp.argsort(-jnp.where(masked, conf, -1.0), axis=1,
+                            stable=True)
+        rank = jnp.argsort(order, axis=1, stable=True)
+        by_rank = masked & (rank < n)
+        high = masked & (conf > threshold)
+        by_threshold = high.sum(axis=1) >= n
+        fix = jnp.where(by_threshold[:, None], high, by_rank) \
+            & denoise[:, None]
+        toks = jnp.where(fix, x0, toks)
+        fixed = jnp.where(fix, passes[:, None], fixed)
+        sure = jnp.where(fix, conf, sure)
+        n_fixed = fix.sum(axis=1).astype(jnp.int32)
+    with jax.named_scope(FINISH_SCOPE):
+        # the committed block's tokens behind the prompt's, in order
+        skip = (fixed == PROMPT).sum(axis=1)
+        jj = jnp.arange(B)[None, :]
+        at = jnp.minimum(skip[:, None] + jj, B - 1)
+        out = jnp.take_along_axis(toks, at, 1)
+        out_fixed = jnp.take_along_axis(fixed, at, 1)
+        out_sure = jnp.take_along_axis(sure, at, 1)
+        valid = skip[:, None] + jj < B
+        stop = finish_rule(out, eos_ids[:, None], rem[:, None] - (jj + 1))
+        stopped_before = jnp.concatenate(
+            [jnp.zeros_like(stop[:, :1]),
+             jnp.cumsum(stop.astype(jnp.int32), axis=1)[:, :-1] > 0], axis=1)
+        can = valid & ~stopped_before & commit[:, None]
+        counts = can.sum(axis=1).astype(jnp.int32)
+        done = done | (can & stop).any(axis=1)
+        rem = rem - counts
+        ts = jnp.where(commit, ts + B, ts)
+        toks = jnp.where(commit[:, None], mask_id, toks)
+        fixed = jnp.where(commit[:, None], MASKED, fixed)
+        sure = jnp.where(commit[:, None], 0.0, sure)
+        passes = jnp.where(commit, 0, jnp.where(denoise, passes + 1, passes))
+        own = {"block_passes": jnp.sum(live),
+               "blocks_committed": jnp.sum(commit),
+               "tokens_fixed_by_threshold":
+                   jnp.sum(jnp.where(by_threshold, n_fixed, 0)),
+               "tokens_fixed_by_rank":
+                   jnp.sum(jnp.where(by_threshold, 0, n_fixed))}
+        counters = dict(counters, **{
+            name: counters[name] + own[name].astype(jnp.int32)
+            for name in DIFFUSION_COUNTERS})
+    return ((toks, fixed, sure, passes, pool, ts, keys, done, rem, counters),
+            (out.T, counts, out_fixed.T, out_sure.T))
+
+
 def decode_chunk(model, params, cfg, arena, pt, keys, carry, chunk,
                  sample_fn=None, speculate_k=0, adapters=None,
                  arena_constraint=None):
@@ -255,7 +405,17 @@ def decode_chunk(model, params, cfg, arena, pt, keys, carry, chunk,
     bit-identical to speculate_k=0 at every chunk size. `block` is then
     the pair (block (chunk, speculate_k+1, S), counts (chunk, S)):
     block[i, :counts[i, s], s] are slot s's committed tokens of pass i,
-    entries past the count are frozen repeats the host discards."""
+    entries past the count are frozen repeats the host discards.
+
+    DIFFUSION MODE (a model whose `diffusion(cfg)` is not None; `carry.block`
+    holds every slot's block): each scan iteration is one PASS of
+    `model.block_step` over the slots' current blocks (`_block_pass`). `block`
+    is then (block (chunk, B, S), counts (chunk, S), fixed_at (chunk, B, S),
+    confidence (chunk, B, S) float32): counts[i, s] is 0 while slot s's
+    block is being denoised and up to B at the pass that commits it;
+    fixed_at[i, j, s] is the pass of its block at which committed token j
+    was fixed and confidence[i, j, s] the probability that pass gave it. The
+    parameters are the model's, not the engine's."""
     import jax
     import jax.numpy as jnp
 
@@ -264,6 +424,28 @@ def decode_chunk(model, params, cfg, arena, pt, keys, carry, chunk,
             return jnp.argmax(logits, -1).astype(jnp.int32), key
 
     temps, eos_ids, aids = carry.temps, carry.eos_ids, carry.adapter_rows
+
+    diffusion = model.diffusion(cfg)
+    if diffusion is not None:
+        names = model.counter_names(cfg)
+        zeros = {name: jnp.zeros(shape, jnp.int32)
+                 for name, shape in names.items()}
+
+        def block_step(toks, arena, ts, done):
+            return model.block_step(params, cfg, toks, arena, pt, ts, done)
+
+        def pass_body(c, _):
+            return _block_pass(block_step, sample_fn, diffusion, temps,
+                               eos_ids, c)
+
+        (toks, fixed, sure, passes, arena, ts, keys, done, remaining,
+         counters), out = jax.lax.scan(
+                pass_body, carry.block + (arena, carry.ts, keys, carry.done,
+                                          carry.remaining, zeros),
+                None, length=int(chunk))
+        return (out, arena, keys,
+                carry._replace(ts=ts, done=done, remaining=remaining,
+                               block=(toks, fixed, sure, passes)), counters)
 
     if int(speculate_k) > 0:
         def verify(inputs, arena, ts, done):

@@ -35,7 +35,7 @@ from ..observability import request_log as _request_log
 from ..observability import watchdog as _watchdog
 from ..observability.tracer import get_tracer, request_scope, trace_span
 from .kv_cache import ShapeBuckets, SlotKVCache
-from .model import require_features, serving_model
+from .model import BLOCK_DIFFUSION, require_features, serving_model
 from .metrics import _TICK_PHASES, EngineMetrics, RequestMetrics
 from .scheduler import (PREFILL_PENDING, CompileJournal,
                         ContinuousBatchingScheduler)
@@ -342,6 +342,11 @@ class GenerationRequest:
         self.adapter_id = int(adapter_id)
         self.on_token = on_token
         self.tokens: List[int] = []
+        # block diffusion alone: for each generated token the pass of
+        # its block at which it was fixed (0 .. denoising_steps - 1) and
+        # the probability that pass gave it
+        self.fixed_at: List[int] = []
+        self.confidence: List[float] = []
         self.state = "queued"
         self.metrics = RequestMetrics(clock)
         self.request_id = request_id
@@ -442,7 +447,10 @@ class ServingEngine:
         self.kv = SlotKVCache(cfg, serving.num_slots, max_len, dtype,
                               block_size=serving.block_size,
                               num_blocks=serving.kv_blocks,
-                              prefix_cache=serving.prefix_cache,
+                              # a hit would need a block-causal warm
+                              # prefill, which no model has written
+                              prefix_cache=serving.prefix_cache
+                              and BLOCK_DIFFUSION not in model.features,
                               mesh_shards=plan.tp if plan else 1,
                               arena_device=plan.arena_sharding
                               if plan else None,
@@ -667,6 +675,9 @@ class ServingEngine:
             # this token: swallow the emission, the slot frees next step
             return
         req.tokens.append(event.token)
+        if event.fixed_at is not None:
+            req.fixed_at.append(event.fixed_at)
+            req.confidence.append(event.confidence)
         req.metrics.mark_token()
         self.metrics.tokens_out += 1
         if event.finished:
@@ -1037,6 +1048,11 @@ class ServingEngine:
                 f"{what} refused: the serving model {self.model.name!r} "
                 f"has {len(self.kv.group_layout)} cache groups and a "
                 "ticket carries one")
+        if BLOCK_DIFFUSION in self.model.features:
+            raise MigrationError(
+                f"{what} refused: the serving model {self.model.name!r} "
+                "generates by diffusion over blocks and a ticket carries "
+                "no block")
 
     def migrate_out(self, request) -> "Any":
         """Extract one RUNNING or PARKED sequence into a portable
@@ -1466,6 +1482,23 @@ class ServingEngine:
         # `expert_tokens` and `router_tokens` since start)
         s["model"] = self.model.name
         s.update(self.model.describe(self.cfg))
+        diffusion = self.model.diffusion(self.cfg)
+        if diffusion is not None:
+            # generation by diffusion over blocks: the model's parameters,
+            # what the loop's counters say a block and a pass came to, and
+            # the time to the first committed BLOCK (a request's first
+            # tokens arrive with it: `mean_ttft` is this number)
+            counted = self.scheduler.model_counters
+            passes = int(counted["block_passes"])
+            blocks = int(counted["blocks_committed"])
+            s["diffusion"] = dict(
+                diffusion,
+                passes_per_block=passes / blocks if blocks else None,
+                tokens_per_pass=self.scheduler.block_tokens / passes
+                if passes else None,
+                mean_time_to_first_block=s.get("mean_ttft"))
+            s["prefix_cache"] = "off: a hit would need a block-causal " \
+                "warm prefill"
         s["cache_row_bytes"] = self.kv.cache_row_bytes
         for name, value in self.scheduler.model_counters.items():
             s[name] = value.tolist()

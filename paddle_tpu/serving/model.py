@@ -7,13 +7,20 @@ in a layer (`cache_spec` -> `CacheSpec`: how many "heads" the arena has
 and how wide a row is), its `prefill` of one prompt suffix into its
 pages, ONE `decode_step` over the whole pool and, if it implements
 speculation, one multi-position `verify` pass; plus the engine features
-it declares. Everything else is the engine's and is the same for every
-model: slots, blocks, page tables, prefix hashing, swap and migration
-payloads on the block axis, the HTTP service, and the whole fused
-decode loop around the step (`decode_loop`: the scan, the sampler and
-its key cadence, the frozen-slot rule, the EOS/budget finish rule, the
-n-gram drafter and its acceptance, the named decode carry). A model has
-no loop of its own.
+it declares. A model that generates by DIFFUSION OVER BLOCKS (the
+feature "block_diffusion") writes one `block_step`, a pass over a block
+of B positions a slot, in place of the decode step, and names its
+block length, denoising steps, threshold and mask token in
+`diffusion(cfg)`. Everything else is the engine's and is the same for
+every model: slots, blocks, page tables, prefix hashing, swap and
+migration payloads on the block axis, the HTTP service, and the whole
+fused decode loop around the step or the pass (`decode_loop`: the scan,
+the sampler and its key cadence, the frozen-slot rule, the EOS/budget
+finish rule, the n-gram drafter and its acceptance, the unmasking rule
+and the commit of a denoised block, the named decode carry). A model
+has no loop of its own: whether a scan iteration yields one token a
+slot, several or, until a block commits, none is the loop's body, which
+the engine picks from what the model declares.
 
 A config object names its model by a `serving_model()` method
 (`models.gpt.GPTConfig`, `models.moonlight.MoonlightConfig`); a config
@@ -46,7 +53,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional, Tuple
 
-__all__ = ["FEATURES", "CacheSpec", "GroupLayout", "ServingModel",
+__all__ = ["FEATURES", "BLOCK_DIFFUSION", "CacheSpec", "GroupLayout", "ServingModel",
            "serving_model", "require_features", "cache_groups",
            "group_columns", "ring_pages"]
 
@@ -54,6 +61,12 @@ __all__ = ["FEATURES", "CacheSpec", "GroupLayout", "ServingModel",
 # ServingConfig field (see `require_features`)
 FEATURES = ("int8_weights", "int8_kv", "adapters", "speculation", "mesh",
             "prefill_chunk")
+# what a model declares of HOW it generates, switched on by no option: its
+# tokens come from `block_step` passes over blocks (`diffusion(cfg)`), the
+# prefill's logits pick nothing, and host swap, migration and prefix hits
+# are refused or off (the decode carry holds a block; a hit would need a
+# block-causal warm prefill)
+BLOCK_DIFFUSION = "block_diffusion"
 
 
 class CacheSpec(NamedTuple):
@@ -166,6 +179,18 @@ class ServingModel:
           feature "speculation": the positions ts..ts+k of every slot
           in one pass, row j attending over 0..ts+j; writes past a
           slot's page row and a frozen slot's go to scratch.
+      block_step(params, cfg, toks (S, Bk), arena, pt, ts, done)
+          -> (logits (S, Bk, V) f32, arena, counters)
+          feature "block_diffusion", Bk the block length: a PASS over
+          every slot's current block. Writes the Bk rows' cache state
+          at ts .. ts + Bk - 1 (ts a multiple of Bk, Bk divides the
+          page) and EVERY row attends 0 .. ts + Bk - 1: `verify`'s
+          contract with the causal mask taken out of the block. Row j's
+          logits score the token AT ts + j. Every pass writes; the
+          commit pass is simply the pass whose block holds no mask, so
+          a row's last write is the final one. The model's `prefill`
+          then writes the prompt's WHOLE blocks alone, block-causally,
+          and returns None for its logits (they would pick nothing).
     With several cache groups (`cache_spec` a tuple) `arena` is the
     tuple of the groups' arenas, the primary first, and `pages` / `pt`
     hold every group's page row side by side at `cache_groups`' columns
@@ -211,6 +236,13 @@ class ServingModel:
         """{name: shape} of the counters the programs return."""
         return {}
 
+    def diffusion(self, cfg):
+        """None, or for a model that generates by diffusion over blocks
+        (feature "block_diffusion") the loop's parameters as the MODEL
+        has them: {"block_length", "denoising_steps",
+        "confidence_threshold", "remasking", "mask_token_id"}."""
+        return None
+
     def describe(self, cfg):
         """What `engine.stats()` says of the served config beside the
         model's name: a dict of plain values (the experts this chip
@@ -228,6 +260,10 @@ class ServingModel:
     def verify(self, params, cfg, toks, arena, pt, ts, done, *,
                adapters=None, adapter_ids=None):
         """The speculative verify pass (feature "speculation")."""
+        raise NotImplementedError
+
+    def block_step(self, params, cfg, toks, arena, pt, ts, done):
+        """The block pass (feature "block_diffusion")."""
         raise NotImplementedError
 
     def quantize_params(self, params, cfg):
@@ -263,6 +299,9 @@ def require_features(model: ServingModel, serving) -> None:
     missing = [f"{option} needs {feature!r}"
                for feature, (on, option) in asked.items()
                if on and feature not in model.features]
+    if BLOCK_DIFFUSION in model.features and serving.preempt:
+        missing.append("preempt=True parks a slot's carry rows, not its "
+                       "block: a block-diffusion model is not swapped")
     if missing:
         raise ValueError(
             f"the serving model {model.name!r} does not implement: "

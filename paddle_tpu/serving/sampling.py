@@ -5,7 +5,8 @@ model)."""
 
 from __future__ import annotations
 
-__all__ = ["threefry2x32", "sample_key", "sample_split", "sample_gumbel"]
+__all__ = ["threefry2x32", "sample_key", "sample_split", "sample_fold",
+           "sample_gumbel"]
 
 # -- serving sampler PRNG ---------------------------------------------------
 #
@@ -70,6 +71,16 @@ def sample_split(key):
     import jax.numpy as jnp
 
     y0, y1 = threefry2x32(key, jnp.uint32(1), jnp.uint32(0))
+    return jnp.stack([y0, y1], axis=-1)
+
+
+def sample_fold(key, j):
+    """The key of lane `j` (uint32) of a draw that takes several rows from
+    one key (a block of positions a slot a pass): counter (2, j) of the
+    key's stream, disjoint from the split's (1, 0) and the draws' (0, .)."""
+    import jax.numpy as jnp
+
+    y0, y1 = threefry2x32(key, jnp.uint32(2), jnp.asarray(j, jnp.uint32))
     return jnp.stack([y0, y1], axis=-1)
 
 

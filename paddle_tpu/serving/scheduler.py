@@ -149,6 +149,29 @@ dispatch's GUARANTEED token floor — acceptance only over-delivers, so
 the 1/chunk steady-state dispatch bound is preserved and the only cost
 of a lucky streak is one EOS-style overshoot dispatch at the tail.
 
+BLOCK DIFFUSION (a model whose `diffusion(cfg)` is not None; the
+parameters are the model's, no ServingConfig knob): a chunk iteration is
+one PASS over a block of B positions a slot (decode_loop's `_block_pass`
+over the model's `block_step`), which emits nothing until the slot's
+block commits and then up to B tokens at once. Admission samples NO
+first token (the prefill's logits score the tokens AT its rows, not the
+next one): the prompt's whole blocks are prefilled, its last p mod B
+tokens open the slot's first block in the carry (`admit_block`), the
+slot runs with nothing produced and admit() returns PREFILL_PENDING;
+nothing is fetched, so the prefill's counters ride the next block
+fetch and a prompt gets a staging buffer of its own. The dispatch block
+is (tokens (chunk, B, S), counts (chunk, S), fixed_at (chunk, B, S),
+confidence (chunk, B, S)) with counts 0..B; `_collect` walks the
+committed tokens (the speculative telemetry does not run for it), each
+event carrying `fixed_at`, the pass of its block at which the token was
+fixed, and `confidence`, the probability that pass gave it, and the
+finish rule lands
+inside a committed block where the device trimmed it (an eos, a budget
+that is no multiple of B). A dispatch of `chunk` passes is sure to
+commit chunk // (steps + 1) blocks a live slot, and that many times B
+tokens (less the prompt's remainder in a request's first block) is the
+floor `_needs_dispatch` counts on.
+
 MULTI-TENANT ADAPTERS (adapters=AdapterPool): co-batched slots each hit
 a DIFFERENT LoRA adapter inside the same fused dispatch. A per-slot
 adapter-ROW vector rides as the LAST field of the donated decode
@@ -182,7 +205,7 @@ from ..observability.tracer import get_tracer, trace_span
 from ..utils.compile_cache import ensure_compile_cache
 from . import sampling
 from .decode_loop import (FINISH_SCOPE, SAMPLE_SCOPE, DecodeCarry,
-                          decode_chunk, finish_rule,
+                          decode_chunk, finish_rule, open_block,
                           spec_ngram_seed)
 from .kv_cache import ShapeBuckets, SlotKVCache
 from .model import serving_model
@@ -192,18 +215,25 @@ _TRACER = get_tracer()
 __all__ = ["CompileJournal", "ContinuousBatchingScheduler",
            "SequenceEvent", "SwappedSequence", "PREFILL_PENDING"]
 
-# admit()'s "admission succeeded, first token pending" sentinel
-# (chunked prefill only): pages are mapped and the slot is prefilling,
-# but the first-token event will surface from a later advance_prefill
-# tick. Distinct from None, which still means "no slot/pages right now".
+# admit()'s "admission succeeded, first token pending" sentinel: under
+# chunked prefill pages are mapped and the slot is prefilling, and the
+# first-token event will surface from a later advance_prefill tick;
+# under block diffusion the slot is running and its first tokens arrive
+# with its first committed block. Distinct from None, which still means
+# "no slot/pages right now".
 PREFILL_PENDING = object()
 
 
 class SequenceEvent(NamedTuple):
-    """One emitted token: (opaque request object, token id, finished)."""
+    """One emitted token: (opaque request object, token id, finished);
+    under block diffusion also the pass of its block at which the token
+    was fixed and the probability that pass gave it (None for every other
+    model)."""
     request: Any
     token: int
     finished: bool
+    fixed_at: Optional[int] = None
+    confidence: Optional[float] = None
 
 
 class _Running:
@@ -213,13 +243,15 @@ class _Running:
     reset in-graph at admission."""
 
     __slots__ = ("req", "pos", "produced", "max_new", "eos_id",
-                 "live_from", "seq", "adapter_id")
+                 "live_from", "seq", "adapter_id", "blocks")
 
     def __init__(self, req, pos, max_new, eos_id, live_from, seq=0,
-                 adapter_id=0):
+                 adapter_id=0, produced=1):
         self.req = req
         self.pos = pos                    # absolute position fed next
-        self.produced = 1                 # prefill already sampled one
+        self.produced = produced          # prefill already sampled one
+        #                                   (block diffusion: none)
+        self.blocks = 0                   # blocks committed (diffusion)
         self.max_new = max_new
         self.eos_id = eos_id
         self.live_from = live_from        # first dispatch carrying tokens
@@ -468,6 +500,12 @@ class _Inflight(NamedTuple):
     counters: Any = None  # the model's in-graph counters of this chunk
     #                       (None for a model that has none), fetched
     #                       WITH the block
+    fixed: Any = None     # block diffusion: device ((chunk, B, S) int32,
+    #                       (chunk, B, S) float32), the pass that fixed
+    #                       each committed token and its confidence there;
+    #                       block is (chunk, B, S) and counts 0..B then
+    floor: int = 0        # the tokens this dispatch is SURE to deliver
+    #                       a running slot (`_needs_dispatch`)
 
 
 class ContinuousBatchingScheduler:
@@ -542,6 +580,22 @@ class ContinuousBatchingScheduler:
         pin = None if plan is None else plan.constrain_arena
         self.decode_attention = "gather" if self.speculate_k else \
             self.model.decode_attention_path(kv.arena, pin)
+        # generation by diffusion over blocks (None for every other
+        # model): the MODEL's parameters, which the loop's third body
+        # and the host's block walk read. A dispatch of `chunk` passes
+        # is sure to commit chunk // (steps + 1) blocks a live slot (a
+        # block takes at most steps + 1 passes), so that many times B
+        # tokens is its floor; the first block of a request emits the
+        # prompt's p mod B tokens fewer.
+        self.diffusion = self.model.diffusion(cfg)
+        self._dispatch_floor = self.decode_chunk if self.diffusion is None \
+            else (self.decode_chunk
+                  // (self.diffusion["denoising_steps"] + 1)
+                  * self.diffusion["block_length"])
+        self.block_tokens = 0             # tokens committed blocks emitted
+        # counters of prefills whose admission fetched nothing (block
+        # diffusion: no first token), riding the next block fetch
+        self._deferred_counters: List[Any] = []
         # which attention a COLD prompt's prefill runs in each bucket,
         # by the model's word on the same inputs (None: the model does
         # not say), and the host's counts of the prefills it dispatched:
@@ -689,7 +743,9 @@ class ContinuousBatchingScheduler:
         # per-slot adapter pool rows ride in the SAME donated carry
         self._state = DecodeCarry.idle(
             s_dim, self.speculate_ngram if self.speculate_k else None,
-            adapters_on)
+            adapters_on,
+            None if self.diffusion is None
+            else self.diffusion["block_length"])
 
         # device page table: every row scratch until its slot admits
         self._pt = jnp.zeros((s_dim, self.kv.table_width), jnp.int32)
@@ -738,8 +794,10 @@ class ContinuousBatchingScheduler:
                 prev, table = state.spec
                 state = state._replace(spec=(prev, spec_ngram_seed(
                     table, slot, tokens[0], real_len)))
-            return (c_rep(logits[0]), c_arena(arena), c_rep(pt),
-                    c_rep(state), c_rep(counters))
+            # a block-diffusion model's prefill hands back no logits
+            return (None if logits is None else c_rep(logits[0]),
+                    c_arena(arena), c_rep(pt), c_rep(state),
+                    c_rep(counters))
 
         def prefill_impl(params, arena, pt, state, tokens, pfx_len,
                          real_len, pages, slot, *alo):
@@ -790,6 +848,31 @@ class ContinuousBatchingScheduler:
                 state = state._replace(
                     adapter_rows=state.adapter_rows.at[slot].set(aid[0]))
             return c_rep(first), c_rep(keys), c_rep(state)
+
+        def admit_block_impl(keys, state, slot, seed, temp, pos, max_new,
+                             eos_id, toks, fixed):
+            # block diffusion's admission: the prefill's logits pick
+            # NOTHING (logits at i score the token at i), so no token is
+            # sampled here; the slot's first block opens with the
+            # prompt's last p mod B tokens and the mask elsewhere, at
+            # the position `pos` of its first row, and the first tokens
+            # arrive with the first commit
+            self._note_compile("admit_block")
+            with jax.named_scope(SAMPLE_SCOPE):
+                keys = keys.at[slot].set(sampling.sample_key(seed))
+            with jax.named_scope(FINISH_SCOPE):
+                b_toks, b_fixed, b_sure, b_passes = state.block
+                state = state._replace(
+                    ts=state.ts.at[slot].set(pos),
+                    done=state.done.at[slot].set(False),
+                    remaining=state.remaining.at[slot].set(max_new),
+                    temps=state.temps.at[slot].set(temp),
+                    eos_ids=state.eos_ids.at[slot].set(eos_id),
+                    block=(b_toks.at[slot].set(toks),
+                           b_fixed.at[slot].set(fixed),
+                           b_sure.at[slot].set(0.0),
+                           b_passes.at[slot].set(0)))
+            return c_rep(keys), c_rep(state)
 
         def chunk_impl(params, arena, pt, keys, state, *apool):
             self._note_compile("decode_chunk")
@@ -903,7 +986,9 @@ class ContinuousBatchingScheduler:
             self._prefill_chunk_jit = jax.jit(prefill_chunk_impl,
                                               donate_argnums=(1, 2, 3),
                                               **layered)
-        self._admit_jit = jax.jit(admit_impl, donate_argnums=(0, 1))
+        self._admit_jit = jax.jit(
+            admit_impl if self.diffusion is None else admit_block_impl,
+            donate_argnums=(0, 1))
         self._chunk_jit = jax.jit(chunk_impl, donate_argnums=(1, 3, 4),
                                   **layered)
         self._release_jit = jax.jit(release_impl, donate_argnums=(0, 1))
@@ -1087,7 +1172,11 @@ class ContinuousBatchingScheduler:
             return PREFILL_PENDING
         suffix_len = p_len - pfx_len
         bucket = self.buckets.bucket_for(suffix_len)
-        padded = self._staging_for(bucket)
+        # block diffusion's admission waits for nothing (no first token),
+        # so the next one may fill the bucket's staging buffer while this
+        # prefill's copy of it is still to be made: a buffer of its own
+        padded = self._staging_for(bucket) if self.diffusion is None \
+            else np.empty((1, bucket), np.int32)
         padded[0, :suffix_len] = prompt[0, pfx_len:]
         padded[0, suffix_len:] = 0
         self._count_prefill(bucket, pfx_len, suffix_len)
@@ -1103,10 +1192,15 @@ class ContinuousBatchingScheduler:
                     padded, np.int32(pfx_len), np.int32(suffix_len),
                     pages, np.int32(slot), *self._adapter_args(adapter_id))
             self.kv.store_arena(arena)
-        event = self._sample_first(
-            slot, req, logits, p_len, max_new, temperature, seed,
-            eos_id, int(prompt[0, -1]), self._admit_counter,
-            adapter_id=adapter_id, counters=counters)
+        if self.diffusion is not None:
+            event = self._open_first_block(
+                slot, req, prompt[0], max_new, temperature, seed, eos_id,
+                self._admit_counter, counters)
+        else:
+            event = self._sample_first(
+                slot, req, logits, p_len, max_new, temperature, seed,
+                eos_id, int(prompt[0, -1]), self._admit_counter,
+                adapter_id=adapter_id, counters=counters)
         self._admit_counter += 1
         rlog = _request_log.get_request_log()
         if rlog is not None:
@@ -1172,6 +1266,30 @@ class ContinuousBatchingScheduler:
         else:
             self._running[slot] = st
         return SequenceEvent(req, first, finished)
+
+    def _open_first_block(self, slot, req, prompt, max_new, temperature,
+                          seed, eos_id, seq, counters):
+        """Block diffusion's admission tail: no first token (the
+        prefill's logits pick nothing). The slot's carry rows are set
+        to its first block, which opens with the prompt's last p mod B
+        tokens, and the slot is promoted to _running with nothing
+        produced. Nothing is fetched: the prefill's counters ride the
+        next block fetch. Returns PREFILL_PENDING, the engine's "no
+        event yet"."""
+        B = self.diffusion["block_length"]
+        whole = prompt.size // B * B
+        toks, fixed = open_block(self.diffusion, prompt[whole:])
+        self._keys, self._state = self._jit_call(
+            "admit_block", self._admit_jit, self._keys, self._state,
+            np.int32(slot), np.int32(seed), np.float32(temperature),
+            np.int32(whole), np.int32(max_new),
+            np.int32(-1 if eos_id is None else eos_id), toks, fixed)
+        if counters is not None:
+            self._deferred_counters.append(counters)
+        self._running[slot] = _Running(
+            req, pos=prompt.size, max_new=max_new, eos_id=eos_id,
+            live_from=self._launches, seq=seq, produced=0)
+        return PREFILL_PENDING
 
     @property
     def prefill_pending(self) -> bool:
@@ -1300,8 +1418,13 @@ class ContinuousBatchingScheduler:
         early — that overshoot is unknowable host-side and bounded by
         one dispatch.)"""
         for st in self._running.values():
-            covered = sum(fl.size for fl in self._inflight
+            covered = sum(fl.floor for fl in self._inflight
                           if fl.index >= st.live_from)
+            if self.diffusion is not None and not st.blocks:
+                # a request's first block emits the prompt's p mod B
+                # tokens fewer than a block
+                covered -= min(covered,
+                               st.pos % self.diffusion["block_length"])
             if st.max_new - st.produced > covered:
                 return True
         return False
@@ -1325,16 +1448,19 @@ class ContinuousBatchingScheduler:
                 self.params, self.kv.arena, self._pt, self._keys,
                 self._state, *apool)
             self.kv.store_arena(arena)
-        counts = None
+        counts = fixed = None
         if self.speculate_k:
             block, counts = block
+        elif self.diffusion is not None:
+            block, counts, *fixed = block
         # the ring's per-token decode_iter spans interpolate between this
         # dispatch's launch and its collect (0 = the ring was off)
         begin_ns = dispatch.begin_ns if _TRACER.enabled else 0
         self._inflight.append(_Inflight(block, self._launches,
                                         self.decode_chunk, begin_ns,
                                         counts, dispatch.seconds,
-                                        counters))
+                                        counters, fixed,
+                                        self._dispatch_floor))
         self._launches += 1
         if self.on_launch is not None:
             self.on_launch()
@@ -1355,6 +1481,19 @@ class ContinuousBatchingScheduler:
         duration to _collect."""
         import jax
 
+        if fl.fixed is not None:
+            # block diffusion: the tokens, their counts, the passes that
+            # fixed them and their confidences there, with this chunk's
+            # counters and those of the prefills admitted since the last
+            # fetch
+            deferred, self._deferred_counters = self._deferred_counters, []
+            block, counts, (fixed, sure), counters, deferred = \
+                jax.device_get((fl.block, fl.counts, fl.fixed, fl.counters,
+                                deferred))
+            for c in [counters] + deferred:
+                self._add_counters(c)
+            return (np.asarray(block), np.asarray(counts),
+                    (np.asarray(fixed), np.asarray(sure)))
         if fl.counters is not None:
             block, counts, counters = jax.device_get(
                 (fl.block, fl.counts, fl.counters))
@@ -1371,7 +1510,8 @@ class ContinuousBatchingScheduler:
         """Walk one fetched block into events. host_s + device_s is the
         dispatch's wall attribution, and host_s is the per-dispatch
         overhead the native-core work is judged against."""
-        block, counts = fetched
+        block, counts, *fixed = fetched
+        fixed, sure = fixed[0] if fixed else (None, None)
         if self.on_dispatch_timed is not None:
             self.on_dispatch_timed(fl.host_s, device_s)
         end_ns = time.monotonic_ns() if fl.begin_ns else 0
@@ -1395,8 +1535,33 @@ class ContinuousBatchingScheduler:
                     # start in a later block (the slot was frozen or
                     # carried the PREVIOUS occupant here)
                     continue
+                fixed_at = confidence = None
                 if counts is None:
                     toks = (int(block[i, slot]),)
+                elif fixed is not None:
+                    # a pass of block diffusion: 0 tokens while the
+                    # slot's block is denoised, up to B when it commits
+                    n = int(counts[i, slot])
+                    toks = tuple(int(block[i, j, slot]) for j in range(n))
+                    fixed_at = tuple(int(fixed[i, j, slot])
+                                     for j in range(n))
+                    confidence = tuple(float(sure[i, j, slot])
+                                       for j in range(n))
+                    if n:
+                        st.blocks += 1
+                        self.block_tokens += n
+                        if fl.begin_ns:
+                            w = end_ns - fl.begin_ns
+                            _TRACER.record_complete(
+                                "serving/block_commit",
+                                fl.begin_ns + (i * w) // fl.size,
+                                fl.begin_ns + ((i + 1) * w) // fl.size,
+                                "serving",
+                                {"request_id": getattr(
+                                    st.req, "request_id", None),
+                                 "slot": slot, "block": st.blocks - 1,
+                                 "tokens": n, "fixed_at": list(fixed_at),
+                                 "chunk_index": i, "dispatch": fl.index})
                 else:
                     n = int(counts[i, slot])
                     toks = tuple(int(block[i, j, slot])
@@ -1440,7 +1605,10 @@ class ContinuousBatchingScheduler:
                              "slot": slot, "pos": st.pos, "token": tok,
                              "finished": finished, "chunk_index": i,
                              "dispatch": fl.index})
-                    events.append(SequenceEvent(st.req, tok, finished))
+                    events.append(SequenceEvent(
+                        st.req, tok, finished,
+                        None if fixed_at is None else fixed_at[j],
+                        None if confidence is None else confidence[j]))
                     if emitted is not None:
                         ent = emitted.get(slot)
                         if ent is None:

@@ -27,8 +27,8 @@ def test_main_exits_nonzero_on_cpu_before_any_work(monkeypatch, capsys):
     def no_work(*args, **kwargs):
         raise AssertionError("a phase ran without a TPU")
 
-    for name in ("phase_kernels", "phase_share_kernels", "phase_train",
-                 "phase_serve"):
+    for name in ("phase_kernels", "phase_share_kernels",
+                 "phase_block_diffusion", "phase_train", "phase_serve"):
         monkeypatch.setattr(chip_smoke, name, no_work)
     assert jax.default_backend() == "cpu"
     assert chip_smoke.main() != 0
@@ -46,6 +46,7 @@ def test_last_stdout_line_is_the_verdict_and_the_device(monkeypatch, capsys):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(chip_smoke, "phase_kernels", lambda: {"cases": 0})
     monkeypatch.setattr(chip_smoke, "phase_share_kernels", lambda: {})
+    monkeypatch.setattr(chip_smoke, "phase_block_diffusion", lambda: {})
     monkeypatch.setattr(
         chip_smoke, "phase_train",
         lambda *a, **kw: {"losses": [2.0, 1.0], "scope": None})
@@ -90,6 +91,19 @@ def test_share_kernels_phase_interpreted():
     live = sum(1 for n in range(48) if n % 7 != 3)
     assert facts["combine_rows_fetched_mellum"] == live * 4
     assert 0 < facts["combine_rows_fetched_command_a"] < live * 4
+
+
+def test_block_diffusion_phase_interpreted():
+    """The phase at a small size with the kernel paths forced: the flash
+    forward with `block=` and the block-row paged kernel interpreted, two
+    blocks of passes, every served token judged at the pass that fixed it."""
+    facts = chip_smoke.phase_block_diffusion(
+        hidden=128, heads=2, kv_heads=1, width=128, experts=4, vocab=256,
+        prompt_len=18, max_new=6, bucket=128, page=8, force_kernels=True)
+    assert facts["blocks_committed"] == 2
+    assert len(facts["fixed_at"]) == 6 and set(facts["fixed_at"]) <= {0, 1, 2, 3}
+    assert facts["max_logit_deficit"] <= chip_smoke.LOGIT_MARGIN
+    assert 0 < facts["max_confidence_drift"] <= chip_smoke.LOGIT_MARGIN
 
 
 def test_train_then_serve_phases():

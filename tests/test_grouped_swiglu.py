@@ -327,3 +327,44 @@ def test_the_mixers_rounds_keep_their_layout_behind_the_combine_kernel(
     assert text.count('custom_call_target="tpu_custom_call"') == 4
     rounds = re.findall(r"f32\[4,4,%d\]\{([0-9,]+)" % tokens, text)
     assert len(rounds) > 40 and set(rounds) <= {"2,0,1", "2,1,0"}, set(rounds)
+
+
+def test_a_block_pass_kernels_compile_for_a_described_v5e(one_chip):
+    """SDAR-30B-A3B-Chat's widths (4 KV heads of 128, 8 queries a KV head,
+    blocks of 4, pages of 128): the grouped paged kernel with BLOCK ROWS
+    (32 query rows a KV head, 4 new rows through the live page) over 64
+    slots of 32 pages, and the flash forward under the block-causal mask
+    at the 3,072-row bucket with a traced length."""
+    from paddle_tpu.ops import flash_attention as fa
+    from paddle_tpu.ops import paged_attention as pa
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    S, B, heads, kvh, d, pages = 64, 4, 32, 4, 128, 32
+
+    def block_rows(q, new, arena, pt, lengths):
+        return pa._grouped_call(q, new, arena, 1, pt, jnp.zeros_like(lengths),
+                                lengths, False)
+
+    # (tests/conftest.py asks for float32 products everywhere; the kernel's
+    # are the arena's bfloat16, as the chip's run has them)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(block_rows, donate_argnums=(2,)).lower(
+            shape(S, B, heads, d), shape(S, B, kvh, 2 * d),
+            shape(6, 1, S * pages + 1, kvh, 128, 2 * d),
+            shape(S, pages, dtype=jnp.int32),
+            shape(S, dtype=jnp.int32)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    # the arena leaves the call as the buffer it came in
+    assert compiled.memory_analysis().temp_size_in_bytes < (64 << 20)
+
+    def prefill_attention(q, k, v, length):
+        return fa._causal_rows_call(q, k, v, length, d ** -0.5, False,
+                                    block=B)
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(prefill_attention).lower(
+            shape(3072, heads, d), shape(3072, kvh, d), shape(3072, kvh, d),
+            shape(dtype=jnp.int32)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1

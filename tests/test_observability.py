@@ -262,7 +262,8 @@ def test_self_time_rollup_subtracts_children():
     with obs.trace_span("parent"):
         time.sleep(0.002)
         with obs.trace_span("child"):
-            time.sleep(0.004)
+            # long against what a loaded host oversleeps the parent's 2 ms by
+            time.sleep(0.05)
     st = obs.self_times(obs.get_tracer().snapshot())
     assert st["parent"]["total_us"] > st["parent"]["self_us"]
     assert st["child"]["self_us"] == pytest.approx(
