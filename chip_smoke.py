@@ -453,11 +453,18 @@ def phase_train(cfg, batch, seq, steps=3, learning_rate=1e-4,
     under CompiledProgram.with_data_parallel over every device, and
     one_chip_losses (same sizes, one device) is what it must reproduce.
     Returns the losses, the scope (the serve phase reads its weights),
-    and how many Mosaic custom calls the compiled step holds."""
+    how many Mosaic custom calls the compiled step holds, and per step
+    how many scope variables the executor handed to its plan's _put
+    (`scope_vars_placed`) with the runs that found every one in place:
+    only the first step after the startup program may place any."""
     import jax
     import jax.numpy as jnp
     import paddle_tpu as pt
     from paddle_tpu.models.gpt import gpt_lm_program
+    from paddle_tpu.observability.metrics import get_registry
+
+    def counted(name):
+        return int(get_registry().counter(name).value)
 
     _require(cfg.dropout == 0.0, "train: cfg.dropout must be 0")
     main, startup, fetches = gpt_lm_program(
@@ -473,16 +480,28 @@ def phase_train(cfg, batch, seq, steps=3, learning_rate=1e-4,
 
     exe = pt.Executor()
     scope = pt.Scope()
-    losses = []
+    losses, placed_by_step = [], []
     with pt.scope_guard(scope):
         exe.run(startup)
+        in_place0 = counted("executor_scope_in_place_runs_total")
         for step in range(steps):
             # the optimized HLO of the step, once: it is a second
             # lower+compile of the same computation
             exe.capture_hlo = step == 0
+            before = counted("executor_scope_vars_placed_total")
             out, = exe.run(target, feed=feed, fetch_list=[loss_var])
+            placed_by_step.append(
+                counted("executor_scope_vars_placed_total") - before)
             losses.append(float(np.asarray(out).reshape(-1)[0]))
+        in_place = counted("executor_scope_in_place_runs_total") - in_place0
     tag = f"train: b={batch} s={seq}" + (" dp" if data_parallel else "")
+    _require(not any(placed_by_step[1:]) and in_place >= steps - 1,
+             f"{tag}: a step after the first placed scope variables "
+             f"(by step {placed_by_step}; {in_place} of {steps} runs "
+             "found the scope in place)")
+    _require(bool(placed_by_step[0]) == bool(data_parallel),
+             f"{tag}: the first step placed {placed_by_step[0]} scope "
+             "variables")
     if data_parallel:
         # replicated parameters, one copy per chip: a plan that had only
         # met a virtual mesh could leave everything on the first device
@@ -505,6 +524,7 @@ def phase_train(cfg, batch, seq, steps=3, learning_rate=1e-4,
     _require(losses[-1] < losses[0],
              f"{tag}: loss did not fall on a repeated batch: {losses}")
     facts = {"losses": losses, "mosaic_calls": mosaic_calls,
+             "scope_vars_placed": placed_by_step, "runs_in_place": in_place,
              "scope": scope}
     if one_chip_losses is not None:
         gap = max(abs(a - b) / abs(a)

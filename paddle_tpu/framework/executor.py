@@ -63,15 +63,36 @@ class Scope:
 
     def __init__(self):
         self._vars: Dict[str, Any] = {}
+        # name -> the sharding plan under whose layout the value is KNOWN
+        # to sit: a step compiled under that plan returned it, or the
+        # executor placed it for that plan. Every other write forgets the
+        # name, so the next run under a plan places it again. The scope
+        # holds the note, not the executor: it dies with the scope and no
+        # array is referenced twice.
+        self._placed_for: Dict[str, Any] = {}
 
     def find_var(self, name: str):
         return self._vars.get(name)
 
     def set_var(self, name: str, value) -> None:
         self._vars[name] = value
+        if self._placed_for:
+            self._placed_for.pop(name, None)
 
     def erase(self, name: str) -> None:
         self._vars.pop(name, None)
+        self._placed_for.pop(name, None)
+
+    def _set_placed(self, values: Dict[str, Any], plan) -> None:
+        """The executor's own write: `values` as a step compiled under
+        `plan` returned them (or as placed for it); plan None is a plain
+        write of them all."""
+        self._vars.update(values)
+        if plan is not None:
+            self._placed_for.update(dict.fromkeys(values, plan))
+        elif self._placed_for:
+            for name in values:
+                self._placed_for.pop(name, None)
 
     def var_names(self) -> List[str]:
         return list(self._vars)
@@ -173,7 +194,10 @@ def classify_persistables(program, feed_names: set, fetch_names):
     return mutable, created, readonly
 
 
-def _as_feed_array(value, var: Optional[Variable]):
+def _as_feed_array(value, var: Optional[Variable], on_host: bool = False):
+    """A feed in its variable's dtype. on_host leaves a host value on the
+    host, in the dtype the device will hold, for a sharding plan to send
+    to its shards in one transfer; a device array is returned as it is."""
     import jax
     import jax.numpy as jnp
     if isinstance(value, jax.Array):
@@ -185,6 +209,9 @@ def _as_feed_array(value, var: Optional[Variable]):
     arr = np.asarray(value)
     if var is not None and var.dtype is not None:
         arr = arr.astype(var.dtype, copy=False)
+    if on_host:
+        return arr.astype(jax.dtypes.canonicalize_dtype(arr.dtype),
+                          copy=False)
     return jnp.asarray(arr)
 
 
@@ -357,8 +384,9 @@ class Executor:
         # the work is and never overlapping: prepare (everything up to
         # the compile-cache lookup), compile (a miss), place (scope reads
         # and host->device placement), dispatch (the compiled step's
-        # call, which returns futures), writeback (scope.set_var, host
-        # post ops, finite flags), fetch (the wait for the device).
+        # call, which returns futures), writeback (the donated inputs
+        # let go, the scope's update, host post ops, finite flags), fetch
+        # (the wait for the device), release (what the run still holds).
         with trace_span("executor/prepare", "executor"):
             # Host-boundary ops (save/load/send/recv/readers) run eagerly
             # against the scope: the prefix before the first compute op
@@ -487,34 +515,60 @@ class Executor:
         with trace_span("executor/place", "executor"):
             reg.gauge("executor_cache_size",
                       "compiled executables cached").set(len(self._cache))
-            mut_in = {}
-            for n in mutable:
-                val = scope.find_var(n)
-                if val is None:
-                    raise RuntimeError(
-                        f"persistable var {n!r} not initialized in scope; "
-                        "run the startup program first")
-                mut_in[n] = val
-            ro_in = {n: scope.find_var(n) for n in readonly}
-            for n, v in ro_in.items():
-                if v is None:
-                    raise RuntimeError(
-                        f"persistable var {n!r} not initialized in scope; "
-                        "run the startup program first")
-            feed_in = {k: _as_feed_array(v, blk.vars.get(k))
-                       for k, v in feed.items()}
+            # Under a plan, a name goes through the plan's _put only where
+            # the scope does not say that a step under this plan left it
+            # (Scope._placed_for): the first step after startup, a value
+            # the user or another program wrote in between, a second
+            # scope. A read-only value placed here is written back, as a
+            # step's outputs are, so it is transferred once and a later
+            # set_var of it is seen like any other.
+            known = scope._placed_for if dist_plan is not None else None
+            mut_in: Dict[str, Any] = {}
+            # the read-only values and, until it is taken out below, the
+            # key: on a multi-process mesh it must be a GLOBAL replicated
+            # array (every process holds the same key: startup ran with
+            # the same seed everywhere), so it is placed like the rest
+            ro_in: Dict[str, Any] = {}
+            to_place: Dict[str, Any] = {}
+            for names, vals in ((mutable, mut_in),
+                                ((*readonly, "@RNG@"), ro_in)):
+                for n in names:
+                    val = scope.find_var(n)
+                    if val is None:
+                        raise RuntimeError(
+                            f"persistable var {n!r} not initialized in "
+                            "scope; run the startup program first")
+                    if known is not None and known.get(n) is not dist_plan:
+                        to_place[n] = val
+                    vals[n] = val
+            if to_place:
+                to_place = dist_plan.place_scope(to_place)
+                for n, val in to_place.items():
+                    (mut_in if n in mut_in else ro_in)[n] = val
+                # the step returns what it consumes; the rest stays placed
+                scope._set_placed({n: v for n, v in to_place.items()
+                                   if n in ro_in}, dist_plan)
+            key = ro_in.pop("@RNG@")
+            reg.counter("executor_scope_vars_placed_total",
+                        "scope variables a run handed to its plan's _put "
+                        "(not left in place by a step under the same "
+                        "plan)").inc(len(to_place))
+            if not to_place:
+                reg.counter("executor_scope_in_place_runs_total",
+                            "runs that found every scope variable in "
+                            "place").inc()
+            # nothing below may hold a donated input but mut_in
+            del val, vals, to_place
             if dist_plan is not None:
-                feed_in = dist_plan.shard_feed(feed_in)
-                mut_in = dist_plan.place_scope(mut_in)
-                ro_in = dist_plan.place_scope(ro_in)
-
-            key = scope.find_var("@RNG@")
-            if dist_plan is not None:
-                # on a multi-process mesh the key must be a GLOBAL
-                # replicated array (every process holds the same key:
-                # startup ran with the same seed everywhere); _put is a
-                # no-op otherwise
-                key = dist_plan._put(key, dist_plan.scope_sharding("@RNG@"))
+                # host values go from the host to their shards in one
+                # transfer; a device array under the plan's sharding
+                # passes through
+                feed_in = dist_plan.shard_feed(
+                    {k: _as_feed_array(v, blk.vars.get(k), on_host=True)
+                     for k, v in feed.items()})
+            else:
+                feed_in = {k: _as_feed_array(v, blk.vars.get(k))
+                           for k, v in feed.items()}
 
         if getattr(self, "capture_hlo", False):
             # tools/comm_volume.py: optimized HLO with the SPMD partitioner's
@@ -540,9 +594,14 @@ class Executor:
                 mut_in, ro_in, feed_in, key)
 
         with trace_span("executor/writeback", "executor"):
-            for n, v in new_mut.items():
-                scope.set_var(n, v)
-            scope.set_var("@RNG@", new_key)
+            # The donated inputs go now, while the device computes: the
+            # step consumed their buffers at dispatch, and with this
+            # reference gone each is freed as the scope overwrites it
+            # below (some hundreds of arrays, a shard a chip each), not
+            # after the fetch with the chips waiting.
+            del mut_in
+            scope._set_placed(new_mut, dist_plan)
+            scope._set_placed({"@RNG@": new_key}, dist_plan)
 
             for op in host_post:  # saves/sends see the post-step scope
                 with trace_span(f"host/{op.type}", "host"):
@@ -569,11 +628,10 @@ class Executor:
             out = list(fetches)
         with trace_span("executor/release", "executor"):
             # what the call still holds of the step goes here, where it
-            # went when the function returned: the donated inputs (some
-            # hundreds of arrays whose buffers the step consumed), the
-            # dictionaries around them and the fetched device arrays.
-            # Same order, same work, under a name of its own
-            del mut_in, ro_in, feed_in, new_mut, fetches, key, new_key
+            # went when the function returned: the dictionaries, the
+            # fetched device arrays and the keys (the donated inputs went
+            # at the head of writeback)
+            del ro_in, feed_in, new_mut, fetches, key, new_key
         return out
 
     # -- training telemetry (observability/train_stats.py) -------------------

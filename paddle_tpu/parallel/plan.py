@@ -41,6 +41,7 @@ class ShardingPlan:
         self.mesh = Mesh(
             np.asarray(devs).reshape(mesh_shape), self.axis_names)
         self.batch_axis = self.axis_names[0]
+        self._shardings: Dict = {}  # PartitionSpec -> NamedSharding on the mesh
 
     # -- shardings -----------------------------------------------------------
     def _spec(self, *parts):
@@ -48,8 +49,13 @@ class ShardingPlan:
         return PartitionSpec(*parts)
 
     def _nsh(self, spec):
-        from jax.sharding import NamedSharding
-        return NamedSharding(self.mesh, spec)
+        """The mesh's NamedSharding of `spec`, built once a spec: a step
+        asks for hundreds of them and nearly all are the replicated one."""
+        sh = self._shardings.get(spec)
+        if sh is None:
+            from jax.sharding import NamedSharding
+            sh = self._shardings[spec] = NamedSharding(self.mesh, spec)
+        return sh
 
     def feed_sharding(self, shape=None, name=None):
         """Explicit per-feed PartitionSpec when given (e.g. sequence dim on
@@ -130,15 +136,13 @@ class ShardingPlan:
         return out
 
     def place_scope(self, scope_vals: Dict):
-        out = {}
-        for k, v in scope_vals.items():
-            sh = self.scope_sharding(k)
-            arr = getattr(v, "sharding", None)
-            if arr is not None and arr == sh:
-                out[k] = v
-            else:
-                out[k] = self._put(v, sh)
-        return out
+        """Scope values under their shardings (`_put` hands back one that
+        sits there already). The executor sends only the names it cannot
+        see a step under this plan to have left: `jit` below returns every
+        mutable and created name under `scope_sharding(name)`, and that is
+        what it recognises them by."""
+        return {k: self._put(v, self.scope_sharding(k))
+                for k, v in scope_vals.items()}
 
     def constrain(self, op, env) -> None:
         """Re-assert shardings on sharded-param outputs so GSPMD keeps TP

@@ -8,6 +8,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -113,6 +114,9 @@ def test_train_then_serve_phases():
     trained = chip_smoke.phase_train(cfg, batch=4, seq=16)
     assert trained["mosaic_calls"] == 0     # no Mosaic on the CPU
     assert trained["losses"][-1] < trained["losses"][0]
+    # no plan: nothing to place, and every run says so
+    assert trained["scope_vars_placed"] == [0, 0, 0]
+    assert trained["runs_in_place"] == 3
     params = collect_gpt_params(trained["scope"], cfg, dtype=jnp.bfloat16)
     facts = chip_smoke.phase_serve(
         params, cfg, prompt_lens=(6, 7, 3, 12, 14, 16), shared_prefix=4,
@@ -131,8 +135,31 @@ def test_data_parallel_step_with_the_kernel_matches_one_device():
     one = chip_smoke.phase_train(cfg, batch=8, seq=128)
     # raises SmokeFailure if the losses part ways or parameters do not
     # sit on every device
-    chip_smoke.phase_train(cfg, batch=8, seq=128, data_parallel=True,
-                           one_chip_losses=one["losses"])
+    four = chip_smoke.phase_train(cfg, batch=8, seq=128, data_parallel=True,
+                                  one_chip_losses=one["losses"])
+    # the first step after the startup program places the scope, no later one
+    assert four["scope_vars_placed"][0] > 10
+    assert four["scope_vars_placed"][1:] == [0, 0]
+    assert four["runs_in_place"] == 2
+
+
+def test_train_phase_fails_when_a_later_step_places_scope_variables(monkeypatch):
+    """The phase's line carries the executor's counters and the phase fails
+    where a step after the first handed scope variables to the plan: here
+    the scope is made to forget, at every write-back, who placed what."""
+    from paddle_tpu.framework.executor import Scope
+
+    def forgetful(self, values, plan):
+        for name, value in values.items():
+            self.set_var(name, value)
+
+    monkeypatch.setattr(Scope, "_set_placed", forgetful)
+    cfg = _toy(layers=1)
+    with pytest.raises(chip_smoke.SmokeFailure, match="placed scope variables"):
+        chip_smoke.phase_train(cfg, batch=8, seq=16, data_parallel=True)
+    # without a plan there is nothing to forget
+    facts = chip_smoke.phase_train(cfg, batch=8, seq=16)
+    assert facts["scope_vars_placed"] == [0, 0, 0]
 
 
 def test_compile_cache_placement(monkeypatch, tmp_path):
