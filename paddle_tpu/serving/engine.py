@@ -162,9 +162,12 @@ class ServingConfig:
     Observability knobs. Every tick's phases are spans of
     observability.trace_span whatever the knobs say (serving/tick/admit,
     /prefill_chunk, /launch, /collect, /stream under serving/engine_step,
-    with serving/decode_dispatch inside the launch; inside admit the two
-    waits for the device have their own spans, serving/wait/first_token
-    and, under page pressure, serving/wait/fence): they show in any
+    with serving/decode_dispatch inside the launch; the other waits for
+    the device have their own spans: serving/wait/first_token, one a
+    tick that admitted, BETWEEN launch and collect (ahead of the launch
+    when no dispatch is in flight) and inside no phase
+    (the tick profile books it under bookkeeping), and, under page
+    pressure, serving/wait/fence inside admit): they show in any
     profiler trace and, while the ring is on, in /tracez. The knobs only
     add sinks that those spans hand their own durations to.
     dispatch_timing=True attributes every fused decode dispatch's wall
@@ -479,6 +482,10 @@ class ServingEngine:
         # host blocked in the next fetch, and the watchdog/flight record
         # must still see the last launch that went in
         self.scheduler.on_launch = self._on_dispatch_launched
+        # an admission's first token goes out the moment the scheduler
+        # has read it, inside scheduler.step() (or the fence)
+        self.scheduler.on_first_tokens = self._emit_first_tokens
+        self._first_emitted = 0           # ... since the tick began
         # count-scaled histogram layout: one dispatch can emit up to
         # num_slots * decode_chunk * (1 + speculate_k) tokens, and the
         # acceptance histogram spans 0..speculate_k accepted per pass
@@ -743,22 +750,26 @@ class ServingEngine:
             # proceeds past it
             self.faults.begin_step(step_no)
         with trace_span("serving/tick/admit", "serving") as sp:
-            emitted = self._admit_tick(step_no)
+            self._admit_tick(step_no)
         if phases is not None:
             phases["admit"] = sp.seconds
         # chunked prefill: dispatch at most one prefill token budget,
         # interleaved with (and ordered before) this tick's decode
-        # dispatch; completed prefills' first tokens fan out here.
-        # A monolithic engine has nothing mid-prefill and opens no span.
+        # dispatch. A monolithic engine has nothing mid-prefill and
+        # opens no span.
         if self.scheduler.prefill_pending:
             with trace_span("serving/tick/prefill_chunk", "serving") as sp:
-                for event in self.scheduler.advance_prefill():
-                    self._emit(event)
-                    emitted += 1
+                self.scheduler.advance_prefill()
             if phases is not None:
                 phases["prefill_chunk"] = sp.seconds
         # scheduler.step() opens serving/tick/launch and
-        # serving/tick/collect around its two segments
+        # serving/tick/collect around its two segments and, between
+        # them (ahead of both when no dispatch is in flight),
+        # serving/wait/first_token around the one fetch of the
+        # first tokens of this tick's admissions. Those go out at once
+        # (_emit_first_tokens), apart from the collected block's
+        # events, which alone are a dispatch's tokens
+        self._first_emitted = 0
         events = self.scheduler.step()
         if events:
             self.metrics.decode_steps += 1
@@ -767,16 +778,24 @@ class ServingEngine:
             with trace_span("serving/tick/stream", "serving") as sp:
                 for event in events:
                     self._emit(event)
-            emitted += len(events)
             if phases is not None:
                 phases["stream"] = sp.seconds
         self._sync_gauges()
-        return emitted
+        return self._first_emitted + len(events)
 
-    def _admit_tick(self, step_no: int) -> int:
+    def _emit_first_tokens(self, events) -> None:
+        """The scheduler's on_first_tokens: fan the first tokens out
+        where they were read (between a tick's phases, or inside the
+        admit phase under a fence; no phase span of their own)."""
+        self._first_emitted += len(events)
+        for event in events:
+            self._emit(event)
+
+    def _admit_tick(self, step_no: int) -> None:
         """The admit phase of a tick: deferred cancels, swap-ins, queue
-        pops, and admissions with their prefill dispatches. Returns the
-        first tokens emitted."""
+        pops, and admissions with their prefill dispatches. Nothing is
+        emitted here: an admission's first token is read behind the
+        tick's launch (scheduler.step())."""
         admitted = []
         with self._lock:
             # apply deferred cancels first (scheduler state is only ever
@@ -820,7 +839,6 @@ class ServingEngine:
             while self._queue and len(admitted) < can_take:
                 admitted.append(self._queue.pop(0))
             self.metrics.queue_depth = len(self._queue)
-        emitted = 0
         for i, req in enumerate(admitted):
             with self._lock:
                 if req.state != "queued":
@@ -870,18 +888,13 @@ class ServingEngine:
             # any executor/compile spans it triggers) inherit the id;
             # request_scope is the shared no-op when tracing is off
             with request_scope(req.request_id):
-                event = self.scheduler.admit(
+                admitted_now = self.scheduler.admit(
                     req, req.prompt, req.max_new_tokens,
                     temperature=req.temperature, seed=req.seed,
                     eos_id=req.eos_id,
                     adapter_id=getattr(req, "adapter_id", 0))
-                assert event is not None  # can_admit checked, same thread
-                if event is not PREFILL_PENDING:
-                    self._emit(event)
-                    emitted += 1
-                # else: chunked prefill — pages mapped, first token
-                # surfaces from a later advance_prefill tick below
-        return emitted
+                # can_admit checked, same thread
+                assert admitted_now is PREFILL_PENDING
 
     def _sync_gauges(self) -> None:
         """The tick's tail: registry gauges and counters set from the
@@ -1003,6 +1016,9 @@ class ServingEngine:
         tokens-per-dispatch telemetry the normal step() path does —
         fence-heavy regimes would otherwise read inconsistently high
         tokens-per-dispatch."""
+        # the first tokens of admissions the fence finds half done go out
+        # ahead of every block (_emit_first_tokens), and count towards no
+        # dispatch
         for batch in self.scheduler._sync_batches():
             if batch:
                 self.metrics.decode_steps += 1
@@ -1453,6 +1469,13 @@ class ServingEngine:
         if self.adapters is not None:
             s.update(self.adapters.occupancy())
         s["compiled_executables"] = self.scheduler.compile_count
+        # the admissions' first tokens: fetches made (one a tick that
+        # admitted), tokens they carried, and those read with a dispatch
+        # already launched behind their sampler (the device had work
+        # queued while the host waited)
+        for name in ("first_token_waits", "first_tokens",
+                     "first_tokens_behind_launch"):
+            s[name] = getattr(self.scheduler, name)
         # "paged_kernel", "latent_paged_kernel" or "gather": the decode
         # step's attention path
         s["decode_attention"] = self.scheduler.decode_attention

@@ -49,7 +49,21 @@ Decode fast path (why this is fast, not just correct):
     slot retire, admissions between chunks) hides under device compute.
     This is safe without host inspection because the in-graph done mask
     freezes finished slots — the device never needs the host's verdict
-    to keep the batch sound.
+    to keep the batch sound. An ADMISSION keeps the pipeline whole: its
+    prefill and its sampler are queued, the slot runs with its first
+    token PENDING (the sampler has already written the token, the
+    position, the budget and the done bit into the device carry), and
+    the host reads the token only after the tick's launch
+    (`_resolve_first`: one fetch a tick for every admission of the
+    tick, under the span serving/wait/first_token, between
+    serving/tick/launch and serving/tick/collect), so the next chunk is
+    in the device's queue when the sampler ends. Only a tick that finds
+    NO dispatch in flight (an empty engine) reads it ahead of its
+    launch: there the sampler is all the device has to do, and the
+    launch's host time would come on top of the time to first token. A request that its
+    first token finished (eos, a budget of one) rides that chunk frozen
+    and is retired at the fetch; the fence (sync) reads pending first
+    tokens before it collects anything.
 
 The decode carry (decode_loop.DecodeCarry: current token, position,
 done, remaining budget, temperature, eos id — all per-slot — and, when
@@ -215,12 +229,12 @@ _TRACER = get_tracer()
 __all__ = ["CompileJournal", "ContinuousBatchingScheduler",
            "SequenceEvent", "SwappedSequence", "PREFILL_PENDING"]
 
-# admit()'s "admission succeeded, first token pending" sentinel: under
-# chunked prefill pages are mapped and the slot is prefilling, and the
-# first-token event will surface from a later advance_prefill tick;
-# under block diffusion the slot is running and its first tokens arrive
-# with its first committed block. Distinct from None, which still means
-# "no slot/pages right now".
+# admit()'s "admission succeeded, first token pending" sentinel, what
+# every successful admission returns: the slot is running with its first
+# token still on the device (step() reads it behind the tick's launch),
+# or prefilling in chunks (the last chunk's sampler leaves it there), or,
+# under block diffusion, running towards its first committed block.
+# Distinct from None, which still means "no slot/pages right now".
 PREFILL_PENDING = object()
 
 
@@ -487,6 +501,18 @@ class _SlotRows(NamedTuple):
     spec: Any = None
 
 
+class _PendingFirst(NamedTuple):
+    """One admission whose first token is still on the device."""
+    slot: int
+    st: "_Running"      # the slot's record; the token is emitted only
+    #                     while the slot still holds it (a cancel drops
+    #                     it); its live_from is the count of dispatches
+    #                     launched before the admission
+    first: Any          # device int32 scalar (a future; its copy to the
+    #                     host started at admission)
+    counters: Any       # the prefill's in-graph counters, or None
+
+
 class _Inflight(NamedTuple):
     """One launched-but-unfetched chunk dispatch."""
     block: Any          # device (chunk, S) int32 token block (a future)
@@ -661,6 +687,19 @@ class ContinuousBatchingScheduler:
         self._pt = None
         self._inflight: List[_Inflight] = []
         self._launches = 0
+        # admissions whose first token the host has not read yet, in
+        # admission order; and where their events go THE MOMENT they are
+        # read, apart from a block's: the engine hangs its emitter here
+        # (a first token leaves before the tick launches or collects
+        # anything more), else they wait for drain_first_tokens()
+        self._pending_first: List[_PendingFirst] = []
+        self._first_events: List[SequenceEvent] = []
+        self.on_first_tokens = self._first_events.extend
+        # fetches of first tokens, the tokens they carried, and those of
+        # them whose fetch had a dispatch launched behind their sampler
+        self.first_token_waits = 0
+        self.first_tokens = 0
+        self.first_tokens_behind_launch = 0
         # fired inside _launch, right at enqueue — the engine hangs its
         # dispatches heartbeat here so a device-side stall with the host
         # blocked in the NEXT collect still shows this launch (a metric
@@ -679,10 +718,6 @@ class ContinuousBatchingScheduler:
         # None): the engine installs its plan here so scheduled
         # dispatch delays fire at the launch site
         self.faults = None
-        # per-bucket host staging buffers, reused across admissions
-        # (jit copies feed arrays at dispatch, so mutation-after-call is
-        # safe and admission never allocates)
-        self._staging: Dict[int, np.ndarray] = {}
         # executable cost & compile journal (CompileJournal, installed
         # by the engine under ServingConfig(tick_profile=True)). The
         # None default is the pinned bare path: _jit_call dispatches
@@ -1089,11 +1124,15 @@ class ContinuousBatchingScheduler:
     def inflight_count(self) -> int:
         return len(self._inflight)
 
-    def _staging_for(self, bucket: int) -> np.ndarray:
-        buf = self._staging.get(bucket)
-        if buf is None:
-            buf = self._staging[bucket] = np.zeros((1, bucket), np.int32)
-        return buf
+    @staticmethod
+    def _staged(tokens: np.ndarray, bucket: int) -> np.ndarray:
+        """`tokens` padded to its bucket in a host buffer of its own: no
+        admission waits for its prefill any more, so the next one of the
+        tick would refill a shared buffer before this prefill's copy of
+        it is made (on the CPU jax may alias a numpy argument)."""
+        padded = np.zeros((1, bucket), np.int32)
+        padded[0, :tokens.size] = tokens
+        return padded
 
     def _adapter_args(self, adapter_id: int) -> tuple:
         """The varargs tail the prefill entry points take: (pool pytree,
@@ -1131,20 +1170,22 @@ class ContinuousBatchingScheduler:
         prefix blocks shared in, refcounted), prefill the prompt SUFFIX
         into the fresh blocks (padded to its shape bucket), sample the
         first token, and reset the slot's entries in the device decode
-        carry + page table. Returns the first-token event, or None when
+        carry + page table. Returns PREFILL_PENDING, or None when
         no slot is free OR the arena is out of pages (caller keeps the
         request queued).
 
-        With a dispatch in flight, everything here just enqueues behind
-        it (the arena/page-table/state inputs are its output futures);
-        only the first-token fetch at the end waits.
+        Nothing here waits for the device: with a dispatch in flight
+        everything enqueues behind it (the arena/page-table/state inputs
+        are its output futures), and the first token stays on the device
+        until step() has launched the tick's chunk (_resolve_first);
+        its event goes to on_first_tokens.
 
         CHUNKED PREFILL (prefill_chunk set): pages are mapped exactly
         as above, but no prefill dispatch runs here — the slot is
-        registered as mid-prefill and PREFILL_PENDING is returned; the
+        registered as mid-prefill; the
         engine's advance_prefill ticks dispatch the budget-bounded
-        chunks (first one in this same engine step) and the first-token
-        event surfaces when the final chunk's logits are sampled.
+        chunks (first one in this same engine step) and the final
+        chunk's logits are sampled as a monolithic prefill's are.
         Prefix-cache registration of this prompt's fresh full blocks is
         DEFERRED until the chunk that fills each block has been
         enqueued (a concurrent admission must never hit a block whose
@@ -1172,13 +1213,7 @@ class ContinuousBatchingScheduler:
             return PREFILL_PENDING
         suffix_len = p_len - pfx_len
         bucket = self.buckets.bucket_for(suffix_len)
-        # block diffusion's admission waits for nothing (no first token),
-        # so the next one may fill the bucket's staging buffer while this
-        # prefill's copy of it is still to be made: a buffer of its own
-        padded = self._staging_for(bucket) if self.diffusion is None \
-            else np.empty((1, bucket), np.int32)
-        padded[0, :suffix_len] = prompt[0, pfx_len:]
-        padded[0, suffix_len:] = 0
+        padded = self._staged(prompt[0, pfx_len:], bucket)
         self._count_prefill(bucket, pfx_len, suffix_len)
         with profiler.RecordEvent("serving/prefill", bucket=bucket,
                                   prompt_len=p_len, slot=slot,
@@ -1193,11 +1228,11 @@ class ContinuousBatchingScheduler:
                     pages, np.int32(slot), *self._adapter_args(adapter_id))
             self.kv.store_arena(arena)
         if self.diffusion is not None:
-            event = self._open_first_block(
+            self._open_first_block(
                 slot, req, prompt[0], max_new, temperature, seed, eos_id,
                 self._admit_counter, counters)
         else:
-            event = self._sample_first(
+            self._sample_first(
                 slot, req, logits, p_len, max_new, temperature, seed,
                 eos_id, int(prompt[0, -1]), self._admit_counter,
                 adapter_id=adapter_id, counters=counters)
@@ -1208,7 +1243,7 @@ class ContinuousBatchingScheduler:
                        request_id=getattr(req, "request_id", None),
                        slot=slot, bucket=bucket, prompt_len=p_len,
                        prefix_len=int(pfx_len), suffix_len=suffix_len)
-        return event
+        return PREFILL_PENDING
 
     def _count_prefill(self, bucket: int, start: int, real_len: int) -> None:
         """One prefill dispatch of `bucket` rows, `real_len` of them real,
@@ -1230,13 +1265,19 @@ class ContinuousBatchingScheduler:
 
     def _sample_first(self, slot, req, logits, p_len, max_new,
                       temperature, seed, eos_id, prev_tok,
-                      seq, adapter_id=0, counters=None) -> SequenceEvent:
+                      seq, adapter_id=0, counters=None) -> None:
         """Sample the first token from last-position prefill logits and
-        promote the slot to _running — the shared tail of monolithic
-        admit() and the final prefill chunk (_prefill_step). ONE body
-        so first-token finish semantics can never diverge between the
-        two paths (the chunked-streams-identical contract depends on
-        it)."""
+        promote the slot to _running with that token PENDING — the
+        shared tail of monolithic admit() and the final prefill chunk
+        (_prefill_step). ONE body so first-token finish semantics can
+        never diverge between the two paths (the
+        chunked-streams-identical contract depends on it). Nothing is
+        fetched here: the sampler has written the token and its finish
+        verdict into the device carry, so the next chunk may be queued
+        behind it unread; the copy to the host starts now and
+        _resolve_first() reads it behind the tick's launch."""
+        import jax
+
         aid_row = () if self.adapters is None \
             else (np.int32(self.adapters.row_of(adapter_id)),)
         first, self._keys, self._state = self._jit_call(
@@ -1246,26 +1287,62 @@ class ContinuousBatchingScheduler:
             np.int32(max_new),
             np.int32(-1 if eos_id is None else eos_id),
             np.int32(prev_tok), *aid_row)
-        # the one wait for the device in an admission: with a dispatch in
-        # flight the prefill and this sample are queued behind it
-        with trace_span("serving/wait/first_token", "serving"):
-            if counters is None:
-                first = int(first)
-            else:
-                # the prefill's counters ride the one fetch there is
-                import jax
-                first, counters = jax.device_get((first, counters))
-                first = int(first)
+        for leaf in jax.tree_util.tree_leaves((first, counters)):
+            leaf.copy_to_host_async()
+        st = self._running[slot] = _Running(
+            req, pos=p_len, max_new=max_new, eos_id=eos_id,
+            live_from=self._launches, seq=seq, adapter_id=adapter_id)
+        self._pending_first.append(_PendingFirst(slot, st, first, counters))
+
+    def _resolve_first(self) -> None:
+        """Read every pending first token, in admission order, by ONE
+        fetch (the prefills' counters ride it), and hand their events
+        to on_first_tokens at once: the one wait for the device that
+        admissions cost a tick. step() calls it behind the launch, so
+        the wait is for work that the next chunk is already queued
+        behind; the fence calls it before it collects. A request that its
+        first token finished leaves its slot here (the chunk launched
+        meanwhile carried it frozen, and collect skips a slot that is no
+        longer running); a token whose slot was cancelled meanwhile is
+        dropped."""
+        if not self._pending_first:
+            return
+        import jax
+
+        pending, self._pending_first = self._pending_first, []
+        with trace_span("serving/wait/first_token", "serving",
+                        {"tokens": len(pending)}):
+            fetched = jax.device_get([(p.first, p.counters)
+                                      for p in pending])
+        self.first_token_waits += 1
+        self.first_tokens += len(pending)
+        events = []
+        for p, (first, counters) in zip(pending, fetched):
+            self.first_tokens_behind_launch += \
+                self._launches > p.st.live_from
+            if counters is not None:
                 self._add_counters(counters)
-        st = _Running(req, pos=p_len, max_new=max_new, eos_id=eos_id,
-                      live_from=self._launches, seq=seq,
-                      adapter_id=adapter_id)
-        finished = st.finished_by(first)
-        if finished:
-            self.kv.free(slot)
-        else:
-            self._running[slot] = st
-        return SequenceEvent(req, first, finished)
+            if self._running.get(p.slot) is not p.st:
+                continue                 # cancelled with the token pending
+            first = int(first)
+            finished = p.st.finished_by(first)
+            if finished:
+                del self._running[p.slot]
+                self.kv.free(p.slot)
+            events.append(SequenceEvent(p.st.req, first, finished))
+        if events:
+            self.on_first_tokens(events)
+
+    def drain_first_tokens(self) -> List[SequenceEvent]:
+        """The first-token events read since the last drain, in
+        admission order, where nobody took on_first_tokens (the engine
+        does); empties the buffer. They come APART from a block's events
+        (a dispatch's token count is the block's alone) and AHEAD of
+        them: a request's first token precedes its second in every
+        stream."""
+        events = list(self._first_events)
+        self._first_events.clear()
+        return events
 
     def _open_first_block(self, slot, req, prompt, max_new, temperature,
                           seed, eos_id, seq, counters):
@@ -1274,8 +1351,7 @@ class ContinuousBatchingScheduler:
         to its first block, which opens with the prompt's last p mod B
         tokens, and the slot is promoted to _running with nothing
         produced. Nothing is fetched: the prefill's counters ride the
-        next block fetch. Returns PREFILL_PENDING, the engine's "no
-        event yet"."""
+        next block fetch."""
         B = self.diffusion["block_length"]
         whole = prompt.size // B * B
         toks, fixed = open_block(self.diffusion, prompt[whole:])
@@ -1289,14 +1365,13 @@ class ContinuousBatchingScheduler:
         self._running[slot] = _Running(
             req, pos=prompt.size, max_new=max_new, eos_id=eos_id,
             live_from=self._launches, seq=seq, produced=0)
-        return PREFILL_PENDING
 
     @property
     def prefill_pending(self) -> bool:
         """Is any admitted sequence still mid chunked prefill?"""
         return bool(self._prefilling)
 
-    def advance_prefill(self) -> List[SequenceEvent]:
+    def advance_prefill(self) -> None:
         """One CHUNKED-PREFILL tick: dispatch budget-bounded prefill
         chunks — at most `prefill_chunk` suffix tokens in total — for
         the oldest-admitted mid-prefill slots, oldest first. Called by
@@ -1304,13 +1379,10 @@ class ContinuousBatchingScheduler:
         a long prompt's prefill interleaves with decode instead of
         monopolizing the device (the Sarathi piggyback: every tick
         pays at most one chunk of prefill next to its decode chunk).
-        Returns the first-token events of sequences whose FINAL chunk
-        completed this tick (sampled by the same admission executable
-        as monolithic prefill). No-op ([] after one attribute read) on
-        a monolithic engine."""
-        if not self._prefilling:
-            return []
-        events: List[SequenceEvent] = []
+        A sequence whose FINAL chunk is dispatched this tick is sampled
+        by the same admission executable as monolithic prefill, its
+        first token pending like any admission's. No-op (one attribute
+        read) on a monolithic engine."""
         budget = self.prefill_chunk
         while self._prefilling and budget > 0:
             slot = min(self._prefilling,
@@ -1320,21 +1392,15 @@ class ContinuousBatchingScheduler:
             if n > budget:
                 break                    # per-tick token budget spent
             budget -= n
-            event = self._prefill_step(slot, n)
-            if event is not None:
-                events.append(event)
-        return events
+            self._prefill_step(slot, n)
 
-    def _prefill_step(self, slot: int, n: int) -> Optional[SequenceEvent]:
+    def _prefill_step(self, slot: int, n: int) -> None:
         """Dispatch ONE prefill chunk of `n` suffix tokens for `slot`
         (padded to its shape bucket). On the final chunk, sample the
-        first token, promote the slot to _running, and return its
-        event; None otherwise."""
+        first token and promote the slot to _running."""
         pf = self._prefilling[slot]
         bucket = self.buckets.bucket_for(n)
-        padded = self._staging_for(bucket)
-        padded[0, :n] = pf.suffix[pf.cursor:pf.cursor + n]
-        padded[0, n:] = 0
+        padded = self._staged(pf.suffix[pf.cursor:pf.cursor + n], bucket)
         start = pf.start + pf.cursor
         self._count_prefill(bucket, start, n)
         with profiler.RecordEvent("serving/prefill_chunk", bucket=bucket,
@@ -1367,12 +1433,12 @@ class ContinuousBatchingScheduler:
                        budget=self.prefill_chunk)
         pf.chunk_index += 1
         if pf.cursor < pf.suffix.size:
-            return None
+            return
         # final chunk: its last-position logits seed the first token
         # through the SAME admission sampler executable — and the same
         # promotion body — the monolithic path uses
         del self._prefilling[slot]
-        return self._sample_first(
+        self._sample_first(
             slot, pf.req, logits, pf.p_len, pf.max_new, pf.temperature,
             pf.seed, pf.eos_id, pf.prev_tok, pf.seq,
             adapter_id=pf.adapter_id)
@@ -1380,15 +1446,27 @@ class ContinuousBatchingScheduler:
     def step(self) -> List[SequenceEvent]:
         """One pipeline tick: launch the next chunk dispatch over the
         whole pool (free/finished slots ride along frozen in-graph —
-        fixed shapes are what keep this a single executable), then fetch
+        fixed shapes are what keep this a single executable), then read
+        the first tokens of the tick's admissions (behind the launch, so
+        the device has the chunk queued while the host waits; a tick
+        that launches nothing reads them in the same place, and one that
+        finds NO dispatch in flight reads them ahead of its launch: the
+        wait goes where it holds nothing up), then fetch
         and fan out the OLDEST in-flight block. With overlap on, one
         dispatch is always left in flight while sequences are active, so
         this tick's host work (device_get, event fan-out, tracing, the
         engine's retire/admit in between) runs under the NEXT dispatch's
-        device compute."""
-        if not self._running and not self._inflight:
+        device compute. Returns the block's events; the first tokens'
+        went to on_first_tokens as they were read."""
+        if not (self._running or self._inflight or self._pending_first):
             return []
         self._ensure_jits()
+        if not self._inflight:
+            # nothing is running ahead of the tick's samplers (an empty
+            # engine, or overlap off): the device has nothing else to do
+            # and the launch's host time would only be added to the
+            # first tokens', so they are read first
+            self._resolve_first()
         launched = False
         if self._running and self._needs_dispatch():
             with trace_span("serving/tick/launch", "serving") as sp:
@@ -1396,6 +1474,7 @@ class ContinuousBatchingScheduler:
             if self.on_tick_phase is not None:
                 self.on_tick_phase("launch", sp.seconds)
             launched = True
+        self._resolve_first()
         if self._inflight and (len(self._inflight) > 1 or not launched
                                or not self.overlap):
             fl = self._inflight.pop(0)
@@ -1414,9 +1493,10 @@ class ContinuousBatchingScheduler:
         them. Skipping the launch when everything left is already in
         flight is what keeps dispatches-per-token at exactly 1/chunk in
         the steady state instead of paying a tail dispatch of frozen
-        ride-alongs per drained batch. (EOS can still finish a slot
-        early — that overshoot is unknowable host-side and bounded by
-        one dispatch.)"""
+        ride-alongs per drained batch. A pending first token counts as
+        produced, so a budget of one launches nothing. (EOS can still
+        finish a slot early, its first token's too — that overshoot is
+        unknowable host-side and bounded by one dispatch.)"""
         for st in self._running.values():
             covered = sum(fl.floor for fl in self._inflight
                           if fl.index >= st.live_from)
@@ -1637,7 +1717,8 @@ class ContinuousBatchingScheduler:
         """Drop a running sequence (client disconnect): free its pages
         without emitting further tokens. Tokens the in-flight dispatch
         already produced for it are discarded at collect (the slot is no
-        longer in _running). Unlike EOS/budget retirement — where the
+        longer in _running), and so is a first token still pending
+        (_resolve_first). Unlike EOS/budget retirement — where the
         decode loop froze the slot in-graph at the exact finish token —
         a cancel is a host-only verdict, so the release executable
         freezes the device-side slot and points its page row at scratch
@@ -1670,21 +1751,27 @@ class ContinuousBatchingScheduler:
     # -- host-swap preemption ------------------------------------------------
 
     def sync(self) -> List[SequenceEvent]:
-        """Collect EVERY in-flight dispatch and return its events — the
+        """Read every pending first token and collect EVERY in-flight
+        dispatch, and return their events, the first tokens ahead — the
         fence swap_out() needs: once the pipeline is empty, the device
         carry and arena reflect exactly the tokens the host has seen,
         so a slot's rows can be copied out without losing in-flight
         work. A slow path by construction (it forfeits the overlap
         win); callers reach for it only under page pressure or at
         shutdown."""
-        return [e for batch in self._sync_batches() for e in batch]
+        batches = self._sync_batches()
+        return self.drain_first_tokens() + [e for batch in batches
+                                            for e in batch]
 
     def _sync_batches(self) -> List[List[SequenceEvent]]:
         """sync() with per-dispatch granularity: one event list per
         collected in-flight dispatch, so the engine's fence path can
         feed the same decode_steps / tokens-per-dispatch telemetry the
-        normal step() collection does."""
+        normal step() collection does. Pending first tokens are read
+        first (no admission stays half done behind a fence); their
+        events go to on_first_tokens."""
         batches: List[List[SequenceEvent]] = []
+        self._resolve_first()
         while self._inflight:
             fl = self._inflight.pop(0)
             # not a tick's collect phase: the fence runs inside admit
@@ -1736,7 +1823,7 @@ class ContinuousBatchingScheduler:
             raise RuntimeError(
                 "swap_out of a model with several cache groups: the "
                 "payload carries the primary group alone")
-        if self._inflight:
+        if self._inflight or self._pending_first:
             raise RuntimeError(
                 "swap_out with dispatches in flight — sync() first")
         self._ensure_jits()

@@ -17,7 +17,7 @@ from paddle_tpu.serving import ServingConfig, ServingEngine, sampling
 from paddle_tpu.serving.decode_loop import (DecodeCarry, decode_chunk,
                                             finish_rule)
 from paddle_tpu.serving.model import CacheSpec, ServingModel
-from paddle_tpu.serving.scheduler import _Running
+from paddle_tpu.serving.scheduler import PREFILL_PENDING, _Running
 
 V = 23
 
@@ -142,12 +142,18 @@ def test_finish_rule_has_one_source(max_new, eos_id, token):
     always = jnp.zeros((V, V), jnp.float32).at[:, token].set(1.0)
     sched = toy_engine(always, num_slots=1, decode_chunk=1,
                        overlap=False).scheduler
+    sched.on_first_tokens = sched._first_events.extend  # driven by hand
     prompt = np.asarray([1, 2], np.int32)
 
     first_ends = max_new <= 1 or token == eos_id
-    ev = sched.admit("r", prompt, max_new, eos_id=eos_id)
-    assert ev.token == token and ev.finished == first_ends
+    assert sched.admit("r", prompt, max_new, eos_id=eos_id) \
+        is PREFILL_PENDING
     assert bool(sched._state.done[0]) == first_ends
+    # the first token is read by the tick; with overlap off a live slot's
+    # second token comes in the same tick, behind it
+    block = sched.step()
+    (ev,) = sched.drain_first_tokens()
+    assert ev.token == token and ev.finished == first_ends
     assert finish_rule(token, -1 if eos_id is None else eos_id,
                        max_new - 1) == first_ends
 
@@ -155,8 +161,10 @@ def test_finish_rule_has_one_source(max_new, eos_id, token):
     host = _Running("r", pos=2, max_new=max_new, eos_id=eos_id, live_from=0)
     host.produced = 2
     assert host.finished_by(token) == second_ends
-    if not first_ends:
-        (ev,) = sched.step()
+    if first_ends:
+        assert block == []
+    else:
+        (ev,) = block
         assert ev.token == token and ev.finished == second_ends
         assert bool(sched._state.done[0]) == second_ends
     # and the scan alone, from the carry an admission leaves

@@ -620,8 +620,9 @@ def test_engine_serves_moonlight_and_counts_without_another_sync(
         params, monkeypatch):
     """The normal path: ServingEngine over the Moonlight tree, greedy
     tokens the reference's best at every step, the counters exact, and
-    their fetch riding the fetches there already were: one device_get an
-    admission (with the first token) and one a collected chunk."""
+    their fetch riding the fetches there already were: one device_get a
+    tick that admitted (with its first tokens) and one a collected
+    chunk."""
     eng = _engine(params)
     fetches = []
     real = jax.device_get
@@ -635,7 +636,9 @@ def test_engine_serves_moonlight_and_counts_without_another_sync(
     assert stats["cache_row_bytes"] == CFG.row_width * 4
     assert stats["decode_attention"] == "gather"        # the CPU
     assert stats["compiled_executables"] == 2 + 2       # 2 buckets
-    assert len(fetches) == stats["prefills"] + stats["dispatches"]
+    assert stats["first_tokens"] == stats["prefills"] == 4
+    assert len(fetches) == stats["first_token_waits"] + stats["dispatches"]
+    assert stats["first_token_waits"] < stats["prefills"]   # 3 slots a tick
     tokens = sum(len(p) for p in prompts) + 4 * 6       # prefilled + decoded
     n_moe, k = CFG.layers - CFG.first_k_dense, CFG.experts_per_tok
     assert stats["router_tokens"] == tokens * n_moe

@@ -1661,7 +1661,7 @@ _EXEC_PHASES = {"executor/prepare", "executor/place", "executor/dispatch",
                 "executor/writeback", "executor/fetch", "executor/release"}
 _TICK_SPANS = {"serving/tick/admit", "serving/tick/launch",
                "serving/tick/collect", "serving/tick/stream"}
-_ADMISSION_SPANS = ("serving/prefill", "serving/wait/first_token")
+_FIRST_TOKEN_WAIT = "serving/wait/first_token"
 
 
 @contextlib.contextmanager
@@ -1771,38 +1771,87 @@ def test_phase_spans_reach_the_profiler_trace(layer, tiny_engine_params,
     ticks = [e for e in events if e[0] == "serving/engine_step"]
     assert ticks
     _assert_disjoint(ticks)
-    seen = set()
+    seen, tokens_waited = set(), []
     for tick in ticks:
         inside = _children_of(events, tick)
         phases = [k for k in inside if k[0].startswith("serving/tick/")]
         _assert_disjoint(phases)
         seen |= {k[0] for k in phases}
-        # a dispatch lies inside its phase: a prefill and the wait for
-        # its first token in the admission, the decode dispatch in the
-        # launch
+        # a dispatch lies inside its phase: a prefill in the admission,
+        # the decode dispatch in the launch
         for name, phase in (("serving/prefill", "serving/tick/admit"),
-                            ("serving/wait/first_token",
-                             "serving/tick/admit"),
                             ("serving/decode_dispatch",
                              "serving/tick/launch")):
             for k in (k for k in inside if k[0] == name):
                 assert any(p[0] == phase and p[1] <= k[1] and k[2] <= p[2]
                            for p in phases), (k, phases)
-        # an admission is its prefill, then the wait for its token
-        per_admission = [k for k in inside if k[0] in _ADMISSION_SPANS]
-        _assert_disjoint(per_admission)
-        assert [k[0] for k in per_admission] == \
-            list(_ADMISSION_SPANS) * (len(per_admission) // 2)
-        # at most 8 spans a tick, and those two an admission
-        assert len(inside) - len(per_admission) + 1 <= 8, inside
+        # an admission waits for nothing: a tick that admitted has ONE
+        # wait for all its first tokens, a phase's sibling behind the
+        # admissions and before the collect, inside no phase; it lies
+        # behind the launch too where a dispatch was in flight (the
+        # tick collects one besides the one it launched)
+        prefills = [k for k in inside if k[0] == "serving/prefill"]
+        _assert_disjoint(prefills)
+        waits = [k for k in inside if k[0] == _FIRST_TOKEN_WAIT]
+        assert len(waits) == bool(prefills)
+        for wait in waits:
+            assert int(wait[3]["tokens"]) == len(prefills)
+            _assert_disjoint(sorted(phases + [wait], key=lambda k: k[1]))
+            before = {p[0] for p in phases if p[2] <= wait[1]}
+            assert "serving/tick/admit" in before
+            assert "serving/tick/collect" not in before
+            in_flight = {"serving/tick/launch", "serving/tick/collect"} \
+                <= {p[0] for p in phases}
+            assert ("serving/tick/launch" in before) == in_flight
+            tokens_waited.append((len(prefills), in_flight))
+        # at most 9 spans a tick, and one more a prefill
+        assert len(inside) - len(prefills) + 1 <= 9, inside
     assert seen == _TICK_SPANS                  # monolithic prefill:
     #                                             no prefill_chunk phase
-    for name in _ADMISSION_SPANS:
-        assert sum(e[0] == name for e in events) == 3
+    assert sum(e[0] == "serving/prefill" for e in events) == 3
+    # two admissions into the empty engine, then the third into the
+    # engine they left empty: both waits ahead of their tick's launch
+    assert tokens_waited == [(2, False), (1, False)]
     assert "serving/wait/fence" not in {e[0] for e in events}
     # every event of the line lies inside some tick
     assert all(any(t[1] <= e[1] and e[2] <= t[2] for t in ticks)
                for e in events)
+
+
+def test_first_token_wait_lies_behind_the_launch(tiny_engine_params):
+    """With a dispatch in flight a tick that admits reads the first
+    tokens BEHIND its launch: one serving/wait/first_token carrying the
+    tick's count of tokens, after serving/tick/launch has ended and
+    before serving/tick/collect begins, every prefill inside the admit
+    phase before it."""
+    cfg, params = tiny_engine_params
+    obs.enable_tracing()
+    eng = _attr_engine(params, cfg, decode_chunk=4)
+    try:
+        first, *late = _attr_prompts(cfg, 2)
+        eng.submit(first, max_new_tokens=14)
+        eng.step()
+        eng.step()
+        assert eng.scheduler.inflight_count == 1
+        obs.get_tracer().clear()
+        eng.submit(late[0], max_new_tokens=3)
+        eng.step()
+        spans = {}
+        for sp in obs.get_tracer().snapshot():
+            spans.setdefault(sp.name, []).append(sp)
+        eng.run_until_drained()
+    finally:
+        eng.close()
+    end = lambda sp: sp.ts_us + sp.dur_us
+    (admit,), (launch,), (collect,), (wait,), (prefill,) = (
+        spans[name] for name in (
+            "serving/tick/admit", "serving/tick/launch",
+            "serving/tick/collect", "serving/wait/first_token",
+            "serving/prefill"))
+    assert wait.args == {"tokens": 1}
+    assert admit.ts_us <= prefill.ts_us and end(prefill) <= end(admit)
+    assert end(admit) <= launch.ts_us
+    assert end(launch) <= wait.ts_us and end(wait) <= collect.ts_us
 
 
 @pytest.mark.parametrize("layer", ["executor", "engine"])

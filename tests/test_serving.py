@@ -647,22 +647,28 @@ def test_kv_pool_donated_in_place(trained):
     assert eng.stats()["completed"] == 1
 
 
-def test_admit_staging_buffers_reused(trained):
-    """Admission stages prompts through ONE preallocated host buffer per
-    bucket instead of a fresh np.zeros per call."""
+def test_admit_stages_every_prompt_in_its_own_buffer(trained):
+    """No admission waits for its prefill any more, so two prompts of
+    ONE bucket admitted in one tick cannot share a host staging buffer
+    (the second would refill it before the first prefill's copy is
+    made): each pads into a buffer of its own, the pad zeroed."""
     cfg, _ = trained
     eng = make_engine(trained, num_slots=2)
-    rng = np.random.RandomState(15)
-    eng.generate([rng.randint(0, cfg.vocab_size, (3,)).astype(np.int32)],
-                 max_new_tokens=2)
     sched = eng.scheduler
-    buf4 = sched._staging.get(4)
-    assert buf4 is not None and buf4.shape == (1, 4)
-    eng.generate([rng.randint(0, cfg.vocab_size, (4,)).astype(np.int32),
-                  rng.randint(0, cfg.vocab_size, (7,)).astype(np.int32)],
-                 max_new_tokens=2)
-    assert sched._staging.get(4) is buf4     # same object, reused
-    assert set(sched._staging) == {4, 8}     # one buffer per bucket
+    rng = np.random.RandomState(15)
+    three, four = (rng.randint(1, cfg.vocab_size, (n,)).astype(np.int32)
+                   for n in (3, 4))
+    a, b = sched._staged(three, 4), sched._staged(four, 4)
+    assert a is not b and a.shape == b.shape == (1, 4)
+    assert a[0].tolist() == three.tolist() + [0] and a.dtype == np.int32
+    assert b[0].tolist() == four.tolist()
+    reqs = [eng.submit(p, max_new_tokens=2) for p in (three, four)]
+    eng.step()                               # both admitted in one tick
+    assert eng.stats()["first_tokens"] == 2
+    eng.run_until_drained()
+    for req, p in zip(reqs, (three, four)):
+        assert req.output().tolist() == sequential_ref(trained, p,
+                                                       2).tolist()
 
 
 def test_dispatch_amortization_metrics(trained):
