@@ -192,7 +192,7 @@ def phase_share_kernels(heads=128, kv_heads=8, head_dim=128, window=4096,
     of window / block + 1 blocks (slots below, at and beyond the window,
     one frozen); the flash forward's band of `window` over `rows` rows
     with shared KV heads; and the expert layer that holds `held` of
-    `experts` experts (`models/moonlight._moe`: the sliced kernel where an
+    `experts` experts (`models/_experts.moe`: the sliced kernel where an
     expert's matrices do not fit VMEM whole) at a step's and a prompt's
     row counts; and the combine kernel (ops/routed_combine) against XLA's
     gather and sum of the same rows, at `combine_hidden` lanes with every
@@ -201,7 +201,7 @@ def phase_share_kernels(heads=128, kv_heads=8, head_dim=128, window=4096,
 
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.models import moonlight as ml
+    from paddle_tpu.models import _experts as ex
     from paddle_tpu.ops.flash_attention import flash_causal_rows
     from paddle_tpu.ops.paged_attention import paged_attention
 
@@ -276,7 +276,7 @@ def phase_share_kernels(heads=128, kv_heads=8, head_dim=128, window=4096,
           "shared_gate": w(hidden, shared * width),
           "shared_up": w(hidden, shared * width),
           "shared_down": w(shared * width, hidden)}
-    moe = jax.jit(lambda lp, x, live: ml._moe(cfg, lp, x, live))
+    moe = jax.jit(lambda lp, x, live: ex.moe(cfg, lp, x, live))
 
     def twin(lp, x, live):
         """Every held expert on every token, weighted by the pick's weight
@@ -284,13 +284,13 @@ def phase_share_kernels(heads=128, kv_heads=8, head_dim=128, window=4096,
         weights are ARGUMENTS: as constants 2 GB of them cost the compiler
         tens of GB of the host's memory."""
         x32 = x.astype(jnp.float32)
-        pk, pw = ml.route(cfg, lp, x)
+        pk, pw = ex.route(cfg, lp, x)
         f32 = lambda a: a.astype(jnp.float32)
-        y = ml._swiglu(x32, f32(lp["shared_gate"]), f32(lp["shared_up"]),
+        y = ex.swiglu(x32, f32(lp["shared_gate"]), f32(lp["shared_up"]),
                        f32(lp["shared_down"])) / shared
         for e in range(held):
             we = jnp.sum(jnp.where(pk == held + e, pw, 0), -1)
-            y = y + jnp.where(live, we, 0)[:, None] * ml._swiglu(
+            y = y + jnp.where(live, we, 0)[:, None] * ex.swiglu(
                 x32, f32(lp["w_gate"][e]), f32(lp["w_up"][e]),
                 f32(lp["w_down"][e]))
         return y
@@ -311,7 +311,7 @@ def phase_share_kernels(heads=128, kv_heads=8, head_dim=128, window=4096,
                  f"{int(live.sum()) * picks}")
         facts[f"moe_rel_err_{n}"] = err
         facts[f"moe_held_picks_{n}"] = held_picks
-    facts["moe_path"] = ml.expert_product_path(lp)
+    facts["moe_path"] = ex.expert_product_path(lp)
 
     # the combine kernel against XLA's gather and sum of the same rows:
     # Mellum's widths (8 picks of 64, every pick held) and this share's (8
@@ -332,7 +332,7 @@ def phase_share_kernels(heads=128, kv_heads=8, head_dim=128, window=4096,
         bits = jax.lax.bitcast_convert_type(ys, jnp.uint16).astype(jnp.uint32)
         words = (bits[:, :h // 2] | (bits[:, h // 2:] << 16))[:, None, :]
         pw = jnp.where(pos < buffer, jnp.abs(normal(n, picks)), 0)
-        both = jax.jit(lambda ys, sh, by_dma: ml._combine(
+        both = jax.jit(lambda ys, sh, by_dma: ex._combine(
             ys, pos, pw, live, sh, term, jnp.bfloat16, by_dma),
             static_argnums=2)
         sh = None if term is None else normal(n, h).astype(jnp.bfloat16)
@@ -366,7 +366,7 @@ def phase_block_diffusion(hidden=256, heads=8, kv_heads=2, head_dim=128,
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from paddle_tpu.models import mellum, sdar
+    from paddle_tpu.models import _grouped, sdar
     from paddle_tpu.serving import ServingConfig, ServingEngine
 
     cfg = sdar.SdarConfig(
@@ -376,12 +376,12 @@ def phase_block_diffusion(hidden=256, heads=8, kv_heads=2, head_dim=128,
         max_pos=max(4 * page, 2 * bucket), mask_token_id=vocab - 1,
         init_range=0.05, name="sdar-smoke")
     params = sdar.init_params(cfg, jax.random.PRNGKey(40), jnp.bfloat16)
-    paths = (mellum.decode_attention_path, mellum.prefill_attention_path)
+    paths = (_grouped.decode_attention_path, _grouped.prefill_attention_path)
     if force_kernels:
-        for module in (sdar, mellum):     # the programs' and the verdicts'
-            module.decode_attention_path = \
-                lambda a, c=None: {"full": "paged_kernel"}
-            module.prefill_attention_path = lambda a, b, c=None: "flash"
+        # the programs' and the verdicts' one source
+        _grouped.decode_attention_path = \
+            lambda a, c=None: {"full": "paged_kernel"}
+        _grouped.prefill_attention_path = lambda a, b, c=None: "flash"
     try:
         engine = ServingEngine(params, cfg, ServingConfig(
             num_slots=2, prefill_buckets=(bucket,), max_len=cfg.max_pos,
@@ -391,8 +391,8 @@ def phase_block_diffusion(hidden=256, heads=8, kv_heads=2, head_dim=128,
         engine.run_until_drained()
         stats = engine.stats()
     finally:
-        for module in (sdar, mellum):
-            module.decode_attention_path, module.prefill_attention_path = paths
+        _grouped.decode_attention_path, _grouped.prefill_attention_path = \
+            paths
     B = cfg.block_length
     _require(req.state == "finished" and len(req.tokens) == max_new
              and len(req.fixed_at) == len(req.confidence) == max_new,

@@ -11,76 +11,63 @@ cache manager and fused chunk loop as every other model:
     learned scale, no bias), statistics in float32; a final LN before
     the head; the head is the embedding, transposed
     (`tie_word_embeddings`), times `logit_scale`;
-  * `heads` query heads share `kv_heads` KV heads (128 over 8: query
-    head i reads KV head i // 16), no bias, no q/k norm, softmax scale
-    head_dim^-0.5. `layer_types[l]` is "sliding_attention" (position i
-    attends j with i - sliding_window < j <= i; q and k rotated on all
-    `head_dim` values in INTERLEAVED pairs, `rope_gptj`) or
-    "full_attention" (causal over everything and NO positions at all:
-    q and k are the projections as they are). The two kinds are the two
-    cache groups of models/mellum ("full", the primary, and "window", a
-    ring of ceil(window / block_size) + 1 blocks a slot), prefilled
-    through the banded / full flash forward and decoded through the
-    grouped paged kernel, by mellum's own pieces;
-  * every layer is sparse: `models/moonlight._moe` with
-    `router_scoring = "sigmoid"`, no correction bias, no scaling factor,
-    the picks' scores over their sum; `n_shared_experts` shared SwiGLU
-    experts stored as ONE SwiGLU n times as wide and combined by their
+  * 128 query heads over 8 KV heads, no bias, no q/k norm, softmax scale
+    head_dim^-0.5; a "sliding_attention" layer (window 4096) rotates q
+    and k on all `head_dim` values in INTERLEAVED pairs (`rope_gptj`), a
+    "full_attention" layer has NO positions at all. The two kinds are
+    the two cache groups of models/_grouped.py, prefilled through the
+    banded / full flash forward and decoded through the grouped paged
+    kernel, by that module's pieces;
+  * every layer is sparse: the shared expert layer (models/_experts.py)
+    with sigmoid scoring, no correction bias, no scaling factor, the
+    picks' scores over their sum; the shared experts combined by their
     MEAN (`shared_expert_combination = "average"`); `experts_held =
     (first, count)`: the routed experts this chip holds of
-    `n_routed_experts` (None: all). The router scores every expert of
-    the model; a pick of an expert held elsewhere gets no row, and what
-    it would add is left out (`moonlight.held_experts`);
+    `n_routed_experts` (None: all; `_experts.held_experts`);
   * `vocab_size` is the rows of the embedding HELD here; `vocab_slice`
     (first, rows, of) names them in the published vocabulary. Token ids
     are the slice's own (0 .. rows - 1): the traffic draws them there,
     and the logits and the sampling are over the slice.
 
-Shared, not copied: models/mellum's cache groups, prefill attention,
-ring write and decode attention (`_attend_rows`, `_write_ring`,
-`_attend_step`, the path verdicts); models/moonlight's `rope`, `_moe`,
-`route`, `grouped_experts`, `_masked_attention`, the counters.
+Shared, not copied: models/_grouped.py's cache groups, prefill and decode
+attention, path verdicts and serving class; models/_experts.py's layer and
+counters; models/_decoder.py's `rope` and `embed`; serving/pages.py's page
+and ring writes.
 
-Parameters (`x @ W`, W is (in, out)): wte (V, h), norm_f (h,),
-layers[i]: norm (h,); wq (h, heads*d), wk, wv (h, kv_heads*d), wo
-(heads*d, h); router (h, E); w_gate, w_up (held, h, F), w_down (held, F,
-h); shared_gate, shared_up (h, n_shared*F), shared_down (n_shared*F, h).
+Parameters: `_grouped.init_params`' tree (ONE `norm` a layer, the held
+experts' matrices alone, no `head`).
 
 Named scopes: `embed`, `norm`, `attn/project`, `attn/window`,
 `attn/full`, `moe/router`, `moe/dispatch`, `moe/experts`, `moe/shared`,
 `moe/combine`, `head`. In-graph counters beside the expert layer's and
-mellum's `decode_rows_*`: `moe_picks_routed` (live tokens x
+the cache groups' `decode_rows_*`: `moe_picks_routed` (live tokens x
 experts_per_tok, every layer) and `moe_picks_held` (those that fell on
 an expert held here), by both programs, and `decode_moe_picks_routed`,
 `decode_moe_picks_held` by the decode step alone.
 
 Not built, because the published config has no key for it: the vision
 tower; the `prefix_dense_*` layers (`first_k_dense_replace` is 0).
-Refused by the engine from `features`, as mellum's are.
+Refused by the engine from `features`: int8 weights or cache, adapters,
+speculation, a mesh plan, chunked prefill; and, having two cache groups,
+host swap and migration.
 """
 
 from __future__ import annotations
 
-from .gpt_decode import _write_pages
-from .mellum import (FULL, WINDOW, MellumConfig, _MellumServingModel,
-                     _arena_out, _arenas, _attend_rows, _attend_step,
-                     _tables, _write_ring, decode_attention_path,
-                     prefill_attention_path)
-from .moonlight import _act_dtype, _moe, _zero_counters, held_experts, rope
+from . import _decoder, _experts, _grouped
 
 __all__ = ["CommandAConfig", "init_params", "forward_logits",
            "prefill_pages", "decode_step_pages", "COMMAND_A_SERVING_MODEL"]
 
 
-class CommandAConfig(MellumConfig):
+class CommandAConfig(_grouped.GroupedConfig):
     """The published keys under this package's names (defaults are
     command-a-plus-05-2026's `config.json`, whole: every expert and the
-    whole vocabulary held)."""
+    whole vocabulary held). Of what models/_experts.py reads it states
+    the shared experts' MEAN; the scoring, the factor and the held range
+    are that module's defaults unless `experts_held` is given."""
 
-    router_scoring = "sigmoid"
     shared_expert_combination = "average"
-    routed_scaling_factor = 1.0
-    rope_scaling = None
 
     def __init__(self, vocab_size=262144, hidden=4096, layers=32, heads=128,
                  kv_heads=8, head_dim=128, moe_intermediate=4096,
@@ -96,8 +83,8 @@ class CommandAConfig(MellumConfig):
             n_routed_experts=n_routed_experts,
             experts_per_tok=experts_per_tok, layer_types=layer_types,
             sliding_window=sliding_window, rms_eps=None,
-            rope_theta=rope_theta, rope_scaling=None, max_pos=max_pos,
-            init_range=init_range, name=name)
+            rope_theta=rope_theta, max_pos=max_pos, init_range=init_range,
+            name=name)
         if experts_held is not None:
             first, count = experts_held
             if not (0 <= first and 0 < count
@@ -124,42 +111,9 @@ class CommandAConfig(MellumConfig):
 
 
 def init_params(cfg: CommandAConfig, key, dtype):
-    """Seeded random weights on the default device: normal(0, init_range)
-    matrices (the router's too), unit norms. The routed experts are the
-    `held_experts(cfg)` alone; the router is as wide as the model has
-    experts. One jitted maker called once a layer."""
-    import jax
-    import jax.numpy as jnp
-
-    h, d, F = cfg.hidden, cfg.head_dim, cfg.moe_intermediate
-    held, Fs = held_experts(cfg)[1], cfg.n_shared_experts * F
-    std = cfg.init_range
-    shapes = {"wq": (h, cfg.heads * d), "wk": (h, cfg.kv_heads * d),
-              "wv": (h, cfg.kv_heads * d), "wo": (cfg.heads * d, h),
-              "router": (h, cfg.n_routed_experts), "w_gate": (held, h, F),
-              "w_up": (held, h, F), "w_down": (held, F, h),
-              "shared_gate": (h, Fs), "shared_up": (h, Fs),
-              "shared_down": (Fs, h)}
-
-    def normal(k, shape):
-        return (std * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
-
-    def layer(k):
-        ks = jax.random.split(k, len(shapes))
-        lp = {name: normal(kk, shape)
-              for (name, shape), kk in zip(shapes.items(), ks)}
-        lp["norm"] = jnp.ones((h,), dtype)
-        return lp
-
-    def top(k):
-        return {"wte": normal(k, (cfg.vocab_size, h)),
-                "norm_f": jnp.ones((h,), dtype)}
-
-    make = jax.jit(layer)
-    keys = jax.random.split(key, cfg.layers + 1)
-    params = jax.jit(top)(keys[-1])
-    params["layers"] = [make(keys[i]) for i in range(cfg.layers)]
-    return params
+    """`_grouped.init_params`' seeded weights: the held experts' matrices
+    alone, ONE norm a layer, the head the embedding."""
+    return _grouped.init_params(cfg, key, dtype, norms=("norm",), tied=True)
 
 
 # -- the block's pieces ----------------------------------------------------------
@@ -177,12 +131,6 @@ def _layer_norm(x, g, eps):
         return (c * inv * g.astype(jnp.float32)).astype(x.dtype)
 
 
-def _embed(params, tokens, dtype):
-    import jax
-    with jax.named_scope("embed"):
-        return params["wte"][tokens].astype(dtype)
-
-
 def _project(cfg, lp, u, pos, kind):
     """The projections of normed tokens u (T, h) at positions pos (T,):
     q (T, heads, d), k, v (T, kv_heads, d). A window layer rotates q and
@@ -194,17 +142,9 @@ def _project(cfg, lp, u, pos, kind):
     k = (u @ lp["wk"]).reshape(T, cfg.kv_heads, d)
     v = (u @ lp["wv"]).reshape(T, cfg.kv_heads, d)
     if kind == "window":
-        q = rope(q, pos[:, None], cfg.rope_theta)
-        k = rope(k, pos[:, None], cfg.rope_theta)
+        q = _decoder.rope(q, pos[:, None], cfg.rope_theta)
+        k = _decoder.rope(k, pos[:, None], cfg.rope_theta)
     return q, k, v
-
-
-def _experts(cfg, lp, u, live, counters):
-    """FFN(u): the routed experts held here and the shared experts'
-    mean, the layer's counters added to `counters`."""
-    y, c = _moe(cfg, lp, u, live)
-    return y, dict(counters, **{name: counters[name] + c[name]
-                                for name in c})
 
 
 def _head(cfg, params, x):
@@ -225,15 +165,15 @@ def forward_logits(params, cfg, tokens):
     import jax.numpy as jnp
     T = tokens.shape[0]
     pos = jnp.arange(T)
-    x = _embed(params, tokens, _act_dtype(params))
-    counters = _zero_counters(cfg)
+    x = _decoder.embed(params, tokens, _decoder.act_dtype(params))
+    counters = _experts.zero_counters(cfg)
     live = jnp.ones((T,), bool)
     for li, lp in enumerate(params["layers"]):
         kind = cfg.kind(li)
         u = _layer_norm(x, lp["norm"], cfg.layer_norm_eps)
         q, k, v = _project(cfg, lp, u, pos, kind)
-        o = _attend_rows(cfg, q, k, v, kind, flash=False)
-        y, counters = _experts(cfg, lp, u, live, counters)
+        o = _grouped.attend_rows(cfg, q, k, v, kind, False)
+        y, counters = _experts.experts(cfg, lp, u, live, counters)
         x = x + o.reshape(T, -1) @ lp["wo"] + y
     return _head(cfg, params, x)
 
@@ -242,135 +182,109 @@ def forward_logits(params, cfg, tokens):
 
 def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
     """Prefill ONE sequence's COLD prompt tokens (1, B) into its page row
-    `pages`, as models/mellum.prefill_pages (the full group's columns
-    whole pages from 0, the window group's ring the pages it will hold;
-    `pfx_len` is 0). Returns (logits (1, V) float32 of position
-    real_len - 1, arena, counters)."""
+    `pages` (the full group's columns whole pages from 0, the window
+    group's ring the pages it will hold; `pfx_len` is 0). Returns (logits
+    (1, V) float32 of position real_len - 1, arena, counters)."""
     import jax
     import jax.numpy as jnp
 
-    arenas = _arenas(cfg, arena)
+    arenas = _grouped.arenas(arena)
     B = tokens.shape[1]
     bs = arenas["full"].shape[4]
     dtype = arenas["full"].dtype
-    rows_of = _tables(cfg, pages, bs)
-    flash = prefill_attention_path(arena, B) == "flash"
+    rows_of = _grouped.tables(cfg, pages, bs)
+    flash = _grouped.prefill_attention_path(arena, B) == "flash"
     j = jnp.arange(B)
     pos = pfx_len + j
     live = j < real_len
-    x = _embed(params, tokens[0], dtype)
-    counters = _zero_counters(cfg)
+    x = _decoder.embed(params, tokens[0], dtype)
+    counters = _experts.zero_counters(cfg)
     for li, lp in enumerate(params["layers"]):
         kind, lg = cfg.kind(li), cfg.index_in_group(li)
         u = _layer_norm(x, lp["norm"], cfg.layer_norm_eps)
         with jax.named_scope("attn/project"):
             q, k, v = _project(cfg, lp, u, pos, kind)
             kv = jnp.concatenate([k, v], -1).astype(dtype)
-            if kind == "full":
-                arenas[kind] = _write_pages(arenas[kind], lg, rows_of[kind],
-                                            pfx_len, real_len, kv)
-            else:
-                arenas[kind] = _write_ring(arenas[kind], lg, rows_of[kind],
-                                           real_len, kv)
+            arenas[kind] = _grouped.write_prompt(
+                arenas[kind], lg, rows_of[kind], pfx_len, real_len, kv, kind)
         with jax.named_scope("attn/" + kind):
-            o = _attend_rows(cfg, q, k, v, kind, flash, real_len)
+            o = _grouped.attend_rows(cfg, q, k, v, kind, flash, real_len)
         with jax.named_scope("attn/project"):
             a = o.reshape(B, -1) @ lp["wo"]
-        y, counters = _experts(cfg, lp, u, live, counters)
+        y, counters = _experts.experts(cfg, lp, u, live, counters)
         x = x + a + y
     last = x[real_len - 1][None]
-    return _head(cfg, params, last), _arena_out(arenas), counters
+    return _head(cfg, params, last), _grouped.arena_out(arenas), counters
 
 
 # -- decode through the pages ------------------------------------------------------
 
 def decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
                       attention=None):
-    """One decode step of every slot, as models/mellum.decode_step_pages:
-    tokens, ts (S,), pt (S, P + R); a full layer attends 0..ts, a window
-    layer max(0, ts - window + 1)..ts. Returns (logits (S, V) float32,
-    arena, counters)."""
+    """One decode step of every slot: tokens, ts (S,), pt (S, P + R); a
+    full layer attends 0..ts, a window layer max(0, ts - window + 1)..ts.
+    Returns (logits (S, V) float32, arena, counters)."""
     import jax
     import jax.numpy as jnp
 
-    arenas = _arenas(cfg, arena)
+    arenas = _grouped.arenas(arena)
     s_dim = pt.shape[0]
     bs = arenas["full"].shape[4]
     dtype = arenas["full"].dtype
-    tables = _tables(cfg, pt, bs)
+    tables = _grouped.tables(cfg, pt, bs)
     if attention is None:
-        attention = decode_attention_path(arena)
+        attention = _grouped.decode_attention_path(arena)
     live = jnp.ones((s_dim,), bool) if done is None else ~done
     lo = {"full": jnp.zeros_like(ts),
           "window": jnp.maximum(ts - cfg.sliding_window + 1, 0)}
-    x = _embed(params, tokens, dtype)
-    counters = _zero_counters(cfg)
+    x = _decoder.embed(params, tokens, dtype)
+    counters = _experts.zero_counters(cfg)
     for li, lp in enumerate(params["layers"]):
         kind, lg = cfg.kind(li), cfg.index_in_group(li)
         u = _layer_norm(x, lp["norm"], cfg.layer_norm_eps)
         with jax.named_scope("attn/project"):
             q, k, v = _project(cfg, lp, u, ts, kind)
         with jax.named_scope("attn/" + kind):
-            o, arenas[kind] = _attend_step(
+            o, arenas[kind] = _grouped.attend_step(
                 cfg, q, k, v, arenas[kind], lg, tables[kind], ts, done,
                 lo[kind], kind, attention[kind])
         with jax.named_scope("attn/project"):
             a = o.reshape(s_dim, -1).astype(dtype) @ lp["wo"]
-        y, counters = _experts(cfg, lp, u, live, counters)
+        y, counters = _experts.experts(cfg, lp, u, live, counters)
         x = x + a + y
-    for kind, name in (("full", FULL), ("window", WINDOW)):
+    for kind, name in (("full", _grouped.FULL), ("window", _grouped.WINDOW)):
         counters["decode_rows_" + kind] = (
             jnp.sum(jnp.where(live, ts - lo[kind] + 1, 0)).astype(jnp.int32)
             * cfg.layer_types.count(name))
-    return _head(cfg, params, x), _arena_out(arenas), counters
+    return _head(cfg, params, x), _grouped.arena_out(arenas), counters
 
 
 # -- the engine's view of this model ---------------------------------------------
 
-class _CommandAServingModel(_MellumServingModel):
-    def counter_names(self, cfg):
-        # mellum's (the expert layer's, `expert_tokens` over the experts
-        # HELD, and the rows a decode step attended by kind of layer) and
-        # the picks: routed = live tokens x experts_per_tok a layer, held
-        # = those whose expert is held here; `decode_*` the step's alone
-        names = dict(super().counter_names(cfg),
-                     expert_tokens=(held_experts(cfg)[1],))
-        names.update({name: () for name in (
-            "moe_picks_routed", "moe_picks_held",
-            "decode_moe_picks_routed", "decode_moe_picks_held")})
-        return names
+class _CommandAServingModel(_grouped.GroupedBlockModel):
+    prefill_pages = staticmethod(prefill_pages)
+    decode_step_pages = staticmethod(decode_step_pages)
+
+    # the picks: routed = live tokens x experts_per_tok a layer, held =
+    # those whose expert is held here; `decode_*` the step's alone
+    own_counters = ("moe_picks_routed", "moe_picks_held",
+                    "decode_moe_picks_routed", "decode_moe_picks_held")
 
     def describe(self, cfg):
-        first, count = held_experts(cfg)
+        first, count = _experts.held_experts(cfg)
         return {"experts_held": {"first": first, "count": count,
                                  "of": cfg.n_routed_experts},
                 "vocab_slice": dict(zip(("first", "rows", "of"),
                                         cfg.vocab_slice))}
 
-    @staticmethod
-    def _picks(cfg, c, decode):
+    def _counters(self, cfg, c, decode):
         import jax.numpy as jnp
-        out = _MellumServingModel._counters(c, decode)
         routed = c["router_tokens"] * cfg.experts_per_tok
         held = jnp.sum(c["expert_tokens"]).astype(jnp.int32)
-        zero = jnp.zeros((), jnp.int32)
-        out.update(moe_picks_routed=routed, moe_picks_held=held,
-                   decode_moe_picks_routed=routed if decode else zero,
-                   decode_moe_picks_held=held if decode else zero)
-        return out
-
-    def prefill(self, params, cfg, tokens, pfx_len, real_len, arena, pages,
-                adapters=None, adapter_id=None):
-        logits, arena, c = prefill_pages(params, cfg, tokens, pfx_len,
-                                         real_len, arena, pages)
-        return logits, arena, self._picks(cfg, c, decode=False)
-
-    def decode_step(self, params, cfg, tokens, arena, pt, ts, done, *,
-                    adapters=None, adapter_ids=None, arena_constraint=None):
-        logits, arena, c = decode_step_pages(
-            params, cfg, tokens, arena, pt, ts, done,
-            attention=decode_attention_path(arena, arena_constraint))
-        return logits, arena, self._picks(cfg, c, decode=True)
+        return super()._counters(cfg, dict(
+            c, moe_picks_routed=routed, moe_picks_held=held,
+            decode_moe_picks_routed=routed, decode_moe_picks_held=held),
+            decode)
 
 
 COMMAND_A_SERVING_MODEL = _CommandAServingModel("command-a-plus-05-2026")

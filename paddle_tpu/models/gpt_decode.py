@@ -34,6 +34,9 @@ from typing import Optional
 
 import numpy as np
 
+from ..serving import pages as _pages
+from . import _decoder
+
 __all__ = ["collect_gpt_params", "quantize_params", "gpt_forward_logits",
            "gpt_prefill", "gpt_decode_step", "gpt_prefill_pages",
            "gpt_decode_step_pages", "gpt_decode_verify_pages",
@@ -157,6 +160,15 @@ def _gelu_tanh(x):
     return jax.nn.gelu(x, approximate=True)
 
 
+def _probs(scores, mask, hd, dtype):
+    """softmax over the keys of `scores` / sqrt(hd) under `mask`, the
+    statistics in float32, the result in `dtype`."""
+    import jax.numpy as jnp
+    scores = jnp.where(mask, scores / np.sqrt(hd), -1e30)
+    probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
+    return (probs / probs.sum(-1, keepdims=True)).astype(dtype)
+
+
 # -- multi-tenant LoRA adapter path -----------------------------------------
 #
 # An adapter pool is the pytree {proj: {"a": (N, L, in, rank),
@@ -230,8 +242,7 @@ def gpt_forward_logits(params, cfg, tokens):
     import jax.numpy as jnp
 
     b, s = tokens.shape
-    dtype = params["wte"].dtype if params["wte"].dtype == jnp.bfloat16 \
-        else jnp.float32
+    dtype = _decoder.act_dtype(params)
     x = (params["wte"][tokens] + params["wpe"][:s]).astype(dtype)
     mask = jnp.tril(jnp.ones((s, s), bool))
     for blk in params["blocks"]:
@@ -242,10 +253,7 @@ def gpt_forward_logits(params, cfg, tokens):
         hd = q.shape[-1]
         scores = jnp.einsum("bqnd,bknd->bnqk", q, k,
                             preferred_element_type=jnp.float32)
-        scores = scores / np.sqrt(hd)
-        scores = jnp.where(mask, scores, -1e30)
-        probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
-        probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
+        probs = _probs(scores, mask, hd, dtype)
         ctx = jnp.einsum("bnqk,bknd->bqnd", probs, v).reshape(b, s, -1)
         x = x + _dense(ctx, blk["out"])
         h = _ln(x, blk["ln2"])
@@ -262,8 +270,7 @@ def _prefill_blocks(params, cfg, tokens, max_len):
 
     b, p_len = tokens.shape
     heads, hd = cfg.heads, cfg.hidden // cfg.heads
-    dtype = params["wte"].dtype if params["wte"].dtype == jnp.bfloat16 \
-        else jnp.float32
+    dtype = _decoder.act_dtype(params)
     x = _embed(params, tokens, slice(None, p_len), dtype)
     mask = jnp.tril(jnp.ones((p_len, p_len), bool))
     cache = jnp.zeros((cfg.layers, 2, b, heads, max_len, hd), dtype)
@@ -283,9 +290,7 @@ def _prefill_blocks(params, cfg, tokens, max_len):
         with _stage("attn/attend"):
             scores = jnp.einsum("bqnd,bknd->bnqk", q, k,
                                 preferred_element_type=jnp.float32)
-            scores = jnp.where(mask, scores / np.sqrt(hd), -1e30)
-            probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
-            probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
+            probs = _probs(scores, mask, hd, dtype)
             ctx = jnp.einsum("bnqk,bknd->bqnd", probs, v).reshape(
                 b, p_len, -1)
         with _stage("attn/project"):
@@ -342,10 +347,7 @@ def gpt_decode_step(params, cfg, token, cache, t):
             K, V = cache[li, 0], cache[li, 1]          # (b, n, S, hd)
             scores = jnp.einsum("bnqd,bnkd->bnqk", q, K,
                                 preferred_element_type=jnp.float32)
-            scores = jnp.where(pos_mask[None, None, None, :],
-                               scores / np.sqrt(hd), -1e30)
-            probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
-            probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
+            probs = _probs(scores, pos_mask[None, None, None, :], hd, dtype)
             ctx = jnp.einsum("bnqk,bnkd->bnqd", probs, V)
             ctx = ctx.transpose(0, 2, 1, 3).reshape(b, 1, -1)
         with _stage("attn/project"):
@@ -415,10 +417,7 @@ def gpt_decode_verify_pages(params, cfg, toks, arena, pt, ts, done=None,
             K, V = _kv_gather(arena, li, pt, dtype)  # (S, n, L, hd)
             scores = jnp.einsum("bqnd,bnkd->bnqk", q, K,
                                 preferred_element_type=jnp.float32)
-            scores = jnp.where(pos_mask[:, None, :, :],
-                               scores / np.sqrt(hd), -1e30)
-            probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
-            probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
+            probs = _probs(scores, pos_mask[:, None, :, :], hd, dtype)
             ctx = jnp.einsum("bnqk,bnkd->bqnd", probs, V).reshape(
                 s_dim, D, -1)
         with _stage("attn/project"):
@@ -451,25 +450,6 @@ def paged_arena_shapes(layers, num_blocks, heads, block_size, hd):
     return data, data[:-1] + (2,)
 
 
-def _gather_pages(leaf, li, pages):
-    """Assemble one sequence's K|V matrix from layer `li` of a block
-    arena leaf (data or scale plane).
-
-    leaf: (layers, 1, num_blocks, heads, block_size, w).
-    pages: (..., P) int32 page table (one row per sequence). Returns
-    (..., heads, P*block_size, w): the blocks in logical order, so row
-    t of the result is the K|V of absolute position t wherever block
-    t // block_size happens to live in the arena. Whole pages are
-    indexed straight out of the leaf (no `leaf[li, 0]` plane is sliced
-    out first). Entries past a sequence's allocated tail point at the
-    scratch block; the causal mask keeps attention from ever reading
-    those rows."""
-    g = leaf[li, 0, pages]                # (..., P, heads, bs, w)
-    g = g.swapaxes(-4, -3)                # (..., heads, P, bs, w)
-    return g.reshape(*g.shape[:-3], g.shape[-3] * g.shape[-2],
-                     g.shape[-1])
-
-
 # -- quantized block arena ---------------------------------------------------
 #
 # A quantized arena is the pytree (data, scales): data is the usual
@@ -496,11 +476,7 @@ def _arena_compute_dtype(params, data, scales):
     """The activation dtype a paged kernel runs in: the arena dtype for
     the full-precision form (f32/bf16 engines), the params' wte-derived
     dtype for a quantized arena (int8 is storage, never math)."""
-    import jax.numpy as jnp
-    if scales is None:
-        return data.dtype
-    return params["wte"].dtype if params["wte"].dtype == jnp.bfloat16 \
-        else jnp.float32
+    return data.dtype if scales is None else _decoder.act_dtype(params)
 
 
 def _quantize_rows(val):
@@ -542,50 +518,18 @@ def _kv_write(arena, li, wblk, woff, k, v):
     return data, scales.at[li, 0, wblk, :, woff, :].set(srows)
 
 
-def _write_pages(leaf, li, pages, start, real_len, rows):
-    """Put rows (B, heads, w), the positions start .. start+real_len-1
-    of ONE sequence, into `leaf` (data or scale plane) as WHOLE PAGES:
-    the touched pages are read, the real rows merged in by position, and
-    the pages scattered back, each a (heads, block_size, w) piece that
-    is contiguous in the arena's own layout. (A scatter of single rows
-    makes XLA want the arena with heads next to the lanes, and it then
-    copies the whole arena into that layout and back, at the program's
-    edges or around every layer.) Pages no real row falls in, and pages
-    past the page row, are redirected to scratch block 0, which is what
-    the row scatter did with pad rows; `start` need not be aligned (a
-    later chunk of a chunked prefill keeps the rows before it)."""
-    import jax
-    import jax.numpy as jnp
-    bs, w = leaf.shape[4], leaf.shape[5]
-    B, heads = rows.shape[0], rows.shape[1]
-    P = pages.shape[0]
-    n_t = -(-B // bs) + 1                 # pages B unaligned rows can touch
-    off = start % bs
-    buf = jax.lax.dynamic_update_slice(
-        jnp.zeros((n_t * bs, heads, w), rows.dtype), rows, (off, 0, 0))
-    tiles = buf.reshape(n_t, bs, heads, w).transpose(0, 2, 1, 3)
-    r = jnp.arange(n_t * bs)
-    valid = ((r >= off) & (r < off + real_len)).reshape(n_t, 1, bs, 1)
-    t = jnp.arange(n_t)
-    pidx = start // bs + t
-    ids = jnp.where((pidx < P) & (t * bs < off + real_len),
-                    pages[jnp.minimum(pidx, P - 1)], 0)
-    merged = jnp.where(valid, tiles, leaf[li, 0, ids])
-    return leaf.at[li, 0, ids].set(merged)
-
-
 def _kv_write_pages(arena, li, pages, start, real_len, k, v):
     """The prefill's K|V write of one sequence's suffix (k, v: (B, heads,
     hd), row j at position start + j, rows past real_len are padding):
-    whole pages through `_write_pages`, quantize-at-write on a quantized
-    arena (data rows and their scale-plane entries ride the same page
-    ids). Leaves every real row exactly as `_kv_write` would."""
+    whole pages through serving/pages.write_pages, quantize-at-write on a
+    quantized arena (data rows and their scale-plane entries ride the same
+    page ids). Leaves every real row exactly as `_kv_write` would."""
     data, scales = _arena_parts(arena)
     rows, srows = _kv_rows(scales, k, v)
-    data = _write_pages(data, li, pages, start, real_len, rows)
+    data = _pages.write_pages(data, li, pages, start, real_len, rows)
     if scales is None:
         return data
-    return data, _write_pages(scales, li, pages, start, real_len, srows)
+    return data, _pages.write_pages(scales, li, pages, start, real_len, srows)
 
 
 def _kv_gather(arena, li, pages, dtype):
@@ -594,40 +538,28 @@ def _kv_gather(arena, li, pages, dtype):
     fused right before the attention einsum — the only dequant site,
     no fp32 copy of the pool ever exists."""
     data, scales = _arena_parts(arena)
-    g = _gather_pages(data, li, pages)     # (..., heads, L, 2*hd)
+    g = _pages.gather_pages(data, li, pages)       # (..., heads, L, 2*hd)
     hd = g.shape[-1] // 2
     k, v = g[..., :hd], g[..., hd:]
     if scales is None:
         return k, v
-    s = _gather_pages(scales, li, pages).astype(dtype)     # (.., L, 2)
+    s = _pages.gather_pages(scales, li, pages).astype(dtype)   # (.., L, 2)
     return k.astype(dtype) * s[..., 0:1], v.astype(dtype) * s[..., 1:2]
-
-
-def _kernel_arena(arena, arena_constraint):
-    """Whether a Mosaic kernel may sit beside this arena: the backend is
-    a TPU, the arena is the bare full-precision array with a
-    lane-aligned K|V row, and no mesh plan constrains it."""
-    import jax
-    data, scales = _arena_parts(arena)
-    return (scales is None and arena_constraint is None
-            and data.shape[-1] % 128 == 0
-            and jax.default_backend() == "tpu")
 
 
 def prefill_attention_path(arena, bucket, arena_constraint=None):
     """Which attention a COLD prompt's prefill runs in a bucket of
     `bucket` rows, read off its input like decode_attention_path:
     "flash" (ops/flash_attention's forward over the prompt's own rows)
-    on an arena a kernel may sit beside (`_kernel_arena`) when the
-    bucket is whole 128-row tiles; "gather" (the page row gathered back
-    and masked by position) for everything else. On the quantized arena
-    a cold prompt must go on attending over its dequantized rows, or a
-    chunked prefill, whose later chunks read those rows, and a whole
+    where serving/pages.kernel_beside lets a kernel sit beside the arena
+    and the bucket is whole 128-row tiles; "gather" (the page row gathered
+    back and masked by position) for everything else. On the quantized
+    arena a cold prompt must go on attending over its dequantized rows, or
+    a chunked prefill, whose later chunks read those rows, and a whole
     one stop agreeing. A prefill with rows already cached (pfx_len > 0)
     gathers whatever this says."""
-    if bucket % 128 == 0 and _kernel_arena(arena, arena_constraint):
-        return "flash"
-    return "gather"
+    return "flash" if _pages.kernel_beside(arena, arena_constraint, bucket) \
+        else "gather"
 
 
 def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
@@ -656,23 +588,20 @@ def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
     pages: (P,) int32 — THIS sequence's page row; suffix K/V rows are
     written to block pages[pos // bs] offset pos % bs as whole pages.
     The attention is ONE algorithm, the causal softmax of the suffix's
-    queries, in two forms chosen by the traced pfx_len under one
-    `lax.cond`. WARM (pfx_len > 0: a prefix hit, a later chunk): the
-    whole page row is gathered back (prefix blocks included, so hit
-    blocks are never recomputed) and masked by position. COLD
-    (pfx_len == 0): the bucket's queries attend over the prompt's own
-    k, v, nothing gathered; where prefill_attention_path says "flash"
-    through the flash forward (no score matrix in HBM, no work on the
-    keys past the bucket), else through the gather as well, and the
-    program is then the one it was before there were two forms (no
-    cond). `arena_constraint` is the mesh plan's layout pin or None,
-    only asked whether there is one. Pad positions (j >= real_len)
-    compute values nobody reads in either form and write to the
-    SCRATCH block unconditionally: with a large hit prefix and a small
-    suffix bucket, pfx_len + bucket can run past max_pages*bs, where a
-    clamped page gather would collide a pad write with a real row — and
-    no real query ever reads a pad row anyway (the causal mask stops at
-    pos <= p_len - 1).
+    queries, in two forms (serving/pages.cold_or_warm). WARM: the whole
+    page row is gathered back (prefix blocks included, so hit blocks are
+    never recomputed) and masked by position. COLD: the bucket's queries
+    attend over the prompt's own k, v, nothing gathered, where
+    prefill_attention_path says "flash" through the flash forward (no
+    score matrix in HBM, no work on the keys past the bucket); else
+    through the gather as well, with no cond. `arena_constraint` is the
+    mesh plan's layout pin or None, only asked whether there is one. Pad
+    positions (j >= real_len) compute values nobody reads in either form
+    and write to the SCRATCH block unconditionally: with a large hit
+    prefix and a small suffix bucket, pfx_len + bucket can run past
+    max_pages*bs, where a clamped page gather would collide a pad write
+    with a real row — and no real query ever reads a pad row anyway (the
+    causal mask stops at pos <= p_len - 1).
 
     Returns (logits of position pfx_len+real_len-1, (1, V) f32, arena).
     Compiles once per SUFFIX bucket — prefix-cache hits shrink the
@@ -684,7 +613,6 @@ def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
     every projection gathers its A/B rows and adds the low-rank delta
     (id 0 selects the base output bit-exactly), so the prompt's K/V
     rows are computed under the same adapter the decode path serves."""
-    import jax
     import jax.numpy as jnp
 
     heads, hd = cfg.heads, cfg.hidden // cfg.heads
@@ -718,17 +646,14 @@ def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
             K, V = _kv_gather(arena, li, pages, dtype)  # (heads, L, hd)
             scores = jnp.einsum("bnd,nkd->bnk", q, K,
                                 preferred_element_type=jnp.float32)
-            scores = jnp.where(mask[:, None, :], scores / np.sqrt(hd),
-                               -1e30)
-            probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
-            probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
+            probs = _probs(scores, mask[:, None, :], hd, dtype)
             return jnp.einsum("bnk,nkd->bnd", probs, V)
 
         with _stage("attn/attend"):
             if attention == "flash":
                 def cold(arena, q=q, k=k, v=v):
                     return flash_causal_rows(q, k, v, 1.0 / np.sqrt(hd))
-                ctx = jax.lax.cond(pfx_len == 0, cold, warm, arena)
+                ctx = _pages.cold_or_warm(pfx_len, cold, warm, arena)
             else:
                 ctx = warm(arena)
         with _stage("attn/project"):
@@ -741,17 +666,15 @@ def gpt_prefill_pages(params, cfg, tokens, pfx_len, real_len, arena,
 def decode_attention_path(arena, arena_constraint=None):
     """Which attention the paged decode step runs, read off its input:
     "paged_kernel" (ops/paged_attention: each slot's live pages straight
-    out of the arena) when the backend is a TPU, the arena is the bare
-    full-precision array with a lane-aligned K|V row and no mesh
-    constrains it; "gather" (`_kv_gather` + einsum) for everything else:
-    the quantized (int8, scales) arena, the tensor-parallel plan, a
-    backend that is not a TPU. The speculative verify pass
+    out of the arena) where serving/pages.kernel_beside lets a kernel sit
+    beside it; "gather" (`_kv_gather` + einsum) for everything else: the
+    quantized (int8, scales) arena, the tensor-parallel plan, a backend
+    that is not a TPU. The speculative verify pass
     (gpt_decode_verify_pages, several query rows a slot) always
     gathers. One algorithm; the form of the input says whether the
     kernel applies, and no option or environment variable does."""
-    if _kernel_arena(arena, arena_constraint):
-        return "paged_kernel"
-    return "gather"
+    return "paged_kernel" if _pages.kernel_beside(arena, arena_constraint) \
+        else "gather"
 
 
 def gpt_decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
@@ -833,10 +756,7 @@ def gpt_decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
                 K, V = _kv_gather(arena, li, pt, dtype)  # (S, heads, L, hd)
                 scores = jnp.einsum("bnqd,bnkd->bnqk", q, K,
                                     preferred_element_type=jnp.float32)
-                scores = jnp.where(pos_mask[:, None, None, :],
-                                   scores / np.sqrt(hd), -1e30)
-                probs = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
-                probs = (probs / probs.sum(-1, keepdims=True)).astype(dtype)
+                probs = _probs(scores, pos_mask[:, None, None, :], hd, dtype)
                 ctx = jnp.einsum("bnqk,bnkd->bnqd", probs, V)
                 ctx = ctx.transpose(0, 2, 1, 3).reshape(s_dim, 1, -1)
         with _stage("attn/project"):
@@ -943,9 +863,7 @@ class _GPTServingModel(ServingModel):
         return CacheSpec(shape[0], shape[3], shape[5])
 
     def activation_dtype(self, params):
-        import jax.numpy as jnp
-        return jnp.bfloat16 if params["wte"].dtype == jnp.bfloat16 \
-            else jnp.float32
+        return _decoder.act_dtype(params)
 
     def decode_attention_path(self, arena, arena_constraint=None):
         return decode_attention_path(arena, arena_constraint)

@@ -2,10 +2,11 @@
 layer, generated by DIFFUSION OVER BLOCKS.
 
 Pre-norm decoder, RMSNorm, no bias, untied embedding and head. The layer is
-models/mellum's with every layer full, plain rotary positions and a
-per-head RMSNorm of q and k before the rotation (`q_norm`, `k_norm`, (d,)
-each); every layer sparse, `models/moonlight`'s expert layer under softmax
-scoring with the picks' weights over their sum, no shared expert.
+models/_grouped.py's with every layer full (one cache group), plain rotary
+positions and a per-head RMSNorm of q and k before the rotation (`q_norm`,
+`k_norm`, (d,) each); every layer sparse, the shared expert layer
+(models/_experts.py) under softmax scoring with the picks' weights over
+their sum, no shared expert.
 
 What differs from every other served model is HOW IT GENERATES. The sequence
 is cut in blocks of B = `block_length` positions from position 0, and
@@ -34,11 +35,12 @@ module supplies is the block pass:
     pass's, is the final one. The expert layer sees S x B rows, the head all
     of them.
 
-Shared, not copied: models/mellum's config, weights, cache group and path
-verdicts; models/moonlight's `_rms`, `rope`, `_ffn` / `_moe` / `route`,
-`_head`, `_masked_attention`, the counters.
+Shared, not copied: models/_grouped.py's config base, weights, cache group,
+prompt attention (`attend_rows`, the block-causal rule) and path verdicts;
+models/_experts.py's `ffn` and counters; models/_decoder.py's `rms`, `rope`,
+`embed`, `head`; serving/pages.py's page reads and writes.
 
-Parameters: Mellum's, and layers[i]: q_norm, k_norm (d,).
+Parameters: `_grouped.init_params`' tree, and layers[i]: q_norm, k_norm (d,).
 
 Named scopes: `embed`, `norm` (norm1 and the q/k norms), `attn/project`,
 `attn/full`, `moe/*`, `head`. In-graph counters beside the expert layer's:
@@ -61,13 +63,9 @@ from __future__ import annotations
 
 import math
 
-from ..serving.decode_loop import DIFFUSION_COUNTERS
-from .gpt_decode import _gather_pages, _write_pages
-from .mellum import (FULL, MellumConfig, _MellumServingModel,
-                     decode_attention_path, prefill_attention_path)
-from .mellum import init_params as _mellum_params
-from .moonlight import (_act_dtype, _ffn, _head, _masked_attention, _rms,
-                        _zero_counters, rope)
+from ..serving import pages as _pages
+from ..serving.model import BLOCK_DIFFUSION, DIFFUSION_COUNTERS
+from . import _decoder, _experts, _grouped
 
 __all__ = ["SdarConfig", "init_params", "forward_logits", "prefill_pages",
            "block_step_pages", "SDAR_SERVING_MODEL"]
@@ -75,10 +73,13 @@ __all__ = ["SdarConfig", "init_params", "forward_logits", "prefill_pages",
 REMASKING = "low_confidence_dynamic"
 
 
-class SdarConfig(MellumConfig):
+class SdarConfig(_grouped.GroupedConfig):
     """The published keys under this package's names (defaults are
     SDAR-30B-A3B-Chat's `config.json`) and the generation parameters the
     family's loop takes."""
+
+    n_shared_experts = 0
+    router_scoring = "softmax"
 
     def __init__(self, vocab_size=151936, hidden=2048, layers=48, heads=32,
                  kv_heads=4, head_dim=128, moe_intermediate=768,
@@ -92,9 +93,9 @@ class SdarConfig(MellumConfig):
             kv_heads=kv_heads, head_dim=head_dim,
             moe_intermediate=moe_intermediate,
             n_routed_experts=n_routed_experts,
-            experts_per_tok=experts_per_tok, layer_types=(FULL,) * layers,
-            sliding_window=None, rms_eps=rms_eps, rope_theta=rope_theta,
-            rope_scaling=None, max_pos=max_pos, init_range=init_range,
+            experts_per_tok=experts_per_tok,
+            layer_types=(_grouped.FULL,) * layers, rms_eps=rms_eps,
+            rope_theta=rope_theta, max_pos=max_pos, init_range=init_range,
             name=name)
         B, steps = int(block_length), int(denoising_steps)
         if B < 1 or B & (B - 1):
@@ -122,9 +123,10 @@ class SdarConfig(MellumConfig):
 
 
 def init_params(cfg: SdarConfig, key, dtype):
-    """Mellum's seeded weights and a unit `q_norm`, `k_norm` a layer."""
+    """`_grouped.init_params`' seeded weights and a unit `q_norm`, `k_norm`
+    a layer."""
     import jax.numpy as jnp
-    params = _mellum_params(cfg, key, dtype)
+    params = _grouped.init_params(cfg, key, dtype)
     for lp in params["layers"]:
         lp.update(q_norm=jnp.ones((cfg.head_dim,), dtype),
                   k_norm=jnp.ones((cfg.head_dim,), dtype))
@@ -132,12 +134,6 @@ def init_params(cfg: SdarConfig, key, dtype):
 
 
 # -- the layer's pieces ----------------------------------------------------------
-
-def _embed(params, tokens, dtype):
-    import jax
-    with jax.named_scope("embed"):
-        return params["wte"][tokens].astype(dtype)
-
 
 def _project(cfg, lp, x, pos):
     """norm1 and the projections of tokens x (T, h) at positions pos (T,):
@@ -147,33 +143,18 @@ def _project(cfg, lp, x, pos):
     import jax
     T, d = x.shape[0], cfg.head_dim
     with jax.named_scope("norm"):
-        h = _rms(x, lp["norm1"], cfg.rms_eps)
+        h = _decoder.rms(x, lp["norm1"], cfg.rms_eps)
     q = (h @ lp["wq"]).reshape(T, cfg.heads, d)
     k = (h @ lp["wk"]).reshape(T, cfg.kv_heads, d)
     v = (h @ lp["wv"]).reshape(T, cfg.kv_heads, d)
     with jax.named_scope("norm"):
-        q = _rms(q, lp["q_norm"], cfg.rms_eps)
-        k = _rms(k, lp["k_norm"], cfg.rms_eps)
-    q = rope(q, pos[:, None], cfg.rope_theta, None, interleaved=False)
-    k = rope(k, pos[:, None], cfg.rope_theta, None, interleaved=False)
+        q = _decoder.rms(q, lp["q_norm"], cfg.rms_eps)
+        k = _decoder.rms(k, lp["k_norm"], cfg.rms_eps)
+    q = _decoder.rope(q, pos[:, None], cfg.rope_theta, None,
+                      interleaved=False)
+    k = _decoder.rope(k, pos[:, None], cfg.rope_theta, None,
+                      interleaved=False)
     return q, k, v
-
-
-def _attend_rows(cfg, q, k, v, flash, real_len=None):
-    """Block-causal attention of one sequence over its own rows: row i
-    attends j <= i | (B - 1). q (T, heads, d), k, v (T, kv_heads, d) -> (T,
-    heads, d). `real_len`: the rows that are real (a multiple of B), which
-    the flash forward neither visits nor returns past."""
-    import jax.numpy as jnp
-    scale = 1.0 / math.sqrt(cfg.head_dim)
-    B = cfg.block_length
-    if flash:
-        from ..ops.flash_attention import flash_causal_rows
-        return flash_causal_rows(q, k, v, scale, length=real_len, block=B)
-    i = jnp.arange(q.shape[0])
-    mask = i[None, :] <= (i[:, None] | (B - 1))
-    return _masked_attention(q, jnp.repeat(k, cfg.group, 1),
-                             jnp.repeat(v, cfg.group, 1), mask, scale)
 
 
 # -- the whole sequence, no cache (tests; generation never runs it) --------------
@@ -185,16 +166,17 @@ def forward_logits(params, cfg, tokens):
     import jax.numpy as jnp
     T = tokens.shape[0]
     pos = jnp.arange(T)
-    x = _embed(params, tokens, _act_dtype(params))
-    counters = _zero_counters(cfg)
+    x = _decoder.embed(params, tokens, _decoder.act_dtype(params))
+    counters = _experts.zero_counters(cfg)
     live = jnp.ones((T,), bool)
     for lp in params["layers"]:
         q, k, v = _project(cfg, lp, x, pos)
-        o = _attend_rows(cfg, q, k, v, flash=False)
+        o = _grouped.attend_rows(cfg, q, k, v, "full", False,
+                                 block=cfg.block_length)
         x = x + o.reshape(T, -1) @ lp["wo"]
-        y, counters, _ = _ffn(cfg, lp, x, live, counters)
+        y, counters, _ = _experts.ffn(cfg, lp, x, live, counters)
         x = x + y
-    return _head(cfg, params, x)
+    return _decoder.head(cfg, params, x)
 
 
 # -- prefill into the pages --------------------------------------------------------
@@ -215,22 +197,23 @@ def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
     dtype = arena.dtype
     B = cfg.block_length
     whole = real_len // B * B
-    flash = prefill_attention_path(arena, L) == "flash"
+    flash = _grouped.prefill_attention_path(arena, L) == "flash"
     j = jnp.arange(L)
     pos = pfx_len + j
     live = j < whole
-    x = _embed(params, tokens[0], dtype)
-    counters = _zero_counters(cfg)
+    x = _decoder.embed(params, tokens[0], dtype)
+    counters = _experts.zero_counters(cfg)
     for li, lp in enumerate(params["layers"]):
         with jax.named_scope("attn/project"):
             q, k, v = _project(cfg, lp, x, pos)
             kv = jnp.concatenate([k, v], -1).astype(dtype)
-            arena = _write_pages(arena, li, pages, pfx_len, whole, kv)
+            arena = _pages.write_pages(arena, li, pages, pfx_len, whole, kv)
         with jax.named_scope("attn/full"):
-            o = _attend_rows(cfg, q, k, v, flash, whole)
+            o = _grouped.attend_rows(cfg, q, k, v, "full", flash, whole,
+                                     block=B)
         with jax.named_scope("attn/project"):
             x = x + o.reshape(L, -1) @ lp["wo"]
-        y, counters, _ = _ffn(cfg, lp, x, live, counters)
+        y, counters, _ = _experts.ffn(cfg, lp, x, live, counters)
         x = x + y
     return None, arena, counters
 
@@ -254,7 +237,7 @@ def _attend_block(cfg, q, k, v, arena, li, pt, ts, done, path):
     rows_at = (ts % bs)[:, None] + jnp.arange(B)[None, :]
     a = arena.at[li, 0, wblk[:, None], :, rows_at].set(
         jnp.concatenate([k, v], -1).astype(dtype))
-    rows = _gather_pages(a, li, pt)                # (S, kv, P*bs, 2d)
+    rows = _pages.gather_pages(a, li, pt)          # (S, kv, P*bs, 2d)
     keep = jnp.arange(rows.shape[2])[None, :] < (ts + B)[:, None]
     qg = q.reshape(S, B, cfg.kv_heads, cfg.group, d)
     s = jnp.einsum("sbkgd,skld->sbkgl", qg, rows[..., :d],
@@ -281,12 +264,12 @@ def block_step_pages(params, cfg, toks, arena, pt, ts, done=None,
     S, B = toks.shape
     dtype = arena.dtype
     if attention is None:
-        attention = decode_attention_path(arena)["full"]
+        attention = _grouped.decode_attention_path(arena)["full"]
     live = jnp.ones((S,), bool) if done is None else ~done
     rows_live = jnp.repeat(live, B)
     pos = (ts[:, None] + jnp.arange(B)[None, :]).reshape(-1)
-    x = _embed(params, toks.reshape(-1), dtype)
-    counters = _zero_counters(cfg)
+    x = _decoder.embed(params, toks.reshape(-1), dtype)
+    counters = _experts.zero_counters(cfg)
     for li, lp in enumerate(params["layers"]):
         with jax.named_scope("attn/project"):
             q, k, v = _project(cfg, lp, x, pos)
@@ -298,17 +281,20 @@ def block_step_pages(params, cfg, toks, arena, pt, ts, done=None,
                 attention)
         with jax.named_scope("attn/project"):
             x = x + o.reshape(S * B, -1).astype(dtype) @ lp["wo"]
-        y, counters, _ = _ffn(cfg, lp, x, rows_live, counters)
+        y, counters, _ = _experts.ffn(cfg, lp, x, rows_live, counters)
         x = x + y
     counters = dict(counters, decode_rows_full=(
         jnp.sum(jnp.where(live, ts + B, 0)).astype(jnp.int32) * cfg.layers))
-    return _head(cfg, params, x).reshape(S, B, -1), arena, counters
+    return _decoder.head(cfg, params, x).reshape(S, B, -1), arena, counters
 
 
 # -- the engine's view of this model ---------------------------------------------
 
-class _SdarServingModel(_MellumServingModel):
-    features = frozenset({"block_diffusion"})
+class _SdarServingModel(_grouped.GroupedBlockModel):
+    features = frozenset({BLOCK_DIFFUSION})
+    prefill_pages = staticmethod(prefill_pages)
+    # the loop's own ride with the block's, at zero from both programs
+    own_counters = DIFFUSION_COUNTERS
 
     def diffusion(self, cfg):
         return {"block_length": cfg.block_length,
@@ -316,27 +302,6 @@ class _SdarServingModel(_MellumServingModel):
                 "confidence_threshold": cfg.confidence_threshold,
                 "remasking": cfg.remasking,
                 "mask_token_id": cfg.mask_token_id}
-
-    def counter_names(self, cfg):
-        names = dict(super().counter_names(cfg))
-        del names["decode_rows_window"]
-        names.update({name: () for name in DIFFUSION_COUNTERS})
-        return names
-
-    @staticmethod
-    def _named(c, decode):
-        import jax.numpy as jnp
-        out = _MellumServingModel._counters(c, decode)
-        del out["decode_rows_window"]
-        zero = jnp.zeros((), jnp.int32)
-        out.update({name: zero for name in DIFFUSION_COUNTERS})
-        return out
-
-    def prefill(self, params, cfg, tokens, pfx_len, real_len, arena, pages,
-                adapters=None, adapter_id=None):
-        logits, arena, c = prefill_pages(params, cfg, tokens, pfx_len,
-                                         real_len, arena, pages)
-        return logits, arena, self._named(c, decode=False)
 
     def decode_step(self, params, cfg, tokens, arena, pt, ts, done, **_):
         raise NotImplementedError(
@@ -346,8 +311,8 @@ class _SdarServingModel(_MellumServingModel):
     def block_step(self, params, cfg, toks, arena, pt, ts, done):
         logits, arena, c = block_step_pages(
             params, cfg, toks, arena, pt, ts, done,
-            attention=decode_attention_path(arena)["full"])
-        return logits, arena, self._named(c, decode=True)
+            attention=self.decode_attention_path(arena)["full"])
+        return logits, arena, self._counters(cfg, c, decode=True)
 
 
 SDAR_SERVING_MODEL = _SdarServingModel("SDAR-30B-A3B-Chat")

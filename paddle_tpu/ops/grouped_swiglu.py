@@ -17,7 +17,7 @@ start plus the number of earlier (token, pick)s on the same expert, a
 blocked running count over the tokens (a triangular 0/1 matrix product a
 block, exact in float32, and a masked sum over the blocks' totals). The
 caller lays the rows out once and reads the products back by the same
-positions (models/moonlight.py::_moe, `_combine`): by XLA's gather out
+positions (models/_experts.py::moe, `_combine`): by XLA's gather out
 of the plain (R, h) output, or, for a long prompt, by the row DMAs of
 ops/routed_combine out of the PACKED one.
 

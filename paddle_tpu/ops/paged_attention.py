@@ -2,7 +2,7 @@
 over the KV block arena where it lies.
 
 The serving decode step attends ONE new position per slot over that
-slot's cached rows. The XLA form (models/gpt_decode `_gather_pages` +
+slot's cached rows. The XLA form (serving/pages `gather_pages` +
 einsum) assembles every slot's WHOLE page row into a dense
 (S, heads, P*block_size, hd) K and V first and masks afterwards, so a
 step moves the arena's worth of bytes whatever is live. This kernel

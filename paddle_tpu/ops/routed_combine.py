@@ -24,14 +24,14 @@ dead token's picks, a pick of an expert held elsewhere) starts NO DMA
 and adds exactly 0 by a select on the words: an out-of-range DMA would
 fault where XLA's gather clips, and a buffer never written may hold NaN.
 
-THE SUM is `models/moonlight._weighted_sum`'s to the bit: each product as
+THE SUM is `models/_experts._weighted_sum`'s to the bit: each product as
 the expert kernel rounded it, times its float32 weight, added in float32
 in pick order; then the shared experts' term in float32 (times
 `shared_scale`), ONE rounding to the output's type: no float32 (T, h)
 leaves the kernel, and the sum is written where the shared term lay (an
 alias, where their types agree). Asked for float32 and given no shared
 term it hands out the picks' sum itself (a layer that holds a share of
-the experts, under its `lax.cond`: models/moonlight._moe adds the shared
+the experts, under its `lax.cond`: models/_experts.moe adds the shared
 term behind it as it did). A token whose picks are all past the buffer
 gets 0 (+ the shared term): the layout gives a dead token no position,
 so its row needs no mask of its own.

@@ -23,9 +23,11 @@ from __future__ import annotations
 
 from typing import Any, NamedTuple
 
+from .model import DIFFUSION_COUNTERS
+
 __all__ = ["DecodeCarry", "finish_rule", "decode_chunk", "spec_ngram_seed",
-           "SAMPLE_SCOPE", "FINISH_SCOPE", "UNMASK_SCOPE",
-           "DIFFUSION_COUNTERS", "MASKED", "PROMPT", "open_block"]
+           "SAMPLE_SCOPE", "FINISH_SCOPE", "UNMASK_SCOPE", "MASKED", "PROMPT",
+           "open_block"]
 
 # The loop's own stages in a device trace (`jax.named_scope`, metadata
 # only): the sampling call with its key split, and the finish rule with the
@@ -37,12 +39,6 @@ FINISH_SCOPE = "loop/finish"
 # pass fixes (the threshold, else the rank)
 UNMASK_SCOPE = "loop/unmask"
 
-# The diffusion body's in-graph counters, under the names a block-diffusion
-# model lists in its `counter_names`: live slot-passes, blocks committed,
-# and the positions fixed because their confidence cleared the threshold or
-# because they ranked first where too few did.
-DIFFUSION_COUNTERS = ("block_passes", "blocks_committed",
-                      "tokens_fixed_by_threshold", "tokens_fixed_by_rank")
 # `fixed_at` of a block position that is not a pass's number: still the
 # mask token, or a token of the prompt (never emitted)
 MASKED, PROMPT = -2, -1

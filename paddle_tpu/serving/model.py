@@ -53,7 +53,8 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional, Tuple
 
-__all__ = ["FEATURES", "BLOCK_DIFFUSION", "CacheSpec", "GroupLayout", "ServingModel",
+__all__ = ["FEATURES", "BLOCK_DIFFUSION", "DIFFUSION_COUNTERS", "CacheSpec",
+           "GroupLayout", "ServingModel",
            "serving_model", "require_features", "cache_groups",
            "group_columns", "ring_pages"]
 
@@ -67,6 +68,13 @@ FEATURES = ("int8_weights", "int8_kv", "adapters", "speculation", "mesh",
 # are refused or off (the decode carry holds a block; a hit would need a
 # block-causal warm prefill)
 BLOCK_DIFFUSION = "block_diffusion"
+# The diffusion body's in-graph counters (serving/decode_loop.py counts
+# them), under the names such a model lists in its `counter_names`: live
+# slot-passes, blocks committed, and the positions fixed because their
+# confidence cleared the threshold or because they ranked first where too
+# few did.
+DIFFUSION_COUNTERS = ("block_passes", "blocks_committed",
+                      "tokens_fixed_by_threshold", "tokens_fixed_by_rank")
 
 
 class CacheSpec(NamedTuple):

@@ -26,6 +26,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks"))
 from reference import command_a_ref as ref                   # noqa: E402
 
+from paddle_tpu.models import _decoder as dec                # noqa: E402
+from paddle_tpu.models import _experts as ex                 # noqa: E402
+from paddle_tpu.models import _grouped as gr                 # noqa: E402
 from paddle_tpu.models import command_a as ca                # noqa: E402
 from paddle_tpu.models import mellum as mm                   # noqa: E402
 from paddle_tpu.models import moonlight as ml                # noqa: E402
@@ -85,9 +88,9 @@ def reference_logits(params, seq, **kw):
 # -- the config and what the engine is told -------------------------------------
 
 def test_config_kinds_groups_and_the_share():
-    assert CFG.layer_types == (mm.WINDOW,) * 3 + (mm.FULL,)
-    assert CFG.group == 4 and ml.held_experts(CFG) == HELD
-    assert ml.held_experts(WHOLE) == (0, E)
+    assert CFG.layer_types == (gr.WINDOW,) * 3 + (gr.FULL,)
+    assert CFG.group == 4 and ex.held_experts(CFG) == HELD
+    assert ex.held_experts(WHOLE) == (0, E)
     assert WHOLE.vocab_slice == (0, 96, 96)
     model = serving_model(CFG)
     full, window = model.cache_spec(CFG)
@@ -104,7 +107,7 @@ def test_config_kinds_groups_and_the_share():
         ca.CommandAConfig(vocab_slice=(0, 64, 768), **SIZES)
     published = ca.CommandAConfig()
     assert (published.heads, published.kv_heads, published.group) == (128, 8, 16)
-    assert published.layer_types.count(mm.FULL) == 8 and published.layers == 32
+    assert published.layer_types.count(gr.FULL) == 8 and published.layers == 32
 
 
 def test_init_makes_only_the_held_experts(params, whole):
@@ -168,7 +171,7 @@ def test_interleaved_pairs_against_the_references_rotation(params):
     reference turns the interleaved pairs where they lie: the same scores."""
     x = jax.random.normal(jax.random.PRNGKey(2), (5, 3, 16), jnp.float32)
     pos = jnp.asarray([0, 3, 9, 20, 41])
-    mine = ml.rope(x, pos[:, None], 50000.0)
+    mine = dec.rope(x, pos[:, None], 50000.0)
     theirs = ref._rope_interleaved(x, pos, 50000.0)
     back = jnp.concatenate([theirs[..., 0::2], theirs[..., 1::2]], -1)
     assert float(jnp.abs(mine - back).max()) < 1e-5
@@ -192,7 +195,7 @@ def test_the_shares_add_up_to_the_uncut_layer(whole):
         for first in range(0, E, 2):
             cfg = ca.CommandAConfig(experts_held=(first, 2), **SIZES)
             part = share_of(whole, first, 2)["layers"][1]
-            y, c = ml._moe(cfg, part, u, live)
+            y, c = ex.moe(cfg, part, u, live)
             mine, theirs, _ = ref.ffn(u, part, REF_CFG, held=(first, 2))
             assert np.abs(np.asarray(y) - np.asarray(mine + theirs)).max() <= 2e-6
             total += np.asarray(y) - np.asarray(shared)
@@ -200,7 +203,7 @@ def test_the_shares_add_up_to_the_uncut_layer(whole):
         assert held_picks == 37 * 4              # every pick is some chip's
         assert np.abs(total + np.asarray(shared) - uncut).max() <= 5e-6
         # and the uncut layer of the PROGRAM is the same function
-        y, _ = ml._moe(WHOLE, lp, u, live)
+        y, _ = ex.moe(WHOLE, lp, u, live)
         assert np.abs(np.asarray(y) - uncut).max() <= 2e-6
 
 
@@ -208,7 +211,7 @@ def test_the_shared_experts_are_averaged(whole):
     lp = whole["layers"][0]
     u = jax.random.normal(jax.random.PRNGKey(4), (9, 64), jnp.float32)
     _, shared, _ = ref.ffn(u, lp, dict(REF_CFG, num_experts=E), held=(0, E))
-    by_hand = sum(ml._swiglu(u, lp["shared_gate"][:, s], lp["shared_up"][:, s],
+    by_hand = sum(ex.swiglu(u, lp["shared_gate"][:, s], lp["shared_up"][:, s],
                              lp["shared_down"][s])
                   for s in (slice(0, 32), slice(32, 64))) / 2
     assert float(jnp.abs(shared - by_hand).max()) < 1e-6
@@ -217,7 +220,7 @@ def test_the_shared_experts_are_averaged(whole):
 def test_sigmoid_routing_without_bias_or_factor(whole):
     lp = whole["layers"][2]
     u = jax.random.normal(jax.random.PRNGKey(5), (11, 64), jnp.float32)
-    picks, w = ml.route(WHOLE, lp, u)
+    picks, w = ex.route(WHOLE, lp, u)
     scores = jax.nn.sigmoid(jnp.dot(u, lp["router"],
                                     precision=jax.lax.Precision.HIGHEST))
     want_w, want = jax.lax.top_k(scores, 4)
@@ -237,7 +240,7 @@ def _moe_of_pr37(cfg, lp, x, live):
                                                row_tile_for)
     T, k, E = x.shape[0], cfg.experts_per_tok, cfg.n_routed_experts
     tile = row_tile_for(T * k, E)
-    picks, w = ml.route(cfg, lp, x)
+    picks, w = ex.route(cfg, lp, x)
     pos, group_sizes = routed_positions(picks, live, E, tile)
     at = pos.reshape(-1)
     token = jnp.arange(T * k, dtype=jnp.int32) // k
@@ -249,9 +252,9 @@ def _moe_of_pr37(cfg, lp, x, live):
         source = jnp.zeros((rows,), jnp.int32).at[at].set(
             token, mode="drop", unique_indices=True)
         xs = x[source]
-    ys = ml.grouped_experts(lp, xs, group_sizes, tile)
+    ys = ex.grouped_experts(lp, xs, group_sizes, tile)
     if cfg.n_shared_experts:
-        shared = ml._swiglu(x, lp["shared_gate"], lp["shared_up"],
+        shared = ex.swiglu(x, lp["shared_gate"], lp["shared_up"],
                             lp["shared_down"])
     back = ys.at[pos.T.reshape(-1)].get(mode="clip").reshape(k, T, -1)
     y = back[0].astype(jnp.float32) * w[:, 0, None]
@@ -296,7 +299,7 @@ def test_every_expert_held_is_bit_for_bit_what_it_was(name, tokens, dtype):
     lp = jax.tree_util.tree_map(lambda a: a.astype(dtype), params["layers"][-1])
     x = jax.random.normal(jax.random.PRNGKey(tokens), (tokens, 64)).astype(dtype)
     live = jnp.arange(tokens) % 5 != 1
-    got, counters = ml._moe(cfg, lp, x, live)
+    got, counters = ex.moe(cfg, lp, x, live)
     want, sizes = _moe_of_pr37(cfg, lp, x, live)
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(want, np.float32))
@@ -355,12 +358,12 @@ def test_the_second_static_size_and_its_fall_back(whole, monkeypatch, skew):
     u = jax.random.normal(jax.random.PRNGKey(6), (64, 64), jnp.float32)
     u = u.at[:, 0].set(4.0)
     live = jnp.arange(64) != 7
-    monkeypatch.setattr(ml, "HELD_SPLIT_FROM", 1 << 30)
-    one, c_one = ml._moe(cfg, lp, u, live)                     # one buffer
-    monkeypatch.setattr(ml, "HELD_SPLIT_FROM", 16)
-    text = str(jax.make_jaxpr(lambda x: ml._moe(cfg, lp, x, live)[0])(u))
+    monkeypatch.setattr(ex, "HELD_SPLIT_FROM", 1 << 30)
+    one, c_one = ex.moe(cfg, lp, u, live)                     # one buffer
+    monkeypatch.setattr(ex, "HELD_SPLIT_FROM", 16)
+    text = str(jax.make_jaxpr(lambda x: ex.moe(cfg, lp, x, live)[0])(u))
     assert "cond[" in text
-    two, c_two = ml._moe(cfg, lp, u, live)
+    two, c_two = ex.moe(cfg, lp, u, live)
     np.testing.assert_allclose(np.asarray(two), np.asarray(one), atol=1e-6)
     np.testing.assert_array_equal(c_two["expert_tokens"], c_one["expert_tokens"])
     held = int(c_two["expert_tokens"].sum())

@@ -223,7 +223,7 @@ BS, PAGES = 16, 4          # block size and page-row width of the cases
 
 
 def _gather_reference(q, arena, layer, pt, ts):
-    """`_gather_pages` + the einsum path of gpt_decode_step_pages, for
+    """`gather_pages` + the einsum path of gpt_decode_step_pages, for
     one layer: the form the kernel must reproduce."""
     import jax.numpy as jnp
     hd = q.shape[-1]

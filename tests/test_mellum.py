@@ -26,6 +26,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks"))
 from reference import mellum_ref as ref                      # noqa: E402
 
+from paddle_tpu.models import _experts as ex                 # noqa: E402
+from paddle_tpu.models import _grouped as gr                 # noqa: E402
 from paddle_tpu.models import mellum as mm                   # noqa: E402
 from paddle_tpu.models import moonlight as ml                # noqa: E402
 from paddle_tpu.ops.flash_attention import (flash_causal_rows,  # noqa: E402
@@ -79,8 +81,8 @@ def reference_logits(params, seq):
 # -- the config and its cache groups -------------------------------------------
 
 def test_layer_kinds_and_cache_groups():
-    assert CFG.layer_types == (mm.WINDOW,) * 3 + (mm.FULL,) \
-        + (mm.WINDOW,) * 3 + (mm.FULL,)
+    assert CFG.layer_types == (gr.WINDOW,) * 3 + (gr.FULL,) \
+        + (gr.WINDOW,) * 3 + (gr.FULL,)
     assert [CFG.index_in_group(i) for i in range(8)] == [0, 1, 2, 0, 3, 4, 5, 1]
     full, window = serving_model(CFG).cache_spec(CFG)
     assert full == CacheSpec(2, 1, 32, None, "full")
@@ -91,10 +93,10 @@ def test_layer_kinds_and_cache_groups():
     # the published depth: 7 full layers and 21 window layers, 3 : 1
     big = mm.MellumConfig()
     assert (big.heads, big.kv_heads, big.group, big.layers) == (32, 4, 8, 28)
-    assert sum(t == mm.FULL for t in big.layer_types) == 7
+    assert sum(t == gr.FULL for t in big.layer_types) == 7
     assert big.serving_model() is mm.MELLUM_SERVING_MODEL
     with pytest.raises(ValueError, match="window layers alone"):
-        mm.MellumConfig(layers=2, layer_types=[mm.WINDOW] * 2)
+        mm.MellumConfig(layers=2, layer_types=[gr.WINDOW] * 2)
     with pytest.raises(ValueError, match="primary"):
         cache_groups(type("M", (), {"cache_spec": lambda self, cfg: (
             CacheSpec(1, 1, 8, 4, "w"),)})(), None, 16, 4)
@@ -104,7 +106,7 @@ def test_a_config_of_full_layers_alone_is_one_group():
     cfg = mm.MellumConfig(vocab_size=211, hidden=64, layers=2, heads=4,
                           kv_heads=2, head_dim=16, moe_intermediate=32,
                           n_routed_experts=8, experts_per_tok=2,
-                          layer_types=[mm.FULL] * 2, max_pos=64)
+                          layer_types=[gr.FULL] * 2, max_pos=64)
     assert serving_model(cfg).cache_spec(cfg) == CacheSpec(2, 2, 32, None,
                                                            "full")
     kv = SlotKVCache(cfg, 2, 32, jnp.float32, block_size=4)
@@ -148,7 +150,7 @@ def test_the_window_counts_its_own_position(params):
 def test_softmax_routing_against_a_hand_written_top_k(params):
     lp = params["layers"][0]
     x = jax.random.normal(jax.random.PRNGKey(9), (7, CFG.hidden), jnp.float32)
-    picks, w = ml.route(CFG, lp, x)
+    picks, w = ex.route(CFG, lp, x)
     logits = np.asarray(x, np.float64) @ np.asarray(lp["router"], np.float64)
     for t in range(7):
         p = np.exp(logits[t] - logits[t].max())
@@ -168,15 +170,15 @@ def test_the_expert_layer_is_moonlights_without_a_shared_expert(params):
     assert "shared_gate" not in lp and "router_bias" not in lp
     x = jax.random.normal(jax.random.PRNGKey(4), (6, CFG.hidden), jnp.float32)
     live = jnp.asarray([True] * 5 + [False])
-    text = str(jax.make_jaxpr(lambda x: ml._moe(CFG, lp, x, live))(x))
-    y, c = ml._moe(CFG, lp, x, live)
+    text = str(jax.make_jaxpr(lambda x: ex.moe(CFG, lp, x, live))(x))
+    y, c = ex.moe(CFG, lp, x, live)
     assert int(c["router_tokens"]) == 5
     assert int(c["expert_tokens"].sum()) == 5 * CFG.experts_per_tok
-    picks, w = ml.route(CFG, lp, x)
+    picks, w = ex.route(CFG, lp, x)
     by_hand = np.zeros((6, CFG.hidden), np.float32)
     for t in range(5):
         for e, we in zip(np.asarray(picks[t]), np.asarray(w[t])):
-            by_hand[t] += we * np.asarray(ml._swiglu(
+            by_hand[t] += we * np.asarray(ex.swiglu(
                 x[t][None], lp["w_gate"][e], lp["w_up"][e], lp["w_down"][e]))[0]
     np.testing.assert_allclose(np.asarray(y), by_hand, atol=1e-5)
     assert "logistic" in text                        # the experts' silu

@@ -31,6 +31,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu  # noqa: F401
+from paddle_tpu.models import _decoder as dec
+from paddle_tpu.models import _experts as ex
 from paddle_tpu.models import moonlight as ml
 from paddle_tpu.serving import ServingConfig, ServingEngine
 from paddle_tpu.serving.kv_cache import SlotKVCache
@@ -133,7 +135,7 @@ def test_router_matches_reference_and_bias_moves_picks_not_weights(params):
     lp = params["layers"][1]
     x = jnp.asarray(np.random.default_rng(5).normal(0, 1, (64, CFG.hidden)),
                     jnp.float32)
-    picks, w = ml.route(CFG, lp, x)
+    picks, w = ex.route(CFG, lp, x)
     with jax.default_matmul_precision("highest"):
         r_picks, r_w, _ = ref.router(x, lp["router"], lp["router_bias"],
                                      REF_CFG)
@@ -149,7 +151,7 @@ def test_router_matches_reference_and_bias_moves_picks_not_weights(params):
     # a large bias on one expert pulls it into (nearly) every token's
     # picks, and each weight is still the UNBIASED score over the sum
     biased = dict(lp, router_bias=lp["router_bias"].at[3].add(5.0))
-    b_picks, b_w = ml.route(CFG, biased, x)
+    b_picks, b_w = ex.route(CFG, biased, x)
     assert (np.asarray(b_picks) == 3).any(-1).all()
     assert not (np.asarray(picks) == 3).any(-1).all()
     at = np.take_along_axis(scores, np.asarray(b_picks), -1)
@@ -184,7 +186,7 @@ def test_grouped_product_against_expert_loop(params):
     # nobody's rows hold poison: a product's row reads its own row alone
     xs = np.full((rows, CFG.hidden), np.nan, np.float32)
     xs[pos[live]] = np.asarray(x)[live][:, None, :]
-    ys = np.asarray(ml.grouped_experts(lp, jnp.asarray(xs),
+    ys = np.asarray(ex.grouped_experts(lp, jnp.asarray(xs),
                                        jnp.asarray(sizes), tile))
     for t in range(T - 2):
         for j in range(2):
@@ -199,13 +201,13 @@ def test_no_dense_product_and_no_dropped_token(params, monkeypatch):
     a buffer of the static size the layout names."""
     from paddle_tpu.ops.grouped_swiglu import padded_rows, row_tile_for
     seen = []
-    real = ml.grouped_experts
+    real = ex.grouped_experts
 
     def spy(lp, xs, sizes, tile, packed=False):
         seen.append((xs.shape[0], int(np.asarray(sizes).sum()), tile))
         return real(lp, xs, sizes, tile, packed)
 
-    monkeypatch.setattr(ml, "grouped_experts", spy)
+    monkeypatch.setattr(ex, "grouped_experts", spy)
     T = 23
     ml.forward_logits(params, CFG, jnp.asarray(tokens_of(1, T)))
     k, E = CFG.experts_per_tok, CFG.n_routed_experts
@@ -226,11 +228,11 @@ def test_the_expert_kernel_is_the_same_layer_and_is_counted(
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.normal(0, 1, (21, CFG.hidden)), jnp.float32)
     live = jnp.arange(21) < 19
-    assert ml.expert_product_path(lp) == "ragged_dot"       # the CPU
-    want, fallback = ml._moe(CFG, lp, x, live)
-    monkeypatch.setattr(ml, "expert_product_path",
+    assert ex.expert_product_path(lp) == "ragged_dot"       # the CPU
+    want, fallback = ex.moe(CFG, lp, x, live)
+    monkeypatch.setattr(ex, "expert_product_path",
                         lambda lp: "grouped_swiglu_kernel")
-    got, kernel = ml._moe(CFG, lp, x, live)
+    got, kernel = ex.moe(CFG, lp, x, live)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     assert int(kernel["kernel_passes"]) == int(kernel["moe_passes"]) == 1
     assert int(fallback["kernel_passes"]) == 0
@@ -247,7 +249,7 @@ def _moe_by_experts(cfg, lp, x, live):
     """The expert layer one token and one expert at a time, float32, in
     numpy: the picks and weights are `route`'s (the router has its own
     tests), everything after them is recomputed."""
-    picks, w = (np.asarray(a) for a in ml.route(cfg, lp, x))
+    picks, w = (np.asarray(a) for a in ex.route(cfg, lp, x))
     x = np.asarray(x, np.float32)
 
     def swiglu(row, gate, up, down):
@@ -307,12 +309,12 @@ def test_the_expert_layer_against_a_loop_over_experts(
         product, hidden, dtype, atol = "grouped_swiglu_kernel", 256, \
             jnp.bfloat16, 3e-2
         lp = _wide_layer(lp, hidden)
-        monkeypatch.setattr(ml, "COMBINE_KERNEL_FROM", 0)
+        monkeypatch.setattr(ex, "COMBINE_KERNEL_FROM", 0)
     x = jnp.asarray(np.random.default_rng(17).normal(0, 1, (29, hidden)),
                     jnp.float32).astype(dtype)
     live = np.arange(29) < 26
-    monkeypatch.setattr(ml, "expert_product_path", lambda lp: product)
-    got, counters = ml._moe(cfg, lp, x, jnp.asarray(live))
+    monkeypatch.setattr(ex, "expert_product_path", lambda lp: product)
+    got, counters = ex.moe(cfg, lp, x, jnp.asarray(live))
     want = _moe_by_experts(cfg, lp, x, live)
     np.testing.assert_allclose(np.asarray(got, np.float32), want, atol=atol,
                                rtol=2e-2 if by_dma else 1e-7)
@@ -342,7 +344,7 @@ def test_the_expert_layer_sorts_nothing(params):
     lp = params["layers"][1]
     for T in (8, 700):
         names = _primitives(jax.make_jaxpr(
-            lambda x, live: ml._moe(CFG, lp, x, live))(
+            lambda x, live: ex.moe(CFG, lp, x, live))(
                 jnp.zeros((T, CFG.hidden)), jnp.ones((T,), bool)).jaxpr)
         assert not [n for n in names if "sort" in n]
         assert {"gather", "scatter", "ragged_dot_general"} <= names \
@@ -676,14 +678,14 @@ def test_the_tolerance_catches_bfloat16_where_float32_is_stated(
             inv = jax.lax.rsqrt(jnp.mean(x16 * x16, -1, keepdims=True)
                                 + jnp.bfloat16(eps))
             return (x16 * inv).astype(x.dtype) * g
-        monkeypatch.setattr(ml, "_rms", rms16)
+        monkeypatch.setattr(dec, "rms", rms16)
     else:
-        real = ml.route
+        real = ex.route
 
         def route16(cfg, lp, x):
             picks, w = real(cfg, lp, x)
             return picks, w.astype(jnp.bfloat16).astype(jnp.float32)
-        monkeypatch.setattr(ml, "route", route16)
+        monkeypatch.setattr(ex, "route", route16)
     seq = tokens_of(0, 40)
     got = np.asarray(ml.forward_logits(params, CFG, jnp.asarray(seq)))
     want = reference_logits(params, seq)

@@ -1,7 +1,7 @@
 """The combine kernel (ops/routed_combine.py) and the packed rows it reads
 (ops/grouped_swiglu.py, `packed=True`), interpreted on the CPU, against
-XLA's sum `models/moonlight._weighted_sum` bit for bit; and the rule that
-says which of the two a pass takes (`models/moonlight.combine_path`).
+XLA's sum `models/_experts._weighted_sum` bit for bit; and the rule that
+says which of the two a pass takes (`models/_experts.combine_path`).
 
 The weights of the bit-for-bit cases lie on a grid of 1/128: a bfloat16
 product times such a weight is exact in float32, so a fused multiply-add
@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.models import moonlight as ml
+from paddle_tpu.models import _experts as ex
 from paddle_tpu.ops import grouped_swiglu as gs
 from paddle_tpu.ops import routed_combine as rc
 from paddle_tpu.ops.routed_combine import routed_combine
@@ -81,9 +81,9 @@ def test_the_kernel_is_the_weighted_sum_bit_for_bit(k, experts, held, T,
     term = None if shared == "none" else jnp.asarray(
         rng.normal(0, 1, (T, H)), jnp.float32).astype(jnp.bfloat16)
     scale = 0.25 if shared == "average" else None
-    want = ml._combine(clean, pos, jnp.asarray(w), jnp.asarray(live), term,
+    want = ex._combine(clean, pos, jnp.asarray(w), jnp.asarray(live), term,
                        scale, jnp.bfloat16, False)
-    got = ml._combine(jnp.asarray(dirty), pos, jnp.asarray(w),
+    got = ex._combine(jnp.asarray(dirty), pos, jnp.asarray(w),
                       jnp.asarray(live), term, scale, jnp.bfloat16, True)
     assert got.shape == (T, H) and got.dtype == jnp.bfloat16
     np.testing.assert_array_equal(_bits(got), _bits(want))
@@ -98,7 +98,7 @@ def test_a_float32_sum_leaves_the_kernel_unrounded():
     pos, _, rows, _, live, w = _routing(rng, 40, 4, 8, 8, 3)
     y = jnp.asarray(rng.normal(0, 1, (rows, H)), jnp.float32) \
         .astype(jnp.bfloat16)
-    want = ml._weighted_sum(y, pos, jnp.asarray(w), jnp.asarray(live))
+    want = ex._weighted_sum(y, pos, jnp.asarray(w), jnp.asarray(live))
     got = routed_combine(jnp.asarray(_pack(y)), pos, jnp.asarray(w),
                          dtype=jnp.float32)
     np.testing.assert_array_equal(_bits(got), _bits(want))
@@ -186,14 +186,14 @@ def test_the_path_is_chosen_by_the_products_static_size(monkeypatch):
     and 1,024 and every decode step do not."""
     lp = {"w_gate": jnp.zeros((2, 256, 128), jnp.bfloat16)}
     x = jnp.zeros((8, 256), jnp.bfloat16)
-    enough = ml.COMBINE_KERNEL_FROM // (256 * 2)
-    assert ml.combine_path(lp, x, enough) == "gather"             # the CPU
-    monkeypatch.setattr(ml, "expert_product_path",
+    enough = ex.COMBINE_KERNEL_FROM // (256 * 2)
+    assert ex.combine_path(lp, x, enough) == "gather"             # the CPU
+    monkeypatch.setattr(ex, "expert_product_path",
                         lambda lp: "grouped_swiglu_kernel")
-    assert ml.combine_path(lp, x, enough) == "row_dma_kernel"
-    assert ml.combine_path(lp, x, enough - 1) == "gather"
-    assert ml.combine_path(lp, x.astype(jnp.float32), enough) == "gather"
-    assert ml.combine_path(lp, jnp.zeros((8, 384), jnp.bfloat16),
+    assert ex.combine_path(lp, x, enough) == "row_dma_kernel"
+    assert ex.combine_path(lp, x, enough - 1) == "gather"
+    assert ex.combine_path(lp, x.astype(jnp.float32), enough) == "gather"
+    assert ex.combine_path(lp, jnp.zeros((8, 384), jnp.bfloat16),
                            enough) == "gather"
     cells = {  # h, k, router's experts, held, parts of a prompt
         "moonlight": (2048, 6, 64, 64, 1), "xing": (3584, 4, 64, 64, 1),
@@ -202,9 +202,9 @@ def test_the_path_is_chosen_by_the_products_static_size(monkeypatch):
     def path(model, tokens):
         h, k, experts, held, parts = cells[model]
         tile = gs.row_tile_for(tokens * k, experts)
-        slots = tokens * k // (parts if tokens * k >= ml.HELD_SPLIT_FROM
+        slots = tokens * k // (parts if tokens * k >= ex.HELD_SPLIT_FROM
                                else 1)
-        return ml.combine_path(lp, jnp.zeros((tokens, h), jnp.bfloat16),
+        return ex.combine_path(lp, jnp.zeros((tokens, h), jnp.bfloat16),
                                gs.padded_rows(slots, held, tile))
 
     for model in cells:
@@ -251,7 +251,7 @@ def _digest_of_moe(tokens, held):
                 shared=cfg.n_shared_experts)
 
     def moe(x, live):
-        y, counters = ml._moe(cfg, lp, x, live)
+        y, counters = ex.moe(cfg, lp, x, live)
         counters.pop("combine_kernel_passes", None)
         return y, counters
     text = str(jax.make_jaxpr(moe)(jnp.zeros((tokens, 256), jnp.bfloat16),
@@ -262,7 +262,7 @@ def _digest_of_moe(tokens, held):
 @pytest.mark.parametrize("product,tokens,held", list(PARENT_MOE))
 def test_a_step_and_a_short_prompt_trace_the_parents_layer(
         monkeypatch, product, tokens, held):
-    monkeypatch.setattr(ml, "expert_product_path", lambda lp: product)
+    monkeypatch.setattr(ex, "expert_product_path", lambda lp: product)
     assert _digest_of_moe(tokens, held) == PARENT_MOE[product, tokens, held]
 
 
@@ -287,12 +287,12 @@ def test_the_layer_through_the_kernel_is_the_layer_through_the_gather(
     x = jnp.asarray(rng.normal(0, 1, (T, 256)), jnp.float32) \
         .astype(jnp.bfloat16)
     live = jnp.arange(T) < 57
-    monkeypatch.setattr(ml, "HELD_SPLIT_FROM", 64)
-    want, gather = jax.jit(lambda x: ml._moe(cfg, lp, x, live))(x)
-    monkeypatch.setattr(ml, "expert_product_path",
+    monkeypatch.setattr(ex, "HELD_SPLIT_FROM", 64)
+    want, gather = jax.jit(lambda x: ex.moe(cfg, lp, x, live))(x)
+    monkeypatch.setattr(ex, "expert_product_path",
                         lambda lp: "grouped_swiglu_kernel")
-    monkeypatch.setattr(ml, "COMBINE_KERNEL_FROM", 0)
-    got, kernel = jax.jit(lambda x: ml._moe(cfg, lp, x, live))(x)
+    monkeypatch.setattr(ex, "COMBINE_KERNEL_FROM", 0)
+    got, kernel = jax.jit(lambda x: ex.moe(cfg, lp, x, live))(x)
     assert int(kernel["combine_kernel_passes"]) == 1
     assert int(kernel["kernel_passes"]) == 1
     for name in ("expert_tokens", "router_tokens", "experts_touched",

@@ -31,15 +31,15 @@ from reference import sdar_ref as ref                        # noqa: E402
 from paddle_tpu.models import command_a as ca                # noqa: E402
 from paddle_tpu.models import mellum as mm                   # noqa: E402
 from paddle_tpu.models import sdar                           # noqa: E402
-from paddle_tpu.models.moonlight import _masked_attention    # noqa: E402
+from paddle_tpu.models._decoder import masked_attention      # noqa: E402
 from paddle_tpu.ops.flash_attention import flash_causal_rows  # noqa: E402
 from paddle_tpu.ops.paged_attention import paged_attention   # noqa: E402
 from paddle_tpu.serving import (ServingConfig, ServingEngine,  # noqa: E402
                                 SlotKVCache)
-from paddle_tpu.serving.decode_loop import (DIFFUSION_COUNTERS,  # noqa: E402
-                                            MASKED, PROMPT, DecodeCarry,
-                                            open_block)
-from paddle_tpu.serving.model import serving_model           # noqa: E402
+from paddle_tpu.serving.decode_loop import (MASKED, PROMPT,  # noqa: E402
+                                            DecodeCarry, open_block)
+from paddle_tpu.serving.model import (DIFFUSION_COUNTERS,    # noqa: E402
+                                      serving_model)
 
 B, MASK = 4, 210
 CFG = sdar.SdarConfig(vocab_size=211, hidden=64, layers=2, heads=4,
@@ -472,7 +472,7 @@ def test_block_causal_flash_forward_against_the_masked_attention(rows,
             for _ in range(2))
     got = flash_causal_rows(q, k, v, 0.2, length=jnp.int32(length), block=4)
     i = jnp.arange(rows)
-    want = _masked_attention(q, jnp.repeat(k, 2, 1), jnp.repeat(v, 2, 1),
+    want = masked_attention(q, jnp.repeat(k, 2, 1), jnp.repeat(v, 2, 1),
                              i[None, :] <= (i[:, None] | 3), 0.2)
     assert float(jnp.abs(got[:length] - want[:length]).max()) <= 3e-6
     assert float(jnp.abs(got[length:]).max()) == 0.0 if length < rows \

@@ -215,13 +215,13 @@ def test_the_unpaged_pair_names_its_stages(gpt_params):
     assert stages_in(text) == GPT_STAGES
 
 
-def _moonlight():
+def _moonlight(**more):
     from paddle_tpu.models.moonlight import MoonlightConfig, init_params
     cfg = MoonlightConfig(
         vocab_size=211, hidden=64, layers=3, heads=4, kv_lora_rank=32,
         qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
         intermediate=96, moe_intermediate=32, n_routed_experts=8,
-        n_shared_experts=1, experts_per_tok=2, max_pos=64)
+        n_shared_experts=1, experts_per_tok=2, max_pos=64, **more)
     return cfg, init_params(cfg, jax.random.PRNGKey(0), jnp.float32), {}
 
 
@@ -249,16 +249,56 @@ def _command_a():
         {"prefill_buckets": (8, 16, 32)}
 
 
+def _xing():
+    return _moonlight(q_lora_rank=16, hc_mult=4, hc_sinkhorn_iters=6,
+                      name="Xing4.0-29B-A4B")
+
+
+def _sdar():
+    from paddle_tpu.models.sdar import SdarConfig, init_params
+    cfg = SdarConfig(
+        vocab_size=211, hidden=64, layers=2, heads=4, kv_heads=2, head_dim=16,
+        moe_intermediate=32, n_routed_experts=8, experts_per_tok=2,
+        max_pos=64, mask_token_id=210, init_range=0.08)
+    return cfg, init_params(cfg, jax.random.PRNGKey(0), jnp.float32), {}
+
+
+_MOE = {"moe/router", "moe/dispatch", "moe/experts", "moe/combine"}
+_MLA = {"mla/project", "mla/attend", "ffn/dense", "head",
+        "moe/shared"} | _MOE
+# block: (its builder, the stages of its prefill, those its decode chunk
+# has beside them, the families it must not name)
+BLOCK_STAGES = {
+    "moonlight": (_moonlight, _MLA, {"mla/absorb"}, ("attn/", "hc/")),
+    "xing": (_xing, _MLA | {"hc/coeff", "hc/pre", "hc/post"}, {"mla/absorb"},
+             ("attn/",)),
+    "mellum": (_mellum, {"attn/project", "attn/window", "attn/full",
+                         "head"} | _MOE, set(), ("mla/", "ffn/", "hc/")),
+    "command_a": (_command_a, {"embed", "norm", "attn/project", "attn/window",
+                               "attn/full", "moe/shared", "head"} | _MOE,
+                  set(), ("mla/", "ffn/", "hc/")),
+    # the prefill returns no logits: neither the final norm nor the head runs
+    "sdar": (_sdar, {"embed", "norm", "attn/project", "attn/full"} | _MOE,
+             {"head"}, ("mla/", "ffn/", "hc/", "attn/window")),
+}
+
+
 @pytest.mark.parametrize("program", ["chunk", "prefill"])
-def test_command_a_names_every_stage_in_both_programs(program):
-    """The parallel block's eleven stages, the expert layer's through the
-    `lax.cond` of a share's second buffer size where a program has it."""
-    cfg, params, kw = _command_a()
+@pytest.mark.parametrize("block", sorted(BLOCK_STAGES))
+def test_every_block_names_every_stage_in_both_programs(block, program):
+    """Every stage of every served block, read from the compiled programs'
+    `op_name`s as benchmarks/lib/stage_times.py reads a trace: a jaxpr does
+    not show a lost `named_scope`. command-a's expert layer through the
+    `lax.cond` of a share's second buffer size where a program has it;
+    SDAR's `head` in the block step alone."""
+    build, both, decode_only, never = BLOCK_STAGES[block]
+    cfg, params, kw = build()
     found = stages_in(serving_hlo(engine_of(params, cfg, **kw), program))
-    assert {"embed", "norm", "attn/project", "attn/window", "attn/full",
-            "moe/router", "moe/dispatch", "moe/experts", "moe/shared",
-            "moe/combine", "head"} <= found, found
-    assert not {s for s in found if s.startswith(("mla/", "ffn/", "hc/"))}
+    want = both | (decode_only if program == "chunk" else set())
+    assert want <= found, (sorted(want - found), found)
+    assert not {s for s in found if s.startswith(never)}, found
+    if block == "sdar" and program == "prefill":
+        assert "head" not in found
 
 
 @pytest.mark.parametrize("family", ["gpt", "moonlight", "mellum", "command_a"])

@@ -31,6 +31,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu  # noqa: F401
+from paddle_tpu.models import _experts as ex
+from paddle_tpu.models import _decoder as dec
 from paddle_tpu.models import moonlight as ml
 from paddle_tpu.serving import ServingConfig, ServingEngine
 from paddle_tpu.serving.kv_cache import SlotKVCache
@@ -140,8 +142,8 @@ def test_yarn_frequencies_at_the_published_numbers():
     yarn = {"type": "yarn", "factor": 64, "beta_fast": 32, "beta_slow": 1,
             "original_max_position_embeddings": 4096, "mscale": 1,
             "mscale_all_dim": 1}
-    inv, mscale = ml.rope_frequencies(64, 10000.0, yarn)
-    plain, one = ml.rope_frequencies(64, 10000.0)
+    inv, mscale = dec.rope_frequencies(64, 10000.0, yarn)
+    plain, one = dec.rope_frequencies(64, 10000.0)
     inv, plain = np.asarray(inv), np.asarray(plain)
     assert mscale == one == 1.0
     np.testing.assert_allclose(plain, 10000.0 ** (-np.arange(32) / 32),
@@ -163,7 +165,7 @@ def test_yarn_frequencies_at_the_published_numbers():
                               "rope_scaling": yarn}) \
         == pytest.approx(want, rel=1e-12)
     # mscale over mscale_all_dim where they differ
-    _, ratio = ml.rope_frequencies(64, 10000.0, dict(yarn, mscale=0.707))
+    _, ratio = dec.rope_frequencies(64, 10000.0, dict(yarn, mscale=0.707))
     assert ratio == pytest.approx((0.0707 * math.log(64) + 1)
                                   / (0.1 * math.log(64) + 1))
 
@@ -265,14 +267,14 @@ def _as_parent(params, cfg, tokens):
     live = jnp.ones((T,), bool)
     counters = ml._zero_counters(cfg)
     for lp in params["layers"]:
-        h = ml._rms(x, lp["norm1"], cfg.rms_eps)
+        h = dec.rms(x, lp["norm1"], cfg.rms_eps)
         q_nope, q_rope, c, k_rope = ml._project(cfg, lp, h, pos)
         k, v = ml._expand(cfg, lp, c, k_rope)
         q = jnp.concatenate([q_nope, q_rope], -1)
-        o = ml._masked_attention(q, k, v, mask,
+        o = dec.masked_attention(q, k, v, mask,
                                  1.0 / np.sqrt(cfg.qk_head_dim))
         x = x + o.reshape(T, -1) @ lp["wo"]
-        y, counters, _ = ml._ffn(cfg, lp, x, live, counters)
+        y, counters, _ = ex.ffn(cfg, lp, x, live, counters)
         x = x + y
     return ml._head(cfg, params, x)
 

@@ -1,10 +1,10 @@
-"""The expert layer alone: `models/moonlight.py::_moe` stage by stage at
+"""The expert layer alone: `models/_experts.py::moe` stage by stage at
 the four expert cells' widths.
 
 One layer's weights (router, the experts HELD (64 of 64; command-a's 16
 of 128, routed over all), the shared experts where the
 config has them; bfloat16, made on the chip and passed as ARGUMENTS: as
-constants 1 GB of them costs minutes of compile) and `_moe` jitted once a
+constants 1 GB of them costs minutes of compile) and `moe` jitted once a
 row count, traced once a case:
   * every prompt length a cell's traffic file sends, in the bucket the
     engine would take for it (the rows past the length are not live);
@@ -22,7 +22,7 @@ the microseconds of each stage a line gives
     (tokens x picks x h x 2 B; the held share of them where the layer
     holds a share of the experts) over 819 GB/s, over the stage's time;
   * `combine_ns_row`: `combine_us` over the bucket's tokens x picks, and
-    `combine_path`: which carrier the case's combine took (`_moe`'s
+    `combine_path`: which carrier the case's combine took (`moe`'s
     counter `combine_kernel_passes`: "row_dma_kernel", else "gather"; a
     checkout without the counter has the gather alone).
 A chip is required: on any other backend it exits 1 with nothing
@@ -31,8 +31,9 @@ measured.
     chiprun -- python tools/bench_expert_layer.py
     chiprun -- python tools/bench_expert_layer.py --models xing --repo .scratch/parent
 
-`--repo DIR` times `_moe` of another checkout (the parent's, unpacked by
-`git archive`); `--tile N` replaces `row_tile_for`'s choice in the prompt
+`--repo DIR` times the layer of another checkout (the parent's, unpacked
+by `git archive`; one older than PR 43 has it at `models/moonlight.py::_moe`,
+the one fall-back below); `--tile N` replaces `row_tile_for`'s choice in the prompt
 cases for a sweep (nothing but this tool does). Prints one JSON line a
 case and a table a model; the same goes to
 chiprun_out/bench_expert_layer[.<tag>].json.
@@ -130,11 +131,9 @@ def stage_table(trace_dir):
         scope_reduce.metadata_ops(path))["modules"]
 
 
-def bench(name, model, ml, gs):
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
+def config_of(model):
+    """What the layer reads of a config (`models/_experts.py`'s list), of
+    one of MODELS."""
     experts = model.get("experts", 64)
     held = model.get("held", experts)
     cfg = types.SimpleNamespace(
@@ -146,15 +145,43 @@ def bench(name, model, ml, gs):
         # is not told which experts it holds cannot run this case)
         cfg.experts_held = (0, held)
         cfg.shared_expert_combination = model["combination"]
+    return cfg
+
+
+def checkout_layer():
+    """The expert layer of the checkout that is first on `sys.path`."""
+    try:
+        from paddle_tpu.models._experts import moe
+    except ImportError:            # --repo of a checkout before PR 43
+        from paddle_tpu.models.moonlight import _moe as moe
+    return moe
+
+
+def layer_program(name, cfg, moe_layer, tokens):
+    """`moe_layer` (the checkout's `moe`) of one config, jitted under the
+    name its trace is read by."""
+    import jax
+
+    def moe(lp, x, live):
+        return moe_layer(cfg, lp, x, live)
+    moe.__name__ = f"moe_{name}_{tokens}"
+    return jax.jit(moe)
+
+
+def bench(name, model, moe_layer, gs):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    experts = model.get("experts", 64)
+    held = model.get("held", experts)
+    cfg = config_of(model)
     lp = layer(jax, jnp, model)
     h, F, k = model["h"], model["F"], model["k"]
     programs, rows = {}, []
     for case, tokens, length in cases_of(model["traffic"]):
         if tokens not in programs:
-            def moe(lp, x, live):
-                return ml._moe(cfg, lp, x, live)
-            moe.__name__ = f"moe_{name}_{tokens}"
-            programs[tokens] = jax.jit(moe)
+            programs[tokens] = layer_program(name, cfg, moe_layer, tokens)
         program = programs[tokens]
         x = jax.random.normal(jax.random.PRNGKey(tokens + length),
                               (tokens, h), jnp.bfloat16)
@@ -227,7 +254,7 @@ def main():
         print(f"a chip is required; the backend is {jax.default_backend()!r}",
               file=sys.stderr)
         return 1
-    from paddle_tpu.models import moonlight as ml
+    moe = checkout_layer()
     from paddle_tpu.ops import grouped_swiglu as gs
 
     if args.tile:
@@ -238,7 +265,7 @@ def main():
     results = []
     for name in args.models.split(","):
         jax.clear_caches()
-        rows = bench(name, MODELS[name], ml, gs)
+        rows = bench(name, MODELS[name], moe, gs)
         results += rows
         cols = ("case", "tokens", "live", "tile", "rows_computed",
                 *(f"{s}_us" for s in STAGES), "layer_us", "flops_routed",
