@@ -442,6 +442,83 @@ def phase_block_diffusion(hidden=256, heads=8, kv_heads=2, head_dim=128,
 
 
 # ---------------------------------------------------------------------------
+# phase 2d: a recurrent state beside a paged cache, through the kernel paths
+# ---------------------------------------------------------------------------
+
+def phase_state_group(hidden=256, heads=2, head_dim=128, rank=128, rope=128,
+                      width=128, experts=8, picks=2, vocab=512, prompt_len=130,
+                      max_new=18, bucket=256, page=128, force_kernels=False):
+    """One request of a small hybrid model (models/kimi_linear: 1 dense + 4
+    layers with one latent, lane-aligned widths, bfloat16, half of the
+    experts held) through the engine: the flash prefill of the latent layer
+    and the chunked scan of the KDA layers (a prompt of 130 rows in a bucket
+    of 256: the state written is the one AT row 130), then two chunks of
+    recurrent steps through ops/kda_step beside the latent paged kernel,
+    the slot's state a block of a float32 state group. Every served token's
+    logit against its position's largest by the program's own float32
+    forward over the whole sequence (the chunked form, no cache).
+    `force_kernels` (the CPU test): take the kernel paths interpreted."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.models import _latent, kimi_linear
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    cfg = kimi_linear.KimiLinearConfig(
+        vocab_size=vocab, hidden=hidden, layers=5, heads=heads,
+        kv_lora_rank=rank, qk_nope_head_dim=head_dim, qk_rope_head_dim=rope,
+        v_head_dim=head_dim, kda_heads=heads, kda_head_dim=head_dim,
+        intermediate=2 * hidden, moe_intermediate=width,
+        n_routed_experts=experts, experts_per_tok=picks,
+        experts_held=(experts // 2, experts // 2), kda_decay_rank=head_dim,
+        kda_gate_rank=head_dim, max_pos=max(4 * page, 2 * bucket),
+        init_range=0.05, name="kimi-linear-smoke")
+    params = kimi_linear.init_params(cfg, jax.random.PRNGKey(45), jnp.bfloat16)
+    model = cfg.serving_model()
+    forced = (_latent.decode_attention_path, kimi_linear.recurrence_path,
+              type(model).prefill_attention_path)
+    if force_kernels:
+        # the programs' and the verdicts' one source
+        _latent.decode_attention_path = lambda a, c=None: "latent_paged_kernel"
+        kimi_linear.recurrence_path = lambda cfg: "kernel"
+        type(model).prefill_attention_path = lambda self, a, b, c=None: "flash"
+    try:
+        engine = ServingEngine(params, cfg, ServingConfig(
+            num_slots=2, prefill_buckets=(bucket,), max_len=cfg.max_pos,
+            block_size=page, decode_chunk=8))
+        prompt = np.random.default_rng(45).integers(0, vocab, prompt_len)
+        req = engine.submit(prompt, max_new)
+        engine.run_until_drained()
+        stats = engine.stats()
+    finally:
+        (_latent.decode_attention_path, kimi_linear.recurrence_path,
+         type(model).prefill_attention_path) = forced
+    _require(req.state == "finished" and len(req.tokens) == max_new,
+             f"state_group: {len(req.tokens)} of {max_new} tokens served")
+    state = stats["state"]
+    _require(stats["decode_attention"] == {"latent": "latent_paged_kernel"}
+             and state["recurrence_path"] == "kernel",
+             "state_group: a step gathered or the recurrence ran in XLA: "
+             f"{stats['decode_attention']}, {state}")
+    _require(state["peak_blocks_used"] == 2 and state["blocks_used"] == 0,
+             f"state_group: the slot's two state blocks: {state}")
+    _require(stats["kda_state_steps"] == 4 * (max_new - 1)
+             and stats["kda_prefill_rows"] == 4 * prompt_len,
+             f"state_group: {stats['kda_state_steps']} state steps, "
+             f"{stats['kda_prefill_rows']} prefill rows")
+    wide = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params)
+    seq = list(prompt) + list(req.tokens)
+    logits = np.asarray(kimi_linear.forward_logits(
+        wide, cfg, jnp.asarray(seq)))[prompt_len - 1:-1]
+    deficit = logits.max(-1) - logits[np.arange(max_new), np.asarray(req.tokens)]
+    _require(float(deficit.max()) <= LOGIT_MARGIN,
+             f"state_group: a served token lies {float(deficit.max())} under "
+             "its position's best logit")
+    return {"state": state, "kda_state_steps": stats["kda_state_steps"],
+            "max_logit_deficit": float(deficit.max())}
+
+
+# ---------------------------------------------------------------------------
 # phase 3: train
 # ---------------------------------------------------------------------------
 
@@ -841,6 +918,7 @@ def main():
     run("kernels", phase_kernels)
     run("share_kernels", phase_share_kernels)
     run("block_diffusion", phase_block_diffusion)
+    run("state_group", phase_state_group)
     # the published context (tiled kernels), then s=512 (single-pass)
     long_run = run("train_s1024", phase_train, cfg, batch=8, seq=1024)
     run("train_s512", phase_train, cfg, batch=16, seq=512)

@@ -2,11 +2,12 @@
 its in-graph counters, and the half of a serving class that every such
 block repeats.
 
-FIVE CELLS run this file (`moe_time_share` 45-69% of their busy time:
-PERF.md, section 5): moonlight-longctx-offline, xing-longdoc-offline
-(models/moonlight.py), mellum-mixedlen-offline (models/mellum.py),
-command-a-reason-offline (models/command_a.py) and sdar-blockgen-offline
-(models/sdar.py). A change here is a change to all five.
+SIX CELLS run this file (`moe_time_share` 45-69% of their busy time in the
+first five: PERF.md, section 5): moonlight-longctx-offline,
+xing-longdoc-offline (models/moonlight.py), mellum-mixedlen-offline
+(models/mellum.py), command-a-reason-offline (models/command_a.py),
+sdar-blockgen-offline (models/sdar.py) and kimi-linear-longgen-offline
+(models/kimi_linear.py). A change here is a change to all six.
 
 THE LAYER (`moe`). Every token goes to `experts_per_tok` of
 `n_routed_experts` SwiGLU experts (`route`) plus the shared experts, if
@@ -27,7 +28,10 @@ default) or "softmax"; `routed_scaling_factor`, 1.0 (sigmoid scoring
 alone); `shared_expert_combination`, "sum" (the default) or "average" (the
 shared experts, stored as ONE SwiGLU n times as wide, over their count);
 `experts_held`, None (all) or (first, count), the routed experts this chip
-holds; `rms_eps`, `ffn`'s norm alone. Of a layer's parameters `lp`:
+holds (a layer that holds a SHARE reads its products back pick by pick
+and adds a pick of an expert held elsewhere as 0 by a select:
+`_weighted_sum`);
+`rms_eps`, `ffn`'s norm alone. Of a layer's parameters `lp`:
 `router` (h, E) (its presence makes the layer a routed one), `router_bias`
 (E,) float32 where the picks are ranked with a correction bias, `w_gate`,
 `w_up` (held, h, F), `w_down` (held, F, h), with shared experts
@@ -220,38 +224,56 @@ def _lay_out(lp, x, picks, live, groups, tile, slots, average, packed):
     return ys, pos, group_sizes
 
 
-def _weighted_sum(ys, pos, w, live):
+def _weighted_sum(ys, pos, w, live, share=False):
     """XLA's sum of `_combine`: pick by pick, (k, T, h) in
     the weights' type (token-major it would be re-laid for k = 4 and 6),
     then ONE multiply-and-sum over the picks in float32, in pick order;
-    a dead pick's `pos` is past the buffer and reads whatever its last
-    row holds (a dead token's sum is zeroed here, a dead pick of a live
-    token has weight 0: `moe`). Returns (T, h) float32."""
+    a dead token's sum is zeroed here. `share` (static, `moe`'s: the
+    layer holds a share of the experts) says what a dead pick of a live
+    token is. Without it there is none, and the products come back in ONE
+    gather of k T rows. With it a pick of an expert held elsewhere has a
+    `pos` past the buffer, which the clipped gather reads from the
+    buffer's last row: a row of a tile no expert owns, never written,
+    that held NaN on the chip where float32 state had lain (PR 45), and
+    0 x NaN is NaN; so such a pick adds 0 by a SELECT, and the products
+    come back in k gathers of T rows (the TPU's compiler refused the one
+    gather of 1,024 x 8 and of 4,096 x 8 rows of 2,304 values behind a
+    held layer's buffer, 70-106 MB of scoped VMEM: PR 45; the sum is the
+    same to the bit). Returns (T, h) float32."""
     import jax.numpy as jnp
     T, k = pos.shape
-    back = ys.at[pos.T.reshape(-1)].get(mode="clip").reshape(k, T, -1)
-    y = back[0].astype(jnp.float32) * w[:, 0, None]
+    if share:
+        back = [ys.at[pos[:, j]].get(mode="clip") for j in range(k)]
+    else:
+        back = ys.at[pos.T.reshape(-1)].get(mode="clip").reshape(k, T, -1)
+
+    def term(j):
+        t = back[j].astype(jnp.float32) * w[:, j, None]
+        return jnp.where(pos[:, j, None] < ys.shape[0], t, 0) if share else t
+
+    y = term(0)
     for j in range(1, k):
-        y = y + back[j].astype(jnp.float32) * w[:, j, None]
+        y = y + term(j)
     return jnp.where(live[:, None], y, 0)
 
 
-def _combine(ys, pos, w, live, shared, scale, dtype, by_dma):
+def _combine(ys, pos, w, live, shared, scale, dtype, by_dma, share=False):
     """`moe/combine`'s whole sum: every token's routed products times
     their weights, summed in float32 in pick order, a dead token's sum
     0; plus `shared` (T, h) in float32 (None: no shared expert), times
     `scale` where that is not None; ONE rounding to `dtype`. `by_dma`
     (static, `combine_path`'s) says how `_lay_out` left `ys` and who
     reads it: the buffer's rows (R, h), gathered by XLA
-    (`_weighted_sum`), or the kernel's packed rows, fetched by the
-    kernel ops/routed_combine, a DMA a row that is someone's (a dead
-    token has no position), the shared term and the rounding inside
-    it."""
+    (`_weighted_sum`, told whether the layer holds a `share`), or the
+    kernel's packed rows, fetched by the kernel ops/routed_combine, a DMA
+    a row that is someone's (a dead token has no position), the shared
+    term and the rounding inside it."""
     if by_dma:
         from ..ops.routed_combine import routed_combine
         return routed_combine(ys, pos, w, shared,
                               1.0 if scale is None else scale, dtype)
-    return _sum_end(_weighted_sum(ys, pos, w, live), shared, scale, dtype)
+    return _sum_end(_weighted_sum(ys, pos, w, live, share), shared, scale,
+                    dtype)
 
 
 def _sum_end(y, shared, scale, dtype):
@@ -306,7 +328,7 @@ def moe(cfg, lp, x, live):
             # rounding come behind the `lax.cond`, where XLA's sum has them
             with jax.named_scope("moe/combine"):
                 return _combine(ys, pos, w, live, None, None, jnp.float32,
-                                by_dma), sizes
+                                by_dma, held < E), sizes
 
         def in_parts(*whole):
             # the barrier keeps a part's sum out of the fusion that stacks
@@ -344,7 +366,8 @@ def moe(cfg, lp, x, live):
             scale = 1.0 / cfg.n_shared_experts
     with jax.named_scope("moe/combine"):
         if parts == 1:
-            y = _combine(ys, pos, w, live, shared, scale, x.dtype, by_dma)
+            y = _combine(ys, pos, w, live, shared, scale, x.dtype, by_dma,
+                         held < E)
         else:
             y = _sum_end(y, shared, scale, x.dtype)
     passes = jnp.any(live).astype(jnp.int32)

@@ -1,0 +1,792 @@
+"""Kimi-Linear-48B-A3B-Instruct (`model_type: kimi_linear`) on the serving
+path: a HYBRID decoder, three layers of delta-rule LINEAR attention (KDA)
+to every layer of position-free LATENT attention, over sigmoid-routed
+experts of which this chip may hold a SHARE.
+
+Served through serving.model.ServingModel by the same engine, scheduler,
+cache manager and fused chunk loop as every other model. A layer is
+`x += mixer(RMSNorm(x)); x += ffn(RMSNorm(x))`, and its kind is read from
+the two published lists (`kda_layers`, `full_attn_layers`, 1-indexed):
+
+  * a KDA layer keeps NO rows a token. What a slot carries is a FIXED-SIZE
+    STATE: S (heads, key 128, value 128) float32 and the last
+    `short_conv_kernel_size - 1` pre-activation rows of q|k|v (the
+    convolution's history). Both are STATE GROUPS of the one cache manager
+    (serving/model.py `CacheSpec.state`): a slot's state of one layer is
+    one block of the group `state` (float32 beside the bfloat16 latent
+    arena) and its history one block of the group `conv` (the arena's
+    type), each one column of the page table, handed out at admission and
+    taken back at retirement with the pages. Two groups and not one,
+    because the two differ in type and in shape and a block has one of
+    each: the history in the state's block would be 72 KB of bfloat16
+    riding as float32 in two more "heads".
+    The mixer (arXiv:2510.26692, "Kimi Delta Attention"): q|k|v = u Wqkv;
+    a causal depthwise convolution of width 4 and SiLU, a filter a
+    channel; q and k l2-normalised a head (q also times d^-0.5); a decay a
+    head and KEY CHANNEL in log space, `g = -exp(A_log) softplus((u
+    Wa_down) Wa_up + dt_bias)`; `beta = sigmoid(u Wb)`; the recurrence
+        S' = exp(g)[:, None] S;  S = S' + beta outer(k, v - S'^T k);
+        o = S^T q
+    in float32; then `RMSNorm_d(o) sigmoid((u Wg_down) Wg_up)` a head and
+    `Wo`. No positions anywhere.
+    Its two programs: the RECURRENT STEP (`kda_step`: one position a slot,
+    ONE read-modify-write of the slot's state block; a frozen slot's write
+    goes to scratch block 0) and the CHUNKED PREFILL (`kda_chunked`: the
+    same recurrence over a prompt in chunks of `KDA_CHUNK` rows, solved
+    inside a chunk through the cumulative decay and the unit-lower-
+    triangular system of the delta rule, carried between chunks by a
+    `lax.scan`; algebraically the recurrence, to float32 rounding. No
+    positive exponent is ever formed: decays are taken against a point
+    inside the chunk, sub-chunk by sub-chunk of 16 rows, and elementwise
+    inside a sub-chunk. Rows at or past `real_len` get beta = 0 and g = 0,
+    so the state and the history written are those AT `real_len`, not at
+    the bucket's end);
+  * a latent layer is models/_latent.py's (Moonlight's) with `mla_use_nope`:
+    nothing is rotated; the cached row stays 576 values in 640 lanes and
+    the flash forward and the latent paged kernel run as they are. Its
+    group `latent` is the primary one;
+  * layer 1 is a dense SwiGLU, every other layer the shared expert layer
+    (models/_experts.py) with Moonlight's `route` (sigmoid, correction
+    bias, renormalised, times `routed_scaling_factor`), ONE shared expert,
+    and `experts_held = (first, count)`: the routed experts this chip
+    holds (None: all);
+  * `vocab_size` is the rows of the embedding and of the head HELD here;
+    `vocab_slice` (first, rows, of) names them in the published
+    vocabulary.
+
+Parameters (`x @ W`, W is (in, out); no bias): wte (V, h), head (h, V),
+norm_f (h,), layers[i]: norm1, norm2 (h,) and the feed-forward's as
+models/moonlight.py lists them; a KDA layer's wqkv (h, 3 n d), conv_w (4,
+3 n d), wa_down (h, r), wa_up (r, n d), dt_bias (n d,) float32, a_log (n,)
+float32, wb (h, n), wg_down (h, r), wg_up (r, n d), o_norm (d,), wo (n d,
+h); a latent layer's wq, wkva, kv_norm, wkvb, wo.
+
+Named scopes: `embed`, `norm`, `kda/project` (q|k|v, the two low-rank
+pairs, beta), `kda/conv` (convolution, SiLU, l2 norms, the decay's
+softplus), `kda/recur` (the state's read-modify-write and `o`; the chunked
+scan in a prefill), `kda/gate` (the head norm, sigmoid gate, `Wo`),
+`mla/*`, `moe/*`, `ffn/dense`, `head`. In-graph counters beside the expert
+layer's: `kda_state_steps` (live slots x KDA layers a step),
+`kda_prefill_rows` (real rows x KDA layers), `mla_decode_rows` (live
+positions x latent layers a step), and command-a's held-pick counters.
+
+Refused by the engine (`serving.model.require_features`): int8 weights or
+cache, adapters, speculation, a mesh plan, chunked prefill and host swap,
+each with what a state group lacks for it; migration at the call; prefix
+hits are off.
+"""
+
+from __future__ import annotations
+
+from ..serving import pages as _pages
+from ..serving.model import CacheSpec, group_columns
+from . import _decoder, _experts, _latent
+
+__all__ = ["KimiLinearConfig", "init_params", "forward_logits",
+           "prefill_pages", "decode_step_pages", "kda_chunked", "kda_step",
+           "kda_step_inputs", "kda_state_update", "recurrence_path",
+           "KDA_CHUNK", "KIMI_LINEAR_SERVING_MODEL"]
+
+# Rows a chunk of the prefill's scan, and rows a sub-chunk inside which
+# decays are taken elementwise (the published kernels' sizes).
+KDA_CHUNK = 64
+KDA_SUB = 16
+# The chunked form's products are float32 at this precision: it is the
+# recurrence to float32 rounding, and a state that four thousand rows of
+# bfloat16 products built would be a state kept in a lower precision.
+KDA_PRECISION = "highest"
+
+LATENT, STATE, CONV = "latent", "state", "conv"
+
+
+def _published_kinds(layers):
+    """Kimi-Linear's lists at a depth of `layers`: a period is three KDA
+    layers and a latent one, and the LAST layer is latent whatever the
+    period says (27: the published `full_attn_layers` end in 24, 27)."""
+    full = [i for i in range(1, layers + 1) if i % 4 == 0]
+    if layers == 27:
+        full.append(27)
+    return [i for i in range(1, layers + 1) if i not in full], full
+
+
+class KimiLinearConfig:
+    """The published keys under this package's names (defaults are
+    Kimi-Linear-48B-A3B-Instruct's `config.json`, whole: every expert and
+    the whole vocabulary held) and what the published text leaves open
+    (`kda_decay_rank`, `kda_gate_rank`, `l2_eps`, the state's type:
+    benchmarks/configs/kimi-linear-48b-a3b.json `assumed`)."""
+
+    # what models/_latent.py and models/_experts.py read beside the keys
+    q_lora_rank = None
+    rope_scaling = None
+    rope_theta = 10000.0          # never read: `mla_use_nope`
+    mla_use_nope = True
+    router_scoring = "sigmoid"
+
+    def __init__(self, vocab_size=163840, hidden=2304, layers=27, heads=32,
+                 kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+                 v_head_dim=128, kda_heads=32, kda_head_dim=128,
+                 short_conv_kernel_size=4, kda_layers=None,
+                 full_attn_layers=None, intermediate=9216,
+                 moe_intermediate=1024, n_routed_experts=256,
+                 n_shared_experts=1, experts_per_tok=8, first_k_dense=1,
+                 routed_scaling_factor=2.446, rms_eps=1e-5,
+                 experts_held=None, vocab_slice=None, kda_decay_rank=None,
+                 kda_gate_rank=None, l2_eps=1e-6, state_dtype="float32",
+                 max_pos=1048576, init_range=0.02,
+                 name="Kimi-Linear-48B-A3B-Instruct"):
+        if kda_layers is None and full_attn_layers is None:
+            kda_layers, full_attn_layers = _published_kinds(layers)
+        kda_layers = tuple(int(i) for i in kda_layers)
+        full_attn_layers = tuple(int(i) for i in full_attn_layers)
+        if sorted(kda_layers + full_attn_layers) != \
+                list(range(1, layers + 1)):
+            raise ValueError(
+                f"kda_layers {kda_layers} and full_attn_layers "
+                f"{full_attn_layers} do not name each of {layers} layers "
+                "once (1-indexed, as published)")
+        if experts_held is not None:
+            first, count = experts_held
+            if not (0 <= first and 0 < count
+                    and first + count <= n_routed_experts):
+                raise ValueError(f"experts_held {experts_held!r} are not "
+                                 f"experts of {n_routed_experts}")
+            experts_held = (int(first), int(count))
+        if vocab_slice is None:
+            vocab_slice = (0, vocab_size, vocab_size)
+        if vocab_slice[1] != vocab_size \
+                or sum(vocab_slice[:2]) > vocab_slice[2]:
+            raise ValueError(f"vocab_slice {vocab_slice!r} (first, rows, of) "
+                             f"does not name {vocab_size} rows of a "
+                             "vocabulary")
+        self.vocab_size = vocab_size
+        self.hidden = hidden
+        self.layers = layers
+        self.heads = heads
+        self.kv_lora_rank = kv_lora_rank
+        self.qk_nope_head_dim = qk_nope_head_dim
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.v_head_dim = v_head_dim
+        self.kda_heads = kda_heads
+        self.kda_head_dim = kda_head_dim
+        self.short_conv_kernel_size = short_conv_kernel_size
+        self.kda_layers = kda_layers
+        self.full_attn_layers = full_attn_layers
+        self.intermediate = intermediate
+        self.moe_intermediate = moe_intermediate
+        self.n_routed_experts = n_routed_experts
+        self.n_shared_experts = n_shared_experts
+        self.experts_per_tok = experts_per_tok
+        self.first_k_dense = first_k_dense
+        self.routed_scaling_factor = routed_scaling_factor
+        self.rms_eps = rms_eps
+        self.experts_held = experts_held
+        self.vocab_slice = tuple(int(n) for n in vocab_slice)
+        # ASSUMED (not keys of the published config): the two low-rank
+        # pairs' rank (None: the head size), the l2 norm's eps, the
+        # state's type
+        self.kda_decay_rank = kda_decay_rank or kda_head_dim
+        self.kda_gate_rank = kda_gate_rank or kda_head_dim
+        self.l2_eps = l2_eps
+        self.state_dtype = state_dtype
+        self.max_pos = max_pos
+        self.init_range = init_range
+        self.name = name
+
+    @property
+    def qk_head_dim(self):
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def row_values(self):
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def row_width(self):
+        return -(-self.row_values // _pages.LANES) * _pages.LANES
+
+    @property
+    def kda_width(self):
+        """Channels of q, of k and of v: heads x head size."""
+        return self.kda_heads * self.kda_head_dim
+
+    def kind(self, li):
+        """"kda" or "mla": layer li's mixer (li from 0; the published
+        lists count from 1)."""
+        return "kda" if li + 1 in self.kda_layers else "mla"
+
+    def index_in_group(self, li):
+        """Layer li's index among the layers of its kind: its plane of
+        its cache group's arena."""
+        same = self.kda_layers if self.kind(li) == "kda" \
+            else self.full_attn_layers
+        return same.index(li + 1)
+
+    @property
+    def state_shape(self):
+        """A slot's recurrent state of one layer: (heads, key, value)."""
+        return (self.kda_heads, self.kda_head_dim, self.kda_head_dim)
+
+    @property
+    def conv_shape(self):
+        """A slot's convolution history of one layer, the last
+        `short_conv_kernel_size - 1` pre-activation rows of q|k|v, as
+        the block stores it: whole lanes where the values fill them
+        (288 x 128 at the published widths: no row of the block is
+        padding), else the rows as they are."""
+        rows, width = self.short_conv_kernel_size - 1, 3 * self.kda_width
+        if (rows * width) % _pages.LANES == 0:
+            return (1, rows * width // _pages.LANES, _pages.LANES)
+        return (1, rows, width)
+
+    def cache_specs(self):
+        """The three cache groups: the latent rows (primary), the
+        recurrent state and the convolution's history (state groups)."""
+        n_kda = len(self.kda_layers)
+        return (CacheSpec(len(self.full_attn_layers), 1, self.row_width,
+                          name=LATENT),
+                CacheSpec(n_kda, self.kda_heads, self.kda_head_dim,
+                          name=STATE, state=True, dtype=self.state_dtype,
+                          state_shape=self.state_shape),
+                CacheSpec(n_kda, 1, 3 * self.kda_width, name=CONV,
+                          state=True, state_shape=self.conv_shape))
+
+    def serving_model(self):
+        if self.name == KIMI_LINEAR_SERVING_MODEL.name:
+            return KIMI_LINEAR_SERVING_MODEL
+        return _KimiLinearServingModel(self.name)
+
+
+def init_params(cfg: KimiLinearConfig, key, dtype):
+    """Seeded random weights on the default device, one jitted maker a
+    KIND of layer (mixer x feed-forward): normal(0, init_range) matrices,
+    unit norms, a small non-zero router correction bias, the held
+    experts' matrices alone; the convolution's filters uniform in
+    +-k^-0.5 (a filter of normal(0, 0.02) would leave v, which no norm
+    rescales, at nothing); `a_log` so that exp(a_log) is uniform in [1,
+    16] and `dt_bias` the inverse softplus of a log-uniform step in
+    [0.001, 0.1] (the family's usual initialisation: they decide how fast
+    a seeded state forgets)."""
+    import jax
+    import jax.numpy as jnp
+
+    h, n, d = cfg.hidden, cfg.kda_heads, cfg.kda_head_dim
+    C = cfg.kda_width
+    E, F = _experts.held_experts(cfg)[1], cfg.moe_intermediate
+    Fs = cfg.n_shared_experts * F
+    K = cfg.short_conv_kernel_size
+    std = cfg.init_range
+
+    def normal(k, shape):
+        return (std * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
+
+    mixers = {
+        "kda": {"wqkv": (h, 3 * C), "wa_down": (h, cfg.kda_decay_rank),
+                "wa_up": (cfg.kda_decay_rank, C), "wb": (h, n),
+                "wg_down": (h, cfg.kda_gate_rank),
+                "wg_up": (cfg.kda_gate_rank, C), "wo": (C, h)},
+        "mla": {"wq": (h, cfg.heads * cfg.qk_head_dim),
+                "wkva": (h, cfg.row_values),
+                "wkvb": (cfg.kv_lora_rank,
+                         cfg.heads * (cfg.qk_nope_head_dim
+                                      + cfg.v_head_dim)),
+                "wo": (cfg.heads * cfg.v_head_dim, h)}}
+    ffns = {False: {"gate": (h, cfg.intermediate),
+                    "up": (h, cfg.intermediate),
+                    "down": (cfg.intermediate, h)},
+            True: {"router": (h, cfg.n_routed_experts),
+                   "w_gate": (E, h, F), "w_up": (E, h, F),
+                   "w_down": (E, F, h), "shared_gate": (h, Fs),
+                   "shared_up": (h, Fs), "shared_down": (Fs, h)}}
+
+    def layer(kind, routed, k):
+        shapes = dict(mixers[kind], **ffns[routed])
+        ks = jax.random.split(k, len(shapes) + 4)
+        lp = {name: normal(kk, shape)
+              for (name, shape), kk in zip(shapes.items(), ks)}
+        lp.update(norm1=jnp.ones((h,), dtype), norm2=jnp.ones((h,), dtype))
+        if kind == "mla":
+            lp["kv_norm"] = jnp.ones((cfg.kv_lora_rank,), dtype)
+        else:
+            bound = K ** -0.5
+            lp["conv_w"] = jax.random.uniform(
+                ks[-4], (K, 3 * C), jnp.float32, -bound, bound).astype(dtype)
+            lp["a_log"] = jnp.log(jax.random.uniform(
+                ks[-3], (n,), jnp.float32, 1.0, 16.0))
+            dt = jnp.exp(jax.random.uniform(
+                ks[-2], (C,), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+            lp["dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
+            lp["o_norm"] = jnp.ones((d,), dtype)
+        if routed:
+            lp["router_bias"] = 0.01 * jax.random.normal(
+                ks[-1], (cfg.n_routed_experts,), jnp.float32)
+        return lp
+
+    def top(k):
+        k1, k2 = jax.random.split(k)
+        return {"wte": normal(k1, (cfg.vocab_size, h)),
+                "head": normal(k2, (h, cfg.vocab_size)),
+                "norm_f": jnp.ones((h,), dtype)}
+
+    make = {}
+    keys = jax.random.split(key, cfg.layers + 1)
+    params = jax.jit(top)(keys[-1])
+    params["layers"] = []
+    for li in range(cfg.layers):
+        which = (cfg.kind(li), li >= cfg.first_k_dense)
+        if which not in make:
+            make[which] = jax.jit(
+                lambda k, which=which: layer(*which, k))
+        params["layers"].append(make[which](keys[li]))
+    return params
+
+
+# -- the KDA mixer's pieces ------------------------------------------------------
+
+def recurrence_path(cfg):
+    """ "kernel" where the step's read-modify-write of the state is the
+    Pallas kernel ops/kda_step.py (a TPU, the state float32 blocks of
+    whole (128, 128) tiles), "xla" elsewhere (the CPU)."""
+    if _pages.kernel_beside() and cfg.kda_head_dim % _pages.LANES == 0 \
+            and cfg.state_dtype == "float32":
+        return "kernel"
+    return "xla"
+
+
+def _kda_project(cfg, lp, u):
+    """`kda/project`: q|k|v before the convolution (T, 3C), the decay's
+    pre-activation a (T, C), beta's b (T, n) and the gate's z (T, C)."""
+    import jax
+    with jax.named_scope("kda/project"):
+        qkv = u @ lp["wqkv"]
+        a = (u @ lp["wa_down"]) @ lp["wa_up"]
+        b = u @ lp["wb"]
+        z = (u @ lp["wg_down"]) @ lp["wg_up"]
+    return qkv, a, b, z
+
+
+def _kda_activate(cfg, lp, conv, a, b):
+    """The rest of `kda/conv` behind the convolution's sum `conv` (T, 3C)
+    float32: SiLU, the split, the l2 norms, the decay and beta, all
+    float32: q, k, v, g (T, n, d), beta (T, n)."""
+    import jax
+    import jax.numpy as jnp
+    T = conv.shape[0]
+    n, d = cfg.kda_heads, cfg.kda_head_dim
+    act = jax.nn.silu(conv)
+    q, k, v = (part.reshape(T, n, d) for part in jnp.split(act, 3, -1))
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + cfg.l2_eps) \
+        * d ** -0.5
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + cfg.l2_eps)
+    g = -jnp.exp(lp["a_log"])[None, :, None] * jax.nn.softplus(
+        a.astype(jnp.float32) + lp["dt_bias"]).reshape(T, n, d)
+    beta = jax.nn.sigmoid(b.astype(jnp.float32))
+    return q, k, v, g, beta
+
+
+def _kda_gate(cfg, lp, o, z):
+    """`kda/gate`: the head norm of o (T, n, d) float32, the sigmoid
+    gate z (T, C), `Wo`."""
+    import jax
+    import jax.numpy as jnp
+    T = o.shape[0]
+    with jax.named_scope("kda/gate"):
+        y = _decoder.rms(o, lp["o_norm"], cfg.rms_eps)
+        y = y * jax.nn.sigmoid(z.astype(jnp.float32)).reshape(o.shape)
+        return y.reshape(T, -1).astype(z.dtype) @ lp["wo"]
+
+
+def _conv_rows(cfg, lp, padded, T):
+    """The causal depthwise convolution's sum over `padded` (K - 1 + T,
+    3C), the K - 1 rows of history first: (T, 3C) float32."""
+    import jax.numpy as jnp
+    K = cfg.short_conv_kernel_size
+    w = lp["conv_w"].astype(jnp.float32)
+    x = padded.astype(jnp.float32)
+    out = w[0] * x[:T]
+    for i in range(1, K):
+        out = out + w[i] * x[i:i + T]
+    return out
+
+
+def kda_step(S, q, k, v, g, beta):
+    """The recurrence, one position: S (..., dk, dv) float32, q, k, g
+    (..., dk), v (..., dv), beta (...,). Returns (S_t, o_t)."""
+    import jax.numpy as jnp
+    Sd = jnp.exp(g)[..., None] * S
+    u = beta[..., None] * (v - jnp.sum(Sd * k[..., None], -2))
+    S = Sd + k[..., None] * u[..., None, :]
+    return S, jnp.sum(S * q[..., None], -2)
+
+
+def kda_chunked(q, k, v, g, beta, S0=None, chunk=KDA_CHUNK, sub=KDA_SUB):
+    """The recurrence over T positions of one sequence in chunks: q, k, g
+    (T, n, dk) float32, v (T, n, dv), beta (T, n), S0 (n, dk, dv) or None
+    (zeros). Returns (o (T, n, dv) float32, S_T). Algebraically
+    `kda_step` T times. Inside a chunk of C rows with the cumulative
+    decay G_r = sum_{i<=r} g_i: the delta rule's corrections U solve the
+    unit-lower-triangular system (I + diag(beta) A) U = diag(beta) (V -
+    K+ S0), A_ji = sum_c k_j k_i exp(G_j - G_i) for i < j, K+ = k
+    exp(G); then O = Q+ S0 + B U with B_rj = sum_c q_r k_j exp(G_r - G_j)
+    for j <= r, and S_C = exp(G_C) S0 + (k exp(G_C - G))^T U. Every
+    exponent is <= 0: between sub-chunks of `sub` rows the differences
+    are taken against the later sub-chunk's first row (two factors, each
+    at most 1, and a product the MXU does), inside a sub-chunk
+    elementwise. A row with beta = 0 and g = 0 leaves the state as it
+    was."""
+    import jax
+    import jax.numpy as jnp
+    hi = KDA_PRECISION
+    T, n, dk = q.shape
+    dv = v.shape[-1]
+    sub = min(sub, chunk)
+    C = min(chunk, -(-T // sub) * sub)
+    if C % sub:
+        raise ValueError(f"a chunk of {C} rows is not whole sub-chunks of "
+                         f"{sub}")
+    N, ns = -(-T // C), C // sub
+    if N * C != T:
+        pad = ((0, N * C - T), (0, 0), (0, 0))
+        q, k, v, g = (jnp.pad(a, pad) for a in (q, k, v, g))
+        beta = jnp.pad(beta, pad[:2])
+    # (N, n, C, d): a chunk's rows next to the lanes' axis
+    q, k, v, g = (a.reshape(N, C, n, -1).transpose(0, 2, 1, 3)
+                  for a in (q, k, v, g))
+    beta = beta.reshape(N, C, n).transpose(0, 2, 1)
+    G = jnp.cumsum(g, 2)
+    Gs = G.reshape(N, n, ns, sub, dk)
+    ks = k.reshape(Gs.shape)
+    # the rows of both Gram matrices, A's (k) and B's (q), side by side
+    rows = jnp.stack([ks, q.reshape(Gs.shape)])          # (2,N,n,ns,sub,dk)
+    # inside a sub-chunk, elementwise: sum_c r_j k_i exp(G_j - G_i), i <= j
+    # (one reduce; the (sub, sub, dk) terms are never stored)
+    low = jnp.tril(jnp.ones((sub, sub), bool))
+    inside = jnp.exp(jnp.where(
+        low[..., None], Gs[:, :, :, :, None] - Gs[:, :, :, None], -jnp.inf))
+    diag = jnp.sum(rows[..., :, None, :] * ks[:, :, :, None] * inside, -1)
+    # between sub-chunks, against the LATER one's first row: its own rows
+    # decayed from there, the earlier ones' keys decayed up to there
+    own = rows * jnp.exp(Gs - Gs[:, :, :, :1])
+    blocks = []
+    for rb in range(ns):
+        parts = []
+        if rb:
+            back = ks[:, :, :rb] * jnp.exp(Gs[:, :, rb, None, :1]
+                                           - Gs[:, :, :rb])
+            parts.append(jnp.einsum(
+                "xbhjc,bhic->xbhji", own[:, :, :, rb],
+                back.reshape(N, n, rb * sub, dk), precision=hi))
+        parts.append(diag[:, :, :, rb])
+        if rb < ns - 1:
+            parts.append(jnp.zeros(diag.shape[:3]
+                                   + (sub, (ns - 1 - rb) * sub), diag.dtype))
+        blocks.append(jnp.concatenate(parts, -1))
+    grams = jnp.concatenate(blocks, -2)                   # (2, N, n, C, C)
+    A, B = jnp.tril(grams[0], -1), grams[1]
+    k_plus = k * jnp.exp(G)
+    q_plus = q * jnp.exp(G)
+    k_end = k * jnp.exp(G[:, :, -1:] - G)
+    L = jnp.eye(C, dtype=A.dtype) + beta[..., None] * A
+    rhs = beta[..., None] * jnp.concatenate([v, k_plus], -1)
+    solved = jax.lax.linalg.triangular_solve(
+        L, rhs, left_side=True, lower=True, unit_diagonal=True)
+    u_v, w = solved[..., :dv], solved[..., dv:]
+    decay_end = jnp.exp(G[:, :, -1])                          # (N, n, dk)
+
+    def carry(S, c):
+        u_v, w, q_plus, B, k_end, decay_end = c
+        U = u_v - jnp.einsum("hck,hkv->hcv", w, S, precision=hi)
+        o = jnp.einsum("hck,hkv->hcv", q_plus, S, precision=hi) \
+            + jnp.einsum("hrj,hjv->hrv", B, U, precision=hi)
+        S = decay_end[..., None] * S \
+            + jnp.einsum("hck,hcv->hkv", k_end, U, precision=hi)
+        return S, o
+
+    if S0 is None:
+        S0 = jnp.zeros((n, dk, dv), jnp.float32)
+    S, o = jax.lax.scan(carry, S0, (u_v, w, q_plus, B, k_end, decay_end))
+    return o.transpose(0, 2, 1, 3).reshape(N * C, n, dv)[:T], S
+
+
+def _kda_prompt(cfg, lp, u, real_len):
+    """A KDA layer's mixer over ONE sequence's rows u (B, h), `real_len`
+    of them real, from a zero state: (the mixer's output (B, h), the
+    state S at `real_len` (n, d, d) float32, the history at `real_len`
+    (K - 1, 3C))."""
+    import jax
+    import jax.numpy as jnp
+    B = u.shape[0]
+    K = cfg.short_conv_kernel_size
+    qkv, a, b, z = _kda_project(cfg, lp, u)
+    with jax.named_scope("kda/conv"):
+        padded = jnp.pad(qkv, ((K - 1, 0), (0, 0)))
+        # rows real_len - (K - 1) .. real_len - 1, zeros before row 0
+        hist = jax.lax.dynamic_slice_in_dim(padded, real_len, K - 1, 0)
+        q, k, v, g, beta = _kda_activate(
+            cfg, lp, _conv_rows(cfg, lp, padded, B), a, b)
+        live = jnp.arange(B) < real_len
+        g = jnp.where(live[:, None, None], g, 0.0)
+        beta = jnp.where(live[:, None], beta, 0.0)
+    with jax.named_scope("kda/recur"):
+        o, S = kda_chunked(q, k, v, g, beta)
+    return _kda_gate(cfg, lp, o, z), S, hist
+
+
+def _state_block(ids, done):
+    """Where a slot's state block is written: its own, or scratch block 0
+    for a frozen slot."""
+    import jax.numpy as jnp
+    return ids if done is None else jnp.where(done, 0, ids)
+
+
+def kda_step_inputs(cfg, lp, u, arenas, lg, conv_ids, done):
+    """`kda/project` and `kda/conv` of a step: for every slot's row u (S,
+    h) the recurrence's operands q, k, v, g (S, n, d), beta (S, n), all
+    float32, and the gate's z (S, C), the convolution taken over the
+    slot's history, block `conv_ids` (S,) of layer `lg` of its arena, which
+    moves one row on (a frozen slot's to scratch). Returns (q, k, v, g,
+    beta, z, arenas)."""
+    import jax
+    import jax.numpy as jnp
+    s_dim = u.shape[0]
+    K = cfg.short_conv_kernel_size
+    qkv, a, b, z = _kda_project(cfg, lp, u)
+    with jax.named_scope("kda/conv"):
+        conv = arenas[CONV]
+        hist = conv[lg, 0, conv_ids].reshape(s_dim, K - 1, -1)
+        window = jnp.concatenate([hist, qkv[:, None].astype(hist.dtype)], 1)
+        w = lp["conv_w"].astype(jnp.float32)
+        summed = jnp.sum(window.astype(jnp.float32) * w[None], 1)
+        arenas[CONV] = conv.at[lg, 0, _state_block(conv_ids, done)].set(
+            window[:, 1:].reshape((s_dim,) + conv.shape[3:]))
+        q, k, v, g, beta = _kda_activate(cfg, lp, summed, a, b)
+    return q, k, v, g, beta, z, arenas
+
+
+def kda_state_update(arenas, lg, state_ids, done, q, k, v, g, beta, path):
+    """`kda/recur` of a step: every slot's state, block `state_ids` (S,)
+    of layer `lg` of its arena, read, moved one position on and written
+    ONCE (a frozen slot's to scratch), by the kernel ops/kda_step.py or by
+    XLA's gather-update-scatter (`path`: `recurrence_path`). Returns (o
+    (S, n, d) float32, arenas). The served step runs THIS; so does the
+    numeric check of the cell `kimi-linear-longgen-offline`, on the
+    engine's own blocks (benchmarks/modes/serve-closed-kimi-linear.py)."""
+    import jax
+    import jax.numpy as jnp
+    with jax.named_scope("kda/recur"):
+        state = arenas[STATE]
+        if path == "kernel":
+            from ..ops.kda_step import kda_step_blocks
+            o, arenas[STATE] = kda_step_blocks(
+                state, lg, state_ids, done, q, k, v, g, beta)
+        else:
+            S, o = kda_step(state[lg, 0, state_ids].astype(jnp.float32),
+                            q, k, v, g, beta)
+            arenas[STATE] = state.at[
+                lg, 0, _state_block(state_ids, done)].set(
+                    S.astype(state.dtype))
+    return o, arenas
+
+
+def _kda_decode(cfg, lp, u, arenas, lg, state_ids, conv_ids, done, path):
+    """A KDA layer's mixer one position on for every slot: u (S, h); the
+    slot's history and state are blocks `conv_ids`, `state_ids` (S,) of
+    layer `lg` of their arenas. Returns (the mixer's output (S, h),
+    arenas)."""
+    q, k, v, g, beta, z, arenas = kda_step_inputs(cfg, lp, u, arenas, lg,
+                                                  conv_ids, done)
+    o, arenas = kda_state_update(arenas, lg, state_ids, done, q, k, v, g,
+                                 beta, path)
+    return _kda_gate(cfg, lp, o, z), arenas
+
+
+def _zero_counters(cfg):
+    import jax.numpy as jnp
+    zero = jnp.zeros((), jnp.int32)
+    return dict(_experts.zero_counters(cfg), kda_state_steps=zero,
+                kda_prefill_rows=zero, mla_decode_rows=zero)
+
+
+def _arenas(arena):
+    return dict(zip((LATENT, STATE, CONV), arena))
+
+
+def _arena_out(arenas):
+    return (arenas[LATENT], arenas[STATE], arenas[CONV])
+
+
+# -- the whole sequence, no cache (tests; generation never runs it) --------------
+
+def forward_logits(params, cfg, tokens):
+    """Logits (T, V) float32 of one sequence tokens (T,): the served math
+    without a cache (the chunked KDA, the expanded latent attention)."""
+    import jax.numpy as jnp
+    T = tokens.shape[0]
+    pos = jnp.arange(T)
+    x = _decoder.embed(params, tokens, _decoder.act_dtype(params))
+    mask = pos[None, :] <= pos[:, None]
+    scale = _latent.attention_scale(cfg)
+    counters = _zero_counters(cfg)
+    live = jnp.ones((T,), bool)
+    for li, lp in enumerate(params["layers"]):
+        u = _decoder.rms(x, lp["norm1"], cfg.rms_eps)
+        if cfg.kind(li) == "kda":
+            y, _, _ = _kda_prompt(cfg, lp, u, T)
+        else:
+            q_nope, q_rope, c, k_rope = _latent.project(cfg, lp, u, pos)
+            k, v = _latent.expand(cfg, lp, c, k_rope)
+            q = jnp.concatenate([q_nope, q_rope], -1)
+            o = _decoder.masked_attention(q, k, v, mask, scale)
+            y = o.reshape(T, -1) @ lp["wo"]
+        x = x + y
+        y, counters, _ = _experts.ffn(cfg, lp, x, live, counters)
+        x = x + y
+    return _decoder.head(cfg, params, x)
+
+
+# -- prefill into the pages and the state blocks ---------------------------------
+
+def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
+    """Prefill ONE sequence's COLD prompt tokens (1, B) (`pfx_len` is 0: a
+    model with state groups takes no prefix hits): the latent layers' rows
+    as whole pages of the latent group's columns, each KDA layer's state
+    and history AT `real_len` into the slot's blocks of the state groups
+    (written whole, never read). Returns (logits (1, V) float32 of
+    position real_len - 1, arena, counters)."""
+    import jax
+    import jax.numpy as jnp
+
+    arenas = _arenas(arena)
+    latent = arenas[LATENT]
+    B = tokens.shape[1]
+    bs = latent.shape[4]
+    dtype = latent.dtype
+    cols = group_columns(cfg.cache_specs(), pages.shape[0], bs)
+    rows = pages[cols[0]]
+    state_id, conv_id = pages[cols[1]][0], pages[cols[2]][0]
+    flash = _pages.kernel_beside(bucket=B)
+    j = jnp.arange(B)
+    pos = pfx_len + j
+    live = j < real_len
+    x = _decoder.embed(params, tokens[0], dtype)
+    counters = _zero_counters(cfg)
+    for li, lp in enumerate(params["layers"]):
+        lg = cfg.index_in_group(li)
+        if cfg.kind(li) == "kda":
+            with jax.named_scope("norm"):
+                u = _decoder.rms(x, lp["norm1"], cfg.rms_eps)
+            y, S, hist = _kda_prompt(cfg, lp, u, real_len)
+            with jax.named_scope("kda/recur"):
+                arenas[STATE] = arenas[STATE].at[lg, 0, state_id].set(
+                    S.astype(arenas[STATE].dtype))
+            with jax.named_scope("kda/conv"):
+                arenas[CONV] = arenas[CONV].at[lg, 0, conv_id].set(
+                    hist.reshape(arenas[CONV].shape[3:]))
+        else:
+            y, arenas[LATENT] = _latent.prefill_attend(
+                cfg, lp, x, j, pos, arenas[LATENT], lg, rows, pfx_len,
+                real_len, flash, cold_only=True)
+        x = x + y
+        y, counters, _ = _experts.ffn(cfg, lp, x, live, counters)
+        x = x + y
+    counters["kda_prefill_rows"] = (real_len * len(cfg.kda_layers)
+                                    ).astype(jnp.int32)
+    last = x[real_len - 1][None]
+    return _decoder.head(cfg, params, last), _arena_out(arenas), counters
+
+
+# -- decode through the pages and the state blocks --------------------------------
+
+def decode_attention_path(arena, arena_constraint=None):
+    """{group: path} of the latent group: models/_latent.py's verdict on
+    ITS arena (the state groups attend nothing)."""
+    return {LATENT: _latent.decode_attention_path(arena[0],
+                                                  arena_constraint)}
+
+
+def decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
+                      attention=None, recurrence=None):
+    """One decode step of every slot: tokens, ts (S,), pt (S, P + 2). A
+    latent layer writes each live slot's row at ts and attends over 0..ts
+    (absorbed); a KDA layer reads, updates and writes each live slot's
+    history and state block ONCE. A frozen slot's writes reach scratch
+    block 0 alone. Returns (logits (S, V) float32, arena, counters)."""
+    import jax
+    import jax.numpy as jnp
+
+    arenas = _arenas(arena)
+    s_dim = pt.shape[0]
+    bs = arenas[LATENT].shape[4]
+    dtype = arenas[LATENT].dtype
+    cols = group_columns(cfg.cache_specs(), pt.shape[1], bs)
+    table = pt[:, cols[0]]
+    state_ids, conv_ids = pt[:, cols[1]][:, 0], pt[:, cols[2]][:, 0]
+    if attention is None:
+        attention = decode_attention_path(arena)
+    if recurrence is None:
+        recurrence = recurrence_path(cfg)
+    live = jnp.ones((s_dim,), bool) if done is None else ~done
+    x = _decoder.embed(params, tokens, dtype)
+    counters = _zero_counters(cfg)
+    for li, lp in enumerate(params["layers"]):
+        lg = cfg.index_in_group(li)
+        if cfg.kind(li) == "kda":
+            with jax.named_scope("norm"):
+                u = _decoder.rms(x, lp["norm1"], cfg.rms_eps)
+            y, arenas = _kda_decode(cfg, lp, u, arenas, lg, state_ids,
+                                    conv_ids, done, recurrence)
+        else:
+            y, arenas[LATENT] = _latent.step_attend(
+                cfg, lp, x, ts, arenas[LATENT], lg, table, done,
+                attention[LATENT])
+        x = x + y.astype(dtype)
+        y, counters, _ = _experts.ffn(cfg, lp, x, live, counters)
+        x = x + y
+    n_live = jnp.sum(live).astype(jnp.int32)
+    counters["kda_state_steps"] = n_live * len(cfg.kda_layers)
+    counters["mla_decode_rows"] = (
+        jnp.sum(jnp.where(live, ts + 1, 0)).astype(jnp.int32)
+        * len(cfg.full_attn_layers))
+    return _decoder.head(cfg, params, x), _arena_out(arenas), counters
+
+
+# -- the engine's view of this model ---------------------------------------------
+
+class _KimiLinearServingModel(_experts.ExpertBlockModel):
+    prefill_pages = staticmethod(prefill_pages)
+    decode_step_pages = staticmethod(decode_step_pages)
+
+    own_counters = ("kda_state_steps", "kda_prefill_rows", "mla_decode_rows",
+                    "moe_picks_routed", "moe_picks_held",
+                    "decode_moe_picks_routed", "decode_moe_picks_held")
+
+    def cache_spec(self, cfg):
+        return cfg.cache_specs()
+
+    def decode_attention_path(self, arena, arena_constraint=None):
+        return decode_attention_path(arena, arena_constraint)
+
+    def prefill_attention_path(self, arena, bucket, arena_constraint=None):
+        return "flash" if _pages.kernel_beside(bucket=bucket) else "gather"
+
+    def describe(self, cfg):
+        first, count = _experts.held_experts(cfg)
+        return {"experts_held": {"first": first, "count": count,
+                                 "of": cfg.n_routed_experts},
+                "vocab_slice": dict(zip(("first", "rows", "of"),
+                                        cfg.vocab_slice)),
+                "state": {"recurrence_path": recurrence_path(cfg),
+                          "prefill_chunk_rows": KDA_CHUNK}}
+
+    def _counters(self, cfg, c, decode):
+        import jax.numpy as jnp
+        routed = c["router_tokens"] * cfg.experts_per_tok
+        held = jnp.sum(c["expert_tokens"]).astype(jnp.int32)
+        return super()._counters(cfg, dict(
+            c, moe_picks_routed=routed, moe_picks_held=held,
+            decode_moe_picks_routed=routed, decode_moe_picks_held=held),
+            decode)
+
+
+KIMI_LINEAR_SERVING_MODEL = _KimiLinearServingModel(
+    "Kimi-Linear-48B-A3B-Instruct")

@@ -26,16 +26,24 @@ and fused chunk loop (serving/decode_loop.py) as the GPT family:
     normalised and scaled) plus one shared SwiGLU: the shared expert layer,
     models/_experts.py, which this file configures and does not contain.
 
-This file is the latent attention, the residual mixer and the block's
-programs. Shared, imported and not copied: `rms`, `rope` (YaRN),
-`masked_attention`, `head` from models/_decoder.py; `ffn`, the counters
-and the serving class's half from models/_experts.py; the page reads and
-writes, the kernel rule and the cold-or-warm switch from serving/pages.py.
+This file is the residual mixer and the block's programs. Shared,
+imported and not copied: the latent attention itself (projections, the
+cache row, the expanded prefill and the absorbed step, its path verdict)
+from models/_latent.py, which a hybrid model's latent layers run too;
+`rms`, `rope` (YaRN), `masked_attention`, `head` from models/_decoder.py;
+`ffn`, the counters and the serving class's half from models/_experts.py;
+the page reads and writes, the kernel rule and the cold-or-warm switch
+from serving/pages.py.
 
 What a config may change in the block (defaults are Moonlight's, whose
 program they leave as it was, to the bit):
   * `q_lora_rank`: the query through a low-rank pair with a norm
     between, `q = RMSNorm(h W_qa; q_norm) W_qb`;
+  * `mla_use_nope` (the published key of a model whose latent layers
+    carry no positions; False here): the rotation is an argument of the
+    latent attention, `_latent.project`: on, the 64 "rope" values of q
+    and k are rotated; off, they are projected, cached and scored as
+    they are;
   * `rope_scaling`: YaRN's dict (`rope_frequencies`: each rotary
     frequency blended between itself and itself over `factor`; the
     softmax scale times mscale^2, `attention_scale`);
@@ -76,16 +84,13 @@ rope scaling that is not YaRN.
 
 from __future__ import annotations
 
-import math
-
 from ..serving import pages as _pages
 from ..serving.model import CacheSpec
-from . import _decoder, _experts
+from . import _decoder, _experts, _latent
 
 __all__ = ["MoonlightConfig", "init_params", "forward_logits",
-           "prefill_pages", "decode_step_pages",
-           "decode_attention_path", "absorbed_attention",
-           "attention_scale", "hc_coefficients", "MOONLIGHT_SERVING_MODEL"]
+           "prefill_pages", "decode_step_pages", "hc_coefficients",
+           "MOONLIGHT_SERVING_MODEL"]
 
 
 class MoonlightConfig:
@@ -102,7 +107,7 @@ class MoonlightConfig:
                  rope_theta=50000.0, max_pos=8192, init_range=0.02,
                  q_lora_rank=None, rope_scaling=None, hc_mult=1,
                  hc_sinkhorn_iters=20, hc_eps=1e-6, hc_res_clamp=30.0,
-                 name="Moonlight-16B-A3B"):
+                 mla_use_nope=False, name="Moonlight-16B-A3B"):
         self.vocab_size = vocab_size
         self.hidden = hidden
         self.layers = layers
@@ -131,6 +136,8 @@ class MoonlightConfig:
             raise ValueError("rope_scaling is None or a YaRN dict, not "
                              f"{rope_scaling!r}")
         self.rope_scaling = rope_scaling
+        # True: the latent attention carries no positions (nothing rotated)
+        self.mla_use_nope = bool(mla_use_nope)
         # residual streams: 1 is `x + f(norm(x))`; n > 1 the
         # manifold-constrained hyper-connections over n streams
         if hc_mult < 1:
@@ -250,17 +257,6 @@ def init_params(cfg: MoonlightConfig, key, dtype):
 
 
 # -- the block's pieces -------------------------------------------------------
-
-def attention_scale(cfg):
-    """1 / sqrt(nope + rope), times YaRN's mscale(factor,
-    mscale_all_dim) squared where the positions are stretched."""
-    scale = 1.0 / math.sqrt(cfg.qk_head_dim)
-    sc = cfg.rope_scaling
-    if sc is not None and sc.get("mscale_all_dim", 0):
-        scale *= _decoder.yarn_mscale(sc["factor"],
-                                      sc["mscale_all_dim"]) ** 2
-    return scale
-
 
 # -- the residual path ---------------------------------------------------------
 
@@ -413,59 +409,6 @@ def _head(cfg, params, x):
     return _decoder.head(cfg, params, x)
 
 
-def _project(cfg, lp, x, pos):
-    """The attention's projections of tokens x (T, h) at positions pos
-    (T,): q_nope (T, n, nope), q_rope (T, n, rope) rotated, and the cache
-    row's two parts, c (T, rank) normed and k_rope (T, rope) rotated.
-    The query is one matrix, or (`q_lora_rank`) a low-rank pair with a
-    norm between."""
-    T = x.shape[0]
-    n, nope = cfg.heads, cfg.qk_nope_head_dim
-    theta, scaling = cfg.rope_theta, cfg.rope_scaling
-    if cfg.q_lora_rank is None:
-        q = x @ lp["wq"]
-    else:
-        q = _decoder.rms(x @ lp["wqa"], lp["q_norm"],
-                         cfg.rms_eps) @ lp["wqb"]
-    q = q.reshape(T, n, cfg.qk_head_dim)
-    q_nope = q[..., :nope]
-    q_rope = _decoder.rope(q[..., nope:], pos[:, None], theta, scaling)
-    kva = x @ lp["wkva"]
-    c = _decoder.rms(kva[:, :cfg.kv_lora_rank], lp["kv_norm"], cfg.rms_eps)
-    k_rope = _decoder.rope(kva[:, cfg.kv_lora_rank:], pos, theta, scaling)
-    return q_nope, q_rope, c, k_rope
-
-
-def _cache_rows(cfg, c, k_rope):
-    """[c | k_rope | 0] (T, row_width): the row as the arena stores it."""
-    import jax.numpy as jnp
-    pad = cfg.row_width - cfg.row_values
-    parts = [c, k_rope]
-    if pad:
-        parts.append(jnp.zeros((c.shape[0], pad), c.dtype))
-    return jnp.concatenate(parts, -1)
-
-
-def _wkvb_heads(cfg, lp):
-    """(W_UK, W_UV): (rank, n, nope) and (rank, n, v), the key and the
-    value half of W_kvb by head."""
-    w = lp["wkvb"].reshape(cfg.kv_lora_rank, cfg.heads,
-                           cfg.qk_nope_head_dim + cfg.v_head_dim)
-    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
-
-
-def _expand(cfg, lp, c, k_rope):
-    """Keys (T, n, nope + rope) and values (T, n, v) from cache rows."""
-    import jax.numpy as jnp
-    w_uk, w_uv = _wkvb_heads(cfg, lp)
-    k_nope = jnp.einsum("tc,cnd->tnd", c, w_uk)
-    v = jnp.einsum("tc,cnd->tnd", c, w_uv)
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope[:, None, :],
-                                  k_nope.shape[:2] + k_rope.shape[-1:])], -1)
-    return k, v
-
-
 def _ffn_sublayer(cfg, lp, x, live, counters):
     """The layer's second sublayer around the residual state x."""
     x, counters, _ = _residual(
@@ -498,14 +441,14 @@ def forward_logits(params, cfg, tokens):
     x = _streams_in(cfg, params["wte"][tokens].astype(
         _decoder.act_dtype(params)))
     mask = pos[None, :] <= pos[:, None]
-    scale = attention_scale(cfg)
+    scale = _latent.attention_scale(cfg)
     counters = _zero_counters(cfg)
     live = jnp.ones((T,), bool)
     for lp in params["layers"]:
         def attend(u, counters, lp=lp):
             h = _decoder.rms(u, lp["norm1"], cfg.rms_eps)
-            q_nope, q_rope, c, k_rope = _project(cfg, lp, h, pos)
-            k, v = _expand(cfg, lp, c, k_rope)
+            q_nope, q_rope, c, k_rope = _latent.project(cfg, lp, h, pos)
+            k, v = _latent.expand(cfg, lp, c, k_rope)
             q = jnp.concatenate([q_nope, q_rope], -1)
             o = _decoder.masked_attention(q, k, v, mask, scale)
             return o.reshape(T, -1) @ lp["wo"], counters, None
@@ -527,15 +470,10 @@ def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
     a cold prompt over its own rows, causally; after a prefix hit over
     the whole gathered page row, masked by position. Returns (logits (1,
     V) float32 of position pfx_len + real_len - 1, arena, counters)."""
-    import jax
     import jax.numpy as jnp
-    from ..ops.flash_attention import flash_causal_rows
 
     B = tokens.shape[1]
-    bs = arena.shape[4]
-    L = pages.shape[0] * bs
     dtype = arena.dtype
-    scale = attention_scale(cfg)
     flash = _pages.kernel_beside(bucket=B)
     j = jnp.arange(B)
     pos = pfx_len + j
@@ -544,33 +482,10 @@ def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
     counters = _zero_counters(cfg)
     for li, lp in enumerate(params["layers"]):
         def attend(u, counters, arena=arena, li=li, lp=lp):
-            with jax.named_scope("mla/project"):
-                h = _decoder.rms(u, lp["norm1"], cfg.rms_eps)
-                q_nope, q_rope, c, k_rope = _project(cfg, lp, h, pos)
-                q = jnp.concatenate([q_nope, q_rope], -1)
-                rows = _cache_rows(cfg, c, k_rope)
-                arena = _pages.write_pages(arena, li, pages, pfx_len,
-                                           real_len, rows[:, None, :])
-
-            def cold(arena):
-                k, v = _expand(cfg, lp, c, k_rope)
-                if flash:
-                    return flash_causal_rows(q, k, v, scale, length=real_len)
-                return _decoder.masked_attention(
-                    q, k, v, j[None, :] <= j[:, None], scale)
-
-            def warm(arena):
-                cached = _pages.gather_pages(arena, li, pages)[0]  # (L, W)
-                k, v = _expand(
-                    cfg, lp, cached[:, :cfg.kv_lora_rank],
-                    cached[:, cfg.kv_lora_rank:cfg.row_values])
-                return _decoder.masked_attention(
-                    q, k, v, jnp.arange(L)[None, :] <= pos[:, None], scale)
-
-            with jax.named_scope("mla/attend"):
-                o = _pages.cold_or_warm(pfx_len, cold, warm, arena)
-            with jax.named_scope("mla/project"):
-                return o.reshape(B, -1) @ lp["wo"], counters, arena
+            y, arena = _latent.prefill_attend(
+                cfg, lp, u, j, pos, arena, li, pages, pfx_len, real_len,
+                flash)
+            return y, counters, arena
 
         x, counters, arena = _residual(cfg, lp.get("hc_attn"), x, attend,
                                        live, counters)
@@ -581,26 +496,6 @@ def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
 
 # -- decode through the pages: absorbed attention ------------------------------
 
-def decode_attention_path(arena, arena_constraint=None):
-    """ "latent_paged_kernel" on a TPU over the bare arena with a
-    lane-aligned row; "gather" elsewhere (the CPU)."""
-    return "latent_paged_kernel" \
-        if _pages.kernel_beside(arena, arena_constraint) else "gather"
-
-
-def absorbed_attention(q_ext, rows, mask):
-    """The gather form of the absorbed step: q_ext (S, n, W) scaled,
-    rows (S, L, W) each slot's cached rows, mask (S, L). Returns the
-    context (S, n, W) in the rows' space."""
-    import jax.numpy as jnp
-    s = jnp.einsum("snw,slw->snl", q_ext, rows,
-                   preferred_element_type=jnp.float32)
-    s = jnp.where(mask[:, None, :], s, -1e30)
-    p = jnp.exp(s - jnp.max(s, -1, keepdims=True))
-    p = (p / p.sum(-1, keepdims=True)).astype(rows.dtype)
-    return jnp.einsum("snl,slw->snw", p, rows)
-
-
 def decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
                       attention=None):
     """One decode step of every slot: tokens, ts (S,), pt (S, P). Writes
@@ -609,53 +504,20 @@ def decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
     block (the gather) or nowhere (the kernel) and its logits are the
     caller's to discard. Returns (logits (S, V) float32, arena,
     counters)."""
-    import jax
     import jax.numpy as jnp
 
-    s_dim, P = pt.shape
-    bs = arena.shape[4]
+    s_dim = pt.shape[0]
     dtype = arena.dtype
-    rank = cfg.kv_lora_rank
-    scale = attention_scale(cfg)
     if attention is None:
-        attention = decode_attention_path(arena)
-    if attention == "latent_paged_kernel":
-        from ..ops.paged_attention import latent_paged_attention
+        attention = _latent.decode_attention_path(arena)
     live = jnp.ones((s_dim,), bool) if done is None else ~done
     x = _streams_in(cfg, params["wte"][tokens].astype(dtype))
     counters = _zero_counters(cfg)
-    pad = cfg.row_width - cfg.row_values
     for li, lp in enumerate(params["layers"]):
         def attend(u, counters, arena=arena, li=li, lp=lp):
-            with jax.named_scope("mla/project"):
-                h = _decoder.rms(u, lp["norm1"], cfg.rms_eps)
-                q_nope, q_rope, c, k_rope = _project(cfg, lp, h, ts)
-                row = _cache_rows(cfg, c, k_rope)
-            with jax.named_scope("mla/absorb"):
-                w_uk, w_uv = _wkvb_heads(cfg, lp)
-                q_lat = jnp.einsum("snd,cnd->snc", q_nope, w_uk)
-                parts = [q_lat, q_rope]
-                if pad:
-                    parts.append(jnp.zeros(q_rope.shape[:2] + (pad,), dtype))
-                q_ext = (jnp.concatenate(parts, -1).astype(jnp.float32)
-                         * scale).astype(dtype)
-            with jax.named_scope("mla/attend"):
-                if attention == "latent_paged_kernel":
-                    o_ext, arena = latent_paged_attention(
-                        q_ext, row, arena, li, pt, ts, done)
-                else:
-                    wblk = pt[jnp.arange(s_dim), ts // bs]
-                    if done is not None:
-                        wblk = jnp.where(done, 0, wblk)
-                    arena = arena.at[li, 0, wblk, 0, ts % bs].set(row)
-                    cached = _pages.gather_pages(arena, li, pt)[:, 0]
-                    o_ext = absorbed_attention(
-                        q_ext, cached,
-                        jnp.arange(P * bs)[None, :] <= ts[:, None])
-            with jax.named_scope("mla/absorb"):
-                o = jnp.einsum("snc,cnd->snd", o_ext[..., :rank], w_uv)
-            with jax.named_scope("mla/project"):
-                return o.reshape(s_dim, -1) @ lp["wo"], counters, arena
+            y, arena = _latent.step_attend(cfg, lp, u, ts, arena, li, pt,
+                                           done, attention)
+            return y, counters, arena
 
         x, counters, arena = _residual(cfg, lp.get("hc_attn"), x, attend,
                                        live, counters)
@@ -675,7 +537,7 @@ class _MoonlightServingModel(_experts.ExpertBlockModel):
         return CacheSpec(cfg.layers, 1, cfg.row_width)
 
     def decode_attention_path(self, arena, arena_constraint=None):
-        return decode_attention_path(arena, arena_constraint)
+        return _latent.decode_attention_path(arena, arena_constraint)
 
     def counter_names(self, cfg):
         # with residual streams also hc_passes: sublayers mixed, and

@@ -394,7 +394,7 @@ class ServingEngine:
         self.cfg = cfg
         self.config = serving
         model = self.model = serving_model(cfg)
-        require_features(model, serving)
+        require_features(model, serving, cfg)
         max_pos = model.max_positions(cfg)
         max_len = int(serving.max_len if serving.max_len is not None
                       else max_pos)
@@ -1499,12 +1499,18 @@ class ServingEngine:
             # `decode_attention` above is the model's {group: path}
             s["prefill_attention"]["groups"] = {
                 g.spec.name: s["prefill_attention"]["path"]
-                for g in self.kv.group_layout}
+                for g in self.kv.group_layout if not g.spec.state}
         # the served architecture, what a token costs the arena in a
         # layer, and the model's own in-graph counters (a routed model's
         # `expert_tokens` and `router_tokens` since start)
         s["model"] = self.model.name
         s.update(self.model.describe(self.cfg))
+        state = self.kv.state_occupancy()
+        if state is not None:
+            # the state groups' blocks (one a slot a group) and bytes a
+            # slot, beside what the model says of its recurrence
+            # (`describe`: which path it took, the prefill's chunk)
+            s["state"] = dict(state, **s.get("state", {}))
         diffusion = self.model.diffusion(self.cfg)
         if diffusion is not None:
             # generation by diffusion over blocks: the model's parameters,

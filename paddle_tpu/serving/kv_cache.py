@@ -40,7 +40,12 @@ group's row is a RING of `ring_pages` blocks fixed at admission: a slot
 never holds more than `window + block_size` rows of it, nothing is freed
 before the slot is. Admission needs every pool to have the blocks; a
 model with more than one group takes no prefix hits and cannot be
-swapped out (one payload carries one group).
+swapped out (one payload carries one group). A STATE group (a fixed-size
+state a slot: serving/model.py) is a `_GroupPool` like any other whose
+page row is ONE column: the slot's one block, of the group's own shape
+and type (a float32 arena beside a bfloat16 one), claimed at admission and
+released with the slot. Its allocation is recorded under the span
+`serving/state_alloc`.
 
 Block index 0 is the reserved SCRATCH block (of every group's arena): never allocated, it absorbs
 the in-graph ride-along writes of frozen slots (see
@@ -68,6 +73,8 @@ from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from ..observability.tracer import get_tracer
 
 __all__ = ["ShapeBuckets", "SlotKVCache"]
 
@@ -113,12 +120,19 @@ class _GroupPool:
     """A further cache group's blocks: an arena, a free list and every
     slot's page row (`layout.pages` entries, all claimed at admission and
     released with the slot). No sharing, no hashes: a block belongs to
-    one slot."""
+    one slot. A window group's row is a ring of blocks of rows; a STATE
+    group's is one block, the slot's state (`spec.state`: `blocks_for` is
+    1 whatever the length, the arena has the spec's block shape and, where
+    it names one, the spec's type)."""
 
     def __init__(self, layout, num_slots, block_size, num_blocks, alloc,
                  dtype):
+        import jax.numpy as jnp
         self.layout = layout
         self.block_size = int(block_size)
+        if layout.spec.dtype is not None:
+            dtype = jnp.dtype(layout.spec.dtype)
+        self.dtype = dtype
         if num_blocks is None:
             num_blocks = num_slots * layout.pages + 1
         self.num_blocks = int(num_blocks)
@@ -127,8 +141,8 @@ class _GroupPool:
                 f"cache group {layout.spec.name!r}: num_blocks must be >= "
                 f"2 (scratch + 1), got {num_blocks}")
         shape = layout.spec.arena_shape(self.num_blocks, self.block_size)
-        self.kv = alloc(shape, dtype)
-        self.pool_bytes = math.prod(shape) * dtype.itemsize
+        self.kv = alloc(shape, self.dtype)
+        self.pool_bytes = math.prod(shape) * self.dtype.itemsize
         self._free_blocks = list(range(self.num_blocks - 1, 0, -1))
         self._slot_blocks: List[List[int]] = [[] for _ in range(num_slots)]
         self.peak_blocks_used = 0
@@ -166,12 +180,22 @@ class _GroupPool:
 
     def occupancy(self) -> Dict[str, object]:
         spec = self.layout.spec
-        return {"name": spec.name, "window": spec.window,
-                "layers": spec.layers, "pages_a_slot": self.layout.pages,
-                "blocks_total": self.blocks_total,
-                "blocks_used": self.blocks_used,
-                "peak_blocks_used": self.peak_blocks_used,
-                "pool_bytes": self.pool_bytes}
+        out = {"name": spec.name, "window": spec.window,
+               "layers": spec.layers, "pages_a_slot": self.layout.pages,
+               "blocks_total": self.blocks_total,
+               "blocks_used": self.blocks_used,
+               "peak_blocks_used": self.peak_blocks_used,
+               "pool_bytes": self.pool_bytes}
+        if spec.state:
+            out.update(state=True, dtype=str(self.dtype),
+                       bytes_a_slot=self.block_bytes)
+        return out
+
+    @property
+    def block_bytes(self) -> int:
+        """One block over every layer of the group: what a slot holds of
+        a state group."""
+        return self.pool_bytes // self.num_blocks
 
 
 class SlotKVCache:
@@ -628,9 +652,17 @@ class SlotKVCache:
         elif pending:
             self._pending_reg[slot] = pending
         row = self._install_blocks(slot, blocks, p_len)
+        tracer = get_tracer()
         for pool in self._pools:
             # every further group's row, whole, beside the primary's
-            held = pool.claim(slot, total_positions)
+            if pool.layout.spec.state and tracer.enabled:
+                # ring only: one an admission a state group
+                args = {"slot": slot, "group": pool.layout.spec.name}
+                with tracer.span("serving/state_alloc", "serving", args):
+                    held = pool.claim(slot, total_positions)
+                    args["block"] = held[0]
+            else:
+                held = pool.claim(slot, total_positions)
             row[pool.layout.start:pool.layout.start + len(held)] = held
         self.page_table[slot] = row
         return row, len(claimed) * bs
@@ -808,8 +840,21 @@ class SlotKVCache:
         rows = {self.spec.name:
                 len(self._slot_blocks[slot]) * self.block_size}
         rows.update({p.layout.spec.name: p.held_rows(slot)
-                     for p in self._pools})
+                     for p in self._pools if not p.layout.spec.state})
         return rows
+
+    def state_occupancy(self) -> Optional[Dict[str, object]]:
+        """The STATE groups' blocks, summed over the groups (each holds
+        one a slot): total, in use, the peak, and the bytes a slot holds
+        of them in all; None for a model without one."""
+        pools = [p for p in self._pools if p.layout.spec.state]
+        if not pools:
+            return None
+        return {"groups": [p.layout.spec.name for p in pools],
+                "blocks_total": sum(p.blocks_total for p in pools),
+                "blocks_used": sum(p.blocks_used for p in pools),
+                "peak_blocks_used": sum(p.peak_blocks_used for p in pools),
+                "bytes_a_slot": sum(p.block_bytes for p in pools)}
 
     def occupancy(self) -> Dict[str, object]:
         out = self._occupancy()
@@ -826,8 +871,11 @@ class SlotKVCache:
                  "pool_bytes": self._pool_bytes
                  - sum(p.pool_bytes for p in self._pools)}
             ] + [p.occupancy() for p in self._pools]
-            out["prefix_cache"] = "off: a window group's rows before a " \
-                "hit are not kept"
+            out["prefix_cache"] = (
+                "off: a hit is valid only with the slot's state at its "
+                "edge, and no snapshot is kept"
+                if any(p.layout.spec.state for p in self._pools) else
+                "off: a window group's rows before a hit are not kept")
         return out
 
     def _occupancy(self) -> Dict[str, object]:

@@ -47,6 +47,24 @@ left every later position's window. A model with more than one group
 takes no prefix hits (a hit is valid for a window group only with the
 window's rows before it), and host swap (`preempt`) and migration are
 refused for it at construction and at the call: both carry one group.
+
+STATE GROUPS. A group may hold, instead of rows a token, a FIXED-SIZE
+STATE A SLOT (`CacheSpec.state`): what a recurrent layer carries from
+position to position, the same size whatever the sequence's length, in a
+type of its own (`dtype`: a delta-rule state is float32 beside a bfloat16
+latent arena). Nothing new manages it: a slot's state of one layer IS ONE
+BLOCK of the group's arena `(layers, 1, num_blocks) + state_shape`, the
+group's page row is ONE column of the one page table, and the group's
+`_GroupPool` hands the slot that block at admission and takes it back when
+the slot retires, with its pages. The block is WRITTEN WHOLE by the
+prefill (never read: an admitted slot starts from its prompt's state, so
+admission resets nothing), read-modified-written once a step by a live
+slot, and a frozen slot's write goes to scratch block 0 like a frozen
+row's. A state group comes after the primary group. What several groups
+already mean holds for it (no prefix hits, no swap, no migration, one
+chip, full-precision arenas), and `require_features` says for each
+option what a state group lacks: no snapshot of a slot's state is taken,
+so nothing can park it, hand it on, roll it back or resume it mid-prompt.
 """
 
 from __future__ import annotations
@@ -54,7 +72,7 @@ from __future__ import annotations
 from typing import NamedTuple, Optional, Tuple
 
 __all__ = ["FEATURES", "BLOCK_DIFFUSION", "DIFFUSION_COUNTERS", "CacheSpec",
-           "GroupLayout", "ServingModel",
+           "GroupLayout", "ServingModel", "state_groups",
            "serving_model", "require_features", "cache_groups",
            "group_columns", "ring_pages"]
 
@@ -84,16 +102,29 @@ class CacheSpec(NamedTuple):
     index it) and heads on axis 3 (a mesh plan shards it). One spec is
     one GROUP of layers; `window` (None: every position is kept) makes
     the group a ring of `ring_pages` blocks a slot; `name` is how
-    `engine.stats()` calls the group."""
+    `engine.stats()` calls the group. `state=True`: the group holds a
+    fixed-size state A SLOT and nothing a token (the module's STATE
+    GROUPS): a block is `state_shape` values (None: the shape of a block
+    of rows, `(heads, block_size, row_width)`) of `dtype` (None: the
+    arena's type), one block a slot a layer, one column of the page
+    table."""
     layers: int
     heads: int
     row_width: int
     window: Optional[int] = None
     name: str = "kv"
+    state: bool = False
+    dtype: Optional[str] = None
+    state_shape: Optional[Tuple[int, ...]] = None
+
+    def block_shape(self, block_size: int):
+        """One block of one layer: rows a token, or a slot's state."""
+        if self.state and self.state_shape is not None:
+            return tuple(self.state_shape)
+        return (self.heads, block_size, self.row_width)
 
     def arena_shape(self, num_blocks: int, block_size: int):
-        return (self.layers, 1, num_blocks, self.heads, block_size,
-                self.row_width)
+        return (self.layers, 1, num_blocks) + self.block_shape(block_size)
 
 
 def ring_pages(window: int, block_size: int) -> int:
@@ -117,11 +148,21 @@ class GroupLayout(NamedTuple):
 def _layout(specs, max_pages: int, block_size: int):
     out, start = [], 0
     for spec in specs:
-        pages = max_pages if spec.window is None else \
-            min(max_pages, ring_pages(spec.window, block_size))
+        if spec.state:
+            pages = 1               # the slot's one block of state
+        elif spec.window is None:
+            pages = max_pages
+        else:
+            pages = min(max_pages, ring_pages(spec.window, block_size))
         out.append(GroupLayout(spec, start, pages))
         start += pages
     return tuple(out)
+
+
+def _specs(model, cfg) -> Tuple[CacheSpec, ...]:
+    """`model.cache_spec(cfg)` as a tuple: one spec is one group."""
+    specs = model.cache_spec(cfg)
+    return (specs,) if isinstance(specs, CacheSpec) else tuple(specs)
 
 
 def cache_groups(model, cfg, max_len: int, block_size: int
@@ -130,13 +171,11 @@ def cache_groups(model, cfg, max_len: int, block_size: int
     `max_len` positions: the primary group's `max_pages` columns first,
     then each further group's (a window group's `ring_pages`, never more
     than `max_pages`)."""
-    specs = model.cache_spec(cfg)
-    if isinstance(specs, CacheSpec):
-        specs = (specs,)
-    if specs[0].window is not None:
+    specs = _specs(model, cfg)
+    if specs[0].window is not None or specs[0].state:
         raise ValueError("the first cache group is the primary one and "
-                         "keeps every position: list a window group after "
-                         "it")
+                         "keeps every position: list a window group or a "
+                         "state group after it")
     return _layout(specs, -(-int(max_len) // int(block_size)), block_size)
 
 
@@ -203,7 +242,11 @@ class ServingModel:
     tuple of the groups' arenas, the primary first, and `pages` / `pt`
     hold every group's page row side by side at `cache_groups`' columns
     (a window group's a ring, read modulo its width); `pfx_len` is then
-    always 0 (no prefix hits).
+    always 0 (no prefix hits). A STATE group's column holds the block of
+    its arena that is the slot's state: `prefill` writes that block whole
+    in every layer of the group (the state at `real_len`, not at the
+    bucket's end), `decode_step` reads and writes a live slot's once a
+    layer and sends a frozen slot's write to scratch block 0.
     `counters` is None or a dict of small int32 arrays the program
     accumulated in-graph (a routed model's tokens per expert), with the
     names and shapes `counter_names` gives, from both programs alike;
@@ -288,11 +331,38 @@ def serving_model(cfg) -> ServingModel:
     return GPT_SERVING_MODEL
 
 
-def require_features(model: ServingModel, serving) -> None:
+# What each option would need of a model with a STATE group, which no
+# model has written: the state group is built, its snapshots are not.
+_STATE_LACKS = {
+    "int8_weights": "the recurrence's projections have no int8 path",
+    "int8_kv": "a state block is float32 values, not rows with a scale "
+               "plane",
+    "adapters": "the recurrent mixer's projections take no LoRA pair",
+    "speculation": "a rejected draft needs the slot's state as it was "
+                   "before the verify pass, and no snapshot is kept",
+    "mesh": "a state block's heads are not sharded and the recurrence is "
+            "one chip's program",
+    "prefill_chunk": "a later chunk needs the state and the convolution's "
+                     "history carried in from the chunk before; the "
+                     "prefill writes them and never reads them",
+    "preempt": "a swap payload carries the primary group's blocks; no "
+               "snapshot of a slot's state is taken",
+}
+
+
+def state_groups(model: ServingModel, cfg) -> Tuple[CacheSpec, ...]:
+    """The model's STATE groups (none for most models)."""
+    return tuple(spec for spec in _specs(model, cfg) if spec.state)
+
+
+def require_features(model: ServingModel, serving, cfg=None) -> None:
     """Refuse, at engine construction, every ServingConfig option that
     asks for a feature `model` does not declare: an engine must never
     serve base-model tokens, read quantized rows as values or run one
-    chip's program on a mesh because a model lacked the path."""
+    chip's program on a mesh because a model lacked the path. With `cfg`,
+    a model whose `cache_spec` has a STATE group is refused each such
+    option, and host swap (`preempt`), with what the state group lacks
+    for it, whatever the model declares."""
     asked = {
         "int8_weights": (serving.weight_dtype == "int8",
                          "weight_dtype='int8'"),
@@ -307,6 +377,12 @@ def require_features(model: ServingModel, serving) -> None:
     missing = [f"{option} needs {feature!r}"
                for feature, (on, option) in asked.items()
                if on and feature not in model.features]
+    held = state_groups(model, cfg) if cfg is not None else ()
+    if held:
+        asked["preempt"] = (serving.preempt, "preempt=True")
+        missing = [f"{option} with the state group {held[0].name!r}: "
+                   f"{_STATE_LACKS[feature]}"
+                   for feature, (on, option) in asked.items() if on]
     if BLOCK_DIFFUSION in model.features and serving.preempt:
         missing.append("preempt=True parks a slot's carry rows, not its "
                        "block: a block-diffusion model is not swapped")

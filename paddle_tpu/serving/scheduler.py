@@ -1258,6 +1258,8 @@ class ContinuousBatchingScheduler:
         if key == "cold_flash":
             from ..ops.flash_attention import causal_rows_tiles
             for g in self.kv.group_layout:
+                if g.spec.state:
+                    continue            # a state group attends nothing
                 visited, held = causal_rows_tiles(bucket, real_len,
                                                   g.spec.window)
                 self.prefill_counts["tiles_visited"] += visited

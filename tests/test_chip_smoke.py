@@ -29,7 +29,8 @@ def test_main_exits_nonzero_on_cpu_before_any_work(monkeypatch, capsys):
         raise AssertionError("a phase ran without a TPU")
 
     for name in ("phase_kernels", "phase_share_kernels",
-                 "phase_block_diffusion", "phase_train", "phase_serve"):
+                 "phase_block_diffusion", "phase_state_group", "phase_train",
+                 "phase_serve"):
         monkeypatch.setattr(chip_smoke, name, no_work)
     assert jax.default_backend() == "cpu"
     assert chip_smoke.main() != 0
@@ -48,6 +49,7 @@ def test_last_stdout_line_is_the_verdict_and_the_device(monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, "phase_kernels", lambda: {"cases": 0})
     monkeypatch.setattr(chip_smoke, "phase_share_kernels", lambda: {})
     monkeypatch.setattr(chip_smoke, "phase_block_diffusion", lambda: {})
+    monkeypatch.setattr(chip_smoke, "phase_state_group", lambda: {})
     monkeypatch.setattr(
         chip_smoke, "phase_train",
         lambda *a, **kw: {"losses": [2.0, 1.0], "scope": None})
@@ -105,6 +107,21 @@ def test_block_diffusion_phase_interpreted():
     assert len(facts["fixed_at"]) == 6 and set(facts["fixed_at"]) <= {0, 1, 2, 3}
     assert facts["max_logit_deficit"] <= chip_smoke.LOGIT_MARGIN
     assert 0 < facts["max_confidence_drift"] <= chip_smoke.LOGIT_MARGIN
+
+
+def test_state_group_phase_interpreted():
+    """The phase at a small size with the kernel paths forced: the flash
+    forward and the latent paged kernel interpreted beside the step's
+    kernel over a state block, a prompt shorter than its bucket, two chunks
+    of steps."""
+    facts = chip_smoke.phase_state_group(
+        hidden=128, heads=2, head_dim=128, rank=128, rope=128, width=128,
+        experts=4, vocab=256, prompt_len=130, max_new=10, bucket=256,
+        page=128, force_kernels=True)
+    assert facts["state"]["recurrence_path"] == "kernel"
+    assert facts["state"]["peak_blocks_used"] == 2
+    assert facts["kda_state_steps"] == 4 * 9
+    assert facts["max_logit_deficit"] <= chip_smoke.LOGIT_MARGIN
 
 
 def test_train_then_serve_phases():

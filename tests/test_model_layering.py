@@ -2,8 +2,9 @@
 
   serving/{engine, scheduler, kv_cache, decode_loop}       know no model
   serving/model.py, serving/pages.py                       the interface
-  models/_decoder.py, _experts.py, _grouped.py             shared pieces
-  models/{gpt_decode, moonlight, mellum, command_a, sdar}  leaves
+  models/_decoder.py, _experts.py, _grouped.py, _latent.py shared pieces
+  models/{gpt_decode, moonlight, mellum, command_a, sdar,
+          kimi_linear}                                     leaves
 
 R1: no leaf imports another leaf. R2: a module under models/ imports from
 serving/ only `model` and `pages`. R3: a leaf takes the shared pieces as
@@ -27,8 +28,9 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODELS = os.path.join(ROOT, "paddle_tpu", "models")
-LEAVES = ("gpt_decode", "moonlight", "mellum", "command_a", "sdar")
-SHARED = ("_decoder", "_experts", "_grouped")
+LEAVES = ("gpt_decode", "moonlight", "mellum", "command_a", "sdar",
+          "kimi_linear")
+SHARED = ("_decoder", "_experts", "_grouped", "_latent")
 SERVED = LEAVES + SHARED
 
 

@@ -33,6 +33,7 @@ import pytest
 import paddle_tpu  # noqa: F401
 from paddle_tpu.models import _decoder as dec
 from paddle_tpu.models import _experts as ex
+from paddle_tpu.models import _latent
 from paddle_tpu.models import moonlight as ml
 from paddle_tpu.serving import ServingConfig, ServingEngine
 from paddle_tpu.serving.kv_cache import SlotKVCache
@@ -360,21 +361,21 @@ def test_absorbed_step_matches_expanded(params):
     T = 9
     x = jnp.asarray(rng.normal(0, 1, (T, CFG.hidden)), jnp.float32)
     pos = jnp.arange(T)
-    q_nope, q_rope, c, k_rope = ml._project(CFG, lp, x, pos)
-    k, v = ml._expand(CFG, lp, c, k_rope)
+    q_nope, q_rope, c, k_rope = _latent.project(CFG, lp, x, pos)
+    k, v = _latent.expand(CFG, lp, c, k_rope)
     scale = 1.0 / np.sqrt(CFG.qk_head_dim)
     q = jnp.concatenate([q_nope, q_rope], -1)[-1:]           # the last token
     s_exp = jnp.einsum("qnd,knd->nqk", q, k) * scale
     p = jax.nn.softmax(s_exp, -1)
     o_exp = jnp.einsum("nqk,knd->qnd", p, v)[0]
-    w_uk, w_uv = ml._wkvb_heads(CFG, lp)
+    w_uk, w_uv = _latent.wkvb_heads(CFG, lp)
     q_lat = jnp.einsum("snd,cnd->snc", q_nope[-1:], w_uk)
     pad = jnp.zeros((1, CFG.heads, CFG.row_width - CFG.row_values))
     q_ext = jnp.concatenate([q_lat, q_rope[-1:], pad], -1) * scale
-    rows = ml._cache_rows(CFG, c, k_rope)
+    rows = _latent.cache_rows(CFG, c, k_rope)
     s_abs = jnp.einsum("snw,lw->snl", q_ext, rows)[0]
     np.testing.assert_allclose(s_abs, s_exp[:, 0], atol=2e-6)
-    o_ext = ml.absorbed_attention(q_ext, rows[None], jnp.ones((1, T), bool))
+    o_ext = _latent.absorbed_attention(q_ext, rows[None], jnp.ones((1, T), bool))
     o_abs = jnp.einsum("snc,cnd->snd", o_ext[..., :CFG.kv_lora_rank], w_uv)[0]
     np.testing.assert_allclose(o_abs, o_exp, atol=2e-6)
 
@@ -466,7 +467,7 @@ def test_latent_kernel_matches_gather(name):
             want_arena = want_arena.at[li, 0, wblk, 0, ts_now % bs].set(
                 row_now)
             cached = want_arena[li, 0, pt, 0].reshape(S, P * bs, W)
-            want = ml.absorbed_attention(
+            want = _latent.absorbed_attention(
                 q, cached, jnp.arange(P * bs)[None] <= ts_now[:, None])
             np.testing.assert_allclose(
                 np.asarray(out, np.float32)[live],

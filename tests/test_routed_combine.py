@@ -229,9 +229,11 @@ PARENT_MOE = {
     ("grouped_swiglu_kernel", 512, "all"): "2a17545889b1d3f7",
     # a layer that holds 2 of 8 experts, 2,048 tokens in two parts under
     # the `lax.cond` (command-a's 2,048 bucket in small), two averaged
-    # shared experts behind it
-    ("ragged_dot", 2048, "share"): "c9f4bf0810b864f9",
-    ("grouped_swiglu_kernel", 2048, "share"): "882e7539e23a00a0",
+    # shared experts behind it. Computed again at PR 45 (its review round):
+    # XLA's sum of a layer that holds a SHARE is pick by pick with a select
+    # (`ex._weighted_sum(share=True)`); the four that hold all are the parent's
+    ("ragged_dot", 2048, "share"): "153669ce95512193",
+    ("grouped_swiglu_kernel", 2048, "share"): "46a8b7face328da8",
 }
 _CFG = {
     "all": types.SimpleNamespace(

@@ -488,11 +488,15 @@ def test_block_causal_flash_forward_against_the_masked_attention(rows,
 # `_grouped_kernel`, `flash_causal_rows`, `_moe` and the loop with this model
 # trace what they traced, through the gather (what the CPU serves) and through
 # the kernels (`.kernel`: the decode step with the grouped paged kernel forced,
-# where B = 1 must be the kernel it was).
+# where B = 1 must be the kernel it was). command-a's three were computed
+# again at PR 45, whose review made a layer that holds a SHARE of the experts
+# combine by a select, pick by pick (models/_experts._weighted_sum: a pick held
+# elsewhere no longer multiplies the routed buffer's never-written last row
+# by 0); Mellum, which holds every expert, keeps the parent's.
 PARENT = {
-    "command_a.decode": "f90aecbd5ae215b7",
-    "command_a.decode.kernel": "d407a2bfcaf9dc15",
-    "command_a.prefill": "a5b3828356013aef",
+    "command_a.decode": "87a4936e25c1afe3",
+    "command_a.decode.kernel": "9b411f0bb25f295c",
+    "command_a.prefill": "f58604d02312aebd",
     "kernel.flash_causal_rows.window": "f29109e6f9230434",
     "kernel.paged_attention_grouped": "803cdca448cca1da",
     "mellum.decode": "47cc377bf4679650",

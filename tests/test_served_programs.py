@@ -25,6 +25,12 @@ it; the file names only addresses that both trees have). A case is
   * `<model>.init`: sha256 of the bytes of the float32 weights a seed
     draws, leaves in sorted order.
 
+`command_a.prefill.tpu` and `command_a.decode.tpu` were computed again at PR
+45 (its review round): a layer that holds a SHARE of the experts now sums its
+picks by a select, pick by pick (`models/_experts._weighted_sum`), so that a
+pick held elsewhere never multiplies the routed buffer's never-written last
+row by 0; every model that holds all its experts traces what it traced.
+
 Mellum's and command-a's programs are pinned by tests/test_sdar.py::PARENT,
 the CPU's pair of GPT, Moonlight and Xing by tests/test_mellum.py::PARENT.
 The CPU gives identity, never a time.
@@ -83,8 +89,8 @@ PARENT = {
     "xing.decode.tpu": "8660304e6bd49c29",
     "mellum.prefill.tpu": "db3a92150d64f7ea",
     "mellum.decode.tpu": "ed45a5314893c6c7",
-    "command_a.prefill.tpu": "ceef07fedbdd3f7e",
-    "command_a.decode.tpu": "047ebf395c337f84",
+    "command_a.prefill.tpu": "50700700e8c46ab4",
+    "command_a.decode.tpu": "2904706132e92170",
     "sdar.prefill": "f13aba2086490cba",
     "sdar.block": "56b73e3a985d01c4",
     "sdar.block.kernel": "2b1f8c44da608615",

@@ -33,6 +33,7 @@ import pytest
 import paddle_tpu  # noqa: F401
 from paddle_tpu.models import _experts as ex
 from paddle_tpu.models import _decoder as dec
+from paddle_tpu.models import _latent
 from paddle_tpu.models import moonlight as ml
 from paddle_tpu.serving import ServingConfig, ServingEngine
 from paddle_tpu.serving.kv_cache import SlotKVCache
@@ -159,7 +160,7 @@ def test_yarn_frequencies_at_the_published_numbers():
     cfg = ml.MoonlightConfig(qk_nope_head_dim=128, qk_rope_head_dim=64,
                              rope_scaling=yarn)
     want = (0.1 * math.log(64) + 1) ** 2 / math.sqrt(192)
-    assert ml.attention_scale(cfg) == pytest.approx(want, rel=1e-12)
+    assert _latent.attention_scale(cfg) == pytest.approx(want, rel=1e-12)
     assert want * math.sqrt(192) == pytest.approx(2.005, abs=1e-3)
     assert ref.softmax_scale({"qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
                               "rope_scaling": yarn}) \
@@ -268,8 +269,8 @@ def _as_parent(params, cfg, tokens):
     counters = ml._zero_counters(cfg)
     for lp in params["layers"]:
         h = dec.rms(x, lp["norm1"], cfg.rms_eps)
-        q_nope, q_rope, c, k_rope = ml._project(cfg, lp, h, pos)
-        k, v = ml._expand(cfg, lp, c, k_rope)
+        q_nope, q_rope, c, k_rope = _latent.project(cfg, lp, h, pos)
+        k, v = _latent.expand(cfg, lp, c, k_rope)
         q = jnp.concatenate([q_nope, q_rope], -1)
         o = dec.masked_attention(q, k, v, mask,
                                  1.0 / np.sqrt(cfg.qk_head_dim))

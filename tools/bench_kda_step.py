@@ -1,0 +1,190 @@
+"""One KDA layer's two programs alone: the recurrent step over a pool of
+state blocks, and the chunked prefill of one prompt.
+
+The STEP (`kda/recur` of models/kimi_linear.py's decode step) in both
+forms, XLA's gather-update-scatter (`kda_step` over `arena[layer, 0, ids]`)
+and the kernel ops/kda_step.py, at the cell's shape: 128 slots (some
+frozen), 32 heads of 128 x 128 float32, 10 layers, a shuffled page column.
+The kernel is first held against the XLA form on the same chip (the arena
+compared whole but for scratch block 0). Beside each time stand the bytes
+a step must move (a live slot's state read once and written once: 2 x
+2,097,152 B a layer a slot) over the chip's 819 GB/s, and the share
+`kda_decode_hbm_roofline` would read. The CHUNKED PREFILL (`kda_chunked`)
+of 512 .. 4,096 rows, with its share of the peak as
+`kda_prefill_flops_roofline` counts it (the recurrence's own three
+products, 6 x 128 x 128 FLOPs a row a head).
+
+Device time is the sum of the first chip's operations in a profiler trace;
+the host's clock a call stands beside it. A chip is required (`--tiny`
+rehearses the program on the CPU at a toy size and reports no time).
+
+    chiprun -- python tools/bench_kda_step.py
+
+Prints one JSON line; the same goes to chiprun_out/bench_kda_step.json.
+"""
+
+import argparse
+import glob
+import json
+import os
+import sys
+import tempfile
+import time
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
+
+HBM_BYTES_PER_S = 819e9          # TPU v5e (Google Cloud documentation)
+PEAK_FLOPS = 197e12
+
+
+def operation_seconds(trace_dir):
+    """{operation: seconds} of the first chip's operations in a trace."""
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    totals = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:TPU:"):
+            for line in plane.lines:
+                if line.name == "XLA Ops":
+                    for ev in line.events:
+                        name = ev.name.split(" = ")[0].lstrip("%")
+                        totals[name] = totals.get(name, 0.0) + ev.duration_ns * 1e-9
+            break
+    return totals
+
+
+def timed(run, calls, tiny, top=14):
+    """(device seconds, host seconds, [(operation, us)] the largest first) a
+    call of `run()`, over `calls` of them."""
+    import jax
+    run().block_until_ready()
+    if tiny:
+        return None, None, []
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = run()
+        out.block_until_ready()
+        host = (time.perf_counter() - t0) / calls
+        jax.profiler.stop_trace()
+        totals = operation_seconds(trace_dir)
+    largest = sorted(totals.items(), key=lambda kv: -kv[1])[:top]
+    return (sum(totals.values()) / calls or None, host,
+            [(name, seconds / calls * 1e6) for name, seconds in largest])
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--slots", type=int, default=128)
+    ap.add_argument("--rows", default="512,1024,2048,4096")
+    ap.add_argument("--precision", default=None,
+                    help="replace models/kimi_linear.KDA_PRECISION (a sweep)")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    if jax.default_backend() != "tpu" and not args.tiny:
+        print(f"a chip is required; the backend is {jax.default_backend()!r}",
+              file=sys.stderr)
+        return 1
+    from paddle_tpu.models import kimi_linear as kl
+    from paddle_tpu.ops.kda_step import kda_step_blocks
+    if args.precision:
+        kl.KDA_PRECISION = args.precision
+
+    slots, heads, d, layers = (4, 4, 16, 2) if args.tiny else \
+        (args.slots, 32, 128, 10)
+    rng = np.random.default_rng(0)
+    key = jax.random.split(jax.random.PRNGKey(0), 8)
+    arena = 0.1 * jax.random.normal(key[0], (layers, 1, slots + 1, heads, d, d),
+                                    jnp.float32)
+    ids = jnp.asarray(1 + rng.permutation(slots), jnp.int32)
+    done = jnp.arange(slots) % 7 == 5
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(key[1], (slots, heads, d))) * d ** -0.5
+    k = unit(jax.random.normal(key[2], (slots, heads, d)))
+    v = jax.random.normal(key[3], (slots, heads, d))
+    g = -jnp.exp(jax.random.uniform(key[4], (slots, heads, d), minval=-7.0,
+                                    maxval=0.5))
+    beta = jax.nn.sigmoid(jax.random.normal(key[5], (slots, heads)))
+
+    def xla_step(arena, li, done):
+        S, o = kl.kda_step(arena[li, 0, ids], q, k, v, g, beta)
+        return o, arena.at[li, 0, jnp.where(done, 0, ids)].set(S)
+
+    def kernel_step(arena, li, done):
+        return kda_step_blocks(arena, li, ids, done, q, k, v, g, beta)
+
+    # the kernel against XLA's form, some slots frozen
+    o_x, a_x = jax.jit(xla_step, static_argnums=1)(arena, 1, done)
+    o_k, a_k = jax.jit(kernel_step, static_argnums=1)(arena + 0, 1, done)
+    live = ~np.asarray(done)
+    err_o = float(np.abs(np.asarray(o_x) - np.asarray(o_k))[live].max())
+    err_s = float(jnp.abs(a_x[:, :, 1:] - a_k[:, :, 1:]).max())
+    if not (err_o < 1e-4 and err_s < 1e-4):
+        raise SystemExit(f"the kernel disagrees with XLA's form: o {err_o}, "
+                         f"state {err_s}")
+    result = {"slots": slots, "heads": heads, "head_dim": d, "layers": layers,
+              "kernel_vs_xla": {"o": err_o, "state": err_s}, "step": {},
+              "prefill": []}
+    none = jnp.zeros((slots,), bool)
+    state_bytes = 2 * heads * d * d * 4           # read once, written once
+    for name, step in (("xla", xla_step), ("kernel", kernel_step)):
+        def program(arena, step=step):
+            total = jnp.zeros((slots, heads, d), jnp.float32)
+            for li in range(layers):
+                o, arena = step(arena, li, none)
+                total = total + o
+            return total, arena
+
+        program = jax.jit(program, donate_argnums=0)
+        holder = [arena + 0]
+
+        def run():
+            total, holder[0] = program(holder[0])
+            return total
+
+        device, host, _ = timed(run, 8, args.tiny)
+        floor = slots * state_bytes / HBM_BYTES_PER_S
+        result["step"][name] = {
+            "layer_us": device and device / layers * 1e6,
+            "host_layer_us": host and host / layers * 1e6,
+            "floor_us": floor * 1e6,
+            "kda_decode_hbm_roofline": device and 100 * floor * layers / device}
+    for rows in ([32] if args.tiny else [int(r) for r in args.rows.split(",")]):
+        kk = jax.random.split(jax.random.PRNGKey(rows), 5)
+        pq = unit(jax.random.normal(kk[0], (rows, heads, d))) * d ** -0.5
+        pk = unit(jax.random.normal(kk[1], (rows, heads, d)))
+        pv = jax.random.normal(kk[2], (rows, heads, d))
+        pg = -jnp.exp(jax.random.uniform(kk[3], (rows, heads, d), minval=-7.0,
+                                         maxval=0.5))
+        pb = jax.nn.sigmoid(jax.random.normal(kk[4], (rows, heads)))
+        chunked = jax.jit(lambda *a: kl.kda_chunked(*a)[0])
+        device, host, largest = timed(lambda: chunked(pq, pk, pv, pg, pb), 4,
+                                      args.tiny)
+        flops = rows * heads * 6 * d * d
+        result["prefill"].append({
+            "rows": rows, "chunk": kl.KDA_CHUNK, "precision": kl.KDA_PRECISION,
+            "top_operations_us": largest,
+            "layer_us": device and device * 1e6,
+            "host_layer_us": host and host * 1e6, "flops_counted": flops,
+            "kda_prefill_flops_roofline": device
+            and 100 * flops / PEAK_FLOPS / device})
+    line = json.dumps(result)
+    print(line)
+    if not args.tiny:
+        out = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                           "chiprun_out")
+        os.makedirs(out, exist_ok=True)
+        with open(os.path.join(out, "bench_kda_step.json"), "w") as f:
+            f.write(line + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -102,7 +102,7 @@ def prepare(pa, slots, heads, pages):
 
     def check(ts, done):
         """The kernel against a gather and two einsums on the same chip."""
-        from paddle_tpu.models.moonlight import absorbed_attention
+        from paddle_tpu.models._latent import absorbed_attention
         got, after = jax.jit(pa.latent_paged_attention)(
             q, row, arena + 0, 1, pt, ts, done)
         at = jnp.where(done, 0, pt[jnp.arange(slots), ts // BLOCK])
