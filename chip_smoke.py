@@ -446,17 +446,21 @@ def phase_block_diffusion(hidden=256, heads=8, kv_heads=2, head_dim=128,
 # ---------------------------------------------------------------------------
 
 def phase_state_group(hidden=256, heads=2, head_dim=128, rank=128, rope=128,
-                      width=128, experts=8, picks=2, vocab=512, prompt_len=130,
-                      max_new=18, bucket=256, page=128, force_kernels=False):
-    """One request of a small hybrid model (models/kimi_linear: 1 dense + 4
-    layers with one latent, lane-aligned widths, bfloat16, half of the
-    experts held) through the engine: the flash prefill of the latent layer
-    and the chunked scan of the KDA layers (a prompt of 130 rows in a bucket
-    of 256: the state written is the one AT row 130), then two chunks of
-    recurrent steps through ops/kda_step beside the latent paged kernel,
-    the slot's state a block of a float32 state group. Every served token's
-    logit against its position's largest by the program's own float32
-    forward over the whole sequence (the chunked form, no cache).
+                      width=128, experts=8, picks=2, vocab=512,
+                      prompt_lens=(130, 70), max_news=(18, 3), bucket=256,
+                      page=128, force_kernels=False):
+    """Two requests, one behind the other, of a small hybrid model
+    (models/kimi_linear: 1 dense + 4 layers with one latent, lane-aligned
+    widths, bfloat16, half of the experts held) through the engine: the
+    flash prefill of the latent layer and the chunked scan of the KDA
+    layers through ops/kda_chunk in both buckets (a prompt of 130 rows in
+    a bucket of 256: the state written is the one AT row 130, and the
+    bucket's fourth chunk is passed by; one of 70 in a bucket of 128),
+    then chunks of recurrent steps through ops/kda_step beside the latent
+    paged kernel, the slot's state a block of a float32 state group. Every
+    served token's logit against its position's largest by the program's
+    own float32 forward over the whole sequence (`kda_chunked` in
+    `jax.numpy`, no cache: a prefill that is not finite fails here).
     `force_kernels` (the CPU test): take the kernel paths interpreted."""
     import jax
     import jax.numpy as jnp
@@ -476,46 +480,66 @@ def phase_state_group(hidden=256, heads=2, head_dim=128, rank=128, rope=128,
     params = kimi_linear.init_params(cfg, jax.random.PRNGKey(45), jnp.bfloat16)
     model = cfg.serving_model()
     forced = (_latent.decode_attention_path, kimi_linear.recurrence_path,
+              kimi_linear.prefill_recurrence_path,
               type(model).prefill_attention_path)
     if force_kernels:
         # the programs' and the verdicts' one source
         _latent.decode_attention_path = lambda a, c=None: "latent_paged_kernel"
         kimi_linear.recurrence_path = lambda cfg: "kernel"
+        # (the float32 forward below is `jax.numpy`'s whatever this says)
+        kimi_linear.prefill_recurrence_path = \
+            lambda cfg, bucket=None: "kernel"
         type(model).prefill_attention_path = lambda self, a, b, c=None: "flash"
     try:
         engine = ServingEngine(params, cfg, ServingConfig(
-            num_slots=2, prefill_buckets=(bucket,), max_len=cfg.max_pos,
-            block_size=page, decode_chunk=8))
-        prompt = np.random.default_rng(45).integers(0, vocab, prompt_len)
-        req = engine.submit(prompt, max_new)
-        engine.run_until_drained()
+            num_slots=2, prefill_buckets=(bucket // 2, bucket),
+            max_len=cfg.max_pos, block_size=page, decode_chunk=8))
+        rng = np.random.default_rng(45)
+        served = []
+        for prompt_len, max_new in zip(prompt_lens, max_news):
+            prompt = rng.integers(0, vocab, prompt_len)
+            req = engine.submit(prompt, max_new)
+            engine.run_until_drained()
+            served.append((prompt, req, max_new))
         stats = engine.stats()
+        wide = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params)
+        worst = 0.0
+        for prompt, req, max_new in served:
+            _require(req.state == "finished" and len(req.tokens) == max_new,
+                     f"state_group: {len(req.tokens)} of {max_new} tokens "
+                     "served")
+            logits = np.asarray(kimi_linear.forward_logits(
+                wide, cfg, jnp.asarray(list(prompt) + list(req.tokens)))
+            )[len(prompt) - 1:-1]
+            deficit = logits.max(-1) - logits[np.arange(max_new),
+                                              np.asarray(req.tokens)]
+            _require(float(deficit.max()) <= LOGIT_MARGIN,
+                     f"state_group: a served token lies {float(deficit.max())}"
+                     " under its position's best logit")
+            worst = max(worst, float(deficit.max()))
     finally:
         (_latent.decode_attention_path, kimi_linear.recurrence_path,
+         kimi_linear.prefill_recurrence_path,
          type(model).prefill_attention_path) = forced
-    _require(req.state == "finished" and len(req.tokens) == max_new,
-             f"state_group: {len(req.tokens)} of {max_new} tokens served")
     state = stats["state"]
     _require(stats["decode_attention"] == {"latent": "latent_paged_kernel"}
-             and state["recurrence_path"] == "kernel",
+             and state["recurrence_path"] == "kernel"
+             and state["prefill_recurrence_path"] == "kernel"
+             and state["prefill_kernel_buckets"] == [bucket // 2, bucket],
              "state_group: a step gathered or the recurrence ran in XLA: "
              f"{stats['decode_attention']}, {state}")
     _require(state["peak_blocks_used"] == 2 and state["blocks_used"] == 0,
              f"state_group: the slot's two state blocks: {state}")
-    _require(stats["kda_state_steps"] == 4 * (max_new - 1)
-             and stats["kda_prefill_rows"] == 4 * prompt_len,
+    chunks = sum(-(-n // kimi_linear.KDA_CHUNK) for n in prompt_lens)
+    _require(stats["kda_state_steps"] == 4 * (sum(max_news) - len(max_news))
+             and stats["kda_prefill_rows"] == 4 * sum(prompt_lens)
+             and stats["kda_prefill_chunks"] == 4 * chunks,
              f"state_group: {stats['kda_state_steps']} state steps, "
-             f"{stats['kda_prefill_rows']} prefill rows")
-    wide = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params)
-    seq = list(prompt) + list(req.tokens)
-    logits = np.asarray(kimi_linear.forward_logits(
-        wide, cfg, jnp.asarray(seq)))[prompt_len - 1:-1]
-    deficit = logits.max(-1) - logits[np.arange(max_new), np.asarray(req.tokens)]
-    _require(float(deficit.max()) <= LOGIT_MARGIN,
-             f"state_group: a served token lies {float(deficit.max())} under "
-             "its position's best logit")
+             f"{stats['kda_prefill_rows']} prefill rows in "
+             f"{stats['kda_prefill_chunks']} chunks (of {4 * chunks} live)")
     return {"state": state, "kda_state_steps": stats["kda_state_steps"],
-            "max_logit_deficit": float(deficit.max())}
+            "kda_prefill_chunks": stats["kda_prefill_chunks"],
+            "max_logit_deficit": worst}
 
 
 # ---------------------------------------------------------------------------

@@ -40,7 +40,12 @@ the two published lists (`kda_layers`, `full_attn_layers`, 1-indexed):
     inside the chunk, sub-chunk by sub-chunk of 16 rows, and elementwise
     inside a sub-chunk. Rows at or past `real_len` get beta = 0 and g = 0,
     so the state and the history written are those AT `real_len`, not at
-    the bucket's end);
+    the bucket's end). On a TPU, in buckets of whole 128-row tiles, the
+    chunked form runs as ONE Pallas call a layer, ops/kda_chunk.py
+    (`prefill_recurrence_path`): a chunk's Gram matrices, its triangular
+    system, the three state products and the state's carry stay in VMEM,
+    and a chunk wholly past `real_len` is passed by; `kda_chunked` in
+    `jax.numpy` is the CPU's path, odd widths' and the tests' oracle;
   * a latent layer is models/_latent.py's (Moonlight's) with `mla_use_nope`:
     nothing is rotated; the cached row stays 576 values in 640 lanes and
     the flash forward and the latent paged kernel run as they are. Its
@@ -64,11 +69,18 @@ h); a latent layer's wq, wkva, kv_norm, wkvb, wo.
 Named scopes: `embed`, `norm`, `kda/project` (q|k|v, the two low-rank
 pairs, beta), `kda/conv` (convolution, SiLU, l2 norms, the decay's
 softplus), `kda/recur` (the state's read-modify-write and `o`; the chunked
-scan in a prefill), `kda/gate` (the head norm, sigmoid gate, `Wo`),
-`mla/*`, `moe/*`, `ffn/dense`, `head`. In-graph counters beside the expert
-layer's: `kda_state_steps` (live slots x KDA layers a step),
-`kda_prefill_rows` (real rows x KDA layers), `mla_decode_rows` (live
-positions x latent layers a step), and command-a's held-pick counters.
+scan in a prefill, `kda_chunk` in a trace where it is the kernel),
+`kda/gate` (the head norm, sigmoid gate, `Wo`), `mla/*`, `moe/*`,
+`ffn/dense`, `head`. In-graph counters beside the expert layer's:
+`kda_state_steps` (live slots x KDA layers a step), `kda_prefill_rows`
+(real rows x KDA layers), `kda_prefill_chunks` (chunks the scan visited x
+KDA layers: `kda_prefill_rows / (64 kda_prefill_chunks)` is the share of
+visited rows that were real; the `jax.numpy` form visits every chunk of
+the bucket), `mla_decode_rows` (live positions x latent layers a step),
+and command-a's held-pick counters. `engine.stats()["state"]` names both
+paths, `recurrence_path` (the step's) and `prefill_recurrence_path` (what the
+prefills TRACED so far took, `prefill_kernel_buckets` the buckets in which
+that was the kernel; the rule's word before any is traced).
 
 Refused by the engine (`serving.model.require_features`): int8 weights or
 cache, adapters, speculation, a mesh plan, chunked prefill and host swap,
@@ -78,6 +90,8 @@ hits are off.
 
 from __future__ import annotations
 
+import weakref
+
 from ..serving import pages as _pages
 from ..serving.model import CacheSpec, group_columns
 from . import _decoder, _experts, _latent
@@ -85,7 +99,8 @@ from . import _decoder, _experts, _latent
 __all__ = ["KimiLinearConfig", "init_params", "forward_logits",
            "prefill_pages", "decode_step_pages", "kda_chunked", "kda_step",
            "kda_step_inputs", "kda_state_update", "recurrence_path",
-           "KDA_CHUNK", "KIMI_LINEAR_SERVING_MODEL"]
+           "prefill_recurrence_path", "KDA_CHUNK",
+           "KIMI_LINEAR_SERVING_MODEL"]
 
 # Rows a chunk of the prefill's scan, and rows a sub-chunk inside which
 # decays are taken elementwise (the published kernels' sizes).
@@ -353,6 +368,33 @@ def recurrence_path(cfg):
     return "xla"
 
 
+def prefill_recurrence_path(cfg, bucket=None):
+    """ "kernel" where a prompt's chunked scan is the Pallas kernel
+    ops/kda_chunk.py: the step's rule, a bucket of whole 128-row tiles
+    (None: the verdict for such buckets) and heads in pairs; "xla"
+    (`kda_chunked`, plain `jax.numpy`) elsewhere: the CPU, odd widths."""
+    if recurrence_path(cfg) == "kernel" and cfg.kda_heads % 2 == 0 \
+            and _pages.kernel_beside(bucket=bucket):
+        return "kernel"
+    return "xla"
+
+
+# {cfg: {bucket: path}}: what `prefill_pages` took in each bucket it was
+# traced for, for `engine.stats()["state"]` to report what RAN
+_PREFILLS_TRACED = weakref.WeakKeyDictionary()
+
+
+def _prefill_paths_taken(cfg):
+    """("kernel" if a traced prefill of `cfg` ran the kernel in some bucket
+    else "xla", the buckets that did). Before any prefill is traced: the
+    rule's word for a bucket of whole tiles, and no bucket."""
+    traced = _PREFILLS_TRACED.get(cfg)
+    if not traced:
+        return prefill_recurrence_path(cfg), []
+    kernel = sorted(b for b, path in traced.items() if path == "kernel")
+    return ("kernel" if kernel else "xla"), kernel
+
+
 def _kda_project(cfg, lp, u):
     """`kda/project`: q|k|v before the convolution (T, 3C), the decay's
     pre-activation a (T, C), beta's b (T, n) and the gate's z (T, C)."""
@@ -508,11 +550,13 @@ def kda_chunked(q, k, v, g, beta, S0=None, chunk=KDA_CHUNK, sub=KDA_SUB):
     return o.transpose(0, 2, 1, 3).reshape(N * C, n, dv)[:T], S
 
 
-def _kda_prompt(cfg, lp, u, real_len):
+def _kda_prompt(cfg, lp, u, real_len, path):
     """A KDA layer's mixer over ONE sequence's rows u (B, h), `real_len`
-    of them real, from a zero state: (the mixer's output (B, h), the
-    state S at `real_len` (n, d, d) float32, the history at `real_len`
-    (K - 1, 3C))."""
+    of them real, from a zero state, the scan by `path`
+    (`prefill_recurrence_path`): (the mixer's output (B, h), the state S
+    at `real_len` (n, d, d) float32, the history at `real_len` (K - 1,
+    3C), the chunks the scan visited: the kernel passes by those wholly
+    past `real_len`)."""
     import jax
     import jax.numpy as jnp
     B = u.shape[0]
@@ -528,8 +572,13 @@ def _kda_prompt(cfg, lp, u, real_len):
         g = jnp.where(live[:, None, None], g, 0.0)
         beta = jnp.where(live[:, None], beta, 0.0)
     with jax.named_scope("kda/recur"):
-        o, S = kda_chunked(q, k, v, g, beta)
-    return _kda_gate(cfg, lp, o, z), S, hist
+        if path == "kernel":
+            from ..ops.kda_chunk import kda_chunk
+            o, S, visited = kda_chunk(q, k, v, g, beta, real_len=real_len)
+        else:
+            o, S = kda_chunked(q, k, v, g, beta)
+            visited = -(-B // KDA_CHUNK)
+    return _kda_gate(cfg, lp, o, z), S, hist, visited
 
 
 def _state_block(ids, done):
@@ -604,7 +653,8 @@ def _zero_counters(cfg):
     import jax.numpy as jnp
     zero = jnp.zeros((), jnp.int32)
     return dict(_experts.zero_counters(cfg), kda_state_steps=zero,
-                kda_prefill_rows=zero, mla_decode_rows=zero)
+                kda_prefill_rows=zero, kda_prefill_chunks=zero,
+                mla_decode_rows=zero)
 
 
 def _arenas(arena):
@@ -631,7 +681,8 @@ def forward_logits(params, cfg, tokens):
     for li, lp in enumerate(params["layers"]):
         u = _decoder.rms(x, lp["norm1"], cfg.rms_eps)
         if cfg.kind(li) == "kda":
-            y, _, _ = _kda_prompt(cfg, lp, u, T)
+            # the oracle, never the kernel it is held against
+            y = _kda_prompt(cfg, lp, u, T, "xla")[0]
         else:
             q_nope, q_rope, c, k_rope = _latent.project(cfg, lp, u, pos)
             k, v = _latent.expand(cfg, lp, c, k_rope)
@@ -665,17 +716,22 @@ def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
     rows = pages[cols[0]]
     state_id, conv_id = pages[cols[1]][0], pages[cols[2]][0]
     flash = _pages.kernel_beside(bucket=B)
+    recurrence = prefill_recurrence_path(cfg, B)
+    _PREFILLS_TRACED.setdefault(cfg, {})[B] = recurrence
     j = jnp.arange(B)
     pos = pfx_len + j
     live = j < real_len
     x = _decoder.embed(params, tokens[0], dtype)
     counters = _zero_counters(cfg)
+    chunks = 0
     for li, lp in enumerate(params["layers"]):
         lg = cfg.index_in_group(li)
         if cfg.kind(li) == "kda":
             with jax.named_scope("norm"):
                 u = _decoder.rms(x, lp["norm1"], cfg.rms_eps)
-            y, S, hist = _kda_prompt(cfg, lp, u, real_len)
+            y, S, hist, visited = _kda_prompt(cfg, lp, u, real_len,
+                                              recurrence)
+            chunks = chunks + visited
             with jax.named_scope("kda/recur"):
                 arenas[STATE] = arenas[STATE].at[lg, 0, state_id].set(
                     S.astype(arenas[STATE].dtype))
@@ -691,6 +747,7 @@ def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
         x = x + y
     counters["kda_prefill_rows"] = (real_len * len(cfg.kda_layers)
                                     ).astype(jnp.int32)
+    counters["kda_prefill_chunks"] = jnp.asarray(chunks, jnp.int32)
     last = x[real_len - 1][None]
     return _decoder.head(cfg, params, last), _arena_out(arenas), counters
 
@@ -756,7 +813,8 @@ class _KimiLinearServingModel(_experts.ExpertBlockModel):
     prefill_pages = staticmethod(prefill_pages)
     decode_step_pages = staticmethod(decode_step_pages)
 
-    own_counters = ("kda_state_steps", "kda_prefill_rows", "mla_decode_rows",
+    own_counters = ("kda_state_steps", "kda_prefill_rows",
+                    "kda_prefill_chunks", "mla_decode_rows",
                     "moe_picks_routed", "moe_picks_held",
                     "decode_moe_picks_routed", "decode_moe_picks_held")
 
@@ -771,11 +829,14 @@ class _KimiLinearServingModel(_experts.ExpertBlockModel):
 
     def describe(self, cfg):
         first, count = _experts.held_experts(cfg)
+        prefill_path, kernel_buckets = _prefill_paths_taken(cfg)
         return {"experts_held": {"first": first, "count": count,
                                  "of": cfg.n_routed_experts},
                 "vocab_slice": dict(zip(("first", "rows", "of"),
                                         cfg.vocab_slice)),
                 "state": {"recurrence_path": recurrence_path(cfg),
+                          "prefill_recurrence_path": prefill_path,
+                          "prefill_kernel_buckets": kernel_buckets,
                           "prefill_chunk_rows": KDA_CHUNK}}
 
     def _counters(self, cfg, c, decode):

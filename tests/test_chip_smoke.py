@@ -111,16 +111,20 @@ def test_block_diffusion_phase_interpreted():
 
 def test_state_group_phase_interpreted():
     """The phase at a small size with the kernel paths forced: the flash
-    forward and the latent paged kernel interpreted beside the step's
-    kernel over a state block, a prompt shorter than its bucket, two chunks
-    of steps."""
+    forward and the latent paged kernel interpreted beside the chunked
+    scan's kernel and the step's kernel over a state block, two prompts
+    shorter than their buckets (the longer one's last chunk passed by), a
+    chunk and a part of steps."""
     facts = chip_smoke.phase_state_group(
         hidden=128, heads=2, head_dim=128, rank=128, rope=128, width=128,
-        experts=4, vocab=256, prompt_len=130, max_new=10, bucket=256,
-        page=128, force_kernels=True)
+        experts=4, vocab=256, prompt_lens=(130, 70), max_news=(10, 3),
+        bucket=256, page=128, force_kernels=True)
     assert facts["state"]["recurrence_path"] == "kernel"
+    assert facts["state"]["prefill_recurrence_path"] == "kernel"
+    assert facts["state"]["prefill_kernel_buckets"] == [128, 256]
     assert facts["state"]["peak_blocks_used"] == 2
-    assert facts["kda_state_steps"] == 4 * 9
+    assert facts["kda_state_steps"] == 4 * (9 + 2)
+    assert facts["kda_prefill_chunks"] == 4 * (3 + 2)
     assert facts["max_logit_deficit"] <= chip_smoke.LOGIT_MARGIN
 
 

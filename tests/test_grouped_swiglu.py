@@ -368,3 +368,26 @@ def test_a_block_pass_kernels_compile_for_a_described_v5e(one_chip):
             shape(3072, heads, d), shape(3072, kvh, d), shape(3072, kvh, d),
             shape(dtype=jnp.int32)).compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("bucket", [512, 1024, 2048, 4096])
+def test_the_chunked_scans_kernel_compiles_for_a_described_v5e(one_chip, bucket):
+    """Kimi-Linear's widths (32 heads of 128 x 128, float32) in the four
+    buckets of its cell: the single-row reads, the (128, 128) transposes
+    and the `HIGHEST` products of ops/kda_chunk.py are Mosaic's to refuse,
+    and nothing of an operand's size is made beside the call."""
+    from paddle_tpu.ops import kda_chunk as kc
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    n, d = 32, 128
+    heads = kc.heads_a_step(n)
+    compiled = kc._call.lower(
+        shape(1, dtype=jnp.int32), shape(bucket, n * d), shape(bucket, n * d),
+        shape(bucket, n * d), shape(bucket, n * d),
+        shape(n // heads, bucket, heads), None, heads=heads,
+        interpret=False).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

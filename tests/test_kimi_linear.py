@@ -281,6 +281,7 @@ def test_the_engine_serves_the_references_greedy_tokens(params, p_len, new):
     assert st["decode_attention"] == {"latent": "gather"}
     assert st["kda_state_steps"] == 4 * (new - 1)
     assert st["kda_prefill_rows"] == 4 * p_len
+    assert st["kda_prefill_chunks"] == 4          # one chunk holds a bucket
     assert st["moe_picks_routed"] == 2 * st["router_tokens"]
     assert st["moe_picks_held"] == sum(st["expert_tokens"])
     assert st["prefix_cache"].startswith("off: a hit is valid only with")
@@ -404,7 +405,10 @@ def test_engine_stats_name_the_state(params):
     assert st["state"] == {"groups": ["state", "conv"], "blocks_total": 6,
                            "blocks_used": 0, "peak_blocks_used": 0,
                            "bytes_a_slot": 4 * 4 * 16 * 16 * 4 + 4 * 3 * 192 * 4,
-                           "recurrence_path": "xla", "prefill_chunk_rows": 64}
+                           "recurrence_path": "xla",
+                           "prefill_recurrence_path": "xla",
+                           "prefill_kernel_buckets": [],
+                           "prefill_chunk_rows": 64}
     assert [g["name"] for g in st["groups"]] == ["latent", "state", "conv"]
     assert st["prefill_attention"]["groups"] == {"latent": "gather"}
     eng.close()

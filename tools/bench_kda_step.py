@@ -9,10 +9,17 @@ The kernel is first held against the XLA form on the same chip (the arena
 compared whole but for scratch block 0). Beside each time stand the bytes
 a step must move (a live slot's state read once and written once: 2 x
 2,097,152 B a layer a slot) over the chip's 819 GB/s, and the share
-`kda_decode_hbm_roofline` would read. The CHUNKED PREFILL (`kda_chunked`)
-of 512 .. 4,096 rows, with its share of the peak as
+`kda_decode_hbm_roofline` would read. The CHUNKED PREFILL of 512 .. 4,096
+rows in both forms, plain `jax.numpy` (`kda_chunked`) and the kernel
+ops/kda_chunk.py, with its share of the peak as
 `kda_prefill_flops_roofline` counts it (the recurrence's own three
-products, 6 x 128 x 128 FLOPs a row a head).
+products, 6 x 128 x 128 FLOPs a row a head). THE NUMERIC HOLD (`hold`): at
+4,096 rows each form's `o` and final state against the token-by-token
+float32 scan (relative Frobenius error), beside a control whose products'
+operands are rounded to bfloat16; and the same under CORRELATED keys (one
+token repeated; a shared mean direction), slow decay and beta near 1, where
+the triangular system's matrix has powers past 1e9 and only a substitution
+keeps its digits (`hold.correlated`).
 
 Device time is the sum of the first chip's operations in a profiler trace;
 the host's clock a call stands beside it. A chip is required (`--tiny`
@@ -93,6 +100,7 @@ def main():
               file=sys.stderr)
         return 1
     from paddle_tpu.models import kimi_linear as kl
+    from paddle_tpu.ops.kda_chunk import kda_chunk
     from paddle_tpu.ops.kda_step import kda_step_blocks
     if args.precision:
         kl.KDA_PRECISION = args.precision
@@ -156,25 +164,71 @@ def main():
             "host_layer_us": host and host / layers * 1e6,
             "floor_us": floor * 1e6,
             "kda_decode_hbm_roofline": device and 100 * floor * layers / device}
-    for rows in ([32] if args.tiny else [int(r) for r in args.rows.split(",")]):
-        kk = jax.random.split(jax.random.PRNGKey(rows), 5)
-        pq = unit(jax.random.normal(kk[0], (rows, heads, d))) * d ** -0.5
-        pk = unit(jax.random.normal(kk[1], (rows, heads, d)))
-        pv = jax.random.normal(kk[2], (rows, heads, d))
-        pg = -jnp.exp(jax.random.uniform(kk[3], (rows, heads, d), minval=-7.0,
-                                         maxval=0.5))
-        pb = jax.nn.sigmoid(jax.random.normal(kk[4], (rows, heads)))
-        chunked = jax.jit(lambda *a: kl.kda_chunked(*a)[0])
-        device, host, largest = timed(lambda: chunked(pq, pk, pv, pg, pb), 4,
-                                      args.tiny)
-        flops = rows * heads * 6 * d * d
-        result["prefill"].append({
-            "rows": rows, "chunk": kl.KDA_CHUNK, "precision": kl.KDA_PRECISION,
-            "top_operations_us": largest,
-            "layer_us": device and device * 1e6,
-            "host_layer_us": host and host * 1e6, "flops_counted": flops,
-            "kda_prefill_flops_roofline": device
-            and 100 * flops / PEAK_FLOPS / device})
+    def operands(rows, seed):
+        kk = jax.random.split(jax.random.PRNGKey(seed), 5)
+        return (unit(jax.random.normal(kk[0], (rows, heads, d))) * d ** -0.5,
+                unit(jax.random.normal(kk[1], (rows, heads, d))),
+                jax.random.normal(kk[2], (rows, heads, d)),
+                -jnp.exp(jax.random.uniform(kk[3], (rows, heads, d),
+                                            minval=-7.0, maxval=0.5)),
+                jax.nn.sigmoid(jax.random.normal(kk[4], (rows, heads))))
+
+    forms = {"xla": jax.jit(lambda *a: kl.kda_chunked(*a)[:2]),
+             "kernel": jax.jit(lambda *a: kda_chunk(*a)[:2])}
+    for rows in ([64] if args.tiny else [int(r) for r in args.rows.split(",")]):
+        ops = operands(rows, rows)
+        for path, form in forms.items():
+            device, host, largest = timed(lambda: form(*ops)[0], 4, args.tiny)
+            flops = rows * heads * 6 * d * d
+            result["prefill"].append({
+                "rows": rows, "path": path, "chunk": kl.KDA_CHUNK,
+                "precision": kl.KDA_PRECISION, "top_operations_us": largest,
+                "layer_us": device and device * 1e6,
+                "host_layer_us": host and host * 1e6, "flops_counted": flops,
+                "kda_prefill_flops_roofline": device
+                and 100 * flops / PEAK_FLOPS / device})
+    # THE NUMERIC HOLD: both forms against the token-by-token float32 scan
+    # on the same rows, beside a control whose products' operands are
+    # rounded to bfloat16 (`reduce_precision`: the compiler drops an
+    # `astype` pair)
+    rows = 64 if args.tiny else 4096
+    ops = operands(rows, 46)
+
+    def scan(q, k, v, g, beta):
+        S, o = jax.lax.scan(lambda S, x: kl.kda_step(S, *x),
+                            jnp.zeros((heads, d, d), jnp.float32),
+                            (q, k, v, g, beta))
+        return o, S
+
+    def rounded(*a):
+        real = jnp.einsum
+        jnp.einsum = lambda spec, *xs, **kw: real(
+            spec, *(jax.lax.reduce_precision(x, 8, 7) for x in xs), **kw)
+        try:
+            return kl.kda_chunked(*a)[:2]
+        finally:
+            jnp.einsum = real
+
+    size = lambda a: float(jnp.sqrt(jnp.sum(a.astype(jnp.float32) ** 2)))
+
+    def held(ops, forms):
+        want = jax.jit(scan)(*ops)
+        return {path: {name: size(x - y) / size(y)
+                       for name, x, y in zip(("o", "state"), form(*ops), want)}
+                for path, form in forms.items()}
+
+    result["hold"] = dict(
+        held(ops, dict(forms, bfloat16_products=jax.jit(rounded))), rows=rows)
+    # keys that are NOT nearly orthogonal: one key row a head in every row
+    # (a run of one token behind the width-4 convolution), g = -1e-3, beta
+    # = 0.9; and random keys about a shared direction, slow random decays
+    q, k, v, g, beta = ops
+    one = jnp.broadcast_to(k[:1], k.shape)
+    result["hold"]["correlated"] = {
+        "one_token": held((one * d ** -0.5, one, v, jnp.full_like(g, -1e-3),
+                           jnp.full_like(beta, 0.9)), forms),
+        "shared_mean": held((q, unit(k + 3.0 * k[:1] * d ** 0.5), v,
+                             g * 2e-2, jax.nn.sigmoid(1.0 + beta)), forms)}
     line = json.dumps(result)
     print(line)
     if not args.tiny:
