@@ -8,9 +8,10 @@ keeps scores in VMEM, O(s) memory, with a custom VJP whose backward is also
 a Pallas kernel.
 
 Layout is (batch, seq, heads, head_dim) end-to-end — no transposes around
-the kernel. Row statistics (m, l, lse, delta) are stored lane-padded to 128
-(Mosaic tiling requires the last dim be a lane multiple or the full array
-dim). `attention()` dispatches: Pallas on TPU backends, the einsum
+the kernel. The forward's row statistics (m, l, lse) are stored lane-padded
+to 128 (Mosaic tiling requires the last dim be a lane multiple or the full
+array dim); the tiled backward takes delta as compact rows.
+`attention()` dispatches: Pallas on TPU backends, the einsum
 reference elsewhere (CPU tests) or when shapes are tiny/unaligned;
 impl='flash' forces the kernel (interpreted on CPU, an error on any
 other non-TPU backend).
@@ -61,23 +62,6 @@ def _lanes_to(x, n):
         return x[:, :n]
     assert n % _LANES == 0
     return jnp.tile(x, (1, n // _LANES))
-
-
-def _masked_scores(q, k, b_ref, k_idx, q_idx, block_q, block_k, kv_len,
-                   sm_scale, causal):
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
-    if b_ref is not None:
-        s = s + b_ref[0].astype(jnp.float32)       # (1, block_k) broadcast
-    col = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-           + k_idx * block_k)
-    s = jnp.where(col < kv_len, s, _NEG_INF)       # mask kv padding
-    if causal:
-        row = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-               + q_idx * block_q)
-        s = jnp.where(row >= col, s, _NEG_INF)
-    return s
 
 
 # ---------------------------------------------------------------------------
@@ -248,99 +232,141 @@ def _fwd_kernel(qt_ref, kt_ref, bits_ref, meta_ref, q_ref, k_ref, v_ref,
 
 
 # ---------------------------------------------------------------------------
-# Pallas backward kernels
+# Pallas backward kernel: one pass over the tile pairs that hold work
 # ---------------------------------------------------------------------------
+#
+# The tiled backward is ONE call whose grid is (heads, VISITS), a walk as
+# the forward's but KV-tile major: a KV tile's visits follow each other, its
+# query tiles in order, so `dk` and `dv` (and `db`) accumulate in float32
+# scratch over them and are written at the tile's last visit, while `dq`
+# accumulates in a float32 scratch of ALL the call's query rows, (rows, d),
+# and is written once, at the head's last visit (how many rows a call may
+# hold: `backward_span_rows`). A pair's scores, probabilities, `dp` and `ds`
+# exist once and feed all three gradients. They are held TRANSPOSED,
+# (block_k, block_q): `lse` and `delta` then broadcast from (1, block_q)
+# rows (`delta` comes as compact rows; the forward's lane-padded `lse` tile
+# is transposed here), a key's bias is a column, `dv = p^T do` and `dk =
+# ds^T q` are plain products, and only `dq = ds k` transposes its left
+# operand. The scores, the exponential against the saved `lse`, `dp - delta`
+# and `ds` are float32; `p` and `ds` take the TYPE OF THE OPERAND they meet
+# in a product, as the forward's `p` does (float32 operands: nothing is
+# rounded), and every product accumulates in float32. A square pair ON the
+# causal diagonal is taken in two halves of its keys, the upper half against
+# the upper half of its rows alone (the quarter above holds nothing).
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
-                    dk_ref, dv_ref, db_ref, dk_scr, dv_scr, db_scr, *,
-                    sm_scale, causal, block_q, block_k, kv_len):
+# beside _FIRST and _LAST, of a KV tile here: the pair lies ON the diagonal
+_DIAGONAL = 4
+
+_NT = (((1,), (1,)), ((), ()))          # a b^T
+_TN = (((0,), (0,)), ((), ()))          # a^T b
+
+
+def _diagonal_halves(causal, block_q, block_k):
+    """Whether a pair ON the diagonal is taken in two halves: square
+    tiles whose halves are whole lanes."""
+    return causal and block_q == block_k and block_q % 256 == 0
+
+
+def _bwd_walk(nq, nk, block_q, block_k, causal, row0=0):
+    """The backward's walk as numpy int32 arrays (query tile (V,), KV tile
+    (V,), bits (V,)), KV-tile major, over `nq` query tiles whose first row
+    is row `row0` of the sequence and `nk` KV tiles from column 0. Causal:
+    a KV tile is visited by the query tiles that hold a row at or below its
+    first column; one above every row (sk > sq) keeps the last query tile's
+    visit, which meets masks alone and stores zeros."""
+    first = np.zeros(nk, np.int64)
+    if causal:
+        first = np.clip((np.arange(nk) * block_k - row0) // block_q, 0, nq - 1)
+    visits = nq - first
+    upto = np.concatenate([[0], np.cumsum(visits)])
+    kt = np.repeat(np.arange(nk), visits)
+    at = np.arange(upto[-1]) - upto[kt]
+    qt = first[kt] + at
+    diagonal = (_diagonal_halves(causal, block_q, block_k)
+                & (row0 + qt * block_q == kt * block_k))
+    bits = (_FIRST * (at == 0) + _LAST * (at == visits[kt] - 1)
+            + _DIAGONAL * diagonal)
+    return qt.astype(np.int32), kt.astype(np.int32), bits.astype(np.int32)
+
+
+def _bwd_kernel(qt_ref, kt_ref, bits_ref, q_ref, k_ref, v_ref, b_ref,
+                do_ref, lse_ref, dl_ref, dq_ref, dk_ref, dv_ref, db_ref,
+                dq_scr, dk_scr, dv_scr, db_scr, *, sm_scale, causal,
+                block_q, block_k, kv_len, row0):
     from jax.experimental import pallas as pl
 
-    k_idx, q_idx = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+    i = pl.program_id(1)
+    q_idx, k_idx, bits = qt_ref[i], kt_ref[i], bits_ref[i]
 
-    @pl.when(q_idx == 0)
+    @pl.when(i == 0)
+    def _head():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    @pl.when((bits & _FIRST) != 0)
     def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
         if db_scr is not None:
-            db_scr[:] = jnp.zeros_like(db_scr)
+            db_scr[...] = jnp.zeros_like(db_scr)
 
-    def _compute():
-        q, v = q_ref[0], v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        s = _masked_scores(q, k_ref[0], b_ref, k_idx, q_idx,
-                           block_q, block_k, kv_len, sm_scale, causal)
-        p = jnp.exp(s - lse_ref[0][:, :1])           # (block_q, block_k)
-        dv_scr[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - dl_ref[0][:, :1]) * sm_scale
-        dk_scr[:] += jax.lax.dot_general(
-            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def _pair(keys=(0, block_k), rows=(0, block_q)):
+        """The keys [k0, k1) of the KV tile against the rows [r0, r1) of
+        the query tile."""
+        (k0, k1), (r0, r1) = keys, rows
+        q, do = q_ref[0, r0:r1, :], do_ref[0, r0:r1, :]
+        k, v = k_ref[0, k0:k1, :], v_ref[0, k0:k1, :]
+        st = jax.lax.dot_general(                  # (keys, rows)
+            k, q, _NT, preferred_element_type=jnp.float32) * sm_scale
+        if b_ref is not None:
+            st = st + b_ref[0, k0:k1, :1]          # a key's bias: a column
+        if causal or kv_len % block_k:
+            col = (jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+                   + (k_idx * block_k + k0))
+            keep = col < kv_len                    # kv padding
+            if causal:
+                row = (jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+                       + (q_idx * block_q + (row0 + r0)))
+                keep = keep & (row >= col)
+            st = jnp.where(keep, st, _NEG_INF)
+        pt = jnp.exp(st - lse_ref[0, r0:r1, :].T[:1])
+        dv_scr[k0:k1, :] += jnp.dot(pt.astype(do.dtype), do,
+                                    preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(
+            v, do, _NT, preferred_element_type=jnp.float32)
+        dst = pt * (dpt - dl_ref[0, :, r0:r1]) * sm_scale
         if db_scr is not None:
-            # per-key bias grad: sum of ds over query rows (note ds already
-            # carries sm_scale; the bias enters the scores unscaled, so
-            # divide it back out)
-            db_scr[:] += jnp.broadcast_to(
-                jnp.sum(ds, axis=0, keepdims=True) / sm_scale,
-                db_scr.shape)
+            # a key's bias grad: ds summed over the query rows (ds carries
+            # sm_scale; the bias enters the scores unscaled, so divide it
+            # back out)
+            db_scr[k0:k1, :] += jnp.sum(dst, axis=1, keepdims=True) / sm_scale
+        dk_scr[k0:k1, :] += jnp.dot(dst.astype(q.dtype), q,
+                                    preferred_element_type=jnp.float32)
+        at = pl.ds(pl.multiple_of(q_idx * block_q + r0, 128), r1 - r0)
+        dq_scr[at, :] += jax.lax.dot_general(
+            dst.astype(k.dtype), k, _TN, preferred_element_type=jnp.float32)
 
-    if causal:
-        @pl.when(q_idx * block_q + block_q - 1 >= k_idx * block_k)
-        def _():
-            _compute()
+    if _diagonal_halves(causal, block_q, block_k):
+        half = block_k // 2
+
+        @pl.when((bits & _DIAGONAL) != 0)
+        def _diagonal():
+            _pair(keys=(0, half))
+            _pair(keys=(half, block_k), rows=(half, block_q))
+
+        pl.when((bits & _DIAGONAL) == 0)(_pair)
     else:
-        _compute()
+        _pair()
 
-    @pl.when(q_idx == nq - 1)
+    @pl.when((bits & _LAST) != 0)
     def _fin():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
         if db_ref is not None:
-            db_ref[0] = db_scr[:]
+            db_ref[0] = db_scr[...]
 
-
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
-                   dq_ref, dq_scr, *, sm_scale, causal,
-                   block_q, block_k, kv_len):
-    from jax.experimental import pallas as pl
-
-    q_idx, k_idx = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(k_idx == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    def _compute():
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        s = _masked_scores(q, k, b_ref, k_idx, q_idx,
-                           block_q, block_k, kv_len, sm_scale, causal)
-        p = jnp.exp(s - lse_ref[0][:, :1])
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - dl_ref[0][:, :1]) * sm_scale
-        dq_scr[:] += jax.lax.dot_general(
-            ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        @pl.when(k_idx * block_k <= q_idx * block_q + block_q - 1)
-        def _():
-            _compute()
-    else:
-        _compute()
-
-    @pl.when(k_idx == nk - 1)
-    def _fin():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _out():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -669,125 +695,177 @@ def _flash_call(q, k, v, bias, causal, sm_scale, interpret, blocks=None,
     return o[:, :sq0], lse
 
 
-def _flash_bwd_call(q, k, v, bias, o, lse, do, causal, sm_scale, interpret):
-    """lse: lane-padded (bn, sq_pad, 128) from _flash_call."""
+# What the one-pass backward may hold in VMEM: Mosaic's own grant to a
+# kernel on a TPU v5e. Nothing more is asked for (`vmem_limit_bytes`).
+_BWD_VMEM = 16 * 2 ** 20
+
+
+def _tile_bytes(rows, cols, itemsize):
+    """A (rows, cols) buffer as VMEM holds it: whole (8 x 32 bit, 128)
+    tiles."""
+    sub = 8 * 4 // itemsize
+    return (-(-rows // sub) * sub) * (-(-cols // _LANES) * _LANES) * itemsize
+
+
+def _bwd_vmem_bytes(rows, d, itemsize, block_q, block_k):
+    """The bytes of VMEM the one-pass backward needs of a head whose dq
+    holds `rows` (padded) rows: every block twice (the pipeline's two
+    buffers), the scratch once, and the float32 (block_k, block_q) values
+    of a pair (the scores, p, dp, ds and a mask: six at once at most). A
+    key's bias and its gradient are counted whether or not one is given."""
+    qd = _tile_bytes(block_q, d, itemsize)
+    kd = _tile_bytes(block_k, d, itemsize)
+    key = _tile_bytes(block_k, _LANES, 4)
+    stats = _tile_bytes(block_q, _LANES, 4) + _tile_bytes(1, block_q, 4)
+    blocks = 2 * qd + stats + 4 * kd + 2 * key    # q do lse delta k v dk dv b db
+    blocks += _tile_bytes(rows, d, itemsize)      # dq, all the call's rows
+    scratch = _tile_bytes(rows, d, 4) + 2 * _tile_bytes(block_k, d, 4) + key
+    return 2 * blocks + scratch + 6 * block_q * block_k * 4
+
+
+def backward_span_rows(sq, sk, d, dtype):
+    """The query rows ONE call of the tiled backward takes of (sq, sk) rows
+    of width d: a pure function of what the call can see. All `sq` where a
+    head's float32 dq and its block in `dtype` fit `_BWD_VMEM` beside the
+    tiles (`_bwd_vmem_bytes`; at tiles of 512 up to 6,144 rows in bfloat16
+    and 3,072 in float32, at any d up to 128); a longer sequence in spans of
+    that many rows, a call each, every call the same kernel over its span's
+    rows and the keys they attend, `dk`, `dv` and `db` summed over the spans
+    in float32."""
+    block_q, block_k = _pick_blocks(sq, sk)
+    itemsize = jnp.dtype(dtype).itemsize
+    unit = max(block_q, block_k)         # a span starts on a tile of both
+    rows = -(-sq // unit) * unit
+    while rows > unit and _bwd_vmem_bytes(
+            rows, d, itemsize, block_q, block_k) > _BWD_VMEM:
+        rows -= unit
+    return rows
+
+
+def _bwd_span(q, k, v, bias, lse, dl, do, causal, sm_scale, interpret,
+              blocks, row0, kv_dtype):
+    """The one Mosaic call (the comment above `_bwd_kernel`) over the query
+    rows it is handed, rows `row0` on of the sequence, and the keys from 0.
+    q, do: (bn, rows, d); lse (bn, rows_pad, 128) and dl (bn, 1, rows_pad)
+    padded to the tile. Returns dq as q, dk and dv in `kv_dtype`, db (bn,
+    sk) float32 or None."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bn, sq0, d = q.shape
     sk0 = k.shape[1]
-    block_q, block_k = _pick_blocks(sq0, sk0)
-
-    dl = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-
+    block_q, block_k = blocks
     q = _pad_to(q, 1, block_q)
-    do_p = _pad_to(do, 1, block_q)
-    dl_p = jnp.broadcast_to(
-        _pad_to(dl, 1, block_q)[:, :, None],
-        (bn, q.shape[1], _LANES))
+    do = _pad_to(do, 1, block_q)
     k = _pad_to(k, 1, block_k)
     v = _pad_to(v, 1, block_k)
-    bias3 = None
-    if bias is not None:
-        bias3 = _pad_to(bias, 1, block_k)[:, None, :]
     sq, sk = q.shape[1], k.shape[1]
-    nq, nk = sq // block_q, sk // block_k
+    qt, kt, bits = _bwd_walk(sq // block_q, sk // block_k, block_q, block_k,
+                             causal, row0)
 
-    common_in = [q, k, v] + ([bias3] if bias3 is not None else []) \
-        + [do_p, lse, dl_p]
-    kw = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
-              block_k=block_k, kv_len=sk0)
+    q_map = lambda i, s, qt, kt, bits: (i, qt[s], 0)
+    row_map = lambda i, s, qt, kt, bits: (i, 0, qt[s])
+    kv_map = lambda i, s, qt, kt, bits: (i, kt[s], 0)
+    q_spec = pl.BlockSpec((1, block_q, d), q_map)
+    kv_spec = pl.BlockSpec((1, block_k, d), kv_map)
+    key_spec = pl.BlockSpec((1, block_k, _LANES), kv_map)
 
+    kern = functools.partial(
+        _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
+        block_k=block_k, kv_len=sk0, row0=row0)
+    args, in_specs = [q, k, v], [q_spec, kv_spec, kv_spec]
+    out_specs = [pl.BlockSpec((1, sq, d), lambda i, s, *_: (i, 0, 0)),
+                 kv_spec, kv_spec]
+    out_shape = [jax.ShapeDtypeStruct((bn, sq, d), q.dtype),
+                 jax.ShapeDtypeStruct((bn, sk, d), kv_dtype),
+                 jax.ShapeDtypeStruct((bn, sk, d), kv_dtype)]
+    scratch = [pltpu.VMEM((sq, d), jnp.float32),
+               pltpu.VMEM((block_k, d), jnp.float32),
+               pltpu.VMEM((block_k, d), jnp.float32)]
     if bias is not None:
-        dkv_kern = functools.partial(_bwd_dkv_kernel, **kw)
-        dq_kern = functools.partial(_bwd_dq_kernel, **kw)
+        # a key's bias and its grad ride lane-padded, a column a key
+        args.append(jnp.broadcast_to(
+            _pad_to(bias, 1, block_k).astype(jnp.float32)[:, :, None],
+            (bn, sk, _LANES)))
+        in_specs.append(key_spec)
+        out_specs.append(key_spec)
+        out_shape.append(jax.ShapeDtypeStruct((bn, sk, _LANES), jnp.float32))
+        scratch.append(pltpu.VMEM((block_k, _LANES), jnp.float32))
     else:
-        def dkv_kern(q_r, k_r, v_r, do_r, lse_r, dl_r, dk_r, dv_r, ks, vs):
-            _bwd_dkv_kernel(q_r, k_r, v_r, None, do_r, lse_r, dl_r,
-                            dk_r, dv_r, None, ks, vs, None, **kw)
+        def kern(*refs, kern=kern):
+            # no bias: neither its block, nor db's, nor db's scratch
+            kern(*refs[:6], None, *refs[6:12], None, *refs[12:], None)
+    args += [do, lse, dl]
+    in_specs += [q_spec, pl.BlockSpec((1, block_q, _LANES), q_map),
+                 pl.BlockSpec((1, 1, block_q), row_map)]
 
-        def dq_kern(q_r, k_r, v_r, do_r, lse_r, dl_r, dq_r, qs):
-            _bwd_dq_kernel(q_r, k_r, v_r, None, do_r, lse_r, dl_r,
-                           dq_r, qs, **kw)
-
-    # dk/dv: grid (bn, nk, nq)
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda i, kk, j: (i, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, kk, j: (i, kk, 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, kk, j: (i, kk, 0)),
-    ]
-    if bias is not None:
-        in_specs.append(pl.BlockSpec((1, 1, block_k),
-                                     lambda i, kk, j: (i, 0, kk)))
-    in_specs += [
-        pl.BlockSpec((1, block_q, d), lambda i, kk, j: (i, j, 0)),
-        pl.BlockSpec((1, block_q, _LANES), lambda i, kk, j: (i, j, 0)),
-        pl.BlockSpec((1, block_q, _LANES), lambda i, kk, j: (i, j, 0)),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, block_k, d), lambda i, kk, j: (i, kk, 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, kk, j: (i, kk, 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((bn, sk, d), k.dtype),
-        jax.ShapeDtypeStruct((bn, sk, d), v.dtype),
-    ]
-    scratch = [
-        pltpu.VMEM((block_k, d), jnp.float32),
-        pltpu.VMEM((block_k, d), jnp.float32),
-    ]
-    if bias is not None:
-        out_specs.append(pl.BlockSpec((1, 8, block_k),
-                                      lambda i, kk, j: (i, 0, kk)))
-        out_shape.append(jax.ShapeDtypeStruct((bn, 8, sk), jnp.float32))
-        scratch.append(pltpu.VMEM((8, block_k), jnp.float32))
     outs = pl.pallas_call(
-        dkv_kern,
-        grid=(bn, nk, nq),
-        in_specs=in_specs,
-        out_specs=out_specs,
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(bn, qt.shape[0]),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch),
         out_shape=out_shape,
-        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*common_in)
-    if bias is not None:
-        dk, dv, db8 = outs
-        db = db8[:, 0, :sk0]
-    else:
-        dk, dv = outs
-        db = None
-
-    # dq: grid (bn, nq, nk)
-    in_specs2 = [
-        pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
-    ]
-    if bias is not None:
-        in_specs2.append(pl.BlockSpec((1, 1, block_k),
-                                      lambda i, j, kk: (i, 0, kk)))
-    in_specs2 += [
-        pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
-        pl.BlockSpec((1, block_q, _LANES), lambda i, j, kk: (i, j, 0)),
-        pl.BlockSpec((1, block_q, _LANES), lambda i, j, kk: (i, j, 0)),
-    ]
-    dq, = pl.pallas_call(
-        dq_kern,
-        grid=(bn, nq, nk),
-        in_specs=in_specs2,
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((bn, sq, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(*common_in)
-
+        name="flash_bwd",
+    )(jnp.asarray(qt), jnp.asarray(kt), jnp.asarray(bits), *args)
+    dq, dk, dv = outs[:3]
+    db = outs[3][:, :sk0, 0] if bias is not None else None
     return dq[:, :sq0], dk[:, :sk0], dv[:, :sk0], db
+
+
+# jitted INLINE: a program that unrolls its layers traces the call and its
+# kernel once and replays the equations at each layer (tracing the kernel a
+# layer was seconds of a training step's set-up on four chips: PERF.md, PR
+# 48), and what the compiler is handed holds no call: the program is the one
+# an unjitted function would give
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("causal", "sm_scale", "interpret"))
+def _flash_bwd_call(q, k, v, bias, o, lse, do, causal, sm_scale, interpret):
+    """The tiled backward. q, o, do: (bn, sq, d); k, v: (bn, sk, d); bias
+    (bn, sk) or None; lse: lane-padded (bn, sq_pad, 128) from _flash_call.
+    Returns dq, dk, dv and db (bn, sk) or None. ONE Mosaic call where
+    `backward_span_rows` gives all the rows, else one a span of them."""
+    bn, sq0, d = q.shape
+    sk0 = k.shape[1]
+    blocks = _pick_blocks(sq0, sk0)
+    span = backward_span_rows(sq0, sk0, d, q.dtype)
+
+    # delta as compact rows, (bn, 1, sq): a pair broadcasts them down its
+    # (block_k, block_q) scores; lse stays as the forward left it
+    dl = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    dl = _pad_to(dl, 1, blocks[0])[:, None, :]
+    if span >= sq0:
+        # every key rides along: one above every row (causal, sk > sq)
+        # meets masks alone and comes back zero
+        return _bwd_span(q, k, v, bias, lse, dl, do, causal, sm_scale,
+                         interpret, blocks, 0, k.dtype)
+
+    dq = []
+    dk = jnp.zeros((bn, sk0, d), jnp.float32)
+    dv = jnp.zeros((bn, sk0, d), jnp.float32)
+    db = None if bias is None else jnp.zeros((bn, sk0), jnp.float32)
+    for r0 in range(0, sq0, span):
+        r1 = min(r0 + span, sq0)
+        # the keys a span attends: causal, those up to its last row
+        ks = min(sk0, r1) if causal else sk0
+        pad = -(-r1 // blocks[0]) * blocks[0]
+        dq_s, dk_s, dv_s, db_s = _bwd_span(
+            q[:, r0:r1], k[:, :ks], v[:, :ks],
+            None if bias is None else bias[:, :ks], lse[:, r0:pad],
+            dl[:, :, r0:pad], do[:, r0:r1], causal, sm_scale, interpret,
+            blocks, r0, jnp.float32)
+        dq.append(dq_s)
+        dk = dk.at[:, :ks].add(dk_s)
+        dv = dv.at[:, :ks].add(dv_s)
+        if bias is not None:
+            db = db.at[:, :ks].add(db_s)
+    return (jnp.concatenate(dq, axis=1), dk.astype(k.dtype),
+            dv.astype(v.dtype), db)
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 import paddle_tpu as pt
+from paddle_tpu.ops import flash_attention as fa
 from paddle_tpu.ops.flash_attention import mha_reference, flash_attention
 from paddle_tpu.parallel.ring import ring_attention, ulysses_attention
 
@@ -63,6 +64,206 @@ def test_flash_kernel_interpret(causal, with_bias):
                                         sm, True) ** 2).sum())(bk)
         np.testing.assert_allclose(np.asarray(db_fl), np.asarray(db_ref),
                                    atol=5e-4, rtol=5e-4)
+
+
+# A bfloat16 gradient of the tiled backward against the float32 reference on
+# the same rounded inputs, as a share of the reference's LARGEST entry.
+# bfloat16 keeps 8 bits, so one rounding moves a value by at most 2**-9 of
+# itself. Four roundings stand between the two gradients: the saved `o`
+# behind delta, `p` and `ds` where they enter a product, and the result. Were
+# all four aligned on the largest entry they would move it by 4 x 2**-9 =
+# 2**-7; a sum whose terms are larger than the sum can lose as much again, so
+# the limit is 2**-6 (1.6%). The interpreter reads 0.2-0.5% over these
+# shapes; a backward that dropped a tile pair or a mask is off by tens of
+# percent. `p` or `ds` COMPUTED in bfloat16 would still pass here: that is
+# held by the float32 cases, which run the same code at 5e-5, and by
+# `test_the_products_take_their_operands_type`.
+BF16_REL_TOL = 2.0 ** -6
+
+# (sq, sk): 1,024 at tiles of 512; 768 at tiles of 128; sq != sk; a length
+# that is no multiple of its tile (1,000 rows are padded to 8 x 128)
+TILED_SHAPES = [(1024, 1024), (768, 768), (512, 1024), (1000, 1000)]
+
+
+def _tiled_inputs(sq, sk, d, bias, dtype, heads=2):
+    rng = np.random.RandomState(sq + sk + d)
+    mk = lambda s: jnp.asarray(
+        rng.randn(1, s, heads, d).astype(np.float32)).astype(dtype)
+    q, k, v = mk(sq), mk(sk), mk(sk)
+    bias_k = None
+    if bias:
+        bias_k = jnp.asarray((rng.rand(1, sk) > 0.9).astype(np.float32)
+                             * -1e4)[:, None, None, :]
+    return q, k, v, bias_k
+
+
+def _hold_tiled_backward(sq, sk, d, causal, bias, dtype, heads=2):
+    """dq, dk, dv (and db) of `flash_attention`'s vjp, interpreted, against
+    `jax.grad` of `mha_reference` in float32 on the same inputs."""
+    q, k, v, bias_k = _tiled_inputs(sq, sk, d, bias, dtype, heads)
+    sm = 1.0 / np.sqrt(d)
+    f32 = lambda x: x.astype(jnp.float32)
+    wrt = (0, 1, 2, 3) if bias else (0, 1, 2)
+    want = jax.grad(
+        lambda q, k, v, b: (mha_reference(q, k, v, b, causal) ** 2).sum(),
+        argnums=wrt)(f32(q), f32(k), f32(v), bias_k)
+    got = jax.grad(
+        lambda q, k, v, b: (f32(flash_attention(q, k, v, b, causal, sm, True))
+                            ** 2).sum(), argnums=wrt)(q, k, v, bias_k)
+    for name, a, b in zip(("dq", "dk", "dv", "db"), got, want):
+        assert a.dtype == (jnp.float32 if name == "db" else dtype)
+        a, b = np.asarray(f32(a)), np.asarray(b)
+        if dtype == jnp.float32:
+            tol = 5e-4 if name == "db" else 5e-5
+            np.testing.assert_allclose(a, b, atol=tol, rtol=tol, err_msg=name)
+        else:
+            assert np.abs(a - b).max() < BF16_REL_TOL * np.abs(b).max(), name
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("sq,sk", TILED_SHAPES)
+@pytest.mark.parametrize("with_bias", [False, True])
+@pytest.mark.parametrize("causal", [False, True])
+def test_one_pass_backward_against_the_references_gradients(
+        causal, with_bias, sq, sk, dtype):
+    """The tiled backward (`_flash_bwd_call`: one Mosaic call that computes
+    a tile pair's s, p, dp and ds once for dq, dk, dv and db)."""
+    _hold_tiled_backward(sq, sk, 64, causal, with_bias, dtype)
+
+
+def _eqns(jaxpr, name):
+    """Every equation of primitive `name`, through sub-jaxprs and kernels."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _eqns(sub, name)
+    return found
+
+
+def _backward_jaxpr(heads, sq, sk, d, dtype, causal=True, bias=False):
+    def shape(*dims, dtype=dtype):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    tile = fa._pick_blocks(sq, sk)[0]      # the forward's: lse comes padded
+    rows = -(-sq // tile) * tile
+    return jax.make_jaxpr(
+        lambda q, k, v, b, o, lse, do: fa._flash_bwd_call(
+            q, k, v, b if bias else None, o, lse, do, causal, d ** -0.5,
+            True))(
+        shape(heads, sq, d), shape(heads, sk, d), shape(heads, sk, d),
+        shape(heads, sk, dtype=jnp.float32), shape(heads, sq, d),
+        shape(heads, rows, 128, dtype=jnp.float32), shape(heads, sq, d)).jaxpr
+
+
+@pytest.mark.parametrize("rows,calls", [(3072, 1), (3584, 2)])
+def test_the_dq_rule_on_each_side(rows, calls):
+    """`backward_span_rows`: float32 rows of width 64 at tiles of 512 keep a
+    head's whole float32 dq in VMEM up to 3,072 rows, ONE call; 3,584 rows
+    are two spans (3,072 + 512), a call each over the keys its rows attend,
+    dk, dv and db summed over them: the same gradients either way."""
+    assert fa.backward_span_rows(rows, rows, 64, jnp.float32) == 3072
+    jaxpr = _backward_jaxpr(1, rows, rows, 64, jnp.float32, bias=True)
+    found = _eqns(jaxpr, "pallas_call")
+    assert len(found) == calls
+    assert {eqn.params["name"] for eqn in found} == {"flash_bwd"}
+    _hold_tiled_backward(rows, rows, 64, True, True, jnp.float32, heads=1)
+
+
+def test_the_dq_rule_is_a_function_of_rows_width_and_type():
+    """What one call holds follows `_bwd_vmem_bytes` against Mosaic's grant:
+    a step of 512 rows costs 512 x 128 lanes x (4 + 2 x itemsize) bytes, d
+    below the lane width buys nothing, and the cells' layer is far inside."""
+    span = fa.backward_span_rows
+    assert span(1024, 1024, 64, jnp.bfloat16) == 1024      # the cells'
+    assert span(4096, 4096, 128, jnp.bfloat16) == 4096
+    for d in (64, 128):
+        assert span(65536, 65536, d, jnp.bfloat16) == 6144
+        assert span(65536, 65536, d, jnp.float32) == 3072
+    assert span(65536, 65536, 192, jnp.bfloat16) == 2048
+    for itemsize, rows in ((2, 6144), (4, 3072)):
+        fits = fa._bwd_vmem_bytes(rows, 128, itemsize, 512, 512)
+        over = fa._bwd_vmem_bytes(rows + 512, 128, itemsize, 512, 512)
+        assert fits <= fa._BWD_VMEM < over
+        assert over - fits == 512 * 128 * (4 + 2 * itemsize)
+    # tiles of 128 (768 rows): a span is a multiple of the tile
+    assert span(768, 768, 64, jnp.bfloat16) == 768
+
+
+def test_the_cells_backward_is_one_call_named_flash_bwd():
+    """8 x 12 heads of 1,024 rows x 64 in bfloat16, causal: ONE
+    `pallas_call` (the parent had two: dk/dv, then dq) named `flash_bwd`,
+    three visits a head on 512 x 512 tiles: (0, 0), (1, 0), (1, 1)."""
+    found = _eqns(_backward_jaxpr(96, 1024, 1024, 64, jnp.bfloat16),
+                  "pallas_call")
+    assert len(found) == 1
+    assert found[0].params["name"] == "flash_bwd"
+    assert found[0].params["grid_mapping"].grid == (96, 3)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_products_take_their_operands_type(dtype):
+    """float32 operands: `p` and `ds` stay float32, nothing in the backward
+    is rounded to fewer bits. bfloat16 operands: every product of the kernel
+    takes both operands in bfloat16 and accumulates in float32; the scores,
+    the exponential and ds's arithmetic stay float32."""
+    jaxpr = _backward_jaxpr(2, 1024, 1024, 64, dtype, bias=True)
+    kernel, = _eqns(jaxpr, "pallas_call")
+    dots = _eqns(kernel.params["jaxpr"], "dot_general")
+    # a whole pair and the diagonal's two halves, five products each
+    assert len(dots) == 15
+    for eqn in dots:
+        assert {v.aval.dtype for v in eqn.invars} == {jnp.dtype(dtype)}
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+    narrow = [eqn for eqn in _eqns(jaxpr, "convert_element_type")
+              if jnp.dtype(eqn.params["new_dtype"]).itemsize < 4
+              and jnp.issubdtype(eqn.params["new_dtype"], jnp.floating)]
+    if dtype == jnp.float32:
+        assert not narrow
+    for eqn in _eqns(kernel.params["jaxpr"], "exp"):
+        assert eqn.invars[0].aval.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("nq,nk,bq,bk,kv_len,row0", [
+    (2, 2, 512, 512, 1024, 0), (4, 4, 256, 256, 1024, 0),
+    (3, 5, 256, 256, 1280, 0), (5, 3, 256, 256, 768, 0),
+    (5, 5, 128, 128, 600, 0), (2, 4, 512, 256, 1000, 0),
+    (4, 2, 256, 512, 1024, 0), (1, 4, 512, 128, 512, 0),
+    (1, 7, 512, 512, 3584, 3072), (2, 6, 512, 512, 3072, 2048)])
+def test_the_backwards_walk_visits_the_pairs_that_hold_work(
+        causal, nq, nk, bq, bk, kv_len, row0):
+    """KV-tile major, a KV tile's query tiles in order; every pair that
+    holds an attended (row, column) once, and none other except the ONE
+    visit a KV tile above every row keeps (it meets masks alone and stores
+    zeros; the span loop hands none); the square pairs ON the diagonal
+    marked for their two halves, also in a span that starts at `row0`."""
+    qt, kt, bits = fa._bwd_walk(nq, nk, bq, bk, causal, row0)
+    row = row0 + np.arange(nq * bq)[:, None]
+    col = np.arange(nk * bk)[None, :]
+    keep = (col < kv_len) & ((row >= col) if causal else (row >= 0))
+    holds = keep.reshape(nq, bq, nk, bk).any((1, 3))
+
+    visited = set(zip(qt.tolist(), kt.tolist()))
+    assert len(visited) == len(qt)
+    assert np.all(np.diff(kt) >= 0)
+    for c in range(nk):
+        mine = kt == c
+        assert mine.sum() == max(holds[:, c].sum(), 1)
+        assert np.all(np.diff(qt[mine]) == 1) and qt[mine][-1] == nq - 1
+        assert (bits[mine] & fa._FIRST != 0).tolist() == \
+            [True] + [False] * (mine.sum() - 1)
+        assert (bits[mine] & fa._LAST != 0).tolist() == \
+            [False] * (mine.sum() - 1) + [True]
+        if holds[:, c].any():
+            assert set(qt[mine]) == set(np.nonzero(holds[:, c])[0])
+    for j, c, b in zip(qt, kt, bits):
+        halves = (causal and bq == bk and bq % 256 == 0
+                  and row0 + j * bq == c * bk)
+        assert bool(b & fa._DIAGONAL) == halves
 
 
 @pytest.mark.parametrize("causal", [False, True])

@@ -391,3 +391,44 @@ def test_the_chunked_scans_kernel_compiles_for_a_described_v5e(one_chip, bucket)
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("heads,sq,sk,d,dtype,causal,bias,calls", [
+    (96, 1024, 1024, 64, jnp.bfloat16, True, False, 1),
+    (8, 1024, 1024, 64, jnp.float32, True, True, 1),
+    (16, 1280, 768, 128, jnp.bfloat16, False, True, 1),
+    (16, 4096, 4096, 128, jnp.bfloat16, True, False, 1),
+    (2, 6144, 6144, 128, jnp.bfloat16, True, True, 1),
+    (2, 6656, 6656, 128, jnp.bfloat16, True, True, 2),
+], ids=["train_s1024", "float32_bias", "bert_1280x768_d128", "the_tools_4096",
+        "the_rules_edge", "past_the_rules_edge"])
+def test_the_flash_backward_compiles_for_a_described_v5e(
+        one_chip, heads, sq, sk, d, dtype, causal, bias, calls):
+    """The tiled backward (ops/flash_attention.py, PR 48) at the training
+    cells' layer, with the per-key bias in float32, off the 512 grid, at the
+    tool's long shape, and on both sides of `backward_span_rows`' edge: ONE
+    Mosaic call named `flash_bwd` while a head's float32 dq fits the VMEM
+    Mosaic grants (the compiler agrees with `_bwd_vmem_bytes` at the
+    largest head it admits), a call a span of rows beyond."""
+    from paddle_tpu.ops import flash_attention as fa
+
+    assert -(-sq // fa.backward_span_rows(sq, sk, d, dtype)) == calls
+
+    def shape(*dims, dtype=dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def backward(q, k, v, b, o, lse, do):
+        return fa._flash_bwd_call(q, k, v, b if bias else None, o, lse, do,
+                                  causal, d ** -0.5, False)
+
+    tile = fa._pick_blocks(sq, sk)[0]      # the forward's: lse comes padded
+    rows = -(-sq // tile) * tile
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(backward).lower(
+            shape(heads, sq, d), shape(heads, sk, d), shape(heads, sk, d),
+            shape(heads, sk, dtype=jnp.float32), shape(heads, sq, d),
+            shape(heads, rows, 128, dtype=jnp.float32),
+            shape(heads, sq, d)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
+    assert text.count("flash_bwd") >= calls
