@@ -2,16 +2,21 @@
 its in-graph counters, and the half of a serving class that every such
 block repeats.
 
-SIX CELLS run this file (`moe_time_share` 45-69% of their busy time in the
-first five: PERF.md, section 5): moonlight-longctx-offline,
+SEVEN CELLS run this file (`moe_time_share` 45-69% of their busy time in
+the first five: PERF.md, section 5): moonlight-longctx-offline,
 xing-longdoc-offline (models/moonlight.py), mellum-mixedlen-offline
 (models/mellum.py), command-a-reason-offline (models/command_a.py),
-sdar-blockgen-offline (models/sdar.py) and kimi-linear-longgen-offline
-(models/kimi_linear.py). A change here is a change to all six.
+sdar-blockgen-offline (models/sdar.py), kimi-linear-longgen-offline
+(models/kimi_linear.py) and longcat-flash-agentgen-offline
+(models/longcat_flash.py). A change here is a change to all seven.
 
-THE LAYER (`moe`). Every token goes to `experts_per_tok` of
-`n_routed_experts` SwiGLU experts (`route`) plus the shared experts, if
-any. The product is GROUPED over the rows that were routed (no capacity,
+THE LAYER (`moe`). Every token goes to `experts_per_tok` of the router's
+outputs (`route`, by one of THREE rules): `n_routed_experts` SwiGLU
+experts and, where the config has them, `zero_expert_num` IDENTITY
+experts behind them (a pick of one costs nothing: no row is laid out, no
+tile computed, its weight times the layer's own input is added in
+`moe/identity`), plus the shared experts, if any. The product is
+GROUPED over the rows that were routed (no capacity,
 none dropped, never every expert on every token), and those rows are LAID
 OUT ONCE, by counting (ops/grouped_swiglu.routed_positions; no sort):
 `moe/dispatch` gathers them there, `moe/experts` computes whole tiles of
@@ -24,13 +29,16 @@ term and rounds once: ONE sum with two carriers (`combine_path`).
 WHAT THE LAYER READS, all of it; the defaults are here and nowhere else.
 Of a config: `n_routed_experts`, `experts_per_tok`, `n_shared_experts` (0:
 no shared expert, none traced), required; `router_scoring`, "sigmoid" (the
-default) or "softmax"; `routed_scaling_factor`, 1.0 (sigmoid scoring
-alone); `shared_expert_combination`, "sum" (the default) or "average" (the
+default) or "softmax"; `router_renormalize`, True (the picks' weights
+over their sum); `routed_scaling_factor`, 1.0; `zero_expert_num`, 0 (the
+router's outputs past `n_routed_experts`: identity experts, held by
+every chip alike); `shared_expert_combination`, "sum" (the default) or
+"average" (the
 shared experts, stored as ONE SwiGLU n times as wide, over their count);
 `experts_held`, None (all) or (first, count), the routed experts this chip
-holds (a layer that holds a SHARE reads its products back pick by pick
-and adds a pick of an expert held elsewhere as 0 by a select:
-`_weighted_sum`);
+holds (a layer that holds a SHARE of the router's outputs reads its
+products back pick by pick and adds a pick of an expert held elsewhere,
+or of an identity expert, as 0 by a select: `_weighted_sum`);
 `rms_eps`, `ffn`'s norm alone. Of a layer's parameters `lp`:
 `router` (h, E) (its presence makes the layer a routed one), `router_bias`
 (E,) float32 where the picks are ranked with a correction bias, `w_gate`,
@@ -39,7 +47,8 @@ and adds a pick of an expert held elsewhere as 0 by a select:
 `norm2` and a dense layer's `gate`, `up`, `down`.
 
 Scopes: `moe/router`, `moe/dispatch`, `moe/experts`, `moe/shared`,
-`moe/combine`, `ffn/dense`. Counters: `zero_counters` under the layer's
+`moe/identity`, `moe/combine`, `ffn/dense`. Counters: `zero_counters`
+under the layer's
 names, `counter_names` / `counters` under the engine's.
 
 Imports no model and, at module level, no jax.
@@ -50,7 +59,8 @@ from __future__ import annotations
 from ..serving.model import ServingModel
 from . import _decoder
 
-__all__ = ["route", "held_experts", "expert_product_path", "grouped_experts",
+__all__ = ["route", "held_experts", "router_width", "expert_product_path",
+           "grouped_experts",
            "combine_path", "moe", "experts", "ffn", "swiglu", "swiglu_hidden",
            "zero_counters", "counter_names", "counters", "ExpertBlockModel",
            "COMBINE_KERNEL_FROM", "HELD_SLACK", "HELD_SPLIT_FROM"]
@@ -70,25 +80,30 @@ def swiglu(x, gate, up, down):
 
 def route(cfg, lp, x):
     """The router. x (T, h) -> (picks (T, k) int32, weights (T, k)
-    float32), by the config's scoring rule. "sigmoid": scores are
-    sigmoid(x W_g) in float32; the k largest of
-    score + correction bias are picked (one group, so no group stage; a
-    layer without `router_bias` has no bias: the scores themselves are
-    ranked); the weights are the scores WITHOUT the bias at the picks,
-    over their sum + 1e-20, times routed_scaling_factor. "softmax":
-    scores are softmax(x W_g) over the experts in float32, the k largest
-    are picked and their scores divided by their sum (no bias, no
-    factor). The router is as wide as the MODEL has experts, whichever
-    of them this chip holds (`held_experts`)."""
+    float32), by the config's keys; the scores are float32. THREE rules
+    are served, and a key each, not a model's name, tells them apart:
+      * "sigmoid" scoring (the default): scores sigmoid(x W_g); the k
+        largest of score + correction bias are picked (one group, so no
+        group stage; a layer without `router_bias` has no bias: the
+        scores themselves are ranked); the weights are the scores
+        WITHOUT the bias at the picks, over their sum + 1e-20, times
+        `routed_scaling_factor`;
+      * "softmax" scoring: scores softmax(x W_g) over the router's
+        outputs, the k largest picked and their scores divided by their
+        sum (no `router_bias` in the layer, no factor in the config);
+      * "softmax" with `router_bias` in the layer, `router_renormalize`
+        False and a factor: the bias RANKS (the k largest of score +
+        bias) and does not weigh (the weights are the scores at the
+        picks), nothing is renormalised, and the weights are times
+        `routed_scaling_factor`.
+    The router is as wide as the MODEL has outputs (`router_width`),
+    whichever of its experts this chip holds (`held_experts`)."""
     import jax
     import jax.numpy as jnp
     logits = jnp.dot(x.astype(jnp.float32), lp["router"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    if getattr(cfg, "router_scoring", "sigmoid") == "softmax":
-        w, picks = jax.lax.top_k(jax.nn.softmax(logits, -1),
-                                 cfg.experts_per_tok)
-        return picks.astype(jnp.int32), w / w.sum(-1, keepdims=True)
-    scores = jax.nn.sigmoid(logits)
+    softmax = getattr(cfg, "router_scoring", "sigmoid") == "softmax"
+    scores = jax.nn.softmax(logits, -1) if softmax else jax.nn.sigmoid(logits)
     if "router_bias" in lp:
         _, picks = jax.lax.top_k(
             scores + lp["router_bias"].astype(jnp.float32),
@@ -96,7 +111,9 @@ def route(cfg, lp, x):
         w = jnp.take_along_axis(scores, picks, -1)
     else:
         w, picks = jax.lax.top_k(scores, cfg.experts_per_tok)
-    w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    if getattr(cfg, "router_renormalize", True):
+        total = w.sum(-1, keepdims=True)
+        w = w / (total if softmax else total + 1e-20)
     factor = getattr(cfg, "routed_scaling_factor", 1.0)
     if factor != 1.0:
         w = w * factor
@@ -106,12 +123,22 @@ def route(cfg, lp, x):
 def held_experts(cfg):
     """(first, count): the routed experts this chip holds, ids first ..
     first + count - 1 of `cfg.n_routed_experts`. The chip's share
-    of an expert-parallel deployment: the router scores every expert of
+    of an expert-parallel deployment: the router scores every output of
     the model, the layer lays out and computes the picks that fall on
     its own, and what the experts held elsewhere would add is left out
-    (no code stands in for the other chips or their exchange)."""
+    (no code stands in for the other chips or their exchange). An
+    IDENTITY pick (an output from `n_routed_experts` on, of
+    `zero_expert_num`) is nobody's share: it holds no weight, is applied
+    where the token lives and is added by every chip alike."""
     held = getattr(cfg, "experts_held", None)
     return (0, cfg.n_routed_experts) if held is None else tuple(held)
+
+
+def router_width(cfg):
+    """Outputs the router scores: the model's routed experts and, behind
+    them, its identity experts (`zero_expert_num`, 0 where a config has
+    none)."""
+    return cfg.n_routed_experts + getattr(cfg, "zero_expert_num", 0)
 
 
 def expert_product_path(lp):
@@ -170,8 +197,10 @@ def combine_path(lp, x, rows):
     return "gather"
 
 
-# A layer that holds `count` of E experts gets T * k * count / E picks on
-# average and T * k at the worst. Its routed buffer is sized for
+# A layer that holds `count` of the router's E outputs (`router_width`:
+# the identity experts count among them and are never held) gets T * k *
+# count / E picks on average and T * k at the worst. Its routed buffer is
+# sized for
 # HELD_SLACK times the average (a SECOND STATIC SIZE beside the worst
 # case's), so that dispatch's gather, the kernel's grid and the buffer
 # combine reads out of follow the picks that are held (XLA's combine
@@ -179,8 +208,9 @@ def combine_path(lp, x, rows):
 # the kernel's fetches the held ones alone); a pass
 # whose held picks do not fit there (their groups, each rounded up to
 # the tile) takes the other branch of a `lax.cond`, the same code over
-# the tokens in E / (count * HELD_SLACK) parts, each of which fits
-# whatever its routing: no pick is ever dropped. Below HELD_SPLIT_FROM
+# the tokens in E / (count * HELD_SLACK) parts (the power of two at or
+# below it: a bucket's rows divide by it), each of which fits whatever
+# its routing: no pick is ever dropped. Below HELD_SPLIT_FROM
 # picks (a decode step) the worst case is a few hundred rows and the one
 # buffer holds it.
 HELD_SLACK = 2
@@ -297,11 +327,19 @@ def moe(cfg, lp, x, live):
     import jax
     import jax.numpy as jnp
     from ..ops.grouped_swiglu import padded_rows, row_tile_for
-    T, k, E = x.shape[0], cfg.experts_per_tok, cfg.n_routed_experts
+    T, k, E = x.shape[0], cfg.experts_per_tok, router_width(cfg)
     first, held = held_experts(cfg)
     tile = row_tile_for(T * k, E)
     with jax.named_scope("moe/router"):
         picks, w = route(cfg, lp, x)
+    identity = None
+    if E > cfg.n_routed_experts:
+        with jax.named_scope("moe/identity"):
+            # an identity expert returns its input: the picks' weights
+            # times the layer's own input, in float32, added at the sum's end
+            free = picks >= cfg.n_routed_experts
+            identity = jnp.sum(jnp.where(free, w, 0), -1, keepdims=True) \
+                * x.astype(jnp.float32)
     mine, slots, average, parts = live, T * k, T * k, 1
     if held < E:
         picks = picks - first
@@ -311,6 +349,7 @@ def moe(cfg, lp, x, live):
         w = jnp.where(mine, w, 0)
         average = -(-T * k * held // E)
         parts = max(1, E // (held * HELD_SLACK))
+        parts = 1 << (parts.bit_length() - 1)
         if T * k < HELD_SPLIT_FROM or T % parts:
             parts = 1
     if parts > 1:
@@ -364,6 +403,13 @@ def moe(cfg, lp, x, live):
             shared = hidden @ lp["shared_down"]
         if getattr(cfg, "shared_expert_combination", "sum") == "average":
             scale = 1.0 / cfg.n_shared_experts
+    if identity is not None:
+        # the identity picks' term rides where the shared experts' does
+        # (float32, added once at the sum's end)
+        if shared is not None:
+            shared = shared.astype(jnp.float32)
+            identity = identity + (shared if scale is None else shared * scale)
+        shared, scale = identity, None
     with jax.named_scope("moe/combine"):
         if parts == 1:
             y = _combine(ys, pos, w, live, shared, scale, x.dtype, by_dma,
@@ -382,6 +428,20 @@ def moe(cfg, lp, x, live):
                 "rows_computed": jnp.sum(-(-group_sizes // tile)) * tile
                 if kernel else zero,
                 "combine_kernel_passes": passes if by_dma else zero}
+    if identity is not None:
+        # of the live tokens' k picks: identity experts', real experts'
+        # (held here or not) and the held ones'; and the tokens by how many
+        # of their picks were real experts (0..k: the products a token
+        # costs the deployment)
+        real = jnp.where(live, k - jnp.sum(free, -1, dtype=jnp.int32), -1)
+        n_real = jnp.sum(jnp.maximum(real, 0))
+        counters.update(
+            moe_identity_picks=counters["router_tokens"] * k - n_real,
+            moe_expert_picks=n_real,
+            moe_held_picks=jnp.sum(group_sizes).astype(jnp.int32),
+            moe_real_picks_hist=jnp.sum(
+                real[:, None] == jnp.arange(k + 1, dtype=jnp.int32), 0,
+                dtype=jnp.int32))
     return y, counters
 
 
@@ -423,6 +483,21 @@ _BOTH = {"expert_tokens": "expert_tokens", "router_tokens": "router_tokens",
 _DECODE = {"router_tokens": "decode_router_tokens",
            "experts_touched": "decode_experts_touched",
            "moe_passes": "decode_moe_passes"}
+# A layer with identity experts (`zero_expert_num`) also counts, under the
+# engine's own names, by both programs since start: moe_identity_picks,
+# moe_expert_picks (picks of real experts, held here or not),
+# moe_held_picks (those that fell on an expert held here) and
+# moe_real_picks_hist[n]: live tokens n of whose k picks were real experts
+# (each layer counts; it sums to router_tokens).
+_IDENTITY = ("moe_identity_picks", "moe_expert_picks", "moe_held_picks")
+
+
+def _identity_counters(cfg):
+    """{name: shape} of the counters a layer with identity experts adds."""
+    if router_width(cfg) == cfg.n_routed_experts:
+        return {}
+    return dict({name: () for name in _IDENTITY},
+                moe_real_picks_hist=(cfg.experts_per_tok + 1,))
 
 
 def zero_counters(cfg):
@@ -430,13 +505,16 @@ def zero_counters(cfg):
     import jax.numpy as jnp
     zero = jnp.zeros((), jnp.int32)
     return dict({name: zero for name in (*_BOTH, *_DECODE)},
-                expert_tokens=jnp.zeros((held_experts(cfg)[1],), jnp.int32))
+                expert_tokens=jnp.zeros((held_experts(cfg)[1],), jnp.int32),
+                **{name: jnp.zeros(shape, jnp.int32)
+                   for name, shape in _identity_counters(cfg).items()})
 
 
 def counter_names(cfg):
     """{name: shape} of the layer's counters as the engine reports them."""
     return dict({name: () for name in (*_BOTH.values(), *_DECODE.values())},
-                expert_tokens=(held_experts(cfg)[1],))
+                expert_tokens=(held_experts(cfg)[1],),
+                **_identity_counters(cfg))
 
 
 def counters(c, decode):

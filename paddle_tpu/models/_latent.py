@@ -18,9 +18,15 @@ and the latent layers of a hybrid model (models/kimi_linear.py) share.
 What this module reads of a config: `heads`, `kv_lora_rank`,
 `qk_nope_head_dim`, `qk_rope_head_dim`, `qk_head_dim`, `v_head_dim`,
 `row_values`, `row_width`, `rms_eps`, `rope_theta`, `rope_scaling`,
-`q_lora_rank` (None: the query is one matrix) and `mla_use_nope` (False
-where a config does not say): the published key of a model whose latent
-layers carry NO positions. The 64 "rope" values of the query and of the
+`q_lora_rank` (None: the query is one matrix), `mla_q_scale` and
+`mla_kv_scale` (1.0 where a config does not say: what the query, both its
+parts before rotation, and the normed latent c are multiplied by; a model
+published with `mla_scale_q_lora` / `mla_scale_kv_lora` sets them to
+sqrt(hidden / q_lora_rank) and sqrt(hidden / kv_lora_rank); the shared
+rope key is never scaled, and the cached row holds the SCALED latent) and
+`mla_use_nope` (False where a config does not say): the published key of a
+model whose latent layers carry NO positions. The 64 "rope" values of
+the query and of the
 key are then projected, cached and scored UNROTATED: the row, the kernels
 and every width stay what they are, and with the key off the programs
 trace what they traced (tests/test_served_programs.py).
@@ -57,14 +63,20 @@ def project(cfg, lp, x, pos):
     (T,): q_nope (T, n, nope), q_rope (T, n, rope) rotated, and the cache
     row's two parts, c (T, rank) normed and k_rope (T, rope) rotated.
     The query is one matrix, or (`q_lora_rank`) a low-rank pair with a
-    norm between. Under `mla_use_nope` nothing is rotated."""
+    norm between. Under `mla_use_nope` nothing is rotated. `mla_q_scale`
+    and `mla_kv_scale` multiply q and c: folded into the float32 weight of
+    the norm each stands behind (one rounding, not two), or applied to q
+    where the query is one matrix."""
     T = x.shape[0]
     n, nope = cfg.heads, cfg.qk_nope_head_dim
     theta, scaling = cfg.rope_theta, cfg.rope_scaling
+    q_scale = getattr(cfg, "mla_q_scale", 1.0)
     if cfg.q_lora_rank is None:
         q = x @ lp["wq"]
+        if q_scale != 1.0:
+            q = q * q_scale
     else:
-        q = _decoder.rms(x @ lp["wqa"], lp["q_norm"],
+        q = _decoder.rms(x @ lp["wqa"], _scaled(lp["q_norm"], q_scale),
                          cfg.rms_eps) @ lp["wqb"]
     q = q.reshape(T, n, cfg.qk_head_dim)
     rotate = not getattr(cfg, "mla_use_nope", False)
@@ -72,11 +84,20 @@ def project(cfg, lp, x, pos):
     if rotate:
         q_rope = _decoder.rope(q_rope, pos[:, None], theta, scaling)
     kva = x @ lp["wkva"]
-    c = _decoder.rms(kva[:, :cfg.kv_lora_rank], lp["kv_norm"], cfg.rms_eps)
+    c = _decoder.rms(kva[:, :cfg.kv_lora_rank],
+                     _scaled(lp["kv_norm"], getattr(cfg, "mla_kv_scale", 1.0)),
+                     cfg.rms_eps)
     k_rope = kva[:, cfg.kv_lora_rank:]
     if rotate:
         k_rope = _decoder.rope(k_rope, pos, theta, scaling)
     return q_nope, q_rope, c, k_rope
+
+
+def _scaled(g, scale):
+    """A norm's weight times a published scale, in float32 (`rms` works
+    there); the weight itself where the scale is 1."""
+    import jax.numpy as jnp
+    return g if scale == 1.0 else g.astype(jnp.float32) * scale
 
 
 def cache_rows(cfg, c, k_rope):
@@ -168,13 +189,23 @@ def prefill_attend(cfg, lp, u, j, pos, arena, li, pages, pfx_len, real_len,
             cfg, lp, cached[:, :cfg.kv_lora_rank],
             cached[:, cfg.kv_lora_rank:cfg.row_values])
         return _decoder.masked_attention(
-            q, k, v, jnp.arange(L)[None, :] <= pos[:, None], scale)
+            q, k, v, jnp.arange(L)[None, :] <= pos[:, None], scale,
+            _warm_query_rows(cfg))
 
     with jax.named_scope("mla/attend"):
         o = cold(arena) if cold_only else \
             _pages.cold_or_warm(pfx_len, cold, warm, arena)
     with jax.named_scope("mla/project"):
         return o.reshape(B, -1) @ lp["wo"], arena
+
+
+def _warm_query_rows(cfg):
+    """Query rows a block of the warm prefill's masked attention: 512 up to
+    32 heads; beyond, as many as keep a block's float32 scores of ALL heads
+    over the page row what 32 heads make of 512 (64 heads: 256; at 512 the
+    scores of a 5,120-row page row were 671 MB a block, and the 4,096-row
+    prefill did not fit beside LongCat-Flash's weights and arena)."""
+    return 512 if cfg.heads <= 32 else max(128, 512 * 32 // cfg.heads)
 
 
 def step_attend(cfg, lp, u, ts, arena, li, pt, done, attention):

@@ -4,7 +4,7 @@
   serving/model.py, serving/pages.py                       the interface
   models/_decoder.py, _experts.py, _grouped.py, _latent.py shared pieces
   models/{gpt_decode, moonlight, mellum, command_a, sdar,
-          kimi_linear}                                     leaves
+          kimi_linear, longcat_flash}                      leaves
 
 R1: no leaf imports another leaf. R2: a module under models/ imports from
 serving/ only `model` and `pages`. R3: a leaf takes the shared pieces as
@@ -29,7 +29,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODELS = os.path.join(ROOT, "paddle_tpu", "models")
 LEAVES = ("gpt_decode", "moonlight", "mellum", "command_a", "sdar",
-          "kimi_linear")
+          "kimi_linear", "longcat_flash")
 SHARED = ("_decoder", "_experts", "_grouped", "_latent")
 SERVED = LEAVES + SHARED
 

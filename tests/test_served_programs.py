@@ -25,6 +25,12 @@ it; the file names only addresses that both trees have). A case is
   * `<model>.init`: sha256 of the bytes of the float32 weights a seed
     draws, leaves in sorted order.
 
+Kimi-Linear's rows were computed at 4c84304 (PR 48) by PR 50, which gave
+`models/_experts.route` its third rule, `moe` the identity pick and
+`models/_latent.project` its two scales: with the new keys absent every
+program of the six older models traces what it traced. `longcat_flash.*`
+(PR 50's leaf) is its own tree's, the first that has it.
+
 `command_a.prefill.tpu` and `command_a.decode.tpu` were computed again at PR
 45 (its review round): a layer that holds a SHARE of the experts now sums its
 picks by a select, pick by pick (`models/_experts._weighted_sum`), so that a
@@ -96,12 +102,23 @@ PARENT = {
     "sdar.block.kernel": "2b1f8c44da608615",
     "sdar.prefill.tpu": "5d9996e6bffff0f8",
     "sdar.block.tpu": "d7eb2cbb142f7631",
+    "kimi_linear.prefill": "e1a60541d88a0464",
+    "kimi_linear.decode": "1dc7ee60c3e0add4",
+    "kimi_linear.prefill.tpu": "5c56d048f319ead7",
+    "kimi_linear.decode.tpu": "de2cc0338d01d47d",
+    "longcat_flash.prefill": "3c68f19406e8f493",
+    "longcat_flash.decode": "5154a249c2274267",
+    "longcat_flash.decode.kernel": "67710daedcec2457",
+    "longcat_flash.prefill.tpu": "6f578ef9457cd909",
+    "longcat_flash.decode.tpu": "4a49cfa8a10bf9a2",
     "gpt.init": "ea19118c5abc3d80",
     "moonlight.init": "7afc17e1dfebb9b0",
     "xing.init": "a3aa526a0907c2bd",
     "mellum.init": "a90678c41fef4f5f",
     "command_a.init": "8fb555e52d9ebabe",
     "sdar.init": "cb8aeed9efda9803",
+    "kimi_linear.init": "a979e4e58ac39a0a",
+    "longcat_flash.init": "d2b6fc261e496288",
 }
 
 _YARN = {"type": "yarn", "factor": 4, "beta_fast": 32, "beta_slow": 1,
@@ -147,6 +164,29 @@ def _config(model, wide):
             n_shared_experts=2, experts_per_tok=4, experts_held=(4, 4),
             vocab_slice=(0, 96, 768), sliding_window=8,
             max_pos=256), init_params
+    if model == "kimi_linear":
+        from paddle_tpu.models.kimi_linear import (KimiLinearConfig,
+                                                   init_params)
+        return KimiLinearConfig(
+            vocab_size=96, hidden=128 if wide else 64, layers=5, heads=4,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, kda_heads=2, kda_head_dim=128 if wide else 16,
+            intermediate=96, moe_intermediate=128 if wide else 32,
+            n_routed_experts=8, n_shared_experts=1, experts_per_tok=2,
+            experts_held=(2, 4), vocab_slice=(96, 96, 768),
+            kda_decay_rank=16, kda_gate_rank=16, max_pos=256,
+            init_range=0.08), init_params
+    if model == "longcat_flash":
+        from paddle_tpu.models.longcat_flash import (LongcatFlashConfig,
+                                                     init_params)
+        return LongcatFlashConfig(
+            vocab_size=96, hidden=128 if wide else 64, layers=2, heads=4,
+            q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, intermediate=96,
+            moe_intermediate=128 if wide else 32, n_routed_experts=8,
+            zero_expert_num=4, experts_per_tok=4, experts_held=(2, 2),
+            vocab_slice=(96, 96, 768), max_pos=256,
+            init_range=0.08), init_params
     from paddle_tpu.models.sdar import SdarConfig, init_params
     return SdarConfig(
         vocab_size=211, hidden=128 if wide else 64, layers=2, heads=4,
@@ -287,8 +327,14 @@ CASES = (
     + [f"sdar.{program}"
        for program in ("prefill", "block", "block.kernel", "prefill.tpu",
                        "block.tpu")]
+    + [f"kimi_linear.{program}"
+       for program in ("prefill", "decode", "prefill.tpu", "decode.tpu")]
+    + [f"longcat_flash.{program}"
+       for program in ("prefill", "decode", "decode.kernel", "prefill.tpu",
+                       "decode.tpu")]
     + [f"{model}.init" for model in ("gpt", "moonlight", "xing", "mellum",
-                                     "command_a", "sdar")])
+                                     "command_a", "sdar", "kimi_linear",
+                                     "longcat_flash")])
 
 
 @pytest.mark.parametrize("name", CASES)
