@@ -11,7 +11,10 @@ program's seed:
   2. kernels    both Pallas flash families, forward and backward, bf16,
                 12 heads x 64, against mha_reference
   3. train      gpt_lm_program -> pt.Executor, a few steps per shape;
-                the compiled step must contain the Mosaic custom call
+                the compiled step must contain the Mosaic custom call,
+                its loss gradient must come from the saved log-sum-exp
+                (`loss_lowerings`), and at s=1024 the first loss is held
+                against the training cells' float32 reference
   4. serve      pt.server.serve over those weights in bf16; concurrent
                 POST /v1/generate (SSE and JSON), health counters, and
                 every greedy token checked on the float32 reference's
@@ -68,6 +71,14 @@ LOGIT_MARGIN = 0.1
 # sign flips of tiny gradients. That is worth parts in 1e5; a mis-sharded
 # batch or a missing all-reduce shows up as percents.
 DP_LOSS_REL_TOL = 1e-3
+
+
+# The first training loss (bfloat16 products under AMP, float32 softmax
+# and mean) against benchmarks/reference/gpt2_ref.batch_loss in float32
+# on the same weights and batch, relative: the training cells' own limit
+# (benchmarks/modes/train.py LOSS_REL_TOL). Parts in 1e5 are bfloat16
+# rounding; a loss op that lost its float32 inside reads percents.
+LOSS_REL_TOL = 2e-4
 
 
 class SmokeFailure(AssertionError):
@@ -547,7 +558,8 @@ def phase_state_group(hidden=256, heads=2, head_dim=128, rank=128, rope=128,
 # ---------------------------------------------------------------------------
 
 def phase_train(cfg, batch, seq, steps=3, learning_rate=1e-4,
-                data_parallel=False, one_chip_losses=None):
+                data_parallel=False, one_chip_losses=None,
+                reference=False):
     """`steps` Adam steps of the causal-LM program on ONE repeated batch
     through pt.Executor (bf16 AMP, dropout must be 0 in cfg so that the
     loss falling is not left to luck). data_parallel runs the same step
@@ -557,7 +569,13 @@ def phase_train(cfg, batch, seq, steps=3, learning_rate=1e-4,
     how many Mosaic custom calls the compiled step holds, and per step
     how many scope variables the executor handed to its plan's _put
     (`scope_vars_placed`) with the runs that found every one in place:
-    only the first step after the startup program may place any."""
+    only the first step after the startup program may place any.
+    `loss_lowerings` is how often the step's loss gradient was lowered
+    from the saved row log-sum-exp and how often in a program that keeps
+    a vocabulary-wide softmax: the causal-LM program must read (>= 1, 0).
+    reference=True also holds the first loss against the float32
+    reference of the training cells on the same weights and batch
+    (`reference_loss`, `loss_rel_gap`, limit LOSS_REL_TOL)."""
     import jax
     import jax.numpy as jnp
     import paddle_tpu as pt
@@ -582,8 +600,15 @@ def phase_train(cfg, batch, seq, steps=3, learning_rate=1e-4,
     exe = pt.Executor()
     scope = pt.Scope()
     losses, placed_by_step = [], []
+    lowerings = ("loss_lse_lowerings_total",
+                 "loss_softmax_kept_lowerings_total")
     with pt.scope_guard(scope):
         exe.run(startup)
+        if reference:
+            # a copy: the executor donates its buffers to the next step
+            bench_model, gpt2_ref = _cells_reference()
+            initial = bench_model.scope_params(scope, {"n_layer": cfg.layers})
+        lowered0 = [counted(name) for name in lowerings]
         in_place0 = counted("executor_scope_in_place_runs_total")
         for step in range(steps):
             # the optimized HLO of the step, once: it is a second
@@ -595,7 +620,13 @@ def phase_train(cfg, batch, seq, steps=3, learning_rate=1e-4,
                 counted("executor_scope_vars_placed_total") - before)
             losses.append(float(np.asarray(out).reshape(-1)[0]))
         in_place = counted("executor_scope_in_place_runs_total") - in_place0
+        lowered = [counted(name) - before
+                   for name, before in zip(lowerings, lowered0)]
     tag = f"train: b={batch} s={seq}" + (" dp" if data_parallel else "")
+    _require(lowered[0] >= 1 and lowered[1] == 0,
+             f"{tag}: the loss gradient was lowered {lowered[0]} times from "
+             f"the saved log-sum-exp and {lowered[1]} times with a "
+             "vocabulary-wide softmax kept; expected (>= 1, 0)")
     _require(not any(placed_by_step[1:]) and in_place >= steps - 1,
              f"{tag}: a step after the first placed scope variables "
              f"(by step {placed_by_step}; {in_place} of {steps} runs "
@@ -626,7 +657,15 @@ def phase_train(cfg, batch, seq, steps=3, learning_rate=1e-4,
              f"{tag}: loss did not fall on a repeated batch: {losses}")
     facts = {"losses": losses, "mosaic_calls": mosaic_calls,
              "scope_vars_placed": placed_by_step, "runs_in_place": in_place,
-             "scope": scope}
+             "loss_lowerings": lowered, "scope": scope}
+    if reference:
+        want = gpt2_ref.batch_loss(initial, np.asarray(feed["tokens"]),
+                                   cfg.heads)
+        gap = abs(losses[0] - want) / abs(want)
+        facts.update(reference_loss=want, loss_rel_gap=gap)
+        _require(gap <= LOSS_REL_TOL,
+                 f"{tag}: first loss {losses[0]} is {gap:.3g} relative from "
+                 f"the float32 reference's {want} (limit {LOSS_REL_TOL})")
     if one_chip_losses is not None:
         gap = max(abs(a - b) / abs(a)
                   for a, b in zip(one_chip_losses, losses))
@@ -636,6 +675,19 @@ def phase_train(cfg, batch, seq, steps=3, learning_rate=1e-4,
                  f"{one_chip_losses} by {gap:.3g} relative "
                  f"(tolerance {DP_LOSS_REL_TOL})")
     return facts
+
+
+def _cells_reference():
+    """The training cells' own reader of a scope's weights and their
+    float32 reference (benchmarks/lib/model.py, reference/gpt2_ref.py)."""
+    import os
+    bench = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from lib import model
+    from reference import gpt2_ref
+    return model, gpt2_ref
 
 
 # ---------------------------------------------------------------------------
@@ -944,7 +996,8 @@ def main():
     run("block_diffusion", phase_block_diffusion)
     run("state_group", phase_state_group)
     # the published context (tiled kernels), then s=512 (single-pass)
-    long_run = run("train_s1024", phase_train, cfg, batch=8, seq=1024)
+    long_run = run("train_s1024", phase_train, cfg, batch=8, seq=1024,
+                   reference=True)
     run("train_s512", phase_train, cfg, batch=16, seq=512)
     params = None
     if long_run is not None:

@@ -36,6 +36,18 @@ WHITE_LIST: Set[str] = {
     "softmax_with_cross_entropy",
 }
 
+# white-listed ops that move a value and compute nothing. They take bf16
+# activations like the rest, but do not ROUND a value an op emitted in
+# float32 on purpose (_KEEP_F32_OUT: a per-position loss, a saved
+# statistic): it passes through in float32, and so does their output.
+# (gpt_lm_program cuts its per-position loss by a slice: rounded there,
+# the cells' first loss moved by 6e-5 relative, a third of their limit.)
+LAYOUT_OPS: Set[str] = {
+    "reshape2", "reshape", "transpose2", "transpose", "split", "concat",
+    "stack", "slice", "squeeze2", "unsqueeze2", "flatten2", "expand",
+    "pad", "gather",
+}
+
 # ops whose bf16 inputs are cast back to float32 (precision-sensitive)
 BLACK_LIST: Set[str] = {
     "mean", "reduce_sum", "reduce_mean", "sum", "cross_entropy",
@@ -50,7 +62,10 @@ _FLOAT = ("float32",)
 # per-batch stats, not the persistent accumulators)
 _KEEP_F32_IN = {"batch_norm": {"Mean", "Variance", "Scale", "Bias"}}
 _KEEP_F32_OUT = {"batch_norm": {"MeanOut", "VarianceOut", "SavedMean",
-                                "SavedVariance"}}
+                                "SavedVariance"},
+                 # the xent lowering emits a float32 loss and saves a
+                 # float32 log-sum-exp a row whatever the logits' type
+                 "softmax_with_cross_entropy": {"Loss", "Lse"}}
 
 
 class AutoMixedPrecisionLists:
@@ -75,6 +90,7 @@ def rewrite_bf16(program: Program,
     cast_to_bf16 = {}   # f32 var name -> bf16 cast name
     cast_to_f32 = {}    # bf16 var name -> f32 cast name
     cur_dtype = {}      # var name -> tracked dtype string
+    kept_f32 = set()    # float32 on purpose: a layout op does not round it
 
     def _dtype(name):
         if name in cur_dtype:
@@ -108,11 +124,14 @@ def rewrite_bf16(program: Program,
         if op.type in amp_lists.white_list:
             keep_in = _KEEP_F32_IN.get(op.type, set())
             keep_out = _KEEP_F32_OUT.get(op.type, set())
+            passes_f32 = op.type in LAYOUT_OPS and any(
+                n in kept_f32 for n in op.input_names())
             for slot, names in op.inputs.items():
                 if slot in keep_in:
                     continue
                 for j, n in enumerate(names):
-                    if _dtype(n) in _FLOAT:
+                    if _dtype(n) in _FLOAT and not (
+                            passes_f32 and n in kept_f32):
                         names[j] = _insert_cast(n, "bfloat16", cast_to_bf16,
                                                 "@BF16", op)
             new_ops.append(op)
@@ -120,11 +139,9 @@ def rewrite_bf16(program: Program,
                 for n in names:
                     d = _dtype(n)
                     if d in _FLOAT or d == "bfloat16":
-                        # loss stays f32 (xent lowering emits f32 loss)
-                        if slot in keep_out or (
-                                op.type == "softmax_with_cross_entropy"
-                                and slot == "Loss"):
+                        if slot in keep_out or passes_f32:
                             cur_dtype[n] = "float32"
+                            kept_f32.add(n)
                         else:
                             cur_dtype[n] = "bfloat16"
                             if n in blk.vars:

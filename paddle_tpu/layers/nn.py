@@ -444,9 +444,13 @@ def softmax_with_cross_entropy(logits, label, soft_label=False,
     helper = LayerHelper("softmax_with_cross_entropy", name=name)
     softmax_out = helper.create_variable_for_type_inference(logits.dtype)
     loss = helper.create_variable_for_type_inference(logits.dtype)
+    # what the grad op reads beside the logits: a float32 scalar a row
+    lse = helper.create_variable_for_type_inference("float32",
+                                                    stop_gradient=True)
     helper.append_op("softmax_with_cross_entropy",
                      {"Logits": [logits.name], "Label": [label.name]},
-                     {"Softmax": [softmax_out.name], "Loss": [loss.name]},
+                     {"Softmax": [softmax_out.name], "Loss": [loss.name],
+                      "Lse": [lse.name]},
                      {"soft_label": soft_label, "ignore_index": ignore_index,
                       "axis": axis})
     if return_softmax:

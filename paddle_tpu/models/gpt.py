@@ -125,6 +125,24 @@ def gpt_lm_program(cfg: GPTConfig, seq_len: int, is_test=False,
                    recompute=False):
     """(main, startup, fetches) for a causal-LM step: next-token CE with
     the tied wte head, loss over positions 0..seq-2 predicting 1..seq-1.
+    The shift happens where a tensor is one column wide: the loss op sees
+    the head's (b, seq, V) logits as they lie, against the tokens rolled
+    left by one (the last position meets token 0, a valid id whose loss
+    nobody reads), and the per-position LOSS is cut to seq - 1 before the
+    mean: the same sum over the same b x (seq - 1) positions, and a
+    gradient of exactly zero on the last position's logits. XLA folded
+    the older slice of the logits into the head's product and its pad
+    into the backward products, so no copy is saved; what the pad did
+    was stand between the loss gradient's expression and those products.
+    Without it the gradient is no buffer at all: the two backward
+    products read the logits and rebuild it in their operands (PERF.md
+    section 6, PR 51: 2.5 ms of a 66.8 ms step for 1.1).
+    The shift is a slice of the (b, seq, V) logits in the IR and no copy
+    on the device: XLA computes the head's product for the seq - 1 rows
+    the loss reads and folds the gradient's pad into the head's backward
+    products (seen in the step compiled for a v5e and in the cells'
+    traces, PR 51), so the loss op meets (b, seq - 1, V) logits against
+    tokens[:, 1:] and the last position's logits get a gradient of zero.
     recompute=True checkpoints the per-layer residuals and remats the
     segments in the backward (transpiler/recompute.py)."""
     main, startup = pt.Program(), pt.Program()
@@ -136,11 +154,12 @@ def gpt_lm_program(cfg: GPTConfig, seq_len: int, is_test=False,
         with pt.name_scope("head"):
             logits = pt.layers.matmul(h, wte, transpose_y=True)
         with pt.name_scope("loss"):
-            # shift: logits[:, :-1] predict tokens[:, 1:]
-            pred = pt.layers.slice(logits, [1], [0], [seq_len - 1])
-            labels = pt.layers.slice(tokens, [1], [1], [seq_len])
-            labels = pt.layers.reshape(labels, [0, seq_len - 1, 1])
-            loss = pt.layers.softmax_with_cross_entropy(pred, labels)
+            # shift: logits[:, t] predicts tokens[:, t + 1]; the last
+            # position's loss is dropped, not its vocabulary-wide logits
+            labels = pt.layers.roll(tokens, -1, 1)
+            labels = pt.layers.reshape(labels, [0, seq_len, 1])
+            loss = pt.layers.softmax_with_cross_entropy(logits, labels)
+            loss = pt.layers.slice(loss, [1], [0], [seq_len - 1])
             mean_loss = pt.layers.mean(loss)
 
         if optimizer == "adam":

@@ -435,38 +435,97 @@ def _squeeze_label(label):
 
 
 def _softmax_xent_grad_maker(op, block, no_grad_set):
+    """The grad op reads the forward's INPUT logits and the saved row
+    log-sum-exp (`Lse`), never the `Softmax` output. A forward op built
+    without an `Lse` output (by hand, or a program saved before PR 51)
+    keeps the older desc: the saved `Softmax` and no logits."""
     from ..framework.core import grad_var_name
+    softmax = op.output("Softmax")
+    ins = {"Label": op.input("Label"),
+           "Loss@GRAD": [grad_var_name(op.output("Loss")[0])]}
+    attrs = dict(op.attrs)
+    if op.output("Lse"):
+        ins.update(Logits=op.input("Logits"), Lse=op.output("Lse"))
+        # another forward op reading Softmax keeps its write alive; the
+        # gradient still rebuilds the probabilities, the counter says so
+        attrs["softmax_read"] = any(
+            set(softmax) & set(other.input_names())
+            for other in block.ops if other is not op)
+    else:
+        ins["Softmax"] = softmax
+    if softmax:
+        # present only when an aux loss consumed the Softmax output
+        # (entropy penalty, distillation) — the accum resolves it to ""
+        # otherwise and grad_lower skips it
+        ins["Softmax@GRAD"] = [grad_var_name(softmax[0])]
     return [{
         "type": "softmax_with_cross_entropy_grad",
-        "inputs": {"Softmax": op.output("Softmax"),
-                   "Label": op.input("Label"),
-                   "Loss@GRAD": [grad_var_name(op.output("Loss")[0])],
-                   # present only when an aux loss consumed the Softmax
-                   # output (entropy penalty, distillation) — the accum
-                   # resolves it to "" otherwise and grad_lower skips it
-                   "Softmax@GRAD": [grad_var_name(
-                       op.output("Softmax")[0])]},
+        "inputs": ins,
         "outputs": {"Logits@GRAD": [grad_var_name(op.input("Logits")[0])]},
-        "attrs": dict(op.attrs),
+        "attrs": attrs,
     }]
 
 
+_XENT_LOWERINGS = {
+    False: ("loss_lse_lowerings_total",
+            "softmax_with_cross_entropy gradients lowered from the logits "
+            "and the saved row log-sum-exp, with nothing but the op's "
+            "contract asking for Softmax"),
+    True: ("loss_softmax_kept_lowerings_total",
+           "softmax_with_cross_entropy gradients lowered in a program that "
+           "keeps a vocabulary-wide softmax: another op reads it, "
+           "Softmax@GRAD arrives, or the forward op saved no log-sum-exp"),
+}
+
+
 def _softmax_xent_grad_lower(ctx, ins, attrs):
-    """d_logits = (softmax - onehot(label)) * d_loss from the SAVED Softmax
-    (the reference grad kernel's design, softmax_with_cross_entropy_op.h).
-    The generic vjp path instead re-ran log_softmax in the backward,
-    materialising a full f32 logp tensor — at GPT vocab scale that was
-    ~12 ms/step of pure HBM traffic (BASELINE.md r5 GPT roofline)."""
-    softmax = ins["Softmax"][0]
+    """d_logits = (softmax - onehot(label)) * d_loss, with the softmax
+    REBUILT as exp(logits - Lse) in float32 from the logits the forward
+    read (under AMP the head's bfloat16 output, live anyway for the head's
+    own gradient) and the row's saved float32 log-sum-exp, and rounded once
+    to the logits' type.
+
+    What stands between the forward and the backward is a scalar a row and
+    nothing vocabulary-wide, as fused_attention saves `Lse` and not the
+    probabilities (ops/attention_ops.py). The older design (the reference
+    grad kernel's, softmax_with_cross_entropy_op.h) handed this op the
+    saved Softmax and took the label's log-probability out of
+    log_softmax's float32 result. At GPT-2's vocabulary XLA recomputed the
+    former but WROTE the latter: 8 x 1,023 x 50,257 float32 = 1.65 GB a
+    step beside the 0.82 GB of gradient, 5.1 ms of a 64.1 ms step in ONE
+    fusion of a stage that is pure HBM traffic (`loss` 6.3 ms,
+    `loss_time_share` 27.06 / 23.77 with the head; ledger, PR 50; the
+    fusions: PERF.md section 5, PR 51). Now the gradient is an expression
+    of the logits and two values a row, which XLA may fold into the
+    operands of the head's backward products (it does for gpt_lm_program:
+    the gradient is no buffer at all, `loss` 1.2 ms).
+
+    The aux-loss path (`Softmax@GRAD` present) rebuilds `sm` the same way
+    and keeps its formula. A grad op that carries a saved `Softmax` and no
+    `Lse` (see the maker) traces the older expressions.
+    `loss_lse_lowerings_total` / `loss_softmax_kept_lowerings_total` count
+    which kind of program a lowering belonged to."""
     label = ins["Label"][0]
     g = ins["Loss@GRAD"][0]
-    axis = attrs.get("axis", -1) % softmax.ndim
-    sm = softmax.astype(jnp.float32)
+    g_sm = ins.get("Softmax@GRAD", [None])[0]
+    if "Lse" in ins:
+        logits = ins["Logits"][0]
+        out_dtype = logits.dtype
+        sm = jnp.exp(logits.astype(jnp.float32) - ins["Lse"][0])
+        kept = g_sm is not None or bool(attrs.get("softmax_read", False))
+    else:
+        out_dtype = ins["Softmax"][0].dtype
+        sm = ins["Softmax"][0].astype(jnp.float32)
+        kept = True
+    if not ctx.abstract:
+        from ..observability.metrics import get_registry
+        get_registry().counter(*_XENT_LOWERINGS[kept]).inc()
+    axis = attrs.get("axis", -1) % sm.ndim
     if attrs.get("soft_label", False):
         d = sm - label.astype(jnp.float32)
     else:
         lab = label
-        if lab.ndim == softmax.ndim and lab.shape[axis] == 1:
+        if lab.ndim == sm.ndim and lab.shape[axis] == 1:
             lab = jnp.squeeze(lab, axis)
         idx = jnp.expand_dims(lab.astype(jnp.int32), axis)
         # onehot as iota==label: fuses to a select, no (.., V) materialize
@@ -475,28 +534,48 @@ def _softmax_xent_grad_lower(ctx, ins, attrs):
         ignore = attrs.get("ignore_index", -100)
         d = jnp.where(jnp.expand_dims(lab == ignore, axis), 0.0, d)
     dl = d * g.astype(jnp.float32)
-    g_sm = ins.get("Softmax@GRAD", [None])[0]
     if g_sm is not None:
         # aux-loss path through the Softmax output: softmax vjp
         # dL/dlogits += (g_sm - sum(g_sm * sm)) * sm
         gs = g_sm.astype(jnp.float32)
         dl = dl + (gs - jnp.sum(gs * sm, axis=axis, keepdims=True)) * sm
-    return {"Logits@GRAD": [dl.astype(softmax.dtype)]}
+    return {"Logits@GRAD": [dl.astype(out_dtype)]}
 
 
 @register_op("softmax_with_cross_entropy", no_grad_inputs={"Label"},
+             non_diff_outputs={"Lse"},
              grad_maker=_softmax_xent_grad_maker,
              grad_lower=_softmax_xent_grad_lower)
 def _softmax_xent(ctx, ins, attrs):
     """reference: softmax_with_cross_entropy_op.cc — the numerically stable
-    fused path (log-softmax + NLL in one). The grad op consumes the saved
-    Softmax output (as in the reference); gradients do not flow through the
-    Softmax output itself — also the reference's contract."""
+    fused path (log-softmax + NLL in one), float32 inside whatever the
+    logits' type (bf16 logits only halve HBM traffic: AMP-safe).
+
+    What the op SAVES for its gradient is `Lse`, the row's float32
+    log-sum-exp in the shape of `Loss` (max + log of the shifted sum, the
+    two reductions log_softmax makes anyway), not the probabilities: the
+    grad op rebuilds them from the logits (see _softmax_xent_grad_lower).
+    `Softmax` stays in the op's contract, computed by the same expression
+    as before (exp(logp) in the logits' type), but nothing of the gradient
+    reads it: where no other op does either, XLA removes it.
+    The hard-label loss takes the label's logit from the INPUT (a gather of
+    one element a row out of a tensor that lies in HBM already) and shifts
+    it as log_softmax shifts the row: -((x[label] - max) - log_sum), the
+    bits of -log_softmax(x)[label]; gathered out of `logp`, as before PR
+    51, it made XLA write `logp` whole in float32 (1.65 GB a step at
+    GPT-2's 8 x 1,023 x 50,257). Gradients do not flow through `Lse`;
+    they do through `Softmax` (an aux loss's `Softmax@GRAD`)."""
     logits, label = ins["Logits"][0], ins["Label"][0]
     axis = attrs.get("axis", -1) % logits.ndim
-    # f32 internal math: bf16 logits only halve HBM traffic (AMP-safe)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=axis)
+    x = logits.astype(jnp.float32)
+    # jax.nn.log_softmax written out, so that its two reductions are also
+    # the saved output's
+    row_max = jax.lax.stop_gradient(jnp.max(x, axis=axis, keepdims=True))
+    shifted = x - row_max
+    log_sum = jnp.log(jnp.sum(jnp.exp(shifted), axis=axis, keepdims=True))
+    logp = shifted - log_sum
     softmax = jnp.exp(logp).astype(logits.dtype)
+    lse = row_max + log_sum
     if attrs.get("soft_label", False):
         loss = -jnp.sum(label * logp, axis=axis, keepdims=True)
     else:
@@ -504,11 +583,12 @@ def _softmax_xent(ctx, ins, attrs):
         if lab.ndim == logits.ndim and lab.shape[axis] == 1:
             lab = jnp.squeeze(lab, axis)
         idx = jnp.expand_dims(lab.astype(jnp.int32), axis)
-        nll = -jnp.take_along_axis(logp, idx, axis=axis)
+        picked = jnp.take_along_axis(logits, idx, axis=axis)
+        nll = -((picked.astype(jnp.float32) - row_max) - log_sum)
         ignore = attrs.get("ignore_index", -100)
         nll = jnp.where(jnp.expand_dims(lab == ignore, axis), 0.0, nll)
         loss = nll
-    return {"Softmax": [softmax], "Loss": [loss]}
+    return {"Softmax": [softmax], "Loss": [loss], "Lse": [lse]}
 
 
 @register_op("cross_entropy", no_grad_inputs={"Label"})

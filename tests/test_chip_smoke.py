@@ -132,8 +132,12 @@ def test_train_then_serve_phases():
     """The train phase's scope feeds the serve phase, as main() chains
     them."""
     cfg = _toy()
-    trained = chip_smoke.phase_train(cfg, batch=4, seq=16)
+    trained = chip_smoke.phase_train(cfg, batch=4, seq=16, reference=True)
     assert trained["mosaic_calls"] == 0     # no Mosaic on the CPU
+    # the loss gradient rebuilt from the saved log-sum-exp, lowered once,
+    # and the first loss beside the cells' float32 reference
+    assert trained["loss_lowerings"] == [1, 0]
+    assert trained["loss_rel_gap"] <= chip_smoke.LOSS_REL_TOL
     assert trained["losses"][-1] < trained["losses"][0]
     # no plan: nothing to place, and every run says so
     assert trained["scope_vars_placed"] == [0, 0, 0]
