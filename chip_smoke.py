@@ -23,7 +23,9 @@ program's seed:
   6. four chips (only when jax.device_count() >= 4) serve on a
                 tensor-parallel mesh of 4 and train data-parallel
 
-One line per phase, a `summary=` line (versions, per-phase facts), then
+One line per phase, a `compile_log` line (executables, cache hits and
+misses, seconds of trace / lowering / compile / cache load in the whole
+run), a `summary=` line (versions, per-phase facts, the same totals), then
 as the LAST line of stdout one JSON object with exactly these keys:
   {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
 Without a TPU nothing is written to stdout at all. Wall and compile
@@ -1025,9 +1027,21 @@ def main():
     if failed:
         print(f"chip_smoke: FAILED phases: {', '.join(failed)}",
               file=sys.stderr)
+    # what the whole run traced, lowered, compiled or loaded, by the
+    # program's own compile log (the benchmark's set-up metrics read the
+    # same records): a warm machine shows hits and cache_load_s, a cold
+    # one misses and backend_compile_s
+    from paddle_tpu.observability import compile_log
+    compiled = compile_log().totals()
+    print("compile_log executables={executables} hit={hit} miss={miss} "
+          "off={off} trace_s={trace_s:.1f} lower_s={lower_s:.1f} "
+          "backend_compile_s={backend_compile_s:.1f} "
+          "cache_load_s={cache_load_s:.1f} inner_traces={inner_traces}"
+          .format(**compiled, **compiled["cache"]), flush=True)
     print("summary=" + json.dumps(
         {"versions": _versions(), "four_chips": four_chips,
-         "failed": failed, "phases": phases, "claim": None}), flush=True)
+         "failed": failed, "phases": phases, "compile_log": compiled,
+         "claim": None}), flush=True)
     # The last line of stdout is the verdict and the device as jax
     # reports it, these keys and no others: the driver's check reads it.
     print(json.dumps({"ok": not failed, "device": device}), flush=True)

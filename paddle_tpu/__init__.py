@@ -7,6 +7,11 @@ whole blocks to XLA via JAX; data/model parallelism via jax.sharding meshes
 for the capability map.
 """
 
+import time as _time
+
+# `setup/import` of the compile log: the clock at the first line and the last
+_IMPORT_BEGIN_NS = _time.monotonic_ns()
+
 from . import ops  # registers all op lowering rules
 from .framework import (Program, Block, Operator, Variable, Parameter,
                         program_guard, default_main_program,
@@ -65,3 +70,8 @@ class TPUPlace:
 
 
 CUDAPlace = TPUPlace  # source compat for reference scripts
+
+# the compile log hears jax from here on, its one installation (what a caller
+# jits before its first Executor or engine is part of its start too)
+_IMPORT_END_NS = _time.monotonic_ns()
+observability.compile_log().install()

@@ -27,6 +27,7 @@ import numpy as np
 
 from .core import Program, Variable, default_main_program
 from .registry import LowerContext, lower_op, get_op_def
+from ..observability.compile_log import compile_log
 from ..utils.compile_cache import ensure_compile_cache
 from ..observability.metrics import get_registry
 from ..observability.tracer import get_tracer, trace_span
@@ -497,13 +498,17 @@ class Executor:
                 self.compile_count += 1
 
         if was_miss:
+            # the Program lowered to a jitted callable, no more: jax traces,
+            # lowers and compiles it (or loads it) inside the first
+            # `executor/dispatch` below, under the compile log's
+            # `compile/trace`, `compile/lower` and `compile/backend` spans
             with trace_span("executor/compile", "executor",
                             {"ops": len(blk.ops),
                              "fetches": len(all_fetch),
                              "cause": cause}):
                 compiled = self._compile(program, feed_shapes, all_fetch,
                                          mutable, created, readonly,
-                                         dist_plan)
+                                         dist_plan, cause)
             self._cache[cache_key] = compiled
             self._compiled_uids.add(cache_key[0])
             while len(self._cache) > self._cache_capacity:
@@ -574,8 +579,9 @@ class Executor:
             # tools/comm_volume.py: optimized HLO with the SPMD partitioner's
             # collectives, captured without disturbing the jit cache
             try:
-                self.last_hlo = compiled.lower(
-                    mut_in, ro_in, feed_in, key).compile().as_text()
+                with compile_log().probing():
+                    self.last_hlo = compiled.lower(
+                        mut_in, ro_in, feed_in, key).compile().as_text()
             except Exception as e:  # pipeline/custom callables
                 self.last_hlo = None
                 self.last_hlo_error = str(e)
@@ -643,7 +649,11 @@ class Executor:
                                  "argument_bytes": None,
                                  "output_bytes": None, "peak_bytes": None}
         try:
-            aot = compiled.lower(mut_in, ro_in, feed_in, key).compile()
+            # a look at the executable, not a second one: on a miss it
+            # comes before the first dispatch, and the log counts the
+            # compile it causes as the program's (jax keeps it)
+            with compile_log().probing():
+                aot = compiled.lower(mut_in, ro_in, feed_in, key).compile()
             ca = aot.cost_analysis()
             if isinstance(ca, (list, tuple)):
                 ca = ca[0] if ca else {}
@@ -790,7 +800,12 @@ class Executor:
 
     # -- compilation ---------------------------------------------------------
     def _compile(self, program: Program, feed_shapes, fetch_names,
-                 mutable, created, readonly, dist_plan):
+                 mutable, created, readonly, dist_plan, cause=None):
+        """The Program lowered to a jitted callable (the span
+        `executor/compile`): no XLA yet. jax traces, lowers and compiles
+        it inside the first `executor/dispatch`; `fn` tells the compile
+        log, as jax traces it, which program that executable is and the
+        `cause` of the miss that built it."""
         import jax
 
         if getattr(program, "_pipeline", None) is not None:
@@ -813,10 +828,12 @@ class Executor:
         out_names = list(mutable) + list(created)
 
         check_nan_inf = os.environ.get("FLAGS_check_nan_inf", "0") == "1"
+        log_tag = f"program:{str(getattr(program, '_uid', id(program)))[:8]}"
 
         def fn(mut_scope, ro_scope, feed_vals, rng_key):
             import jax.numpy as jnp
 
+            compile_log().note_tag(log_tag, cause)      # trace time only
             env: Dict[str, Any] = {}
             env.update(ro_scope)
             env.update(mut_scope)

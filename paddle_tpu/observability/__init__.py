@@ -58,6 +58,16 @@ first-class layer):
   rebalancer consumes. `FleetHealth` wires store + sampler + engine in
   one call (`Router(health=HealthConfig())`).
 
+* `compile_log` — what a start costs: one record for every executable
+  jax traces, lowers, compiles or loads from its persistent cache (seconds
+  by phase, `cache: hit | miss | off`, the layer's own tag, the jits
+  traced inside it), fed by jax's own monitoring events where the work
+  happens, the same events as `compile/*` spans of the tracer, and the
+  phases of a start that are not jax's (`setup/import`,
+  `serving/engine_build`). `/compilez` serves its table,
+  `compile_log().snapshot()` gives it to a trainer, and
+  `engine.stats()["compile"]` carries its sums.
+
 Quick start:
 
     import paddle_tpu as pt
@@ -75,6 +85,7 @@ from . import (alerts, debug_server, export, metrics,  # noqa: F401
                request_log, timeseries, tracer, train_stats, watchdog)
 from .alerts import (AlertEngine, AlertRule, FleetHealth, HealthConfig,
                      builtin_rules)
+from .compile_log import CompileLog, compile_log
 from .debug_server import (DebugServer, get_debug_server,
                            start_debug_server, stop_debug_server)
 from .export import export_chrome_trace, self_times, summarize
@@ -100,6 +111,7 @@ __all__ = [
     "disable_tracing", "tracing_enabled", "request_scope",
     "current_request_id",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "get_registry",
+    "CompileLog", "compile_log",
     "export_chrome_trace", "self_times", "summarize",
     "DebugServer", "start_debug_server", "stop_debug_server",
     "get_debug_server",

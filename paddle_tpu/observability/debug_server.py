@@ -24,9 +24,11 @@ Endpoints:
     /tickz     engine tick-profiler flight ring (tick_profile engines):
                per-tick phase decomposition; ?engine= one engine,
                ?limit=N newest N, ?chrome=1 chrome-trace download
-    /compilez  executable cost & compile journal (tick_profile
-               engines): per-family count/cost/share + compile-event
-               records; ?engine= one engine, ?limit=N newest records
+    /compilez  the compile log's table (always): an executable a row,
+               seconds of trace / lowering / compile / cache load,
+               hit | miss | off, its tag; and the cost & compile journal
+               of tick_profile engines: per-family count/cost/share;
+               ?engine= one engine, ?limit=N newest records
     /requestz  serving request-lifecycle events (the installed request
                log's ring): in-flight ids + recent transitions;
                ?request_id= one request's timeline, ?limit=N newest N
@@ -56,6 +58,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional
 from urllib.parse import parse_qs, urlparse
 
+from .compile_log import compile_log
 from .export import spans_to_events, ticks_to_events
 from .metrics import MetricsRegistry, get_registry
 from .tracer import Span, Tracer, get_tracer
@@ -603,11 +606,18 @@ class DebugServer:
         })
 
     def _compilez(self, h: _Handler, q: Dict[str, str]) -> None:
-        """Executable cost & compile journal: per-family attribution
-        (calls, compiles, compile seconds + share, cost_analysis
-        FLOPs/bytes) and the compile-event records from every
-        registered tick_profile engine. ?engine= one engine;
-        ?limit=N newest N records per engine."""
+        """What the process compiled, and what it cost. `compile`: the
+        compile log's table, always there (`compile_log.snapshot()`): an
+        executable a row with its tag, seconds of trace, lowering,
+        backend compile or cache load, `cache: hit | miss | off` and the
+        jits traced inside it, the phases of the start, and the sums.
+        `engines`: the per-family journal (calls, compiles, compile
+        seconds + share, cost_analysis FLOPs/bytes) of every registered
+        tick_profile engine, whose `compile_s` are the log's seconds
+        for that family's executables (trace + lowering + compile or
+        load; the first call's run is not in them); `enabled` says
+        whether there is such an engine. ?engine= one engine; ?limit=N
+        newest N records per engine and newest N executables."""
         limit = _parse_limit(h, q, default=None)
         if limit is _BAD_LIMIT:
             return
@@ -626,6 +636,7 @@ class DebugServer:
             "enabled": bool(sources),
             "engine": engine,
             "engines": engines,
+            "compile": compile_log().snapshot(limit),
         })
 
     def _requestz(self, h: _Handler, q: Dict[str, str]) -> None:

@@ -33,6 +33,7 @@ import numpy as np
 
 from ..observability import request_log as _request_log
 from ..observability import watchdog as _watchdog
+from ..observability.compile_log import compile_log
 from ..observability.tracer import get_tracer, request_scope, trace_span
 from .kv_cache import ShapeBuckets, SlotKVCache
 from .model import BLOCK_DIFFUSION, require_features, serving_model
@@ -390,7 +391,12 @@ class ServingEngine:
     implement refuse here, at construction."""
 
     def __init__(self, params, cfg, serving: Optional[ServingConfig] = None):
-        serving = serving or ServingConfig()
+        # a start's `serving/engine_build` (once an engine): a span of the
+        # one tracer and a phase of the compile log
+        with compile_log().phase("serving/engine_build"):
+            self._build(params, cfg, serving or ServingConfig())
+
+    def _build(self, params, cfg, serving: ServingConfig):
         self.cfg = cfg
         self.config = serving
         model = self.model = serving_model(cfg)
@@ -1469,6 +1475,10 @@ class ServingEngine:
         if self.adapters is not None:
             s.update(self.adapters.occupancy())
         s["compiled_executables"] = self.scheduler.compile_count
+        # what the PROCESS traced, lowered, compiled or loaded so far, in
+        # sums; /compilez and `compile_log().snapshot()` have the table,
+        # an executable a row (this engine's carry the scheduler's tags)
+        s["compile"] = compile_log().totals()
         # the admissions' first tokens: fetches made (one a tick that
         # admitted), tokens they carried, and those read with a dispatch
         # already launched behind their sampler (the device had work

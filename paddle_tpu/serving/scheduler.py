@@ -216,6 +216,7 @@ import numpy as np
 from .. import profiler
 from ..observability import request_log as _request_log
 from ..observability.tracer import get_tracer, trace_span
+from ..observability.compile_log import compile_log
 from ..utils.compile_cache import ensure_compile_cache
 from . import sampling
 from .decode_loop import (FINISH_SCOPE, SAMPLE_SCOPE, DecodeCarry,
@@ -768,6 +769,12 @@ class ContinuousBatchingScheduler:
         if self._chunk_jit is not None:
             return
         ensure_compile_cache()
+        # once an engine, at its first request: the carry, the device
+        # page table and the jitted entry points (nothing compiles yet)
+        with compile_log().phase("serving/engine_build/jits"):
+            self._build_jits()
+
+    def _build_jits(self):
         import jax
         import jax.numpy as jnp
 
@@ -1035,10 +1042,13 @@ class ContinuousBatchingScheduler:
 
     def _note_compile(self, tag: str) -> None:
         """The impl bodies' trace-time side effect: one append per
-        distinct input signature (= per compiled executable). Suppressed
-        while _cost_probe AOT-lowers an already-compiled entry point —
-        lowering re-runs the body, and a probe must never show up as a
-        compile."""
+        distinct input signature (= per compiled executable), and the
+        same tag on the compile log's record of the trace open on this
+        thread. The append is suppressed while _cost_probe AOT-lowers
+        an already-compiled entry point — lowering re-runs the body,
+        and a probe must never show up as a compile (the log marks its
+        record `probe`)."""
+        compile_log().note_tag(tag)
         if not self._probing:
             self._compile_events.append(tag)
 
@@ -1048,21 +1058,24 @@ class ContinuousBatchingScheduler:
         off path) is a single attribute read and a bare call — zero
         clock reads, zero probes, identical compile events.
 
-        With a journal: the call is timed, and if compile_events grew
-        (this signature traced a new executable) the lowered
-        computation's cost_analysis() FLOPs/bytes are probed and the
-        event is journaled under `family` — the same tag string the
-        impl body appended, so journal and compile_events can never
-        disagree."""
+        With a journal: if compile_events grew (this signature traced a
+        new executable) the event is journaled under `family` — the
+        same tag string the impl body appended, so journal and
+        compile_events can never disagree — with the seconds the
+        compile log holds for that executable (trace + lowering +
+        compile or cache load: jax's own stopwatch, and not the first
+        call's run) and the lowered computation's cost_analysis()
+        FLOPs/bytes."""
         journal = self.compile_journal
         if journal is None:
             return fn(*args)
         n0 = len(self._compile_events)
-        t0 = time.perf_counter()
         out = fn(*args)
-        seconds = time.perf_counter() - t0
         compiled = len(self._compile_events) > n0
-        cost = self._cost_probe(fn, args) if compiled else None
+        seconds, cost = 0.0, None
+        if compiled:
+            seconds = compile_log().seconds_of(family)
+            cost = self._cost_probe(fn, args)
         journal.note_call(family, seconds, compiled, cost)
         return out
 
@@ -1081,7 +1094,8 @@ class ContinuousBatchingScheduler:
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
                 if hasattr(a, "shape") and hasattr(a, "dtype")
                 else np.asarray(a), args)
-            cost = fn.lower(*avals).cost_analysis()
+            with compile_log().probing():
+                cost = fn.lower(*avals).cost_analysis()
         except Exception:
             return None
         finally:
