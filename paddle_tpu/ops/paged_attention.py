@@ -199,13 +199,16 @@ def _call(q, new, arena, layer, pt, lengths, interpret):
 # over where it applies: the walk moves in groups of _GROUPED_PAGES pages,
 # each page copied from its own block into a row slice of one buffer a KV
 # head, and one step of the online softmax attends a group (two products a
-# KV head a group, not a page); _GROUPED_BUFFERS - 1 groups are in flight
-# while a step computes. A short last group is attended at the buffer's
-# size with the pages that were not fetched masked (the buffers are zeroed
-# once, so what they hold is always a number). The live page's write-back
-# is waited for when the stage is next needed (or by the last program).
-# The READS still drain between slots: the latent walk's one stream of
-# groups across slots is not taken over (PERF.md, section 7).
+# KV head a group, not a page). A short last group is attended at the
+# buffer's size with the pages that were not fetched masked (the buffers are
+# zeroed once, so what they hold is always a number: zeros, or an earlier
+# group's rows). The live page's write-back is waited for when the stage is
+# next needed (or by the last program). And nothing drains between slots:
+# the groups of a slot and of the next LIVE slot are one stream, of which
+# _GROUPED_BUFFERS - 1 groups are in flight whenever a step computes, carried
+# from program to program as the latent walk's is (its section below has the
+# account: PRIMED, BASE, the next live slot found through the prefetched
+# lengths), the next slot's pages read through its own `lo` and its own ring.
 
 
 # pages a step of the grouped walk's online softmax and group buffers (the
@@ -215,38 +218,43 @@ _GROUPED_BUFFERS = 3
 
 
 def _grouped_kernel(layer_ref, pt_ref, lo_ref, len_ref, q_ref, new_ref,
-                    arena_ref, arena_out_ref, o_ref, kv_buf, stage, writing,
+                    arena_ref, arena_out_ref, o_ref, kv_buf, stage, state,
                     sems, wsem, *, block_size, pages, group, buffers,
                     block=1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     s = pl.program_id(0)
+    n_slots = pl.num_programs(0)
     li = layer_ref[0]
     bs = block_size
     length = len_ref[s]                       # live rows; 0 = frozen slot
     lo = lo_ref[s]                            # first position attended
     kv_heads, rows, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+    # what one program leaves the next, as in the latent kernel below
+    PRIMED, BASE, WRITING = 0, 1, 2
 
     @pl.when(s == 0)
     def _first():
         # a group's buffer is attended whole, the rows of pages that were
         # not fetched masked: they must be numbers (p = 0 times them)
         kv_buf[...] = jnp.zeros_like(kv_buf)
-        writing[0] = 0
+        state[PRIMED] = 0
+        state[BASE] = 0
+        state[WRITING] = 0
 
     def write_back(blk=0):
         return pltpu.make_async_copy(
             stage, arena_out_ref.at[li, 0, blk], wsem)
 
-    def block_of(p):
-        return pt_ref[s * pages + jax.lax.rem(p, pages)]
+    def block_of(t, p):
+        return pt_ref[t * pages + jax.lax.rem(p, pages)]
 
-    def page_copy(p, buf, i):
-        """Page p of this slot -> rows [i * bs, (i + 1) * bs) of every KV
+    def page_copy(t, p, buf, i):
+        """Page p of slot t -> rows [i * bs, (i + 1) * bs) of every KV
         head of group buffer `buf`."""
         return pltpu.make_async_copy(
-            arena_ref.at[li, 0, block_of(p)],
+            arena_ref.at[li, 0, block_of(t, p)],
             kv_buf.at[buf, :, pl.ds(pl.multiple_of(i * bs, bs), bs)],
             sems.at[buf])
 
@@ -281,34 +289,59 @@ def _grouped_kernel(layer_ref, pt_ref, lo_ref, len_ref, q_ref, new_ref,
     def _live():
         first = jax.lax.div(lo, bs)
         last = jax.lax.div(length - 1, bs)
-        n_pages = last - first + 1
-        n_groups = jax.lax.div(n_pages + group - 1, group)
+        n_groups = jax.lax.div(last - first + group, group)
+        base = state[BASE]
+        # the next live slot (a frozen one in between is passed over and
+        # touches nothing); n_slots where this is the last
+        nxt = jax.lax.while_loop(
+            lambda t: jnp.logical_and(
+                t < n_slots, len_ref[jnp.minimum(t, n_slots - 1)] == 0),
+            lambda t: t + 1, s + 1)
+        nxt_live = nxt < n_slots
+        nxt = jnp.minimum(nxt, n_slots - 1)
+        first_next = jax.lax.div(lo_ref[nxt], bs)
+        end_next = jnp.where(
+            nxt_live, jax.lax.div(len_ref[nxt] - 1, bs) + 1, 0)
 
-        def start(g):
-            """The live pages of group g into buffer g % buffers; nothing
-            past the slot's last group."""
-            p0 = first + g * group
-            live = jnp.clip(last + 1 - p0, 0, group)
-            buf = jax.lax.rem(g, buffers)
+        def start(j):
+            """Group j of the stream of groups that runs from this slot's
+            (0..n_groups - 1, cut from its own first page) into the next
+            live slot's (cut from ITS first page): its live pages into
+            buffer (base + j) % buffers. Nothing where the stream has
+            ended."""
+            own = j < n_groups
+            t = jnp.where(own, s, nxt)
+            p0 = jnp.where(own, first + j * group,
+                           first_next + (j - n_groups) * group)
+            live = jnp.clip(jnp.where(own, last + 1, end_next) - p0, 0, group)
+            buf = jax.lax.rem(base + j, buffers)
 
             def one(i, c):
-                page_copy(p0 + i, buf, i).start()
+                page_copy(t, p0 + i, buf, i).start()
                 return c
             jax.lax.fori_loop(0, live, one, 0)
 
+        def starts(j, c):
+            start(j)
+            return c
+
         def landed(g):
-            p0 = first + g * group
-            live = jnp.clip(last + 1 - p0, 0, group)
-            buf = jax.lax.rem(g, buffers)
+            live = jnp.clip(last + 1 - first - g * group, 0, group)
+            buf = jax.lax.rem(base + g, buffers)
 
             def one(i, c):
-                page_copy(p0, buf, i).wait()       # a wait reads sizes only
+                page_copy(s, first, buf, i).wait()     # a wait reads sizes only
                 return c
             jax.lax.fori_loop(0, live, one, 0)
             return buf
 
-        for g in range(buffers - 1):
-            start(g)
+        # buffers - 1 groups are in flight whenever a step computes: the
+        # slot before this one has started this slot's share of them
+        # (unless it was none: the first live slot), and what reaches past
+        # this slot's last group into the next one's starts here
+        have = jnp.where(state[PRIMED] == 1,
+                         jnp.minimum(n_groups, buffers - 1), 0)
+        jax.lax.fori_loop(have, buffers - 1, starts, 0)
 
         def group_step(g, carry):
             buf = landed(g)
@@ -327,6 +360,9 @@ def _grouped_kernel(layer_ref, pt_ref, lo_ref, len_ref, q_ref, new_ref,
         # whole from the stage (see the kernel above)
         g_last = n_groups - 1
         buf = landed(g_last)
+        start(g_last + buffers - 1)
+        state[PRIMED] = nxt_live.astype(jnp.int32)
+        state[BASE] = jax.lax.rem(base + n_groups, buffers)
         i_live = last - (first + g_last * group)
         # BLOCK ROWS: the pass's `block` new rows are positions length -
         # block .. length - 1, which one page holds (`block` divides the
@@ -349,12 +385,12 @@ def _grouped_kernel(layer_ref, pt_ref, lo_ref, len_ref, q_ref, new_ref,
         # the write-back of the slot before this one is waited for here,
         # where the stage is next needed (or by the last program), not at
         # its own program's end
-        @pl.when(writing[0] == 1)
+        @pl.when(state[WRITING] == 1)
         def _stage_free():
             write_back().wait()
         stage[...] = page
-        write_back(block_of(last)).start()
-        writing[0] = 1
+        write_back(block_of(s, last)).start()
+        state[WRITING] = 1
         done = attend(kv_buf[buf], first + g_last * group, carry)
         for h in range(kv_heads):
             _, l, acc = done[h]
@@ -364,7 +400,7 @@ def _grouped_kernel(layer_ref, pt_ref, lo_ref, len_ref, q_ref, new_ref,
     def _frozen():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(jnp.logical_and(s == pl.num_programs(0) - 1, writing[0] == 1))
+    @pl.when(jnp.logical_and(s == n_slots - 1, state[WRITING] == 1))
     def _drain():
         write_back().wait()
 
@@ -417,7 +453,7 @@ def _grouped_call(q, new, arena, layer, pt, lo, lengths, interpret):
                 pltpu.VMEM((_GROUPED_BUFFERS, kv_heads, group * block_size,
                             w), arena.dtype),
                 pltpu.VMEM((kv_heads, block_size, w), arena.dtype),
-                pltpu.SMEM((1,), jnp.int32),
+                pltpu.SMEM((3,), jnp.int32),
                 pltpu.SemaphoreType.DMA((_GROUPED_BUFFERS,)),
                 pltpu.SemaphoreType.DMA(()),
             ]),

@@ -370,6 +370,35 @@ def test_a_block_pass_kernels_compile_for_a_described_v5e(one_chip):
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
+@pytest.mark.parametrize("slots,kvh,queries,pages", [
+    (48, 4, 8, 9), (48, 4, 8, 128),          # Mellum: a window ring, the table
+    (32, 8, 16, 33), (32, 8, 16, 80),        # command-a's share
+], ids=["mellum_window", "mellum_full", "command_a_window", "command_a_full"])
+def test_a_decode_steps_grouped_walk_compiles_for_a_described_v5e(
+        one_chip, slots, kvh, queries, pages):
+    """The grouped paged kernel of a decode step (one new row a slot, the
+    walk ONE stream of page groups across slots) at the widths and tables
+    Mellum and command-a serve: pages of 128 rows, 256 lanes a KV head."""
+    from paddle_tpu.ops import paged_attention as pa
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def step(q, new, arena, pt, lo, lengths):
+        return pa._grouped_call(q, new, arena, 1, pt, lo, lengths, False)
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(step, donate_argnums=(2,)).lower(
+            shape(slots, kvh * queries, 128), shape(slots, kvh, 256),
+            shape(2, 1, slots * pages + 1, kvh, 128, 256),
+            shape(slots, pages, dtype=jnp.int32),
+            shape(slots, dtype=jnp.int32),
+            shape(slots, dtype=jnp.int32)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    # the arena leaves the call as the buffer it came in
+    assert compiled.memory_analysis().temp_size_in_bytes < (64 << 20)
+
+
 @pytest.mark.parametrize("bucket", [512, 1024, 2048, 4096])
 def test_the_chunked_scans_kernel_compiles_for_a_described_v5e(one_chip, bucket):
     """Kimi-Linear's widths (32 heads of 128 x 128, float32) in the four

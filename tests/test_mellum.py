@@ -498,6 +498,86 @@ def test_grouped_paged_kernel_against_mha_reference(mode):
     np.testing.assert_array_equal(np.asarray(after), a)   # and no write
 
 
+
+def _one_a_call(q, k, v, arena, table, ts, done, lo):
+    """The same slots, ONE A CALL in their order on the same arena: what a
+    slot gets and writes when no other slot's pages are anywhere near."""
+    outs = []
+    for s in range(len(ts)):
+        one = slice(s, s + 1)
+        out, arena = paged_attention(
+            q[one], k[one], v[one], arena, 1, table[one], ts[one], done[one],
+            lo=None if lo is None else lo[one])
+        outs.append(out)
+    return jnp.concatenate(outs, 0), arena
+
+
+# (page-table width, window or None, ts a slot, the frozen slots): pages of
+# 4 rows, so a group of the walk is 16 rows and a full table of 12 pages
+# three groups; the window of 40 rows has a ring of 11 blocks (three groups
+# too) that positions past 44 have wrapped, and lo = ts - 39 begins
+# mid-page wherever ts + 1 is no multiple of 4
+_STREAMS = {
+    # every cut of a walk: one row, a page, a page and a row, a group, a
+    # group and a row, two groups, the whole table; frozen slots first,
+    # last and between live ones
+    "every_length": (12, None, [9, 0, 3, 4, 30, 15, 16, 31, 32, 7, 47, 20],
+                     [0, 4, 9, 11]),
+    "one_live_slot": (12, None, [5, 23, 40, 9], [0, 1, 3]),
+    "no_live_slot": (12, None, [5, 23, 40], [0, 1, 2]),
+    "few_pages_beside_many": (12, None, [2, 47, 1, 44, 6, 46], []),
+    "ring_wrapped_mid_page": (11, 40, [97, 45, 12, 63, 130, 38, 81, 99],
+                              [2, 6]),
+    "ring_of_one_group": (RING, WINDOW, [5, 23, 40, 9, 2, 30], [4]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STREAMS))
+def test_the_grouped_walk_streams_across_slots_and_leaks_nothing(case):
+    """The walk's page groups are ONE stream across the live slots of a
+    call: every slot's output and the WHOLE arena against a float64 gather,
+    and bit for bit against the same slots run one a call."""
+    P, window, ts, frozen = _STREAMS[case]
+    S, kvh, g, hd = len(ts), 2, 4, 16
+    rng = np.random.default_rng(len(case))
+    blocks = 1 + S * P
+    arena = jnp.asarray(rng.normal(size=(2, 1, blocks, kvh, BS, 2 * hd)),
+                        jnp.float32)
+    table = (1 + rng.permutation(S * P)).reshape(S, P).astype(np.int32)
+    ts = np.asarray(ts, np.int32)
+    done = np.isin(np.arange(S), frozen)
+    lo = None if window is None else np.maximum(ts - window + 1, 0) \
+        .astype(np.int32)
+    q, k, v = (jnp.asarray(rng.normal(size=(S, n, hd)), jnp.float32)
+               for n in (kvh * g, kvh, kvh))
+    slots = (jnp.asarray(table), jnp.asarray(ts), jnp.asarray(done))
+    bound = None if lo is None else jnp.asarray(lo)
+    got, after = paged_attention(q, k, v, arena, 1, *slots, lo=bound)
+    alone, after_alone = _one_a_call(q, k, v, arena, *slots, bound)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(alone))
+    np.testing.assert_array_equal(np.asarray(after), np.asarray(after_alone))
+
+    a = np.array(arena)
+    for s in np.flatnonzero(~done):
+        t = int(ts[s])
+        a[1, 0, table[s, (t // BS) % P], :, t % BS] = np.concatenate(
+            [k[s], v[s]], -1)
+    np.testing.assert_array_equal(np.asarray(after), a)   # frozen: no write
+    for s in range(S):
+        if done[s]:
+            assert float(jnp.abs(got[s]).max()) == 0.0   # and zeros
+            continue
+        pos = np.arange(0 if lo is None else lo[s], ts[s] + 1)
+        rows = a[1, 0, table[s, (pos // BS) % P], :, pos % BS] \
+            .astype(np.float64)                           # (n, kvh, 2hd)
+        qs = np.asarray(q[s], np.float64).reshape(kvh, g, hd)
+        sc = np.einsum("kgd,nkd->kgn", qs, rows[..., :hd]) / math.sqrt(hd)
+        pr = np.exp(sc - sc.max(-1, keepdims=True))
+        want = np.einsum("kgn,nkd->kgd", pr / pr.sum(-1, keepdims=True),
+                         rows[..., hd:]).reshape(kvh * g, hd)
+        assert np.abs(np.asarray(got[s]) - want).max() <= 2e-6
+
+
 # -- the models with one cache group: the parent's programs ---------------------
 
 # sha256 (16 hex) of str(jax.make_jaxpr(...)) at the sizes below, computed on

@@ -455,6 +455,63 @@ def test_block_row_kernel_against_the_gather(block, group):
                                   np.asarray(arena)[:, :, 0])
 
 
+# (ts a slot, the frozen slots): pages of 8 rows and B = 4, so a block sits
+# at a page's first (ts % 8 == 0) or last (ts % 8 == 4) block position; a
+# group of the walk is 4 pages and the table of 9 pages three groups
+_BLOCK_STREAMS = {
+    "first_block_position": ([0, 8, 32, 64, 24, 40], []),
+    "last_block_position": ([4, 12, 28, 68, 36, 60], []),
+    "frozen_first_last_and_between": ([8, 4, 36, 16, 64, 28, 68, 0],
+                                      [0, 3, 4, 7]),
+    "one_live_slot": ([8, 60, 16], [0, 2]),
+    "no_live_slot": ([8, 60, 16], [0, 1, 2]),
+    "few_pages_beside_many": ([0, 68, 4, 64, 8, 60], []),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BLOCK_STREAMS))
+def test_block_rows_stream_across_slots_and_leak_nothing(case):
+    """B = 4 block rows through the walk that is ONE stream of page groups
+    across a call's live slots: the outputs and the WHOLE arena against the
+    gather, and bit for bit against the same slots run one a call."""
+    ts, frozen = _BLOCK_STREAMS[case]
+    S, B, kvh, group, hd, bs, P = len(ts), 4, 2, 8, 8, 8, 9
+    rng = np.random.default_rng(len(case))
+    cfg = sdar.SdarConfig(**{**_sizes(), "heads": kvh * group,
+                             "kv_heads": kvh, "head_dim": hd,
+                             "block_length": B, "denoising_steps": 1})
+    arena = jnp.asarray(rng.normal(size=(2, 1, 1 + S * P, kvh, bs, 2 * hd)),
+                        jnp.float32)
+    table = jnp.asarray(1 + rng.permutation(S * P).reshape(S, P), jnp.int32)
+    ts = jnp.asarray(ts, jnp.int32)
+    done = jnp.asarray(np.isin(np.arange(S), frozen))
+    q = jnp.asarray(rng.normal(size=(S, B, kvh * group, hd)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(S, B, kvh, hd)), jnp.float32)
+            for _ in range(2))
+    got, after = paged_attention(q, k, v, arena, 1, table, ts, done)
+    outs, alone = [], arena
+    for s in range(S):
+        one = slice(s, s + 1)
+        out, alone = paged_attention(q[one], k[one], v[one], alone, 1,
+                                     table[one], ts[one], done[one])
+        outs.append(out)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(jnp.concatenate(outs, 0)))
+    np.testing.assert_array_equal(np.asarray(after), np.asarray(alone))
+    want, gathered = sdar._attend_block(cfg, q, k, v, arena, 1, table, ts,
+                                        done, "gather")
+    live = ~np.asarray(done)
+    if live.any():
+        assert float(jnp.abs(got - want)[live].max()) <= 2e-6
+    assert not np.asarray(got)[~live].any()               # frozen: zeros
+    # the gather sends a frozen slot's rows to scratch block 0; the kernel
+    # writes nothing of it: every other block is the same
+    np.testing.assert_array_equal(np.asarray(after)[:, :, 1:],
+                                  np.asarray(gathered)[:, :, 1:])
+    np.testing.assert_array_equal(np.asarray(after)[:, :, 0],
+                                  np.asarray(arena)[:, :, 0])
+
+
 def test_block_rows_refuse_a_bound_and_a_block_that_straddles_a_page():
     q = jnp.zeros((2, 4, 4, 8), jnp.float32)
     k = jnp.zeros((2, 4, 2, 8), jnp.float32)
@@ -497,15 +554,20 @@ def test_block_causal_flash_forward_against_the_masked_attention(rows,
 # again at PR 45, whose review made a layer that holds a SHARE of the experts
 # combine by a select, pick by pick (models/_experts._weighted_sum: a pick held
 # elsewhere no longer multiplies the routed buffer's never-written last row
-# by 0); Mellum, which holds every expert, keeps the parent's.
+# by 0); Mellum, which holds every expert, keeps the parent's. The three that
+# hold `_grouped_kernel` (`kernel.paged_attention_grouped` and the two
+# `.decode.kernel`) were computed again at PR 53 (parent d089719) on its own
+# final tree: the kernel's walk became ONE stream of page groups across
+# slots (an SMEM state of three, a `while` to the next live slot), so its
+# jaxpr changed; the five rows without it pass with the hash they had.
 PARENT = {
     "command_a.decode": "87a4936e25c1afe3",
-    "command_a.decode.kernel": "9b411f0bb25f295c",
+    "command_a.decode.kernel": "ae8b8522df04089f",
     "command_a.prefill": "f58604d02312aebd",
     "kernel.flash_causal_rows.window": "f29109e6f9230434",
-    "kernel.paged_attention_grouped": "803cdca448cca1da",
+    "kernel.paged_attention_grouped": "10f00b62d70ba722",
     "mellum.decode": "47cc377bf4679650",
-    "mellum.decode.kernel": "f4b2b16be7a202e9",
+    "mellum.decode.kernel": "7f16176521ad27bc",
     "mellum.prefill": "4150004fe0d428a5",
 }
 _MELLUM = dict(vocab_size=211, hidden=64, layers=4, heads=4, kv_heads=1,

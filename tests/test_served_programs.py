@@ -37,6 +37,12 @@ picks by a select, pick by pick (`models/_experts._weighted_sum`), so that a
 pick held elsewhere never multiplies the routed buffer's never-written last
 row by 0; every model that holds all its experts traces what it traced.
 
+`mellum.decode.tpu`, `command_a.decode.tpu`, `sdar.block.kernel` and
+`sdar.block.tpu` were computed again at PR 53 (parent d089719) on its own
+final tree: they hold `ops/paged_attention._grouped_kernel`, whose decode walk
+became ONE stream of page groups across slots; every other row, the gather
+programs of the same three models among them, passes with the hash it had.
+
 Mellum's and command-a's programs are pinned by tests/test_sdar.py::PARENT,
 the CPU's pair of GPT, Moonlight and Xing by tests/test_mellum.py::PARENT.
 The CPU gives identity, never a time.
@@ -94,14 +100,14 @@ PARENT = {
     "xing.prefill.tpu": "13b71fc4b5b0dd35",
     "xing.decode.tpu": "8660304e6bd49c29",
     "mellum.prefill.tpu": "db3a92150d64f7ea",
-    "mellum.decode.tpu": "ed45a5314893c6c7",
+    "mellum.decode.tpu": "a52b812dffbacd36",
     "command_a.prefill.tpu": "50700700e8c46ab4",
-    "command_a.decode.tpu": "2904706132e92170",
+    "command_a.decode.tpu": "a39b15511bffd6be",
     "sdar.prefill": "f13aba2086490cba",
     "sdar.block": "56b73e3a985d01c4",
-    "sdar.block.kernel": "2b1f8c44da608615",
+    "sdar.block.kernel": "6d0832f8eeaa0593",
     "sdar.prefill.tpu": "5d9996e6bffff0f8",
-    "sdar.block.tpu": "d7eb2cbb142f7631",
+    "sdar.block.tpu": "d6c8ad5bad4fa3aa",
     "kimi_linear.prefill": "e1a60541d88a0464",
     "kimi_linear.decode": "1dc7ee60c3e0add4",
     "kimi_linear.prefill.tpu": "5c56d048f319ead7",

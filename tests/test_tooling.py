@@ -71,12 +71,14 @@ def test_timeline_cli_without_xprof(tmp_path):
         assert "Traceback" not in r.stderr, (cli, r.stderr)
 
 
-def test_bench_latent_decode_measures_on_a_chip_or_not_at_all():
-    """tools/bench_latent_decode.py times a device kernel: on the CPU it
-    exits 1 and prints no number, it does not fall back to the
-    interpreter."""
+@pytest.mark.parametrize("tool", ["bench_latent_decode",
+                                  "bench_grouped_decode"])
+def test_a_decode_kernels_bench_measures_on_a_chip_or_not_at_all(tool):
+    """tools/bench_latent_decode.py and tools/bench_grouped_decode.py time
+    a device kernel: on the CPU they exit 1 and print no number, they do
+    not fall back to the interpreter."""
     r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools/bench_latent_decode.py")],
+        [sys.executable, os.path.join(REPO, f"tools/{tool}.py")],
         capture_output=True, text=True, timeout=120,
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 1, (r.returncode, r.stderr)

@@ -45,7 +45,7 @@ SHAPES = {"moonlight": dict(slots=32, heads=16, pages=64),
 KERNEL = "latent_paged_attention"
 
 
-def kernel_seconds(trace_dir):
+def kernel_seconds(trace_dir, kernel=KERNEL):
     """(events, seconds) of the kernel on the first chip of a trace."""
     from jax.profiler import ProfileData
 
@@ -58,7 +58,7 @@ def kernel_seconds(trace_dir):
             if line.name != "XLA Ops":
                 continue
             durs = [ev.duration_ns for ev in line.events
-                    if KERNEL in ev.name.split("=")[0]]
+                    if kernel in ev.name.split("=")[0]]
             if durs:
                 return len(durs), sum(durs) * 1e-9
     return 0, 0.0
