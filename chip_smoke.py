@@ -556,6 +556,104 @@ def phase_state_group(hidden=256, heads=2, head_dim=128, rank=128, rope=128,
 
 
 # ---------------------------------------------------------------------------
+# phase 2e: a state-space mixer's state beside a paged cache
+# ---------------------------------------------------------------------------
+
+def phase_state_space(hidden=256, heads=2, kv_heads=1, mamba_heads=8,
+                      mamba_head_dim=64, mamba_state=128, width=128,
+                      experts=8, picks=3, vocab=512, prompt_lens=(200, 70),
+                      max_news=(18, 3), bucket=256, page=128, chunk=64,
+                      force_kernels=False):
+    """Two requests, one behind the other, of a small hybrid model
+    (models/granite_hybrid: three Mamba-2 layers and one position-free
+    grouped-query attention layer, lane-aligned widths, bfloat16, half of
+    the experts held) through the engine: the flash prefill of the
+    attention layer at the published scale and the chunked scan of the
+    mamba layers ending MID-BUCKET (a prompt of 200 rows in a bucket of
+    256, in chunks of 64: the state written is the one AT row 200; one of
+    70 in a bucket of 128), then chunks of recurrent steps through
+    ops/ssd_step beside the grouped paged kernel, the slot's state a block
+    of a float32 state group. Every served token's logit against its
+    position's largest by the program's own float32 forward over the whole
+    sequence (no cache, no kernel). `force_kernels` (the CPU test): take
+    the kernel paths interpreted."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.models import _grouped, granite_hybrid
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    cfg = granite_hybrid.GraniteHybridConfig(
+        vocab_size=vocab, hidden=hidden, layers=4, heads=heads,
+        kv_heads=kv_heads,
+        layer_types=("mamba", "attention", "mamba", "mamba"),
+        mamba_heads=mamba_heads, mamba_head_dim=mamba_head_dim,
+        mamba_state=mamba_state, mamba_chunk=chunk, moe_intermediate=width,
+        shared_intermediate=2 * width, n_routed_experts=experts,
+        experts_per_tok=picks, experts_held=(experts // 2, experts // 2),
+        max_pos=max(4 * page, 2 * bucket), init_range=0.05,
+        name="granite-hybrid-smoke")
+    params = granite_hybrid.init_params(cfg, jax.random.PRNGKey(54),
+                                        jnp.bfloat16)
+    forced = (_grouped.decode_attention_path, granite_hybrid.recurrence_path,
+              _grouped.prefill_attention_path)
+    if force_kernels:
+        # the programs' and the verdicts' one source
+        _grouped.decode_attention_path = \
+            lambda a, c=None: {"full": "paged_kernel"}
+        granite_hybrid.recurrence_path = lambda cfg: "kernel"
+        _grouped.prefill_attention_path = lambda a, b, c=None: "flash"
+    try:
+        engine = ServingEngine(params, cfg, ServingConfig(
+            num_slots=2, prefill_buckets=(bucket // 2, bucket),
+            max_len=cfg.max_pos, block_size=page, decode_chunk=8))
+        rng = np.random.default_rng(54)
+        served = []
+        for prompt_len, max_new in zip(prompt_lens, max_news):
+            prompt = rng.integers(0, vocab, prompt_len)
+            req = engine.submit(prompt, max_new)
+            engine.run_until_drained()
+            served.append((prompt, req, max_new))
+        stats = engine.stats()
+        wide = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params)
+        worst = 0.0
+        for prompt, req, max_new in served:
+            _require(req.state == "finished" and len(req.tokens) == max_new,
+                     f"state_space: {len(req.tokens)} of {max_new} tokens "
+                     "served")
+            logits = np.asarray(granite_hybrid.forward_logits(
+                wide, cfg, jnp.asarray(list(prompt) + list(req.tokens)))
+            )[len(prompt) - 1:-1]
+            deficit = logits.max(-1) - logits[np.arange(max_new),
+                                              np.asarray(req.tokens)]
+            # the logits are the published ones, after / logits_scaling
+            _require(float(deficit.max())
+                     <= LOGIT_MARGIN / cfg.logits_scaling,
+                     f"state_space: a served token lies {float(deficit.max())}"
+                     " under its position's best logit")
+            worst = max(worst, float(deficit.max()))
+    finally:
+        (_grouped.decode_attention_path, granite_hybrid.recurrence_path,
+         _grouped.prefill_attention_path) = forced
+    state = stats["state"]
+    _require(stats["decode_attention"] == {"full": "paged_kernel"}
+             and state["recurrence_path"] == "kernel"
+             and stats["prefill_attention"]["path"] == "flash",
+             "state_space: a step gathered or the recurrence ran in XLA: "
+             f"{stats['decode_attention']}, {state}, "
+             f"{stats['prefill_attention']}")
+    _require(state["peak_blocks_used"] == 2 and state["blocks_used"] == 0,
+             f"state_space: the slot's two state blocks: {state}")
+    _require(stats["ssd_state_steps"] == 3 * (sum(max_news) - len(max_news))
+             and stats["ssd_prefill_rows"] == 3 * sum(prompt_lens),
+             f"state_space: {stats['ssd_state_steps']} state steps, "
+             f"{stats['ssd_prefill_rows']} prefill rows")
+    return {"state": state, "ssd_state_steps": stats["ssd_state_steps"],
+            "ssd_prefill_rows": stats["ssd_prefill_rows"],
+            "max_logit_deficit": worst}
+
+
+# ---------------------------------------------------------------------------
 # phase 3: train
 # ---------------------------------------------------------------------------
 
@@ -997,6 +1095,7 @@ def main():
     run("share_kernels", phase_share_kernels)
     run("block_diffusion", phase_block_diffusion)
     run("state_group", phase_state_group)
+    run("state_space", phase_state_space)
     # the published context (tiled kernels), then s=512 (single-pass)
     long_run = run("train_s1024", phase_train, cfg, batch=8, seq=1024,
                    reference=True)

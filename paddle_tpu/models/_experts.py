@@ -59,8 +59,8 @@ from __future__ import annotations
 from ..serving.model import ServingModel
 from . import _decoder
 
-__all__ = ["route", "held_experts", "router_width", "expert_product_path",
-           "grouped_experts",
+__all__ = ["route", "held_experts", "checked_share", "router_width",
+           "expert_product_path", "grouped_experts",
            "combine_path", "moe", "experts", "ffn", "swiglu", "swiglu_hidden",
            "zero_counters", "counter_names", "counters", "ExpertBlockModel",
            "COMBINE_KERNEL_FROM", "HELD_SLACK", "HELD_SPLIT_FROM"]
@@ -132,6 +132,27 @@ def held_experts(cfg):
     where the token lives and is added by every chip alike."""
     held = getattr(cfg, "experts_held", None)
     return (0, cfg.n_routed_experts) if held is None else tuple(held)
+
+
+def checked_share(experts_held, n_routed_experts, vocab_slice, vocab_size):
+    """A config's two keys that name a chip's SHARE, checked and in their
+    stored form: `experts_held` None (all) or (first, count) among
+    `n_routed_experts`; `vocab_slice` None (the whole vocabulary) or
+    (first, rows, of) with `rows` the `vocab_size` held."""
+    if experts_held is not None:
+        first, count = experts_held
+        if not (0 <= first and 0 < count
+                and first + count <= n_routed_experts):
+            raise ValueError(f"experts_held {experts_held!r} are not "
+                             f"experts of {n_routed_experts}")
+        experts_held = (int(first), int(count))
+    if vocab_slice is None:
+        vocab_slice = (0, vocab_size, vocab_size)
+    if vocab_slice[1] != vocab_size or sum(vocab_slice[:2]) > vocab_slice[2]:
+        raise ValueError(f"vocab_slice {vocab_slice!r} (first, rows, of) "
+                         f"does not name {vocab_size} rows of a "
+                         "vocabulary")
+    return experts_held, tuple(int(n) for n in vocab_slice)
 
 
 def router_width(cfg):

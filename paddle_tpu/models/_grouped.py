@@ -52,7 +52,7 @@ class GroupedConfig:
                  head_dim, moe_intermediate, n_routed_experts,
                  experts_per_tok, rms_eps, rope_theta, max_pos, init_range,
                  name, layer_types=None, sliding_window=None,
-                 rope_scaling=None):
+                 rope_scaling=None, attention_scale=None):
         if heads % kv_heads:
             raise ValueError(f"{heads} query heads do not share {kv_heads} "
                              "KV heads evenly")
@@ -85,6 +85,9 @@ class GroupedConfig:
         # the FULL layers' positions: None or YaRN's dict as
         # _decoder.rope_frequencies reads it
         self.rope_scaling = rope_scaling
+        # the softmax scale where a model publishes one (None:
+        # head_dim^-0.5)
+        self.attention_scale = attention_scale
         self.max_pos = max_pos
         self.init_range = init_range
         self.name = name
@@ -160,7 +163,8 @@ def attend_rows(cfg, q, k, v, kind, flash, real_len=None, block=None):
     j <= i | (block - 1). `real_len`: the rows that are not padding, which
     the flash forward neither visits nor returns (zeros)."""
     import jax.numpy as jnp
-    scale = 1.0 / math.sqrt(cfg.head_dim)
+    scale = 1.0 / math.sqrt(cfg.head_dim) if cfg.attention_scale is None \
+        else cfg.attention_scale
     window = cfg.sliding_window if kind == "window" else None
     if flash:
         from ..ops.flash_attention import flash_causal_rows
@@ -246,7 +250,9 @@ def _gather_attend(cfg, q, rows, keep):
     S, d = q.shape[0], cfg.head_dim
     qg = q.reshape(S, cfg.kv_heads, cfg.group, d)
     s = jnp.einsum("skgd,skld->skgl", qg, rows[..., :d],
-                   preferred_element_type=jnp.float32) / math.sqrt(d)
+                   preferred_element_type=jnp.float32)
+    s = s / math.sqrt(d) if cfg.attention_scale is None \
+        else s * cfg.attention_scale
     s = jnp.where(keep[:, None, None, :], s, -1e30)
     p = jnp.exp(s - jnp.max(s, -1, keepdims=True))
     p = (p / p.sum(-1, keepdims=True)).astype(rows.dtype)
@@ -265,6 +271,11 @@ def attend_step(cfg, q, k, v, arena, lg, table, ts, done, lo, kind, path):
     bs, dtype = arena.shape[4], arena.dtype
     if path == "paged_kernel":
         from ..ops.paged_attention import paged_attention
+        if cfg.attention_scale is not None:
+            # the kernel scales by head_dim^-0.5: the rest of a published
+            # scale rides on q
+            q = (q.astype(jnp.float32) * (cfg.attention_scale * math.sqrt(
+                cfg.head_dim))).astype(q.dtype)
         return paged_attention(q, k, v, arena, lg, table, ts, done,
                                lo=None if kind == "full" else lo)
     page = ts // bs

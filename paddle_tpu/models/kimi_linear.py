@@ -94,7 +94,7 @@ import weakref
 
 from ..serving import pages as _pages
 from ..serving.model import CacheSpec, group_columns
-from . import _decoder, _experts, _latent
+from . import _decoder, _experts, _latent, _recurrent
 
 __all__ = ["KimiLinearConfig", "init_params", "forward_logits",
            "prefill_pages", "decode_step_pages", "kda_chunked", "kda_step",
@@ -111,7 +111,8 @@ KDA_SUB = 16
 # bfloat16 products built would be a state kept in a lower precision.
 KDA_PRECISION = "highest"
 
-LATENT, STATE, CONV = "latent", "state", "conv"
+LATENT, STATE, CONV = "latent", _recurrent.STATE, _recurrent.CONV
+GROUPS = (LATENT, STATE, CONV)
 
 
 def _published_kinds(layers):
@@ -160,20 +161,8 @@ class KimiLinearConfig:
                 f"kda_layers {kda_layers} and full_attn_layers "
                 f"{full_attn_layers} do not name each of {layers} layers "
                 "once (1-indexed, as published)")
-        if experts_held is not None:
-            first, count = experts_held
-            if not (0 <= first and 0 < count
-                    and first + count <= n_routed_experts):
-                raise ValueError(f"experts_held {experts_held!r} are not "
-                                 f"experts of {n_routed_experts}")
-            experts_held = (int(first), int(count))
-        if vocab_slice is None:
-            vocab_slice = (0, vocab_size, vocab_size)
-        if vocab_slice[1] != vocab_size \
-                or sum(vocab_slice[:2]) > vocab_slice[2]:
-            raise ValueError(f"vocab_slice {vocab_slice!r} (first, rows, of) "
-                             f"does not name {vocab_size} rows of a "
-                             "vocabulary")
+        experts_held, vocab_slice = _experts.checked_share(
+            experts_held, n_routed_experts, vocab_slice, vocab_size)
         self.vocab_size = vocab_size
         self.hidden = hidden
         self.layers = layers
@@ -196,7 +185,7 @@ class KimiLinearConfig:
         self.routed_scaling_factor = routed_scaling_factor
         self.rms_eps = rms_eps
         self.experts_held = experts_held
-        self.vocab_slice = tuple(int(n) for n in vocab_slice)
+        self.vocab_slice = vocab_slice
         # ASSUMED (not keys of the published config): the two low-rank
         # pairs' rank (None: the head size), the l2 norm's eps, the
         # state's type
@@ -246,25 +235,19 @@ class KimiLinearConfig:
     def conv_shape(self):
         """A slot's convolution history of one layer, the last
         `short_conv_kernel_size - 1` pre-activation rows of q|k|v, as
-        the block stores it: whole lanes where the values fill them
-        (288 x 128 at the published widths: no row of the block is
-        padding), else the rows as they are."""
-        rows, width = self.short_conv_kernel_size - 1, 3 * self.kda_width
-        if (rows * width) % _pages.LANES == 0:
-            return (1, rows * width // _pages.LANES, _pages.LANES)
-        return (1, rows, width)
+        the block stores it (288 x 128 at the published widths)."""
+        return _recurrent.history_shape(self.short_conv_kernel_size - 1,
+                                        3 * self.kda_width)
 
     def cache_specs(self):
         """The three cache groups: the latent rows (primary), the
         recurrent state and the convolution's history (state groups)."""
-        n_kda = len(self.kda_layers)
         return (CacheSpec(len(self.full_attn_layers), 1, self.row_width,
                           name=LATENT),
-                CacheSpec(n_kda, self.kda_heads, self.kda_head_dim,
-                          name=STATE, state=True, dtype=self.state_dtype,
-                          state_shape=self.state_shape),
-                CacheSpec(n_kda, 1, 3 * self.kda_width, name=CONV,
-                          state=True, state_shape=self.conv_shape))
+                *_recurrent.specs(
+                    len(self.kda_layers), self.kda_heads, self.kda_head_dim,
+                    self.state_shape, self.state_dtype,
+                    self.short_conv_kernel_size - 1, 3 * self.kda_width))
 
     def serving_model(self):
         if self.name == KIMI_LINEAR_SERVING_MODEL.name:
@@ -438,19 +421,6 @@ def _kda_gate(cfg, lp, o, z):
         return y.reshape(T, -1).astype(z.dtype) @ lp["wo"]
 
 
-def _conv_rows(cfg, lp, padded, T):
-    """The causal depthwise convolution's sum over `padded` (K - 1 + T,
-    3C), the K - 1 rows of history first: (T, 3C) float32."""
-    import jax.numpy as jnp
-    K = cfg.short_conv_kernel_size
-    w = lp["conv_w"].astype(jnp.float32)
-    x = padded.astype(jnp.float32)
-    out = w[0] * x[:T]
-    for i in range(1, K):
-        out = out + w[i] * x[i:i + T]
-    return out
-
-
 def kda_step(S, q, k, v, g, beta):
     """The recurrence, one position: S (..., dk, dv) float32, q, k, g
     (..., dk), v (..., dv), beta (...,). Returns (S_t, o_t)."""
@@ -560,14 +530,10 @@ def _kda_prompt(cfg, lp, u, real_len, path):
     import jax
     import jax.numpy as jnp
     B = u.shape[0]
-    K = cfg.short_conv_kernel_size
     qkv, a, b, z = _kda_project(cfg, lp, u)
     with jax.named_scope("kda/conv"):
-        padded = jnp.pad(qkv, ((K - 1, 0), (0, 0)))
-        # rows real_len - (K - 1) .. real_len - 1, zeros before row 0
-        hist = jax.lax.dynamic_slice_in_dim(padded, real_len, K - 1, 0)
-        q, k, v, g, beta = _kda_activate(
-            cfg, lp, _conv_rows(cfg, lp, padded, B), a, b)
+        summed, hist = _recurrent.conv_prompt(qkv, lp["conv_w"], real_len)
+        q, k, v, g, beta = _kda_activate(cfg, lp, summed, a, b)
         live = jnp.arange(B) < real_len
         g = jnp.where(live[:, None, None], g, 0.0)
         beta = jnp.where(live[:, None], beta, 0.0)
@@ -581,13 +547,6 @@ def _kda_prompt(cfg, lp, u, real_len, path):
     return _kda_gate(cfg, lp, o, z), S, hist, visited
 
 
-def _state_block(ids, done):
-    """Where a slot's state block is written: its own, or scratch block 0
-    for a frozen slot."""
-    import jax.numpy as jnp
-    return ids if done is None else jnp.where(done, 0, ids)
-
-
 def kda_step_inputs(cfg, lp, u, arenas, lg, conv_ids, done):
     """`kda/project` and `kda/conv` of a step: for every slot's row u (S,
     h) the recurrence's operands q, k, v, g (S, n, d), beta (S, n), all
@@ -596,18 +555,10 @@ def kda_step_inputs(cfg, lp, u, arenas, lg, conv_ids, done):
     moves one row on (a frozen slot's to scratch). Returns (q, k, v, g,
     beta, z, arenas)."""
     import jax
-    import jax.numpy as jnp
-    s_dim = u.shape[0]
-    K = cfg.short_conv_kernel_size
     qkv, a, b, z = _kda_project(cfg, lp, u)
     with jax.named_scope("kda/conv"):
-        conv = arenas[CONV]
-        hist = conv[lg, 0, conv_ids].reshape(s_dim, K - 1, -1)
-        window = jnp.concatenate([hist, qkv[:, None].astype(hist.dtype)], 1)
-        w = lp["conv_w"].astype(jnp.float32)
-        summed = jnp.sum(window.astype(jnp.float32) * w[None], 1)
-        arenas[CONV] = conv.at[lg, 0, _state_block(conv_ids, done)].set(
-            window[:, 1:].reshape((s_dim,) + conv.shape[3:]))
+        summed, arenas[CONV] = _recurrent.conv_step(
+            arenas[CONV], lg, conv_ids, done, qkv, lp["conv_w"])
         q, k, v, g, beta = _kda_activate(cfg, lp, summed, a, b)
     return q, k, v, g, beta, z, arenas
 
@@ -621,7 +572,6 @@ def kda_state_update(arenas, lg, state_ids, done, q, k, v, g, beta, path):
     numeric check of the cell `kimi-linear-longgen-offline`, on the
     engine's own blocks (benchmarks/modes/serve-closed-kimi-linear.py)."""
     import jax
-    import jax.numpy as jnp
     with jax.named_scope("kda/recur"):
         state = arenas[STATE]
         if path == "kernel":
@@ -629,11 +579,10 @@ def kda_state_update(arenas, lg, state_ids, done, q, k, v, g, beta, path):
             o, arenas[STATE] = kda_step_blocks(
                 state, lg, state_ids, done, q, k, v, g, beta)
         else:
-            S, o = kda_step(state[lg, 0, state_ids].astype(jnp.float32),
+            S, o = kda_step(_recurrent.read_blocks(state, lg, state_ids),
                             q, k, v, g, beta)
-            arenas[STATE] = state.at[
-                lg, 0, _state_block(state_ids, done)].set(
-                    S.astype(state.dtype))
+            arenas[STATE] = _recurrent.write_blocks(state, lg, state_ids,
+                                                    done, S)
     return o, arenas
 
 
@@ -655,14 +604,6 @@ def _zero_counters(cfg):
     return dict(_experts.zero_counters(cfg), kda_state_steps=zero,
                 kda_prefill_rows=zero, kda_prefill_chunks=zero,
                 mla_decode_rows=zero)
-
-
-def _arenas(arena):
-    return dict(zip((LATENT, STATE, CONV), arena))
-
-
-def _arena_out(arenas):
-    return (arenas[LATENT], arenas[STATE], arenas[CONV])
 
 
 # -- the whole sequence, no cache (tests; generation never runs it) --------------
@@ -707,14 +648,14 @@ def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
     import jax
     import jax.numpy as jnp
 
-    arenas = _arenas(arena)
+    arenas = _recurrent.by_name(GROUPS, arena)
     latent = arenas[LATENT]
     B = tokens.shape[1]
     bs = latent.shape[4]
     dtype = latent.dtype
     cols = group_columns(cfg.cache_specs(), pages.shape[0], bs)
     rows = pages[cols[0]]
-    state_id, conv_id = pages[cols[1]][0], pages[cols[2]][0]
+    state_id, conv_id = _recurrent.block_ids(pages, cols[1:])
     flash = _pages.kernel_beside(bucket=B)
     recurrence = prefill_recurrence_path(cfg, B)
     _PREFILLS_TRACED.setdefault(cfg, {})[B] = recurrence
@@ -733,11 +674,11 @@ def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
                                               recurrence)
             chunks = chunks + visited
             with jax.named_scope("kda/recur"):
-                arenas[STATE] = arenas[STATE].at[lg, 0, state_id].set(
-                    S.astype(arenas[STATE].dtype))
+                arenas[STATE] = _recurrent.write_block(arenas[STATE], lg,
+                                                       state_id, S)
             with jax.named_scope("kda/conv"):
-                arenas[CONV] = arenas[CONV].at[lg, 0, conv_id].set(
-                    hist.reshape(arenas[CONV].shape[3:]))
+                arenas[CONV] = _recurrent.write_block(arenas[CONV], lg,
+                                                      conv_id, hist)
         else:
             y, arenas[LATENT] = _latent.prefill_attend(
                 cfg, lp, x, j, pos, arenas[LATENT], lg, rows, pfx_len,
@@ -749,7 +690,7 @@ def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
                                     ).astype(jnp.int32)
     counters["kda_prefill_chunks"] = jnp.asarray(chunks, jnp.int32)
     last = x[real_len - 1][None]
-    return _decoder.head(cfg, params, last), _arena_out(arenas), counters
+    return _decoder.head(cfg, params, last), _recurrent.in_order(GROUPS, arenas), counters
 
 
 # -- decode through the pages and the state blocks --------------------------------
@@ -771,13 +712,13 @@ def decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
     import jax
     import jax.numpy as jnp
 
-    arenas = _arenas(arena)
+    arenas = _recurrent.by_name(GROUPS, arena)
     s_dim = pt.shape[0]
     bs = arenas[LATENT].shape[4]
     dtype = arenas[LATENT].dtype
     cols = group_columns(cfg.cache_specs(), pt.shape[1], bs)
     table = pt[:, cols[0]]
-    state_ids, conv_ids = pt[:, cols[1]][:, 0], pt[:, cols[2]][:, 0]
+    state_ids, conv_ids = _recurrent.block_ids(pt, cols[1:])
     if attention is None:
         attention = decode_attention_path(arena)
     if recurrence is None:
@@ -804,7 +745,7 @@ def decode_step_pages(params, cfg, tokens, arena, pt, ts, done=None,
     counters["mla_decode_rows"] = (
         jnp.sum(jnp.where(live, ts + 1, 0)).astype(jnp.int32)
         * len(cfg.full_attn_layers))
-    return _decoder.head(cfg, params, x), _arena_out(arenas), counters
+    return _decoder.head(cfg, params, x), _recurrent.in_order(GROUPS, arenas), counters
 
 
 # -- the engine's view of this model ---------------------------------------------

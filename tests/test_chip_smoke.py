@@ -29,7 +29,8 @@ def test_main_exits_nonzero_on_cpu_before_any_work(monkeypatch, capsys):
         raise AssertionError("a phase ran without a TPU")
 
     for name in ("phase_kernels", "phase_share_kernels",
-                 "phase_block_diffusion", "phase_state_group", "phase_train",
+                 "phase_block_diffusion", "phase_state_group",
+                 "phase_state_space", "phase_train",
                  "phase_serve"):
         monkeypatch.setattr(chip_smoke, name, no_work)
     assert jax.default_backend() == "cpu"
@@ -50,6 +51,7 @@ def test_last_stdout_line_is_the_verdict_and_the_device(monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, "phase_share_kernels", lambda: {})
     monkeypatch.setattr(chip_smoke, "phase_block_diffusion", lambda: {})
     monkeypatch.setattr(chip_smoke, "phase_state_group", lambda: {})
+    monkeypatch.setattr(chip_smoke, "phase_state_space", lambda: {})
     monkeypatch.setattr(
         chip_smoke, "phase_train",
         lambda *a, **kw: {"losses": [2.0, 1.0], "scope": None})
@@ -126,6 +128,23 @@ def test_state_group_phase_interpreted():
     assert facts["kda_state_steps"] == 4 * (9 + 2)
     assert facts["kda_prefill_chunks"] == 4 * (3 + 2)
     assert facts["max_logit_deficit"] <= chip_smoke.LOGIT_MARGIN
+
+
+def test_state_space_phase_interpreted():
+    """The phase at a small size with the kernel paths forced: the flash
+    forward and the grouped paged kernel at the published scale interpreted
+    beside the step's kernel over a state block, two prompts that end
+    mid-bucket (the longer one mid-chunk), a chunk and a part of steps."""
+    facts = chip_smoke.phase_state_space(
+        hidden=128, heads=2, kv_heads=1, mamba_heads=4, mamba_head_dim=64,
+        mamba_state=128, width=128, experts=4, picks=2, vocab=256,
+        prompt_lens=(200, 70), max_news=(10, 3), bucket=256, page=128,
+        force_kernels=True)
+    assert facts["state"]["recurrence_path"] == "kernel"
+    assert facts["state"]["peak_blocks_used"] == 2
+    assert facts["ssd_state_steps"] == 3 * (9 + 2)
+    assert facts["ssd_prefill_rows"] == 3 * 270
+    assert facts["max_logit_deficit"] <= chip_smoke.LOGIT_MARGIN / 16
 
 
 def test_train_then_serve_phases():

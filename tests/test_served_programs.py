@@ -43,6 +43,22 @@ final tree: they hold `ops/paged_attention._grouped_kernel`, whose decode walk
 became ONE stream of page groups across slots; every other row, the gather
 programs of the same three models among them, passes with the hash it had.
 
+`granite_hybrid.*` (PR 54's leaf: a Mamba-2 mixer on the state groups, one
+position-free attention layer through `models/_grouped.py` under its new key
+`attention_scale`) is its own tree's, the first that has it. The same PR moved Kimi-Linear's state-block and convolution-history
+plumbing into `models/_recurrent.py`, which both leaves call: the move
+itself left every `kimi_linear.*` row with the hash it had, and with the key
+at its default so do Mellum's, command-a's and SDAR's. Its review round then
+made `_recurrent.write_block` ONE form, the arena viewed as one run of
+blocks and the block put in by one `dynamic_update_slice` (what granite's
+state needed so that the TPU's compiler would not copy a 3.4 GB arena around
+one block), so `kimi_linear.prefill` and `kimi_linear.prefill.tpu` were
+computed again: the same block written to the same place by a
+`dynamic_update_slice` where an `.at[].set` stood (compiled for a described v5e at the
+cell's size: the same 44 updates and 27 scatters, temporaries 0.088 and
+0.703 GB as before; PERF.md section 6). Kimi-Linear's decode rows pass with
+the hash they had.
+
 Mellum's and command-a's programs are pinned by tests/test_sdar.py::PARENT,
 the CPU's pair of GPT, Moonlight and Xing by tests/test_mellum.py::PARENT.
 The CPU gives identity, never a time.
@@ -108,9 +124,9 @@ PARENT = {
     "sdar.block.kernel": "6d0832f8eeaa0593",
     "sdar.prefill.tpu": "5d9996e6bffff0f8",
     "sdar.block.tpu": "d6c8ad5bad4fa3aa",
-    "kimi_linear.prefill": "e1a60541d88a0464",
+    "kimi_linear.prefill": "1b18b3f38dc4f076",
     "kimi_linear.decode": "1dc7ee60c3e0add4",
-    "kimi_linear.prefill.tpu": "5c56d048f319ead7",
+    "kimi_linear.prefill.tpu": "658728eb5ae0f119",
     "kimi_linear.decode.tpu": "de2cc0338d01d47d",
     "longcat_flash.prefill": "3c68f19406e8f493",
     "longcat_flash.decode": "5154a249c2274267",
@@ -125,6 +141,11 @@ PARENT = {
     "sdar.init": "cb8aeed9efda9803",
     "kimi_linear.init": "a979e4e58ac39a0a",
     "longcat_flash.init": "d2b6fc261e496288",
+    "granite_hybrid.prefill": "7a722f63d130020d",
+    "granite_hybrid.decode": "2291079b72bbe03e",
+    "granite_hybrid.prefill.tpu": "d0c35c86d052900a",
+    "granite_hybrid.decode.tpu": "09ee5b426d1050d6",
+    "granite_hybrid.init": "e9d4705ca900bc67",
 }
 
 _YARN = {"type": "yarn", "factor": 4, "beta_fast": 32, "beta_slow": 1,
@@ -181,6 +202,20 @@ def _config(model, wide):
             n_routed_experts=8, n_shared_experts=1, experts_per_tok=2,
             experts_held=(2, 4), vocab_slice=(96, 96, 768),
             kda_decay_rank=16, kda_gate_rank=16, max_pos=256,
+            init_range=0.08), init_params
+    if model == "granite_hybrid":
+        from paddle_tpu.models.granite_hybrid import (GraniteHybridConfig,
+                                                      init_params)
+        return GraniteHybridConfig(
+            vocab_size=96, hidden=128 if wide else 64, layers=4,
+            heads=2 if wide else 4, kv_heads=1 if wide else 2,
+            layer_types=("mamba", "attention", "mamba", "mamba"),
+            mamba_heads=16 if wide else 8, mamba_head_dim=16,
+            mamba_state=128 if wide else 16, mamba_chunk=64 if wide else 8,
+            moe_intermediate=128 if wide else 32,
+            shared_intermediate=256 if wide else 48, n_routed_experts=8,
+            experts_per_tok=3, experts_held=(4, 4),
+            vocab_slice=(96, 96, 768), max_pos=256,
             init_range=0.08), init_params
     if model == "longcat_flash":
         from paddle_tpu.models.longcat_flash import (LongcatFlashConfig,
@@ -338,9 +373,11 @@ CASES = (
     + [f"longcat_flash.{program}"
        for program in ("prefill", "decode", "decode.kernel", "prefill.tpu",
                        "decode.tpu")]
+    + [f"granite_hybrid.{program}"
+       for program in ("prefill", "decode", "prefill.tpu", "decode.tpu")]
     + [f"{model}.init" for model in ("gpt", "moonlight", "xing", "mellum",
                                      "command_a", "sdar", "kimi_linear",
-                                     "longcat_flash")])
+                                     "longcat_flash", "granite_hybrid")])
 
 
 @pytest.mark.parametrize("name", CASES)

@@ -72,11 +72,11 @@ def test_timeline_cli_without_xprof(tmp_path):
 
 
 @pytest.mark.parametrize("tool", ["bench_latent_decode",
-                                  "bench_grouped_decode"])
+                                  "bench_grouped_decode", "bench_ssd_step"])
 def test_a_decode_kernels_bench_measures_on_a_chip_or_not_at_all(tool):
-    """tools/bench_latent_decode.py and tools/bench_grouped_decode.py time
-    a device kernel: on the CPU they exit 1 and print no number, they do
-    not fall back to the interpreter."""
+    """tools/bench_latent_decode.py, tools/bench_grouped_decode.py and
+    tools/bench_ssd_step.py time a device kernel: on the CPU they exit 1
+    and print no number, they do not fall back to the interpreter."""
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, f"tools/{tool}.py")],
         capture_output=True, text=True, timeout=120,
@@ -84,6 +84,23 @@ def test_a_decode_kernels_bench_measures_on_a_chip_or_not_at_all(tool):
     assert r.returncode == 1, (r.returncode, r.stderr)
     assert "a chip is required" in r.stderr and not r.stdout, (r.stdout,
                                                               r.stderr)
+
+
+def test_the_state_space_bench_rehearses_its_program_on_the_cpu():
+    """`tools/bench_ssd_step.py --tiny`: the kernel interpreted against XLA's
+    form, the chunked scan against the token-by-token one, and NO time."""
+    import json
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "tools/bench_ssd_step.py"),
+         "--tiny"], capture_output=True, text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 0, r.stderr
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert all(v["y"] < 1e-4 and v["state"] < 1e-5
+               for v in out["kernel_vs_xla"].values())
+    assert out["hold"]["y"] < 1e-4 and out["hold"]["state"] < 1e-4
+    assert all(v["layer_us"] is None for v in out["step"].values())
+    assert all(p["layer_us"] is None for p in out["prefill"])
 
 
 def test_op_bench_single_op():
