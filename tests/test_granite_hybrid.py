@@ -481,6 +481,50 @@ def test_the_step_kernel_is_the_recurrence_and_spares_a_frozen_slot():
                            jnp.exp(dt * A), B, C)
 
 
+@pytest.mark.parametrize("S,H,P,N", [(5, 8, 8, 128), (3, 128, 64, 128),
+                                     (4, 5, 64, 128)])
+def test_the_step_kernel_writes_the_float32_update_and_y_holds_float32(S, H, P, N):
+    """The interpreted kernel at a toy block (half of one MXU product's 16
+    heads), at the PUBLISHED block (128 heads of 64 x 128: 64 products of a head
+    pair) and at an odd head count (the last product one head): the state it
+    writes EQUALS `state * decay + dx * B` in float32 in that order, element
+    for element and with no tolerance. XLA's CPU backend, which interprets the
+    kernel here, is free to carry either product unrounded into the sum (a fused
+    multiply-add) and chooses by the loop it emits, so an element may be any of
+    the three float32 sums that order allows; the chip has no such instruction
+    and tools/bench_ssd_step.py holds the one there. `y` is the float64
+    contraction of that state to float32 rounding, and no block but the live
+    slots' is touched."""
+    key = jax.random.split(jax.random.PRNGKey(S + H), 6)
+    arena = jax.random.normal(key[0], (2, 1, S + 3, H, P, N))
+    ids = 1 + jax.random.permutation(key[5], S + 2)[:S]
+    done = jnp.arange(S) % 3 == 1
+    x = jax.random.normal(key[1], (S, H, P))
+    dt = jnp.exp(jax.random.uniform(key[2], (S, H), minval=-7.0, maxval=0.0))
+    decay = jnp.exp(-dt * jnp.arange(1, H + 1) / H)
+    B, C = jax.random.normal(key[3], (S, N)), jax.random.normal(key[4], (S, N))
+    y, new = ss.ssd_step_blocks(arena, 1, ids, done, x, dt, decay, B, C)
+    live = np.asarray(~done)
+    kept, fell = (np.asarray(a)[live] for a in (
+        arena[1, 0, ids] * decay[..., None, None],
+        (x * dt[..., None])[..., None] * B[:, None, None, :]))
+    wide = lambda a, b: (np.asarray(a, np.float64)[live]     # a product, unrounded
+                         * np.asarray(b, np.float64)[live])
+    got = np.asarray(new[1, 0, ids])[live]
+    assert ((got == kept + fell)
+            | (got == (wide(arena[1, 0, ids], decay[..., None, None]) + fell
+                       ).astype(np.float32))
+            | (got == (kept + wide((x * dt[..., None])[..., None],
+                                   B[:, None, None, :])).astype(np.float32))).all()
+    y64 = np.einsum("shpn,sn->shp", got.astype(np.float64),
+                    np.asarray(C, np.float64)[live])
+    assert y.shape == (S, H, P) and y.dtype == jnp.float32
+    assert np.linalg.norm(np.asarray(y)[live] - y64) <= 1e-6 * np.linalg.norm(y64)
+    untouched = np.setdiff1d(np.arange(1, S + 3), np.asarray(ids)[live])
+    assert bool((new[1, 0, untouched] == arena[1, 0, untouched]).all())
+    assert bool((new[0] == arena[0]).all())
+
+
 @pytest.mark.parametrize("path,kept_in", [("xla", "float32"), ("kernel", "float32"),
                                           ("xla", "bfloat16")])
 def test_the_steps_two_halves_are_the_step_and_the_reference(params, path, kept_in):
@@ -555,9 +599,10 @@ def one_chip():
 def test_the_step_kernel_compiles_for_a_described_v5e(one_chip, frozen):
     """The published widths (128 heads of 64 x 128 float32, a 4 MB block a
     slot) over the cell's 96 slots and nine layers, without and with frozen
-    slots sent to scratch: the lane-sliced column reads and stores and the 16
-    MB of double-buffered blocks are Mosaic's to refuse, and nothing of the
-    arena's size is made beside the call."""
+    slots sent to scratch: the lane-sliced column reads, the decay's 48 KB of
+    scalar memory, the 64 transposed-operand products at `HIGHEST` with their
+    one-row stores and the 16 MB of double-buffered blocks are Mosaic's to
+    refuse, and nothing of the arena's size is made beside the call."""
     def shape(*dims, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 

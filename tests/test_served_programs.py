@@ -59,6 +59,14 @@ cell's size: the same 44 updates and 27 scatters, temporaries 0.088 and
 0.703 GB as before; PERF.md section 6). Kimi-Linear's decode rows pass with
 the hash they had.
 
+`granite_hybrid.decode.tpu` was computed again at PR 55 (parent 4fa0813) on
+its own final tree: it holds `ops/ssd_step._kernel`, which takes the decay as
+a third scalar-prefetched operand and dt x alone as its columns, and gives
+`y` as rows of two heads (its contraction is the MXU's). Every other row
+passes with the hash it had, `granite_hybrid.decode` (the CPU's
+gather-update-scatter), `.prefill`, `.prefill.tpu` and `.init` among them:
+nothing that two leaves share moved.
+
 Mellum's and command-a's programs are pinned by tests/test_sdar.py::PARENT,
 the CPU's pair of GPT, Moonlight and Xing by tests/test_mellum.py::PARENT.
 The CPU gives identity, never a time.
@@ -144,7 +152,7 @@ PARENT = {
     "granite_hybrid.prefill": "7a722f63d130020d",
     "granite_hybrid.decode": "2291079b72bbe03e",
     "granite_hybrid.prefill.tpu": "d0c35c86d052900a",
-    "granite_hybrid.decode.tpu": "09ee5b426d1050d6",
+    "granite_hybrid.decode.tpu": "a4688ee096887fcc",
     "granite_hybrid.init": "e9d4705ca900bc67",
 }
 

@@ -87,8 +87,10 @@ def test_a_decode_kernels_bench_measures_on_a_chip_or_not_at_all(tool):
 
 
 def test_the_state_space_bench_rehearses_its_program_on_the_cpu():
-    """`tools/bench_ssd_step.py --tiny`: the kernel interpreted against XLA's
-    form, the chunked scan against the token-by-token one, and NO time."""
+    """`tools/bench_ssd_step.py --tiny`: the kernel interpreted and XLA's form,
+    each `y` against the float64 contraction of the state it wrote, the
+    kernel's three throw-away forms built, the chunked scan against the
+    token-by-token one, and NO time."""
     import json
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools/bench_ssd_step.py"),
@@ -96,10 +98,13 @@ def test_the_state_space_bench_rehearses_its_program_on_the_cpu():
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stderr
     out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert all(v["y"] < 1e-4 and v["state"] < 1e-5
-               for v in out["kernel_vs_xla"].values())
+    assert set(out["y_vs_float64"]) == {"xla", "kernel"}
+    assert all(v < 1e-6 for v in out["y_vs_float64"].values())
+    assert isinstance(out["state_equals_xla"], bool)
     assert out["hold"]["y"] < 1e-4 and out["hold"]["state"] < 1e-4
     assert all(v["layer_us"] is None for v in out["step"].values())
+    assert all(out["step"]["kernel"][k] is None for k in (
+        "as_served", "no_y", "no_broadcasts", "arithmetic_alone"))
     assert all(p["layer_us"] is None for p in out["prefill"])
 
 

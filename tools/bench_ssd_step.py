@@ -6,10 +6,23 @@ forms, XLA's gather-update-scatter (`ssd_step` over `arena[layer, 0, ids]`)
 and the kernel ops/ssd_step.py (one grid step a slot's whole block), at the
 cell's shape: 96 slots (some frozen), 128 heads of 64 x 128 float32, 9 layers, a
 shuffled page column. The kernel is first held against the XLA form on the
-same chip (the arena compared whole but for scratch block 0). Beside each
-time stand the bytes a step must move (a live slot's state read once and
-written once: 2 x 4,194,304 B a layer a slot) over the chip's 819 GB/s, and
-the share `ssd_decode_hbm_roofline` would read. The CHUNKED SCAN of 256 ..
+same chip: the live slots' blocks EQUAL (both are `state * decay + dx * B` in
+float32 on a VPU with no fused multiply-add), and each form's `y` against a
+FLOAT64 contraction, on the host, of the state that form wrote
+(`y_vs_float64`, relative Frobenius error: the kernel's product is the
+MXU's, and a product short of float32's passes shows HERE, where the
+interpreter on the CPU computes in float32 whatever the MXU would do; the
+kernel may read twice XLA's and no more). Beside each time stand the bytes a
+step must move (a live slot's state read once and written once: 2 x 4,194,304
+B a layer a slot) over the chip's 819 GB/s, and the share
+`ssd_decode_hbm_roofline` would read. Beside the kernel `as_served` stand
+three throw-away forms of it, made here by handing the served kernel
+stand-ins for its operands and in no path of the program: `no_y` (the
+state's update and both block transfers: `y` is never stored, so its
+product is dead code), `no_broadcasts` (`y` computed, every read of the
+decay and of dt x a constant) and `arithmetic_alone` (every slot sent ONE
+block, which the pipeline then fetches once and writes once: what the VPU,
+XLU and MXU take with the DMA out of the way). The CHUNKED SCAN of 256 ..
 2,048 rows (`ssd_chunked`, plain `jax.numpy` at `highest`) with its share
 of the peak as `ssd_prefill_flops_roofline` counts it (6 x 64 x 128 FLOPs a
 row a head), and at 2,048 rows its `y` and final state against the
@@ -28,6 +41,8 @@ Prints one JSON line; the same goes to chiprun_out/bench_ssd_step.json.
 """
 
 import argparse
+import functools
+import inspect
 import json
 import os
 import sys
@@ -37,6 +52,40 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 HBM_BYTES_PER_S = 819e9          # TPU v5e (Google Cloud documentation)
 PEAK_FLOPS = 197e12
+
+
+class _Constant:
+    """Stands in for an operand of the kernel: every read is one constant."""
+
+    def __init__(self, value):
+        self.value = value
+
+    def __getitem__(self, index):
+        import jax.numpy as jnp
+        return jnp.float32(self.value)
+
+
+class _Unwritten:
+    """Stands in for an output of the kernel: a store goes nowhere."""
+
+    def __setitem__(self, index, value):
+        pass
+
+
+def throw_away(served, no_y=False, no_broadcasts=False):
+    """The served kernel with `y`'s output and / or its two broadcast
+    operands replaced by stand-ins: a diagnostic form, timed and thrown
+    away."""
+    names = list(inspect.signature(served).parameters)
+
+    def kernel(*refs, **static):
+        refs = dict(zip(names, refs))
+        if no_broadcasts:
+            refs["decay_ref"], refs["dx_ref"] = _Constant(0.9), _Constant(0.5)
+        if no_y:
+            refs["y_ref"] = _Unwritten()
+        served(*refs.values(), **static)
+    return kernel
 
 
 def main():
@@ -55,7 +104,7 @@ def main():
         return 1
     from bench_kda_step import timed
     from paddle_tpu.models import granite_hybrid as gh
-    from paddle_tpu.ops.ssd_step import ssd_step_blocks
+    from paddle_tpu.ops import ssd_step
 
     slots, H, P, N, layers = (4, 8, 8, 128, 2) if args.tiny else \
         (args.slots, 128, 64, 128, 9)
@@ -78,32 +127,43 @@ def main():
         S, y = gh.ssd_step(arena[li, 0, ids], x, dt, A, B, C)
         return y, arena.at[li, 0, jnp.where(done, 0, ids)].set(S)
 
-    def kernel_step(arena, li, done):
-        return ssd_step_blocks(arena, li, ids, done, x, dt, jnp.exp(dt * A),
-                               B, C)
+    def kernel_step(arena, li, done, ids=ids):
+        return ssd_step.ssd_step_blocks(arena, li, ids, done, x, dt,
+                                        jnp.exp(dt * A), B, C)
 
     forms = [("xla", xla_step), ("kernel", kernel_step)]
     # the kernel against XLA's form, some slots frozen
-    y_x, a_x = jax.jit(xla_step, static_argnums=1)(small, 1, done)
     live = ~np.asarray(done)
     result = {"slots": slots, "heads": H, "head_dim": P, "state": N,
-              "layers": layers, "kernel_vs_xla": {}, "step": {}, "prefill": []}
-    for name, step in forms[1:]:
-        y_k, a_k = jax.jit(step, static_argnums=1)(small + 0, 1, done)
-        err_y = float(np.abs(np.asarray(y_x) - np.asarray(y_k))[live].max())
-        err_s = float(jnp.abs(a_x[:, :, 1:] - a_k[:, :, 1:]).max())
-        if not (err_y < 1e-3 and err_s < 1e-4):
-            raise SystemExit(f"{name} disagrees with XLA's form: y {err_y}, "
-                             f"state {err_s}")
-        result["kernel_vs_xla"][name] = {"y": err_y, "state": err_s}
-        del y_k, a_k
-    del y_x, a_x, small
+              "layers": layers, "y_vs_float64": {}, "step": {}, "prefill": []}
+    wrote = {}
+    for name, step in forms:
+        y, arena = jax.jit(step, static_argnums=1)(small + 0, 1, done)
+        wrote[name] = np.asarray(arena[1, 0, ids])[live]
+        y64 = np.einsum("shpn,sn->shp", wrote[name].astype(np.float64),
+                        np.asarray(C, np.float64)[live])
+        result["y_vs_float64"][name] = float(
+            np.linalg.norm(np.asarray(y)[live] - y64) / np.linalg.norm(y64))
+        del y, arena, y64
+    result["state_equals_xla"] = bool((wrote["kernel"] == wrote["xla"]).all())
+    # (the CPU may fuse a multiply into the add in one form and not the other)
+    if not (result["state_equals_xla"] or args.tiny):
+        raise SystemExit("the kernel's blocks are not XLA's: largest "
+                         f"{np.abs(wrote['kernel'] - wrote['xla']).max()}")
+    if result["y_vs_float64"]["kernel"] > 2 * result["y_vs_float64"]["xla"]:
+        raise SystemExit("the kernel's y is further from the float64 "
+                         f"contraction than twice XLA's: {result['y_vs_float64']}")
+    del wrote, small
     holder = [0.1 * jax.random.normal(key[5], (layers, 1, slots + 1, H, P, N),
                                       jnp.float32)]
     none = jnp.zeros((slots,), bool)
     state_bytes = 2 * H * P * N * 4               # read once, written once
-    for name, step in forms:
-        def program(arena, step=step):
+    floor = slots * state_bytes / HBM_BYTES_PER_S
+
+    def layer_time(step):
+        """(device, host) seconds a layer and the largest operations of
+        nine layers' steps in one program, the arena donated."""
+        def program(arena):
             total = jnp.zeros((slots, H, P), jnp.float32)
             for li in range(layers):
                 y, arena = step(arena, li, none)
@@ -117,13 +177,29 @@ def main():
             return total
 
         device, host, largest = timed(run, 8, args.tiny, top=4)
-        floor = slots * state_bytes / HBM_BYTES_PER_S
+        return device and device / layers, host and host / layers, largest
+
+    for name, step in forms:
+        device, host, largest = layer_time(step)
         result["step"][name] = {
-            "layer_us": device and device / layers * 1e6,
-            "host_layer_us": host and host / layers * 1e6,
+            "layer_us": device and device * 1e6,
+            "host_layer_us": host and host * 1e6,
             "floor_us": floor * 1e6, "top_operations_us": largest,
-            "ssd_decode_hbm_roofline": device
-            and 100 * floor * layers / device}
+            "ssd_decode_hbm_roofline": device and 100 * floor / device}
+    # the three readings, and the arithmetic with the DMA out of the way
+    result["step"]["kernel"]["as_served"] = result["step"]["kernel"]["layer_us"]
+    served = ssd_step._kernel
+    try:
+        for name, stand_ins in [("no_y", dict(no_y=True)),
+                                ("no_broadcasts", dict(no_broadcasts=True))]:
+            ssd_step._kernel = throw_away(served, **stand_ins)
+            device = layer_time(kernel_step)[0]
+            result["step"]["kernel"][name] = device and device * 1e6
+    finally:
+        ssd_step._kernel = served
+    device = layer_time(functools.partial(kernel_step,
+                                          ids=jnp.ones_like(ids)))[0]
+    result["step"]["kernel"]["arithmetic_alone"] = device and device * 1e6
     del holder[0]
 
     def operands(rows, seed):
