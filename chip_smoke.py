@@ -478,7 +478,7 @@ def phase_state_group(hidden=256, heads=2, head_dim=128, rank=128, rope=128,
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from paddle_tpu.models import _latent, kimi_linear
+    from paddle_tpu.models import _delta, _latent, kimi_linear
     from paddle_tpu.serving import ServingConfig, ServingEngine
 
     cfg = kimi_linear.KimiLinearConfig(
@@ -543,7 +543,7 @@ def phase_state_group(hidden=256, heads=2, head_dim=128, rank=128, rope=128,
              f"{stats['decode_attention']}, {state}")
     _require(state["peak_blocks_used"] == 2 and state["blocks_used"] == 0,
              f"state_group: the slot's two state blocks: {state}")
-    chunks = sum(-(-n // kimi_linear.KDA_CHUNK) for n in prompt_lens)
+    chunks = sum(-(-n // _delta.KDA_CHUNK) for n in prompt_lens)
     _require(stats["kda_state_steps"] == 4 * (sum(max_news) - len(max_news))
              and stats["kda_prefill_rows"] == 4 * sum(prompt_lens)
              and stats["kda_prefill_chunks"] == 4 * chunks,
@@ -650,6 +650,107 @@ def phase_state_space(hidden=256, heads=2, kv_heads=1, mamba_heads=8,
              f"{stats['ssd_prefill_rows']} prefill rows")
     return {"state": state, "ssd_state_steps": stats["ssd_state_steps"],
             "ssd_prefill_rows": stats["ssd_prefill_rows"],
+            "max_logit_deficit": worst}
+
+
+def phase_gated_delta(hidden=256, heads=2, kv_heads=1, head_dim=256,
+                      key_heads=2, value_heads=4, width=128, experts=8,
+                      picks=3, vocab=512, prompt_lens=(200, 70),
+                      max_news=(18, 3), bucket=256, page=128,
+                      force_kernels=False):
+    """Two requests, one behind the other, of a small hybrid model
+    (models/qwen3_next: three Gated-DeltaNet layers, key heads shared 2 : 1
+    by the value heads under a SCALAR decay a head, and one GATED
+    grouped-query attention layer of head size 256 with a partial rotation,
+    lane-aligned widths, bfloat16, half of the experts held, the shared
+    expert weighed by the token) through the engine: the flash prefill at d
+    = dv = 256 and the scan kernel ops/kda_chunk over broadcast operands
+    ending MID-BUCKET (a prompt of 200 rows in a bucket of 256: the state
+    written is the one AT row 200, the fourth chunk's; one of 70 in a bucket
+    of 128), then chunks of recurrent steps through ops/kda_step beside the
+    grouped paged kernel over (kv_heads, 128, 512) pages, the slot's state a
+    block of a float32 state group. Every served token's logit against its
+    position's largest by the program's own float32 forward over the whole
+    sequence (no cache, no kernel). `force_kernels` (the CPU test): take
+    the kernel paths interpreted."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.models import _delta, _grouped, qwen3_next
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    cfg = qwen3_next.Qwen3NextConfig(
+        vocab_size=vocab, hidden=hidden, layers=4, heads=heads,
+        kv_heads=kv_heads, head_dim=head_dim, gdn_key_heads=key_heads,
+        gdn_value_heads=value_heads, moe_intermediate=width,
+        shared_intermediate=width, n_routed_experts=experts,
+        experts_per_tok=picks, experts_held=(experts // 2, experts // 2),
+        max_pos=max(4 * page, 2 * bucket), init_range=0.05,
+        name="qwen3-next-smoke")
+    params = qwen3_next.init_params(cfg, jax.random.PRNGKey(56),
+                                    jnp.bfloat16)
+    forced = (_grouped.decode_attention_path, qwen3_next.recurrence_path,
+              qwen3_next.prefill_recurrence_path,
+              _grouped.prefill_attention_path)
+    if force_kernels:
+        # the programs' and the verdicts' one source
+        _grouped.decode_attention_path = \
+            lambda a, c=None: {"full": "paged_kernel"}
+        qwen3_next.recurrence_path = lambda cfg: "kernel"
+        qwen3_next.prefill_recurrence_path = \
+            lambda cfg, bucket=None: "kernel"
+        _grouped.prefill_attention_path = lambda a, b, c=None: "flash"
+    try:
+        engine = ServingEngine(params, cfg, ServingConfig(
+            num_slots=2, prefill_buckets=(bucket // 2, bucket),
+            max_len=cfg.max_pos, block_size=page, decode_chunk=8))
+        rng = np.random.default_rng(56)
+        served = []
+        for prompt_len, max_new in zip(prompt_lens, max_news):
+            prompt = rng.integers(0, vocab, prompt_len)
+            req = engine.submit(prompt, max_new)
+            engine.run_until_drained()
+            served.append((prompt, req, max_new))
+        stats = engine.stats()
+        wide = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params)
+        worst = 0.0
+        for prompt, req, max_new in served:
+            _require(req.state == "finished" and len(req.tokens) == max_new,
+                     f"gated_delta: {len(req.tokens)} of {max_new} tokens "
+                     "served")
+            logits = np.asarray(qwen3_next.forward_logits(
+                wide, cfg, jnp.asarray(list(prompt) + list(req.tokens)))
+            )[len(prompt) - 1:-1]
+            deficit = logits.max(-1) - logits[np.arange(max_new),
+                                              np.asarray(req.tokens)]
+            _require(float(deficit.max()) <= LOGIT_MARGIN,
+                     f"gated_delta: a served token lies {float(deficit.max())}"
+                     " under its position's best logit")
+            worst = max(worst, float(deficit.max()))
+    finally:
+        (_grouped.decode_attention_path, qwen3_next.recurrence_path,
+         qwen3_next.prefill_recurrence_path,
+         _grouped.prefill_attention_path) = forced
+    state = stats["state"]
+    _require(stats["decode_attention"] == {"full": "paged_kernel"}
+             and state["recurrence_path"] == "kernel"
+             and state["prefill_recurrence_path"] == "kernel"
+             and stats["prefill_attention"]["path"] == "flash",
+             "gated_delta: a step gathered or a recurrence ran in XLA: "
+             f"{stats['decode_attention']}, {state}, "
+             f"{stats['prefill_attention']}")
+    _require(state["peak_blocks_used"] == 2 and state["blocks_used"] == 0,
+             f"gated_delta: the slot's two state blocks: {state}")
+    chunks = sum(-(-n // _delta.KDA_CHUNK) for n in prompt_lens)
+    _require(stats["gdn_state_steps"] == 3 * (sum(max_news) - len(max_news))
+             and stats["gdn_prefill_rows"] == 3 * sum(prompt_lens)
+             and stats["gdn_prefill_chunks"] == 3 * chunks,
+             f"gated_delta: {stats['gdn_state_steps']} state steps, "
+             f"{stats['gdn_prefill_rows']} prefill rows, "
+             f"{stats['gdn_prefill_chunks']} chunks visited")
+    return {"state": state, "gdn_state_steps": stats["gdn_state_steps"],
+            "gdn_prefill_rows": stats["gdn_prefill_rows"],
+            "gdn_prefill_chunks": stats["gdn_prefill_chunks"],
             "max_logit_deficit": worst}
 
 
@@ -1096,6 +1197,7 @@ def main():
     run("block_diffusion", phase_block_diffusion)
     run("state_group", phase_state_group)
     run("state_space", phase_state_space)
+    run("gated_delta", phase_gated_delta)
     # the published context (tiled kernels), then s=512 (single-pass)
     long_run = run("train_s1024", phase_train, cfg, batch=8, seq=1024,
                    reference=True)

@@ -2,7 +2,7 @@
 feed-forward: the RMS norm, rotary positions (plain and YaRN), masked XLA
 attention, the stage `embed`, the untied head and the activation type.
 
-Imported by models/{moonlight, mellum, command_a, sdar}.py, by
+Imported by every served leaf but GPT's, by
 models/_experts.py and by models/_grouped.py; imports no model and, at
 module level, no jax (`import paddle_tpu` never loads this file).
 """
@@ -15,13 +15,16 @@ __all__ = ["rms", "yarn_mscale", "rope_frequencies", "rope",
            "masked_attention", "embed", "head", "act_dtype"]
 
 
-def rms(x, g, eps):
-    """RMS norm, statistics in float32, the result in x's type."""
+def rms(x, g, eps, centred=False):
+    """RMS norm, statistics in float32, the result in x's type. `centred`
+    (static): the weight is stored ZERO-CENTRED and the scale is `1 + g`
+    (Qwen3-Next's family: a weight of zeros is the plain norm)."""
     import jax
     import jax.numpy as jnp
     x32 = x.astype(jnp.float32)
     inv = jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return (x32 * inv * g.astype(jnp.float32)).astype(x.dtype)
+    g = g.astype(jnp.float32)
+    return (x32 * inv * (1.0 + g if centred else g)).astype(x.dtype)
 
 
 def yarn_mscale(factor, mscale):

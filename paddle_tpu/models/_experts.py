@@ -2,13 +2,15 @@
 its in-graph counters, and the half of a serving class that every such
 block repeats.
 
-SEVEN CELLS run this file (`moe_time_share` 45-69% of their busy time in
+NINE CELLS run this file (`moe_time_share` 45-69% of their busy time in
 the first five: PERF.md, section 5): moonlight-longctx-offline,
 xing-longdoc-offline (models/moonlight.py), mellum-mixedlen-offline
 (models/mellum.py), command-a-reason-offline (models/command_a.py),
 sdar-blockgen-offline (models/sdar.py), kimi-linear-longgen-offline
-(models/kimi_linear.py) and longcat-flash-agentgen-offline
-(models/longcat_flash.py). A change here is a change to all seven.
+(models/kimi_linear.py), longcat-flash-agentgen-offline
+(models/longcat_flash.py), granite-h-shortchat-offline
+(models/granite_hybrid.py) and qwen3-next-longmix-offline
+(models/qwen3_next.py). A change here is a change to all nine.
 
 THE LAYER (`moe`). Every token goes to `experts_per_tok` of the router's
 outputs (`route`, by one of THREE rules): `n_routed_experts` SwiGLU
@@ -32,9 +34,11 @@ no shared expert, none traced), required; `router_scoring`, "sigmoid" (the
 default) or "softmax"; `router_renormalize`, True (the picks' weights
 over their sum); `routed_scaling_factor`, 1.0; `zero_expert_num`, 0 (the
 router's outputs past `n_routed_experts`: identity experts, held by
-every chip alike); `shared_expert_combination`, "sum" (the default) or
+every chip alike); `shared_expert_combination`, "sum" (the default),
 "average" (the
-shared experts, stored as ONE SwiGLU n times as wide, over their count);
+shared experts, stored as ONE SwiGLU n times as wide, over their count) or
+"token_gate" (the shared term weighed by the TOKEN, `sigmoid(x .
+shared_token_gate)` in float32, the layer's vector (h,));
 `experts_held`, None (all) or (first, count), the routed experts this chip
 holds (a layer that holds a SHARE of the router's outputs reads its
 products back pick by pick and adds a pick of an expert held elsewhere,
@@ -43,7 +47,8 @@ or of an identity expert, as 0 by a select: `_weighted_sum`);
 `router` (h, E) (its presence makes the layer a routed one), `router_bias`
 (E,) float32 where the picks are ranked with a correction bias, `w_gate`,
 `w_up` (held, h, F), `w_down` (held, F, h), with shared experts
-`shared_gate`, `shared_up` (h, Fs), `shared_down` (Fs, h); `ffn` also
+`shared_gate`, `shared_up` (h, Fs), `shared_down` (Fs, h), under
+"token_gate" `shared_token_gate` (h,); `ffn` also
 `norm2` and a dense layer's `gate`, `up`, `down`.
 
 Scopes: `moe/router`, `moe/dispatch`, `moe/experts`, `moe/shared`,
@@ -63,7 +68,8 @@ __all__ = ["route", "held_experts", "checked_share", "router_width",
            "expert_product_path", "grouped_experts",
            "combine_path", "moe", "experts", "ffn", "swiglu", "swiglu_hidden",
            "zero_counters", "counter_names", "counters", "ExpertBlockModel",
-           "COMBINE_KERNEL_FROM", "HELD_SLACK", "HELD_SPLIT_FROM"]
+           "COMBINE_KERNEL_FROM", "COMBINE_KERNEL_PICKS",
+           "COMBINE_KERNEL_WIDE", "HELD_SLACK", "HELD_SPLIT_FROM"]
 
 _LANES = 128
 
@@ -202,18 +208,34 @@ def grouped_experts(lp, xs, group_sizes, tile, packed=False):
 # from it on 34-80 ns (command-a's 4,096 bucket, 96 MiB, is the smallest
 # that is slow: PERF.md, PR 39).
 COMBINE_KERNEL_FROM = 84 << 20
+# WHERE THE KERNEL HAS BEEN HELD ON THE CHIP: at most this many picks a token
+# at any served width (4 of 3,584, 6 and 8 of 2,048, 8 of 2,304 and of
+# 4,096), and more picks from this width on (granite's 10 of 4,096).
+# OUTSIDE it, at 10 picks of 2,048 (PR 56: a prompt of 8,192 tokens, a held
+# buffer of 36,864 rows, the picks' sum in float32), a prefill through the
+# kernel left the chip in a state in which the decode chunk behind it
+# halted the core or hung; the same prefills through XLA's gather serve
+# every bucket (`tools/`-free probes, PERF.md section 6). NOT UNDERSTOOD:
+# the kernel runs without Mosaic's range checks, its arithmetic is generic
+# in k and in the row's width, and interpreted it is right. Until it is,
+# the shape is not sent to it (ROADMAP S18 a).
+COMBINE_KERNEL_PICKS = 8
+COMBINE_KERNEL_WIDE = 4096
 
 
-def combine_path(lp, x, rows):
+def combine_path(lp, x, rows, picks):
     """ "row_dma_kernel" where the expert product is the kernel, the rows
-    are bfloat16 of whole 256 lanes and `rows` of them (static) make
-    COMBINE_KERNEL_FROM bytes; "gather" elsewhere (the CPU, a decode
-    step, a short prompt)."""
+    are bfloat16 of whole 256 lanes, `rows` of them (static) make
+    COMBINE_KERNEL_FROM bytes and `picks` a token (static) at this width
+    lie where the kernel has been held on the chip; "gather" elsewhere
+    (the CPU, a decode step, a short prompt, more picks of a narrow
+    row)."""
     import jax.numpy as jnp
     h = x.shape[1]
     if expert_product_path(lp) == "grouped_swiglu_kernel" \
             and x.dtype == jnp.bfloat16 and h % (2 * _LANES) == 0 \
-            and rows * h * x.dtype.itemsize >= COMBINE_KERNEL_FROM:
+            and rows * h * x.dtype.itemsize >= COMBINE_KERNEL_FROM \
+            and (picks <= COMBINE_KERNEL_PICKS or h >= COMBINE_KERNEL_WIDE):
         return "row_dma_kernel"
     return "gather"
 
@@ -376,7 +398,7 @@ def moe(cfg, lp, x, live):
     if parts > 1:
         slots = T * k // parts
     by_dma = combine_path(
-        lp, x, padded_rows(slots, held, tile)) == "row_dma_kernel"
+        lp, x, padded_rows(slots, held, tile), k) == "row_dma_kernel"
     if parts == 1:
         ys, pos, group_sizes = _lay_out(lp, x, picks, mine, held, tile,
                                         slots, average, by_dma)
@@ -422,7 +444,16 @@ def moe(cfg, lp, x, live):
                 # MiB more at Xing's 16k bucket)
                 hidden, ys = jax.lax.optimization_barrier((hidden, ys))
             shared = hidden @ lp["shared_down"]
-        if getattr(cfg, "shared_expert_combination", "sum") == "average":
+            combination = getattr(cfg, "shared_expert_combination", "sum")
+            if combination == "token_gate":
+                # a weight a token, in float32, on the term the sum's end
+                # adds in float32
+                gate = jax.nn.sigmoid(jnp.dot(
+                    x.astype(jnp.float32),
+                    lp["shared_token_gate"].astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST))
+                shared = shared.astype(jnp.float32) * gate[:, None]
+        if combination == "average":
             scale = 1.0 / cfg.n_shared_experts
     if identity is not None:
         # the identity picks' term rides where the shared experts' does

@@ -1,6 +1,8 @@
-"""Grouped-query attention over CACHE GROUPS: what Mellum, command-a and
-SDAR share (models/mellum.py, command_a.py, sdar.py; the cells
-mellum-mixedlen-offline, command-a-reason-offline, sdar-blockgen-offline).
+"""Grouped-query attention over CACHE GROUPS: what Mellum, command-a,
+SDAR, granite-4.0-h-small and Qwen3-Next share (models/mellum.py,
+command_a.py, sdar.py, granite_hybrid.py, qwen3_next.py; the cells
+mellum-mixedlen-offline, command-a-reason-offline, sdar-blockgen-offline,
+granite-h-shortchat-offline, qwen3-next-longmix-offline).
 
   * `heads` query heads share `kv_heads` KV heads (query head i reads KV
     head i // group); the cache row of a token in a layer is a KV head's K
@@ -20,7 +22,9 @@ mellum-mixedlen-offline, command-a-reason-offline, sdar-blockgen-offline).
     kernel does not apply (the CPU).
 
 What a block brings itself: its norms, its projections and positions
-(`_project`, written three times: the three differ in norm and rotation),
+(`_project`, written once a leaf: they differ in norm, in rotation (none,
+whole, YaRN, the first quarter of a head) and in what else the query
+projection carries (Qwen3-Next's output gate)),
 its residual (sequential or parallel), its programs.
 
 Imports no model and, at module level, no jax.

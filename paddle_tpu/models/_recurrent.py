@@ -1,8 +1,9 @@
 """A slot's RECURRENT STATE and its CONVOLUTION HISTORY as blocks of two
 state groups: what every block with a fixed-size state a slot shares
-(models/kimi_linear.py's delta-rule layers, models/granite_hybrid.py's
-state-space layers; the cells kimi-linear-longgen-offline and
-granite-h-shortchat-offline).
+(models/kimi_linear.py's and models/qwen3_next.py's delta-rule layers,
+models/granite_hybrid.py's state-space layers; the cells
+kimi-linear-longgen-offline, granite-h-shortchat-offline and
+qwen3-next-longmix-offline).
 
 Such a layer keeps NO rows a token. What a slot carries of it is one block
 of a float32 group (the state, `STATE`) and one block of a group of the
@@ -26,7 +27,8 @@ mixer's own arithmetic:
     (a frozen slot's write goes to scratch block 0).
 
 What a mixer brings itself: its projections, activation, recurrence (one
-position and chunked), gate and kernels.
+position and chunked; the delta rule's, which two mixers share, is
+models/_delta.py's), gate and kernels.
 
 Imports no model and, at module level, no jax.
 """

@@ -29,7 +29,8 @@ the two published lists (`kda_layers`, `full_attn_layers`, 1-indexed):
         o = S^T q
     in float32; then `RMSNorm_d(o) sigmoid((u Wg_down) Wg_up)` a head and
     `Wo`. No positions anywhere.
-    Its two programs: the RECURRENT STEP (`kda_step`: one position a slot,
+    Its two programs (the recurrence itself is models/_delta.py's, which
+    models/qwen3_next.py shares): the RECURRENT STEP (`kda_step`: one position a slot,
     ONE read-modify-write of the slot's state block; a frozen slot's write
     goes to scratch block 0) and the CHUNKED PREFILL (`kda_chunked`: the
     same recurrence over a prompt in chunks of `KDA_CHUNK` rows, solved
@@ -90,26 +91,14 @@ hits are off.
 
 from __future__ import annotations
 
-import weakref
-
 from ..serving import pages as _pages
 from ..serving.model import CacheSpec, group_columns
-from . import _decoder, _experts, _latent, _recurrent
+from . import _decoder, _delta, _experts, _latent, _recurrent
 
 __all__ = ["KimiLinearConfig", "init_params", "forward_logits",
-           "prefill_pages", "decode_step_pages", "kda_chunked", "kda_step",
-           "kda_step_inputs", "kda_state_update", "recurrence_path",
-           "prefill_recurrence_path", "KDA_CHUNK",
+           "prefill_pages", "decode_step_pages", "kda_step_inputs",
+           "kda_state_update", "recurrence_path", "prefill_recurrence_path",
            "KIMI_LINEAR_SERVING_MODEL"]
-
-# Rows a chunk of the prefill's scan, and rows a sub-chunk inside which
-# decays are taken elementwise (the published kernels' sizes).
-KDA_CHUNK = 64
-KDA_SUB = 16
-# The chunked form's products are float32 at this precision: it is the
-# recurrence to float32 rounding, and a state that four thousand rows of
-# bfloat16 products built would be a state kept in a lower precision.
-KDA_PRECISION = "highest"
 
 LATENT, STATE, CONV = "latent", _recurrent.STATE, _recurrent.CONV
 GROUPS = (LATENT, STATE, CONV)
@@ -362,22 +351,6 @@ def prefill_recurrence_path(cfg, bucket=None):
     return "xla"
 
 
-# {cfg: {bucket: path}}: what `prefill_pages` took in each bucket it was
-# traced for, for `engine.stats()["state"]` to report what RAN
-_PREFILLS_TRACED = weakref.WeakKeyDictionary()
-
-
-def _prefill_paths_taken(cfg):
-    """("kernel" if a traced prefill of `cfg` ran the kernel in some bucket
-    else "xla", the buckets that did). Before any prefill is traced: the
-    rule's word for a bucket of whole tiles, and no bucket."""
-    traced = _PREFILLS_TRACED.get(cfg)
-    if not traced:
-        return prefill_recurrence_path(cfg), []
-    kernel = sorted(b for b, path in traced.items() if path == "kernel")
-    return ("kernel" if kernel else "xla"), kernel
-
-
 def _kda_project(cfg, lp, u):
     """`kda/project`: q|k|v before the convolution (T, 3C), the decay's
     pre-activation a (T, C), beta's b (T, n) and the gate's z (T, C)."""
@@ -421,105 +394,6 @@ def _kda_gate(cfg, lp, o, z):
         return y.reshape(T, -1).astype(z.dtype) @ lp["wo"]
 
 
-def kda_step(S, q, k, v, g, beta):
-    """The recurrence, one position: S (..., dk, dv) float32, q, k, g
-    (..., dk), v (..., dv), beta (...,). Returns (S_t, o_t)."""
-    import jax.numpy as jnp
-    Sd = jnp.exp(g)[..., None] * S
-    u = beta[..., None] * (v - jnp.sum(Sd * k[..., None], -2))
-    S = Sd + k[..., None] * u[..., None, :]
-    return S, jnp.sum(S * q[..., None], -2)
-
-
-def kda_chunked(q, k, v, g, beta, S0=None, chunk=KDA_CHUNK, sub=KDA_SUB):
-    """The recurrence over T positions of one sequence in chunks: q, k, g
-    (T, n, dk) float32, v (T, n, dv), beta (T, n), S0 (n, dk, dv) or None
-    (zeros). Returns (o (T, n, dv) float32, S_T). Algebraically
-    `kda_step` T times. Inside a chunk of C rows with the cumulative
-    decay G_r = sum_{i<=r} g_i: the delta rule's corrections U solve the
-    unit-lower-triangular system (I + diag(beta) A) U = diag(beta) (V -
-    K+ S0), A_ji = sum_c k_j k_i exp(G_j - G_i) for i < j, K+ = k
-    exp(G); then O = Q+ S0 + B U with B_rj = sum_c q_r k_j exp(G_r - G_j)
-    for j <= r, and S_C = exp(G_C) S0 + (k exp(G_C - G))^T U. Every
-    exponent is <= 0: between sub-chunks of `sub` rows the differences
-    are taken against the later sub-chunk's first row (two factors, each
-    at most 1, and a product the MXU does), inside a sub-chunk
-    elementwise. A row with beta = 0 and g = 0 leaves the state as it
-    was."""
-    import jax
-    import jax.numpy as jnp
-    hi = KDA_PRECISION
-    T, n, dk = q.shape
-    dv = v.shape[-1]
-    sub = min(sub, chunk)
-    C = min(chunk, -(-T // sub) * sub)
-    if C % sub:
-        raise ValueError(f"a chunk of {C} rows is not whole sub-chunks of "
-                         f"{sub}")
-    N, ns = -(-T // C), C // sub
-    if N * C != T:
-        pad = ((0, N * C - T), (0, 0), (0, 0))
-        q, k, v, g = (jnp.pad(a, pad) for a in (q, k, v, g))
-        beta = jnp.pad(beta, pad[:2])
-    # (N, n, C, d): a chunk's rows next to the lanes' axis
-    q, k, v, g = (a.reshape(N, C, n, -1).transpose(0, 2, 1, 3)
-                  for a in (q, k, v, g))
-    beta = beta.reshape(N, C, n).transpose(0, 2, 1)
-    G = jnp.cumsum(g, 2)
-    Gs = G.reshape(N, n, ns, sub, dk)
-    ks = k.reshape(Gs.shape)
-    # the rows of both Gram matrices, A's (k) and B's (q), side by side
-    rows = jnp.stack([ks, q.reshape(Gs.shape)])          # (2,N,n,ns,sub,dk)
-    # inside a sub-chunk, elementwise: sum_c r_j k_i exp(G_j - G_i), i <= j
-    # (one reduce; the (sub, sub, dk) terms are never stored)
-    low = jnp.tril(jnp.ones((sub, sub), bool))
-    inside = jnp.exp(jnp.where(
-        low[..., None], Gs[:, :, :, :, None] - Gs[:, :, :, None], -jnp.inf))
-    diag = jnp.sum(rows[..., :, None, :] * ks[:, :, :, None] * inside, -1)
-    # between sub-chunks, against the LATER one's first row: its own rows
-    # decayed from there, the earlier ones' keys decayed up to there
-    own = rows * jnp.exp(Gs - Gs[:, :, :, :1])
-    blocks = []
-    for rb in range(ns):
-        parts = []
-        if rb:
-            back = ks[:, :, :rb] * jnp.exp(Gs[:, :, rb, None, :1]
-                                           - Gs[:, :, :rb])
-            parts.append(jnp.einsum(
-                "xbhjc,bhic->xbhji", own[:, :, :, rb],
-                back.reshape(N, n, rb * sub, dk), precision=hi))
-        parts.append(diag[:, :, :, rb])
-        if rb < ns - 1:
-            parts.append(jnp.zeros(diag.shape[:3]
-                                   + (sub, (ns - 1 - rb) * sub), diag.dtype))
-        blocks.append(jnp.concatenate(parts, -1))
-    grams = jnp.concatenate(blocks, -2)                   # (2, N, n, C, C)
-    A, B = jnp.tril(grams[0], -1), grams[1]
-    k_plus = k * jnp.exp(G)
-    q_plus = q * jnp.exp(G)
-    k_end = k * jnp.exp(G[:, :, -1:] - G)
-    L = jnp.eye(C, dtype=A.dtype) + beta[..., None] * A
-    rhs = beta[..., None] * jnp.concatenate([v, k_plus], -1)
-    solved = jax.lax.linalg.triangular_solve(
-        L, rhs, left_side=True, lower=True, unit_diagonal=True)
-    u_v, w = solved[..., :dv], solved[..., dv:]
-    decay_end = jnp.exp(G[:, :, -1])                          # (N, n, dk)
-
-    def carry(S, c):
-        u_v, w, q_plus, B, k_end, decay_end = c
-        U = u_v - jnp.einsum("hck,hkv->hcv", w, S, precision=hi)
-        o = jnp.einsum("hck,hkv->hcv", q_plus, S, precision=hi) \
-            + jnp.einsum("hrj,hjv->hrv", B, U, precision=hi)
-        S = decay_end[..., None] * S \
-            + jnp.einsum("hck,hcv->hkv", k_end, U, precision=hi)
-        return S, o
-
-    if S0 is None:
-        S0 = jnp.zeros((n, dk, dv), jnp.float32)
-    S, o = jax.lax.scan(carry, S0, (u_v, w, q_plus, B, k_end, decay_end))
-    return o.transpose(0, 2, 1, 3).reshape(N * C, n, dv)[:T], S
-
-
 def _kda_prompt(cfg, lp, u, real_len, path):
     """A KDA layer's mixer over ONE sequence's rows u (B, h), `real_len`
     of them real, from a zero state, the scan by `path`
@@ -538,12 +412,7 @@ def _kda_prompt(cfg, lp, u, real_len, path):
         g = jnp.where(live[:, None, None], g, 0.0)
         beta = jnp.where(live[:, None], beta, 0.0)
     with jax.named_scope("kda/recur"):
-        if path == "kernel":
-            from ..ops.kda_chunk import kda_chunk
-            o, S, visited = kda_chunk(q, k, v, g, beta, real_len=real_len)
-        else:
-            o, S = kda_chunked(q, k, v, g, beta)
-            visited = -(-B // KDA_CHUNK)
+        o, S, visited = _delta.scan(q, k, v, g, beta, real_len, path)
     return _kda_gate(cfg, lp, o, z), S, hist, visited
 
 
@@ -573,16 +442,8 @@ def kda_state_update(arenas, lg, state_ids, done, q, k, v, g, beta, path):
     engine's own blocks (benchmarks/modes/serve-closed-kimi-linear.py)."""
     import jax
     with jax.named_scope("kda/recur"):
-        state = arenas[STATE]
-        if path == "kernel":
-            from ..ops.kda_step import kda_step_blocks
-            o, arenas[STATE] = kda_step_blocks(
-                state, lg, state_ids, done, q, k, v, g, beta)
-        else:
-            S, o = kda_step(_recurrent.read_blocks(state, lg, state_ids),
-                            q, k, v, g, beta)
-            arenas[STATE] = _recurrent.write_blocks(state, lg, state_ids,
-                                                    done, S)
+        o, arenas[STATE] = _delta.step_blocks(
+            arenas[STATE], lg, state_ids, done, q, k, v, g, beta, path)
     return o, arenas
 
 
@@ -658,7 +519,7 @@ def prefill_pages(params, cfg, tokens, pfx_len, real_len, arena, pages):
     state_id, conv_id = _recurrent.block_ids(pages, cols[1:])
     flash = _pages.kernel_beside(bucket=B)
     recurrence = prefill_recurrence_path(cfg, B)
-    _PREFILLS_TRACED.setdefault(cfg, {})[B] = recurrence
+    _delta.note_prefill(cfg, B, recurrence)
     j = jnp.arange(B)
     pos = pfx_len + j
     live = j < real_len
@@ -770,7 +631,8 @@ class _KimiLinearServingModel(_experts.ExpertBlockModel):
 
     def describe(self, cfg):
         first, count = _experts.held_experts(cfg)
-        prefill_path, kernel_buckets = _prefill_paths_taken(cfg)
+        prefill_path, kernel_buckets = _delta.prefill_paths_taken(
+            cfg, prefill_recurrence_path(cfg))
         return {"experts_held": {"first": first, "count": count,
                                  "of": cfg.n_routed_experts},
                 "vocab_slice": dict(zip(("first", "rows", "of"),
@@ -778,7 +640,7 @@ class _KimiLinearServingModel(_experts.ExpertBlockModel):
                 "state": {"recurrence_path": recurrence_path(cfg),
                           "prefill_recurrence_path": prefill_path,
                           "prefill_kernel_buckets": kernel_buckets,
-                          "prefill_chunk_rows": KDA_CHUNK}}
+                          "prefill_chunk_rows": _delta.KDA_CHUNK}}
 
     def _counters(self, cfg, c, decode):
         import jax.numpy as jnp

@@ -4,8 +4,8 @@ state in VMEM from the first chunk to the last.
     S' = exp(g)[:, None] * S;  S = S' + outer(k, beta (v - S'^T k));
     o = S^T q                                  (a row a head, float32)
 
-over T rows of ONE sequence, algebraically `models/kimi_linear.kda_step` T
-times, in the chunked form `models/kimi_linear.kda_chunked` derives: with
+over T rows of ONE sequence, algebraically `models/_delta.kda_step` T
+times, in the chunked form `models/_delta.kda_chunked` derives: with
 the cumulative decay G_r = sum_{i<=r} g_i inside a chunk of 64 rows, the
 delta rule's corrections solve the unit-lower-triangular system
 (I + diag(beta) A) U = diag(beta) (V - K+ S0), A_ji = sum_c k_j k_i

@@ -30,7 +30,7 @@ def test_main_exits_nonzero_on_cpu_before_any_work(monkeypatch, capsys):
 
     for name in ("phase_kernels", "phase_share_kernels",
                  "phase_block_diffusion", "phase_state_group",
-                 "phase_state_space", "phase_train",
+                 "phase_state_space", "phase_gated_delta", "phase_train",
                  "phase_serve"):
         monkeypatch.setattr(chip_smoke, name, no_work)
     assert jax.default_backend() == "cpu"
@@ -52,6 +52,7 @@ def test_last_stdout_line_is_the_verdict_and_the_device(monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, "phase_block_diffusion", lambda: {})
     monkeypatch.setattr(chip_smoke, "phase_state_group", lambda: {})
     monkeypatch.setattr(chip_smoke, "phase_state_space", lambda: {})
+    monkeypatch.setattr(chip_smoke, "phase_gated_delta", lambda: {})
     monkeypatch.setattr(
         chip_smoke, "phase_train",
         lambda *a, **kw: {"losses": [2.0, 1.0], "scope": None})
@@ -145,6 +146,27 @@ def test_state_space_phase_interpreted():
     assert facts["ssd_state_steps"] == 3 * (9 + 2)
     assert facts["ssd_prefill_rows"] == 3 * 270
     assert facts["max_logit_deficit"] <= chip_smoke.LOGIT_MARGIN / 16
+
+
+def test_gated_delta_phase_interpreted():
+    """The phase at a small size with the kernel paths forced: the flash
+    forward and the grouped paged kernel at head size 256 interpreted beside
+    the delta rule's two kernels over broadcast operands (a scalar decay, 2
+    key heads under 4 value heads), two prompts that end mid-bucket (the
+    longer one inside its fourth chunk), a chunk and a part of steps."""
+    facts = chip_smoke.phase_gated_delta(
+        hidden=128, heads=2, kv_heads=1, head_dim=256, key_heads=2,
+        value_heads=4, width=128, experts=4, picks=2, vocab=256,
+        prompt_lens=(200, 70), max_news=(10, 3), bucket=256, page=128,
+        force_kernels=True)
+    assert facts["state"]["recurrence_path"] == "kernel"
+    assert facts["state"]["prefill_recurrence_path"] == "kernel"
+    assert facts["state"]["prefill_kernel_buckets"] == [128, 256]
+    assert facts["state"]["peak_blocks_used"] == 2
+    assert facts["gdn_state_steps"] == 3 * (9 + 2)
+    assert facts["gdn_prefill_rows"] == 3 * 270
+    assert facts["gdn_prefill_chunks"] == 3 * (4 + 2)
+    assert facts["max_logit_deficit"] <= chip_smoke.LOGIT_MARGIN
 
 
 def test_train_then_serve_phases():

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from test_kimi_linear import CFG, _kda_inputs
 
+from paddle_tpu.models import _delta
 from paddle_tpu.models import kimi_linear as kl
 from paddle_tpu.ops import kda_chunk as kc
 from paddle_tpu.serving import SlotKVCache
@@ -52,7 +53,7 @@ def _scan(q, k, v, g, beta, S0=None):
     """`kda_step` a row at a time: (o (T, n, dv), S_T)."""
     n, d = q.shape[1:]
     S0 = jnp.zeros((n, d, v.shape[-1])) if S0 is None else S0
-    S, o = jax.lax.scan(lambda S, x: kl.kda_step(S, *x), S0,
+    S, o = jax.lax.scan(lambda S, x: _delta.kda_step(S, *x), S0,
                         (q, k, v, g, beta))
     return o, S
 
@@ -68,7 +69,7 @@ def test_the_kernel_is_the_recurrence_and_the_chunked_form(length):
     assert int(visited) == -(-length // kc.CHUNK)
     assert float(jnp.abs(o - want_o).max()) <= O_ATOL
     assert float(jnp.abs(S - want_S).max()) <= S_ATOL
-    form_o, form_S = kl.kda_chunked(*operands)
+    form_o, form_S = _delta.kda_chunked(*operands)
     assert float(jnp.abs(o - form_o).max()) <= 2 * O_ATOL
     assert float(jnp.abs(S - form_S).max()) <= 2 * S_ATOL
 
@@ -106,7 +107,7 @@ def test_correlated_keys_are_solved_as_the_recurrence_solves_them(kind, length):
     assert float(jnp.abs(want_o).max()) > 0.1
     assert float(jnp.abs(o - want_o).max()) <= O_ATOL
     assert float(jnp.abs(S - want_S).max()) <= S_ATOL
-    form_o, form_S = kl.kda_chunked(*operands)
+    form_o, form_S = _delta.kda_chunked(*operands)
     assert float(jnp.abs(o - form_o).max()) <= 2 * O_ATOL
     assert float(jnp.abs(S - form_S).max()) <= 2 * S_ATOL
 
@@ -167,7 +168,7 @@ def test_every_product_of_the_kernel_is_float32_at_highest():
 
 
 def test_an_odd_head_count_is_refused():
-    assert (kc.CHUNK, kc.SUB) == (kl.KDA_CHUNK, kl.KDA_SUB)
+    assert (kc.CHUNK, kc.SUB) == (_delta.KDA_CHUNK, _delta.KDA_SUB)
     q, k, v, g, beta = _kda_inputs(8, 1)
     with pytest.raises(ValueError, match="pairs heads"):
         kc.kda_chunk(q[:, :1], k[:, :1], v[:, :1], g[:, :1], beta[:, :1])

@@ -29,6 +29,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
 from reference import kimi_linear_ref as ref                 # noqa: E402
 
 from paddle_tpu.models import _experts as ex                 # noqa: E402
+from paddle_tpu.models import _delta                         # noqa: E402
 from paddle_tpu.models import _latent                        # noqa: E402
 from paddle_tpu.models import kimi_linear as kl              # noqa: E402
 from paddle_tpu.models import moonlight as ml                # noqa: E402
@@ -153,30 +154,30 @@ def test_the_chunked_form_is_the_recurrence(length, chunk):
         assert not bool(jnp.isfinite(jnp.exp(-jnp.cumsum(g, 0))).all())
     with jax.default_matmul_precision("highest"):
         want = ref.kda_recurrence(q, k, v, g, beta)
-        got, S = kl.kda_chunked(q, k, v, g, beta, chunk=chunk)
+        got, S = _delta.kda_chunked(q, k, v, g, beta, chunk=chunk)
     assert got.shape == (length, 2, 16) and bool(jnp.isfinite(got).all())
     assert float(jnp.abs(got - want).max()) <= 5e-6
     # the state it ends in is the recurrence's
     S_want = jnp.zeros((2, 16, 16))
     for t in range(length):
-        S_want, _ = kl.kda_step(S_want, q[t], k[t], v[t], g[t], beta[t])
+        S_want, _ = _delta.kda_step(S_want, q[t], k[t], v[t], g[t], beta[t])
     assert float(jnp.abs(S - S_want).max()) <= 5e-5
 
 
 def test_a_padded_row_leaves_the_state_as_it_was():
     q, k, v, g, beta = _kda_inputs(40, 7, strong=False)
     live = jnp.arange(40) < 27
-    _, S_cut = kl.kda_chunked(q[:27], k[:27], v[:27], g[:27], beta[:27], chunk=16)
-    _, S_pad = kl.kda_chunked(q, k, v, jnp.where(live[:, None, None], g, 0.0),
+    _, S_cut = _delta.kda_chunked(q[:27], k[:27], v[:27], g[:27], beta[:27], chunk=16)
+    _, S_pad = _delta.kda_chunked(q, k, v, jnp.where(live[:, None, None], g, 0.0),
                               jnp.where(live[:, None], beta, 0.0), chunk=16)
     assert float(jnp.abs(S_cut - S_pad).max()) <= 1e-6
 
 
 def test_the_chunked_form_carries_a_state_in():
     q, k, v, g, beta = _kda_inputs(48, 9, strong=False)
-    whole_o, whole_S = kl.kda_chunked(q, k, v, g, beta, chunk=16)
-    _, S0 = kl.kda_chunked(q[:20], k[:20], v[:20], g[:20], beta[:20], chunk=16)
-    o, S = kl.kda_chunked(q[20:], k[20:], v[20:], g[20:], beta[20:], S0, chunk=16)
+    whole_o, whole_S = _delta.kda_chunked(q, k, v, g, beta, chunk=16)
+    _, S0 = _delta.kda_chunked(q[:20], k[:20], v[:20], g[:20], beta[:20], chunk=16)
+    o, S = _delta.kda_chunked(q[20:], k[20:], v[20:], g[20:], beta[20:], S0, chunk=16)
     assert float(jnp.abs(o - whole_o[20:]).max()) <= 5e-6
     assert float(jnp.abs(S - whole_S).max()) <= 5e-6
 
@@ -591,7 +592,7 @@ def test_the_step_kernel_is_the_recurrence_and_spares_a_frozen_slot():
                         -jnp.exp(jax.random.normal(key[4], (S, H, D))),
                         jax.nn.sigmoid(jax.random.normal(key[5], (S, H))))
     o, new = ks.kda_step_blocks(arena, 1, ids, done, q, k, v, g, beta)
-    S_want, o_want = kl.kda_step(arena[1, 0, ids], q, k, v, g, beta)
+    S_want, o_want = _delta.kda_step(arena[1, 0, ids], q, k, v, g, beta)
     live = np.asarray(~done)
     assert float(jnp.abs(o - o_want)[live].max()) <= 1e-5
     assert float(jnp.abs(new[1, 0, ids[live]] - S_want[live]).max()) <= 1e-5

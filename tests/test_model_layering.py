@@ -3,9 +3,10 @@
   serving/{engine, scheduler, kv_cache, decode_loop}       know no model
   serving/model.py, serving/pages.py                       the interface
   models/_decoder.py, _experts.py, _grouped.py, _latent.py,
-         _recurrent.py                                     shared pieces
+         _recurrent.py, _delta.py                          shared pieces
   models/{gpt_decode, moonlight, mellum, command_a, sdar,
-          kimi_linear, longcat_flash, granite_hybrid}      leaves
+          kimi_linear, longcat_flash, granite_hybrid,
+          qwen3_next}                                      leaves
 
 R1: no leaf imports another leaf. R2: a module under models/ imports from
 serving/ only `model` and `pages`. R3: a leaf takes the shared pieces as
@@ -30,8 +31,9 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODELS = os.path.join(ROOT, "paddle_tpu", "models")
 LEAVES = ("gpt_decode", "moonlight", "mellum", "command_a", "sdar",
-          "kimi_linear", "longcat_flash", "granite_hybrid")
-SHARED = ("_decoder", "_experts", "_grouped", "_latent", "_recurrent")
+          "kimi_linear", "longcat_flash", "granite_hybrid", "qwen3_next")
+SHARED = ("_decoder", "_experts", "_grouped", "_latent", "_recurrent",
+          "_delta")
 SERVED = LEAVES + SHARED
 
 

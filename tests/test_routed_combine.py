@@ -187,17 +187,22 @@ def test_the_path_is_chosen_by_the_products_static_size(monkeypatch):
     lp = {"w_gate": jnp.zeros((2, 256, 128), jnp.bfloat16)}
     x = jnp.zeros((8, 256), jnp.bfloat16)
     enough = ex.COMBINE_KERNEL_FROM // (256 * 2)
-    assert ex.combine_path(lp, x, enough) == "gather"             # the CPU
+    assert ex.combine_path(lp, x, enough, 8) == "gather"          # the CPU
     monkeypatch.setattr(ex, "expert_product_path",
                         lambda lp: "grouped_swiglu_kernel")
-    assert ex.combine_path(lp, x, enough) == "row_dma_kernel"
-    assert ex.combine_path(lp, x, enough - 1) == "gather"
-    assert ex.combine_path(lp, x.astype(jnp.float32), enough) == "gather"
+    assert ex.combine_path(lp, x, enough, 8) == "row_dma_kernel"
+    assert ex.combine_path(lp, x, enough - 1, 8) == "gather"
+    assert ex.combine_path(lp, x.astype(jnp.float32), enough, 8) == "gather"
     assert ex.combine_path(lp, jnp.zeros((8, 384), jnp.bfloat16),
-                           enough) == "gather"
+                           enough, 8) == "gather"
+    # more than eight picks go to the kernel from 4,096 values a row on: where
+    # it has been held on the chip (granite's 10 of 4,096), and not where it
+    # has not (PR 56: Qwen3-Next's 10 of 2,048)
+    assert ex.combine_path(lp, x, enough, 10) == "gather"
     cells = {  # h, k, router's experts, held, parts of a prompt
         "moonlight": (2048, 6, 64, 64, 1), "xing": (3584, 4, 64, 64, 1),
-        "mellum": (2304, 8, 64, 64, 1), "commanda": (4096, 8, 128, 16, 4)}
+        "mellum": (2304, 8, 64, 64, 1), "commanda": (4096, 8, 128, 16, 4),
+        "sdar": (2048, 8, 128, 128, 1), "granite": (4096, 10, 72, 36, 1)}
 
     def path(model, tokens):
         h, k, experts, held, parts = cells[model]
@@ -205,7 +210,7 @@ def test_the_path_is_chosen_by_the_products_static_size(monkeypatch):
         slots = tokens * k // (parts if tokens * k >= ex.HELD_SPLIT_FROM
                                else 1)
         return ex.combine_path(lp, jnp.zeros((tokens, h), jnp.bfloat16),
-                               gs.padded_rows(slots, held, tile))
+                               gs.padded_rows(slots, held, tile), k)
 
     for model in cells:
         for tokens in (4096, 6144, 8192, 16384):
@@ -214,6 +219,10 @@ def test_the_path_is_chosen_by_the_products_static_size(monkeypatch):
             assert path(model, step) == "gather", (model, step)
     assert path("mellum", 512) == path("mellum", 1024) == "gather"
     assert path("moonlight", 2048) == path("xing", 2048) == "row_dma_kernel"
+    assert path("granite", 2048) == "row_dma_kernel"
+    cells["qwen3next"] = (2048, 10, 512, 64, 4)
+    for tokens in (2048, 4096, 8192, 12288, 16384):
+        assert path("qwen3next", tokens) == "gather", tokens
 
 
 # sha256 (16 hex) of str(jax.make_jaxpr(...)) of `_moe` at a decode step's and

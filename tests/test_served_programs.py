@@ -67,6 +67,16 @@ passes with the hash it had, `granite_hybrid.decode` (the CPU's
 gather-update-scatter), `.prefill`, `.prefill.tpu` and `.init` among them:
 nothing that two leaves share moved.
 
+`qwen3_next.*` (PR 56's leaf: a Gated-DeltaNet mixer on the state groups
+and a gated attention layer through `models/_grouped.py`) is its own tree's,
+the first that has it. The same PR moved the delta rule's two `jax.numpy`
+forms and their two dispatches out of `models/kimi_linear.py` into
+`models/_delta.py`, which both leaves call, gave `models/_decoder.rms` its
+key `centred` (the family's `1 + w`) and `models/_experts.moe` its third
+`shared_expert_combination`, "token_gate": with the keys at their defaults
+EVERY other row passes with the hash it had, Kimi-Linear's four among them
+(a move of code changes no jaxpr).
+
 Mellum's and command-a's programs are pinned by tests/test_sdar.py::PARENT,
 the CPU's pair of GPT, Moonlight and Xing by tests/test_mellum.py::PARENT.
 The CPU gives identity, never a time.
@@ -154,6 +164,11 @@ PARENT = {
     "granite_hybrid.prefill.tpu": "d0c35c86d052900a",
     "granite_hybrid.decode.tpu": "a4688ee096887fcc",
     "granite_hybrid.init": "e9d4705ca900bc67",
+    "qwen3_next.prefill": "265634792235a427",
+    "qwen3_next.decode": "5c732b71912ea21a",
+    "qwen3_next.prefill.tpu": "384dd40b198b3c96",
+    "qwen3_next.decode.tpu": "3d4376b4b0774f40",
+    "qwen3_next.init": "07d394916d954b03",
 }
 
 _YARN = {"type": "yarn", "factor": 4, "beta_fast": 32, "beta_slow": 1,
@@ -222,6 +237,20 @@ def _config(model, wide):
             mamba_state=128 if wide else 16, mamba_chunk=64 if wide else 8,
             moe_intermediate=128 if wide else 32,
             shared_intermediate=256 if wide else 48, n_routed_experts=8,
+            experts_per_tok=3, experts_held=(4, 4),
+            vocab_slice=(96, 96, 768), max_pos=256,
+            init_range=0.08), init_params
+    if model == "qwen3_next":
+        from paddle_tpu.models.qwen3_next import Qwen3NextConfig, init_params
+        return Qwen3NextConfig(
+            vocab_size=96, hidden=128 if wide else 64, layers=4,
+            heads=2 if wide else 4, kv_heads=1 if wide else 2,
+            head_dim=64 if wide else 16, gdn_key_heads=1 if wide else 2,
+            gdn_value_heads=2 if wide else 4,
+            gdn_key_dim=128 if wide else 16,
+            gdn_value_dim=128 if wide else 16,
+            moe_intermediate=128 if wide else 32,
+            shared_intermediate=128 if wide else 32, n_routed_experts=8,
             experts_per_tok=3, experts_held=(4, 4),
             vocab_slice=(96, 96, 768), max_pos=256,
             init_range=0.08), init_params
@@ -383,9 +412,12 @@ CASES = (
                        "decode.tpu")]
     + [f"granite_hybrid.{program}"
        for program in ("prefill", "decode", "prefill.tpu", "decode.tpu")]
+    + [f"qwen3_next.{program}"
+       for program in ("prefill", "decode", "prefill.tpu", "decode.tpu")]
     + [f"{model}.init" for model in ("gpt", "moonlight", "xing", "mellum",
                                      "command_a", "sdar", "kimi_linear",
-                                     "longcat_flash", "granite_hybrid")])
+                                     "longcat_flash", "granite_hybrid",
+                                     "qwen3_next")])
 
 
 @pytest.mark.parametrize("name", CASES)

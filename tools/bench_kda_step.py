@@ -25,7 +25,16 @@ Device time is the sum of the first chip's operations in a profiler trace;
 the host's clock a call stands beside it. A chip is required (`--tiny`
 rehearses the program on the CPU at a toy size and reports no time).
 
-    chiprun -- python tools/bench_kda_step.py
+    chiprun -- python tools/bench_kda_step.py [--model qwen3next]
+
+`--model qwen3next` (PR 56): models/qwen3_next.py's Gated DeltaNet at ITS
+cell's shapes, 64 slots, 9 layers, prompts of 2,048 .. 16,384 rows, a
+SCALAR decay a head and 16 key heads feeding 32 value heads (value head h
+reads q, k of key head h // 2). Both reach the two kernels as BROADCAST
+operands (the decay over a head's 128 key channels, a key head's q, k
+over its two value heads), so the kernels are Kimi-Linear's as they are;
+what that costs beside the state's 2 MB a head-set a slot is this tool's
+`step.kernel.layer_us` against `floor_us`.
 
 Prints one JSON line; the same goes to chiprun_out/bench_kda_step.json.
 """
@@ -42,6 +51,14 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 
 HBM_BYTES_PER_S = 819e9          # TPU v5e (Google Cloud documentation)
 PEAK_FLOPS = 197e12
+# a served delta-rule mixer's shapes: both have 32 value heads of 128 x 128
+MODELS = {
+    "kimi": {"slots": 128, "layers": 10, "value_heads_a_key_head": 1,
+             "scalar_decay": False, "rows": "512,1024,2048,4096"},
+    "qwen3next": {"slots": 64, "layers": 9, "value_heads_a_key_head": 2,
+                  "scalar_decay": True, "rows": "2048,4096,8192,16384"}}
+# the plain chunked form holds every chunk's Gram matrices at once
+XLA_FORM_UP_TO = 4096
 
 
 def operation_seconds(trace_dir):
@@ -86,11 +103,19 @@ def timed(run, calls, tiny, top=14):
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--tiny", action="store_true")
-    ap.add_argument("--slots", type=int, default=128)
-    ap.add_argument("--rows", default="512,1024,2048,4096")
+    ap.add_argument("--model", choices=tuple(MODELS), default="kimi",
+                    help="kimi: 128 slots, 10 layers, a decay a key channel, "
+                    "32 key heads; qwen3next: 64 slots, 9 layers, a SCALAR "
+                    "decay a head and 16 key heads feeding 32 value heads "
+                    "(value head h reads q, k of key head h // 2), both "
+                    "handed to the kernels as broadcast operands, as "
+                    "models/qwen3_next.py serves them")
+    ap.add_argument("--slots", type=int, default=None)
+    ap.add_argument("--rows", default=None)
     ap.add_argument("--precision", default=None,
-                    help="replace models/kimi_linear.KDA_PRECISION (a sweep)")
+                    help="replace models/_delta.KDA_PRECISION (a sweep)")
     args = ap.parse_args()
+    model = MODELS[args.model]
 
     import jax
     import jax.numpy as jnp
@@ -99,14 +124,29 @@ def main():
         print(f"a chip is required; the backend is {jax.default_backend()!r}",
               file=sys.stderr)
         return 1
-    from paddle_tpu.models import kimi_linear as kl
+    from paddle_tpu.models import _delta
     from paddle_tpu.ops.kda_chunk import kda_chunk
     from paddle_tpu.ops.kda_step import kda_step_blocks
     if args.precision:
-        kl.KDA_PRECISION = args.precision
+        _delta.KDA_PRECISION = args.precision
 
     slots, heads, d, layers = (4, 4, 16, 2) if args.tiny else \
-        (args.slots, 32, 128, 10)
+        (args.slots or model["slots"], 32, 128, model["layers"])
+    share = model["value_heads_a_key_head"]
+
+    def keyed(key, lead):
+        """q or k: a unit vector a KEY head, read by its value heads."""
+        x = jax.random.normal(key, (lead, heads // share, d))
+        return jnp.repeat(x / jnp.linalg.norm(x, axis=-1, keepdims=True),
+                          share, 1)
+
+    def decay(key, lead):
+        """g <= 0: a value a key channel, or one a head over its channels."""
+        g = -jnp.exp(jax.random.uniform(
+            key, (lead, heads, 1 if model["scalar_decay"] else d),
+            minval=-7.0, maxval=0.5))
+        return jnp.broadcast_to(g, (lead, heads, d))
+
     rng = np.random.default_rng(0)
     key = jax.random.split(jax.random.PRNGKey(0), 8)
     arena = 0.1 * jax.random.normal(key[0], (layers, 1, slots + 1, heads, d, d),
@@ -114,15 +154,14 @@ def main():
     ids = jnp.asarray(1 + rng.permutation(slots), jnp.int32)
     done = jnp.arange(slots) % 7 == 5
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-    q = unit(jax.random.normal(key[1], (slots, heads, d))) * d ** -0.5
-    k = unit(jax.random.normal(key[2], (slots, heads, d)))
+    q = keyed(key[1], slots) * d ** -0.5
+    k = keyed(key[2], slots)
     v = jax.random.normal(key[3], (slots, heads, d))
-    g = -jnp.exp(jax.random.uniform(key[4], (slots, heads, d), minval=-7.0,
-                                    maxval=0.5))
+    g = decay(key[4], slots)
     beta = jax.nn.sigmoid(jax.random.normal(key[5], (slots, heads)))
 
     def xla_step(arena, li, done):
-        S, o = kl.kda_step(arena[li, 0, ids], q, k, v, g, beta)
+        S, o = _delta.kda_step(arena[li, 0, ids], q, k, v, g, beta)
         return o, arena.at[li, 0, jnp.where(done, 0, ids)].set(S)
 
     def kernel_step(arena, li, done):
@@ -137,7 +176,10 @@ def main():
     if not (err_o < 1e-4 and err_s < 1e-4):
         raise SystemExit(f"the kernel disagrees with XLA's form: o {err_o}, "
                          f"state {err_s}")
-    result = {"slots": slots, "heads": heads, "head_dim": d, "layers": layers,
+    result = {"model": args.model, "slots": slots, "heads": heads,
+              "key_heads": heads // share,
+              "scalar_decay": model["scalar_decay"], "head_dim": d,
+              "layers": layers,
               "kernel_vs_xla": {"o": err_o, "state": err_s}, "step": {},
               "prefill": []}
     none = jnp.zeros((slots,), bool)
@@ -166,23 +208,24 @@ def main():
             "kda_decode_hbm_roofline": device and 100 * floor * layers / device}
     def operands(rows, seed):
         kk = jax.random.split(jax.random.PRNGKey(seed), 5)
-        return (unit(jax.random.normal(kk[0], (rows, heads, d))) * d ** -0.5,
-                unit(jax.random.normal(kk[1], (rows, heads, d))),
+        return (keyed(kk[0], rows) * d ** -0.5, keyed(kk[1], rows),
                 jax.random.normal(kk[2], (rows, heads, d)),
-                -jnp.exp(jax.random.uniform(kk[3], (rows, heads, d),
-                                            minval=-7.0, maxval=0.5)),
+                decay(kk[3], rows),
                 jax.nn.sigmoid(jax.random.normal(kk[4], (rows, heads))))
 
-    forms = {"xla": jax.jit(lambda *a: kl.kda_chunked(*a)[:2]),
+    forms = {"xla": jax.jit(lambda *a: _delta.kda_chunked(*a)[:2]),
              "kernel": jax.jit(lambda *a: kda_chunk(*a)[:2])}
-    for rows in ([64] if args.tiny else [int(r) for r in args.rows.split(",")]):
+    for rows in ([64] if args.tiny else [
+            int(r) for r in (args.rows or model["rows"]).split(",")]):
         ops = operands(rows, rows)
         for path, form in forms.items():
+            if path == "xla" and rows > XLA_FORM_UP_TO:
+                continue           # gigabytes of Gram matrices; never served
             device, host, largest = timed(lambda: form(*ops)[0], 4, args.tiny)
             flops = rows * heads * 6 * d * d
             result["prefill"].append({
-                "rows": rows, "path": path, "chunk": kl.KDA_CHUNK,
-                "precision": kl.KDA_PRECISION, "top_operations_us": largest,
+                "rows": rows, "path": path, "chunk": _delta.KDA_CHUNK,
+                "precision": _delta.KDA_PRECISION, "top_operations_us": largest,
                 "layer_us": device and device * 1e6,
                 "host_layer_us": host and host * 1e6, "flops_counted": flops,
                 "kda_prefill_flops_roofline": device
@@ -195,7 +238,7 @@ def main():
     ops = operands(rows, 46)
 
     def scan(q, k, v, g, beta):
-        S, o = jax.lax.scan(lambda S, x: kl.kda_step(S, *x),
+        S, o = jax.lax.scan(lambda S, x: _delta.kda_step(S, *x),
                             jnp.zeros((heads, d, d), jnp.float32),
                             (q, k, v, g, beta))
         return o, S
@@ -205,7 +248,7 @@ def main():
         jnp.einsum = lambda spec, *xs, **kw: real(
             spec, *(jax.lax.reduce_precision(x, 8, 7) for x in xs), **kw)
         try:
-            return kl.kda_chunked(*a)[:2]
+            return _delta.kda_chunked(*a)[:2]
         finally:
             jnp.einsum = real
 
@@ -235,7 +278,9 @@ def main():
         out = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
                            "chiprun_out")
         os.makedirs(out, exist_ok=True)
-        with open(os.path.join(out, "bench_kda_step.json"), "w") as f:
+        name = "bench_kda_step.json" if args.model == "kimi" \
+            else f"bench_kda_step.{args.model}.json"
+        with open(os.path.join(out, name), "w") as f:
             f.write(line + "\n")
     return 0
 
