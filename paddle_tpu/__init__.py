@@ -71,7 +71,11 @@ class TPUPlace:
 
 CUDAPlace = TPUPlace  # source compat for reference scripts
 
-# the compile log hears jax from here on, its one installation (what a caller
-# jits before its first Executor or engine is part of its start too)
+# from here on the persistent compile cache has its directory and the compile
+# log hears jax: one installation each (what a caller jits before its first
+# Executor or engine, its weights first of all, is part of its start too, and
+# is loaded by the next start only if the cache knew where to keep it)
+from .utils.compile_cache import ensure_compile_cache as _ensure_compile_cache
+_ensure_compile_cache()
 _IMPORT_END_NS = _time.monotonic_ns()
 observability.compile_log().install()

@@ -28,7 +28,6 @@ import numpy as np
 from .core import Program, Variable, default_main_program
 from .registry import LowerContext, lower_op, get_op_def
 from ..observability.compile_log import compile_log
-from ..utils.compile_cache import ensure_compile_cache
 from ..observability.metrics import get_registry
 from ..observability.tracer import get_tracer, trace_span
 from ..observability import train_stats as _train_stats
@@ -257,7 +256,6 @@ class Executor:
         self.recompile_log: "deque[Dict[str, Any]]" = deque(maxlen=64)
         self.last_fetch_names: List[str] = []  # incl. telemetry extras
         _ensure_prng_default()
-        ensure_compile_cache()
 
     def _memo(self, cache, key, build):
         """LRU memoize into `cache` bounded by the shared capacity."""

@@ -217,7 +217,6 @@ from .. import profiler
 from ..observability import request_log as _request_log
 from ..observability.tracer import get_tracer, trace_span
 from ..observability.compile_log import compile_log
-from ..utils.compile_cache import ensure_compile_cache
 from . import sampling
 from .decode_loop import (FINISH_SCOPE, SAMPLE_SCOPE, DecodeCarry,
                           decode_chunk, finish_rule, open_block,
@@ -768,7 +767,6 @@ class ContinuousBatchingScheduler:
     def _ensure_jits(self):
         if self._chunk_jit is not None:
             return
-        ensure_compile_cache()
         # once an engine, at its first request: the carry, the device
         # page table and the jitted entry points (nothing compiles yet)
         with compile_log().phase("serving/engine_build/jits"):

@@ -1,8 +1,16 @@
 """Where XLA's persistent compile cache lives.
 
-The executor and the serving scheduler call `ensure_compile_cache()`
-before they build their first jit, so a second process (or a second run
-on the same machine) loads executables instead of compiling them again.
+`import paddle_tpu` calls `ensure_compile_cache()` as its last act, beside
+the compile log's installation, and nobody else has to: every executable
+the process makes after the import goes through the cache, the caller's
+own first of all (weights built or loaded on the device before any
+Executor or engine exists), so a second process on the same machine loads
+them instead of compiling them again. Until PR 58 the directory was set by
+`Executor.__init__` and the scheduler's `_ensure_jits`, after a server's
+weights had been compiled with no cache to keep them; those calls are gone.
+Setting the directory touches no device and creates nothing on disk (jax
+opens the cache at the first compile, and not at all where
+`jax_enable_compilation_cache` is false).
 """
 
 import os

@@ -4,6 +4,9 @@ every sum, `compile/*` spans nested where the thread was, and the pin: a warm
 step and a warm tick never reach the log."""
 
 import json
+import os
+import subprocess
+import sys
 import threading
 import urllib.request
 
@@ -221,6 +224,99 @@ def test_the_packages_import_is_the_one_installation(monkeypatch, tmp_path):
     assert not LOG.installed()      # where the cache lives, and no more
     import paddle_tpu.utils.compile_cache as where
     assert "observability" not in open(where.__file__).read()
+
+
+# The suite's own process has the persistent cache disabled (conftest.py), so
+# what `import paddle_tpu` does to it is looked at in processes of their own:
+# one that starts as a user's does, with the package in a checkout of its own
+# (a link to this one under tmp_path), so that `<checkout>/.jax_cache` is
+# nobody else's.
+
+_AFTER_THE_IMPORT = """
+import json, os
+{before}
+import paddle_tpu as pt
+import jax
+from jax._src import xla_bridge
+print(json.dumps({{"package": os.path.dirname(pt.__file__),
+                  "dir": jax.config.jax_compilation_cache_dir,
+                  "backends": sorted(xla_bridge._backends)}}))
+"""
+
+_A_MAKER_BEFORE_ANY_ENGINE = """
+import json, sys
+import paddle_tpu as pt
+import jax, jax.numpy as jnp
+# as benchmarks/run.py does after its import: the quick programs persist too
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+@jax.jit
+def make_weights(key):
+    return jax.random.normal(key, (32, 32)) @ jnp.ones((32, 32))
+
+# the key is an argument: one cache entry serves every seed
+make_weights(jax.random.PRNGKey(int(sys.argv[1]))).block_until_ready()
+log = pt.observability.compile_log()
+print(json.dumps({"maker": [r["cache"] for r in log.records()
+                            if r["fun_name"] == "make_weights"],
+                  "cache": log.snapshot()["totals"]["cache"],
+                  "dir": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+def _in_a_process_of_its_own(tmp_path, code, *argv, env=()):
+    checkout = tmp_path / "checkout"
+    if not checkout.exists():
+        checkout.mkdir()
+        (checkout / "paddle_tpu").symlink_to(os.path.dirname(pt.__file__),
+                                             target_is_directory=True)
+    environ = {k: v for k, v in os.environ.items() if k not in (
+        "JAX_ENABLE_COMPILATION_CACHE", "JAX_COMPILATION_CACHE_DIR")}
+    environ.update(env, PYTHONPATH=str(checkout))
+    done = subprocess.run([sys.executable, "-c", code, *argv], env=environ,
+                          cwd=checkout, capture_output=True, text=True,
+                          timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+    return checkout, json.loads(done.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("whose", ["nobody", "caller", "environment"])
+def test_the_import_configures_the_cache_directory(tmp_path, whose):
+    named = str(tmp_path / "named")
+    before = ('import jax; jax.config.update("jax_compilation_cache_dir", '
+              f'{named!r})' if whose == "caller" else "")
+    env = {"JAX_COMPILATION_CACHE_DIR": named} if whose == "environment" else {}
+    checkout, said = _in_a_process_of_its_own(
+        tmp_path, _AFTER_THE_IMPORT.format(before=before), env=env)
+    assert said["package"] == str(checkout / "paddle_tpu")
+    # a directory somebody named is kept; else where the package sits
+    assert said["dir"] == (str(checkout / ".jax_cache") if whose == "nobody"
+                           else named)
+    # setting it touched no device and made nothing on disk
+    assert said["backends"] == []
+    assert not os.path.exists(named) and not (checkout / ".jax_cache").exists()
+
+
+@pytest.mark.parametrize("whose", ["checkout", "environment"])
+def test_a_maker_jitted_before_any_engine_goes_through_the_cache(tmp_path,
+                                                                 whose):
+    """What PR 58 moved: a function jitted right after the import, with no
+    Executor and no engine yet, is a `miss` in a first process and a `hit`
+    in a second, never `off` (with the directory set by the first engine it
+    was compiled on every start and written nowhere: a `miss` every time)."""
+    named = str(tmp_path / "named")
+    env = {"JAX_COMPILATION_CACHE_DIR": named} if whose == "environment" else {}
+    checkout, first = _in_a_process_of_its_own(
+        tmp_path, _A_MAKER_BEFORE_ANY_ENGINE, "7", env=env)
+    where = named if whose == "environment" else str(checkout / ".jax_cache")
+    assert first["dir"] == where
+    assert first["maker"] == ["miss"] and first["cache"]["off"] == 0
+    assert any(name.startswith("jit_make_weights") for name in os.listdir(where))
+    _, second = _in_a_process_of_its_own(
+        tmp_path, _A_MAKER_BEFORE_ANY_ENGINE, "11", env=env)
+    assert second["maker"] == ["hit"] and second["cache"]["off"] == 0
+    assert second["cache"]["miss"] == 0
 
 
 def test_import_is_a_phase_and_observability_stays_stdlib_only():
